@@ -15,6 +15,8 @@ phase-1 (seq<=128), 'pallas' for phase-2 (seq>=256) and anything longer.
 
 from __future__ import annotations
 
+import warnings
+
 import jax
 import jax.numpy as jnp
 
@@ -69,8 +71,10 @@ def dot_product_attention(
     """
     if backend == "auto":
         # Measured crossover (module docstring): the fused kernel wins from
-        # seq ~256 up; below that the XLA path is faster. Off-TPU the kernel
-        # would run in pure-Python interpret mode, so auto never picks it.
+        # seq ~256 up; below that the XLA path is faster. On the CPU backend
+        # (tests) the kernel would run in the Pallas interpreter, so auto
+        # never picks it there; the runners' start-up log says which of the
+        # two a process is (ops/pallas/common.py device_report).
         from bert_pytorch_tpu.ops.pallas.common import interpret_mode
 
         backend = (
@@ -101,9 +105,6 @@ def dot_product_attention(
     if backend == "pallas":
         # Fused kernel incl. in-kernel dropout from the TPU hardware PRNG
         # (the [B,H,S,S] mask never reaches HBM; see ops/pallas/attention.py).
-        # Interpret mode (CPU tests) has no PRNG lowering, so dropout falls
-        # back to the XLA path there (the fused-or-fallback policy of
-        # reference modeling.py:327-335).
         from bert_pytorch_tpu.ops.pallas.attention import flash_attention
         from bert_pytorch_tpu.ops.pallas.common import interpret_mode
 
@@ -121,6 +122,13 @@ def dot_product_attention(
                 q, k, v, bias=kbias,
                 dropout_rate=dropout_rate, dropout_rng=dropout_rng,
                 sequence_ids=sequence_ids)
+        # CPU backend only (interpret_mode): the hardware PRNG has no
+        # interpreter lowering, so a training step with dropout computes
+        # attention on the XLA path below. Never reached on a TPU.
+        warnings.warn(
+            "backend='pallas' with dropout on the CPU backend: the Pallas "
+            "interpreter has no PRNG, attention uses the XLA path",
+            RuntimeWarning, stacklevel=2)
     if backend in ("ring", "ring_manual") and sequence_ids is not None:
         # Ring attention shards the sequence axis across chips; the
         # block-diagonal mask would need per-shard id exchange alongside

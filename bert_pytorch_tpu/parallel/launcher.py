@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import os
 import subprocess
+import sys
 from typing import Optional
 
 import jax
@@ -53,36 +54,49 @@ def initialize(
     On Cloud TPU VMs `jax.distributed.initialize()` auto-discovers everything;
     the explicit arguments cover SLURM-style clusters (the reference's target,
     sbatch:64-70).
+
+    A rendezvous blocks until every process of the job has joined, so the
+    decision to join is never taken quietly: the reason is printed before the
+    call, and whatever the call raises propagates — a rank that cannot join
+    must not go on to train alone against peers that wait for it.
     """
     global _INITIALIZED
     if _INITIALIZED:
         return
-    explicit = (
-        coordinator_address is not None
-        or num_processes is not None
-        or process_id is not None
-    )
-    hostnames = os.environ.get("TPU_WORKER_HOSTNAMES", "")
-    auto_env = len([h for h in hostnames.split(",") if h]) > 1 or (
-        "MEGASCALE_COORDINATOR_ADDRESS" in os.environ
-    )
+    reasons = []
+    if (coordinator_address is not None or num_processes is not None
+            or process_id is not None):
+        reasons.append("explicit arguments")
     # Generic env override (the Cobalt ssh fan-out script sets these,
     # scripts/run_pretraining.cobalt; any launcher without SLURM vars can).
     # ANY of the three present marks the run as explicitly multi-host, so a
     # partially-configured rank fails loudly inside initialize() instead of
     # silently training solo while its peers block on the rendezvous.
-    env_explicit = any(v in os.environ for v in (
-        "JAX_COORDINATOR_ADDRESS", "JAX_NUM_PROCESSES", "JAX_PROCESS_ID"))
-    explicit = explicit or env_explicit
+    env_set = [v for v in ("JAX_COORDINATOR_ADDRESS", "JAX_NUM_PROCESSES",
+                           "JAX_PROCESS_ID") if v in os.environ]
+    if env_set:
+        reasons.append(",".join(env_set))
+    # A TPU VM always carries TPU_WORKER_HOSTNAMES; only MORE THAN ONE
+    # distinct host makes it a pod slice. One host — however many chips it
+    # holds — is one process with nothing to rendezvous with.
+    hosts = {h.strip() for h in
+             os.environ.get("TPU_WORKER_HOSTNAMES", "").split(",")} - {""}
+    if len(hosts) > 1:
+        reasons.append(f"TPU_WORKER_HOSTNAMES lists {len(hosts)} hosts")
+    if "MEGASCALE_COORDINATOR_ADDRESS" in os.environ:
+        reasons.append("MEGASCALE_COORDINATOR_ADDRESS")
+    slurm = ("SLURM_NODELIST" in os.environ
+             and int(os.environ.get("SLURM_NNODES", "1")) > 1)
+    if slurm:
+        reasons.append(f"SLURM_NNODES={os.environ['SLURM_NNODES']}")
+    if not reasons:
+        return  # single host, single process: nothing to rendezvous
     if coordinator_address is None:
         coordinator_address = os.environ.get("JAX_COORDINATOR_ADDRESS")
     if num_processes is None and "JAX_NUM_PROCESSES" in os.environ:
         num_processes = int(os.environ["JAX_NUM_PROCESSES"])
     if process_id is None and "JAX_PROCESS_ID" in os.environ:
         process_id = int(os.environ["JAX_PROCESS_ID"])
-    slurm = "SLURM_NODELIST" in os.environ and int(os.environ.get("SLURM_NNODES", "1")) > 1
-    if not (explicit or auto_env or slurm):
-        return  # single host, single process: nothing to rendezvous
     kwargs = {}
     if coordinator_address or slurm or process_id is not None:
         kwargs["coordinator_address"] = coordinator_address or infer_coordinator()
@@ -94,17 +108,8 @@ def initialize(
         kwargs["process_id"] = process_id
     elif slurm:
         kwargs["process_id"] = int(os.environ.get("SLURM_NODEID", "0"))
-    try:
-        jax.distributed.initialize(**kwargs)
-    except RuntimeError as e:
-        if "already initialized" in str(e).lower() and not env_explicit:
-            # A harness touched jax.devices() first on a single-host run;
-            # continue single-process rather than killing it.
-            import warnings
-
-            warnings.warn(f"jax.distributed.initialize skipped: {e}")
-            return
-        # Explicitly configured multi-host: a failed rendezvous must be
-        # fatal, or this rank trains solo against its peers.
-        raise
+    print(f"launcher: joining a multi-host rendezvous ({'; '.join(reasons)}) "
+          "— this blocks until every process of the job has started",
+          file=sys.stderr, flush=True)
+    jax.distributed.initialize(**kwargs)
     _INITIALIZED = True

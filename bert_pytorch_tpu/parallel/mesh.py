@@ -367,21 +367,16 @@ def logical_axis_rules(strategy="dp") -> list[tuple]:
 
 
 def current_mesh() -> Optional[Mesh]:
-    """The active mesh, from either the new ``jax.set_mesh``/``use_mesh``
-    context or the legacy ``with mesh:`` context used throughout this
-    codebase; None if neither is set."""
-    try:
-        m = jax.sharding.get_mesh()
-        if m is not None and getattr(m, "axis_names", ()):  # non-empty
-            return m
-    except Exception:
-        pass
-    try:
-        from jax._src.mesh import thread_resources
+    """The concrete mesh of the enclosing ``with mesh:`` block — the
+    context this codebase uses throughout — or None outside one.
 
-        pm = thread_resources.env.physical_mesh
-        if not pm.empty:
-            return pm
-    except Exception:
-        pass
-    return None
+    Model code asks while it is being traced under ``jit`` (ring
+    attention needs the devices for its ``shard_map``). jax 0.9's public
+    accessors do not answer that: ``jax.sharding.get_mesh()`` sees only
+    ``jax.set_mesh`` and refuses to be called under ``jit``, and
+    ``get_abstract_mesh()`` carries no devices. So this is the ONE private
+    jax name the package uses; tests/test_mesh.py pins it."""
+    from jax._src.mesh import thread_resources
+
+    mesh = thread_resources.env.physical_mesh
+    return None if mesh.empty else mesh

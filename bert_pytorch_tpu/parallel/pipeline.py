@@ -40,34 +40,16 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from bert_pytorch_tpu.parallel.mesh import AXIS_PIPE, AXIS_SEQ
 
-try:  # jax >= 0.5: top-level shard_map with axis_names + lax.pcast typing
-    from jax import shard_map as _shard_map
 
-    def shard_map(f, *, mesh, axis_names, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, axis_names=axis_names,
-                          in_specs=in_specs, out_specs=out_specs)
+def shard_map(f, *, mesh, axis_names, in_specs, out_specs):
+    """``jax.shard_map`` manual over ``axis_names`` only; every other mesh
+    axis stays automatic (module docstring, "Composition")."""
+    return jax.shard_map(f, mesh=mesh, axis_names=axis_names,
+                         in_specs=in_specs, out_specs=out_specs)
 
-    def _pcast_varying(x, axis_name):
-        return jax.lax.pcast(x, axis_name, to="varying")
 
-except ImportError:  # jax 0.4.x: experimental shard_map, auto= complement
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, axis_names, in_specs, out_specs):
-        # The old API is manual over every mesh axis NOT listed in ``auto``;
-        # the new axis_names= is its complement. check_rep=False because the
-        # legacy replication checker predates (and rejects) the partial-auto
-        # composition this engine relies on; the pcast/pvary annotations the
-        # new typing needs don't exist here, so they no-op below.
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        # jit wrapper: the legacy eager path raises NotImplementedError for
-        # partial-auto shard_maps; under the runner's outer jit this inlines.
-        return jax.jit(_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                  out_specs=out_specs, auto=auto,
-                                  check_rep=False))
-
-    def _pcast_varying(x, axis_name):
-        return x
+def _pcast_varying(x, axis_name):
+    return jax.lax.pcast(x, axis_name, to="varying")
 
 
 def stage_layer_count(n_layers: int, n_stages: int) -> int:

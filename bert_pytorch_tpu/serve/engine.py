@@ -485,6 +485,7 @@ class InferenceEngine:
         import jax
 
         from bert_pytorch_tpu.ops import quant as quant_ops
+        from bert_pytorch_tpu.ops.pallas.common import device_report
 
         t0 = self._clock()
         before = len(self.monitor.events)
@@ -529,6 +530,9 @@ class InferenceEngine:
             "autotune": self.autotune,
             "weight_bytes": sum(quant_ops.weight_bytes(s.params)
                                 for s in self.tasks.values()),
+            # platform / device_kind / device_count / kernels
+            # compiled|interpreted: what these forwards were compiled for
+            **device_report(),
         }
         self.warmed = True
         return len(self.monitor.events) - before

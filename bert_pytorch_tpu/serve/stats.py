@@ -315,7 +315,9 @@ class ServeTelemetry:
                     if cs.get(key) is not None:
                         record[f"warmup_{key}"] = cs[key]
                 for key in ("quantize", "attention_backend",
-                            "weight_bytes", "fuse_epilogues", "autotune"):
+                            "weight_bytes", "fuse_epilogues", "autotune",
+                            "platform", "device_kind", "device_count",
+                            "kernels"):
                     if cs.get(key) is not None:
                         record[key] = cs[key]
         # Outside the lock: the tracer takes its own lock, and nesting

@@ -149,7 +149,12 @@ def gated_grad_health(params, grads, updates, count, every: int,
     built): the host reads the block on its own run-local 0-based sync
     cadence, so a checkpoint-resumed run whose absolute count is not a
     multiple of ``every`` would otherwise have its due steps land only on
-    unsynced steps — zero records for the whole resumed run.
+    unsynced steps — zero records for the whole resumed run. It enters
+    the program only as ``phase % every``: the value is a compile-time
+    constant, so the raw resume step would make every resumed run a new
+    program that no persistent-cache entry matches. Reduced, a resume from
+    a multiple of the cadence (the checkpoint cadence is one, by default)
+    is the very program the first run compiled.
 
     The ``"due"`` scalar tells the host whether the values are real; the
     host additionally only fetches on synced steps, so the cadence that
@@ -165,7 +170,7 @@ def gated_grad_health(params, grads, updates, count, every: int,
     def compute():
         return grad_health(params, grads, updates, grad_scale=grad_scale)
 
-    due = ((count - phase) % every) == 0
+    due = ((count - phase % every) % every) == 0
     if every == 1:
         stats = compute()
     else:
@@ -209,8 +214,7 @@ def health_record(step: int, stats) -> dict:
     ``kind="grad_health"`` JSONL record (floats/lists only). The caller
     has already synced, so the fetch does not block on compute — but it
     is still one host<->device transfer per array, so pull the WHOLE
-    tree in a single device_get instead of ~50 scalar round trips
-    (which through a remote-TPU tunnel each cost a full round trip)."""
+    tree in a single device_get instead of ~50 scalar round trips."""
     import jax
 
     stats = jax.device_get(stats)

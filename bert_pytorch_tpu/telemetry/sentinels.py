@@ -4,8 +4,8 @@ The in-jit half lives in the train step (pretrain.make_train_step emits
 ``metrics["finite"]`` — an ``isfinite`` reduction over the step's losses and
 global grad-norm, one scalar, free to fetch alongside the loss). This module
 is the host half: the policy applied to that scalar, and the heartbeat file
-the capture harness reads instead of guessing liveness from checkpoint
-mtimes (scripts/retry_capture_r04.sh).
+a supervising process reads instead of guessing liveness from checkpoint
+mtimes (serve/supervisor.py does, for its replicas).
 
 K-FAC HBM overflows and fp16 overflows in rounds 2-4 surfaced as NaN losses
 that kept training silently for hundreds of steps before anyone looked at

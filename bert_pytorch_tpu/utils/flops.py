@@ -31,34 +31,39 @@ omitted (sub-1% and not MXU work).
 
 from __future__ import annotations
 
-# Peak dense bf16 matmul TFLOP/s per chip, by PJRT ``device_kind``
-# substring (lowercased). Public numbers from cloud.google.com/tpu/docs.
-_PEAK_TFLOPS_BY_KIND = (
-    # Order matters: the "lite" spellings must match before the generic
-    # generation entries (libtpu reports e.g. "TPU v5 lite" for v5e but
-    # plain "TPU v5" for v5p, and "TPU v6 lite" for v6e/Trillium).
-    ("v6e", 918.0),
-    ("v6 lite", 918.0),
-    ("trillium", 918.0),
-    ("v5p", 459.0),
-    ("v5e", 197.0),
-    ("v5 lite", 197.0),
-    ("v5litepod", 197.0),
-    ("v5", 459.0),
-    ("v4", 275.0),
-    ("v3", 123.0),
-    ("v2", 45.0),
-    # CPU fallback: no meaningful peak; callers should treat 0 as "unknown".
-)
+from typing import Optional
+
+# Peak dense bf16 matmul TFLOP/s per chip, keyed by the exact (lowercased)
+# PJRT ``device_kind``. Public numbers from cloud.google.com/tpu/docs.
+# libtpu reports "TPU v5 lite" for v5e but plain "TPU v5" for v5p, and
+# "TPU v6 lite" for v6e/Trillium — hence exact keys, not substrings: a kind
+# that merely contains "v5" must not be handed the v5p peak.
+_PEAK_TFLOPS_BY_KIND = {
+    "tpu v6 lite": 918.0,
+    "tpu v6e": 918.0,
+    "tpu v5p": 459.0,
+    "tpu v5": 459.0,
+    "tpu v5 lite": 197.0,
+    "tpu v5e": 197.0,
+    "tpu v4": 275.0,
+    "tpu v3": 123.0,
+    "tpu v2": 45.0,
+}
 
 
-def peak_tflops(device_kind: str) -> float:
-    """Peak bf16 TFLOP/s for a device kind string, or 0.0 if unknown."""
-    kind = device_kind.lower()
-    for sub, tf in _PEAK_TFLOPS_BY_KIND:
-        if sub in kind:
-            return tf
-    return 0.0
+def peak_tflops(device_kind: str) -> Optional[float]:
+    """Peak bf16 TFLOP/s for a device kind. None for a device that is not a
+    TPU (the CPU test mesh: no peak, so no MFU). A TPU kind that the table
+    does not hold is an error — an assumed peak would make every MFU
+    computed from it wrong without saying so."""
+    kind = device_kind.strip().lower()
+    if not kind.startswith("tpu"):
+        return None
+    if kind not in _PEAK_TFLOPS_BY_KIND:
+        raise ValueError(
+            f"no peak FLOP/s known for device kind {device_kind!r}; add it "
+            "to utils/flops.py _PEAK_TFLOPS_BY_KIND with its source")
+    return _PEAK_TFLOPS_BY_KIND[kind]
 
 
 def bert_encoder_flops_per_seq(config, seq_len: int) -> float:
@@ -104,9 +109,10 @@ def bert_finetune_flops_per_seq(config, seq_len: int, head_outputs: int = 2,
 
 
 def mfu(seq_per_sec_per_chip: float, flops_per_seq: float,
-        device_kind: str) -> float:
-    """Fraction of the chip's peak used by model FLOPs; 0.0 if peak unknown."""
+        device_kind: str) -> Optional[float]:
+    """Fraction of the chip's peak used by model FLOPs; None ("not
+    measured") off a TPU, where there is no peak to divide by."""
     peak = peak_tflops(device_kind)
-    if peak <= 0:
-        return 0.0
+    if peak is None:
+        return None
     return seq_per_sec_per_chip * flops_per_seq / (peak * 1e12)

@@ -1,16 +1,14 @@
 """Shared retry/backoff policy (docs/fault_tolerance.md).
 
 One place for the backoff math every resilient path uses — the HDF5
-shard reads in ``data/dataset.py``, the bench harness's attempt loop
-(bench.py), and any future network/storage client — instead of each
-call site hand-rolling its own sleep loop with slightly different
-semantics (the pre-PR-5 state: bench.py capped flat sleeps, the capture
-scripts re-invented theirs in shell).
+shard reads in ``data/dataset.py``, the serving router and supervisor,
+and any future network/storage client — instead of each call site
+hand-rolling its own sleep loop with slightly different semantics.
 
 Design constraints, all test-driven:
 
-* **stdlib-only** — the bench parent and the repo-root tools import this
-  by file path on machines without the accelerator stack (the
+* **stdlib-only** — the router, the supervisor and the repo-root tools
+  import this by file path on machines without the accelerator stack (the
   ``tools/_bootstrap.py`` property), so nothing here may import jax,
   numpy, or the package ``__init__`` chain;
 * **deterministic under test** — the jitter source, sleep function, and

@@ -64,7 +64,9 @@ def parse_arguments(argv=None):
     parser.add_argument("--max_seq_len", type=int, default=128)
     parser.add_argument("--seed", type=int, default=42)
     parser.add_argument("--compile_cache_dir", type=str, default="",
-                        help="persistent XLA compilation cache directory; empty disables")
+                        help="persistent XLA compilation cache directory; "
+                             "default <checkout>/.jax_cache, and "
+                             "JAX_COMPILATION_CACHE_DIR wins when set")
     parser.add_argument("--dtype", type=str, default="bfloat16",
                         choices=["bfloat16", "float32"])
     parser.add_argument("--skip_eval", action="store_true")

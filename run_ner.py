@@ -63,7 +63,9 @@ def parse_arguments(argv=None):
                              "(end of run, and on graceful preemption); "
                              "omitted = no checkpoint (pre-PR-5 behavior)")
     parser.add_argument("--compile_cache_dir", type=str, default="",
-                        help="persistent XLA compilation cache directory; empty disables")
+                        help="persistent XLA compilation cache directory; "
+                             "default <checkout>/.jax_cache, and "
+                             "JAX_COMPILATION_CACHE_DIR wins when set")
     parser.add_argument("--dtype", type=str, default="bfloat16",
                         choices=["bfloat16", "float32"])
     parser.add_argument("--save_steps", type=int, default=0,

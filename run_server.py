@@ -116,7 +116,10 @@ def parse_arguments(argv=None):
                              "<output_dir>/postmortem.json, disabled "
                              "without an output_dir")
     parser.add_argument("--compile_cache_dir", type=str, default="",
-                        help="persistent XLA compile cache; empty disables")
+                        help="persistent XLA compile cache; default "
+                             "<checkout>/.jax_cache, and "
+                             "JAX_COMPILATION_CACHE_DIR wins when set "
+                             "(utils/compile_cache.py)")
     parser.add_argument("--serving_version", type=str, default="v0",
                         help="model version this replica starts on "
                              "(serve/registry.py names; reported on "
@@ -156,14 +159,15 @@ def build_service(args):
     from bert_pytorch_tpu.telemetry.compile_events import CompileMonitor
     from bert_pytorch_tpu.utils import checkpoint as ckpt_util
 
-    if args.compile_cache_dir:
-        from bert_pytorch_tpu.utils.compile_cache import enable_compile_cache
+    from bert_pytorch_tpu.utils.compile_cache import enable_compile_cache
 
-        # min_compile_secs=0: persist EVERY per-(task, bucket) forward —
-        # the warm-restart acceptance is "second start performs zero cold
-        # compiles", and the training-oriented default bar would filter
-        # the seconds-scale serve executables out of the cache.
-        enable_compile_cache(args.compile_cache_dir, min_compile_secs=0.0)
+    # min_compile_secs=0: persist EVERY per-(task, bucket) forward —
+    # the warm-restart acceptance is "second start performs zero cold
+    # compiles", and the training-oriented default bar would filter
+    # the seconds-scale serve executables out of the cache.
+    cache_dir = enable_compile_cache(
+        args.compile_cache_dir, min_compile_secs=0.0)
+    logger.info(f"compile cache {cache_dir}")
 
     config = BertConfig.from_json_file(args.model_config_file)
     if config.vocab_size % 8 != 0:
@@ -174,6 +178,9 @@ def build_service(args):
     else:
         tokenizer = get_bpe_tokenizer(
             args.vocab_file, uppercase=args.uppercase)
+
+    logger.info(f"tokenizer back end {type(tokenizer).__module__}."
+                f"{type(tokenizer).__name__}")
 
     def resolve_ckpt(path):
         if not path:
@@ -331,6 +338,10 @@ def main(args) -> int:
         f"attention={service.engine.attention_backend})")
     service.engine.warmup()
     startup = service.engine.startup or {}
+    logger.info(
+        f"running on {startup.get('platform')} "
+        f"({startup.get('device_kind')} x {startup.get('device_count')}), "
+        f"Pallas kernels {startup.get('kernels')}")
     logger.info(
         f"warmup done in {startup.get('cold_start_s')}s: "
         f"{startup.get('compiles_cold')} cold compiles / "

@@ -69,7 +69,9 @@ def parse_args(argv=None):
     parser.add_argument("--max_answer_length", type=int, default=30)
     parser.add_argument("--seed", type=int, default=42)
     parser.add_argument("--compile_cache_dir", type=str, default="",
-                        help="persistent XLA compilation cache directory; empty disables")
+                        help="persistent XLA compilation cache directory; "
+                             "default <checkout>/.jax_cache, and "
+                             "JAX_COMPILATION_CACHE_DIR wins when set")
     parser.add_argument("--gradient_accumulation_steps", type=int, default=1)
     parser.add_argument("--do_lower_case", action="store_true")
     parser.add_argument("--version_2_with_negative", action="store_true")

@@ -1,0 +1,193 @@
+"""Compile for the chip without the chip.
+
+The TPU's compiler is installed here and compiles for a chip that is
+described and not attached (``jax.experimental.topologies``). These tests
+hand it every Pallas kernel of the main paths at BERT-large head geometry
+(16 heads of 64) and the whole phase-2 train step, and fail on what the
+chip's compiler would refuse: a misaligned tile, a kernel that wants more
+fast memory than it may use, a step that does not fit a 16 GB chip.
+Interpret-mode tests cannot see any of that.
+
+Nothing runs — a compile that passes is not a chip run (chip_smoke.py is).
+Skipped where the topology cannot be described. One file and one process on
+purpose: two such compiles in two processes at once fail on libtpu's lock
+file.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs under /tmp
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+import pytest  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+HEADS, DEPTH, HIDDEN = 16, 64, 1024
+# (seq, batch): the largest single-chip microbatches bench.py documents
+# for the phase-1 and phase-2 shapes.
+TRAIN_SHAPES = {128: 56, 512: 28}
+HBM_BYTES = 16 * 1024 ** 3  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One described v5e chip."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as exc:  # no libtpu / no such topology here
+        pytest.skip(f"cannot describe a TPU topology: {exc}")
+    return topo.devices[0]
+
+
+@pytest.fixture(autouse=True)
+def _compile_for_the_chip(monkeypatch):
+    """The kernels ask ``interpret_mode()`` — which sees the CPU backend
+    here — so the tests steer it themselves: compiled, as on the chip. And
+    conftest's fp32 matmul precision is for CPU numerics; the runners leave
+    the default, and Mosaic refuses an fp32-precision matmul of bf16 tiles.
+    (The persistent compile cache is off for the whole suite, conftest.py:
+    an executable compiled for a described chip could be written to it but
+    not read back.)"""
+    from bert_pytorch_tpu.ops.pallas import attention, common, layernorm
+
+    for module in (common, attention, layernorm):
+        monkeypatch.setattr(module, "interpret_mode", lambda: False)
+    with jax.default_matmul_precision("default"), \
+            jax.default_prng_impl("rbg"):  # the runners' --rng_impl default
+        yield
+
+
+def _compile(fn, chip, *shapes):
+    """Lower ``fn`` for the described chip at ``shapes`` ((shape, dtype)
+    pairs) and compile; returns the compiled executable."""
+    sharding = SingleDeviceSharding(chip)
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+            for shape, dtype in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _qkv(batch, seq):
+    return [((batch, seq, HEADS, DEPTH), jnp.bfloat16)] * 3
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# -- the training kernel: forward, forward+backward, dropout, packed -------
+
+# fwd at seq 512 is left out for the file's time budget (7 s): fwd_bwd-512
+# compiles the very same forward kernel through the custom_vjp.
+@pytest.mark.parametrize("variant,seq", [
+    (variant, seq) for variant in ("fwd", "fwd_bwd", "dropout", "packed")
+    for seq in sorted(TRAIN_SHAPES) if (variant, seq) != ("fwd", 512)])
+def test_flash_attention_compiles(chip, variant, seq):
+    from bert_pytorch_tpu.ops.pallas.attention import flash_attention
+
+    batch = TRAIN_SHAPES[seq]
+    bias = ((batch, 1, 1, seq), jnp.float32)
+    extra = {"packed": [((batch, seq), jnp.int32)],   # sequence ids
+             "dropout": [bias, ((4,), jnp.uint32)],   # + raw rbg key data
+             }.get(variant, [bias])
+
+    def loss(q, k, v, *extra):
+        if variant == "packed":
+            out = flash_attention(q, k, v, sequence_ids=extra[0])
+        elif variant == "dropout":
+            out = flash_attention(q, k, v, bias=extra[0], dropout_rate=0.1,
+                                  dropout_rng=extra[1])
+        else:
+            out = flash_attention(q, k, v, bias=extra[0])
+        return jnp.sum(out.astype(jnp.float32))
+
+    fn = loss if variant == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    _assert_kernel(_compile(fn, chip, *_qkv(batch, seq), *extra))
+
+
+# -- the serving kernels ----------------------------------------------------
+
+@pytest.mark.parametrize("seq", [32, 128, 512])
+@pytest.mark.parametrize("kernel", ["flash_attention_infer",
+                                    "flash_attention_infer_int8"])
+def test_infer_attention_compiles(chip, kernel, seq):
+    from bert_pytorch_tpu.ops.pallas import attention
+
+    batch = 8  # run_server.py's default --max_batch_size
+    fn = getattr(attention, kernel)
+    _assert_kernel(_compile(
+        lambda q, k, v, bias: fn(q, k, v, bias=bias), chip,
+        *_qkv(batch, seq), ((batch, 1, 1, seq), jnp.float32)))
+
+
+# -- layer norm ---------------------------------------------------------------
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_layer_norm_compiles(chip, direction):
+    from bert_pytorch_tpu.ops.pallas.layernorm import layer_norm_pallas
+
+    def loss(x, scale, bias):
+        return jnp.sum(layer_norm_pallas(x, scale, bias, 1e-12)
+                       .astype(jnp.float32))
+
+    fn = loss if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    _assert_kernel(_compile(
+        fn, chip, ((28, 512, HIDDEN), jnp.bfloat16),
+        ((HIDDEN,), jnp.float32), ((HIDDEN,), jnp.float32)))
+
+
+# -- the whole phase-2 train step ---------------------------------------------
+
+def test_phase2_train_step_fits_one_chip(chip):
+    """``pretrain.make_train_step`` as run_pretraining.py builds it for the
+    phase-2 recipe — BERT-large, seq 512, 80 predictions, local batch 28,
+    ``remat='dots'``, the fused kernel — compiled for one described chip:
+    the kernel is in the program, and arguments plus temporaries stay under
+    the chip's 16 GB. ``memory_analysis`` counts this one program, not what
+    else the process keeps on the device."""
+    from bert_pytorch_tpu import optim, pretrain
+    from bert_pytorch_tpu.config import BertConfig
+    from bert_pytorch_tpu.models import BertForPreTraining
+    from bert_pytorch_tpu.parallel import (MeshConfig, create_mesh,
+                                           logical_axis_rules)
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    config = BertConfig.from_json_file(
+        os.path.join(repo, "configs", "bert_large_uncased_config.json"))
+    config.vocab_size += -config.vocab_size % 8
+    seq, batch, max_pred = 512, TRAIN_SHAPES[512], 80
+    model = BertForPreTraining(config, dtype=jnp.bfloat16, remat="dots",
+                               attention_backend="pallas")
+    schedule = optim.warmup_poly_schedule(4e-3, 0.128, 1563)
+    tx = optim.lamb(schedule, weight_decay_mask=optim.no_decay_mask)
+    mesh = create_mesh(MeshConfig(data=-1), devices=[chip])
+    sample = (jnp.zeros((1, seq), jnp.int32),) * 3
+    batch_spec = {"input_ids": 3, "segment_ids": 3, "input_mask": 3,
+                  "masked_lm_labels": 3, "next_sentence_labels": 2}
+    with mesh:
+        shardings = pretrain.state_shardings(
+            mesh, model, logical_axis_rules("dp"), sample)
+        b_shardings = pretrain.batch_shardings(mesh, batch_spec)
+        state = jax.eval_shape(
+            pretrain.make_init_fn(model, tx, sample, shardings),
+            jax.random.PRNGKey(0))
+        step = pretrain.make_train_step(
+            model, tx, schedule=schedule, next_sentence=True,
+            shardings=shardings, batch_shardings_=b_shardings,
+            max_pred_per_seq=max_pred)
+        mb = {key: jax.ShapeDtypeStruct(
+            (1, batch) + (seq,) * (ndim - 2), np.int32)
+            for key, ndim in batch_spec.items()}
+        compiled = step.lower(state, mb).compile()
+    _assert_kernel(compiled)
+    mem = compiled.memory_analysis()
+    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert total < HBM_BYTES, (
+        f"phase-2 step needs {total / 2**30:.2f} GiB "
+        f"({mem.argument_size_in_bytes / 2**30:.2f} arguments + "
+        f"{mem.temp_size_in_bytes / 2**30:.2f} temporaries) of a "
+        f"{HBM_BYTES / 2**30:.0f} GiB chip")

@@ -549,7 +549,8 @@ print("STARTUP " + json.dumps(eng.startup))
 """
 
 
-def test_second_process_start_zero_cold_compiles(tmp_path, vocab_file):
+def test_second_process_start_zero_cold_compiles(
+        tmp_path, vocab_file, persistent_cache, monkeypatch):
     """THE cold-start acceptance (docs/serving.md): engine start in this
     process populates the persistent AOT cache; a SECOND, fresh process
     warms entirely from it — zero cold compiles, proven by the
@@ -562,32 +563,26 @@ def test_second_process_start_zero_cold_compiles(tmp_path, vocab_file):
     from bert_pytorch_tpu.tools.make_synthetic_data import TRACE_WORDS
     from bert_pytorch_tpu.utils.compile_cache import enable_compile_cache
 
+    # (the persistent_cache fixture restores the process-global jax config:
+    # later tests must not silently run against this tmp cache)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     cache_dir = str(tmp_path / "aot_cache")
-    assert enable_compile_cache(cache_dir, min_compile_secs=0.0)
-    try:
-        vocab = 5 + len(TRACE_WORDS)
-        vocab += (8 - vocab % 8) % 8
-        cfg = BC(vocab_size=vocab, hidden_size=32, num_hidden_layers=2,
-                 num_attention_heads=4, intermediate_size=64,
-                 max_position_embeddings=64, type_vocab_size=2,
-                 next_sentence=True, hidden_dropout_prob=0.0,
-                 attention_probs_dropout_prob=0.0)
-        tok = BertTokenizer(vocab_file, do_lower_case=True)
-        eng = InferenceEngine(
-            cfg, tok, {"classify": {"labels": ["a", "b"]}},
-            buckets=(BUCKET,), max_batch_size=2, dtype=jnp.float32,
-            seed=11, quantize="int8")
-        eng.warmup()
-        first = eng.startup
-        assert first["compiles_cold"] >= 1  # this process paid the compile
-    finally:
-        # Restore process-global jax config: later tests must not
-        # silently run against this tmp cache.
-        import jax
-        from jax._src import compilation_cache as _cc
-
-        jax.config.update("jax_compilation_cache_dir", None)
-        _cc.reset_cache()
+    assert enable_compile_cache(cache_dir, min_compile_secs=0.0) == cache_dir
+    vocab = 5 + len(TRACE_WORDS)
+    vocab += (8 - vocab % 8) % 8
+    cfg = BC(vocab_size=vocab, hidden_size=32, num_hidden_layers=2,
+             num_attention_heads=4, intermediate_size=64,
+             max_position_embeddings=64, type_vocab_size=2,
+             next_sentence=True, hidden_dropout_prob=0.0,
+             attention_probs_dropout_prob=0.0)
+    tok = BertTokenizer(vocab_file, do_lower_case=True)
+    eng = InferenceEngine(
+        cfg, tok, {"classify": {"labels": ["a", "b"]}},
+        buckets=(BUCKET,), max_batch_size=2, dtype=jnp.float32,
+        seed=11, quantize="int8")
+    eng.warmup()
+    first = eng.startup
+    assert first["compiles_cold"] >= 1  # this process paid the compile
 
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=REPO_ROOT + os.pathsep

@@ -299,7 +299,8 @@ def test_step_timer_mfu_from_device_time():
         record = timer.step_done(step=timer._step_index + 1)
     assert record["mfu"] == pytest.approx(8e12 / 275e12, rel=1e-3)
     assert record["mfu_basis"] == "device"  # every step synced
-    # CPU (unknown peak) reports 0.0, never a bogus number.
+    # CPU (no peak): MFU is absent — not measured — never 0.0 or a bogus
+    # number; an unknown TPU kind is an error, not an assumed peak.
     cpu_timer = StepTimer(window=1, clock=clock, seq_per_step=8,
                           flops_per_seq=1e12, device_kind="cpu")
     cpu_timer.data_start()
@@ -307,7 +308,8 @@ def test_step_timer_mfu_from_device_time():
     cpu_timer.dispatch_end()
     clock.advance(1.0)
     cpu_timer._t_device1 = clock()
-    assert cpu_timer.step_done(1)["mfu"] == 0.0
+    cpu_record = cpu_timer.step_done(1)
+    assert "mfu" not in cpu_record and cpu_record["mfu_basis"] == "none"
 
 
 def test_step_timer_flush_partial_window():
@@ -396,26 +398,8 @@ def test_parse_profile_spec():
 # -- compile events -----------------------------------------------------
 
 
-@pytest.fixture()
-def persistent_cache(tmp_path):
-    import jax
-    from jax._src import compilation_cache as cc
-
-    prev_dir = jax.config.jax_compilation_cache_dir
-    prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
-    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    # jax latches cache-enablement on the first compile of the process
-    # (_cache_used); any earlier test that compiled with no cache dir would
-    # leave the persistent cache permanently off without this reset.
-    cc.reset_cache()
-    try:
-        yield
-    finally:
-        cc.reset_cache()
-        jax.config.update("jax_compilation_cache_dir", prev_dir)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", prev_min)
+# ``persistent_cache`` is the conftest fixture: the cache on, in a
+# directory of the test's own, every compile persisted.
 
 
 def test_compile_event_on_forced_cache_miss(persistent_cache):
@@ -595,10 +579,11 @@ def test_pretraining_smoke_emits_telemetry(pretrain_workdir):
         for key in ("data_wait_p50_s", "data_wait_p95_s", "data_wait_max_s",
                     "host_p50_s", "host_p95_s", "host_max_s",
                     "device_p50_s", "device_p95_s", "device_max_s",
-                    "step_p50_s", "steps_per_sec", "mfu"):
+                    "step_p50_s", "steps_per_sec"):
             assert key in w, f"window record missing {key}"
         assert w["synced_steps"] == w["window_steps"]  # --telemetry_sync_every 1
-    assert windows[0]["mfu"] == 0.0  # CPU: unknown peak, never bogus
+        # CPU: no peak, so MFU is absent ("not measured"), not 0.0
+        assert "mfu" not in w and w["mfu_basis"] == "none"
     # The device-prefetch loader feeds its queue gauges into the windows.
     assert any("loader" in w for w in windows)
 

@@ -8,7 +8,15 @@ The serving resilience layer's acceptance gate, the serve analog of
 :class:`Supervisor` owning N ``run_server.py`` replica subprocesses
 (each warmed from one shared persistent AOT compile cache) behind a
 :class:`Router` front tier — then drives a closed-loop client burst
-through the router while injecting, in sequence:
+through the router while injecting the faults listed below.
+
+This is a CPU harness. The supervisor starts one PROCESS per replica,
+and a TPU chip belongs to one process at a time, so the replicas of one
+fleet cannot share a chip: until replicas can live in one process, each
+pinned to its own device, run this with ``JAX_PLATFORMS=cpu`` (the
+tests do). It proves control flow and counts, never a device number.
+
+The faults, in sequence:
 
 1. **SIGKILL inside the admission window** — replica 0 is armed with
    ``admit_hold@N`` (testing/faults.py): its pipelined assembler emits
@@ -440,7 +448,6 @@ def run_canary(args) -> int:
     canary SLO" report gate proven to fire on the breach artifact."""
     workdir = args.workdir or tempfile.mkdtemp(prefix="chaos_canary_")
     os.makedirs(workdir, exist_ok=True)
-    cache_dir = os.path.join(workdir, "compile_cache")
     vocab_path = synth.write_trace_vocab(os.path.join(workdir, "vocab.txt"))
     config_path = os.path.join(workdir, "model.json")
     with open(config_path, "w") as f:
@@ -450,7 +457,7 @@ def run_canary(args) -> int:
         "--model_config_file", config_path, "--vocab_file", vocab_path,
         "--tasks", "classify", "--classify_labels", "neg,pos",
         "--buckets", "16", "--max_batch_size", "4", "--max_wait_ms", "5",
-        "--dtype", "float32", "--compile_cache_dir", cache_dir,
+        "--dtype", "float32",
         "--trace_sample_rate", "0", "--telemetry_window", "16",
         "--request_timeout_s", "10", "--serving_version", "v1",
     ]
@@ -770,7 +777,6 @@ def run_surge(args) -> int:
     same sink the lint replays."""
     workdir = args.workdir or tempfile.mkdtemp(prefix="chaos_surge_")
     os.makedirs(workdir, exist_ok=True)
-    cache_dir = os.path.join(workdir, "compile_cache")
     vocab_path = synth.write_trace_vocab(os.path.join(workdir, "vocab.txt"))
     config_path = os.path.join(workdir, "model.json")
     with open(config_path, "w") as f:
@@ -780,7 +786,7 @@ def run_surge(args) -> int:
         "--model_config_file", config_path, "--vocab_file", vocab_path,
         "--tasks", "classify", "--classify_labels", "neg,pos",
         "--buckets", "16", "--max_batch_size", "2", "--max_wait_ms", "5",
-        "--dtype", "float32", "--compile_cache_dir", cache_dir,
+        "--dtype", "float32",
         "--trace_sample_rate", "0", "--telemetry_window", "16",
         "--request_timeout_s", "10", "--serving_version", "v1",
     ]
@@ -1163,13 +1169,14 @@ def main(argv=None) -> int:
 
     workdir = args.workdir or tempfile.mkdtemp(prefix="chaos_serve_")
     os.makedirs(workdir, exist_ok=True)
-    cache_dir = os.path.join(workdir, "compile_cache")
     vocab_path = synth.write_trace_vocab(os.path.join(workdir, "vocab.txt"))
     config_path = os.path.join(workdir, "model.json")
     with open(config_path, "w") as f:
         json.dump(model_config(), f)
 
-    # One ReplicaSpec per replica: shared model/cache flags, its own
+    # One ReplicaSpec per replica: shared model flags (and, with no
+    # --compile_cache_dir among them, the one compile cache that
+    # utils/compile_cache.py resolves for every replica alike), its own
     # port + output dir (telemetry JSONL and the heartbeat file the
     # supervisor watches live under it). The LAST replica is armed with
     # the wedge fault — it hangs only after serving --wedge_at requests,
@@ -1182,7 +1189,7 @@ def main(argv=None) -> int:
         "--model_config_file", config_path, "--vocab_file", vocab_path,
         "--tasks", "classify", "--classify_labels", "neg,pos",
         "--buckets", "16", "--max_batch_size", "4", "--max_wait_ms", "5",
-        "--dtype", "float32", "--compile_cache_dir", cache_dir,
+        "--dtype", "float32",
         "--trace_sample_rate", "0", "--telemetry_window", "16",
         "--request_timeout_s", "10", "--serving_version", "v1",
     ]

@@ -5,9 +5,9 @@ Invoked from the tier-1 suite (tests/test_telemetry.py) over EVERY
 committed ``*.jsonl`` artifact in the repo root — bench artifacts,
 telemetry captures, sweep logs — so a future round cannot commit
 malformed metrics (invalid JSON lines, NaN/Infinity spellings, records
-claiming a schema version whose required keys are missing). The capture
-harness (scripts/retry_capture_r04.sh) also runs it over any ``*.jsonl``
-it is about to auto-commit. Legacy artifacts written before the schema
+claiming a schema version whose required keys are missing).
+``chip_smoke.py`` runs it over the serve telemetry its on-chip server
+wrote. Legacy artifacts written before the schema
 existed carry no ``schema`` key and are held to the universal rules only
 (bert_pytorch_tpu/telemetry/schema.py). The ``serve`` record family
 (``serve_window``/``serve_summary``, serve/stats.py) is linted with its

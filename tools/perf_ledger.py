@@ -10,10 +10,10 @@ standalone surface over it; ``bench.py`` appends automatically and
 
 Usage::
 
-    python tools/perf_ledger.py show   PERF_LEDGER.jsonl [--leg serve]
-    python tools/perf_ledger.py append PERF_LEDGER.jsonl --leg train \
+    python tools/perf_ledger.py show   my_ledger.jsonl [--leg serve]
+    python tools/perf_ledger.py append my_ledger.jsonl --leg train \
         --metric step_ms_p50=41.2 --metric mfu=0.38 [--config seq_len=128]
-    python tools/perf_ledger.py check  PERF_LEDGER.jsonl \
+    python tools/perf_ledger.py check  my_ledger.jsonl \
         [--window 8] [--tol 0.25]
 
 ``check`` compares the NEWEST entry of every (leg, config) trajectory

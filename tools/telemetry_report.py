@@ -11,8 +11,8 @@ Usage::
 
     python tools/telemetry_report.py RUN.jsonl              # summary
     python tools/telemetry_report.py RUN.jsonl BASE.jsonl   # diff + verdict
-    python tools/telemetry_report.py RUN.jsonl --ledger PERF_LEDGER.jsonl
-    python tools/telemetry_report.py --ledger PERF_LEDGER.jsonl  # drift only
+    python tools/telemetry_report.py RUN.jsonl --ledger my_ledger.jsonl
+    python tools/telemetry_report.py --ledger my_ledger.jsonl  # drift only
 
 Exit 0 = no regression, 1 = regression (named in the output),
 2 = missing file. ``--format json`` prints one stable versioned object
