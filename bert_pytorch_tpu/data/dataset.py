@@ -41,6 +41,7 @@ from typing import Callable, Optional, Sequence
 import h5py
 import numpy as np
 
+from bert_pytorch_tpu.telemetry.profiler import span
 from bert_pytorch_tpu.utils.retry import RetryPolicy, retry_call
 
 
@@ -383,9 +384,11 @@ class ShardedPretrainingDataset:
 
     def _load_hdf5(self, filepath: str) -> None:
         try:
-            self._next_file_data = self._read_shard(
-                filepath,
-                lambda f: {key: np.asarray(f[key][:]) for key in f.keys()})
+            with span("data:shard_load"):
+                self._next_file_data = self._read_shard(
+                    filepath,
+                    lambda f: {key: np.asarray(f[key][:])
+                               for key in f.keys()})
         except BaseException as e:
             # Runs on the prefetch thread: park the error for the swap in
             # __getitem__ to re-raise (a daemon thread's traceback would

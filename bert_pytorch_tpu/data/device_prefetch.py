@@ -35,6 +35,7 @@ import time
 from typing import Callable, Iterable, Iterator
 
 from bert_pytorch_tpu.data.loader import _bounded_put
+from bert_pytorch_tpu.telemetry.profiler import span
 
 
 def add_cli_args(parser, default: int = 2) -> None:
@@ -108,7 +109,8 @@ class DevicePrefetcher:
         while not self._stop.is_set():
             t0 = self._clock()
             try:
-                item = next(self._source)
+                with span("prefetch:source_wait"):
+                    item = next(self._source)
             except StopIteration:
                 break
             except BaseException as e:  # surfaced at the consumer's next()
@@ -122,7 +124,8 @@ class DevicePrefetcher:
                 return
             t1 = self._clock()
             try:
-                staged = self._stage(item)
+                with span("prefetch:h2d"):
+                    staged = self._stage(item)
             except BaseException as e:
                 _bounded_put(self._queue, (e, 0.0, 0.0), self._stop)
                 return
@@ -167,7 +170,8 @@ class DevicePrefetcher:
         threaded path (no silent skip-and-resume past a failed item)."""
         t0 = self._clock()
         try:
-            item = next(self._source)
+            with span("prefetch:source_wait"):
+                item = next(self._source)
         except StopIteration:
             self._done = True
             raise
@@ -176,7 +180,8 @@ class DevicePrefetcher:
             raise
         t1 = self._clock()
         try:
-            staged = self._stage(item)
+            with span("prefetch:h2d"):
+                staged = self._stage(item)
         except BaseException:
             self._done = True
             raise

@@ -44,6 +44,8 @@ from typing import Iterator, Optional
 
 import numpy as np
 
+from bert_pytorch_tpu.telemetry.profiler import span
+
 BATCH_KEYS = (
     "input_ids",
     "segment_ids",
@@ -292,5 +294,7 @@ class DataLoader:
     @staticmethod
     def _collate(samples) -> dict:
         keys = BATCH_KEYS + PACKED_EXTRA_KEYS[:len(samples[0]) - len(BATCH_KEYS)]
-        arrays = [np.stack([s[i] for s in samples]) for i in range(len(keys))]
+        with span("data:collate"):
+            arrays = [np.stack([s[i] for s in samples])
+                      for i in range(len(keys))]
         return dict(zip(keys, arrays))

@@ -14,6 +14,7 @@ All cross-entropies are computed in fp32 regardless of logit dtype.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import optax
 
@@ -62,14 +63,16 @@ def pretraining_loss_sums(
     fp32 CE)."""
     vocab = prediction_logits.shape[-1]
     labels_flat = masked_lm_labels.reshape(-1)
-    mlm_sum, mlm_count = _xent_sums(
-        prediction_logits.reshape(-1, vocab), labels_flat, -1)
-    preds = jnp.argmax(prediction_logits, axis=-1).reshape(-1)
-    mlm_correct = jnp.sum((preds == labels_flat) & (labels_flat != -1))
+    with jax.named_scope("mlm_loss"):
+        mlm_sum, mlm_count = _xent_sums(
+            prediction_logits.reshape(-1, vocab), labels_flat, -1)
+        preds = jnp.argmax(prediction_logits, axis=-1).reshape(-1)
+        mlm_correct = jnp.sum((preds == labels_flat) & (labels_flat != -1))
     if seq_relationship_logits is not None and next_sentence_labels is not None:
-        nsp_sum, nsp_count = _xent_sums(
-            seq_relationship_logits.reshape(-1, 2),
-            next_sentence_labels.reshape(-1), -1)
+        with jax.named_scope("nsp_loss"):
+            nsp_sum, nsp_count = _xent_sums(
+                seq_relationship_logits.reshape(-1, 2),
+                next_sentence_labels.reshape(-1), -1)
     else:
         nsp_sum = jnp.zeros((), jnp.float32)
         nsp_count = jnp.zeros((), jnp.int32)
@@ -79,20 +82,22 @@ def pretraining_loss_sums(
 def masked_lm_loss(prediction_logits, masked_lm_labels, ignore_index: int = -1):
     """CE over [B, S, V] logits with ignore_index (run_pretraining.py:64-69)."""
     vocab = prediction_logits.shape[-1]
-    return _xent_ignore(
-        prediction_logits.reshape(-1, vocab),
-        masked_lm_labels.reshape(-1),
-        ignore_index,
-    )
+    with jax.named_scope("mlm_loss"):
+        return _xent_ignore(
+            prediction_logits.reshape(-1, vocab),
+            masked_lm_labels.reshape(-1),
+            ignore_index,
+        )
 
 
 def next_sentence_loss(seq_relationship_logits, next_sentence_labels):
     """CE over [B, 2] NSP logits (run_pretraining.py:70-71)."""
-    return _xent_ignore(
-        seq_relationship_logits.reshape(-1, 2),
-        next_sentence_labels.reshape(-1),
-        ignore_index=-1,
-    )
+    with jax.named_scope("nsp_loss"):
+        return _xent_ignore(
+            seq_relationship_logits.reshape(-1, 2),
+            next_sentence_labels.reshape(-1),
+            ignore_index=-1,
+        )
 
 
 def pretraining_loss(
@@ -135,7 +140,8 @@ def token_classification_loss(logits, labels, ignore_index: int = -100):
 
 def mlm_accuracy(prediction_logits, masked_lm_labels, ignore_index: int = -1):
     """Fraction of masked positions predicted correctly (for eval logging)."""
-    preds = jnp.argmax(prediction_logits, axis=-1)
-    valid = masked_lm_labels != ignore_index
-    correct = jnp.logical_and(preds == masked_lm_labels, valid)
-    return jnp.sum(correct) / jnp.maximum(jnp.sum(valid), 1)
+    with jax.named_scope("mlm_loss"):
+        preds = jnp.argmax(prediction_logits, axis=-1)
+        valid = masked_lm_labels != ignore_index
+        correct = jnp.logical_and(preds == masked_lm_labels, valid)
+        return jnp.sum(correct) / jnp.maximum(jnp.sum(valid), 1)

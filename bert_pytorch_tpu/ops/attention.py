@@ -68,7 +68,18 @@ def dot_product_attention(
     Pallas path ignores that bias and regenerates the block-diagonal tile
     mask inside the kernel from the per-token id vectors, preserving its
     no-[B,H,S,S]-in-HBM property.
+
+    Everything here runs under ``jax.named_scope("attention_core")`` (the
+    XLA path's dropout under ``attention_dropout`` inside it), so a profiler
+    trace tells the core from the projections round it on every backend.
     """
+    with jax.named_scope("attention_core"):
+        return _attention_core(q, k, v, bias, dropout_rng, dropout_rate,
+                               deterministic, backend, sequence_ids)
+
+
+def _attention_core(q, k, v, bias, dropout_rng, dropout_rate, deterministic,
+                    backend, sequence_ids):
     if backend == "auto":
         # Measured crossover (module docstring): the fused kernel wins from
         # seq ~256 up; below that the XLA path is faster. On the CPU backend
@@ -128,7 +139,7 @@ def dot_product_attention(
         warnings.warn(
             "backend='pallas' with dropout on the CPU backend: the Pallas "
             "interpreter has no PRNG, attention uses the XLA path",
-            RuntimeWarning, stacklevel=2)
+            RuntimeWarning, stacklevel=3)
     if backend in ("ring", "ring_manual") and sequence_ids is not None:
         # Ring attention shards the sequence axis across chips; the
         # block-diagonal mask would need per-shard id exchange alongside
@@ -195,6 +206,8 @@ def dot_product_attention(
     probs = jax.nn.softmax(scores, axis=-1)
     probs = probs.astype(q.dtype)
     if not deterministic and dropout_rate > 0.0:
-        keep = jax.random.bernoulli(dropout_rng, 1.0 - dropout_rate, probs.shape)
-        probs = probs * keep.astype(probs.dtype) / (1.0 - dropout_rate)
+        with jax.named_scope("attention_dropout"):
+            keep = jax.random.bernoulli(
+                dropout_rng, 1.0 - dropout_rate, probs.shape)
+            probs = probs * keep.astype(probs.dtype) / (1.0 - dropout_rate)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)

@@ -393,6 +393,7 @@ def _flash_forward(q3, k3, v3, bias3, seg3, seed, scale, rate, segmented):
             jax.ShapeDtypeStruct((bh, seq, depth), q3.dtype),
             jax.ShapeDtypeStruct((bh, 1, seq), jnp.float32),
         ],
+        name="flash_fwd",
         interpret=interpret_mode(),
     )(seed, q3, k3, v3, bias3, seg3)
     return out, lse
@@ -438,6 +439,7 @@ def _flash_bwd(scale, rate, segmented, residuals, g):
         ],
         out_specs=pl.BlockSpec((gb, block_q, depth), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, seq, depth), q3.dtype),
+        name="flash_bwd_dq",
         interpret=interpret_mode(),
     )(seed, q3, k3, v3, bias3, seg3, lse, delta, g)
 
@@ -467,6 +469,7 @@ def _flash_bwd(scale, rate, segmented, residuals, g):
             jax.ShapeDtypeStruct((bh, seq, depth), v3.dtype),
             jax.ShapeDtypeStruct((bh, 1, seq), jnp.float32),
         ],
+        name="flash_bwd_dkv",
         interpret=interpret_mode(),
     )(seed, q3, k3, v3, bias3, seg3, lse, delta, g)
 
@@ -616,6 +619,7 @@ def flash_attention_infer(q, k, v, bias=None, sequence_ids=None,
         ],
         out_specs=pl.BlockSpec((g, block_q, depth), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, seq, depth), q3.dtype),
+        name="flash_infer_fwd",
         interpret=interpret_mode(),
     )(q3, k3, v3, bias3, seg3)
     return out3.reshape(batch, heads, seq, depth).transpose(0, 2, 1, 3)
@@ -721,6 +725,7 @@ def flash_attention_infer_int8(q, k, v, bias=None, sequence_ids=None,
         ],
         out_specs=pl.BlockSpec((g, block_q, depth), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, seq, depth), q3.dtype),
+        name="flash_infer_fwd_int8",
         interpret=interpret_mode(),
     )(q8, k8, v3, q_scale, k_scale, bias3, seg3)
     return out3.reshape(batch, heads, seq, depth).transpose(0, 2, 1, 3)

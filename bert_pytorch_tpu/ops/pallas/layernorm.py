@@ -57,6 +57,7 @@ def _ln_forward(x2d, scale, bias, eps):
             jax.ShapeDtypeStruct((rows, 1), jnp.float32),
             jax.ShapeDtypeStruct((rows, 1), jnp.float32),
         ],
+        name="layernorm_fwd",
         interpret=interpret_mode(),
     )(x2d, scale, bias)
     return out, mean, rstd

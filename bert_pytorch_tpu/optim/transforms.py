@@ -102,39 +102,41 @@ def lamb(
         if params is None:
             raise ValueError("lamb requires params")
         if max_grad_norm is not None and max_grad_norm > 0:
-            gnorm = global_norm(grads)
-            gscale = jnp.minimum(1.0, max_grad_norm / (gnorm + 1e-6))
-            grads = jax.tree_util.tree_map(lambda g: g * gscale, grads)
+            with jax.named_scope("clip"):
+                gnorm = global_norm(grads)
+                gscale = jnp.minimum(1.0, max_grad_norm / (gnorm + 1e-6))
+                grads = jax.tree_util.tree_map(lambda g: g * gscale, grads)
 
-        mu, nu = _update_moments(grads, state, b1, b2)
-        count = state.count + 1
-        if bias_correction:
-            c1 = 1.0 - b1 ** count.astype(jnp.float32)
-            c2 = 1.0 - b2 ** count.astype(jnp.float32)
-        else:
-            c1 = c2 = 1.0
+        with jax.named_scope("lamb"):
+            mu, nu = _update_moments(grads, state, b1, b2)
+            count = state.count + 1
+            if bias_correction:
+                c1 = 1.0 - b1 ** count.astype(jnp.float32)
+                c2 = 1.0 - b2 ** count.astype(jnp.float32)
+            else:
+                c1 = c2 = 1.0
 
-        decay_mask = _mask_tree(params, weight_decay_mask)
-        lr = _lr_at(learning_rate, state.count)
+            decay_mask = _mask_tree(params, weight_decay_mask)
+            lr = _lr_at(learning_rate, state.count)
 
-        def param_update(m, v, p, use_decay):
-            m_hat = m / c1
-            v_hat = v / c2
-            upd = m_hat / (jnp.sqrt(v_hat) + eps)
-            if weight_decay > 0:
-                upd = upd + weight_decay * jnp.where(use_decay, 1.0, 0.0) * p.astype(
-                    jnp.float32
+            def param_update(m, v, p, use_decay):
+                m_hat = m / c1
+                v_hat = v / c2
+                upd = m_hat / (jnp.sqrt(v_hat) + eps)
+                if weight_decay > 0:
+                    upd = upd + weight_decay * jnp.where(use_decay, 1.0, 0.0) * p.astype(
+                        jnp.float32
+                    )
+                p_norm = jnp.sqrt(jnp.sum(jnp.square(p.astype(jnp.float32))))
+                u_norm = jnp.sqrt(jnp.sum(jnp.square(upd)))
+                ratio = jnp.where(
+                    (p_norm > 0) & (u_norm > 0), p_norm / u_norm, 1.0
                 )
-            p_norm = jnp.sqrt(jnp.sum(jnp.square(p.astype(jnp.float32))))
-            u_norm = jnp.sqrt(jnp.sum(jnp.square(upd)))
-            ratio = jnp.where(
-                (p_norm > 0) & (u_norm > 0), p_norm / u_norm, 1.0
-            )
-            if trust_clip is not None:
-                ratio = jnp.minimum(ratio, trust_clip)
-            return (-lr * ratio * upd).astype(p.dtype)
+                if trust_clip is not None:
+                    ratio = jnp.minimum(ratio, trust_clip)
+                return (-lr * ratio * upd).astype(p.dtype)
 
-        updates = jax.tree_util.tree_map(param_update, mu, nu, params, decay_mask)
+            updates = jax.tree_util.tree_map(param_update, mu, nu, params, decay_mask)
         return updates, OptState(count, mu, nu)
 
     return optax.GradientTransformation(init, update)

@@ -34,6 +34,15 @@ from bert_pytorch_tpu.parallel.mesh import (AXIS_DATA, AXIS_FSDP, AXIS_PIPE,
                                             AXIS_SEQ)
 from bert_pytorch_tpu.parallel.sharding import params_shardings
 
+# Every ``jax.named_scope`` the train steps write where Flax gives no module
+# name (here, models/losses.py, ops/attention.py, optim/transforms.py). They
+# reach the HLO's ``op_name`` beside the module names and the pass markers
+# JAX adds itself (``jvp(``, ``transpose(``, ``rematted_computation``), so a
+# profiler trace can be read by part and by pass (docs/telemetry.md).
+SCOPES = ("micro_batches", "grad_accumulate", "optimizer", "clip", "lamb",
+          "step_metrics", "mlm_loss", "nsp_loss", "attention_core",
+          "attention_dropout")
+
 
 @flax.struct.dataclass
 class TrainState:
@@ -194,6 +203,11 @@ def make_kfac_fns(
     return apply_loss, tap_shape_fn
 
 
+def _scan_micro_batches(body, init, batch):
+    with jax.named_scope("micro_batches"):
+        return jax.lax.scan(body, init, batch)
+
+
 def _jit_train_step(step_fn, shardings, batch_shardings_, kfac,
                     kfac_shardings, fused_kfac=False):
     """Shared jit dispatch for the train-step builders: donated state,
@@ -287,14 +301,15 @@ def _make_overlap_step_fn(model, tx, mesh, schedule, next_sentence,
 
             (_, aux), grads = jax.value_and_grad(
                 local_loss, has_aux=True)(params)
-            grads_acc = jax.tree_util.tree_map(
-                lambda a, g: a + g.astype(a.dtype), grads_acc, grads)
+            with jax.named_scope("grad_accumulate"):
+                grads_acc = jax.tree_util.tree_map(
+                    lambda a, g: a + g.astype(a.dtype), grads_acc, grads)
             mlm_sum, nsp_sum, correct = aux
             return (grads_acc, rng), (mlm_sum, nsp_sum, correct,
                                       c_mlm, c_nsp)
 
         (grads_acc, _), (mlm_sums, nsp_sums, corrects, c_mlms, c_nsps) = (
-            jax.lax.scan(body, (zero_grads, rng0), batch))
+            _scan_micro_batches(body, (zero_grads, rng0), batch))
         # Metric sums are scalars-per-microbatch: one cheap psum for all.
         g_mlm, g_nsp, g_correct = jax.lax.psum(
             (mlm_sums, nsp_sums, corrects.astype(jnp.float32)), axes)
@@ -316,29 +331,31 @@ def _make_overlap_step_fn(model, tx, mesh, schedule, next_sentence,
             local_grads, mesh=mesh, axis_names={AXIS_DATA, AXIS_FSDP},
             in_specs=(P(), batch_specs, P()),
             out_specs=(P(), P(), P()))(state.params, batch, step_rng)
-        grads = jax.tree_util.tree_map(lambda g: g / accum_steps, grads)
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
-        gnorm = global_norm(grads)
-        metrics = {
-            "loss": jnp.mean(losses),
-            "mlm_accuracy": jnp.mean(accs),
-            "grad_norm": gnorm,
-            # Same sentinel/padding contracts as make_train_step.
-            "finite": (jnp.isfinite(jnp.sum(losses))
-                       & jnp.isfinite(gnorm)).astype(jnp.float32),
-            "real_tokens": jnp.sum(batch["input_mask"]).astype(jnp.float32),
-        }
-        if schedule is not None:
-            metrics["learning_rate"] = schedule(
-                opt_step_count(state.opt_state))
-        if stats_every:
-            from bert_pytorch_tpu.telemetry import model_stats
+        with jax.named_scope("optimizer"):
+            grads = jax.tree_util.tree_map(lambda g: g / accum_steps, grads)
+            updates, opt_state = tx.update(grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("step_metrics"):
+            gnorm = global_norm(grads)
+            metrics = {
+                "loss": jnp.mean(losses),
+                "mlm_accuracy": jnp.mean(accs),
+                "grad_norm": gnorm,
+                # Same sentinel/padding contracts as make_train_step.
+                "finite": (jnp.isfinite(jnp.sum(losses))
+                           & jnp.isfinite(gnorm)).astype(jnp.float32),
+                "real_tokens": jnp.sum(batch["input_mask"]).astype(jnp.float32),
+            }
+            if schedule is not None:
+                metrics["learning_rate"] = schedule(
+                    opt_step_count(state.opt_state))
+            if stats_every:
+                from bert_pytorch_tpu.telemetry import model_stats
 
-            metrics["grad_health"] = model_stats.gated_grad_health(
-                state.params, grads, updates,
-                opt_step_count(state.opt_state), stats_every,
-                phase=stats_phase)
+                metrics["grad_health"] = model_stats.gated_grad_health(
+                    state.params, grads, updates,
+                    opt_step_count(state.opt_state), stats_every,
+                    phase=stats_phase)
         return TrainState(
             params=params, opt_state=opt_state, rng=new_rng), metrics
 
@@ -497,9 +514,10 @@ def make_train_step(
             else:
                 (loss, acc), grads = jax.value_and_grad(
                     loss_fn, has_aux=True)(state.params, mb, sub)
-            grads_acc = jax.tree_util.tree_map(
-                lambda a, g: a + g.astype(a.dtype), grads_acc, grads
-            )
+            with jax.named_scope("grad_accumulate"):
+                grads_acc = jax.tree_util.tree_map(
+                    lambda a, g: a + g.astype(a.dtype), grads_acc, grads
+                )
             return (grads_acc, rng), (loss, acc)
 
         zero_grads = jax.tree_util.tree_map(
@@ -521,17 +539,18 @@ def make_train_step(
                 (loss, (acc, astats)), (grads, gtaps) = jax.value_and_grad(
                     tapped_loss_fn, argnums=(0, 1), has_aux=True
                 )(state.params, kfac.zero_taps(), mb, sub)
-                grads_acc = jax.tree_util.tree_map(
-                    lambda a, g: a + g.astype(a.dtype), grads_acc, grads)
-                gtap_acc = jax.tree_util.tree_map(
-                    jnp.add, gtap_acc, gtaps)
-                astat_acc = jax.tree_util.tree_map(
-                    jnp.add, astat_acc, astats)
+                with jax.named_scope("grad_accumulate"):
+                    grads_acc = jax.tree_util.tree_map(
+                        lambda a, g: a + g.astype(a.dtype), grads_acc, grads)
+                    gtap_acc = jax.tree_util.tree_map(
+                        jnp.add, gtap_acc, gtaps)
+                    astat_acc = jax.tree_util.tree_map(
+                        jnp.add, astat_acc, astats)
                 return (grads_acc, gtap_acc, astat_acc, rng), (loss, acc)
 
             def all_capture(ks):
                 (grads, gtap_sum, astat_sum, _), (losses, accs) = (
-                    jax.lax.scan(
+                    _scan_micro_batches(
                         tapped_body,
                         (zero_grads, kfac.zero_taps(), kfac.zero_astats(),
                          step_rng),
@@ -540,7 +559,7 @@ def make_train_step(
                 return losses, accs, grads, ks
 
             def all_plain(ks):
-                (grads, _), (losses, accs) = jax.lax.scan(
+                (grads, _), (losses, accs) = _scan_micro_batches(
                     body, (zero_grads, step_rng), batch)
                 return losses, accs, grads, ks
 
@@ -584,7 +603,7 @@ def make_train_step(
                 lambda g: g.astype(jnp.float32), grads0)
             if accum_steps > 1:
                 rest = jax.tree_util.tree_map(lambda v: v[1:], batch)
-                (grads, _), (losses_r, accs_r) = jax.lax.scan(
+                (grads, _), (losses_r, accs_r) = _scan_micro_batches(
                     body, (grads0, rng_rest), rest
                 )
                 losses = jnp.concatenate([loss0[None], losses_r])
@@ -594,7 +613,7 @@ def make_train_step(
                 losses = loss0[None]
                 accs = acc0[None]
         else:
-            (grads, _), (losses, accs) = jax.lax.scan(
+            (grads, _), (losses, accs) = _scan_micro_batches(
                 body, (zero_grads, step_rng), batch
             )
         if fused_kfac and kfac_inv_interval:
@@ -605,53 +624,55 @@ def make_train_step(
                        % kfac_inv_interval) == 0
             kfac_state = jax.lax.cond(
                 inv_due, kfac.inverse_factors, lambda s: s, kfac_state)
-        grads = jax.tree_util.tree_map(lambda g: g / accum_steps, grads)
+        with jax.named_scope("optimizer"):
+            grads = jax.tree_util.tree_map(lambda g: g / accum_steps, grads)
 
-        if kfac is not None:
-            grads = kfac.precondition(
-                kfac_state, grads, schedule(opt_step_count(state.opt_state))
-            )
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
-        # grads carry the loss scale in fp16 mode; report the true norm
-        gnorm = (global_norm(grads) / scale if loss_scale
-                 else global_norm(grads))
-        metrics = {
-            "loss": jnp.mean(losses),
-            "mlm_accuracy": jnp.mean(accs),
-            "grad_norm": gnorm,
-            # Failure sentinel (telemetry/sentinels.py): one scalar the host
-            # can fetch for free alongside the loss. isfinite(sum) catches a
-            # non-finite loss in ANY microbatch, not just the mean.
-            "finite": (jnp.isfinite(jnp.sum(losses))
-                       & jnp.isfinite(gnorm)).astype(jnp.float32),
-            # Padding-aware throughput accounting (docs/telemetry.md): the
-            # non-pad token count this step actually trained on. Telemetry
-            # pops it on the sync cadence (never an extra device fetch) and
-            # reports padding_efficiency / real-token throughput; with
-            # sequence packing this approaches the full batch token budget.
-            "real_tokens": jnp.sum(batch["input_mask"]).astype(jnp.float32),
-        }
-        if loss_scale:
-            metrics["loss_scale"] = scale
-        if schedule is not None:
-            metrics["learning_rate"] = schedule(opt_step_count(state.opt_state))
-        if stats_every:
-            from bert_pytorch_tpu.telemetry import model_stats
+            if kfac is not None:
+                grads = kfac.precondition(
+                    kfac_state, grads, schedule(opt_step_count(state.opt_state))
+                )
+            updates, opt_state = tx.update(grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("step_metrics"):
+            # grads carry the loss scale in fp16 mode; report the true norm
+            gnorm = (global_norm(grads) / scale if loss_scale
+                     else global_norm(grads))
+            metrics = {
+                "loss": jnp.mean(losses),
+                "mlm_accuracy": jnp.mean(accs),
+                "grad_norm": gnorm,
+                # Failure sentinel (telemetry/sentinels.py): one scalar the host
+                # can fetch for free alongside the loss. isfinite(sum) catches a
+                # non-finite loss in ANY microbatch, not just the mean.
+                "finite": (jnp.isfinite(jnp.sum(losses))
+                           & jnp.isfinite(gnorm)).astype(jnp.float32),
+                # Padding-aware throughput accounting (docs/telemetry.md): the
+                # non-pad token count this step actually trained on. Telemetry
+                # pops it on the sync cadence (never an extra device fetch) and
+                # reports padding_efficiency / real-token throughput; with
+                # sequence packing this approaches the full batch token budget.
+                "real_tokens": jnp.sum(batch["input_mask"]).astype(jnp.float32),
+            }
+            if loss_scale:
+                metrics["loss_scale"] = scale
+            if schedule is not None:
+                metrics["learning_rate"] = schedule(opt_step_count(state.opt_state))
+            if stats_every:
+                from bert_pytorch_tpu.telemetry import model_stats
 
-            # fp16: skipped overflow steps do NOT advance the inner
-            # optimizer count (optim/transforms.py dynamic_loss_scale),
-            # so a count-based gate would drift off the host's
-            # step-index sync cadence after the first skip and the
-            # records would silently stop. Compute every step instead —
-            # the O(params) reduction is noise next to the step's
-            # O(params x tokens) — and let the sync cadence sample.
-            metrics["grad_health"] = model_stats.gated_grad_health(
-                state.params, grads, updates,
-                opt_step_count(state.opt_state),
-                1 if loss_scale else stats_every,
-                grad_scale=scale if loss_scale else None,
-                phase=stats_phase)
+                # fp16: skipped overflow steps do NOT advance the inner
+                # optimizer count (optim/transforms.py dynamic_loss_scale),
+                # so a count-based gate would drift off the host's
+                # step-index sync cadence after the first skip and the
+                # records would silently stop. Compute every step instead —
+                # the O(params) reduction is noise next to the step's
+                # O(params x tokens) — and let the sync cadence sample.
+                metrics["grad_health"] = model_stats.gated_grad_health(
+                    state.params, grads, updates,
+                    opt_step_count(state.opt_state),
+                    1 if loss_scale else stats_every,
+                    grad_scale=scale if loss_scale else None,
+                    phase=stats_phase)
         new_state = TrainState(params=params, opt_state=opt_state, rng=new_rng)
         if fused_kfac:
             return new_state, metrics, kfac_state
@@ -881,37 +902,39 @@ def make_pp_train_step(
         (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             state.params, batch, step_rng
         )
-        if kfac is not None:
-            grads = kfac.precondition(
-                kfac_state, grads, schedule(opt_step_count(state.opt_state))
-            )
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
-        gnorm = global_norm(grads)
-        metrics = {
-            "loss": loss,
-            "mlm_accuracy": acc,
-            "grad_norm": gnorm,
-            # Failure sentinel (telemetry/sentinels.py), same contract as
-            # make_train_step: a NaN in any microbatch propagates into the
-            # mean loss, so isfinite(loss) covers them all.
-            "finite": (jnp.isfinite(loss)
-                       & jnp.isfinite(gnorm)).astype(jnp.float32),
-            # Padding-aware accounting, same contract as make_train_step.
-            "real_tokens": jnp.sum(batch["input_mask"]).astype(jnp.float32),
-        }
-        if schedule is not None:
-            metrics["learning_rate"] = schedule(opt_step_count(state.opt_state))
-        if stats_every:
-            # Same grad-health block as make_train_step; the norms are
-            # pure per-leaf reductions, so XLA reshards them over the
-            # pipe-sharded gradient layout for free.
-            from bert_pytorch_tpu.telemetry import model_stats
+        with jax.named_scope("optimizer"):
+            if kfac is not None:
+                grads = kfac.precondition(
+                    kfac_state, grads, schedule(opt_step_count(state.opt_state))
+                )
+            updates, opt_state = tx.update(grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("step_metrics"):
+            gnorm = global_norm(grads)
+            metrics = {
+                "loss": loss,
+                "mlm_accuracy": acc,
+                "grad_norm": gnorm,
+                # Failure sentinel (telemetry/sentinels.py), same contract as
+                # make_train_step: a NaN in any microbatch propagates into the
+                # mean loss, so isfinite(loss) covers them all.
+                "finite": (jnp.isfinite(loss)
+                           & jnp.isfinite(gnorm)).astype(jnp.float32),
+                # Padding-aware accounting, same contract as make_train_step.
+                "real_tokens": jnp.sum(batch["input_mask"]).astype(jnp.float32),
+            }
+            if schedule is not None:
+                metrics["learning_rate"] = schedule(opt_step_count(state.opt_state))
+            if stats_every:
+                # Same grad-health block as make_train_step; the norms are
+                # pure per-leaf reductions, so XLA reshards them over the
+                # pipe-sharded gradient layout for free.
+                from bert_pytorch_tpu.telemetry import model_stats
 
-            metrics["grad_health"] = model_stats.gated_grad_health(
-                state.params, grads, updates,
-                opt_step_count(state.opt_state), stats_every,
-                phase=stats_phase)
+                metrics["grad_health"] = model_stats.gated_grad_health(
+                    state.params, grads, updates,
+                    opt_step_count(state.opt_state), stats_every,
+                    phase=stats_phase)
         return TrainState(params=params, opt_state=opt_state, rng=new_rng), metrics
 
     return _jit_train_step(

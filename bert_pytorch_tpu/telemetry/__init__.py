@@ -28,8 +28,8 @@ from bert_pytorch_tpu.telemetry.model_stats import (DivergenceError,
                                                     finetune_grad_health,
                                                     gated_grad_health,
                                                     grad_health)
-from bert_pytorch_tpu.telemetry.profiler import (ProfilerWindow,
-                                                 parse_profile_spec)
+from bert_pytorch_tpu.telemetry.profiler import (SPANS, ProfilerWindow,
+                                                 parse_profile_spec, span)
 from bert_pytorch_tpu.telemetry.runner import TrainTelemetry
 from bert_pytorch_tpu.telemetry.schema import (SCHEMA_VERSION,
                                                validate_file,
@@ -63,11 +63,13 @@ __all__ = [
     "NonFiniteError",
     "ProfilerWindow",
     "SCHEMA_VERSION",
+    "SPANS",
     "StepTimer",
     "stats_every",
     "TrainTelemetry",
     "parse_profile_spec",
     "shapes_digest",
+    "span",
     "validate_file",
     "validate_record",
 ]

@@ -9,9 +9,13 @@ auto-stops: when the range's last step completes — or the run ends inside
 the window — the trace is synced (``block_until_ready`` on the step's
 outputs, so the trace holds the full device work) and written.
 
-While a trace is active each step is wrapped in
-``jax.profiler.StepTraceAnnotation``, which makes XLA's trace viewer group
-events per training step.
+Every step is wrapped in ``jax.profiler.StepTraceAnnotation("train",
+step_num=...)`` and the loop's phases in spans (:func:`span`, ``SPANS``), whoever
+opened the trace session (``--profile_steps``, ``POST /profilez``, a
+benchmark, ``jax.profiler.start_trace`` by hand): they land in the same
+``.xplane.pb`` as the device's ops, on the same clock, and cost a flag test
+while no session is open. The session is the only store: there is no other
+recorder.
 
 The startup window used to be this module's ONLY contract — one window
 per process lifetime, latched by ``done``. :meth:`ProfilerWindow.begin`
@@ -32,9 +36,30 @@ xprof. See docs/telemetry.md for the workflow.
 
 from __future__ import annotations
 
-import contextlib
 import threading
 from typing import Optional, Tuple
+
+import jax
+
+# Every span the program writes, on the thread that writes it. The
+# ``train:*`` spans nest in the step's ``train`` annotation on the loop's
+# thread (telemetry/runner.py, telemetry/step_timer.py, run_pretraining.py);
+# ``prefetch:*`` are per batch on the device-prefetch thread
+# (data/device_prefetch.py; on the loop's thread under --device_prefetch 0);
+# ``data:*`` are per batch / per shard on the loader's threads
+# (data/loader.py, data/dataset.py). docs/telemetry.md names what each
+# covers; benchmarks/trace/scopes.py reads them.
+SPANS = ("train:feed", "train:dispatch", "train:sync", "train:fetch_metrics",
+         "train:log", "train:telemetry", "train:checkpoint", "train:eval",
+         "prefetch:source_wait", "prefetch:h2d",
+         "data:shard_load", "data:collate")
+
+
+def span(name: str, **stats):
+    """One host span in the profiler's trace: a ``TraceAnnotation`` (keyword
+    arguments become the event's stats). Nothing is recorded, and nothing
+    but a flag is read, while no trace session is open."""
+    return jax.profiler.TraceAnnotation(name, **stats)
 
 # Process-wide trace exclusivity (concurrency registry): jax.profiler
 # allows one active trace per process; flipped by whichever thread's
@@ -95,11 +120,10 @@ class ProfilerWindow:
     """
 
     def __init__(self, spec, trace_dir: Optional[str],
-                 enabled: bool = True, annotate: bool = True):
+                 enabled: bool = True):
         self.range = parse_profile_spec(spec) if enabled else None
         self.trace_dir = trace_dir
         self.enabled = bool(enabled)
-        self.annotate = annotate
         self.active = False
         self.done = False
         # True only while the SPEC-driven startup window is tracing:
@@ -119,8 +143,6 @@ class ProfilerWindow:
         if not _acquire_trace():
             return False
         try:
-            import jax
-
             jax.profiler.start_trace(trace_dir or self.trace_dir)
         except Exception:
             # A refused/failed start must release the latch or no trace
@@ -136,8 +158,6 @@ class ProfilerWindow:
         one-shot marker belongs to ``stop``)."""
         if not self.active:
             return False
-        import jax
-
         if sync_target is not None:
             # The trace must hold the device work of every step in the
             # window, not just their dispatches.
@@ -163,13 +183,10 @@ class ProfilerWindow:
         return True
 
     def annotation(self, step_in_run: int):
-        """Context manager wrapping one step's dispatch."""
-        if self.active and self.annotate:
-            import jax
-
-            return jax.profiler.StepTraceAnnotation(
-                "train", step_num=step_in_run)
-        return contextlib.nullcontext()
+        """Context manager wrapping one step of the loop: written whoever
+        opened the trace session, so any capture groups its events by
+        step and every ``train:*`` span has its step number."""
+        return jax.profiler.StepTraceAnnotation("train", step_num=step_in_run)
 
     def maybe_stop(self, step_in_run: int, sync_target=None) -> bool:
         """Stop when the STARTUP window's last step completed

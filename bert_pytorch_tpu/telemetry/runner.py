@@ -32,19 +32,20 @@ Minimal loop integration::
 
     tele = TrainTelemetry(jsonl_path=..., heartbeat_path=..., ...)
     train_step = tele.instrument(train_step, "train_step")
-    for batch in tele.timed(iter(loader)):        # measures data_wait
-        tele.profiler.maybe_start(step)
-        with tele.profiler.annotation(step):
+    for batch in tele.timed(iter(loader), first_step=step + 1):
+        # ^ measures data_wait; opens the profiler's step annotation
+        with telemetry.span("train:dispatch"):
             state, metrics = train_step(state, batch)
         tele.dispatch_done()                      # measures host dispatch
         tele.step_done(step, metrics)             # sync + window + sentinel
-                                                  # + heartbeat + auto-stop
+                                                  # + heartbeat
     tele.finish(step)                             # flush partial window
 """
 
 from __future__ import annotations
 
 import contextlib
+import itertools
 import math
 import time
 from typing import Callable, Iterator, Optional
@@ -53,7 +54,7 @@ from bert_pytorch_tpu.telemetry.compile_events import CompileMonitor
 from bert_pytorch_tpu.telemetry.memory import MemorySampler
 from bert_pytorch_tpu.telemetry.model_stats import (DivergenceMonitor,
                                                     health_record)
-from bert_pytorch_tpu.telemetry.profiler import ProfilerWindow
+from bert_pytorch_tpu.telemetry.profiler import ProfilerWindow, span
 from bert_pytorch_tpu.telemetry.sampler import CaptureController
 from bert_pytorch_tpu.telemetry.sentinels import (FailureSentinel, Heartbeat,
                                                   HeartbeatWatchdog)
@@ -205,46 +206,57 @@ class TrainTelemetry:
         stall noted after the last full window)."""
         t0 = self._clock()
         try:
-            yield
+            with span("train:checkpoint"):
+                yield
         finally:
             self.timer.note_ckpt_stall(self._clock() - t0)
 
     # -- per-step protocol ----------------------------------------------
 
-    def timed(self, iterator: Iterator) -> Iterator:
+    def timed(self, iterator: Iterator, first_step: int = 1) -> Iterator:
         """Wrap the batch iterator so host time blocked on the input
-        pipeline is measured as data_wait."""
-        while True:
-            self.timer.data_start()
-            try:
-                item = next(iterator)
-            except StopIteration:
-                return
-            self.timer.data_end()
-            if self._prefetcher is not None:
-                # The batch just delivered came through the device
-                # prefetcher; record how much of the wait was H2D staging
-                # (0.0 when the batch was already resident).
-                self.timer.note_h2d(self._prefetcher.pop_h2d_wait_s())
-            yield item
+        pipeline is measured as data_wait (and traced as ``train:feed``).
+
+        Each turn of the consumer's loop — the wait for its batch and the
+        whole loop body — runs inside the profiler's step annotation, and
+        the startup trace window opens before the step's feed: the
+        annotation is entered here and left when the loop comes back for
+        the next batch or ends. ``first_step`` is the number, in
+        ``--profile_steps`` terms, of the step the first batch feeds."""
+        for step in itertools.count(first_step):
+            self.profiler.maybe_start(step)
+            with self.profiler.annotation(step):
+                self.timer.data_start()
+                try:
+                    with span("train:feed"):
+                        item = next(iterator)
+                except StopIteration:
+                    return
+                self.timer.data_end()
+                if self._prefetcher is not None:
+                    # The batch just delivered came through the device
+                    # prefetcher; record how much of the wait was H2D
+                    # staging (0.0 when the batch was already resident).
+                    self.timer.note_h2d(self._prefetcher.pop_h2d_wait_s())
+                yield item
+            # Startup trace window's auto-stop, once its last step's
+            # annotation has closed (so the trace holds that step whole).
+            self.profiler.maybe_stop(step, sync_target=self._last_sync_target)
 
     def dispatch_done(self) -> None:
         self.timer.dispatch_end()
 
     def step_done(self, step: int, metrics: Optional[dict] = None,
-                  sync_target=None, force_sync: bool = False,
-                  profile_step: Optional[int] = None) -> Optional[dict]:
-        """Close out one step: device sync (per the cadence), sentinel
-        check, heartbeat, profiler auto-stop, window emission.
+                  sync_target=None,
+                  force_sync: bool = False) -> Optional[dict]:
+        """Close out one step: device sync (per the cadence, traced as
+        ``train:sync``), then — traced as ``train:telemetry`` — sentinel
+        check, heartbeat, memory sample, capture tick, window emission.
 
         ``metrics`` is the step's device metrics dict (used as the sync
         target and the source of the ``finite``/``loss`` scalars);
-        ``sync_target`` overrides it. ``profile_step`` is the step number in
-        the SAME base the runner feeds ``profiler.maybe_start`` — pass it
-        when that base differs from ``step`` (run_pretraining profiles in
-        step-in-run terms while ``step`` is the checkpoint-resumed global
-        step; without it a resumed run would close the trace window
-        immediately). Returns the window record when one was emitted.
+        ``sync_target`` overrides it. Returns the window record when one
+        was emitted.
         """
         # The in-jit grad-health block rides in metrics but is telemetry's,
         # not the runner's: pop it unconditionally so runner-side
@@ -264,6 +276,13 @@ class TrainTelemetry:
             self.timer.device_sync(target)
             synced = True
         self.last_step_synced = synced
+        with span("train:telemetry"):
+            return self._close_step(step, metrics, target, synced, health,
+                                    real_tokens)
+
+    def _close_step(self, step, metrics, target, synced, health,
+                    real_tokens) -> Optional[dict]:
+        """Everything of :meth:`step_done` after the device sync."""
         if synced:
             if real_tokens is not None:
                 self.timer.note_tokens(float(real_tokens))
@@ -299,9 +318,6 @@ class TrainTelemetry:
             self.introspect.note_step(step, loss=hub_loss)
         if self.watchdog is not None:
             self.watchdog.start().note(step)
-        self.profiler.maybe_stop(
-            step if profile_step is None else profile_step,
-            sync_target=target)
         # On-demand capture boundary: starts an armed capture, collects
         # an expired one (the finished profile_window record rides the
         # normal emit tee into hub/recorder/sink).

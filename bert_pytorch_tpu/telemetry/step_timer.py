@@ -60,6 +60,7 @@ from __future__ import annotations
 import time
 from typing import Callable, Optional
 
+from bert_pytorch_tpu.telemetry.profiler import span
 from bert_pytorch_tpu.utils import flops as flops_util
 
 
@@ -185,7 +186,8 @@ class StepTimer:
         (the caller may also force a sync, e.g. on log steps)."""
         import jax
 
-        jax.block_until_ready(sync_target)
+        with span("train:sync"):
+            jax.block_until_ready(sync_target)
         self._t_device1 = self._clock()
         return True
 

@@ -80,6 +80,19 @@ def enable_compile_cache(cache_dir: str = "",
         "jax_persistent_cache_min_compile_time_secs",
         float(MIN_COMPILE_TIME_SECS if min_compile_secs is None
               else min_compile_secs))
+    # An executable keeps the op names it was compiled with (module path,
+    # named scopes, source lines) and a profiler trace shows them. JAX leaves
+    # them out of the cache key by default, so a hit could hand this process
+    # the names of whichever checkout compiled the same arithmetic first.
+    # With them in the key a restart of the same checkout still hits; another
+    # checkout, or an edited file on the traced path, compiles its own (a
+    # program with a Pallas kernel always did). Locations are cut to the line
+    # that wrote the op: with the callers' frames in them the key would turn
+    # on the line a jitted function is first called from, and the second
+    # lowering of the step for its cost record (telemetry/memory.py) would
+    # be a second compile.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
     # jax latches cache-enablement at the first compile of the process: if
     # anything compiled before this call — an eager op that triggered jit —
     # the directory would be silently ignored for the rest of the process.

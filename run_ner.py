@@ -255,10 +255,10 @@ def main(args):
                 batches(datasets["train"], args.batch_size, True, rng),
                 stage=jax.device_put, depth=args.device_prefetch)
             tele.attach_prefetcher(prefetcher)
-            for batch in tele.timed(iter(prefetcher)):
+            for batch in tele.timed(
+                    iter(prefetcher), first_step=global_step + 1):
                 key, sub = jax.random.split(key)
-                tele.profiler.maybe_start(global_step + 1)
-                with tele.profiler.annotation(global_step + 1):
+                with telemetry.span("train:dispatch"):
                     params, opt_state, metrics = train_step(
                         params, opt_state, batch, sub, epoch)
                 tele.dispatch_done()

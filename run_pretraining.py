@@ -1038,11 +1038,12 @@ def main(args) -> dict:
                     loader, args.accumulation_steps, b_shardings,
                     depth=args.device_prefetch)
                 tele.attach_prefetcher(prefetcher)
-                for batch in tele.timed(iter(prefetcher)):
-                    # Profiler window (steps are step_in_run indices; this
-                    # iteration runs step step_in_run + 1).
-                    tele.profiler.maybe_start(step_in_run + 1)
-                    with tele.profiler.annotation(step_in_run + 1):
+                # The profiler's step annotation and trace window count
+                # step_in_run indices; the first batch of this epoch feeds
+                # step step_in_run + 1. Every span below nests in it.
+                for batch in tele.timed(iter(prefetcher),
+                                        first_step=step_in_run + 1):
+                    with telemetry.span("train:dispatch"):
                         state, metrics, kfac_state = dispatch_step(
                             state, batch, kfac_state, global_step)
                     tele.dispatch_done()
@@ -1080,13 +1081,14 @@ def main(args) -> dict:
                             global_step, metrics, emit=tele.emit)
                     # Telemetry step close-out: device sync (per cadence) +
                     # step-window emission + sentinel policy + heartbeat +
-                    # watchdog note + profiler auto-stop. NonFiniteError
-                    # propagates under --sentinel_policy abort.
-                    tele.step_done(global_step, metrics,
-                                   profile_step=step_in_run)
+                    # watchdog note. NonFiniteError propagates under
+                    # --sentinel_policy abort.
+                    tele.step_done(global_step, metrics)
 
                     if global_step % args.log_steps == 0:
-                        last_metrics = {k: float(v) for k, v in metrics.items()}
+                        with telemetry.span("train:fetch_metrics"):
+                            last_metrics = {
+                                k: float(v) for k, v in metrics.items()}
                         if not tele.last_step_synced:
                             # The float() fetches above were this step's
                             # sync; feed the sentinel/heartbeat that missed
@@ -1103,18 +1105,23 @@ def main(args) -> dict:
                             tele.heartbeat.beat(
                                 global_step, last_metrics["loss"])
                         elapsed = time.perf_counter() - train_start
-                        logger.log(
-                            tag="train", step=global_step, epoch=epoch,
-                            average_loss=last_metrics["loss"],
-                            step_loss=last_metrics["loss"],
-                            learning_rate=last_metrics.get("learning_rate", 0.0),
-                            samples_per_second=samples_seen / max(elapsed, 1e-9),
-                            mlm_accuracy=last_metrics.get("mlm_accuracy", 0.0),
-                            grad_norm=last_metrics.get("grad_norm", 0.0))
+                        with telemetry.span("train:log"):
+                            logger.log(
+                                tag="train", step=global_step, epoch=epoch,
+                                average_loss=last_metrics["loss"],
+                                step_loss=last_metrics["loss"],
+                                learning_rate=last_metrics.get(
+                                    "learning_rate", 0.0),
+                                samples_per_second=samples_seen / max(
+                                    elapsed, 1e-9),
+                                mlm_accuracy=last_metrics.get(
+                                    "mlm_accuracy", 0.0),
+                                grad_norm=last_metrics.get("grad_norm", 0.0))
 
                     if (eval_step is not None
                             and global_step % args.num_steps_per_eval == 0):
-                        run_validation(state.params, global_step, epoch)
+                        with telemetry.span("train:eval"):
+                            run_validation(state.params, global_step, epoch)
 
                     if global_step % args.num_steps_per_checkpoint == 0:
                         save_step = global_step + args.previous_phase_end_step

@@ -387,10 +387,10 @@ def main(args):
             try:
                 while global_step < total_steps and not stop.requested:
                     prefetcher = epoch_prefetcher()
-                    for batch in tele.timed(iter(prefetcher)):
+                    for batch in tele.timed(
+                            iter(prefetcher), first_step=global_step + 1):
                         rng, sub = jax.random.split(rng)
-                        tele.profiler.maybe_start(global_step + 1)
-                        with tele.profiler.annotation(global_step + 1):
+                        with telemetry.span("train:dispatch"):
                             params, opt_state, metrics = train_step(
                                 params, opt_state, batch, sub)
                         tele.dispatch_done()

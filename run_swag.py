@@ -225,10 +225,10 @@ def main(args):
                 stage=lambda bv: (jax.device_put(bv[0]), bv[1]),
                 depth=args.device_prefetch)
             tele.attach_prefetcher(prefetcher)
-            for batch, valid in tele.timed(iter(prefetcher)):
+            for batch, valid in tele.timed(
+                    iter(prefetcher), first_step=global_step + 1):
                 key, sub = jax.random.split(key)
-                tele.profiler.maybe_start(global_step + 1)
-                with tele.profiler.annotation(global_step + 1):
+                with telemetry.span("train:dispatch"):
                     params, opt_state, metrics = train_step(
                         params, opt_state, batch, valid, sub)
                 tele.dispatch_done()

@@ -15,6 +15,7 @@ file.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs under /tmp
 
@@ -75,8 +76,16 @@ def _qkv(batch, seq):
     return [((batch, seq, HEADS, DEPTH), jnp.bfloat16)] * 3
 
 
-def _assert_kernel(compiled):
-    assert "tpu_custom_call" in compiled.as_text()
+def _assert_kernel(compiled, *names):
+    """The program holds a Mosaic kernel, and each ``name=`` its
+    ``pl.pallas_call`` gave reached the custom call's ``op_name`` (what a
+    profiler trace of the chip shows in place of a number XLA chose)."""
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    for name in names:
+        assert re.search(
+            r'custom_call_target="tpu_custom_call"[^\n]*'
+            rf'op_name="[^"]*[/(]{name}[/)]', text), name  # jvp(name) too
 
 
 # -- the training kernel: forward, forward+backward, dropout, packed -------
@@ -106,7 +115,9 @@ def test_flash_attention_compiles(chip, variant, seq):
         return jnp.sum(out.astype(jnp.float32))
 
     fn = loss if variant == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
-    _assert_kernel(_compile(fn, chip, *_qkv(batch, seq), *extra))
+    names = ("flash_fwd",) if variant == "fwd" else (
+        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+    _assert_kernel(_compile(fn, chip, *_qkv(batch, seq), *extra), *names)
 
 
 # -- the serving kernels ----------------------------------------------------
@@ -121,7 +132,9 @@ def test_infer_attention_compiles(chip, kernel, seq):
     fn = getattr(attention, kernel)
     _assert_kernel(_compile(
         lambda q, k, v, bias: fn(q, k, v, bias=bias), chip,
-        *_qkv(batch, seq), ((batch, 1, 1, seq), jnp.float32)))
+        *_qkv(batch, seq), ((batch, 1, 1, seq), jnp.float32)),
+        {"flash_attention_infer": "flash_infer_fwd",
+         "flash_attention_infer_int8": "flash_infer_fwd_int8"}[kernel])
 
 
 # -- layer norm ---------------------------------------------------------------
@@ -137,7 +150,8 @@ def test_layer_norm_compiles(chip, direction):
     fn = loss if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
     _assert_kernel(_compile(
         fn, chip, ((28, 512, HIDDEN), jnp.bfloat16),
-        ((HIDDEN,), jnp.float32), ((HIDDEN,), jnp.float32)))
+        ((HIDDEN,), jnp.float32), ((HIDDEN,), jnp.float32)),
+        "layernorm_fwd")
 
 
 # -- the whole phase-2 train step ---------------------------------------------
@@ -183,7 +197,7 @@ def test_phase2_train_step_fits_one_chip(chip):
             (1, batch) + (seq,) * (ndim - 2), np.int32)
             for key, ndim in batch_spec.items()}
         compiled = step.lower(state, mb).compile()
-    _assert_kernel(compiled)
+    _assert_kernel(compiled, "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
     mem = compiled.memory_analysis()
     total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert total < HBM_BYTES, (
