@@ -32,6 +32,7 @@ from bert_pytorch_tpu.config import BertConfig
 from bert_pytorch_tpu import ops
 from bert_pytorch_tpu.ops import quant as quant_ops
 from bert_pytorch_tpu.ops.activations import ACT2FN
+from bert_pytorch_tpu.ops.remat import remat_policy
 
 Array = jnp.ndarray
 Dtype = Any
@@ -422,15 +423,9 @@ class BertEncoder(nn.Module):
     def __call__(self, hidden: Array, bias: Array, deterministic: bool = True,
                  sequence_ids: Optional[Array] = None):
         cfg = self.config
-        if self.remat not in ("none", "dots", "full"):
-            raise ValueError(f"remat must be none|dots|full, got {self.remat!r}")
         layer_cls = BertLayer
-        if self.remat != "none":
-            policy = (
-                jax.checkpoint_policies.nothing_saveable
-                if self.remat == "full"
-                else jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims
-            )
+        policy = remat_policy(self.remat)
+        if policy is not None:
             layer_cls = nn.remat(
                 BertLayer,
                 policy=policy,

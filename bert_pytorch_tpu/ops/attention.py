@@ -6,11 +6,21 @@ MXU, the softmax runs in fp32 for bf16 safety, and the additive mask uses the
 reference's ``(1 - mask) * -10000`` bias convention (modeling.py:862-870).
 
 ``backend='pallas'`` routes to the fused flash-style kernel with in-kernel
-dropout (ops/pallas/attention.py). Measured on one v5e chip, BERT-large
-training with dropout: at seq 512 the fused kernel wins by ~60% (84 vs ~52
-seq/s — the XLA path materializes the [B,H,S,S] probabilities/masks); at
-seq 128 the XLA path still wins (396 vs 366). Rule of thumb: 'xla' for
-phase-1 (seq<=128), 'pallas' for phase-2 (seq>=256) and anything longer.
+dropout (ops/pallas/attention.py); the XLA path below materializes the
+[B, H, S, S] probabilities and their dropout mask. Rule of thumb, which
+``backend='auto'`` follows (``resolve_backend``): 'xla' for phase 1
+(seq <= 128), 'pallas' for phase 2 (seq >= 256) and anything longer. What
+each path costs on the chip is in PERF.md (sections 5 and 6), from the
+benchmark's cells.
+
+Across remat: with dropout on, the XLA path names its boolean keep mask
+(``ops/remat.py`` ``KEEP_MASK``) and ``remat='dots'`` keeps it, B x H x S x S
+bytes a layer and micro-batch (16.7 MB at the phase-1 shape 64 x 16 x 128 x
+128, 0.40 GB over 24 layers), so the backward pass does not draw the random
+words a second time. The mask grows with S squared: at seq 512 and
+micro-batch 16 it is 67 MB a layer, 1.6 GB over 24 — a shape 'auto' never
+sends down this path on a TPU; a caller who forces 'xla' there and needs the
+memory back has ``remat='full'``, which keeps nothing.
 """
 
 from __future__ import annotations
@@ -19,6 +29,9 @@ import warnings
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from bert_pytorch_tpu.ops.remat import KEEP_MASK
 
 
 def make_attention_bias(
@@ -78,19 +91,36 @@ def dot_product_attention(
                                deterministic, backend, sequence_ids)
 
 
+def resolve_backend(backend: str, seq: int, dropout: bool) -> str:
+    """The path a call with ``backend`` takes at sequence length ``seq``.
+
+    'auto' goes by the measured crossover: the fused kernel wins from seq
+    ~256 up; below that the XLA path is faster. On the CPU backend (tests)
+    the kernel would run in the Pallas interpreter, so auto never picks it
+    there, and 'pallas' with ``dropout`` on computes attention on the XLA
+    path (the hardware PRNG has no interpreter lowering; never so on a
+    TPU). The runners' start-up log says which of the two a process is
+    (ops/pallas/common.py device_report).
+    """
+    from bert_pytorch_tpu.ops.pallas.common import interpret_mode
+
+    if backend == "auto":
+        backend = "pallas" if seq >= 256 and not interpret_mode() else "xla"
+    if backend == "pallas" and dropout and interpret_mode():
+        return "xla"
+    return backend
+
+
 def _attention_core(q, k, v, bias, dropout_rng, dropout_rate, deterministic,
                     backend, sequence_ids):
-    if backend == "auto":
-        # Measured crossover (module docstring): the fused kernel wins from
-        # seq ~256 up; below that the XLA path is faster. On the CPU backend
-        # (tests) the kernel would run in the Pallas interpreter, so auto
-        # never picks it there; the runners' start-up log says which of the
-        # two a process is (ops/pallas/common.py device_report).
-        from bert_pytorch_tpu.ops.pallas.common import interpret_mode
-
-        backend = (
-            "pallas" if q.shape[1] >= 256 and not interpret_mode() else "xla"
-        )
+    active = not deterministic and dropout_rate > 0.0
+    resolved = resolve_backend(backend, q.shape[1], active)
+    if backend == "pallas" and resolved == "xla":
+        warnings.warn(
+            "backend='pallas' with dropout on the CPU backend: the Pallas "
+            "interpreter has no PRNG, attention uses the XLA path",
+            RuntimeWarning, stacklevel=3)
+    backend = resolved
     if backend in ("pallas_infer", "pallas_infer_int8"):
         # INFERENCE-ONLY fused forwards (ops/pallas/attention.py
         # flash_attention_infer / flash_attention_infer_int8): no dropout
@@ -117,29 +147,19 @@ def _attention_core(q, k, v, bias, dropout_rng, dropout_rate, deterministic,
         # Fused kernel incl. in-kernel dropout from the TPU hardware PRNG
         # (the [B,H,S,S] mask never reaches HBM; see ops/pallas/attention.py).
         from bert_pytorch_tpu.ops.pallas.attention import flash_attention
-        from bert_pytorch_tpu.ops.pallas.common import interpret_mode
 
         # Packed batches: the caller's bias is the [B, 1, S, S] block
         # diagonal, which the kernel must NOT consume — it rebuilds the
         # tile mask from the id vectors (pad keys carry id 0, so no
         # separate key bias is needed).
         kbias = None if sequence_ids is not None else bias
-        active = not deterministic and dropout_rate > 0.0
         if not active:
             return flash_attention(q, k, v, bias=kbias,
                                    sequence_ids=sequence_ids)
-        if not interpret_mode():
-            return flash_attention(
-                q, k, v, bias=kbias,
-                dropout_rate=dropout_rate, dropout_rng=dropout_rng,
-                sequence_ids=sequence_ids)
-        # CPU backend only (interpret_mode): the hardware PRNG has no
-        # interpreter lowering, so a training step with dropout computes
-        # attention on the XLA path below. Never reached on a TPU.
-        warnings.warn(
-            "backend='pallas' with dropout on the CPU backend: the Pallas "
-            "interpreter has no PRNG, attention uses the XLA path",
-            RuntimeWarning, stacklevel=3)
+        return flash_attention(
+            q, k, v, bias=kbias,
+            dropout_rate=dropout_rate, dropout_rng=dropout_rng,
+            sequence_ids=sequence_ids)
     if backend in ("ring", "ring_manual") and sequence_ids is not None:
         # Ring attention shards the sequence axis across chips; the
         # block-diagonal mask would need per-shard id exchange alongside
@@ -165,7 +185,6 @@ def _attention_core(q, k, v, bias, dropout_rng, dropout_rate, deterministic,
             kbias = jnp.zeros((batch, s_local), jnp.float32)
         else:
             kbias = bias.reshape(batch, s_local).astype(jnp.float32)
-        active = not deterministic and dropout_rate > 0.0
         return _ring_shard(
             q, k, v, kbias,
             dropout_rng if active else None,
@@ -205,9 +224,14 @@ def _attention_core(q, k, v, bias, dropout_rng, dropout_rate, deterministic,
         scores = scores + bias.astype(jnp.float32)
     probs = jax.nn.softmax(scores, axis=-1)
     probs = probs.astype(q.dtype)
-    if not deterministic and dropout_rate > 0.0:
+    if active:
         with jax.named_scope("attention_dropout"):
-            keep = jax.random.bernoulli(
-                dropout_rng, 1.0 - dropout_rate, probs.shape)
+            # Named as the boolean, before the cast: remat='dots' keeps it
+            # (ops/remat.py) at one byte an element, and the backward pass
+            # does not draw the random words a second time.
+            keep = checkpoint_name(
+                jax.random.bernoulli(
+                    dropout_rng, 1.0 - dropout_rate, probs.shape),
+                KEEP_MASK)
             probs = probs * keep.astype(probs.dtype) / (1.0 - dropout_rate)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)

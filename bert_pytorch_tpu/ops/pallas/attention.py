@@ -42,13 +42,19 @@ lowering, so ``dropout_rate > 0`` requires a real TPU; rate 0 runs everywhere
 (tests compare it against the XLA path, and the dropout statistics are
 validated on-chip).
 
-Measured (one v5e chip, BERT-large training step, remat='dots', rbg host
-dropout for the non-attention dropouts): seq 512 batch 28 — XLA attention
-~52 seq/s with dropout; this kernel 84.3 with dropout (512-wide tiles +
-8 bh pairs per program; the original 256x256 single-bh tiles measured
-70.7). Seq 128 still favors the XLA path (366 vs 396 seq/s at the phase-1
-bench shape) — bh-batching closes most but not all of the short-seq grid
-overhead. See ops/attention.py for routing.
+What the kernels cost on the chip (seq 512: forward, dq, dk/dv per update,
+and the wrapper round them) is in PERF.md (sections 5 and 6), from the
+benchmark's cells; seq 128 still favors the XLA path. See ops/attention.py
+for routing.
+
+Across remat: the forward rule names its two residuals, the output ([B*H, S,
+D] in the activation dtype) and the log-sum-exp ([B*H, 1, S] fp32)
+(``ops/remat.py`` ``FLASH_OUT``, ``FLASH_LSE``), and ``remat='dots'`` keeps
+them: B x S x hidden x 2 + B x H x S x 4 bytes a layer and micro-batch in
+bf16 (16.8 + 0.5 MB at the phase-2 shape 16 x 512, 0.41 GB over 24 layers),
+so the backward pass does not run the forward kernel a second time only to
+reproduce them. q/k/v are still rebuilt from the kept projections.
+``remat='full'`` keeps nothing.
 """
 
 from __future__ import annotations
@@ -58,11 +64,13 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from bert_pytorch_tpu.ops.pallas import autotune
 from bert_pytorch_tpu.ops.pallas.common import interpret_mode, pick_block
+from bert_pytorch_tpu.ops.remat import FLASH_LSE, FLASH_OUT
 
 _NEG_INF = -1e30
 
@@ -409,6 +417,11 @@ def _flash(q3, k3, v3, bias3, seg3, seed, scale, rate, segmented):
 def _flash_fwd(q3, k3, v3, bias3, seg3, seed, scale, rate, segmented):
     out, lse = _flash_forward(q3, k3, v3, bias3, seg3, seed, scale, rate,
                               segmented)
+    # Named here, in the forward RULE: remat='dots' keeps both (ops/remat.py),
+    # which leaves the recomputed pallas_call without a live output, so the
+    # backward pass does not run the forward kernel a second time.
+    out = checkpoint_name(out, FLASH_OUT)
+    lse = checkpoint_name(lse, FLASH_LSE)
     return out, (q3, k3, v3, bias3, seg3, seed, out, lse)
 
 

@@ -1,0 +1,64 @@
+"""What an encoder layer keeps across rematerialization.
+
+``remat='dots'`` keeps the outputs of dots without batch dimensions (the
+projections and the FFN) and recomputes all else in the backward pass. That
+rule goes by the KIND of primitive, and attention holds the values for which
+it is most wrong: cheap to keep by the byte, the dearest in the layer to make
+again. So the ops name them where they are made
+(``jax.ad_checkpoint.checkpoint_name``) and the policy keeps them by name:
+
+  - ``KEEP_MASK`` (ops/attention.py, XLA path with dropout on): the boolean
+    keep mask of the attention probabilities, [B, H, S, S] at one byte an
+    element. Recomputed, every layer draws its random words a second time.
+  - ``FLASH_OUT``, ``FLASH_LSE`` (ops/pallas/attention.py): the flash
+    kernel's output ([B*H, S, D], activation dtype) and log-sum-exp
+    ([B*H, 1, S], fp32), the residuals its backward kernels read.
+    Recomputed, the forward kernel runs a second time to reproduce them.
+
+The names do nothing under ``remat='none'`` (no policy), under
+``remat='full'`` (nothing is kept: the user asked for least memory) and where
+no gradient is taken. ``remat_policy`` is the ONE place that builds the
+policy: the scanned encoder (models/bert.py) and the pipeline's stages
+(pretrain.py) both call it.
+"""
+
+from __future__ import annotations
+
+import jax
+import numpy as np
+
+KEEP_MASK = "attention_dropout_keep"
+FLASH_OUT = "flash_out"
+FLASH_LSE = "flash_lse"
+KEPT_NAMES = (KEEP_MASK, FLASH_OUT, FLASH_LSE)
+
+def remat_policy(remat: str):
+    """The ``jax.checkpoint`` policy for a ``remat`` value; None for 'none'."""
+    if remat == "none":
+        return None
+    if remat == "full":
+        return jax.checkpoint_policies.nothing_saveable
+    if remat == "dots":
+        return jax.checkpoint_policies.save_from_both_policies(
+            jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
+            jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES))
+    raise ValueError(f"remat must be none|dots|full, got {remat!r}")
+
+
+def kept_residual_bytes(remat: str, path: str, dropout: bool, batch: int,
+                        seq: int, heads: int, head_dim: int, dtype) -> dict:
+    """Bytes one layer keeps by name for one micro-batch of ``batch`` rows,
+    from shapes: {name: bytes}, empty where the policy keeps none. ``path``
+    is what attention runs (ops/attention.py ``resolve_backend``): the
+    'pallas' kernel, or the 'xla' path, which has a mask to keep only with
+    ``dropout`` on; the ring paths draw their own masks and name nothing."""
+    if remat != "dots":
+        return {}
+    if path == "pallas":
+        return {
+            FLASH_OUT: batch * heads * seq * head_dim * np.dtype(dtype).itemsize,
+            FLASH_LSE: batch * heads * seq * 4,
+        }
+    if path == "xla" and dropout:
+        return {KEEP_MASK: batch * heads * seq * seq}
+    return {}

@@ -28,6 +28,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from bert_pytorch_tpu.models.losses import mlm_accuracy, pretraining_loss
 from bert_pytorch_tpu.ops.grad_utils import global_norm
+from bert_pytorch_tpu.ops.remat import remat_policy
 from bert_pytorch_tpu.optim.transforms import (LossScaleState, OptState,
                                                opt_step_count)
 from bert_pytorch_tpu.parallel.mesh import (AXIS_DATA, AXIS_FSDP, AXIS_PIPE,
@@ -763,11 +764,7 @@ def make_pp_train_step(
         else None
     )
 
-    remat_policy = None
-    if model.remat == "dots":
-        remat_policy = jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims
-    elif model.remat == "full":
-        remat_policy = jax.checkpoint_policies.nothing_saveable
+    policy = remat_policy(model.remat)
 
     def loss_fn(params, batch, rng):
         n_mb, b, seq = batch["input_ids"].shape
@@ -812,9 +809,9 @@ def make_pp_train_step(
             )
             return out
 
-        if remat_policy is not None:
+        if policy is not None:
             apply_one = jax.checkpoint(
-                apply_one, policy=remat_policy, prevent_cse=False
+                apply_one, policy=policy, prevent_cse=False
             )
 
         def stage_fn(local_params, h, bias_mb, rng_rep, stage, mb):

@@ -33,7 +33,9 @@ from bert_pytorch_tpu import optim, pretrain, telemetry
 from bert_pytorch_tpu.config import BertConfig, parse_args_with_config_file, require_args
 from bert_pytorch_tpu.data import DataLoader, DistributedSampler, ShardedPretrainingDataset
 from bert_pytorch_tpu.models import BertForPreTraining
+from bert_pytorch_tpu.ops.attention import resolve_backend
 from bert_pytorch_tpu.ops.pallas.common import device_report
+from bert_pytorch_tpu.ops.remat import kept_residual_bytes
 from bert_pytorch_tpu.parallel import (MeshSpec, MeshSpecError, create_mesh,
                                        logical_axis_rules)
 from bert_pytorch_tpu.parallel import launcher
@@ -226,9 +228,13 @@ def parse_arguments(argv=None) -> argparse.Namespace:
     parser.add_argument("--remat", type=str, default=None,
                         choices=["none", "dots", "full"],
                         help="activation rematerialization policy; 'dots' "
-                             "(keep matmul outputs, recompute elementwise) "
-                             "unlocks ~2x larger microbatches and is the "
-                             "fastest configuration on 16GB v5e chips")
+                             "(keep matmul outputs and attention's named "
+                             "residuals, ops/remat.py: the dropout mask of "
+                             "the XLA path at B*H*S*S bytes a layer, the "
+                             "flash kernel's output and log-sum-exp; "
+                             "recompute all else) unlocks ~2x larger "
+                             "microbatches and is the fastest configuration "
+                             "on 16GB v5e chips; 'full' keeps nothing")
     parser.add_argument("--attention_backend", type=str, default="auto",
                         choices=["auto", "xla", "pallas", "ring"],
                         help="'auto' picks the measured winner by sequence "
@@ -497,6 +503,25 @@ def setup_training(args):
         raise ValueError("global_batch_size must divide by process count")
     args.host_batch_per_step = args.global_batch_size // jax.process_count()
     return args, mesh
+
+
+def _kept_across_remat(model, config, micro_batch, seq) -> str:
+    """The start-up line that says what the layers keep across remat by
+    name (ops/remat.py) and what it costs, from shapes: per layer and
+    micro-batch on one data shard."""
+    dropout = config.attention_probs_dropout_prob > 0.0
+    path = resolve_backend(model.attention_backend, seq, dropout)
+    kept = kept_residual_bytes(
+        model.remat, path, dropout, batch=micro_batch, seq=seq,
+        heads=config.num_attention_heads, head_dim=config.head_dim,
+        dtype=model.dtype)
+    line = f"remat {model.remat}, attention path {path} at seq {seq}: "
+    if not kept:
+        return line + "no named residual kept across remat"
+    total = sum(kept.values()) * config.num_hidden_layers
+    return line + "kept across remat per layer and micro-batch: " + ", ".join(
+        f"{name} {size} B" for name, size in kept.items()
+    ) + f" ({total / 1e9:.2f} GB over {config.num_hidden_layers} layers)"
 
 
 def prepare_model(args, mesh):
@@ -1053,6 +1078,9 @@ def main(args) -> dict:
                     if data_seq_len is None:
                         data_seq_len = int(batch["input_ids"].shape[-1])
                         placement["batch_devices"] = _devices_holding(batch)
+                        logger.info(_kept_across_remat(
+                            model, config, args.local_batch_size,
+                            data_seq_len))
                         if data_seq_len != seq_len:
                             # MFU must use the DATA shape, not the model cap.
                             from bert_pytorch_tpu.utils import flops as _fl

@@ -1,0 +1,162 @@
+"""remat='dots' keeps attention's named residuals (ops/remat.py): the
+probability-dropout mask on the XLA path, the flash kernel's output and
+log-sum-exp on the Pallas path. Counted in the differentiated program, whose
+remat regions JAX has already DCE'd: what is kept is not computed again."""
+
+import collections
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from bert_pytorch_tpu import pretrain
+from bert_pytorch_tpu.config import BertConfig
+from bert_pytorch_tpu.models import bert
+from bert_pytorch_tpu.ops import remat
+from bert_pytorch_tpu.ops.attention import make_attention_bias
+
+LAYERS, BATCH, SEQ, HEADS, HIDDEN = 2, 2, 16, 2, 32
+
+# (backend, deterministic, what remat must not make twice, how many of them
+# the names add to the forward scan's stacked residuals). The interpreter
+# has no PRNG, so the kernel half runs at rate 0; the XLA half runs with
+# attention dropout alone, so every random-bits op is the mask's.
+PATHS = {
+    "xla": ("xla", False, "random_bits", 1),
+    "pallas": ("pallas", True, "flash_fwd", 2),
+}
+PLAIN_DOTS = jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims
+
+
+def _ops(jaxpr, acc=None):
+    """Primitive names (a pallas_call under its kernel's name) -> count,
+    through every sub-jaxpr."""
+    acc = collections.Counter() if acc is None else acc
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "pallas_call":
+            name = eqn.params["name"]
+        acc[name] += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _ops(sub, acc)
+    return acc
+
+
+def _forward_residuals(jaxpr):
+    """The stacked outputs of the layer scan's forward pass."""
+    scan = next(e for e in jaxpr.eqns if e.primitive.name == "scan")
+    return [v.aval for v in scan.outvars[scan.params["num_carry"]:]]
+
+
+def _grad_program(remat_value, path, with_grads=True):
+    backend, deterministic, _, _ = PATHS[path]
+    cfg = BertConfig(
+        vocab_size=64, hidden_size=HIDDEN, num_hidden_layers=LAYERS,
+        num_attention_heads=HEADS, intermediate_size=64,
+        max_position_embeddings=SEQ, hidden_dropout_prob=0.0,
+        attention_probs_dropout_prob=0.1)
+    encoder = bert.BertEncoder(cfg, dtype=jnp.float32, remat=remat_value,
+                               attention_backend=backend)
+    hidden = jax.random.normal(jax.random.PRNGKey(1), (BATCH, SEQ, HIDDEN))
+    mask = jnp.ones((BATCH, SEQ), jnp.int32).at[:, SEQ - 3:].set(0)
+    bias = make_attention_bias(mask)
+    params = nn.unbox(encoder.init(
+        {"params": jax.random.PRNGKey(0), "dropout": jax.random.PRNGKey(2)},
+        hidden, bias, deterministic))["params"]
+
+    def loss(p):
+        out = encoder.apply({"params": p}, hidden, bias, deterministic,
+                            rngs={"dropout": jax.random.PRNGKey(3)})
+        return jnp.sum(out * out)
+
+    grad = jax.grad(loss)
+    return (grad(params) if with_grads else None,
+            jax.make_jaxpr(grad)(params).jaxpr)
+
+
+def _assert_grads_close(got, want, rtol):
+    # One scale for the whole tree: the key bias's gradient is zero by the
+    # softmax's shift invariance, and what is computed for it is rounding.
+    want = jax.tree_util.tree_leaves(want)
+    atol = rtol * max(float(jnp.max(jnp.abs(b))) for b in want)
+    for a, b in zip(jax.tree_util.tree_leaves(got), want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=rtol,
+                                   atol=atol)
+
+
+@pytest.mark.parametrize("path", sorted(PATHS))
+def test_dots_keeps_the_named_residuals(path, monkeypatch):
+    """One costly op per layer under 'dots', two under a policy without the
+    names; the same gradients either way, and as without remat."""
+    _, _, costly, added = PATHS[path]
+    grads, program = _grad_program("dots", path)
+    assert _ops(program)[costly] == 1  # the forward scan's; none in the backward
+    kept = _forward_residuals(program)
+
+    monkeypatch.setattr(bert, "remat_policy", lambda _: PLAIN_DOTS)
+    plain_grads, plain_program = _grad_program("dots", path)
+    assert _ops(plain_program)[costly] == 2
+    assert len(kept) == len(_forward_residuals(plain_program)) + added
+    monkeypatch.undo()
+
+    # Same arithmetic from the same draws; XLA fuses the three programs
+    # differently, so the last bits may differ (they do between 'none' and
+    # plain 'dots' at the parent commit too).
+    _assert_grads_close(grads, plain_grads, rtol=1e-6)
+    _assert_grads_close(grads, _grad_program("none", path)[0], rtol=1e-5)
+
+
+def test_the_kept_mask_is_one_byte_an_element():
+    _, program = _grad_program("dots", "xla", with_grads=False)
+    masks = [a for a in _forward_residuals(program) if a.dtype == jnp.bool_]
+    assert [a.shape for a in masks] == [(LAYERS, BATCH, HEADS, SEQ, SEQ)]
+    assert remat.kept_residual_bytes(
+        "dots", "xla", dropout=True, batch=BATCH, seq=SEQ, heads=HEADS,
+        head_dim=HIDDEN // HEADS, dtype=jnp.float32
+    ) == {remat.KEEP_MASK: masks[0].size // LAYERS}
+
+
+@pytest.mark.parametrize("path", sorted(PATHS))
+def test_full_still_keeps_nothing(path):
+    """The names add nothing to 'full': its policy is the parent's own
+    (asserted below), the costly op runs twice, and only the layer's inputs
+    cross the scan."""
+    _, _, costly, _ = PATHS[path]
+    _, program = _grad_program("full", path, with_grads=False)
+    assert _ops(program)[costly] == 2
+    _, dots_program = _grad_program("dots", path, with_grads=False)
+    assert (len(_forward_residuals(program))
+            < len(_forward_residuals(dots_program)))
+    assert not any(a.shape[1:] in {(BATCH, HEADS, SEQ, SEQ),
+                                   (BATCH * HEADS, SEQ, HIDDEN // HEADS),
+                                   (BATCH * HEADS, 1, SEQ)}
+                   for a in _forward_residuals(program))
+
+
+def test_one_function_builds_the_policy_for_both_sites():
+    assert bert.remat_policy is remat.remat_policy
+    assert pretrain.remat_policy is remat.remat_policy
+    assert remat.remat_policy("none") is None
+    assert (remat.remat_policy("full")
+            is jax.checkpoint_policies.nothing_saveable)
+    with pytest.raises(ValueError, match="none|dots|full"):
+        remat.remat_policy("some")
+
+
+@pytest.mark.parametrize("path,batch,seq,want", [
+    # the benchmark's two cells, BERT-large in bf16: 16 heads of 64
+    ("xla", 64, 128, {remat.KEEP_MASK: 16_777_216}),
+    ("pallas", 16, 512,
+     {remat.FLASH_OUT: 16_777_216, remat.FLASH_LSE: 524_288}),
+    ("ring", 16, 512, {}),
+])
+def test_kept_residual_bytes_from_shapes(path, batch, seq, want):
+    shapes = dict(batch=batch, seq=seq, heads=16, head_dim=64,
+                  dtype=jnp.bfloat16)
+    assert remat.kept_residual_bytes("dots", path, True, **shapes) == want
+    assert remat.kept_residual_bytes("full", path, True, **shapes) == {}
+    assert remat.kept_residual_bytes("none", path, True, **shapes) == {}
+    if path == "xla":
+        assert remat.kept_residual_bytes("dots", path, False, **shapes) == {}
