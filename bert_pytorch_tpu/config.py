@@ -115,6 +115,97 @@ class BertConfig:
         return ((self.vocab_size + multiple - 1) // multiple) * multiple
 
 
+class NemotronHConfig:
+    """Configuration of the ``nemotron_h`` family: a pre-norm residual decoder
+    whose layers are a Mamba-2 mixer (``M``), a routed expert layer with a
+    shared expert (``E``) or grouped-query causal attention (``*``) ALONE, in
+    the order ``hybrid_override_pattern`` gives. Keys and defaults are the
+    published ``config.json``'s (NVIDIA-Nemotron-3-Nano-30B-A3B); extra keys
+    ride along as on :class:`BertConfig`.
+
+    The expert share is stated here, not in the mesh: ``n_routed_experts`` is
+    how many experts THIS chip holds, ``ep_size`` how many chips share each
+    expert layer and ``ep_rank`` which of them this is. The router keeps
+    ``n_routed_experts * ep_size`` outputs (the published width) and the chip
+    holds the experts ``[ep_rank * n_routed_experts, (ep_rank + 1) * ...)``.
+    """
+
+    model_type = "nemotron_h"
+
+    def __init__(self, **values: Any):
+        defaults = dict(
+            vocab_size=131072, hidden_size=2688, num_hidden_layers=52,
+            hybrid_override_pattern=(
+                "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"),
+            num_attention_heads=32, num_key_value_heads=2, head_dim=128,
+            mamba_num_heads=64, mamba_head_dim=64, n_groups=8,
+            ssm_state_size=128, conv_kernel=4, chunk_size=128,
+            use_conv_bias=True, time_step_min=0.001, time_step_max=0.1,
+            time_step_floor=0.0001,
+            n_routed_experts=128, ep_size=1, ep_rank=0,
+            num_experts_per_tok=6, moe_intermediate_size=1856,
+            moe_shared_expert_intermediate_size=3712, n_shared_experts=1,
+            routed_scaling_factor=2.5, norm_topk_prob=True,
+            mlp_hidden_act="relu2", layer_norm_epsilon=1e-5,
+            initializer_range=0.02, rescale_prenorm_residual=True,
+            tie_word_embeddings=False)
+        for key, value in {**defaults, **values}.items():
+            setattr(self, key, value)
+        pattern = self.hybrid_override_pattern
+        if len(pattern) != self.num_hidden_layers or set(pattern) - set("ME*"):
+            raise ValueError(
+                f"hybrid_override_pattern {pattern!r} must be "
+                f"{self.num_hidden_layers} characters of 'M', 'E', '*'")
+        if not 0 <= self.ep_rank < self.ep_size:
+            raise ValueError(f"ep_rank {self.ep_rank} of ep_size {self.ep_size}")
+        if self.mlp_hidden_act != "relu2" or self.tie_word_embeddings:
+            raise ValueError(
+                "nemotron_h is built with relu2 experts and an untied head")
+
+    @classmethod
+    def from_dict(cls, json_object: dict) -> "NemotronHConfig":
+        values = {k: v for k, v in json_object.items() if k != "model_type"}
+        return cls(**values)
+
+    def to_dict(self) -> dict:
+        return dict(copy.deepcopy(self.__dict__), model_type=self.model_type)
+
+    @property
+    def router_experts(self) -> int:
+        """The router's width: every expert of the layer, held or not."""
+        return self.n_routed_experts * self.ep_size
+
+    @property
+    def first_expert(self) -> int:
+        return self.ep_rank * self.n_routed_experts
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        return self.mamba_inner + 2 * self.n_groups * self.ssm_state_size
+
+
+MODEL_FAMILIES = {"bert": BertConfig, "nemotron_h": NemotronHConfig}
+
+
+def load_model_config(json_file: str):
+    """The configuration a model config file describes, of the family its
+    ``model_type`` names (none: ``bert``). The runners build the model and
+    choose the objective from the class this returns
+    (``models.build_pretraining_model``)."""
+    with open(json_file, "r", encoding="utf-8") as reader:
+        values = json.load(reader)
+    family = values.get("model_type", "bert")
+    if family not in MODEL_FAMILIES:
+        raise ValueError(
+            f"unknown model_type {family!r} in {json_file}; this program "
+            f"builds {sorted(MODEL_FAMILIES)}")
+    return MODEL_FAMILIES[family].from_dict(values)
+
+
 def parse_args_with_config_file(
     parser: argparse.ArgumentParser,
     argv: list[str] | None = None,

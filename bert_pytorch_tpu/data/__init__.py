@@ -10,6 +10,7 @@ from bert_pytorch_tpu.data.dataset import (
     LEGACY_FORMAT_KEYS,
     NEW_FORMAT_KEYS,
     ShardedPretrainingDataset,
+    TokenRowsDataset,
 )
 from bert_pytorch_tpu.data.device_prefetch import DevicePrefetcher
 from bert_pytorch_tpu.data.loader import (
@@ -37,6 +38,7 @@ __all__ = [
     "PACKED_FORMAT_KEYS",
     "PackedPretrainingDataset",
     "ShardedPretrainingDataset",
+    "TokenRowsDataset",
     "first_fit_decreasing",
     "pack_features",
     "write_packed_shard",

@@ -80,6 +80,9 @@ class ShardedPretrainingDataset:
     epoch restart).
     """
 
+    # The keys every shard must hold, one entry per sample.
+    COUNT_KEYS = ("input_ids", "next_sentence_labels")
+
     def __init__(
         self,
         files: Sequence[str] | str,
@@ -199,7 +202,9 @@ class ShardedPretrainingDataset:
 
     # -- streaming -----------------------------------------------------------
 
-    def __getitem__(self, idx: int):
+    def _seek(self, idx: int) -> int:
+        """Bring the shard that holds sample ``idx`` into ``self.data`` (the
+        streaming walk below) and return the sample's index inside it."""
         if self.data is None:
             # First access: infer the starting file from idx and prefetch it.
             self.next_file_idx = self._file_idx_for(idx)
@@ -237,7 +242,10 @@ class ShardedPretrainingDataset:
                 self._next_file_thread = self._async_load_file(self.next_file_idx)
                 (self.file_sample_start_idx,
                  self.file_sample_end_idx) = self.file_idxs[self.file_idx]
+        return idx - self.file_sample_start_idx
 
+    def __getitem__(self, idx: int):
+        local = self._seek(idx)
         # Per-sample masking generator, derived from (seed, epoch, index):
         # sample i's draws are independent of read order and worker
         # topology, so a resumed run masks exactly like an uninterrupted
@@ -246,7 +254,6 @@ class ShardedPretrainingDataset:
         self._rng = np.random.default_rng(
             (self._mask_seed_base, int(self.epoch), int(idx)))
 
-        local = idx - self.file_sample_start_idx
         input_ids = np.array(self.data["input_ids"][local])
         next_sentence_label = np.asarray(self.data["next_sentence_labels"][local])
 
@@ -478,7 +485,7 @@ class ShardedPretrainingDataset:
         current_idx = 0
         verified_files, verified_idxs = [], []
         packed_flags, pack_limits = [], []
-        keys = ["input_ids", "next_sentence_labels"]
+        keys = list(self.COUNT_KEYS)
 
         def skip_or_abort(fpath, why):
             if self.shard_error_policy == "abort":
@@ -534,3 +541,23 @@ class ShardedPretrainingDataset:
         packed = packed_flags[0]
         return (verified_files, verified_idxs, packed,
                 max(pack_limits) if packed else 0)
+
+
+class TokenRowsDataset(ShardedPretrainingDataset):
+    """Rows of token ids for a causal objective: shards that hold
+    ``input_ids`` [N, S] alone, every row full. Same streaming, retries and
+    shard verification as the masked-LM dataset; a sample is
+    ``{"input_ids": row}`` (nothing is masked, nothing is drawn), and the
+    loader collates dict samples by key."""
+
+    COUNT_KEYS = ("input_ids",)
+
+    def __init__(self, files, **resilience):
+        super().__init__(files, None, 0, 0.0, 0, **resilience)
+        if self.packed:
+            raise ValueError("token-row shards are never offline-packed")
+
+    def __getitem__(self, idx: int):
+        local = self._seek(idx)
+        return {"input_ids": np.asarray(
+            self.data["input_ids"][local], np.int32)}

@@ -293,6 +293,10 @@ class DataLoader:
 
     @staticmethod
     def _collate(samples) -> dict:
+        if isinstance(samples[0], dict):  # data/dataset.py TokenRowsDataset
+            with span("data:collate"):
+                return {key: np.stack([s[key] for s in samples])
+                        for key in samples[0]}
         keys = BATCH_KEYS + PACKED_EXTRA_KEYS[:len(samples[0]) - len(BATCH_KEYS)]
         with span("data:collate"):
             arrays = [np.stack([s[i] for s in samples])

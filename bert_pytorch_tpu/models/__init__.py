@@ -43,12 +43,36 @@ from bert_pytorch_tpu.models.convert import (
 from bert_pytorch_tpu.models.losses import (
     masked_lm_loss,
     next_sentence_loss,
+    next_token_loss,
     pretraining_loss,
     span_loss,
     token_classification_loss,
 )
 
+from bert_pytorch_tpu.models.nemotron_h import NemotronHForCausalLM
+
+
+def build_pretraining_model(config, dtype, remat: str = "none",
+                            attention_backend: str = "xla"):
+    """The pretraining model of the family ``config`` belongs to
+    (``config.load_model_config`` chose the class from the file's
+    ``model_type``). The model's ``objective`` attribute names what
+    ``pretrain.make_train_step`` trains it on."""
+    from bert_pytorch_tpu.config import BertConfig, NemotronHConfig
+
+    if isinstance(config, NemotronHConfig):
+        return NemotronHForCausalLM(config, dtype=dtype, remat=remat,
+                                    attention_backend=attention_backend)
+    if isinstance(config, BertConfig):
+        return BertForPreTraining(config, dtype=dtype, remat=remat,
+                                  attention_backend=attention_backend)
+    raise TypeError(f"no pretraining model for {type(config).__name__}")
+
+
 __all__ = [
+    "NemotronHForCausalLM",
+    "build_pretraining_model",
+    "next_token_loss",
     "BertEmbeddings",
     "BertEncoder",
     "BertForMaskedLM",

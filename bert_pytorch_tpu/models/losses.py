@@ -113,6 +113,56 @@ def pretraining_loss(
     return loss
 
 
+def next_token_loss(logits, input_ids):
+    """Causal-LM objective: the mean fp32 cross entropy of position t's
+    logits against token t + 1, over the S - 1 predicted positions of every
+    row, and the share of them whose arg-max is right. The vocabulary is the
+    logits' last axis (a slice of a published vocabulary is a smaller
+    vocabulary: ids, logits and loss are over the slice)."""
+    with jax.named_scope("lm_loss"):
+        labels = jnp.roll(input_ids, -1, axis=-1)
+        predicted = jnp.arange(input_ids.shape[-1]) < input_ids.shape[-1] - 1
+        per_pos = optax.softmax_cross_entropy_with_integer_labels(
+            logits.astype(jnp.float32), labels)
+        count = jnp.maximum(jnp.sum(predicted) * (labels.size // labels.shape[-1]), 1)
+        loss = jnp.sum(jnp.where(predicted, per_pos, 0.0)) / count
+        right = (jnp.argmax(logits, axis=-1) == labels) & predicted
+        return loss, jnp.sum(right) / count
+
+
+def chunked_next_token_loss(hidden, head_kernel, input_ids, chunks: int):
+    """:func:`next_token_loss` of ``hidden @ head_kernel`` without ever
+    holding every position's logits: the head and the cross entropy run over
+    ``chunks`` equal pieces of the sequence, one after the other, each
+    rematerialized, so the backward pass holds one piece's logits at a time
+    (at 8192 x 16384 in float32 the whole is 0.5 GB, several times over).
+    Same loss, same accuracy, the head's forward once more in the backward
+    pass. ``chunks`` must divide the sequence length."""
+    batch, seq, width = hidden.shape
+    labels = jnp.roll(input_ids, -1, axis=-1)
+    predicted = jnp.broadcast_to(jnp.arange(seq) < seq - 1, labels.shape)
+    pieces = lambda t: jnp.moveaxis(
+        t.reshape((batch, chunks, seq // chunks) + t.shape[2:]), 1, 0)
+
+    @jax.checkpoint
+    def piece(carry, xs):
+        h, lab, keep = xs
+        with jax.named_scope("lm_head"):
+            logits = jnp.matmul(h, head_kernel.astype(h.dtype))
+        with jax.named_scope("lm_loss"):
+            per_pos = optax.softmax_cross_entropy_with_integer_labels(
+                logits.astype(jnp.float32), lab)
+            right = (jnp.argmax(logits, axis=-1) == lab) & keep
+            return (carry[0] + jnp.sum(jnp.where(keep, per_pos, 0.0)),
+                    carry[1] + jnp.sum(right)), None
+
+    (total, right), _ = jax.lax.scan(
+        piece, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32)),
+        (pieces(hidden), pieces(labels), pieces(predicted)))
+    count = jnp.maximum(batch * (seq - 1), 1)
+    return total / count, right / count
+
+
 def span_loss(start_logits, end_logits, start_positions, end_positions):
     """SQuAD loss: clamp positions into [0, S], CE on start and end, averaged
     (run_squad.py:1085-1092 — clamped index == ignored index S)."""

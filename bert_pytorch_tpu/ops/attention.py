@@ -69,11 +69,20 @@ def dot_product_attention(
     deterministic: bool = True,
     backend: str = "xla",
     sequence_ids: jnp.ndarray | None = None,
+    causal: bool = False,
 ) -> jnp.ndarray:
     """Attention over [B, S, H, D] query/key/value tensors.
 
     Returns [B, S, H, D]. Scores are scaled by 1/sqrt(D) and softmaxed in
     fp32 (modeling.py:403-429's score path, bf16-safe).
+
+    ``causal`` (static) lets position q attend to positions <= q only: a
+    mask on the XLA path; on the Pallas path a static flag of the kernels,
+    which then mask the tiles on the diagonal and skip those above it
+    (ops/pallas/attention.py). ``k`` and ``v`` may have fewer heads than
+    ``q`` (grouped-query attention, H a multiple of theirs): each key-value
+    head then serves H / H_kv consecutive query heads; they are repeated here,
+    before either path, and autodiff sums the repeats' gradients.
 
     ``sequence_ids`` ([B, S], 0 = pad) marks a PACKED batch
     (data/packing.py): on the XLA path the caller's ``bias`` is then the
@@ -87,8 +96,15 @@ def dot_product_attention(
     trace tells the core from the projections round it on every backend.
     """
     with jax.named_scope("attention_core"):
+        if k.shape[2] != q.shape[2]:
+            if q.shape[2] % k.shape[2]:
+                raise ValueError(
+                    f"{q.shape[2]} query heads on {k.shape[2]} key-value heads")
+            repeats = q.shape[2] // k.shape[2]
+            k = jnp.repeat(k, repeats, axis=2)
+            v = jnp.repeat(v, repeats, axis=2)
         return _attention_core(q, k, v, bias, dropout_rng, dropout_rate,
-                               deterministic, backend, sequence_ids)
+                               deterministic, backend, sequence_ids, causal)
 
 
 def resolve_backend(backend: str, seq: int, dropout: bool) -> str:
@@ -112,9 +128,13 @@ def resolve_backend(backend: str, seq: int, dropout: bool) -> str:
 
 
 def _attention_core(q, k, v, bias, dropout_rng, dropout_rate, deterministic,
-                    backend, sequence_ids):
+                    backend, sequence_ids, causal=False):
     active = not deterministic and dropout_rate > 0.0
     resolved = resolve_backend(backend, q.shape[1], active)
+    if causal and resolved not in ("xla", "pallas"):
+        raise ValueError(
+            f"causal attention runs on the 'xla' and 'pallas' paths, not "
+            f"{resolved!r}")
     if backend == "pallas" and resolved == "xla":
         warnings.warn(
             "backend='pallas' with dropout on the CPU backend: the Pallas "
@@ -155,11 +175,11 @@ def _attention_core(q, k, v, bias, dropout_rng, dropout_rate, deterministic,
         kbias = None if sequence_ids is not None else bias
         if not active:
             return flash_attention(q, k, v, bias=kbias,
-                                   sequence_ids=sequence_ids)
+                                   sequence_ids=sequence_ids, causal=causal)
         return flash_attention(
             q, k, v, bias=kbias,
             dropout_rate=dropout_rate, dropout_rng=dropout_rng,
-            sequence_ids=sequence_ids)
+            sequence_ids=sequence_ids, causal=causal)
     if backend in ("ring", "ring_manual") and sequence_ids is not None:
         # Ring attention shards the sequence axis across chips; the
         # block-diagonal mask would need per-shard id exchange alongside
@@ -222,6 +242,10 @@ def _attention_core(q, k, v, bias, dropout_rng, dropout_rate, deterministic,
     scores = scores.astype(jnp.float32)
     if bias is not None:
         scores = scores + bias.astype(jnp.float32)
+    if causal:
+        seq_q, seq_k = scores.shape[-2:]
+        scores = jnp.where(jnp.tril(jnp.ones((seq_q, seq_k), bool)),
+                           scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
     probs = probs.astype(q.dtype)
     if active:

@@ -1,0 +1,151 @@
+"""A routed expert layer that is told which experts it holds.
+
+The router scores every token over ALL ``n_experts`` (the published width);
+this chip holds the experts ``[first, first + held)`` (``held`` is the
+leading axis of the expert weights) and adds only their terms to the result:
+what the absent experts would add lies on other chips (expert parallelism;
+the exchange between chips is not in this file, and on one chip the layer runs
+without it). Nothing stands in for the absent chips.
+
+Routing (DeepSeek-V3's, as ``nemotron_h`` uses it): ``s = sigmoid(logits)``
+in float32, the ``top_k`` largest of ``s + correction_bias`` choose, the
+weights are ``s`` of the chosen, divided by their sum when ``norm_topk`` is
+set, times ``scale``.
+
+No token-slot is ever dropped. The ``tokens x top_k`` slots are sorted so
+that the slots of held experts come first, expert by expert; a token picks
+distinct experts, so at most all of them are local. The grouped products run
+over those sorted rows with the experts' true group sizes and visit only the
+row tiles the groups fill, so the matmul work follows the slots really routed
+here: on a TPU the grouped matmul JAX ships (``megablox`` ``gmm``, with its
+own backward products) at 512-row tiles, which at this layer's shapes runs
+the two products forward and backward in a third of the time of
+``jax.lax.ragged_dot`` (3.5 against 11.1 ms at 3200 live rows, PERF.md 6);
+``ragged_dot`` elsewhere (the CPU tests). The sorted rows are worked through
+in PIECES of a static size (twice the expected local load): as many pieces
+as hold every slot there is, each a ``lax.cond`` that does nothing when it
+lies past the last local slot. So gather, activation and
+scatter-add follow the routed slots too, a piece at a time, and no buffer is
+ever sized for the worst case (a 49152-row branch that is never taken cost
+the step 1 GB of the chip: PERF.md 6).
+
+Scopes (``pretrain.CAUSAL_LM_SCOPES``): ``moe_route``, ``moe_dispatch``,
+``moe_experts``, ``moe_combine``.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from bert_pytorch_tpu.ops.pallas.common import interpret_mode
+
+GMM_TILE_ROWS = 512
+
+
+def grouped_dot(rows, weights, sizes):
+    """rows [M, K] @ weights[g] [K, N] for the rows of group g (consecutive,
+    ``sizes`` [G] of them each); rows past the groups are left undefined."""
+    if interpret_mode() or rows.shape[0] % GMM_TILE_ROWS:
+        return jax.lax.ragged_dot(rows, weights, sizes,
+                                  preferred_element_type=rows.dtype)
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+    tiling = (GMM_TILE_ROWS, min(896, rows.shape[1]), min(640, weights.shape[2]))
+    return megablox.gmm(rows, weights, sizes, rows.dtype, tiling)
+
+
+def route(x, router_w, correction_bias, top_k: int, scale: float,
+          norm_topk: bool = True):
+    """x [T, H] -> (expert ids [T, k] int32, weights [T, k] float32)."""
+    with jax.named_scope("moe_route"):
+        logits = jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                            precision="highest")
+        scores = jax.nn.sigmoid(logits)
+        _, chosen = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(correction_bias), top_k)
+        weights = jnp.take_along_axis(scores, chosen, axis=-1)
+        if norm_topk:
+            weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+        return chosen.astype(jnp.int32), weights * scale
+
+
+def chunk_rows(tokens: int, top_k: int, n_experts: int, held: int,
+               multiple: int = GMM_TILE_ROWS) -> int:
+    """Rows the held experts' slots are worked through at a time: twice the
+    expected local load, rounded up to ``multiple`` rows, at most every slot
+    there is."""
+    most = tokens * top_k
+    rows = -(-2 * most * held // n_experts // multiple) * multiple
+    return min(rows, -(-most // multiple) * multiple)
+
+
+def held_experts(x, chosen, weights, w_up, w_down, first: int,
+                 n_experts: int, activation, multiple: int = GMM_TILE_ROWS):
+    """The held experts' part of the layer's output, and the counters.
+
+    x [T, H]; chosen / weights [T, k] from :func:`route`; w_up [E, H, F],
+    w_down [E, F, H] for the E experts ``first .. first + E``. Returns
+    (out [T, H] in x's dtype, counters): ``local_slots`` (slots routed to held
+    experts), ``load_max_over_mean`` (largest group over the mean group) and
+    ``dropped_slots`` (local slots that no piece reached: 0, since the pieces
+    cover every slot there is).
+    """
+    tokens, top_k = chosen.shape
+    held = w_up.shape[0]
+    rows = chunk_rows(tokens, top_k, n_experts, held, multiple)
+    pieces = -(-tokens * top_k // rows)
+    with jax.named_scope("moe_dispatch"):
+        local = chosen - first                                # [T, k]
+        here = (local >= 0) & (local < held)
+        key = jnp.where(here, local, held).reshape(-1)        # absent: last
+        order = jnp.argsort(key, stable=True)                 # held first
+        sizes = jnp.sum(jax.nn.one_hot(key, held + 1, dtype=jnp.int32),
+                        axis=0)[:held]
+        n_local = jnp.sum(sizes)
+        ends = jnp.cumsum(sizes)
+        order = jnp.pad(order, (0, pieces * rows - tokens * top_k))
+    w_up, w_down = w_up.astype(x.dtype), w_down.astype(x.dtype)
+    flat_weights = weights.reshape(-1)
+
+    @jax.checkpoint  # a piece keeps nothing: its forward is made again
+    def work(slots, lo):
+        with jax.named_scope("moe_dispatch"):
+            token = slots // top_k
+            # Rows past the last local slot belong to no group: the grouped
+            # product leaves them undefined, so every value that goes into
+            # or comes out of one passes a select (in the backward pass too:
+            # the select's transpose is a select).
+            live = (lo + jnp.arange(rows) < n_local)[:, None]
+            mine = jnp.clip(ends - lo, 0, rows) - jnp.clip(
+                ends - sizes - lo, 0, rows)        # this piece's group sizes
+            rows_in = jnp.where(live, x[token], 0)
+        with jax.named_scope("moe_experts"):
+            mid = grouped_dot(rows_in, w_up, mine)
+            mid = activation(jnp.where(live, mid, 0))
+            rows_out = grouped_dot(mid, w_down, mine)
+        with jax.named_scope("moe_combine"):
+            slot_w = flat_weights[slots][:, None]
+            rows_out = jnp.where(live, rows_out, 0).astype(jnp.float32) * slot_w
+            return jnp.zeros((tokens, x.shape[-1]), jnp.float32).at[
+                token].add(rows_out)
+
+    # The pieces one after the other (a Python loop: a scan would stack what
+    # each piece reads, the layer's input and the experts' weights, once a
+    # piece). Pieces past the last local slot do nothing: the work follows
+    # the slots really routed here, a piece at a time.
+    out = jnp.zeros((tokens, x.shape[-1]), jnp.float32)
+    for index in range(pieces):
+        lo = index * rows
+        out = out + jax.lax.cond(
+            lo < n_local, work,
+            lambda *_: jnp.zeros((tokens, x.shape[-1]), jnp.float32),
+            order[lo:lo + rows], jnp.asarray(lo, jnp.int32))
+    counters = {
+        "local_slots": n_local.astype(jnp.float32),
+        "load_max_over_mean": jnp.max(sizes).astype(jnp.float32) * held
+        / jnp.maximum(n_local, 1).astype(jnp.float32),
+        "dropped_slots": jnp.maximum(
+            n_local - pieces * rows, 0).astype(jnp.float32),
+    }
+    return out.astype(x.dtype), counters

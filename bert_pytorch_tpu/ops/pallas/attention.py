@@ -24,6 +24,13 @@ on GPU (SURVEY §2.3) — built TPU-native:
     the backward kernels rebuild the identical mask when recomputing
     probabilities. Statically gated (``segmented``), so unpacked callers
     compile the same kernel as before.
+  - **Causal masking** (``causal``, a static flag like ``segmented``): the
+    tiles the diagonal crosses are masked from the tile's row and column
+    numbers, the tiles above it are never visited (the k loop of the forward
+    and dq kernels ends at the diagonal, the q loop of the dk/dv kernel starts
+    there), and the tiles below it run the unmasked body. With the flag off
+    the kernels trace exactly as before: the bidirectional callers pay
+    nothing.
 
 Derivation with dropout (rate r, keep mask D ∈ {0,1}, P = softmax(S)):
   out   = (D ⊙ P) V / (1-r)
@@ -184,9 +191,27 @@ def _seg_mask(q_seg, k_seg):
     return jnp.where(same, 0.0, -10000.0)
 
 
+def _causal_keep(row0, col0, shape):
+    """[block_q, block_k] bool: column <= row, for the tile whose first row
+    and column are ``row0`` and ``col0``."""
+    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    cols = col0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return cols <= rows
+
+
+def _causal_k_loop(body, init, qb, block_q, block_k):
+    """The k loop of one q block under the causal mask: the unmasked body
+    over the k blocks wholly below the diagonal, the masked one over those it
+    crosses, none above."""
+    n_full = (qb * block_q) // block_k
+    n_seen = ((qb + 1) * block_q + block_k - 1) // block_k
+    carry = jax.lax.fori_loop(0, n_full, body, init)
+    return jax.lax.fori_loop(n_full, n_seen, partial(body, masked=True), carry)
+
+
 def _flash_fwd_kernel(
     seed_ref, q_ref, k_ref, v_ref, bias_ref, seg_ref, out_ref, lse_ref,
-    *, block_k, scale, rate, bh_block, segmented
+    *, block_k, scale, rate, bh_block, segmented, causal=False
 ):
     # q_ref: [G, block_q, D]; k_ref/v_ref: [G, S, D]; bias_ref/seg_ref:
     # [G, 1, S], where G = bh_block (batch*head) pairs per program — an
@@ -208,7 +233,7 @@ def _flash_fwd_kernel(
         if segmented:
             q_seg = seg_ref[g, 0, pl.ds(qb * block_q, block_q)]
 
-        def body(j, carry):
+        def body(j, carry, masked=False):
             m_prev, l_prev, acc = carry
             k = k_ref[g, pl.ds(j * block_k, block_k), :]
             v = v_ref[g, pl.ds(j * block_k, block_k), :]
@@ -221,6 +246,9 @@ def _flash_fwd_kernel(
             if segmented:
                 k_seg = seg_ref[g, 0, pl.ds(j * block_k, block_k)]
                 s = s + _seg_mask(q_seg, k_seg)
+            if masked:
+                s = jnp.where(_causal_keep(qb * block_q, j * block_k, s.shape),
+                              s, _NEG_INF)
             m_cur = jnp.max(s, axis=-1)
             m_new = jnp.maximum(m_prev, m_cur)
             alpha = jnp.exp(m_prev - m_new)
@@ -242,14 +270,19 @@ def _flash_fwd_kernel(
         m0 = jnp.full((q.shape[0],), _NEG_INF, jnp.float32)
         l0 = jnp.zeros((q.shape[0],), jnp.float32)
         acc0 = jnp.zeros(q.shape, jnp.float32)
-        m, l, acc = jax.lax.fori_loop(0, num_kb, body, (m0, l0, acc0))
+        if causal:
+            m, l, acc = _causal_k_loop(body, (m0, l0, acc0), qb, block_q,
+                                       block_k)
+        else:
+            m, l, acc = jax.lax.fori_loop(0, num_kb, body, (m0, l0, acc0))
         out_ref[g] = (acc / (l[:, None] * (1.0 - rate))).astype(out_ref.dtype)
         lse_ref[g, 0] = m + jnp.log(l)
 
 
 def _flash_dq_kernel(
     seed_ref, q_ref, k_ref, v_ref, bias_ref, seg_ref, lse_ref, delta_ref,
-    do_ref, dq_ref, *, block_k, scale, rate, bh_block, segmented
+    do_ref, dq_ref, *, block_k, scale, rate, bh_block, segmented,
+    causal=False
 ):
     """dq for [G, block_q, D] tiles (G bh pairs/program); loops over k blocks."""
     qb = pl.program_id(1)
@@ -266,7 +299,7 @@ def _flash_dq_kernel(
         if segmented:
             q_seg = seg_ref[g, 0, pl.ds(qb * q.shape[0], q.shape[0])]
 
-        def body(j, dq_acc):
+        def body(j, dq_acc, masked=False):
             k = k_ref[g, pl.ds(j * block_k, block_k), :]
             v = v_ref[g, pl.ds(j * block_k, block_k), :]
             b = bias_ref[g, 0, pl.ds(j * block_k, block_k)].astype(jnp.float32)
@@ -279,6 +312,10 @@ def _flash_dq_kernel(
                 # probabilities below must be the ones the forward used.
                 s = s + _seg_mask(
                     q_seg, seg_ref[g, 0, pl.ds(j * block_k, block_k)])
+            if masked:
+                s = jnp.where(
+                    _causal_keep(qb * q.shape[0], j * block_k, s.shape),
+                    s, _NEG_INF)
             p = jnp.exp(s - lse[:, None])  # normalized probabilities
             da = jax.lax.dot_general(
                 do, v, (((1,), (1,)), ((), ())),
@@ -294,14 +331,18 @@ def _flash_dq_kernel(
                 preferred_element_type=jnp.float32,
             )
 
-        dq = jax.lax.fori_loop(0, num_kb, body, jnp.zeros(q.shape, jnp.float32))
+        dq0 = jnp.zeros(q.shape, jnp.float32)
+        if causal:
+            dq = _causal_k_loop(body, dq0, qb, q.shape[0], block_k)
+        else:
+            dq = jax.lax.fori_loop(0, num_kb, body, dq0)
         dq_ref[g] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _flash_dkv_kernel(
     seed_ref, q_ref, k_ref, v_ref, bias_ref, seg_ref, lse_ref, delta_ref,
     do_ref, dk_ref, dv_ref, dbias_ref, *, block_q, scale, rate, bh_block,
-    segmented
+    segmented, causal=False
 ):
     """dk/dv/dbias for [G, block_k, D] tiles; loops over q blocks."""
     kb = pl.program_id(1)
@@ -318,7 +359,7 @@ def _flash_dkv_kernel(
         if segmented:
             k_seg = seg_ref[g, 0, pl.ds(kb * block_k, block_k)]
 
-        def body(i, carry):
+        def body(i, carry, masked=False):
             dk_acc, dv_acc, db_acc = carry
             q = q_ref[g, pl.ds(i * block_q, block_q), :]
             lse = lse_ref[g, 0, pl.ds(i * block_q, block_q)]
@@ -331,6 +372,10 @@ def _flash_dkv_kernel(
             if segmented:
                 s = s + _seg_mask(
                     seg_ref[g, 0, pl.ds(i * block_q, block_q)], k_seg)
+            if masked:
+                s = jnp.where(
+                    _causal_keep(i * block_q, kb * block_k, s.shape),
+                    s, _NEG_INF)
             p = jnp.exp(s - lse[:, None])  # [block_q, block_k]
             da = jax.lax.dot_general(
                 do, v, (((1,), (1,)), ((), ())),
@@ -355,16 +400,21 @@ def _flash_dkv_kernel(
             )
             return dk_acc, dv_acc, db_acc + jnp.sum(ds, axis=0)
 
-        dk, dv, db = jax.lax.fori_loop(
-            0,
-            num_qb,
-            body,
-            (
-                jnp.zeros((block_k, depth), jnp.float32),
-                jnp.zeros((block_k, depth), jnp.float32),
-                jnp.zeros((block_k,), jnp.float32),
-            ),
+        zeros = (
+            jnp.zeros((block_k, depth), jnp.float32),
+            jnp.zeros((block_k, depth), jnp.float32),
+            jnp.zeros((block_k,), jnp.float32),
         )
+        if causal:
+            # q blocks wholly above this k block are skipped, those the
+            # diagonal crosses are masked, those wholly below are not.
+            first = (kb * block_k) // block_q
+            whole = ((kb + 1) * block_k + block_q - 2) // block_q
+            carry = jax.lax.fori_loop(
+                first, whole, partial(body, masked=True), zeros)
+            dk, dv, db = jax.lax.fori_loop(whole, num_qb, body, carry)
+        else:
+            dk, dv, db = jax.lax.fori_loop(0, num_qb, body, zeros)
         dk_ref[g] = (dk * scale).astype(dk_ref.dtype)
         dv_ref[g] = dv.astype(dv_ref.dtype)
         dbias_ref[g, 0] = db.astype(dbias_ref.dtype)
@@ -374,7 +424,14 @@ def _seed_spec():
     return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
-def _flash_forward(q3, k3, v3, bias3, seg3, seed, scale, rate, segmented):
+def _static(segmented, causal):
+    """The kernels' static flags. ``causal`` is passed only when set, so the
+    bidirectional call sites stay as they were."""
+    return dict(segmented=segmented, **({"causal": True} if causal else {}))
+
+
+def _flash_forward(q3, k3, v3, bias3, seg3, seed, scale, rate, segmented,
+                   causal=False):
     """q3/k3/v3: [BH, S, D]; bias3: [BH, 1, S] additive key bias; seg3:
     [BH, 1, S] fp32 sequence ids (all-zero dummy when not segmented)."""
     bh, seq, depth = q3.shape
@@ -383,7 +440,7 @@ def _flash_forward(q3, k3, v3, bias3, seg3, seed, scale, rate, segmented):
     grid = (bh // g, seq // block_q)
     out, lse = pl.pallas_call(
         partial(_flash_fwd_kernel, block_k=block_k, scale=scale, rate=rate,
-                bh_block=g, segmented=segmented),
+                bh_block=g, **_static(segmented, causal)),
         grid=grid,
         in_specs=[
             _seed_spec(),
@@ -407,16 +464,17 @@ def _flash_forward(q3, k3, v3, bias3, seg3, seed, scale, rate, segmented):
     return out, lse
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
-def _flash(q3, k3, v3, bias3, seg3, seed, scale, rate, segmented):
+@partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+def _flash(q3, k3, v3, bias3, seg3, seed, scale, rate, segmented,
+           causal=False):
     out, _ = _flash_forward(q3, k3, v3, bias3, seg3, seed, scale, rate,
-                            segmented)
+                            segmented, causal)
     return out
 
 
-def _flash_fwd(q3, k3, v3, bias3, seg3, seed, scale, rate, segmented):
+def _flash_fwd(q3, k3, v3, bias3, seg3, seed, scale, rate, segmented, causal):
     out, lse = _flash_forward(q3, k3, v3, bias3, seg3, seed, scale, rate,
-                              segmented)
+                              segmented, causal)
     # Named here, in the forward RULE: remat='dots' keeps both (ops/remat.py),
     # which leaves the recomputed pallas_call without a live output, so the
     # backward pass does not run the forward kernel a second time.
@@ -425,7 +483,7 @@ def _flash_fwd(q3, k3, v3, bias3, seg3, seed, scale, rate, segmented):
     return out, (q3, k3, v3, bias3, seg3, seed, out, lse)
 
 
-def _flash_bwd(scale, rate, segmented, residuals, g):
+def _flash_bwd(scale, rate, segmented, causal, residuals, g):
     q3, k3, v3, bias3, seg3, seed, out, lse = residuals
     bh, seq, depth = q3.shape
     block_q, block_k = _pick_blocks(seq)
@@ -437,7 +495,7 @@ def _flash_bwd(scale, rate, segmented, residuals, g):
     gb = _pick_bh_block(seq, bh)
     dq = pl.pallas_call(
         partial(_flash_dq_kernel, block_k=block_k, scale=scale, rate=rate,
-                bh_block=gb, segmented=segmented),
+                bh_block=gb, **_static(segmented, causal)),
         grid=(bh // gb, seq // block_q),
         in_specs=[
             _seed_spec(),
@@ -458,7 +516,7 @@ def _flash_bwd(scale, rate, segmented, residuals, g):
 
     dk, dv, dbias = pl.pallas_call(
         partial(_flash_dkv_kernel, block_q=block_q, scale=scale, rate=rate,
-                bh_block=gb, segmented=segmented),
+                bh_block=gb, **_static(segmented, causal)),
         grid=(bh // gb, seq // block_k),
         in_specs=[
             _seed_spec(),
@@ -745,7 +803,7 @@ def flash_attention_infer_int8(q, k, v, bias=None, sequence_ids=None,
 
 
 def flash_attention(q, k, v, bias=None, dropout_rate=0.0, dropout_rng=None,
-                    sequence_ids=None):
+                    sequence_ids=None, causal=False):
     """Fused attention over [B, S, H, D] tensors.
 
     ``bias`` is the [B, 1, 1, S] additive mask from
@@ -763,6 +821,9 @@ def flash_attention(q, k, v, bias=None, dropout_rate=0.0, dropout_rng=None,
     kernel using the TPU hardware PRNG, seeded from ``dropout_rng`` — the
     [B, H, S, S] mask never exists in HBM and the backward regenerates it
     from the same seed. Requires a real TPU (no interpret-mode lowering).
+
+    ``causal`` (static) masks position q from the positions after it and
+    skips the tiles above the diagonal (module docstring).
     """
     batch, seq, heads, depth = q.shape
     scale = 1.0 / float(depth) ** 0.5
@@ -804,5 +865,5 @@ def flash_attention(q, k, v, bias=None, dropout_rate=0.0, dropout_rng=None,
     else:
         seed = jnp.zeros((1,), jnp.int32)
     out3 = _flash(to3(q), to3(k), to3(v), bias3, seg3, seed, scale,
-                  float(dropout_rate), segmented)
+                  float(dropout_rate), segmented, bool(causal))
     return out3.reshape(batch, heads, seq, depth).transpose(0, 2, 1, 3)

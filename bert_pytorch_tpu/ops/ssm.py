@@ -1,0 +1,123 @@
+"""The Mamba-2 state-space mixer's core: causal depthwise convolution, the
+selective recurrence as a chunked scan (the SSD form of Dao & Gu 2024,
+arXiv:2405.21060), and the gated group RMSNorm that follows it.
+
+The recurrence, per head h with state [P, N] (P = head dim, N = state size):
+
+    h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T        y_t = h_t C_t + D x_t
+
+``ssd_chunked_scan`` computes it in chunks of ``chunk`` positions, in matmul
+form: inside a chunk the outputs are a masked, decay-weighted (C B^T) X
+product; each chunk's contribution to the state is one more product; the
+states pass from chunk to chunk in a ``lax.scan`` whose carry is the float32
+state; and the carried state reaches the chunk's outputs through a last
+product. Matmul operands are in the activations' dtype with float32
+accumulation; the decays (cumulative sums of dt A and their exponentials) and
+the carried state are float32. Plain XLA: no kernel. Autodiff gives the
+backward pass (the scan over chunks reverses).
+
+Everything runs under ``jax.named_scope`` names that ``pretrain.CAUSAL_LM_SCOPES``
+lists (``ssm_conv``, ``ssd_scan``, ``ssm_gate_norm``), so a profiler trace
+tells the recurrence from the projections round it.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+
+def causal_depthwise_conv(x, weight, bias):
+    """x [B, S, C], weight [K, C], bias [C] -> [B, S, C]:
+    ``out_t = bias + sum_k weight[k] x_{t - (K - 1) + k}`` with zeros before
+    the sequence (torch ``Conv1d(groups=C, padding=K-1)`` cut to S)."""
+    with jax.named_scope("ssm_conv"):
+        taps, seq = weight.shape[0], x.shape[1]
+        padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+        out = bias.astype(x.dtype)
+        for k in range(taps):
+            out = out + padded[:, k:k + seq, :] * weight[k].astype(x.dtype)
+        return out
+
+
+def ssd_chunked_scan(x, dt, a, b, c, d, chunk: int):
+    """The selective recurrence over a whole sequence.
+
+    x [B, S, H, P]; dt [B, S, H] float32, already positive; a [H] float32,
+    negative; b, c [B, S, G, N] with H a multiple of G (heads of one group
+    share B and C); d [H]. Returns y [B, S, H, P] in x's dtype. A length that
+    is no multiple of ``chunk`` is padded at the end with dt = 0 (decay 1, no
+    input), which leaves the positions before it untouched.
+    """
+    with jax.named_scope("ssd_scan"):
+        return _ssd(x, dt, a, b, c, d, chunk)
+
+
+def _ssd(x, dt, a, b, c, d, chunk):
+    batch, seq, heads, hdim = x.shape
+    groups, state = b.shape[2], b.shape[3]
+    per = heads // groups
+    dtype = x.dtype
+    pad = (-seq) % chunk
+    if pad:
+        widths = lambda t: ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2)
+        x, dt, b, c = (jnp.pad(t, widths(t)) for t in (x, dt, b, c))
+    n = (seq + pad) // chunk
+    # Heads before positions, so that the two minor axes of every large
+    # tensor are (position, position), (position, width) or (width, state).
+    xs = x.reshape(batch, n, chunk, groups, per, hdim).transpose(0, 1, 3, 4, 2, 5)
+    dts = dt.astype(jnp.float32).reshape(
+        batch, n, chunk, groups, per).transpose(0, 1, 3, 4, 2)
+    bs = b.reshape(batch, n, chunk, groups, state).transpose(0, 1, 3, 2, 4)
+    cs = c.reshape(batch, n, chunk, groups, state).transpose(0, 1, 3, 2, 4)
+    # log decays, cumulative inside the chunk: float32, [B, n, G, per, Q]
+    cum = jnp.cumsum(
+        dts * a.reshape(groups, per, 1).astype(jnp.float32), axis=-1)
+    xdt = xs.astype(jnp.float32) * dts[..., None]
+
+    # 1. inside the chunk:
+    #    y_i += sum_{j <= i} (C_i . B_j) exp(cum_i - cum_j) dt_j x_j
+    cb = jnp.einsum("bngis,bngjs->bngij", cs, bs,
+                    preferred_element_type=jnp.float32)
+    gap = cum[..., :, None] - cum[..., None, :]          # [B, n, G, per, i, j]
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    decay = jnp.exp(jnp.where(lower, gap, -jnp.inf))
+    weights = cb[:, :, :, None] * decay
+    y = jnp.einsum("bnghij,bnghjp->bnghip", weights.astype(dtype),
+                   xdt.astype(dtype), preferred_element_type=jnp.float32)
+
+    # 2. what each chunk adds to the state at its end: [B, n, G, per, P, N]
+    to_end = jnp.exp(cum[..., -1:] - cum)
+    added = jnp.einsum("bngjs,bnghjp->bnghps", bs,
+                       (xdt * to_end[..., None]).astype(dtype),
+                       preferred_element_type=jnp.float32)
+    # 3. the state from chunk to chunk: a float32 carry
+    whole = jnp.exp(cum[..., -1])                          # [B, n, G, per]
+
+    def carry_on(h, step):
+        keep, add = step
+        return h * keep[..., None, None] + add, h
+
+    h0 = jnp.zeros((batch, groups, per, hdim, state), jnp.float32)
+    _, before = jax.lax.scan(
+        carry_on, h0, (whole.swapaxes(0, 1), added.swapaxes(0, 1)))
+    before = before.swapaxes(0, 1)
+    # 4. the carried state's part of the outputs
+    y = y + jnp.einsum("bngis,bnghps->bnghip", cs, before.astype(dtype),
+                       preferred_element_type=jnp.float32
+                       ) * jnp.exp(cum)[..., None]
+    y = y + xs.astype(jnp.float32) * d.reshape(
+        groups, per, 1, 1).astype(jnp.float32)
+    y = y.transpose(0, 1, 4, 2, 3, 5).reshape(batch, n * chunk, heads, hdim)
+    return y[:, :seq].astype(dtype)
+
+
+def gated_group_rms_norm(y, z, weight, groups: int, eps: float):
+    """``RMSNorm_groups(y * silu(z)) * weight``: the mean square is taken
+    over each of ``groups`` equal slices of the last axis (float32)."""
+    with jax.named_scope("ssm_gate_norm"):
+        gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+        shaped = gated.reshape(gated.shape[:-1] + (groups, -1))
+        normed = shaped * jax.lax.rsqrt(
+            jnp.mean(jnp.square(shaped), axis=-1, keepdims=True) + eps)
+        return (normed.reshape(gated.shape) * weight).astype(y.dtype)

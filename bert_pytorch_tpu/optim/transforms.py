@@ -73,6 +73,17 @@ def _mask_tree(params, mask):
     return mask(params) if callable(mask) else mask
 
 
+def _clip_by_global_norm(grads, max_grad_norm):
+    """Global-norm clipping to ``max_grad_norm`` (None or <= 0: off), under
+    the ``clip`` scope: the one clipping LAMB and AdamW share."""
+    if max_grad_norm is None or max_grad_norm <= 0:
+        return grads
+    with jax.named_scope("clip"):
+        gnorm = global_norm(grads)
+        gscale = jnp.minimum(1.0, max_grad_norm / (gnorm + 1e-6))
+        return jax.tree_util.tree_map(lambda g: g * gscale, grads)
+
+
 def lamb(
     learning_rate: ScalarOrSchedule,
     b1: float = 0.9,
@@ -101,11 +112,7 @@ def lamb(
     def update(grads, state, params):
         if params is None:
             raise ValueError("lamb requires params")
-        if max_grad_norm is not None and max_grad_norm > 0:
-            with jax.named_scope("clip"):
-                gnorm = global_norm(grads)
-                gscale = jnp.minimum(1.0, max_grad_norm / (gnorm + 1e-6))
-                grads = jax.tree_util.tree_map(lambda g: g * gscale, grads)
+        grads = _clip_by_global_norm(grads, max_grad_norm)
 
         with jax.named_scope("lamb"):
             mu, nu = _update_moments(grads, state, b1, b2)
@@ -150,16 +157,20 @@ def adamw(
     weight_decay: float = 0.01,
     weight_decay_mask=None,
     bias_correction: bool = True,
+    max_grad_norm: Optional[float] = None,
 ) -> optax.GradientTransformation:
     """Adam with decoupled weight decay — the Apex ``FusedAdam`` role in
     finetuning (run_squad.py:982-988, run_ner.py:243 use
-    bias_correction=False; the default here is True)."""
+    bias_correction=False; the default here is True). ``max_grad_norm``
+    clips by the global norm first, as :func:`lamb` does (off by default:
+    the finetuning runners never clipped)."""
 
     def init(params):
         mu, nu = _init_moments(params)
         return OptState(jnp.zeros((), jnp.int32), mu, nu)
 
     def update(grads, state, params):
+        grads = _clip_by_global_norm(grads, max_grad_norm)
         mu, nu = _update_moments(grads, state, b1, b2)
         count = state.count + 1
         if bias_correction:
@@ -264,14 +275,18 @@ def no_decay_mask(params) -> optax.Params:
     """True where weight decay applies. The analog of the reference's no-decay
     param grouping (run_pretraining.py:279-286: names containing bias/gamma/
     beta/LayerNorm are excluded) — here: any 'bias' leaf and every LayerNorm
-    parameter ('scale' lives only in LayerNorm modules)."""
+    parameter ('scale' lives only in LayerNorm modules). Of the
+    ``nemotron_h`` family (models/nemotron_h.py) also every ``*_bias`` and
+    ``*_scale`` leaf (the convolution's bias, ``dt_bias``, the gated norm's
+    scale, the router's correction buffer) and the state-space mixer's
+    ``A_log`` and ``D``, as Mamba-2's own recipe exempts them."""
     import flax.traverse_util as traverse_util
 
     flat = traverse_util.flatten_dict(params)
     mask = {
         path: not (
-            path[-1] == "bias"
-            or path[-1] == "scale"
+            path[-1] in ("bias", "scale", "A_log", "D")
+            or path[-1].endswith(("_bias", "_scale"))
             or any("layer_norm" in part for part in path)
         )
         for path in flat

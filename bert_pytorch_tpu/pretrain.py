@@ -26,7 +26,9 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from bert_pytorch_tpu.models.losses import mlm_accuracy, pretraining_loss
+from bert_pytorch_tpu.models.losses import (chunked_next_token_loss,
+                                            mlm_accuracy, next_token_loss,
+                                            pretraining_loss)
 from bert_pytorch_tpu.ops.grad_utils import global_norm
 from bert_pytorch_tpu.ops.remat import remat_policy
 from bert_pytorch_tpu.optim.transforms import (LossScaleState, OptState,
@@ -43,6 +45,19 @@ from bert_pytorch_tpu.parallel.sharding import params_shardings
 SCOPES = ("micro_batches", "grad_accumulate", "optimizer", "clip", "lamb",
           "step_metrics", "mlm_loss", "nsp_loss", "attention_core",
           "attention_dropout")
+# The scopes a step of the ``causal_lm`` objective writes beside those
+# (the nemotron_h family: models/nemotron_h.py, ops/ssm.py, ops/moe.py,
+# models/losses.py); it has no ``mlm_loss``, ``nsp_loss``, ``lamb`` or
+# ``attention_dropout``.
+CAUSAL_LM_SCOPES = (
+    "ssm_mixer", "ssm_in_proj", "ssm_conv", "ssd_scan", "ssm_gate_norm",
+    "ssm_out_proj", "moe", "moe_route", "moe_dispatch", "moe_experts",
+    "moe_combine", "moe_shared", "lm_head", "lm_loss")
+
+
+# Rows longer than this many positions take the output head and its loss in
+# pieces of this length (models/losses.py chunked_next_token_loss).
+LM_HEAD_PIECE = 2048
 
 
 @flax.struct.dataclass
@@ -164,6 +179,43 @@ def _apply_pretraining_loss(model, variables, mb, rng, next_sentence,
     )
     acc = mlm_accuracy(mlm_logits, labels)
     return loss, acc, mutated
+
+
+def _apply_causal_lm_loss(model, variables, mb):
+    """The ``causal_lm`` objective's counterpart of
+    :func:`_apply_pretraining_loss`: rows of token ids in, next-token loss
+    out. Returns (loss, aux); ``aux`` holds the token accuracy and the
+    model's routing counters, one scalar each per micro-batch."""
+    ids = mb["input_ids"]
+    pieces, ragged = divmod(ids.shape[-1], LM_HEAD_PIECE)
+    if ragged or pieces < 2:
+        logits, counters = model.apply(variables, ids)
+        loss, accuracy = next_token_loss(logits, ids)
+    else:  # long rows: the head and the loss in pieces of the sequence
+        hidden, counters = model.apply(variables, ids,
+                                       method="hidden_states")
+        loss, accuracy = chunked_next_token_loss(
+            hidden, variables["params"]["lm_head"]["kernel"], ids, pieces)
+    return loss, {"token_accuracy": accuracy, **counters}
+
+
+def _aux_metrics(aux) -> dict:
+    """Step metrics from the micro-batches' stacked aux: the MLM objective's
+    is its accuracy; the causal objective's is a dict whose counters add up
+    over the update (``*_slots``) or take its worst (``*_max_over_mean``)."""
+    if not isinstance(aux, dict):
+        return {"mlm_accuracy": jnp.mean(aux)}
+    reduce = lambda name: (jnp.sum if name.endswith("_slots") else
+                           jnp.max if name.endswith("_max_over_mean") else
+                           jnp.mean)
+    return {name: reduce(name)(value) for name, value in aux.items()}
+
+
+def _real_tokens(batch):
+    """Non-pad tokens of the update: rows of a causal objective are full."""
+    if "input_mask" in batch:
+        return jnp.sum(batch["input_mask"]).astype(jnp.float32)
+    return jnp.asarray(batch["input_ids"].size, jnp.float32)
 
 
 def make_kfac_fns(
@@ -444,6 +496,16 @@ def make_train_step(
     aligning the due gate with the host's run-local sync cadence.
     TrainTelemetry.step_done pops and emits it.
     """
+    # What the model is trained on: ``mlm`` (BERT: masked tokens and next
+    # sentence) unless the model's family says otherwise
+    # (models/nemotron_h.py: ``causal_lm``, rows of token ids).
+    objective = getattr(model, "objective", "mlm")
+    if objective not in ("mlm", "causal_lm"):
+        raise ValueError(f"unknown objective {objective!r}")
+    if objective == "causal_lm" and (kfac is not None or overlap_grad_buckets):
+        raise ValueError(
+            "the causal_lm objective runs the plain first-order step (no "
+            "K-FAC, no bucketed gradient reduction)")
     if kfac is not None and schedule is None:
         raise ValueError("kfac preconditioning requires a schedule")
     if kfac is not None and loss_scale:
@@ -482,6 +544,8 @@ def make_train_step(
             shardings, batch_shardings_, None, None)
 
     def loss_fn(params, mb, rng):
+        if objective == "causal_lm":  # no dropout: rng unused
+            return _apply_causal_lm_loss(model, {"params": params}, mb)
         loss, acc, _ = _apply_pretraining_loss(
             model, {"params": params}, mb, rng,
             next_sentence, max_pred_per_seq)
@@ -640,7 +704,7 @@ def make_train_step(
                      else global_norm(grads))
             metrics = {
                 "loss": jnp.mean(losses),
-                "mlm_accuracy": jnp.mean(accs),
+                **_aux_metrics(accs),
                 "grad_norm": gnorm,
                 # Failure sentinel (telemetry/sentinels.py): one scalar the host
                 # can fetch for free alongside the loss. isfinite(sum) catches a
@@ -652,7 +716,7 @@ def make_train_step(
                 # pops it on the sync cadence (never an extra device fetch) and
                 # reports padding_efficiency / real-token throughput; with
                 # sequence packing this approaches the full batch token budget.
-                "real_tokens": jnp.sum(batch["input_mask"]).astype(jnp.float32),
+                "real_tokens": _real_tokens(batch),
             }
             if loss_scale:
                 metrics["loss_scale"] = scale

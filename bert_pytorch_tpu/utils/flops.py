@@ -108,6 +108,41 @@ def bert_finetune_flops_per_seq(config, seq_len: int, head_outputs: int = 2,
     return 3.0 * (bert_encoder_flops_per_seq(config, seq_len) + head)
 
 
+def nemotron_h_forward_flops_per_token(config, seq_len: int) -> dict:
+    """Forward matmul FLOPs per token of a ``nemotron_h`` model on THIS chip,
+    by part (a matmul of (m, k) x (k, n) costs 2mkn): ``ssm`` (projections and
+    the chunked scan's four products at chunk Q: 2QGN + 2Q inner + 4N inner),
+    ``attention`` (projections and the causal half of the two S x S products),
+    ``experts`` (router, shared expert, and the routed experts by the EXPECTED
+    top_k x held / all of the tokens), ``head``. Embedding lookup, norms,
+    convolution, activations and the optimizer are left out."""
+    h = config.hidden_size
+    kinds = config.hybrid_override_pattern
+    inner, conv_dim = config.mamba_inner, config.mamba_conv_dim
+    q, n, g = config.chunk_size, config.ssm_state_size, config.n_groups
+    ssm = (2 * h * (inner + conv_dim + config.mamba_num_heads) + 2 * inner * h
+           + 2 * q * g * n + 2 * q * inner + 4 * n * inner)
+    heads, kv, hd = (config.num_attention_heads, config.num_key_value_heads,
+                     config.head_dim)
+    attention = (4 * h * heads * hd + 4 * h * kv * hd
+                 + 2 * seq_len * heads * hd)  # 2 products x half the square
+    expected = (config.num_experts_per_tok * config.n_routed_experts
+                / config.router_experts)
+    experts = (2 * h * config.router_experts
+               + 4 * h * config.moe_shared_expert_intermediate_size
+               + expected * 4 * h * config.moe_intermediate_size)
+    return {"ssm": 1.0 * kinds.count("M") * ssm,
+            "attention": 1.0 * kinds.count("*") * attention,
+            "experts": 1.0 * kinds.count("E") * experts,
+            "head": 2.0 * h * config.vocab_size}
+
+
+def nemotron_h_train_flops_per_seq(config, seq_len: int) -> float:
+    """Training (3x forward) matmul FLOPs of one row of ``seq_len`` tokens."""
+    return 3.0 * seq_len * sum(
+        nemotron_h_forward_flops_per_token(config, seq_len).values())
+
+
 def mfu(seq_per_sec_per_chip: float, flops_per_seq: float,
         device_kind: str) -> Optional[float]:
     """Fraction of the chip's peak used by model FLOPs; None ("not

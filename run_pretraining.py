@@ -30,9 +30,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from bert_pytorch_tpu import optim, pretrain, telemetry
-from bert_pytorch_tpu.config import BertConfig, parse_args_with_config_file, require_args
+from bert_pytorch_tpu.config import (load_model_config,
+                                     parse_args_with_config_file, require_args)
 from bert_pytorch_tpu.data import DataLoader, DistributedSampler, ShardedPretrainingDataset
-from bert_pytorch_tpu.models import BertForPreTraining
+from bert_pytorch_tpu.models import BertForPreTraining, build_pretraining_model
 from bert_pytorch_tpu.ops.attention import resolve_backend
 from bert_pytorch_tpu.ops.pallas.common import device_report
 from bert_pytorch_tpu.ops.remat import kept_residual_bytes
@@ -257,7 +258,16 @@ def parse_arguments(argv=None) -> argparse.Namespace:
     parser.add_argument("--optimizer", type=str, default="lamb",
                         choices=["lamb", "adamw"])
     parser.add_argument("--weight_decay", type=float, default=0.01)
-    parser.add_argument("--max_grad_norm", type=float, default=1.0)
+    parser.add_argument("--max_grad_norm", type=float, default=1.0,
+                        help="global-norm gradient clipping of lamb; of adamw "
+                             "only with --adamw_clip")
+    parser.add_argument("--adamw_clip", action="store_true",
+                        help="adamw clips at --max_grad_norm too (the causal "
+                             "recipe; off: adamw as it always ran here)")
+    parser.add_argument("--adam_beta2", type=float, default=0.999,
+                        help="second-moment decay of adamw")
+    parser.add_argument("--adam_eps", type=float, default=1e-6,
+                        help="epsilon of adamw")
     # K-FAC (SURVEY §2.2)
     parser.add_argument("--kfac", action="store_true")
     parser.add_argument("--kfac_stat_decay", type=float, default=0.95)
@@ -527,17 +537,27 @@ def _kept_across_remat(model, config, micro_batch, seq) -> str:
 def prepare_model(args, mesh):
     """Model config + auto-resume discovery (reference prepare_model,
     run_pretraining.py:233-274)."""
-    config = BertConfig.from_json_file(args.model_config_file)
+    # The family (and with it the model and the objective) comes from the
+    # file's ``model_type``: none is BERT, ``nemotron_h`` the hybrid decoder.
+    config = load_model_config(args.model_config_file)
     if config.vocab_size % 8 != 0:  # MXU-friendly padding (reference :237)
         config.vocab_size += 8 - (config.vocab_size % 8)
 
-    model = BertForPreTraining(
+    model = build_pretraining_model(
         config,
         dtype={"bfloat16": jnp.bfloat16, "float16": jnp.float16,
                "float32": jnp.float32}[args.dtype],
         remat=args.remat or ("full" if args.checkpoint_activations else "none"),
         attention_backend=args.attention_backend,
     )
+    args.objective = getattr(model, "objective", "mlm")
+    if args.objective == "causal_lm" and (
+            args.kfac or args.pack_sequences or args.val_input_dir
+            or args.mesh_spec.pipe > 1 or args.overlap_grad_reduce):
+        raise ValueError(
+            "the causal_lm objective trains through the plain step: no "
+            "--kfac, --pack_sequences, --val_input_dir, pipe axis or "
+            "--overlap_grad_reduce")
 
     # Newest VERIFIED checkpoint: the walk-back verifies each retained
     # checkpoint's integrity manifest and skips corrupt/unreadable files
@@ -604,8 +624,10 @@ def prepare_optimizer(args, params_example=None):
             schedule, weight_decay=args.weight_decay,
             weight_decay_mask=mask, max_grad_norm=args.max_grad_norm)
     else:
-        tx = optim.adamw(schedule, weight_decay=args.weight_decay,
-                         weight_decay_mask=mask)
+        tx = optim.adamw(
+            schedule, b2=args.adam_beta2, eps=args.adam_eps,
+            weight_decay=args.weight_decay, weight_decay_mask=mask,
+            max_grad_norm=args.max_grad_norm if args.adamw_clip else None)
     if args.dtype == "float16":
         # Reference-parity AMP: fp16 activations + dynamic loss scaling
         # (GradScaler, run_pretraining.py:314-318); scaler state rides in
@@ -629,6 +651,29 @@ def prepare_dataset(args, config, checkpoint):
             str(p) for p in Path(args.input_dir).rglob("*.hdf5")
             if p.is_file())
 
+    # Data-path resilience (docs/fault_tolerance.md): retried shard IO,
+    # startup skip-vs-abort policy, fault records into the telemetry JSONL.
+    resilience = dict(
+        read_retries=args.data_read_retries,
+        retry_base_delay_s=args.data_retry_base_s,
+        shard_error_policy=args.shard_error_policy,
+        on_fault=args.telemetry_sink.write_record)
+    if args.objective == "causal_lm":
+        # Rows of token ids, every row full: nothing to mask, nothing to pack.
+        from bert_pytorch_tpu.data import TokenRowsDataset
+        dataset = TokenRowsDataset(input_files, **resilience)
+        args.packed, args.pack_k = False, 1
+        sampler = DistributedSampler(
+            dataset, num_replicas=jax.process_count(),
+            rank=jax.process_index())
+        if checkpoint is not None and "sampler" in checkpoint:
+            sampler.load_state_dict(checkpoint["sampler"])
+        loader = DataLoader(dataset, sampler,
+                            batch_size=args.host_batch_per_step,
+                            drop_last=True, num_workers=args.num_workers)
+        logger.info(f"Rows of token ids in dataset: {len(dataset)}")
+        return loader, sampler, None
+
     mask_token_id = getattr(config, "mask_token_id", None)
     vocab_file = getattr(config, "vocab_file", None)
     if mask_token_id is None and vocab_file and os.path.exists(vocab_file):
@@ -648,13 +693,6 @@ def prepare_dataset(args, config, checkpoint):
         logger.info("No vocab_file/mask_token_id in model config; "
                     f"using mask_token_id={mask_token_id}")
 
-    # Data-path resilience (docs/fault_tolerance.md): retried shard IO,
-    # startup skip-vs-abort policy, fault records into the telemetry JSONL.
-    resilience = dict(
-        read_retries=args.data_read_retries,
-        retry_base_delay_s=args.data_retry_base_s,
-        shard_error_policy=args.shard_error_policy,
-        on_fault=args.telemetry_sink.write_record)
     dataset = ShardedPretrainingDataset(
         input_files, int(mask_token_id), args.max_predictions_per_seq,
         args.masked_token_fraction, vocab_size=int(config.vocab_size),
@@ -719,8 +757,15 @@ def main(args) -> dict:
     loader, sampler, val_loader = prepare_dataset(args, config, checkpoint)
 
     rules = logical_axis_rules(args.mesh_spec)
-    seq_len = config.max_position_embeddings
-    sample = (jnp.zeros((1, seq_len), jnp.int32),) * 3
+    causal_lm = args.objective == "causal_lm"
+    if causal_lm:
+        # No position table: the rows' own length is the sequence length, and
+        # the parameters do not depend on it (a short sample initializes).
+        seq_len = int(loader.dataset[0]["input_ids"].shape[-1])
+        sample = (jnp.zeros((1, config.chunk_size), jnp.int32),)
+    else:
+        seq_len = config.max_position_embeddings
+        sample = (jnp.zeros((1, seq_len), jnp.int32),) * 3
     # Packed rows: per-sequence NSP labels [B, K] + the packing arrays;
     # max_predictions_per_seq stays a per-SEQUENCE budget, so the per-ROW
     # MLM gather cap scales by the pack limit.
@@ -741,6 +786,8 @@ def main(args) -> dict:
                   "next_sentence_labels": 3 if packed else 2}
     if packed:
         batch_spec.update({"sequence_ids": 3, "cls_positions": 3})
+    if causal_lm:
+        batch_spec = {"input_ids": 3}
     with mesh:
         fp16 = args.dtype == "float16"
         shardings = pretrain.state_shardings(mesh, model, rules, sample,
@@ -875,7 +922,7 @@ def main(args) -> dict:
         else:
             train_step = pretrain.make_train_step(
                 model, tx, schedule=schedule,
-                next_sentence=bool(config.next_sentence),
+                next_sentence=bool(getattr(config, "next_sentence", False)),
                 shardings=shardings, batch_shardings_=b_shardings,
                 max_pred_per_seq=eff_max_pred,
                 kfac=kfac_obj, kfac_shardings=kfac_shardings,
@@ -895,14 +942,20 @@ def main(args) -> dict:
         # refreshed once the DATA sequence length is known (phase-1 data is
         # 128 tokens while max_position_embeddings stays 512).
         from bert_pytorch_tpu.utils import flops as flops_util
+
+        def flops_per_seq(seq):
+            if causal_lm:
+                return flops_util.nemotron_h_train_flops_per_seq(config, seq)
+            return flops_util.bert_train_flops_per_seq(
+                config, seq, eff_max_pred,
+                next_sentence=bool(config.next_sentence))
+
         tele = telemetry.from_args(
             args,
             sink=args.telemetry_sink,
             is_primary=is_main_process(),
             seq_per_step=args.global_batch_size,
-            flops_per_seq=flops_util.bert_train_flops_per_seq(
-                config, seq_len, eff_max_pred,
-                next_sentence=bool(config.next_sentence)),
+            flops_per_seq=flops_per_seq(seq_len),
             # Padding-aware accounting: the step's token budget; the train
             # step's real_tokens metric divides out the pads
             # (padding_efficiency in the window records).
@@ -1078,17 +1131,14 @@ def main(args) -> dict:
                     if data_seq_len is None:
                         data_seq_len = int(batch["input_ids"].shape[-1])
                         placement["batch_devices"] = _devices_holding(batch)
-                        logger.info(_kept_across_remat(
-                            model, config, args.local_batch_size,
-                            data_seq_len))
+                        if not causal_lm:
+                            logger.info(_kept_across_remat(
+                                model, config, args.local_batch_size,
+                                data_seq_len))
                         if data_seq_len != seq_len:
                             # MFU must use the DATA shape, not the model cap.
-                            from bert_pytorch_tpu.utils import flops as _fl
-                            tele.timer.flops_per_seq = (
-                                _fl.bert_train_flops_per_seq(
-                                    config, data_seq_len,
-                                    eff_max_pred,
-                                    next_sentence=bool(config.next_sentence)))
+                            tele.timer.flops_per_seq = flops_per_seq(
+                                data_seq_len)
                             tele.timer.tokens_per_step = (
                                 args.global_batch_size * data_seq_len)
                     if step_in_run > 1:  # skip step-0 compile in throughput
@@ -1144,7 +1194,11 @@ def main(args) -> dict:
                                     elapsed, 1e-9),
                                 mlm_accuracy=last_metrics.get(
                                     "mlm_accuracy", 0.0),
-                                grad_norm=last_metrics.get("grad_norm", 0.0))
+                                grad_norm=last_metrics.get("grad_norm", 0.0),
+                                # the expert layers' routing counters
+                                # (causal_lm objective; pretrain._aux_metrics)
+                                **{k: v for k, v in last_metrics.items()
+                                   if k.startswith("moe_")})
 
                     if (eval_step is not None
                             and global_step % args.num_steps_per_eval == 0):
@@ -1231,10 +1285,7 @@ def main(args) -> dict:
             from bert_pytorch_tpu.utils import flops as flops_util
             train_mfu = flops_util.mfu(
                 seq_per_sec / max(jax.device_count(), 1),
-                flops_util.bert_train_flops_per_seq(
-                    config, data_seq_len or seq_len,
-                    eff_max_pred,
-                    next_sentence=bool(config.next_sentence)),
+                flops_per_seq(data_seq_len or seq_len),
                 jax.devices()[0].device_kind)
             if train_mfu is not None:
                 logger.info(f"training_mfu = {train_mfu:.4f}")

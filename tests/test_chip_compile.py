@@ -120,6 +120,24 @@ def test_flash_attention_compiles(chip, variant, seq):
     _assert_kernel(_compile(fn, chip, *_qkv(batch, seq), *extra), *names)
 
 
+def test_causal_flash_attention_compiles_at_8192(chip):
+    """The causal flag at the nemotron_h cell's geometry: one row of 8192
+    tokens, 32 query heads of 128 on 2 key-value heads (repeated by the
+    wrapper), forward and both backward kernels; the loops' dynamic bounds
+    and the whole-sequence K/V blocks have to pass Mosaic and fit VMEM."""
+    from bert_pytorch_tpu.ops.attention import dot_product_attention
+
+    def loss(q, k, v):
+        return jnp.sum(dot_product_attention(
+            q, k, v, backend="pallas", causal=True).astype(jnp.float32))
+
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1, 2)), chip,
+        ((1, 8192, 32, 128), jnp.bfloat16), ((1, 8192, 2, 128), jnp.bfloat16),
+        ((1, 8192, 2, 128), jnp.bfloat16))
+    _assert_kernel(compiled, "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+
+
 # -- the serving kernels ----------------------------------------------------
 
 @pytest.mark.parametrize("seq", [32, 128, 512])
