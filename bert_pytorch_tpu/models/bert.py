@@ -32,6 +32,7 @@ from bert_pytorch_tpu.config import BertConfig
 from bert_pytorch_tpu import ops
 from bert_pytorch_tpu.ops import quant as quant_ops
 from bert_pytorch_tpu.ops.activations import ACT2FN
+from bert_pytorch_tpu.ops.dropout import Dropout
 from bert_pytorch_tpu.ops.remat import remat_policy
 
 Array = jnp.ndarray
@@ -226,7 +227,7 @@ class BertEmbeddings(nn.Module):
         self.layer_norm = LayerNorm(
             epsilon=cfg.layer_norm_eps, dtype=self.dtype, name="layer_norm"
         )
-        self.dropout = nn.Dropout(rate=cfg.hidden_dropout_prob)
+        self.dropout = Dropout(rate=cfg.hidden_dropout_prob)
 
     def __call__(
         self,
@@ -339,7 +340,7 @@ class BertSelfAttention(nn.Module):
         )(context)
         if self.kfac_tap:
             out = _kfac_g_tap(self, "output__attn_ctx", out)
-        out = nn.Dropout(rate=cfg.hidden_dropout_prob)(
+        out = Dropout(rate=cfg.hidden_dropout_prob)(
             out, deterministic=deterministic
         )
         return LayerNorm(
@@ -394,7 +395,7 @@ class BertLayer(nn.Module):
         )(intermediate)
         if self.kfac_tap:
             out = _kfac_g_tap(self, "output__mlp_in", out)
-        out = nn.Dropout(rate=cfg.hidden_dropout_prob)(
+        out = Dropout(rate=cfg.hidden_dropout_prob)(
             out, deterministic=deterministic
         )
         out = LayerNorm(
@@ -799,7 +800,7 @@ class _ClassifierHead(nn.Module):
 
     @nn.compact
     def __call__(self, x: Array, deterministic: bool = True) -> Array:
-        x = nn.Dropout(rate=self.dropout_rate)(x, deterministic=deterministic)
+        x = Dropout(rate=self.dropout_rate)(x, deterministic=deterministic)
         # Output layers skip int8 (ops/quant.py EXCLUDE_MODULES): a
         # [hidden, num_labels] kernel saves no bytes worth pre-softmax
         # quantization noise; int8 engines store it bf16 instead.

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from bert_pytorch_tpu.ops.dropout import keep_mask
 from bert_pytorch_tpu.ops.remat import KEEP_MASK
 
 
@@ -254,8 +255,7 @@ def _attention_core(q, k, v, bias, dropout_rng, dropout_rate, deterministic,
             # (ops/remat.py) at one byte an element, and the backward pass
             # does not draw the random words a second time.
             keep = checkpoint_name(
-                jax.random.bernoulli(
-                    dropout_rng, 1.0 - dropout_rate, probs.shape),
+                keep_mask(dropout_rng, 1.0 - dropout_rate, probs.shape),
                 KEEP_MASK)
             probs = probs * keep.astype(probs.dtype) / (1.0 - dropout_rate)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)

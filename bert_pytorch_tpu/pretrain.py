@@ -300,9 +300,10 @@ def _make_overlap_step_fn(model, tx, mesh, schedule, next_sentence,
     valid-token count (a psum of label counts — no gradient flows through
     it) before the backward, so per-shard grads psum to exactly the
     global-mean gradient; bucketed == unbucketed to fp32 roundoff (the
-    parity test pins 1e-6). Dropout draws fold in the shard index — valid
-    streams, but not bit-identical to the unbucketed path's (the same
-    caveat as --rng_impl rbg).
+    parity test pins 1e-6). Dropout: one stream per batch shard, by the
+    rule of ops/dropout.py; this region is already manual over the batch
+    axes, so the shard's index is folded into the key once, here, and
+    ``keep_mask`` draws plainly inside.
     """
     from jax.sharding import PartitionSpec as P  # noqa: F811 (local alias)
 

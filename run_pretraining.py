@@ -34,6 +34,7 @@ from bert_pytorch_tpu.config import (load_model_config,
                                      parse_args_with_config_file, require_args)
 from bert_pytorch_tpu.data import DataLoader, DistributedSampler, ShardedPretrainingDataset
 from bert_pytorch_tpu.models import BertForPreTraining, build_pretraining_model
+from bert_pytorch_tpu.ops import dropout
 from bert_pytorch_tpu.ops.attention import resolve_backend
 from bert_pytorch_tpu.ops.pallas.common import device_report
 from bert_pytorch_tpu.ops.remat import kept_residual_bytes
@@ -253,7 +254,9 @@ def parse_arguments(argv=None) -> argparse.Namespace:
                              "random generator (~16%% faster end-to-end than "
                              "threefry, which synthesizes every mask bit in "
                              "ALU ops); threefry2x32 gives JAX's default "
-                             "cross-platform reproducible streams")
+                             "cross-platform reproducible streams. Under a "
+                             "mesh each shard of the batch draws its own "
+                             "stream with either (ops/dropout.py)")
     # optimizer
     parser.add_argument("--optimizer", type=str, default="lamb",
                         choices=["lamb", "adamw"])
@@ -904,6 +907,7 @@ def main(args) -> dict:
         stats_phase = int(jax.device_get(
             optim.opt_step_count(state.opt_state)))
 
+        dropout.forget_draws()  # draw_shards() below speaks of this step
         if args.mesh_spec.pipe > 1:
             if args.accumulation_steps < mesh.shape[AXIS_PIPE]:
                 raise ValueError(
@@ -1042,6 +1046,7 @@ def main(args) -> dict:
         # The DATA sequence length (what the FLOP/MFU accounting must use;
         # phase-1 data is 128 tokens while max_position_embeddings stays 512).
         data_seq_len = None
+        draw_shards_logged = False
         # Position of the last TRAINED sample this epoch. The sampler's live
         # ``index`` runs ahead of training by the loader queue plus the
         # device_prefetch depth (the reference's checkpoints have the same
@@ -1141,6 +1146,12 @@ def main(args) -> dict:
                                 data_seq_len)
                             tele.timer.tokens_per_step = (
                                 args.global_batch_size * data_seq_len)
+                    if not draw_shards_logged and dropout.draw_shards():
+                        # Known once the dropout-on step has been traced.
+                        draw_shards_logged = True
+                        logger.info(
+                            f"dropout masks drawn in {dropout.draw_shards()} "
+                            "shard(s) of the batch (ops/dropout.py)")
                     if step_in_run > 1:  # skip step-0 compile in throughput
                         samples_seen += args.global_batch_size
                     if step_in_run == 1:
@@ -1319,6 +1330,10 @@ def main(args) -> dict:
                 # Topology label: telemetry-report groups/labels loss and
                 # step-time trajectories per mesh product with this.
                 "mesh_spec": args.mesh_spec.canonical(),
+                # In how many shards of the batch the step draws a dropout
+                # mask (ops/dropout.py): the data-parallel size when every
+                # chip draws its own rows, 1 on one chip, 0 without dropout.
+                "dropout_draw_shards": dropout.draw_shards(),
                 # What the run ran on (platform, device_kind, device_count,
                 # kernels compiled|interpreted): a number in this artifact
                 # is a device number only if this says "tpu".

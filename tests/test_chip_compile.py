@@ -33,15 +33,20 @@ HBM_BYTES = 16 * 1024 ** 3  # one v5e chip
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """One described v5e chip."""
+def topo():
+    """A described host of four v5e chips (2x2)."""
     from jax.experimental import topologies
 
     try:
-        topo = topologies.get_topology_desc(
+        return topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2")
     except Exception as exc:  # no libtpu / no such topology here
         pytest.skip(f"cannot describe a TPU topology: {exc}")
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """One described v5e chip."""
     return topo.devices[0]
 
 
@@ -174,29 +179,16 @@ def test_layer_norm_compiles(chip, direction):
 
 # -- the whole phase-2 train step ---------------------------------------------
 
-def test_phase2_train_step_fits_one_chip(chip):
-    """``pretrain.make_train_step`` as run_pretraining.py builds it for the
-    phase-2 recipe — BERT-large, seq 512, 80 predictions, local batch 28,
-    ``remat='dots'``, the fused kernel — compiled for one described chip:
-    the kernel is in the program, and arguments plus temporaries stay under
-    the chip's 16 GB. ``memory_analysis`` counts this one program, not what
-    else the process keeps on the device."""
-    from bert_pytorch_tpu import optim, pretrain
-    from bert_pytorch_tpu.config import BertConfig
-    from bert_pytorch_tpu.models import BertForPreTraining
+def _compile_train_step(model, tx, devices, accum, rows, seq, max_pred,
+                        schedule=None):
+    """``pretrain.make_train_step`` as run_pretraining.py builds it, under a
+    ``dp`` mesh of the described ``devices``, lowered for ``accum``
+    micro-batches of ``rows`` sequences and compiled."""
+    from bert_pytorch_tpu import pretrain
     from bert_pytorch_tpu.parallel import (MeshConfig, create_mesh,
                                            logical_axis_rules)
 
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    config = BertConfig.from_json_file(
-        os.path.join(repo, "configs", "bert_large_uncased_config.json"))
-    config.vocab_size += -config.vocab_size % 8
-    seq, batch, max_pred = 512, TRAIN_SHAPES[512], 80
-    model = BertForPreTraining(config, dtype=jnp.bfloat16, remat="dots",
-                               attention_backend="pallas")
-    schedule = optim.warmup_poly_schedule(4e-3, 0.128, 1563)
-    tx = optim.lamb(schedule, weight_decay_mask=optim.no_decay_mask)
-    mesh = create_mesh(MeshConfig(data=-1), devices=[chip])
+    mesh = create_mesh(MeshConfig(data=-1), devices=list(devices))
     sample = (jnp.zeros((1, seq), jnp.int32),) * 3
     batch_spec = {"input_ids": 3, "segment_ids": 3, "input_mask": 3,
                   "masked_lm_labels": 3, "next_sentence_labels": 2}
@@ -210,11 +202,35 @@ def test_phase2_train_step_fits_one_chip(chip):
         step = pretrain.make_train_step(
             model, tx, schedule=schedule, next_sentence=True,
             shardings=shardings, batch_shardings_=b_shardings,
-            max_pred_per_seq=max_pred)
-        mb = {key: jax.ShapeDtypeStruct(
-            (1, batch) + (seq,) * (ndim - 2), np.int32)
+            max_pred_per_seq=max_pred, mesh=mesh)
+        batch = {key: jax.ShapeDtypeStruct(
+            (accum, rows) + (seq,) * (ndim - 2), np.int32)
             for key, ndim in batch_spec.items()}
-        compiled = step.lower(state, mb).compile()
+        return step.lower(state, batch).compile()
+
+
+def test_phase2_train_step_fits_one_chip(chip):
+    """``pretrain.make_train_step`` as run_pretraining.py builds it for the
+    phase-2 recipe — BERT-large, seq 512, 80 predictions, local batch 28,
+    ``remat='dots'``, the fused kernel — compiled for one described chip:
+    the kernel is in the program, and arguments plus temporaries stay under
+    the chip's 16 GB. ``memory_analysis`` counts this one program, not what
+    else the process keeps on the device."""
+    from bert_pytorch_tpu import optim
+    from bert_pytorch_tpu.config import BertConfig
+    from bert_pytorch_tpu.models import BertForPreTraining
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    config = BertConfig.from_json_file(
+        os.path.join(repo, "configs", "bert_large_uncased_config.json"))
+    config.vocab_size += -config.vocab_size % 8
+    model = BertForPreTraining(config, dtype=jnp.bfloat16, remat="dots",
+                               attention_backend="pallas")
+    schedule = optim.warmup_poly_schedule(4e-3, 0.128, 1563)
+    tx = optim.lamb(schedule, weight_decay_mask=optim.no_decay_mask)
+    compiled = _compile_train_step(
+        model, tx, [chip], accum=1, rows=TRAIN_SHAPES[512], seq=512,
+        max_pred=80, schedule=schedule)
     _assert_kernel(compiled, "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
     mem = compiled.memory_analysis()
     total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
@@ -223,3 +239,31 @@ def test_phase2_train_step_fits_one_chip(chip):
         f"({mem.argument_size_in_bytes / 2**30:.2f} arguments + "
         f"{mem.temp_size_in_bytes / 2**30:.2f} temporaries) of a "
         f"{HBM_BYTES / 2**30:.0f} GiB chip")
+
+
+# -- the data-parallel step draws each chip's dropout masks on that chip ------
+
+def test_dp4_step_draws_local_masks(topo):
+    """A small ``make_train_step`` with ``rbg`` dropout under ``dp=4`` on the
+    described 2x2, read after the TPU compiler's SPMD partitioner: every
+    ``rng-bit-generator`` makes ONE chip's share of a mask (ops/dropout.py).
+    The partitioner does not split that instruction, so a mask asked for at
+    the global batch size would show here at four times the size."""
+    from bert_pytorch_tpu import optim
+    from bert_pytorch_tpu.config import BertConfig
+    from bert_pytorch_tpu.models import BertForPreTraining
+
+    seq, rows, heads, hidden = 128, 8, 2, 128  # rows a chip
+    config = BertConfig(
+        vocab_size=512, hidden_size=hidden, num_hidden_layers=2,
+        num_attention_heads=heads, intermediate_size=256,
+        max_position_embeddings=seq)
+    model = BertForPreTraining(config, dtype=jnp.bfloat16, remat="dots",
+                               attention_backend="xla")
+    text = _compile_train_step(
+        model, optim.lamb(1e-3), topo.devices, accum=2, rows=rows * 4,
+        seq=seq, max_pred=20).as_text()
+    drawn = {tuple(int(d) for d in dims.split(","))
+             for dims in re.findall(
+                 r"u32\[([0-9,]+)\]\S* rng-bit-generator\(", text)}
+    assert drawn == {(rows, heads, seq, seq), (rows, seq, hidden)}, drawn

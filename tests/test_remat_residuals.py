@@ -50,7 +50,7 @@ def _forward_residuals(jaxpr):
     return [v.aval for v in scan.outvars[scan.params["num_carry"]:]]
 
 
-def _grad_program(remat_value, path, with_grads=True):
+def _grad_program(remat_value, path, with_grads=True, batch=BATCH):
     backend, deterministic, _, _ = PATHS[path]
     cfg = BertConfig(
         vocab_size=64, hidden_size=HIDDEN, num_hidden_layers=LAYERS,
@@ -59,8 +59,8 @@ def _grad_program(remat_value, path, with_grads=True):
         attention_probs_dropout_prob=0.1)
     encoder = bert.BertEncoder(cfg, dtype=jnp.float32, remat=remat_value,
                                attention_backend=backend)
-    hidden = jax.random.normal(jax.random.PRNGKey(1), (BATCH, SEQ, HIDDEN))
-    mask = jnp.ones((BATCH, SEQ), jnp.int32).at[:, SEQ - 3:].set(0)
+    hidden = jax.random.normal(jax.random.PRNGKey(1), (batch, SEQ, HIDDEN))
+    mask = jnp.ones((batch, SEQ), jnp.int32).at[:, SEQ - 3:].set(0)
     bias = make_attention_bias(mask)
     params = nn.unbox(encoder.init(
         {"params": jax.random.PRNGKey(0), "dropout": jax.random.PRNGKey(2)},
@@ -106,6 +106,28 @@ def test_dots_keeps_the_named_residuals(path, monkeypatch):
     # plain 'dots' at the parent commit too).
     _assert_grads_close(grads, plain_grads, rtol=1e-6)
     _assert_grads_close(grads, _grad_program("none", path)[0], rtol=1e-5)
+
+
+def test_dots_keeps_the_mask_drawn_per_shard(monkeypatch):
+    """Under ``dp=4`` the mask comes out of a ``shard_map`` (ops/dropout.py)
+    and is named outside it: still drawn once, in the forward scan, and the
+    gradients are those of ``remat='none'`` from the same draws."""
+    from bert_pytorch_tpu.parallel import MeshConfig, create_mesh
+
+    with create_mesh(MeshConfig(data=4), devices=jax.devices()[:4]):
+        grads, program = _grad_program("dots", "xla", batch=8)
+        ops = _ops(program)
+        assert ops["random_bits"] == 1 and ops["shard_map"]
+        masks = [a for a in _forward_residuals(program)
+                 if a.dtype == jnp.bool_]
+        assert [a.shape for a in masks] == [(LAYERS, 8, HEADS, SEQ, SEQ)]
+
+        monkeypatch.setattr(bert, "remat_policy", lambda _: PLAIN_DOTS)
+        plain_ops = _ops(_grad_program("dots", "xla", False, batch=8)[1])
+        assert plain_ops["random_bits"] == 2
+        monkeypatch.undo()
+        _assert_grads_close(
+            grads, _grad_program("none", "xla", batch=8)[0], rtol=1e-5)
 
 
 def test_the_kept_mask_is_one_byte_an_element():
