@@ -226,8 +226,6 @@ def _config_digest():
 
     key = repr((PHASE, KFAC, LONG_SEQ, LOCAL_BATCH, REMAT,
                 RNG_IMPL, ATTN, N_DEVICES,
-                # kernel-tuning env knobs also change the compiled program
-                os.environ.get("PALLAS_ATTN_BH_BLOCK", ""),
                 # kfac capture mode changes the train-step program; keep
                 # the digest stable for non-kfac configs
                 KFAC_CAPTURE if KFAC else ""))

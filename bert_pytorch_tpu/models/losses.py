@@ -31,54 +31,6 @@ def _xent_ignore(logits: jnp.ndarray, labels: jnp.ndarray, ignore_index: int):
     return jnp.sum(per_pos) / count
 
 
-def _xent_sums(logits: jnp.ndarray, labels: jnp.ndarray, ignore_index: int):
-    """(sum, count) building blocks of :func:`_xent_ignore`: the fp32 CE
-    SUM over non-ignored positions and how many there were. The bucketed
-    data-parallel path (pretrain.py overlap_grad_buckets) needs the sum
-    unnormalized so it can divide by the GLOBAL count before the local
-    backward — that is what makes per-shard gradients psum to exactly the
-    global-mean gradient regardless of how masked positions split across
-    shards."""
-    logits = logits.astype(jnp.float32)
-    valid = labels != ignore_index
-    safe_labels = jnp.where(valid, labels, 0)
-    per_pos = optax.softmax_cross_entropy_with_integer_labels(
-        logits, safe_labels)
-    per_pos = jnp.where(valid, per_pos, 0.0)
-    return jnp.sum(per_pos), jnp.sum(valid)
-
-
-def pretraining_loss_sums(
-    prediction_logits,
-    seq_relationship_logits,
-    masked_lm_labels,
-    next_sentence_labels=None,
-):
-    """Unnormalized pieces of :func:`pretraining_loss`:
-    ``(mlm_sum, mlm_count, nsp_sum, nsp_count, mlm_correct)`` — per-shard
-    sums the overlap path reduces with explicit per-bucket collectives.
-    ``pretraining_loss == mlm_sum/max(mlm_count,1) +
-    nsp_sum/max(nsp_count,1)`` and ``mlm_accuracy ==
-    mlm_correct/max(mlm_count,1)`` by construction (same masking, same
-    fp32 CE)."""
-    vocab = prediction_logits.shape[-1]
-    labels_flat = masked_lm_labels.reshape(-1)
-    with jax.named_scope("mlm_loss"):
-        mlm_sum, mlm_count = _xent_sums(
-            prediction_logits.reshape(-1, vocab), labels_flat, -1)
-        preds = jnp.argmax(prediction_logits, axis=-1).reshape(-1)
-        mlm_correct = jnp.sum((preds == labels_flat) & (labels_flat != -1))
-    if seq_relationship_logits is not None and next_sentence_labels is not None:
-        with jax.named_scope("nsp_loss"):
-            nsp_sum, nsp_count = _xent_sums(
-                seq_relationship_logits.reshape(-1, 2),
-                next_sentence_labels.reshape(-1), -1)
-    else:
-        nsp_sum = jnp.zeros((), jnp.float32)
-        nsp_count = jnp.zeros((), jnp.int32)
-    return mlm_sum, mlm_count, nsp_sum, nsp_count, mlm_correct
-
-
 def masked_lm_loss(prediction_logits, masked_lm_labels, ignore_index: int = -1):
     """CE over [B, S, V] logits with ignore_index (run_pretraining.py:64-69)."""
     vocab = prediction_logits.shape[-1]

@@ -13,9 +13,9 @@ is traced, with no flag:
     manual over the batch axes only, each shard's ``[B/n, ...]`` rows from
     ``fold_in(rng, shard index)``;
   - otherwise (one device, no mesh, inside a ``shard_map`` such as the
-    bucketed-overlap step or the pipeline's stages, a batch that does not
-    divide): plain ``jax.random.bernoulli`` on the key as given, so on one
-    device the masks and the program are what they were.
+    pipeline's stages, a batch that does not divide): plain
+    ``jax.random.bernoulli`` on the key as given, so on one device the
+    masks and the program are what they were.
 
 Either way the bits are independent Bernoulli(keep_prob); under a mesh the
 streams differ from the one-device streams (as ``rbg``'s already did).

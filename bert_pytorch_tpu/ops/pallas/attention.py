@@ -122,31 +122,10 @@ def _pick_bh_block(seq, bh):
     the load-bearing invariant is block agreement, documented on
     _pick_blocks.
 
-    Measured, BERT-large phase-2 shape (seq 512, one v5e): G=1 82.4,
-    G=4 84.0, G=8 84.25 seq/s; G=16 exhausts VMEM (tile footprint scales
-    with G x seq, hence the 4096 budget). At seq 128 G=16 is the best of
-    the sweep (314 -> 366 seq/s), though the XLA path still wins there and
-    stays the router default (ops/attention.py).
-
-    PALLAS_ATTN_BH_BLOCK overrides the target cap (not the divisibility
-    walk) so the capture sweep can probe past the conservative VMEM
-    heuristic at short sequence lengths — e.g. G=32 at seq 128, where the
-    4096 budget leaves half of VMEM unused. The env var is read at TRACE
-    time: changing it mid-process has no effect on shapes already
-    compiled, so sweeps must probe each value in a fresh subprocess (the
-    capture sweep does)."""
-    import os
-
-    env = os.environ.get("PALLAS_ATTN_BH_BLOCK")
-    if env:
-        try:
-            target = int(env)
-        except ValueError:
-            raise ValueError(
-                f"PALLAS_ATTN_BH_BLOCK must be an integer, got {env!r}"
-            ) from None
-    else:
-        target = min(16, max(1, 4096 // max(seq, 1)))
+    The cap is 16 pairs, fewer where the tiles of 16 would not fit VMEM
+    (the footprint scales with G x seq, hence the 4096 budget: 8 at seq
+    512); what the kernels cost in the phase-2 step is in PERF.md 5."""
+    target = min(16, max(1, 4096 // max(seq, 1)))
     g = 1
     while g * 2 <= target and bh % (g * 2) == 0:
         g *= 2

@@ -22,10 +22,9 @@ module lets serving PAY FOR THE MEASUREMENT ONCE and remember it:
   still holds, the PR-8 acceptance);
 * :func:`lookup` is the kernels' consult point: a cached winner wins,
   otherwise the caller falls back to the heuristic. Winners are read at
-  TRACE time (the same property as PALLAS_ATTN_BH_BLOCK): load them
-  BEFORE the first forward traces — the serve engine loads in
-  ``__init__``, before warmup — because already-compiled shapes never
-  re-read the registry.
+  TRACE time: load them BEFORE the first forward traces — the serve
+  engine loads in ``__init__``, before warmup — because already-compiled
+  shapes never re-read the registry.
 
 The registry is PROCESS-GLOBAL, not per-engine: an engine built with
 ``autotune="off"`` in a process where another engine (or a test)

@@ -22,9 +22,10 @@ Strategies (rule sets):
                 microbatches rotate through them on a GPipe schedule
                 (parallel/pipeline.py).
 These compose: a mesh may use several axes at once. The composition is
-first-class via ``MeshSpec`` (``--mesh dp=4,fsdp=2,pipe=2`` style): the
-legacy names above are aliases that lower onto specs, and any axis
-product's rules derive from one template (docs/parallelism.md).
+first-class via ``MeshSpec`` (``--mesh dp=4,fsdp=2,pipe=2``, the one way
+the command line names a mesh): any axis product's rules derive from one
+template, and the names above are the rule sets ``logical_axis_rules``
+also accepts by name (docs/parallelism.md).
 """
 
 from bert_pytorch_tpu.parallel.mesh import (

@@ -209,8 +209,7 @@ class MeshSpec:
     device mesh, logical-axis rules, and collective wiring are all
     DERIVED from this — any axis product is expressible, and the combos
     that cannot work are rejected by :meth:`validate` with the reason.
-    Legacy ``--parallel_strategy`` names lower onto specs via
-    :meth:`from_strategy`. ``data == -1`` means 'all remaining devices'.
+    ``data == -1`` means 'all remaining devices'.
     """
 
     data: int = -1
@@ -252,19 +251,6 @@ class MeshSpec:
         spec = MeshSpec(**sizes)
         spec.validate()
         return spec
-
-    @staticmethod
-    def from_strategy(strategy: str, *, data: int = -1, fsdp: int = 1,
-                      pipe: int = 1, seq: int = 1, model: int = 1,
-                      dcn_data: int = 1) -> "MeshSpec":
-        """Lower a legacy ``--parallel_strategy`` name plus the legacy
-        ``--mesh_*`` sizes onto a spec (rules stay byte-identical)."""
-        if strategy not in _STRATEGY_AXES:
-            raise MeshSpecError(
-                f"unknown strategy '{strategy}'; "
-                f"options: {sorted(_STRATEGY_AXES)}")
-        return MeshSpec(data=data, fsdp=fsdp, pipe=pipe, seq=seq,
-                        model=model, dcn_data=dcn_data)
 
     def canonical(self) -> str:
         """Round-trippable spec string; inactive axes are elided."""

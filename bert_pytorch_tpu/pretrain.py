@@ -33,8 +33,7 @@ from bert_pytorch_tpu.ops.grad_utils import global_norm
 from bert_pytorch_tpu.ops.remat import remat_policy
 from bert_pytorch_tpu.optim.transforms import (LossScaleState, OptState,
                                                opt_step_count)
-from bert_pytorch_tpu.parallel.mesh import (AXIS_DATA, AXIS_FSDP, AXIS_PIPE,
-                                            AXIS_SEQ)
+from bert_pytorch_tpu.parallel.mesh import AXIS_PIPE, AXIS_SEQ
 from bert_pytorch_tpu.parallel.sharding import params_shardings
 
 # Every ``jax.named_scope`` the train steps write where Flax gives no module
@@ -127,13 +126,17 @@ def _mlm_positions(labels, max_pred_per_seq):
     return labels, masked_positions
 
 
-def _apply_model(model, variables, mb, rng, max_pred_per_seq,
-                 mutable=False):
-    """Shared masked-position extraction + model apply: returns
-    ``((mlm_logits, nsp_logits), labels, mutated)`` where ``labels`` are
-    the (possibly position-gathered) MLM labels the loss must score
-    against. Factored out of :func:`_apply_pretraining_loss` so the
-    bucketed-overlap path (same apply, sum-form loss) cannot drift."""
+def _apply_pretraining_loss(model, variables, mb, rng, next_sentence,
+                            max_pred_per_seq, mutable=False):
+    """The one shared apply+loss(+accuracy) sequence behind every
+    pretraining loss path — the plain train-step loss, the fused-capture
+    tapped loss, and the K-FAC stats pass. One definition, so a loss or
+    signature change cannot silently diverge between them.
+
+    Returns (loss, acc, mutated); ``mutated`` is None unless ``mutable``
+    names collections. ``acc`` is always computed — XLA dead-code
+    eliminates it in consumers that drop it.
+    """
     labels, masked_positions = _mlm_positions(
         mb["masked_lm_labels"], max_pred_per_seq
     )
@@ -151,26 +154,7 @@ def _apply_model(model, variables, mb, rng, max_pred_per_seq,
         rngs={"dropout": rng},
         **({"mutable": mutable} if mutable else {}),
     )
-    if mutable:
-        logits, mutated = out
-    else:
-        logits, mutated = out, None
-    return logits, labels, mutated
-
-
-def _apply_pretraining_loss(model, variables, mb, rng, next_sentence,
-                            max_pred_per_seq, mutable=False):
-    """The one shared apply+loss(+accuracy) sequence behind every
-    pretraining loss path — the plain train-step loss, the fused-capture
-    tapped loss, and the K-FAC stats pass. One definition, so a loss or
-    signature change cannot silently diverge between them.
-
-    Returns (loss, acc, mutated); ``mutated`` is None unless ``mutable``
-    names collections. ``acc`` is always computed — XLA dead-code
-    eliminates it in consumers that drop it.
-    """
-    (mlm_logits, nsp_logits), labels, mutated = _apply_model(
-        model, variables, mb, rng, max_pred_per_seq, mutable=mutable)
+    (mlm_logits, nsp_logits), mutated = out if mutable else (out, None)
     loss = pretraining_loss(
         mlm_logits,
         nsp_logits if next_sentence else None,
@@ -284,138 +268,6 @@ def _jit_train_step(step_fn, shardings, batch_shardings_, kfac,
     )
 
 
-def _make_overlap_step_fn(model, tx, mesh, schedule, next_sentence,
-                          max_pred_per_seq, stats_every, stats_phase):
-    """Train step whose data-parallel gradient reduction is EXPLICIT and
-    bucketed for compute/communication overlap (parallel/overlap.py).
-
-    The microbatch backward runs per shard inside a ``shard_map`` over the
-    batch axes, producing LOCAL gradient sums; each availability bucket
-    (heads -> encoder -> embeddings) then gets its own ``lax.psum``, so
-    XLA's latency-hiding scheduler can run early buckets' collectives
-    under the remaining backward compute — the ZeRO/DDP overlap shape the
-    implicit one-shot reduction of plain jit cannot express.
-
-    Numerics: each microbatch's local SUM loss is divided by the GLOBAL
-    valid-token count (a psum of label counts — no gradient flows through
-    it) before the backward, so per-shard grads psum to exactly the
-    global-mean gradient; bucketed == unbucketed to fp32 roundoff (the
-    parity test pins 1e-6). Dropout: one stream per batch shard, by the
-    rule of ops/dropout.py; this region is already manual over the batch
-    axes, so the shard's index is folded into the key once, here, and
-    ``keep_mask`` draws plainly inside.
-    """
-    from jax.sharding import PartitionSpec as P  # noqa: F811 (local alias)
-
-    from bert_pytorch_tpu.models.losses import pretraining_loss_sums
-    from bert_pytorch_tpu.parallel.overlap import bucketed_psum
-    from bert_pytorch_tpu.parallel.pipeline import shard_map
-
-    axes = ("data", "fsdp")
-
-    def local_grads(params, batch, step_rng):
-        # Runs PER SHARD: ``batch`` is the local [A, b_local, ...] slice.
-        # Dropout decorrelates over BOTH batch axes — the batch shards
-        # over ('data','fsdp') even under dp rules (params replicated),
-        # so folding in only 'data' would hand every fsdp shard sharing a
-        # data index identical masks for different examples.
-        shard = (jax.lax.axis_index(AXIS_DATA) * mesh.shape[AXIS_FSDP]
-                 + jax.lax.axis_index(AXIS_FSDP))
-        rng0 = jax.random.fold_in(step_rng, shard)
-        zero_grads = jax.tree_util.tree_map(
-            lambda p: jnp.zeros(p.shape, jnp.float32), params)
-
-        def body(carry, mb):
-            grads_acc, rng = carry
-            rng, sub = jax.random.split(rng)
-            # Global per-microbatch normalizers, from labels alone (the
-            # position gather caps masked counts per row, so count AFTER
-            # it — exactly what the mean-form loss divides by).
-            gathered = _mlm_positions(
-                mb["masked_lm_labels"], max_pred_per_seq)[0]
-            c_mlm = jnp.maximum(
-                jax.lax.psum(jnp.sum(gathered != -1), axes), 1
-            ).astype(jnp.float32)
-            c_nsp = jnp.maximum(
-                jax.lax.psum(
-                    jnp.sum(mb["next_sentence_labels"] != -1), axes), 1
-            ).astype(jnp.float32) if next_sentence else jnp.float32(1)
-
-            def local_loss(p):
-                (mlm_logits, nsp_logits), labels, _ = _apply_model(
-                    model, {"params": p}, mb, sub, max_pred_per_seq)
-                mlm_sum, _, nsp_sum, _, correct = pretraining_loss_sums(
-                    mlm_logits, nsp_logits if next_sentence else None,
-                    labels,
-                    mb["next_sentence_labels"] if next_sentence else None)
-                loss = mlm_sum / c_mlm
-                if next_sentence:
-                    loss = loss + nsp_sum / c_nsp
-                return loss, (mlm_sum, nsp_sum, correct)
-
-            (_, aux), grads = jax.value_and_grad(
-                local_loss, has_aux=True)(params)
-            with jax.named_scope("grad_accumulate"):
-                grads_acc = jax.tree_util.tree_map(
-                    lambda a, g: a + g.astype(a.dtype), grads_acc, grads)
-            mlm_sum, nsp_sum, correct = aux
-            return (grads_acc, rng), (mlm_sum, nsp_sum, correct,
-                                      c_mlm, c_nsp)
-
-        (grads_acc, _), (mlm_sums, nsp_sums, corrects, c_mlms, c_nsps) = (
-            _scan_micro_batches(body, (zero_grads, rng0), batch))
-        # Metric sums are scalars-per-microbatch: one cheap psum for all.
-        g_mlm, g_nsp, g_correct = jax.lax.psum(
-            (mlm_sums, nsp_sums, corrects.astype(jnp.float32)), axes)
-        losses = g_mlm / c_mlms
-        if next_sentence:
-            losses = losses + g_nsp / c_nsps
-        accs = g_correct / c_mlms
-        # The overlap surface: availability-ordered per-bucket collectives.
-        grads = bucketed_psum(grads_acc, axes)
-        return grads, losses, accs
-
-    def step_fn(state: TrainState, batch: dict):
-        accum_steps = batch["input_ids"].shape[0]
-        step_rng, new_rng = jax.random.split(state.rng)
-        batch_specs = {
-            k: P(*([None, axes] + [None] * (v.ndim - 2)))
-            for k, v in batch.items()}
-        grads, losses, accs = shard_map(
-            local_grads, mesh=mesh, axis_names={AXIS_DATA, AXIS_FSDP},
-            in_specs=(P(), batch_specs, P()),
-            out_specs=(P(), P(), P()))(state.params, batch, step_rng)
-        with jax.named_scope("optimizer"):
-            grads = jax.tree_util.tree_map(lambda g: g / accum_steps, grads)
-            updates, opt_state = tx.update(grads, state.opt_state, state.params)
-            params = optax.apply_updates(state.params, updates)
-        with jax.named_scope("step_metrics"):
-            gnorm = global_norm(grads)
-            metrics = {
-                "loss": jnp.mean(losses),
-                "mlm_accuracy": jnp.mean(accs),
-                "grad_norm": gnorm,
-                # Same sentinel/padding contracts as make_train_step.
-                "finite": (jnp.isfinite(jnp.sum(losses))
-                           & jnp.isfinite(gnorm)).astype(jnp.float32),
-                "real_tokens": jnp.sum(batch["input_mask"]).astype(jnp.float32),
-            }
-            if schedule is not None:
-                metrics["learning_rate"] = schedule(
-                    opt_step_count(state.opt_state))
-            if stats_every:
-                from bert_pytorch_tpu.telemetry import model_stats
-
-                metrics["grad_health"] = model_stats.gated_grad_health(
-                    state.params, grads, updates,
-                    opt_step_count(state.opt_state), stats_every,
-                    phase=stats_phase)
-        return TrainState(
-            params=params, opt_state=opt_state, rng=new_rng), metrics
-
-    return step_fn
-
-
 def make_train_step(
     model,
     tx: optax.GradientTransformation,
@@ -434,7 +286,6 @@ def make_train_step(
     stats_every: int = 0,
     stats_phase: int = 0,
     mesh=None,
-    overlap_grad_buckets: bool = False,
 ):
     """Build the jitted train step.
 
@@ -482,13 +333,6 @@ def make_train_step(
     state's current scale before differentiating and the wrapper
     unscales, finite-checks, and skips/backs off.
 
-    ``overlap_grad_buckets=True`` (requires ``mesh``; data-parallel
-    first-order path only) replaces the implicit tree-wide gradient
-    reduction with explicit availability-ordered per-bucket psums so the
-    early buckets' collectives overlap the remaining backward
-    (:func:`_make_overlap_step_fn`; parallel/overlap.py). Exact to fp32
-    roundoff against this function's default path.
-
     ``stats_every > 0`` splices the in-jit grad-health block
     (telemetry/model_stats.py: per-layer-group grad/param norms and
     update:weight ratios) into ``metrics["grad_health"]``, lax.cond-gated
@@ -496,6 +340,10 @@ def make_train_step(
     ``stats_phase`` is the optimizer count at run start (resumed runs),
     aligning the due gate with the host's run-local sync cadence.
     TrainTelemetry.step_done pops and emits it.
+
+    ``mesh`` is accepted and not read: the step takes its layout from
+    ``shardings``. It stays only because ``benchmarks/rehearse/`` passes
+    it and a PR outside the benchmark may not edit those files.
     """
     # What the model is trained on: ``mlm`` (BERT: masked tokens and next
     # sentence) unless the model's family says otherwise
@@ -503,10 +351,10 @@ def make_train_step(
     objective = getattr(model, "objective", "mlm")
     if objective not in ("mlm", "causal_lm"):
         raise ValueError(f"unknown objective {objective!r}")
-    if objective == "causal_lm" and (kfac is not None or overlap_grad_buckets):
+    if objective == "causal_lm" and kfac is not None:
         raise ValueError(
             "the causal_lm objective runs the plain first-order step (no "
-            "K-FAC, no bucketed gradient reduction)")
+            "K-FAC)")
     if kfac is not None and schedule is None:
         raise ValueError("kfac preconditioning requires a schedule")
     if kfac is not None and loss_scale:
@@ -528,22 +376,6 @@ def make_train_step(
         raise ValueError(
             f"kfac_capture_microbatches must be first|all, got "
             f"{kfac_capture_microbatches!r}")
-    if overlap_grad_buckets:
-        if kfac is not None or loss_scale:
-            raise ValueError(
-                "overlap_grad_buckets composes with the plain first-order "
-                "dp path only (no K-FAC, no fp16 loss scaling)")
-        if mesh is None or shardings is None or batch_shardings_ is None:
-            raise ValueError(
-                "overlap_grad_buckets requires mesh + shardings (the "
-                "explicit per-bucket collectives are defined over the "
-                "mesh batch axes)")
-        return _jit_train_step(
-            _make_overlap_step_fn(
-                model, tx, mesh, schedule, next_sentence, max_pred_per_seq,
-                stats_every, stats_phase),
-            shardings, batch_shardings_, None, None)
-
     def loss_fn(params, mb, rng):
         if objective == "causal_lm":  # no dropout: rng unused
             return _apply_causal_lm_loss(model, {"params": params}, mb)

@@ -124,15 +124,6 @@ def parse_arguments(argv=None) -> argparse.Namespace:
     # flag shared by every runner
     from bert_pytorch_tpu.data import device_prefetch as dp_cli
     dp_cli.add_cli_args(parser)
-    # overlapped data-parallel gradient collectives (parallel/overlap.py):
-    # bucket the backward's psum so early layer groups' all-reduces hide
-    # under the remaining backward compute (ZeRO lineage, PAPERS.md)
-    parser.add_argument("--overlap_grad_reduce", action="store_true",
-                        help="explicit availability-ordered per-bucket "
-                             "gradient collectives instead of the implicit "
-                             "tree-wide reduction (dp strategy, first-order "
-                             "optimizers; numerically exact vs the default "
-                             "path at fp32 roundoff)")
     # checkpoint / logging cadence
     parser.add_argument("--num_steps_per_checkpoint", type=int, default=200)
     parser.add_argument("--keep_checkpoints", type=int, default=3)
@@ -316,41 +307,14 @@ def parse_arguments(argv=None) -> argparse.Namespace:
     parser.add_argument("--kfac_skip_layers", type=str, nargs="+",
                         default=["embeddings", "predictions"])
     # mesh
-    parser.add_argument("--mesh_data", type=int, default=-1,
-                        help="data-parallel shards; -1 = all remaining "
-                             "devices. With --mesh_dcn_data > 1 this is "
-                             "the PER-SLICE size (total data parallelism "
-                             "= mesh_data * mesh_dcn_data)")
-    parser.add_argument("--mesh_fsdp", type=int, default=1)
-    parser.add_argument("--mesh_pipe", type=int, default=1,
-                        help="pipeline stages (with --parallel_strategy "
-                             "pp/pp_tp; "
-                             "accumulation microbatches become the GPipe "
-                             "microbatches, so accumulation_steps must be "
-                             ">= stages)")
-    parser.add_argument("--mesh_seq", type=int, default=1,
-                        help="context-parallel shards (with --parallel_"
-                             "strategy sp: ring attention; with pp/pp_tp: "
-                             "the pipeline runs manual over {pipe, seq} "
-                             "with the ring body inside each stage)")
-    parser.add_argument("--mesh_dcn_data", type=int, default=1,
-                        help="multi-slice pods: data-parallel replicas "
-                             "spanning slices over DCN (hybrid device "
-                             "mesh); every other axis stays within a "
-                             "slice on ICI")
-    parser.add_argument("--mesh_model", type=int, default=1)
-    parser.add_argument("--mesh", type=str, default=None,
+    parser.add_argument("--mesh", type=str, default="dp=-1",
                         help="declarative mesh spec, e.g. "
                              "'dp=4,fsdp=2,pipe=2,seq=1' (keys accept "
                              "pp/sp/tp aliases; parallel/mesh.py MeshSpec). "
                              "Any axis product is expressible — rules, "
                              "device mesh, and collective wiring derive "
-                             "from the spec. Overrides --parallel_strategy "
-                             "and the individual --mesh_* sizes")
-    parser.add_argument("--parallel_strategy", type=str, default="dp",
-                        choices=["dp", "fsdp", "tp", "tp_fsdp", "sp", "pp", "pp_tp"],
-                        help="legacy strategy alias; lowers onto a MeshSpec "
-                             "with byte-identical rules (prefer --mesh)")
+                             "from the spec (docs/parallelism.md). The "
+                             "default puts every device on the data axis")
     parser.add_argument("--seed", type=int, default=42)
 
     args = parse_args_with_config_file(parser, argv)
@@ -383,38 +347,7 @@ def setup_training(args):
     jax.config.update("jax_default_prng_impl", args.rng_impl)
     cache_dir = enable_compile_cache(args.compile_cache_dir)
     launcher.initialize()
-    if args.mesh:
-        spec = MeshSpec.parse(args.mesh)
-    else:
-        # Legacy surface: --parallel_strategy + --mesh_* lower onto a
-        # spec (byte-identical rules). The named strategies promise axis
-        # shapes, so misuse of the ALIAS stays an error here even though
-        # the spec itself could realize the product (--mesh lifts these).
-        spec = MeshSpec.from_strategy(
-            args.parallel_strategy, data=args.mesh_data,
-            fsdp=args.mesh_fsdp, pipe=args.mesh_pipe, seq=args.mesh_seq,
-            model=args.mesh_model, dcn_data=args.mesh_dcn_data)
-        if args.mesh_pipe > 1 \
-                and args.parallel_strategy not in ("pp", "pp_tp"):
-            raise ValueError(
-                f"--mesh_pipe {args.mesh_pipe} requires --parallel_strategy "
-                "pp or pp_tp (or express the product with --mesh)")
-        if args.parallel_strategy in ("pp", "pp_tp") and args.mesh_pipe < 2:
-            raise ValueError(
-                "--parallel_strategy pp/pp_tp needs --mesh_pipe >= 2 (a "
-                "1-stage pipeline is just dp with schedule overhead)")
-        if args.parallel_strategy == "pp_tp" and args.mesh_model < 2:
-            raise ValueError(
-                "--parallel_strategy pp_tp needs --mesh_model >= 2 "
-                "(with one model shard use plain pp)")
-        if args.parallel_strategy == "pp" and args.mesh_model > 1:
-            # The engine would run, but plain pp replicates every stage
-            # weight over the model axis: identical work on every model
-            # shard at 1/model throughput — never what anyone wants.
-            raise ValueError(
-                f"--mesh_model {args.mesh_model} with "
-                "--parallel_strategy pp replicates all stage weights "
-                "over the model axis; use pp_tp (or --mesh)")
+    spec = MeshSpec.parse(args.mesh)
     spec.validate(packed=bool(args.pack_sequences))
     mesh = create_mesh(spec.mesh_config())
     # Record the RESOLVED spec (data=-1 replaced by the realized size):
@@ -493,16 +426,6 @@ def setup_training(args):
             f"local_batch_size*data_shards={global_microbatch}"
         )
     args.accumulation_steps = args.global_batch_size // global_microbatch
-    if args.overlap_grad_reduce and (
-            args.mesh_spec.active_axes() - {AXIS_DATA} or args.kfac
-            or args.dtype == "float16"):
-        # The bucketed collectives are defined over the batch axes with
-        # fully-replicated params: sharded-param products, K-FAC's
-        # fused capture, and the fp16 scaler keep the default path.
-        raise ValueError(
-            "--overlap_grad_reduce requires a pure data-parallel mesh "
-            "(fsdp=pipe=seq=model=1) with a first-order optimizer "
-            "(no --kfac) and bf16/fp32")
     if (args.mesh_spec.seq > 1 and args.mesh_spec.pipe == 1
             and args.attention_backend != "ring"):
         # A seq axis exists to avoid O(S^2) dense attention; never
@@ -556,11 +479,10 @@ def prepare_model(args, mesh):
     args.objective = getattr(model, "objective", "mlm")
     if args.objective == "causal_lm" and (
             args.kfac or args.pack_sequences or args.val_input_dir
-            or args.mesh_spec.pipe > 1 or args.overlap_grad_reduce):
+            or args.mesh_spec.pipe > 1):
         raise ValueError(
             "the causal_lm objective trains through the plain step: no "
-            "--kfac, --pack_sequences, --val_input_dir, pipe axis or "
-            "--overlap_grad_reduce")
+            "--kfac, --pack_sequences, --val_input_dir or pipe axis")
 
     # Newest VERIFIED checkpoint: the walk-back verifies each retained
     # checkpoint's integrity manifest and skips corrupt/unreadable files
@@ -936,9 +858,7 @@ def main(args) -> dict:
                 kfac_capture_microbatches=args.kfac_capture_microbatches,
                 loss_scale=fp16,
                 stats_every=telemetry.stats_every(args),
-                stats_phase=stats_phase,
-                mesh=mesh,
-                overlap_grad_buckets=args.overlap_grad_reduce)
+                stats_phase=stats_phase)
 
         # Telemetry (docs/telemetry.md): JSONL sink shared with the logger,
         # step-time decomposition windows, profiler trace window, compile

@@ -1,6 +1,6 @@
 """Async hot-path suite (ISSUE 6; docs/telemetry.md "async the hot path").
 
-Covers the three overlapped phases end to end on CPU:
+Covers the two overlapped phases end to end on CPU:
 
 * async checkpointing — per-directory pending-save keying, device-snapshot
   donation safety, and the acceptance comparison: with a deliberately
@@ -10,10 +10,7 @@ Covers the three overlapped phases end to end on CPU:
 * double-buffered device prefetch — a fast producer drives data_wait p50
   to ~0, a slow producer still attributes the stall to data_wait, and a
   slow staging function reports as the h2d_wait sub-phase (always <= the
-  data_wait it is part of — the schema lint invariant);
-* overlapped data-parallel gradients — the bucketed explicit-psum step
-  (pretrain.make_train_step(overlap_grad_buckets=True)) is numerically
-  identical to the implicit-reduction step at fp32 tolerance.
+  data_wait it is part of — the schema lint invariant).
 """
 
 from __future__ import annotations
@@ -319,105 +316,3 @@ def test_checkpoint_step_p95_collapses_and_report_gates(tmp_path):
     assert rc == 1
     # And the async run against itself is clean.
     assert treport.main([async_jsonl, async_jsonl]) == 0
-
-
-# ---------------------------------------------------------------------------
-# overlapped data-parallel gradients: bucketed == unbucketed
-
-
-def test_bucketed_overlap_gradients_match_unbucketed():
-    """Acceptance: the explicit availability-ordered per-bucket psum path
-    produces gradients (observed through one optimizer step: params,
-    loss, grad_norm) numerically identical to the implicit-reduction path
-    at fp32 tolerance (1e-6)."""
-    import jax
-    import jax.numpy as jnp
-
-    from bert_pytorch_tpu import optim, pretrain
-    from bert_pytorch_tpu.config import BertConfig
-    from bert_pytorch_tpu.models import BertForPreTraining
-    from bert_pytorch_tpu.parallel import (MeshConfig, create_mesh,
-                                           logical_axis_rules)
-
-    # A fresh config (never the shared session fixture — it would leak
-    # the dropout override into later tests). Dropout off: the bucketed
-    # path folds the shard index into the dropout stream (valid draws,
-    # different from the unbucketed path), so exact parity is defined on
-    # the deterministic graph.
-    config = BertConfig(
-        vocab_size=128, hidden_size=32, num_hidden_layers=2,
-        num_attention_heads=4, intermediate_size=64,
-        max_position_embeddings=64, type_vocab_size=2, next_sentence=True,
-        hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0)
-    model = BertForPreTraining(config, dtype=jnp.float32)
-    mesh = create_mesh(MeshConfig(data=-1))
-    rules = logical_axis_rules("dp")
-    seq = 32
-    sample = (jnp.zeros((1, seq), jnp.int32),) * 3
-    tx = optim.lamb(optim.make_schedule("poly", 1e-3, 0.1, 10),
-                    weight_decay=0.01, weight_decay_mask=optim.no_decay_mask,
-                    max_grad_norm=1.0)
-    rng = np.random.default_rng(0)
-    accum, rows = 2, 16
-    batch = {
-        "input_ids": rng.integers(
-            0, config.vocab_size, (accum, rows, seq)).astype(np.int32),
-        "segment_ids": rng.integers(0, 2, (accum, rows, seq)).astype(np.int32),
-        "input_mask": np.ones((accum, rows, seq), np.int32),
-        "masked_lm_labels": np.where(
-            rng.random((accum, rows, seq)) < 0.15,
-            rng.integers(0, config.vocab_size, (accum, rows, seq)),
-            -1).astype(np.int32),
-        "next_sentence_labels": rng.integers(
-            0, 2, (accum, rows)).astype(np.int32),
-    }
-    spec = {"input_ids": 3, "segment_ids": 3, "input_mask": 3,
-            "masked_lm_labels": 3, "next_sentence_labels": 2}
-    with mesh:
-        shardings = pretrain.state_shardings(mesh, model, rules, sample)
-        b_sh = pretrain.batch_shardings(mesh, spec)
-        init_fn = pretrain.make_init_fn(model, tx, sample, shardings)
-        kwargs = dict(schedule=None, next_sentence=True, shardings=shardings,
-                      batch_shardings_=b_sh, max_pred_per_seq=8)
-        step_ref = pretrain.make_train_step(model, tx, **kwargs)
-        step_ovl = pretrain.make_train_step(
-            model, tx, mesh=mesh, overlap_grad_buckets=True, **kwargs)
-        s_ref, m_ref = step_ref(init_fn(jax.random.PRNGKey(0)),
-                                pretrain.put_batch(batch, b_sh))
-        s_ovl, m_ovl = step_ovl(init_fn(jax.random.PRNGKey(0)),
-                                pretrain.put_batch(batch, b_sh))
-    for key in ("loss", "mlm_accuracy", "grad_norm", "real_tokens"):
-        np.testing.assert_allclose(float(m_ref[key]), float(m_ovl[key]),
-                                   rtol=1e-6, atol=1e-7, err_msg=key)
-    assert float(m_ovl["finite"]) == 1.0
-    diffs = jax.tree_util.tree_map(
-        lambda a, b: float(jnp.max(jnp.abs(a - b))), s_ref.params,
-        s_ovl.params)
-    assert max(jax.tree_util.tree_leaves(diffs)) < 1e-6
-
-
-def test_overlap_rejects_unsupported_compositions(tiny_config):
-    import jax.numpy as jnp
-
-    from bert_pytorch_tpu import optim, pretrain
-    from bert_pytorch_tpu.models import BertForPreTraining
-
-    model = BertForPreTraining(tiny_config, dtype=jnp.float32)
-    tx = optim.adamw(optim.make_schedule("poly", 1e-3, 0.1, 10))
-    with pytest.raises(ValueError, match="requires mesh"):
-        pretrain.make_train_step(model, tx, overlap_grad_buckets=True)
-
-
-def test_gradient_buckets_cover_tree_in_availability_order():
-    from bert_pytorch_tpu.parallel import overlap
-
-    grads = {"bert": {"embeddings": {"w": 1}, "encoder": {"layers": {"k": 2}},
-                      "pooler": {"d": 3}},
-             "predictions": {"b": 4}, "seq_relationship": {"k": 5}}
-    flat, _ = __import__("jax").tree_util.tree_flatten_with_path(grads)
-    buckets = {}
-    for path, leaf in flat:
-        buckets.setdefault(overlap._bucket_of(path), []).append(leaf)
-    assert buckets[overlap._BUCKET_EMBEDDINGS] == [1]
-    assert buckets[overlap._BUCKET_ENCODER] == [2]
-    assert sorted(buckets[overlap._BUCKET_HEADS]) == [3, 4, 5]

