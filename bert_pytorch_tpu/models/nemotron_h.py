@@ -17,7 +17,8 @@ experts (``NemotronHConfig``: ``n_routed_experts`` held of
 
 The model returns ``(logits [B, S, V], counters)``; the counters are sums and
 maxima over its expert layers (``moe_local_slots``, ``moe_dropped_slots``,
-``moe_load_max_over_mean``) and ride out of the train step as step metrics.
+``moe_load_max_over_mean``, ``moe_pieces_run``) and ride out of the train
+step as step metrics.
 Parameters carry no logical axis names: under the meshes the trainer builds
 they are replicated (data parallelism); sharding them is the expert-axis work
 ROADMAP.md queues.
@@ -250,18 +251,20 @@ class NemotronHForCausalLM(nn.Module):
         but the head, for a caller that takes the head in pieces
         (models/losses.py ``chunked_next_token_loss``)."""
         x = jnp.take(self.embedding, input_ids, axis=0).astype(self.dtype)
-        slots, dropped, skew = [], [], []
+        slots, dropped, skew, pieces = [], [], [], []
         for layer in self.layers:
             x, counters = layer(x)
             if counters is not None:
                 slots.append(counters["local_slots"])
                 dropped.append(counters["dropped_slots"])
                 skew.append(counters["load_max_over_mean"])
+                pieces.append(counters["pieces_run"])
         zero = jnp.zeros((), jnp.float32)
         return self.final_norm(x), {
             "moe_local_slots": sum(slots, zero),
             "moe_dropped_slots": sum(dropped, zero),
             "moe_load_max_over_mean": jnp.max(jnp.stack(skew)) if skew else zero,
+            "moe_pieces_run": sum(pieces, zero),
         }
 
     def __call__(self, input_ids):

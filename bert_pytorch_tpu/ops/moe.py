@@ -22,18 +22,26 @@ own backward products) at 512-row tiles, which at this layer's shapes runs
 the two products forward and backward in a third of the time of
 ``jax.lax.ragged_dot`` (3.5 against 11.1 ms at 3200 live rows, PERF.md 6);
 ``ragged_dot`` elsewhere (the CPU tests). The sorted rows are worked through
-in PIECES of a static size (twice the expected local load): as many pieces
-as hold every slot there is, each a ``lax.cond`` that does nothing when it
-lies past the last local slot. So gather, activation and
-scatter-add follow the routed slots too, a piece at a time, and no buffer is
-ever sized for the worst case (a 49152-row branch that is never taken cost
-the step 1 GB of the chip: PERF.md 6).
+in PIECES of a static size (twice the expected local load), and only the
+pieces that hold a local slot are run: a loop of ``ceil(local slots / rows)``
+trips, in the forward pass and in the backward pass alike (``_held_sum``, a
+``jax.custom_vjp``: a loop with a traced trip count cannot be differentiated
+by JAX, so the backward pass is written here). Each trip adds into sums that
+the loop carries. So gather, activation and scatter-add follow the routed
+slots too, a piece at a time; no buffer is ever sized for the worst case (a
+49152-row branch that is never taken cost the step 1 GB of the chip: PERF.md
+6), and a piece past the last local slot costs nothing: as ``lax.cond``s,
+each skipped piece wrote zeros for its term, for its residuals and for the
+cotangents of x and of both weight tensors, 584 MB a skipped piece over the
+three passes (PERF.md 5).
 
 Scopes (``pretrain.CAUSAL_LM_SCOPES``): ``moe_route``, ``moe_dispatch``,
 ``moe_experts``, ``moe_combine``.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -80,6 +88,108 @@ def chunk_rows(tokens: int, top_k: int, n_experts: int, held: int,
     return min(rows, -(-most // multiple) * multiple)
 
 
+def _held_sum(rows: int, top_k: int, activation):
+    """``total(x, slot_weights, w_up, w_down, order, sizes, ends, trips) ->
+    [T, H] float32``: the held experts' terms, summed over the first ``trips``
+    pieces of ``rows`` sorted slots (those that hold a local slot), with a
+    backward pass of its own.
+
+    Both passes are a loop whose trip count is the number of such pieces (a
+    traced value; JAX cannot differentiate such a loop, hence the two rules).
+    The forward pass scatter-adds each piece's rows into ONE carried sum and
+    keeps only its arguments. The backward pass makes each piece's forward
+    again, takes its ``jax.vjp`` inside the loop's body, and adds into carried
+    sums for the cotangents of ``x``, the slot weights and both weight
+    tensors (in their own dtypes, as a sum of per-piece cotangents would be).
+    """
+
+    def piece(index, x, slot_weights, order, sizes, ends):
+        """Piece ``index``: its slots and their tokens [rows], which rows
+        hold a local slot [rows, 1], its share of each group [E], and its
+        slots' rows of ``x`` [rows, H] and weights [rows]."""
+        lo = index * rows
+        slots = jax.lax.dynamic_slice(order, (lo,), (rows,))
+        token = slots // top_k
+        # Rows past the last local slot belong to no group: the grouped
+        # product leaves them undefined, so every value that goes into or
+        # comes out of one passes a select (in the backward pass too: the
+        # select's transpose is a select).
+        live = (lo + jnp.arange(rows) < ends[-1])[:, None]
+        mine = jnp.clip(ends - lo, 0, rows) - jnp.clip(
+            ends - sizes - lo, 0, rows)
+        with jax.named_scope("moe_dispatch"):
+            rows_in = x[token]
+        with jax.named_scope("moe_combine"):
+            slot_w = slot_weights[slots]
+        return slots, token, live, mine, rows_in, slot_w
+
+    def experts(live, mine, rows_in, slot_w, w_up, w_down):
+        """A piece's gathered rows [rows, H] and slot weights [rows] -> its
+        weighted outputs [rows, H] in float32."""
+        with jax.named_scope("moe_dispatch"):
+            rows_in = jnp.where(live, rows_in, 0)
+        with jax.named_scope("moe_experts"):
+            mid = grouped_dot(rows_in, w_up, mine)
+            mid = activation(jnp.where(live, mid, 0))
+            rows_out = grouped_dot(mid, w_down, mine)
+        with jax.named_scope("moe_combine"):
+            return jnp.where(live, rows_out, 0).astype(
+                jnp.float32) * slot_w[:, None]
+
+    def forward(x, slot_weights, w_up, w_down, order, sizes, ends, trips):
+        def add_piece(index, total):
+            _, token, live, mine, rows_in, slot_w = piece(
+                index, x, slot_weights, order, sizes, ends)
+            rows_out = experts(live, mine, rows_in, slot_w, w_up, w_down)
+            with jax.named_scope("moe_combine"):
+                return total.at[token].add(rows_out)
+
+        total = jax.lax.fori_loop(0, trips, add_piece,
+                                  jnp.zeros(x.shape, jnp.float32))
+        return total, (x, slot_weights, w_up, w_down, order, sizes, ends,
+                       trips)
+
+    def backward(kept, d_total):
+        x, slot_weights, w_up, w_down, order, sizes, ends, trips = kept
+
+        def cotangents(index):
+            slots, token, live, mine, rows_in, slot_w = piece(
+                index, x, slot_weights, order, sizes, ends)
+            _, pull = jax.vjp(functools.partial(experts, live, mine),
+                              rows_in, slot_w, w_up, w_down)
+            with jax.named_scope("moe_combine"):
+                d_rows_out = d_total[token]
+            return slots, token, pull(d_rows_out)
+
+        def add_piece(index, sums):
+            d_x, d_slot_weights, d_up, d_down = sums
+            slots, token, (d_rows, d_slot, d_up_piece, d_down_piece) = (
+                cotangents(index))
+            with jax.named_scope("moe_dispatch"):
+                d_x = d_x.at[token].add(d_rows)
+            with jax.named_scope("moe_combine"):
+                d_slot_weights = d_slot_weights.at[slots].add(d_slot)
+            with jax.named_scope("moe_experts"):
+                return (d_x, d_slot_weights, d_up + d_up_piece,
+                        d_down + d_down_piece)
+
+        # Piece 0 outside the loop: the weights' sums start as its cotangents
+        # and are never filled with zeros (without a local slot every row's
+        # select yields zeros, so its results are the zeros wanted).
+        slots, token, (d_rows, d_slot, d_up, d_down) = cotangents(0)
+        with jax.named_scope("moe_dispatch"):
+            d_x = jnp.zeros_like(x).at[token].add(d_rows)
+        with jax.named_scope("moe_combine"):
+            d_slot_weights = jnp.zeros_like(slot_weights).at[slots].add(d_slot)
+        sums = jax.lax.fori_loop(1, trips, add_piece,
+                                 (d_x, d_slot_weights, d_up, d_down))
+        return (*sums, None, None, None, None)
+
+    total = jax.custom_vjp(lambda *args: forward(*args)[0])
+    total.defvjp(forward, backward)
+    return total
+
+
 def held_experts(x, chosen, weights, w_up, w_down, first: int,
                  n_experts: int, activation, multiple: int = GMM_TILE_ROWS):
     """The held experts' part of the layer's output, and the counters.
@@ -87,9 +197,10 @@ def held_experts(x, chosen, weights, w_up, w_down, first: int,
     x [T, H]; chosen / weights [T, k] from :func:`route`; w_up [E, H, F],
     w_down [E, F, H] for the E experts ``first .. first + E``. Returns
     (out [T, H] in x's dtype, counters): ``local_slots`` (slots routed to held
-    experts), ``load_max_over_mean`` (largest group over the mean group) and
+    experts), ``load_max_over_mean`` (largest group over the mean group),
     ``dropped_slots`` (local slots that no piece reached: 0, since the pieces
-    cover every slot there is).
+    cover every slot there is) and ``pieces_run`` (the pieces that hold a
+    local slot: the trips of the loop over them).
     """
     tokens, top_k = chosen.shape
     held = w_up.shape[0]
@@ -104,48 +215,17 @@ def held_experts(x, chosen, weights, w_up, w_down, first: int,
                         axis=0)[:held]
         n_local = jnp.sum(sizes)
         ends = jnp.cumsum(sizes)
+        pieces_run = (n_local + rows - 1) // rows
         order = jnp.pad(order, (0, pieces * rows - tokens * top_k))
     w_up, w_down = w_up.astype(x.dtype), w_down.astype(x.dtype)
-    flat_weights = weights.reshape(-1)
-
-    @jax.checkpoint  # a piece keeps nothing: its forward is made again
-    def work(slots, lo):
-        with jax.named_scope("moe_dispatch"):
-            token = slots // top_k
-            # Rows past the last local slot belong to no group: the grouped
-            # product leaves them undefined, so every value that goes into
-            # or comes out of one passes a select (in the backward pass too:
-            # the select's transpose is a select).
-            live = (lo + jnp.arange(rows) < n_local)[:, None]
-            mine = jnp.clip(ends - lo, 0, rows) - jnp.clip(
-                ends - sizes - lo, 0, rows)        # this piece's group sizes
-            rows_in = jnp.where(live, x[token], 0)
-        with jax.named_scope("moe_experts"):
-            mid = grouped_dot(rows_in, w_up, mine)
-            mid = activation(jnp.where(live, mid, 0))
-            rows_out = grouped_dot(mid, w_down, mine)
-        with jax.named_scope("moe_combine"):
-            slot_w = flat_weights[slots][:, None]
-            rows_out = jnp.where(live, rows_out, 0).astype(jnp.float32) * slot_w
-            return jnp.zeros((tokens, x.shape[-1]), jnp.float32).at[
-                token].add(rows_out)
-
-    # The pieces one after the other (a Python loop: a scan would stack what
-    # each piece reads, the layer's input and the experts' weights, once a
-    # piece). Pieces past the last local slot do nothing: the work follows
-    # the slots really routed here, a piece at a time.
-    out = jnp.zeros((tokens, x.shape[-1]), jnp.float32)
-    for index in range(pieces):
-        lo = index * rows
-        out = out + jax.lax.cond(
-            lo < n_local, work,
-            lambda *_: jnp.zeros((tokens, x.shape[-1]), jnp.float32),
-            order[lo:lo + rows], jnp.asarray(lo, jnp.int32))
+    out = _held_sum(rows, top_k, activation)(
+        x, weights.reshape(-1), w_up, w_down, order, sizes, ends, pieces_run)
     counters = {
         "local_slots": n_local.astype(jnp.float32),
         "load_max_over_mean": jnp.max(sizes).astype(jnp.float32) * held
         / jnp.maximum(n_local, 1).astype(jnp.float32),
         "dropped_slots": jnp.maximum(
             n_local - pieces * rows, 0).astype(jnp.float32),
+        "pieces_run": pieces_run.astype(jnp.float32),
     }
     return out.astype(x.dtype), counters

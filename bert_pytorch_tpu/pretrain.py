@@ -186,10 +186,11 @@ def _apply_causal_lm_loss(model, variables, mb):
 def _aux_metrics(aux) -> dict:
     """Step metrics from the micro-batches' stacked aux: the MLM objective's
     is its accuracy; the causal objective's is a dict whose counters add up
-    over the update (``*_slots``) or take its worst (``*_max_over_mean``)."""
+    over the update (``*_slots``, ``*_run``) or take its worst
+    (``*_max_over_mean``)."""
     if not isinstance(aux, dict):
         return {"mlm_accuracy": jnp.mean(aux)}
-    reduce = lambda name: (jnp.sum if name.endswith("_slots") else
+    reduce = lambda name: (jnp.sum if name.endswith(("_slots", "_run")) else
                            jnp.max if name.endswith("_max_over_mean") else
                            jnp.mean)
     return {name: reduce(name)(value) for name, value in aux.items()}

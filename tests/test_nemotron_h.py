@@ -176,8 +176,30 @@ def _program_routed(c, p, x, first, multiple=8):
         lambda t: jnp.square(jax.nn.relu(t)), multiple=multiple)
 
 
-def test_expert_layer_matches_the_reference():
+def _routing_case(taken):
+    """(sizes, parameters, x, pieces run) for an expert layer whose held
+    experts draw no slot, one piece of the sorted slots, or several: the
+    correction bias moves the CHOICE (not the weights) away from or towards
+    them, and ``several`` holds 2 experts of 16, so 4 pieces of 24 rows hold
+    every slot there is."""
     c, p, x = _expert_inputs()
+    lo = c["first"]
+    if taken == "several":
+        c = dict(c, held=2)
+        p = dict(p, **{"l1.w_up": p["l1.w_up"][:2],
+                       "l1.w_down": p["l1.w_down"][:2]})
+    push = {"none": -10.0, "one": 0.0, "several": 0.06}[taken]
+    bias = p["l1.router_bias"].at[lo:lo + c["held"]].add(push)
+    return c, dict(p, **{"l1.router_bias": bias}), x, {
+        "none": 0, "one": 1, "several": 3}[taken]
+
+
+@pytest.mark.parametrize("taken", ["none", "one", "several"])
+def test_expert_layer_matches_the_reference(taken):
+    """Value and the gradients with respect to x, the router and both expert
+    weights, whatever number of pieces holds a local slot; without one the
+    layer's output and all four gradients are exactly zero."""
+    c, p, x, pieces_run = _routing_case(taken)
     names = ("l1.router", "l1.w_up", "l1.w_down")
 
     def mine(x, *w):
@@ -189,11 +211,73 @@ def test_expert_layer_matches_the_reference():
         return ref.expert_layer(q, "l1.", c, x, "f32", shared=False)[0]
 
     args = (x,) + tuple(p[n] for n in names)
+    counters = _program_routed(c, p, x, c["first"])[1]
+    assert float(counters["pieces_run"]) == pieces_run
+    assert float(counters["dropped_slots"]) == 0.0
+    rows = moe.chunk_rows(x.shape[0], c["top_k"], c["experts"], c["held"], 8)
+    assert -(-float(counters["local_slots"]) // rows) == pieces_run
     close(mine(*args), theirs(*args))
     loss = lambda fn: (lambda *a: jnp.sum(jnp.sin(fn(*a))))
     for g, w in zip(jax.grad(loss(mine), argnums=range(4))(*args),
                     jax.grad(loss(theirs), argnums=range(4))(*args)):
         close(g, w)
+        assert pieces_run or not np.any(np.asarray(g))
+    assert pieces_run or not np.any(np.asarray(mine(*args)))
+
+
+def _census(jaxpr, shapes):
+    """(``cond`` equations, zero-fills of one of ``shapes``) in a jaxpr and
+    in every jaxpr inside it (loop bodies, branches, calls)."""
+    from jax.extend import core
+
+    conds = fills = 0
+    for eqn in jaxpr.eqns:
+        conds += eqn.primitive.name == "cond"
+        fills += (eqn.primitive.name == "broadcast_in_dim"
+                  and eqn.outvars[0].aval.shape in shapes
+                  and isinstance(eqn.invars[0], core.Literal)
+                  and eqn.invars[0].val == 0)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if isinstance(sub, core.Jaxpr):
+                    inner = _census(sub, shapes)
+                    conds, fills = conds + inner[0], fills + inner[1]
+    return conds, fills
+
+
+def test_a_piece_without_a_local_slot_costs_no_branch_and_no_zero_fill():
+    """What a skipped ``lax.cond`` cost (PERF.md 6, PR 30): its false branch
+    wrote zeros for the piece's term and, in the backward pass, for the
+    cotangents of x and of BOTH weight tensors, once a piece. The gradient
+    program now holds no ``cond``, and its zero-fills of those shapes do not
+    grow with the number of pieces (2 against 8)."""
+    tokens, hidden, width, held, top_k = 64, 24, 8, 4, 3
+    shapes = {(tokens, hidden), (held, hidden, width), (held, width, hidden)}
+
+    def census(n_experts):
+        def loss(x, router, w_up, w_down):
+            chosen, weights = moe.route(x, router, jnp.zeros((n_experts,)),
+                                        top_k, 2.5)
+            return jnp.sum(moe.held_experts(
+                x, chosen, weights, w_up, w_down, 0, n_experts,
+                lambda t: jnp.square(jax.nn.relu(t)), multiple=8)[0])
+
+        rows = moe.chunk_rows(tokens, top_k, n_experts, held, 8)
+        assert rows != tokens  # a piece's own rows are not counted
+        grad = jax.grad(loss, argnums=range(4))
+        args = (jnp.ones((tokens, hidden)), jnp.ones((hidden, n_experts)),
+                jnp.ones((held, hidden, width)), jnp.ones((held, width, hidden)))
+        assert "stablehlo.case" not in jax.jit(grad).lower(*args).as_text()
+        return -(-tokens * top_k // rows), _census(
+            jax.make_jaxpr(grad)(*args).jaxpr, shapes)
+
+    (few, (conds_few, fills_few)), (many, (conds_many, fills_many)) = (
+        census(16), census(64))
+    assert (few, many) == (2, 8)
+    assert conds_few == conds_many == 0
+    # the forward sum's and the x cotangent's initial zeros, whatever the pieces
+    assert fills_many <= fills_few <= 2
 
 
 def test_the_shares_add_up_to_the_uncut_layer():
@@ -240,6 +324,7 @@ def test_no_slot_is_dropped_under_any_routing(skew):
     want = 64 * c["top_k"] if skew == "all_here" else 0
     assert float(counters["local_slots"]) == want
     assert float(counters["dropped_slots"]) == 0.0
+    assert float(counters["pieces_run"]) == want // rows  # all of them, or none
     close(out, ref.expert_layer(q, "l1.", c, x, "f32", shared=False)[0],
           tol=TOL if want else 0.0)
 
@@ -311,6 +396,8 @@ def test_two_updates_through_make_train_step_match_the_reference():
         state, metrics = step(state, {"input_ids": jnp.asarray(upd)})
         losses.append(float(metrics["loss"]))
         assert float(metrics["moe_dropped_slots"]) == 0.0
+        # one expert layer, two micro-batches: a piece each (or two)
+        assert 2.0 <= float(metrics["moe_pieces_run"]) <= 4.0
         assert float(metrics["finite"]) == 1.0
     followed = ref.follow(seed, TINY, recipe, updates)
     np.testing.assert_allclose(losses, followed["loss"], atol=2e-5)
