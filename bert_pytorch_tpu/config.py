@@ -187,8 +187,139 @@ class NemotronHConfig:
     def mamba_conv_dim(self) -> int:
         return self.mamba_inner + 2 * self.n_groups * self.ssm_state_size
 
+    @property
+    def init_sample_length(self) -> int:
+        """Positions of the sample that initializes the parameters (none
+        depends on the length): one chunk of the scan."""
+        return self.chunk_size
 
-MODEL_FAMILIES = {"bert": BertConfig, "nemotron_h": NemotronHConfig}
+
+class LagunaConfig:
+    """Configuration of the ``laguna`` family: a pre-norm residual decoder
+    whose every layer is attention, then an MLP. The layers differ:
+    ``layer_types`` says which attend to the whole prefix
+    (``full_attention``) and which to the last ``sliding_window`` positions
+    (``sliding_attention``), ``num_attention_heads_per_layer`` how many query
+    heads each has (over ``num_key_value_heads`` key-value heads of
+    ``head_dim``), ``rope_parameters`` the rotary table of each of the two
+    kinds, and ``mlp_layer_types`` which MLP is ``dense``
+    (``intermediate_size``) and which ``sparse`` (routed experts of
+    ``moe_intermediate_size`` and a shared one). Keys and defaults are the
+    published ``config.json``'s (poolside/Laguna-S-2.1); extra keys ride
+    along as on :class:`BertConfig`.
+
+    The chip's share is stated here, not in the mesh, as
+    :class:`NemotronHConfig` states its experts': ``num_experts`` experts are
+    HELD of ``num_experts * ep_size`` (the router's width), ``ep_rank`` says
+    which; ``num_key_value_heads`` and ``num_attention_heads_per_layer``
+    count the heads held of ``tp_size`` times as many (the output projection
+    then adds only their terms).
+    """
+
+    model_type = "laguna"
+
+    def __init__(self, **values: Any):
+        defaults = dict(
+            vocab_size=100352, hidden_size=3072, intermediate_size=12288,
+            num_hidden_layers=48, num_attention_heads=48,
+            num_key_value_heads=8, head_dim=128, tp_size=1, tp_rank=0,
+            num_attention_heads_per_layer=None, layer_types=None,
+            sliding_window=512, gating="per-head", attention_bias=False,
+            rope_parameters={
+                "full_attention": {
+                    "rope_type": "yarn", "rope_theta": 500000, "factor": 128,
+                    "original_max_position_embeddings": 8192, "beta_slow": 1,
+                    "beta_fast": 32, "attention_factor": 1.4852030263919618,
+                    "partial_rotary_factor": 0.5},
+                "sliding_attention": {
+                    "rope_type": "default", "rope_theta": 10000,
+                    "partial_rotary_factor": 1}},
+            mlp_only_layers=[0], mlp_layer_types=None,
+            num_experts=256, ep_size=1, ep_rank=0, num_experts_per_tok=10,
+            moe_intermediate_size=1024, shared_expert_intermediate_size=1024,
+            norm_topk_prob=True, moe_routed_scaling_factor=2.5,
+            moe_router_logit_softcapping=0,
+            moe_apply_router_weight_on_input=False,
+            rms_norm_eps=1e-6, initializer_range=0.02,
+            tie_word_embeddings=False)
+        for key, value in {**defaults, **values}.items():
+            setattr(self, key, value)
+        layers = self.num_hidden_layers
+        if self.layer_types is None:
+            self.layer_types = [
+                "sliding_attention" if i % 4 else "full_attention"
+                for i in range(layers)]
+        if self.mlp_layer_types is None:
+            self.mlp_layer_types = [
+                "dense" if i in self.mlp_only_layers else "sparse"
+                for i in range(layers)]
+        if self.num_attention_heads_per_layer is None:
+            self.num_attention_heads_per_layer = (
+                [self.num_attention_heads] * layers)
+        for name, allowed in (
+                ("layer_types", {"full_attention", "sliding_attention"}),
+                ("mlp_layer_types", {"dense", "sparse"})):
+            got = getattr(self, name)
+            if len(got) != layers or set(got) - allowed:
+                raise ValueError(
+                    f"{name} must be {layers} of {sorted(allowed)}: {got}")
+        heads = self.num_attention_heads_per_layer
+        if len(heads) != layers or any(
+                h % self.num_key_value_heads for h in heads):
+            raise ValueError(
+                f"num_attention_heads_per_layer {heads}: {layers} multiples "
+                f"of num_key_value_heads {self.num_key_value_heads}")
+        if not 0 <= self.ep_rank < self.ep_size:
+            raise ValueError(f"ep_rank {self.ep_rank} of ep_size {self.ep_size}")
+        if not 0 <= self.tp_rank < self.tp_size:
+            raise ValueError(f"tp_rank {self.tp_rank} of tp_size {self.tp_size}")
+        if (self.gating != "per-head" or self.attention_bias
+                or self.tie_word_embeddings
+                or self.moe_router_logit_softcapping
+                or self.moe_apply_router_weight_on_input):
+            raise ValueError(
+                "laguna is built with a per-head gate, no attention bias, an "
+                "untied head, no soft cap on the router's logits and the "
+                "router's weight on the experts' output")
+
+    @classmethod
+    def from_dict(cls, json_object: dict) -> "LagunaConfig":
+        values = {k: v for k, v in json_object.items() if k != "model_type"}
+        return cls(**values)
+
+    def to_dict(self) -> dict:
+        return dict(copy.deepcopy(self.__dict__), model_type=self.model_type)
+
+    @property
+    def router_experts(self) -> int:
+        """The router's width: every expert of the layer, held or not."""
+        return self.num_experts * self.ep_size
+
+    @property
+    def first_expert(self) -> int:
+        return self.ep_rank * self.num_experts
+
+    def rope_of(self, layer: int) -> tuple:
+        """(rotary dimensions of a head, the layer kind's ``rope_parameters``
+        entry) for layer ``layer``."""
+        rope = self.rope_parameters[self.layer_types[layer]]
+        return (int(self.head_dim * rope.get("partial_rotary_factor", 1)),
+                rope)
+
+    def window_of(self, layer: int):
+        """The attention window of layer ``layer``: None on a full layer."""
+        return (self.sliding_window
+                if self.layer_types[layer] == "sliding_attention" else None)
+
+    @property
+    def init_sample_length(self) -> int:
+        """Positions of the sample that initializes the parameters (none
+        depends on the length)."""
+        return 16
+
+
+MODEL_FAMILIES = {"bert": BertConfig, "nemotron_h": NemotronHConfig,
+                  "laguna": LagunaConfig}
 
 
 def load_model_config(json_file: str):

@@ -49,6 +49,7 @@ from bert_pytorch_tpu.models.losses import (
     token_classification_loss,
 )
 
+from bert_pytorch_tpu.models.laguna import LagunaForCausalLM
 from bert_pytorch_tpu.models.nemotron_h import NemotronHForCausalLM
 
 
@@ -58,18 +59,20 @@ def build_pretraining_model(config, dtype, remat: str = "none",
     (``config.load_model_config`` chose the class from the file's
     ``model_type``). The model's ``objective`` attribute names what
     ``pretrain.make_train_step`` trains it on."""
-    from bert_pytorch_tpu.config import BertConfig, NemotronHConfig
+    from bert_pytorch_tpu.config import (BertConfig, LagunaConfig,
+                                         NemotronHConfig)
 
-    if isinstance(config, NemotronHConfig):
-        return NemotronHForCausalLM(config, dtype=dtype, remat=remat,
-                                    attention_backend=attention_backend)
-    if isinstance(config, BertConfig):
-        return BertForPreTraining(config, dtype=dtype, remat=remat,
-                                  attention_backend=attention_backend)
+    for family, model in ((NemotronHConfig, NemotronHForCausalLM),
+                          (LagunaConfig, LagunaForCausalLM),
+                          (BertConfig, BertForPreTraining)):
+        if isinstance(config, family):
+            return model(config, dtype=dtype, remat=remat,
+                         attention_backend=attention_backend)
     raise TypeError(f"no pretraining model for {type(config).__name__}")
 
 
 __all__ = [
+    "LagunaForCausalLM",
     "NemotronHForCausalLM",
     "build_pretraining_model",
     "next_token_loss",

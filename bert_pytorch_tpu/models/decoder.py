@@ -1,0 +1,177 @@
+"""What the decoder families share (``models/nemotron_h.py``,
+``models/laguna.py``): RMSNorm, the bias-free projection, the routed expert
+layer with its shared expert, and the wrapper round a stack of unlike layers:
+embedding, ``layers_0 .. layers_{L-1}``, final RMSNorm, untied output head,
+next-token objective.
+
+Layers of unlike kinds hold unlike parameters, so they cannot be stacked and
+scanned the way ``models/bert.py`` scans its encoder; each is rematerialized
+on its own (``ops/remat.py``'s policy). A layer returns ``(x, counters)``;
+the wrapper adds the layers' counters up (``*_max_over_mean``: their
+largest) and they ride out of the train step as step metrics.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Callable
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from bert_pytorch_tpu.ops import moe
+from bert_pytorch_tpu.ops.remat import remat_policy
+
+Dtype = Any
+
+MOE_COUNTERS = ("moe_local_slots", "moe_dropped_slots",
+                "moe_load_max_over_mean", "moe_pieces_run")
+
+
+def normal(std: float):
+    return nn.initializers.normal(stddev=std)
+
+
+def dense(features: int, std: float, dtype, name):
+    return nn.Dense(features, use_bias=False, dtype=dtype,
+                    param_dtype=jnp.float32, kernel_init=normal(std),
+                    name=name)
+
+
+class RMSNorm(nn.Module):
+    epsilon: float = 1e-5
+    dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           jnp.float32)
+        x32 = x.astype(jnp.float32)
+        normed = x32 * jax.lax.rsqrt(
+            jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.epsilon)
+        return (normed * scale).astype(self.dtype)
+
+
+class ExpertLayer(nn.Module):
+    """Router over every expert of the layer (``router_experts`` wide), the
+    terms of the ``held`` experts from ``first_expert`` on, and the shared
+    expert on every token. ``score`` is the routing rule and ``gated`` the
+    experts' form (``ops/moe.py``): ``sigmoid`` with its correction bias and
+    plain ``activation`` experts for ``nemotron_h``, ``softmax`` and gated
+    experts for ``laguna``; the shared expert has the routed experts' form.
+    Returns (output, the layer's ``moe_*`` counters)."""
+    width: int
+    shared_width: int
+    held: int
+    router_experts: int
+    first_expert: int
+    top_k: int
+    route_scale: float
+    norm_topk: bool
+    activation: Callable
+    std: float
+    out_std: float
+    score: str = "sigmoid"
+    gated: bool = False
+    # (tests at a small size set a smaller rounding of the pieces)
+    piece_multiple: int = moe.GMM_TILE_ROWS
+    dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        hidden, fan = x.shape[-1], 2 if self.gated else 1
+        router_w = self.param("router_kernel", normal(self.std),
+                              (hidden, self.router_experts), jnp.float32)
+        correction = None
+        if self.score == "sigmoid":
+            # The published rule moves this bias outside the gradient, towards
+            # balance; here it is a buffer at zero (route() stops its gradient).
+            correction = self.param(
+                "router_correction_bias", nn.initializers.zeros,
+                (self.router_experts,), jnp.float32)
+        w_up = self.param("experts_up", normal(self.std),
+                          (self.held, hidden, fan * self.width), jnp.float32)
+        w_down = self.param("experts_down", normal(self.out_std),
+                            (self.held, self.width, hidden), jnp.float32)
+        batch, seq = x.shape[:2]
+        flat = x.reshape(batch * seq, hidden)
+        with jax.named_scope("moe"):
+            chosen, weights = moe.route(
+                flat, router_w, correction, self.top_k, self.route_scale,
+                self.norm_topk, self.score)
+            # for a caller that asks (``mutable=["intermediates"]``): which
+            # experts each token chose; otherwise nothing is kept
+            self.sow("intermediates", "chosen", chosen)
+            routed, counters = moe.held_experts(
+                flat, chosen, weights, w_up, w_down, self.first_expert,
+                self.router_experts, self.activation,
+                multiple=self.piece_multiple, gated=self.gated)
+            with jax.named_scope("moe_shared"):
+                mid = dense(fan * self.shared_width, self.std, self.dtype,
+                            "shared_up")(x)
+                if self.gated:
+                    gate, up = jnp.split(mid, 2, axis=-1)
+                    mid = self.activation(gate) * up
+                else:
+                    mid = self.activation(mid)
+                shared = dense(hidden, self.out_std, self.dtype,
+                               "shared_down")(mid)
+            return shared + routed.reshape(x.shape), {
+                "moe_" + name: value for name, value in counters.items()}
+
+
+class CausalDecoder(nn.Module):
+    """The wrapper: a family gives its ``blocks()`` (modules that take x and
+    return ``(x, counters)``), the norm's epsilon and any counters of its own
+    beside the expert layers' (``COUNTERS``). The model returns ``(logits
+    [B, S, V], counters)``."""
+    config: Any
+    dtype: Dtype = jnp.float32
+    remat: str = "none"
+    attention_backend: str = "xla"
+
+    # What pretrain.make_train_step trains these families on.
+    objective = "causal_lm"
+    COUNTERS = MOE_COUNTERS
+
+    def blocks(self, wrap) -> list:
+        """The layers, in order; ``wrap`` rematerializes a block class."""
+        raise NotImplementedError
+
+    def norm_epsilon(self) -> float:
+        raise NotImplementedError
+
+    def setup(self):
+        cfg = self.config
+        self.embedding = self.param(
+            "embedding", normal(cfg.initializer_range),
+            (cfg.vocab_size, cfg.hidden_size), jnp.float32)
+        policy = remat_policy(self.remat)
+        self.layers = self.blocks(
+            (lambda block: block) if policy is None else
+            (lambda block: nn.remat(block, policy=policy, prevent_cse=True)))
+        self.final_norm = RMSNorm(self.norm_epsilon(), self.dtype)
+        self.lm_head = dense(cfg.vocab_size, cfg.initializer_range,
+                             self.dtype, None)
+
+    def hidden_states(self, input_ids):
+        """[B, S] ids -> (the final norm's output [B, S, H], counters): all
+        but the head, for a caller that takes the head in pieces
+        (models/losses.py ``chunked_next_token_loss``)."""
+        x = jnp.take(self.embedding, input_ids, axis=0).astype(self.dtype)
+        seen = {name: [] for name in self.COUNTERS}
+        for layer in self.layers:
+            x, counters = layer(x)
+            for name, value in (counters or {}).items():
+                seen[name].append(value)
+        zero = jnp.zeros((), jnp.float32)
+        return self.final_norm(x), {
+            name: (zero if not values else
+                   jnp.max(jnp.stack(values))
+                   if name.endswith("_max_over_mean") else sum(values, zero))
+            for name, values in seen.items()}
+
+    def __call__(self, input_ids):
+        x, counters = self.hidden_states(input_ids)
+        with jax.named_scope("lm_head"):
+            return self.lm_head(x), counters

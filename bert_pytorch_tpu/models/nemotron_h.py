@@ -8,12 +8,12 @@ layer with a shared expert (ops/moe.py) and ``*`` grouped-query causal
 attention (ops/attention.py) without positional embedding; then a final
 RMSNorm and an untied output head. No bias but the convolution's, no dropout.
 
-Layers of unlike kinds hold unlike parameters, so they cannot be stacked and
-scanned the way ``models/bert.py`` scans its encoder: the layers are
-``layers_0 .. layers_{L-1}``, each rematerialized on its own
-(``ops/remat.py``'s policy). The expert layer holds the chip's share of the
-experts (``NemotronHConfig``: ``n_routed_experts`` held of
-``n_routed_experts * ep_size``) and adds only their terms.
+The wrapper (embedding, ``layers_0 .. layers_{L-1}`` each rematerialized on
+its own, final norm, head), RMSNorm and the expert layer are
+``models/decoder.py``'s, shared with ``models/laguna.py``. The expert layer
+holds the chip's share of the experts (``NemotronHConfig``:
+``n_routed_experts`` held of ``n_routed_experts * ep_size``) and adds only
+their terms.
 
 The model returns ``(logits [B, S, V], counters)``; the counters are sums and
 maxima over its expert layers (``moe_local_slots``, ``moe_dropped_slots``,
@@ -34,15 +34,14 @@ import jax
 import jax.numpy as jnp
 
 from bert_pytorch_tpu.config import NemotronHConfig
-from bert_pytorch_tpu.ops import moe, ssm
+from bert_pytorch_tpu.models.decoder import (CausalDecoder, ExpertLayer,
+                                             RMSNorm)
+from bert_pytorch_tpu.models.decoder import dense as _dense
+from bert_pytorch_tpu.models.decoder import normal as _normal
+from bert_pytorch_tpu.ops import ssm
 from bert_pytorch_tpu.ops.attention import dot_product_attention
-from bert_pytorch_tpu.ops.remat import remat_policy
 
 Dtype = Any
-
-
-def _normal(std: float):
-    return nn.initializers.normal(stddev=std)
 
 
 def _out_std(config: NemotronHConfig) -> float:
@@ -55,26 +54,6 @@ def _out_std(config: NemotronHConfig) -> float:
 
 def relu2(x):
     return jnp.square(jax.nn.relu(x))
-
-
-class RMSNorm(nn.Module):
-    epsilon: float = 1e-5
-    dtype: Dtype = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
-                           jnp.float32)
-        x32 = x.astype(jnp.float32)
-        normed = x32 * jax.lax.rsqrt(
-            jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.epsilon)
-        return (normed * scale).astype(self.dtype)
-
-
-def _dense(features: int, std: float, dtype, name):
-    return nn.Dense(features, use_bias=False, dtype=dtype,
-                    param_dtype=jnp.float32, kernel_init=_normal(std),
-                    name=name)
 
 
 class Mamba2Mixer(nn.Module):
@@ -156,48 +135,19 @@ class CausalAttention(nn.Module):
             ctx.reshape(batch, seq, heads * hd))
 
 
-class ExpertLayer(nn.Module):
-    """Router over every expert of the layer, the held experts' terms, and
-    the shared expert on every token."""
-    config: NemotronHConfig
-    dtype: Dtype = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.config
-        hidden, width = cfg.hidden_size, cfg.moe_intermediate_size
-        held, std = cfg.n_routed_experts, cfg.initializer_range
-        router_w = self.param("router_kernel", _normal(std),
-                              (hidden, cfg.router_experts), jnp.float32)
-        # The published rule moves this bias outside the gradient, towards
-        # balance; here it is a buffer at zero (route() stops its gradient).
-        correction = self.param("router_correction_bias",
-                                nn.initializers.zeros, (cfg.router_experts,),
-                                jnp.float32)
-        w_up = self.param("experts_up", _normal(std), (held, hidden, width),
-                          jnp.float32)
-        w_down = self.param("experts_down", _normal(_out_std(cfg)),
-                            (held, width, hidden), jnp.float32)
-        batch, seq = x.shape[:2]
-        flat = x.reshape(batch * seq, hidden)
-        with jax.named_scope("moe"):
-            chosen, weights = moe.route(
-                flat, router_w, correction, cfg.num_experts_per_tok,
-                cfg.routed_scaling_factor, cfg.norm_topk_prob)
-            # for a caller that asks (``mutable=["intermediates"]``): which
-            # experts each token chose; otherwise nothing is kept
-            self.sow("intermediates", "chosen", chosen)
-            routed, counters = moe.held_experts(
-                flat, chosen, weights, w_up, w_down, cfg.first_expert,
-                cfg.router_experts, relu2,
-                # (tests at a small size set a smaller rounding of the pieces)
-                multiple=getattr(cfg, "moe_piece_multiple", moe.GMM_TILE_ROWS))
-            with jax.named_scope("moe_shared"):
-                shared_w = cfg.moe_shared_expert_intermediate_size
-                mid = relu2(_dense(shared_w, std, self.dtype, "shared_up")(x))
-                shared = _dense(hidden, _out_std(cfg), self.dtype,
-                                "shared_down")(mid)
-            return shared + routed.reshape(x.shape), counters
+def expert_layer(cfg: NemotronHConfig, dtype, name=None) -> ExpertLayer:
+    """The family's expert layer: sigmoid scores with a correction bias,
+    plain relu^2 experts, the share ``cfg`` states."""
+    return ExpertLayer(
+        width=cfg.moe_intermediate_size,
+        shared_width=cfg.moe_shared_expert_intermediate_size,
+        held=cfg.n_routed_experts, router_experts=cfg.router_experts,
+        first_expert=cfg.first_expert, top_k=cfg.num_experts_per_tok,
+        route_scale=cfg.routed_scaling_factor, norm_topk=cfg.norm_topk_prob,
+        activation=relu2, std=cfg.initializer_range, out_std=_out_std(cfg),
+        piece_multiple=getattr(cfg, "moe_piece_multiple",
+                               ExpertLayer.piece_multiple),
+        dtype=dtype, name=name)
 
 
 class NemotronHBlock(nn.Module):
@@ -217,57 +167,17 @@ class NemotronHBlock(nn.Module):
             out = CausalAttention(cfg, self.dtype, self.attention_backend,
                                   name="mixer")(h)
         else:
-            out, counters = ExpertLayer(cfg, self.dtype, name="mixer")(h)
+            out, counters = expert_layer(cfg, self.dtype, name="mixer")(h)
         return x + out, counters
 
 
-class NemotronHForCausalLM(nn.Module):
+class NemotronHForCausalLM(CausalDecoder):
     config: NemotronHConfig
-    dtype: Dtype = jnp.float32
-    remat: str = "none"
-    attention_backend: str = "xla"
 
-    # What pretrain.make_train_step trains this family on.
-    objective = "causal_lm"
+    def blocks(self, wrap):
+        block = wrap(NemotronHBlock)
+        return [block(self.config, kind, self.dtype, self.attention_backend)
+                for kind in self.config.hybrid_override_pattern]
 
-    def setup(self):
-        cfg = self.config
-        self.embedding = self.param(
-            "embedding", _normal(cfg.initializer_range),
-            (cfg.vocab_size, cfg.hidden_size), jnp.float32)
-        block = NemotronHBlock
-        policy = remat_policy(self.remat)
-        if policy is not None:
-            block = nn.remat(NemotronHBlock, policy=policy, prevent_cse=True)
-        self.layers = [
-            block(cfg, kind, self.dtype, self.attention_backend)
-            for kind in cfg.hybrid_override_pattern]
-        self.final_norm = RMSNorm(cfg.layer_norm_epsilon, self.dtype)
-        self.lm_head = _dense(cfg.vocab_size, cfg.initializer_range,
-                              self.dtype, None)
-
-    def hidden_states(self, input_ids):
-        """[B, S] ids -> (the final norm's output [B, S, H], counters): all
-        but the head, for a caller that takes the head in pieces
-        (models/losses.py ``chunked_next_token_loss``)."""
-        x = jnp.take(self.embedding, input_ids, axis=0).astype(self.dtype)
-        slots, dropped, skew, pieces = [], [], [], []
-        for layer in self.layers:
-            x, counters = layer(x)
-            if counters is not None:
-                slots.append(counters["local_slots"])
-                dropped.append(counters["dropped_slots"])
-                skew.append(counters["load_max_over_mean"])
-                pieces.append(counters["pieces_run"])
-        zero = jnp.zeros((), jnp.float32)
-        return self.final_norm(x), {
-            "moe_local_slots": sum(slots, zero),
-            "moe_dropped_slots": sum(dropped, zero),
-            "moe_load_max_over_mean": jnp.max(jnp.stack(skew)) if skew else zero,
-            "moe_pieces_run": sum(pieces, zero),
-        }
-
-    def __call__(self, input_ids):
-        x, counters = self.hidden_states(input_ids)
-        with jax.named_scope("lm_head"):
-            return self.lm_head(x), counters
+    def norm_epsilon(self):
+        return self.config.layer_norm_epsilon

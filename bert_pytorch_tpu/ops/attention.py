@@ -71,6 +71,7 @@ def dot_product_attention(
     backend: str = "xla",
     sequence_ids: jnp.ndarray | None = None,
     causal: bool = False,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """Attention over [B, S, H, D] query/key/value tensors.
 
@@ -80,7 +81,11 @@ def dot_product_attention(
     ``causal`` (static) lets position q attend to positions <= q only: a
     mask on the XLA path; on the Pallas path a static flag of the kernels,
     which then mask the tiles on the diagonal and skip those above it
-    (ops/pallas/attention.py). ``k`` and ``v`` may have fewer heads than
+    (ops/pallas/attention.py). ``window`` (static, with ``causal``, unpacked
+    rows, the same two paths) narrows that to the ``window`` positions up to
+    and including q: the same band as a mask on the XLA path, and on the
+    Pallas path kernels whose loops follow the band (the tiles below it are
+    skipped like those above the diagonal). ``k`` and ``v`` may have fewer heads than
     ``q`` (grouped-query attention, H a multiple of theirs): each key-value
     head then serves H / H_kv consecutive query heads; they are repeated here,
     before either path, and autodiff sums the repeats' gradients.
@@ -105,7 +110,8 @@ def dot_product_attention(
             k = jnp.repeat(k, repeats, axis=2)
             v = jnp.repeat(v, repeats, axis=2)
         return _attention_core(q, k, v, bias, dropout_rng, dropout_rate,
-                               deterministic, backend, sequence_ids, causal)
+                               deterministic, backend, sequence_ids, causal,
+                               window)
 
 
 def resolve_backend(backend: str, seq: int, dropout: bool) -> str:
@@ -129,9 +135,15 @@ def resolve_backend(backend: str, seq: int, dropout: bool) -> str:
 
 
 def _attention_core(q, k, v, bias, dropout_rng, dropout_rate, deterministic,
-                    backend, sequence_ids, causal=False):
+                    backend, sequence_ids, causal=False, window=None):
     active = not deterministic and dropout_rate > 0.0
     resolved = resolve_backend(backend, q.shape[1], active)
+    if window is not None and (not causal or window < 1
+                               or sequence_ids is not None):
+        raise ValueError(
+            "a window is a positive width on the causal mask of unpacked "
+            f"rows (window={window}, causal={causal}, "
+            f"packed={sequence_ids is not None})")
     if causal and resolved not in ("xla", "pallas"):
         raise ValueError(
             f"causal attention runs on the 'xla' and 'pallas' paths, not "
@@ -176,11 +188,12 @@ def _attention_core(q, k, v, bias, dropout_rng, dropout_rate, deterministic,
         kbias = None if sequence_ids is not None else bias
         if not active:
             return flash_attention(q, k, v, bias=kbias,
-                                   sequence_ids=sequence_ids, causal=causal)
+                                   sequence_ids=sequence_ids, causal=causal,
+                                   window=window)
         return flash_attention(
             q, k, v, bias=kbias,
             dropout_rate=dropout_rate, dropout_rng=dropout_rng,
-            sequence_ids=sequence_ids, causal=causal)
+            sequence_ids=sequence_ids, causal=causal, window=window)
     if backend in ("ring", "ring_manual") and sequence_ids is not None:
         # Ring attention shards the sequence axis across chips; the
         # block-diagonal mask would need per-shard id exchange alongside
@@ -245,8 +258,10 @@ def _attention_core(q, k, v, bias, dropout_rng, dropout_rate, deterministic,
         scores = scores + bias.astype(jnp.float32)
     if causal:
         seq_q, seq_k = scores.shape[-2:]
-        scores = jnp.where(jnp.tril(jnp.ones((seq_q, seq_k), bool)),
-                           scores, -1e30)
+        seen = jnp.tril(jnp.ones((seq_q, seq_k), bool))
+        if window is not None:
+            seen &= ~jnp.tril(seen, -window)
+        scores = jnp.where(seen, scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
     probs = probs.astype(q.dtype)
     if active:

@@ -7,10 +7,19 @@ what the absent experts would add lies on other chips (expert parallelism;
 the exchange between chips is not in this file, and on one chip the layer runs
 without it). Nothing stands in for the absent chips.
 
-Routing (DeepSeek-V3's, as ``nemotron_h`` uses it): ``s = sigmoid(logits)``
-in float32, the ``top_k`` largest of ``s + correction_bias`` choose, the
+Two routing rules (``route``'s ``score``), both in float32. ``sigmoid``
+(DeepSeek-V3's, as ``nemotron_h`` uses it): ``s = sigmoid(logits)``, the
+``top_k`` largest of ``s + correction_bias`` choose. ``softmax`` (as
+``laguna`` uses it): ``s = softmax(logits)`` over all the experts, the
+``top_k`` largest choose, and there is no correction bias. Under either the
 weights are ``s`` of the chosen, divided by their sum when ``norm_topk`` is
 set, times ``scale``.
+
+Two forms of expert (``held_experts``'s ``gated``). Plain: ``w_down
+activation(w_up x)``, two products. Gated: ``w_up`` is ``[E, H, 2F]``, the
+gate's columns first and the up projection's after them, ONE product for the
+two, and the expert is ``w_down (activation(gate) * up)``: three products'
+work in two calls. Both go through the same pieces.
 
 No token-slot is ever dropped. The ``tokens x top_k`` slots are sorted so
 that the slots of held experts come first, expert by expert; a token picks
@@ -64,14 +73,22 @@ def grouped_dot(rows, weights, sizes):
 
 
 def route(x, router_w, correction_bias, top_k: int, scale: float,
-          norm_topk: bool = True):
-    """x [T, H] -> (expert ids [T, k] int32, weights [T, k] float32)."""
+          norm_topk: bool = True, score: str = "sigmoid"):
+    """x [T, H] -> (expert ids [T, k] int32, weights [T, k] float32).
+    ``score``: ``sigmoid`` (with its ``correction_bias`` [experts]) or
+    ``softmax`` (``correction_bias`` None)."""
     with jax.named_scope("moe_route"):
         logits = jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
                             precision="highest")
-        scores = jax.nn.sigmoid(logits)
-        _, chosen = jax.lax.top_k(
-            scores + jax.lax.stop_gradient(correction_bias), top_k)
+        if score == "softmax":
+            scores = jax.nn.softmax(logits, axis=-1)
+            _, chosen = jax.lax.top_k(scores, top_k)
+        elif score == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+            _, chosen = jax.lax.top_k(
+                scores + jax.lax.stop_gradient(correction_bias), top_k)
+        else:
+            raise ValueError(f"score must be sigmoid|softmax, got {score!r}")
         weights = jnp.take_along_axis(scores, chosen, axis=-1)
         if norm_topk:
             weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
@@ -88,7 +105,7 @@ def chunk_rows(tokens: int, top_k: int, n_experts: int, held: int,
     return min(rows, -(-most // multiple) * multiple)
 
 
-def _held_sum(rows: int, top_k: int, activation):
+def _held_sum(rows: int, top_k: int, activation, gated: bool = False):
     """``total(x, slot_weights, w_up, w_down, order, sizes, ends, trips) ->
     [T, H] float32``: the held experts' terms, summed over the first ``trips``
     pieces of ``rows`` sorted slots (those that hold a local slot), with a
@@ -129,8 +146,12 @@ def _held_sum(rows: int, top_k: int, activation):
         with jax.named_scope("moe_dispatch"):
             rows_in = jnp.where(live, rows_in, 0)
         with jax.named_scope("moe_experts"):
-            mid = grouped_dot(rows_in, w_up, mine)
-            mid = activation(jnp.where(live, mid, 0))
+            mid = jnp.where(live, grouped_dot(rows_in, w_up, mine), 0)
+            if gated:
+                gate, up = jnp.split(mid, 2, axis=-1)
+                mid = activation(gate) * up
+            else:
+                mid = activation(mid)
             rows_out = grouped_dot(mid, w_down, mine)
         with jax.named_scope("moe_combine"):
             return jnp.where(live, rows_out, 0).astype(
@@ -191,11 +212,13 @@ def _held_sum(rows: int, top_k: int, activation):
 
 
 def held_experts(x, chosen, weights, w_up, w_down, first: int,
-                 n_experts: int, activation, multiple: int = GMM_TILE_ROWS):
+                 n_experts: int, activation, multiple: int = GMM_TILE_ROWS,
+                 gated: bool = False):
     """The held experts' part of the layer's output, and the counters.
 
-    x [T, H]; chosen / weights [T, k] from :func:`route`; w_up [E, H, F],
-    w_down [E, F, H] for the E experts ``first .. first + E``. Returns
+    x [T, H]; chosen / weights [T, k] from :func:`route`; w_up [E, H, F]
+    ([E, H, 2F] when ``gated``: gate columns, then up columns), w_down
+    [E, F, H] for the E experts ``first .. first + E``. Returns
     (out [T, H] in x's dtype, counters): ``local_slots`` (slots routed to held
     experts), ``load_max_over_mean`` (largest group over the mean group),
     ``dropped_slots`` (local slots that no piece reached: 0, since the pieces
@@ -218,7 +241,7 @@ def held_experts(x, chosen, weights, w_up, w_down, first: int,
         pieces_run = (n_local + rows - 1) // rows
         order = jnp.pad(order, (0, pieces * rows - tokens * top_k))
     w_up, w_down = w_up.astype(x.dtype), w_down.astype(x.dtype)
-    out = _held_sum(rows, top_k, activation)(
+    out = _held_sum(rows, top_k, activation, gated)(
         x, weights.reshape(-1), w_up, w_down, order, sizes, ends, pieces_run)
     counters = {
         "local_slots": n_local.astype(jnp.float32),

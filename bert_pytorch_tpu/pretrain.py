@@ -52,6 +52,12 @@ CAUSAL_LM_SCOPES = (
     "ssm_mixer", "ssm_in_proj", "ssm_conv", "ssd_scan", "ssm_gate_norm",
     "ssm_out_proj", "moe", "moe_route", "moe_dispatch", "moe_experts",
     "moe_combine", "moe_shared", "lm_head", "lm_loss")
+# ... and those of the laguna family's step (models/laguna.py; the expert
+# layer, the head and the loss are the ones above).
+LAGUNA_SCOPES = (
+    "attn_qkv", "attn_rope", "attn_gate", "attn_out", "dense_mlp", "moe",
+    "moe_route", "moe_dispatch", "moe_experts", "moe_combine", "moe_shared",
+    "lm_head", "lm_loss")
 
 
 # Rows longer than this many positions take the output head and its loss in
@@ -348,7 +354,7 @@ def make_train_step(
     """
     # What the model is trained on: ``mlm`` (BERT: masked tokens and next
     # sentence) unless the model's family says otherwise
-    # (models/nemotron_h.py: ``causal_lm``, rows of token ids).
+    # (models/decoder.py: ``causal_lm``, rows of token ids).
     objective = getattr(model, "objective", "mlm")
     if objective not in ("mlm", "causal_lm"):
         raise ValueError(f"unknown objective {objective!r}")

@@ -143,6 +143,47 @@ def nemotron_h_train_flops_per_seq(config, seq_len: int) -> float:
         nemotron_h_forward_flops_per_token(config, seq_len).values())
 
 
+def laguna_forward_flops_per_token(config, seq_len: int) -> dict:
+    """Forward matmul FLOPs per token of a ``laguna`` model on THIS chip (the
+    heads, experts and vocabulary rows it holds), by part: ``attention_proj``
+    (q, k, v, the per-head gate and the output projection of every layer),
+    ``attention_full`` and ``attention_window`` (the two S x S products over
+    the pairs a row really sees: the causal half, or the band of
+    ``sliding_window`` positions), ``dense_mlp`` (three products),
+    ``experts`` (router, gated shared expert, and the gated routed experts by
+    the EXPECTED top_k x held / all of the tokens), ``head``. Embedding
+    lookup, norms, rotary, activations and the optimizer are left out."""
+    h, hd, kv = config.hidden_size, config.head_dim, config.num_key_value_heads
+    parts = dict.fromkeys(("attention_proj", "attention_full",
+                           "attention_window", "dense_mlp", "experts"), 0.0)
+    expected = (config.num_experts_per_tok * config.num_experts
+                / config.router_experts)
+    for layer, heads in enumerate(config.num_attention_heads_per_layer):
+        parts["attention_proj"] += (4 * h * heads * hd + 4 * h * kv * hd
+                                    + 2 * h * heads)
+        window = config.window_of(layer)
+        reach = min(window or seq_len, seq_len)
+        seen = reach - reach * (reach - 1) / (2 * seq_len)  # keys a row sees
+        parts["attention_window" if window else "attention_full"] += (
+            4 * seen * heads * hd)
+        if config.mlp_layer_types[layer] == "dense":
+            parts["dense_mlp"] += 6 * h * config.intermediate_size
+        else:
+            parts["experts"] += (
+                2 * h * config.router_experts
+                + 6 * h * config.shared_expert_intermediate_size
+                + expected * 6 * h * config.moe_intermediate_size)
+    return dict(parts, head=2.0 * h * config.vocab_size)
+
+
+def causal_lm_train_flops_per_seq(config, seq_len: int) -> float:
+    """Training (3x forward) matmul FLOPs of one row of ``seq_len`` tokens of
+    a ``causal_lm`` family's model (by the config's ``model_type``)."""
+    per_token = {"nemotron_h": nemotron_h_forward_flops_per_token,
+                 "laguna": laguna_forward_flops_per_token}[config.model_type]
+    return 3.0 * seq_len * sum(per_token(config, seq_len).values())
+
+
 def mfu(seq_per_sec_per_chip: float, flops_per_seq: float,
         device_kind: str) -> Optional[float]:
     """Fraction of the chip's peak used by model FLOPs; None ("not

@@ -464,7 +464,8 @@ def prepare_model(args, mesh):
     """Model config + auto-resume discovery (reference prepare_model,
     run_pretraining.py:233-274)."""
     # The family (and with it the model and the objective) comes from the
-    # file's ``model_type``: none is BERT, ``nemotron_h`` the hybrid decoder.
+    # file's ``model_type``: none is BERT, ``nemotron_h`` the hybrid decoder,
+    # ``laguna`` the decoder of mixed window and full attention.
     config = load_model_config(args.model_config_file)
     if config.vocab_size % 8 != 0:  # MXU-friendly padding (reference :237)
         config.vocab_size += 8 - (config.vocab_size % 8)
@@ -687,7 +688,7 @@ def main(args) -> dict:
         # No position table: the rows' own length is the sequence length, and
         # the parameters do not depend on it (a short sample initializes).
         seq_len = int(loader.dataset[0]["input_ids"].shape[-1])
-        sample = (jnp.zeros((1, config.chunk_size), jnp.int32),)
+        sample = (jnp.zeros((1, config.init_sample_length), jnp.int32),)
     else:
         seq_len = config.max_position_embeddings
         sample = (jnp.zeros((1, seq_len), jnp.int32),) * 3
@@ -869,7 +870,7 @@ def main(args) -> dict:
 
         def flops_per_seq(seq):
             if causal_lm:
-                return flops_util.nemotron_h_train_flops_per_seq(config, seq)
+                return flops_util.causal_lm_train_flops_per_seq(config, seq)
             return flops_util.bert_train_flops_per_seq(
                 config, seq, eff_max_pred,
                 next_sentence=bool(config.next_sentence))
@@ -1126,10 +1127,10 @@ def main(args) -> dict:
                                 mlm_accuracy=last_metrics.get(
                                     "mlm_accuracy", 0.0),
                                 grad_norm=last_metrics.get("grad_norm", 0.0),
-                                # the expert layers' routing counters
-                                # (causal_lm objective; pretrain._aux_metrics)
+                                # the decoder's counters: routing, score
+                                # tiles (causal_lm; pretrain._aux_metrics)
                                 **{k: v for k, v in last_metrics.items()
-                                   if k.startswith("moe_")})
+                                   if k.startswith(("moe_", "attn_"))})
 
                     if (eval_step is not None
                             and global_step % args.num_steps_per_eval == 0):

@@ -39,13 +39,26 @@ jax.config.update("jax_default_matmul_precision", "highest")
 import pytest  # noqa: E402
 
 
+# What a runner leaves set in the process beside the PRNG impl: its compile
+# cache puts the ops' names and source lines into the cache key
+# (utils/compile_cache.py enable_compile_cache).
+_RUNNER_FLAGS = {flag: getattr(jax.config, flag) for flag in (
+    "jax_compilation_cache_include_metadata_in_key",
+    "jax_traceback_in_locations_limit")}
+
+
 @pytest.fixture(autouse=True)
 def _reset_prng_impl():
     """run_pretraining sets the process-global PRNG impl (--rng_impl, default
-    'rbg'); reset it so tests that ran after a runner test see the same
-    threefry streams as tests that ran first."""
+    'rbg') and its compile cache's key flags; reset them so tests that ran
+    after a runner test see the same threefry streams and the same cache keys
+    as tests that ran first (which worker runs which file after which is
+    xdist's choice: tests/test_telemetry.py's cache-hit test failed after a
+    runner test of another file had run in its worker)."""
     yield
     jax.config.update("jax_default_prng_impl", "threefry2x32")
+    for flag, value in _RUNNER_FLAGS.items():
+        jax.config.update(flag, value)
 
 
 @pytest.fixture

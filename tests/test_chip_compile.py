@@ -143,6 +143,26 @@ def test_causal_flash_attention_compiles_at_8192(chip):
     _assert_kernel(compiled, "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
 
 
+def test_windowed_flash_attention_compiles_at_8192(chip):
+    """The window flag at the laguna cell's geometry: one row of 8192 tokens,
+    36 query heads of 128 on 4 key-value heads, a window of one 512-wide
+    tile; the loops' three dynamic segments (masked, unmasked, masked) have
+    to pass Mosaic, and the calls carry the windowed kernels' names."""
+    from bert_pytorch_tpu.ops.attention import dot_product_attention
+
+    def loss(q, k, v):
+        return jnp.sum(dot_product_attention(
+            q, k, v, backend="pallas", causal=True,
+            window=512).astype(jnp.float32))
+
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1, 2)), chip,
+        ((1, 8192, 36, 128), jnp.bfloat16), ((1, 8192, 4, 128), jnp.bfloat16),
+        ((1, 8192, 4, 128), jnp.bfloat16))
+    _assert_kernel(compiled, "flash_window_fwd", "flash_window_bwd_dq",
+                   "flash_window_bwd_dkv")
+
+
 # -- the serving kernels ----------------------------------------------------
 
 @pytest.mark.parametrize("seq", [32, 128, 512])
