@@ -25,6 +25,17 @@ next step's device phase, where overlap hides it.)
 ``depth <= 0`` degrades to inline staging on the consumer thread — same
 iterator contract and gauges, no background thread — so one code path
 serves ``--device_prefetch 0`` everywhere.
+
+A prefetcher lives as long as its source. The fine-tuning runners hand it
+one pass of their loader and build the next when that has ended (the new
+epoch then starts from an empty queue). ``run_pretraining.py`` hands it
+``data/loader.py epoch_chain`` through ``pretrain.device_prefetch``: ONE
+feed a run whose source never ends, whose items are ``(epoch, batch)``,
+and whose producer thread, not the loop, steps the sampler into the next
+epoch — so at a boundary the consumer finds the new epoch's first batches
+staged. The sampler's live position is then the producer's; a checkpoint
+records the epoch of the batch last trained and the rows trained in it
+(docs/fault_tolerance.md).
 """
 
 from __future__ import annotations
@@ -55,9 +66,9 @@ class DevicePrefetcher:
     ``source`` yields host items; ``stage(item)`` moves one to device
     (e.g. ``pretrain.put_batch`` with the step's input shardings). Errors
     from either surface at the consumer's ``next()``. Call ``close()``
-    when abandoning the iterator mid-epoch (the runners do, in their
-    ``finally``): it sets the stop event — which aborts a thread parked
-    in its blocked put — and briefly joins; a thread stuck inside an
+    when abandoning the iterator before its source ends (the runners do,
+    in their ``finally``): it sets the stop event — which aborts a thread
+    parked in its blocked put — and briefly joins; a thread stuck inside an
     uninterruptible ``next(source)`` is left to daemon teardown but will
     not touch the staging fn again (see :meth:`close`).
     """

@@ -20,7 +20,9 @@ forward-moving access pattern (strictly increasing indices per worker;
 forward skips allowed) match the thread path, and the live sampler.index
 tracks DELIVERED batches exactly (the thread path's runs ahead by the
 prefetch queue; resume goes through the runner's trained_index either
-way). Masking draws derive from (seed base, epoch, sample index) inside
+way). ``epoch_chain`` strings the passes of one loader together, epoch
+after epoch, as the source of the pretraining run's one feed. Masking
+draws derive from (seed base, epoch, sample index) inside
 the dataset (data/dataset.py, PR 5) — workers need no per-worker reseed
 to decorrelate, epochs still re-draw, and thread and process paths
 produce byte-identical features (the resume-exactness invariant,
@@ -36,6 +38,7 @@ a recompile for one step.
 
 from __future__ import annotations
 
+import itertools
 import multiprocessing as mp
 import queue
 import threading
@@ -101,6 +104,41 @@ def _worker_main(dataset, index_batches, out_queue, stop_event, worker_id):
         if not _bounded_put(out_queue, (bno, batch), stop_event):
             return
     _bounded_put(out_queue, (None, None), stop_event)
+
+
+def epoch_chain(loader, start_epoch: int = 0) -> Iterator[tuple]:
+    """``(epoch, batch)`` for every batch of ``loader``, epoch after epoch
+    without end: the source of a run's one feed (pretrain.device_prefetch).
+
+    When the loader's iterator for epoch *e* is exhausted, whoever is
+    pulling (the device-prefetch thread; the loop under
+    ``--device_prefetch 0``) calls ``sampler.set_epoch(e + 1)`` — which
+    sets the dataset's epoch for the masks' (seed, epoch, index) draw —
+    and goes on with ``iter(loader)``, inside a ``prefetch:epoch_start``
+    span that lasts until the new epoch's first host batch is there. The
+    loader thread of epoch *e* has ended by then (the ``None`` that ended
+    the iterator was the last thing it put), so the dataset is never read
+    under two epochs at once. A sampler restored mid-epoch (index not 0)
+    starts the chain there, and may have no whole batch left; an epoch
+    taken from its start that yields no batch is an error (fewer rows than
+    one batch under ``drop_last``: the chain would spin for ever).
+    """
+    sampler = loader.sampler
+    resumed = sampler.index != 0
+    for epoch in itertools.count(start_epoch):
+        with span("prefetch:epoch_start", epoch=epoch):
+            sampler.set_epoch(epoch)
+            batches = iter(loader)
+            batch = next(batches, None)
+        if batch is None and not resumed:
+            raise RuntimeError(
+                f"epoch {epoch} of the training data holds no batch: "
+                f"{len(sampler)} rows a rank, {loader.batch_size} a batch "
+                f"(drop_last={loader.drop_last})")
+        while batch is not None:
+            yield epoch, batch
+            batch = next(batches, None)
+        resumed = False
 
 
 class DataLoader:

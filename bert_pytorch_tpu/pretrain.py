@@ -921,29 +921,40 @@ def put_batch(batch: dict, shardings: dict) -> dict:
 
 
 def device_prefetch(loader, accum_steps: int, shardings: dict,
-                    depth: int = 2):
-    """Device-resident stacked batches, staged ``depth`` ahead.
+                    depth: int = 2, start_epoch: int = 0):
+    """The run's ONE feed: ``(epoch, device-resident stacked batch)``,
+    staged ``depth`` ahead, epoch after epoch without end.
 
     A :class:`~bert_pytorch_tpu.data.device_prefetch.DevicePrefetcher`
-    over the loader: a background thread stacks the microbatches and
-    dispatches ``device_put`` with the step's input shardings, so the H2D
-    transfer (and the per-call dispatch latency) hides behind device
-    compute — the role the reference's 4 pinned-memory DataLoader workers
-    + non_blocking copies play on GPU (run_pretraining.py:394-395,539).
-    With this in place the real input pipeline matches the
-    synthetic-resident-batch bench (~400 seq/s, BERT-large phase 1 batch
-    56 on one v5e), the loop's ``data_wait`` measures only true producer
-    stalls, and the staging share reports as telemetry's ``h2d_wait``
-    sub-phase (attach the returned prefetcher to TrainTelemetry).
-    ``depth <= 0`` stages inline on the loop thread.
+    over :func:`~bert_pytorch_tpu.data.loader.epoch_chain` of the loader:
+    a background thread stacks the microbatches and dispatches
+    ``device_put`` with the step's input shardings, so the H2D transfer
+    (and the per-call dispatch latency) hides behind device compute — the
+    role the reference's 4 pinned-memory DataLoader workers +
+    non_blocking copies play on GPU (run_pretraining.py:394-395,539).
+    When the loader's epoch is exhausted that thread, not the loop, steps
+    the sampler (and so the masks) into the next one and goes on: at a
+    boundary the loop finds the new epoch's first batches staged. Every
+    item carries the epoch its rows and masks were drawn under; the
+    sampler's live ``epoch`` and ``index`` are the PRODUCER's, ahead of
+    training, so a checkpoint records the epoch of the batch last trained
+    and the rows trained in it (run_pretraining.py), and a resume passes
+    that epoch as ``start_epoch`` over a sampler restored to that index.
+    The loop's ``data_wait`` then measures only true producer stalls, and
+    the staging share reports as telemetry's ``h2d_wait`` sub-phase
+    (attach the returned prefetcher to TrainTelemetry). ``depth <= 0``
+    stages inline on the loop thread.
     """
     from bert_pytorch_tpu.data.device_prefetch import DevicePrefetcher
+    from bert_pytorch_tpu.data.loader import epoch_chain
 
-    return DevicePrefetcher(
-        iter(loader),
-        stage=lambda host: put_batch(
-            stack_microbatches(host, accum_steps), shardings),
-        depth=depth)
+    def stage(item):
+        epoch, host = item
+        return epoch, put_batch(stack_microbatches(host, accum_steps),
+                                shardings)
+
+    return DevicePrefetcher(epoch_chain(loader, start_epoch), stage=stage,
+                            depth=depth)
 
 
 def stack_microbatches(batch: dict, accum_steps: int) -> dict:

@@ -45,13 +45,15 @@ import jax
 # ``train:*`` spans nest in the step's ``train`` annotation on the loop's
 # thread (telemetry/runner.py, telemetry/step_timer.py, run_pretraining.py);
 # ``prefetch:*`` are per batch on the device-prefetch thread
-# (data/device_prefetch.py; on the loop's thread under --device_prefetch 0);
+# (data/device_prefetch.py; on the loop's thread under --device_prefetch 0),
+# ``prefetch:epoch_start`` per epoch inside its ``prefetch:source_wait``
+# (data/loader.py epoch_chain);
 # ``data:*`` are per batch / per shard on the loader's threads
 # (data/loader.py, data/dataset.py). docs/telemetry.md names what each
 # covers; benchmarks/trace/scopes.py reads them.
 SPANS = ("train:feed", "train:dispatch", "train:sync", "train:fetch_metrics",
          "train:log", "train:telemetry", "train:checkpoint", "train:eval",
-         "prefetch:source_wait", "prefetch:h2d",
+         "prefetch:source_wait", "prefetch:h2d", "prefetch:epoch_start",
          "data:shard_load", "data:collate")
 
 

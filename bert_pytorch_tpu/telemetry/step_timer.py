@@ -136,6 +136,11 @@ class StepTimer:
     def data_end(self) -> None:
         self._t_data1 = self._clock()
 
+    def data_wait_s(self) -> float:
+        """The wait for the batch just delivered (between the two marks
+        above)."""
+        return max(0.0, self._t_data1 - self._t_data0)
+
     def dispatch_end(self) -> None:
         self._t_dispatch1 = self._clock()
 
@@ -200,7 +205,7 @@ class StepTimer:
         """
         if self._t_data0 is None or self._t_data1 is None:
             return None  # marks were skipped (e.g. epoch boundary)
-        self._data_waits.append(max(0.0, self._t_data1 - self._t_data0))
+        self._data_waits.append(self.data_wait_s())
         if self._h2d_attached:
             # Clamp to the step's own data_wait: h2d is a SUB-phase of it
             # (steps with no note contribute 0 — the prefetcher reported

@@ -944,7 +944,6 @@ def main(args) -> dict:
         train_start = time.perf_counter()
         samples_seen = 0
         last_metrics = {}
-        done = False
         # Graceful preemption (docs/fault_tolerance.md; beyond the
         # reference, whose only fault model is die-and-resubmit, SURVEY
         # §5.3): TPU-VM maintenance events and SLURM preemption deliver
@@ -972,13 +971,20 @@ def main(args) -> dict:
         # ``index`` runs ahead of training by the loader queue plus the
         # device_prefetch depth (the reference's checkpoints have the same
         # skew from its 4 DataLoader workers, src/dataset.py:401-425 — data
-        # those pipelines had buffered is silently skipped on resume).
-        # Checkpoints therefore save THIS counter, not the live index.
+        # those pipelines had buffered is silently skipped on resume), and
+        # near the end of an epoch its live ``epoch`` does too: the feed
+        # crosses the boundary batches before the loop does. Checkpoints
+        # therefore save THIS counter and the TRAINED epoch (``epoch``, taken
+        # from the batch just trained), not the sampler's live pair.
         trained_index = sampler.index
+        # Epoch boundaries the loop trained across, and what it waited for
+        # the first batch of each new epoch, summed (run summary).
+        feed_epoch_boundaries = 0
+        feed_boundary_wait_s = 0.0
 
         def sampler_checkpoint_state():
             s = sampler.state_dict()
-            s["index"] = trained_index
+            s["index"], s["epoch"] = trained_index, epoch
             return s
 
         def dispatch_step(state, batch, kfac_state, global_step):
@@ -1029,177 +1035,176 @@ def main(args) -> dict:
         # callers must not inherit a handler over a dead flag).
         prefetcher = None
         try:
-            while not done:
-                sampler.set_epoch(epoch)
-                # Device prefetch (data/device_prefetch.py): a background
-                # thread keeps --device_prefetch batches resident on
-                # device, so data_wait below measures only true producer
-                # stalls and the staging share reports as the h2d_wait
-                # sub-phase. One prefetcher per epoch (the iterator is
-                # one-shot); closed in the finally so an abandoned epoch
-                # never leaks its thread.
-                prefetcher = pretrain.device_prefetch(
-                    loader, args.accumulation_steps, b_shardings,
-                    depth=args.device_prefetch)
-                tele.attach_prefetcher(prefetcher)
-                # The profiler's step annotation and trace window count
-                # step_in_run indices; the first batch of this epoch feeds
-                # step step_in_run + 1. Every span below nests in it.
-                for batch in tele.timed(iter(prefetcher),
-                                        first_step=step_in_run + 1):
-                    with telemetry.span("train:dispatch"):
-                        state, metrics, kfac_state = dispatch_step(
-                            state, batch, kfac_state, global_step)
-                    tele.dispatch_done()
-                    global_step += 1
-                    step_in_run += 1
-                    trained_index += args.host_batch_per_step
-                    if data_seq_len is None:
-                        data_seq_len = int(batch["input_ids"].shape[-1])
-                        placement["batch_devices"] = _devices_holding(batch)
-                        if not causal_lm:
-                            logger.info(_kept_across_remat(
-                                model, config, args.local_batch_size,
-                                data_seq_len))
-                        if data_seq_len != seq_len:
-                            # MFU must use the DATA shape, not the model cap.
-                            tele.timer.flops_per_seq = flops_per_seq(
-                                data_seq_len)
-                            tele.timer.tokens_per_step = (
-                                args.global_batch_size * data_seq_len)
-                    if not draw_shards_logged and dropout.draw_shards():
-                        # Known once the dropout-on step has been traced.
-                        draw_shards_logged = True
+            # The feed (pretrain.device_prefetch; data/device_prefetch.py):
+            # ONE for the whole run. A background thread keeps
+            # --device_prefetch batches resident on device, so data_wait
+            # below measures only true producer stalls and the staging
+            # share reports as the h2d_wait sub-phase; at the end of an
+            # epoch that thread, not this loop, sets the next epoch and
+            # goes on, so the next epoch's first batches are staged while
+            # this epoch's last are trained. Every item carries the epoch
+            # its rows and masks belong to. Closed in the finally so an
+            # abandoned run never leaks the thread.
+            prefetcher = pretrain.device_prefetch(
+                loader, args.accumulation_steps, b_shardings,
+                depth=args.device_prefetch, start_epoch=epoch)
+            tele.attach_prefetcher(prefetcher)
+            # The profiler's step annotation and trace window count
+            # step_in_run indices from 1. Every span below nests in it.
+            for batch_epoch, batch in tele.timed(iter(prefetcher)):
+                if batch_epoch != epoch:
+                    # The loop crossed into a new epoch (the feed did so
+                    # some batches ago): what it waited for this first
+                    # batch is what the boundary cost.
+                    epoch, trained_index = batch_epoch, 0
+                    feed_epoch_boundaries += 1
+                    feed_boundary_wait_s += tele.timer.data_wait_s()
+                with telemetry.span("train:dispatch"):
+                    state, metrics, kfac_state = dispatch_step(
+                        state, batch, kfac_state, global_step)
+                tele.dispatch_done()
+                global_step += 1
+                step_in_run += 1
+                trained_index += args.host_batch_per_step
+                if data_seq_len is None:
+                    data_seq_len = int(batch["input_ids"].shape[-1])
+                    placement["batch_devices"] = _devices_holding(batch)
+                    if not causal_lm:
+                        logger.info(_kept_across_remat(
+                            model, config, args.local_batch_size,
+                            data_seq_len))
+                    if data_seq_len != seq_len:
+                        # MFU must use the DATA shape, not the model cap.
+                        tele.timer.flops_per_seq = flops_per_seq(
+                            data_seq_len)
+                        tele.timer.tokens_per_step = (
+                            args.global_batch_size * data_seq_len)
+                if not draw_shards_logged and dropout.draw_shards():
+                    # Known once the dropout-on step has been traced.
+                    draw_shards_logged = True
+                    logger.info(
+                        f"dropout masks drawn in {dropout.draw_shards()} "
+                        "shard(s) of the batch (ops/dropout.py)")
+                if step_in_run > 1:  # skip step-0 compile in throughput
+                    samples_seen += args.global_batch_size
+                if step_in_run == 1:
+                    # Wait for the first step to EXECUTE before starting the
+                    # clock (reference skips step 0 the same way, its
+                    # run_pretraining.py:494-495). Dispatch of step 1 returns
+                    # as soon as compilation ends; without this barrier the
+                    # executable load and the first execution land inside
+                    # the measured window.
+                    jax.block_until_ready(metrics)
+                    train_start = time.perf_counter()
+                if fault_plan.active:
+                    # Armed NaN injection replaces the fetched scalars
+                    # BEFORE the sentinel observes this step.
+                    metrics = fault_plan.poison_metrics(
+                        global_step, metrics, emit=tele.emit)
+                # Telemetry step close-out: device sync (per cadence) +
+                # step-window emission + sentinel policy + heartbeat +
+                # watchdog note. NonFiniteError propagates under
+                # --sentinel_policy abort.
+                tele.step_done(global_step, metrics)
+
+                if global_step % args.log_steps == 0:
+                    with telemetry.span("train:fetch_metrics"):
+                        last_metrics = {
+                            k: float(v) for k, v in metrics.items()}
+                    if not tele.last_step_synced:
+                        # The float() fetches above were this step's
+                        # sync; feed the sentinel/heartbeat that missed
+                        # the cadence. Both train steps emit the in-jit
+                        # "finite" scalar; the isfinite(loss) fallback
+                        # is defensive for any step that doesn't, so a
+                        # missing key can't read as healthy.
+                        finite = last_metrics.get("finite")
+                        if finite is None:
+                            finite = (1.0 if math.isfinite(
+                                last_metrics["loss"]) else 0.0)
+                        tele.sentinel.observe(
+                            global_step, finite, last_metrics["loss"])
+                        tele.heartbeat.beat(
+                            global_step, last_metrics["loss"])
+                    elapsed = time.perf_counter() - train_start
+                    with telemetry.span("train:log"):
+                        logger.log(
+                            tag="train", step=global_step, epoch=epoch,
+                            average_loss=last_metrics["loss"],
+                            step_loss=last_metrics["loss"],
+                            learning_rate=last_metrics.get(
+                                "learning_rate", 0.0),
+                            samples_per_second=samples_seen / max(
+                                elapsed, 1e-9),
+                            mlm_accuracy=last_metrics.get(
+                                "mlm_accuracy", 0.0),
+                            grad_norm=last_metrics.get("grad_norm", 0.0),
+                            # the decoder's counters: routing, score
+                            # tiles (causal_lm; pretrain._aux_metrics)
+                            **{k: v for k, v in last_metrics.items()
+                               if k.startswith(("moe_", "attn_"))})
+
+                if (eval_step is not None
+                        and global_step % args.num_steps_per_eval == 0):
+                    with telemetry.span("train:eval"):
+                        run_validation(state.params, global_step, epoch)
+
+                if global_step % args.num_steps_per_checkpoint == 0:
+                    save_step = global_step + args.previous_phase_end_step
+                    contents = {"model": state.params,
+                                "optimizer": state.opt_state,
+                                "sampler": sampler_checkpoint_state(),
+                                "epoch": epoch}
+                    if kfac_state is not None:
+                        contents["preconditioner"] = kfac_state
+                    # Async (default): the loop pays only the
+                    # device-side snapshot copy; the D2H fetch +
+                    # msgpack + disk write overlap the next training
+                    # steps. The stall context flags this step's
+                    # duration (+ the save block) as a ckpt_step in
+                    # the telemetry windows either way — what the
+                    # checkpoint-step p95 comparison reads.
+                    with tele.checkpoint_stall():
+                        ckpt.save_checkpoint(
+                            args.model_output_dir, save_step, contents,
+                            keep=args.keep_checkpoints,
+                            async_write=args.checkpoint_write == "async",
+                            layout=args.checkpoint_layout,
+                            mesh_spec=args.mesh_spec.as_dict())
+                    logger.info(f"Saved checkpoint at step {save_step}")
+
+                if fault_plan.active:
+                    # die/term/hang fire AFTER the checkpoint block:
+                    # die@N resumes from whatever N's cadence durably
+                    # wrote — the hard-preemption model under test.
+                    fault_plan.fire_process_faults(
+                        global_step, emit=tele.emit)
+
+                if (args.term_check_steps
+                        and global_step % args.term_check_steps == 0):
+                    flagged = stop.requested
+                    if jax.process_count() > 1:
+                        # Any-host semantics: the scheduler may signal hosts
+                        # at different times; stop only when agreed, at the
+                        # same step on every host (this allgather is the
+                        # agreement point — all hosts reach it).
+                        from jax.experimental import multihost_utils
+                        flagged = bool(multihost_utils.process_allgather(
+                            np.asarray([flagged])).any())
+                    if flagged:
                         logger.info(
-                            f"dropout masks drawn in {dropout.draw_shards()} "
-                            "shard(s) of the batch (ops/dropout.py)")
-                    if step_in_run > 1:  # skip step-0 compile in throughput
-                        samples_seen += args.global_batch_size
-                    if step_in_run == 1:
-                        # Wait for the first step to EXECUTE before starting the
-                        # clock (reference skips step 0 the same way, its
-                        # run_pretraining.py:494-495). Dispatch of step 1 returns
-                        # as soon as compilation ends; without this barrier the
-                        # executable load and the first execution land inside
-                        # the measured window.
-                        jax.block_until_ready(metrics)
-                        train_start = time.perf_counter()
-                    if fault_plan.active:
-                        # Armed NaN injection replaces the fetched scalars
-                        # BEFORE the sentinel observes this step.
-                        metrics = fault_plan.poison_metrics(
-                            global_step, metrics, emit=tele.emit)
-                    # Telemetry step close-out: device sync (per cadence) +
-                    # step-window emission + sentinel policy + heartbeat +
-                    # watchdog note. NonFiniteError propagates under
-                    # --sentinel_policy abort.
-                    tele.step_done(global_step, metrics)
-
-                    if global_step % args.log_steps == 0:
-                        with telemetry.span("train:fetch_metrics"):
-                            last_metrics = {
-                                k: float(v) for k, v in metrics.items()}
-                        if not tele.last_step_synced:
-                            # The float() fetches above were this step's
-                            # sync; feed the sentinel/heartbeat that missed
-                            # the cadence. Both train steps emit the in-jit
-                            # "finite" scalar; the isfinite(loss) fallback
-                            # is defensive for any step that doesn't, so a
-                            # missing key can't read as healthy.
-                            finite = last_metrics.get("finite")
-                            if finite is None:
-                                finite = (1.0 if math.isfinite(
-                                    last_metrics["loss"]) else 0.0)
-                            tele.sentinel.observe(
-                                global_step, finite, last_metrics["loss"])
-                            tele.heartbeat.beat(
-                                global_step, last_metrics["loss"])
-                        elapsed = time.perf_counter() - train_start
-                        with telemetry.span("train:log"):
-                            logger.log(
-                                tag="train", step=global_step, epoch=epoch,
-                                average_loss=last_metrics["loss"],
-                                step_loss=last_metrics["loss"],
-                                learning_rate=last_metrics.get(
-                                    "learning_rate", 0.0),
-                                samples_per_second=samples_seen / max(
-                                    elapsed, 1e-9),
-                                mlm_accuracy=last_metrics.get(
-                                    "mlm_accuracy", 0.0),
-                                grad_norm=last_metrics.get("grad_norm", 0.0),
-                                # the decoder's counters: routing, score
-                                # tiles (causal_lm; pretrain._aux_metrics)
-                                **{k: v for k, v in last_metrics.items()
-                                   if k.startswith(("moe_", "attn_"))})
-
-                    if (eval_step is not None
-                            and global_step % args.num_steps_per_eval == 0):
-                        with telemetry.span("train:eval"):
-                            run_validation(state.params, global_step, epoch)
-
-                    if global_step % args.num_steps_per_checkpoint == 0:
-                        save_step = global_step + args.previous_phase_end_step
-                        contents = {"model": state.params,
-                                    "optimizer": state.opt_state,
-                                    "sampler": sampler_checkpoint_state(),
-                                    "epoch": epoch}
-                        if kfac_state is not None:
-                            contents["preconditioner"] = kfac_state
-                        # Async (default): the loop pays only the
-                        # device-side snapshot copy; the D2H fetch +
-                        # msgpack + disk write overlap the next training
-                        # steps. The stall context flags this step's
-                        # duration (+ the save block) as a ckpt_step in
-                        # the telemetry windows either way — what the
-                        # checkpoint-step p95 comparison reads.
-                        with tele.checkpoint_stall():
-                            ckpt.save_checkpoint(
-                                args.model_output_dir, save_step, contents,
-                                keep=args.keep_checkpoints,
-                                async_write=args.checkpoint_write == "async",
-                                layout=args.checkpoint_layout,
-                                mesh_spec=args.mesh_spec.as_dict())
-                        logger.info(f"Saved checkpoint at step {save_step}")
-
-                    if fault_plan.active:
-                        # die/term/hang fire AFTER the checkpoint block:
-                        # die@N resumes from whatever N's cadence durably
-                        # wrote — the hard-preemption model under test.
-                        fault_plan.fire_process_faults(
-                            global_step, emit=tele.emit)
-
-                    if (args.term_check_steps
-                            and global_step % args.term_check_steps == 0):
-                        flagged = stop.requested
-                        if jax.process_count() > 1:
-                            # Any-host semantics: the scheduler may signal hosts
-                            # at different times; stop only when agreed, at the
-                            # same step on every host (this allgather is the
-                            # agreement point — all hosts reach it).
-                            from jax.experimental import multihost_utils
-                            flagged = bool(multihost_utils.process_allgather(
-                                np.asarray([flagged])).any())
-                        if flagged:
-                            logger.info(
-                                f"termination signal "
-                                f"({stop.signal_name or 'peer host'}) "
-                                "received; writing the final checkpoint "
-                                "and exiting cleanly "
-                                f"(exit code {preemption.EXIT_PREEMPTED})")
-                            tele.emit(preemption.preemption_record(
-                                global_step, stop))
-                            terminated = True
-                            done = True
-                            break
-
-                    if step_in_run >= steps_this_run or global_step >= args.max_steps:
-                        done = True
+                            f"termination signal "
+                            f"({stop.signal_name or 'peer host'}) "
+                            "received; writing the final checkpoint "
+                            "and exiting cleanly "
+                            f"(exit code {preemption.EXIT_PREEMPTED})")
+                        tele.emit(preemption.preemption_record(
+                            global_step, stop))
+                        terminated = True
                         break
-                else:
-                    epoch += 1
-                    trained_index = 0
-                    continue
-                break
+
+                if step_in_run >= steps_this_run or global_step >= args.max_steps:
+                    break
 
             if tele.profiler.active:  # run ended inside the profile window
                 tele.profiler.stop(sync_target=metrics)
@@ -1210,6 +1215,11 @@ def main(args) -> dict:
             seq_per_sec = samples_seen / max(train_time, 1e-9)
             logger.info(f"Total time: {train_time:.2f} s")
             logger.info(f"training_seq_per_sec = {seq_per_sec:.2f}")
+            if feed_epoch_boundaries:
+                logger.info(
+                    f"the loop trained across {feed_epoch_boundaries} epoch "
+                    f"boundaries and waited {feed_boundary_wait_s:.4f} s in "
+                    "all for the first batches of the new epochs")
             # MFU: hardware-normalised counterpart of seq/s (the reference
             # reports raw seq/s only, run_pretraining.py:597-599); None — not
             # measured, and absent from the summary — off a TPU (the CPU
@@ -1255,6 +1265,11 @@ def main(args) -> dict:
                 # mask (ops/dropout.py): the data-parallel size when every
                 # chip draws its own rows, 1 on one chip, 0 without dropout.
                 "dropout_draw_shards": dropout.draw_shards(),
+                # The one feed of the run goes on across epochs: boundaries
+                # the loop trained across, and its waits (train:feed) for
+                # the first batch of each new epoch, summed.
+                "feed_epoch_boundaries": feed_epoch_boundaries,
+                "feed_boundary_wait_s": round(feed_boundary_wait_s, 6),
                 # What the run ran on (platform, device_kind, device_count,
                 # kernels compiled|interpreted): a number in this artifact
                 # is a device number only if this says "tpu".

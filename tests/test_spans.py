@@ -32,8 +32,11 @@ def _tiny_run(tmp, out_name):
     data = tmp / "data"
     if not data.exists():
         data.mkdir()
+        # 8 updates an epoch: the feed, some batches ahead of the 3 updates
+        # trained, stays inside epoch 0 (a second pass over the shards would
+        # load them again, each load on a thread of its own)
         for i in range(2):
-            make_shard(str(data / f"shard_{i}.hdf5"), 64, 32, 1000, seed=i)
+            make_shard(str(data / f"shard_{i}.hdf5"), 128, 32, 1000, seed=i)
         (tmp / "model.json").write_text(json.dumps({
             "vocab_size": 1000, "hidden_size": 32, "num_hidden_layers": 2,
             "num_attention_heads": 4, "intermediate_size": 64,
@@ -146,7 +149,14 @@ def test_feeding_threads_write_their_own_spans(runs):
     [producer] = _line_of(runs, "prefetch:source_wait")
     assert producer is not loop
     assert {e[0] for e in producer} == {"prefetch:source_wait",
-                                        "prefetch:h2d"}
+                                        "prefetch:h2d",
+                                        "prefetch:epoch_start"}
+    # the epoch's start lies inside the pull that met it
+    [(_, start, end, stats)] = [e for e in producer
+                                if e[0] == "prefetch:epoch_start"]
+    assert stats["epoch"] == 0
+    assert any(s <= start and end <= e for name, s, e, _ in producer
+               if name == "prefetch:source_wait")
     [loader] = _line_of(runs, "data:collate")
     [shards] = _line_of(runs, "data:shard_load")
     assert len({id(loop), id(producer), id(loader), id(shards)}) == 4
