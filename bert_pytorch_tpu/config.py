@@ -15,6 +15,7 @@ from __future__ import annotations
 import argparse
 import copy
 import json
+import math
 import sys
 from typing import Any
 
@@ -318,8 +319,153 @@ class LagunaConfig:
         return 16
 
 
+def phi_flash_layer_types(n: int) -> list:
+    """The published rule of the ``phi4flash`` family for a model of ``n``
+    layers (``n % 4 == 0``), layer ``l`` of it: the first half is the
+    self-decoder (even ``l`` a Mamba-1 mixer, odd ``l`` differential attention
+    under the sliding window), layer ``n / 2`` a Mamba-1 mixer whose scan
+    output is kept as the memory, layer ``n / 2 + 1`` full causal differential
+    attention whose keys and values are kept, and from there on even ``l`` a
+    gated memory unit on that memory and odd ``l`` differential
+    cross-attention on those keys and values."""
+    if n < 4 or n % 4:
+        raise ValueError(f"the phi4flash layer rule needs n % 4 == 0, got {n}")
+    half = n // 2
+    return [("mamba" if l % 2 == 0 else "sliding_attention") if l < half else
+            "mamba_memory" if l == half else
+            "full_attention" if l == half + 1 else
+            "gmu" if l % 2 == 0 else "cross_attention" for l in range(n)]
+
+
+class PhiFlashConfig:
+    """Configuration of the ``phi4flash`` family: a pre-norm residual decoder
+    whose every layer is a mixer, then a gated MLP, under LayerNorm with
+    bias, no positions anywhere and a head tied to the embedding. The mixer
+    is one of six kinds (``layer_types``; :func:`phi_flash_layer_types` gives
+    the published rule): ``mamba`` (a Mamba-1 selective scan), ``mamba_memory``
+    (the same, its scan output handed on as the memory), ``sliding_attention``
+    / ``full_attention`` (differential attention under the window / the whole
+    prefix; the full layer hands on its keys and values), ``gmu`` (a gated
+    memory unit on the memory) and ``cross_attention`` (differential
+    attention with its own queries on the kept keys and values). Keys and
+    defaults are the published ``config.json``'s
+    (microsoft/Phi-4-mini-flash-reasoning); extra keys ride along as on
+    :class:`BertConfig`.
+
+    ``layer_indices`` holds each layer's index in the PUBLISHED model of
+    ``published_num_hidden_layers`` layers (the differential attention's
+    ``lambda_init`` depends on it); a model cut to some layers of the
+    published one states both lists. The chip's share of the heads is stated
+    here, as :class:`LagunaConfig` states it: ``num_attention_heads`` and
+    ``num_key_value_heads`` count the heads held of ``tp_size`` times as many
+    (the output projection then adds only their terms, and its bias on rank 0
+    alone).
+    """
+
+    model_type = "phi4flash"
+
+    def __init__(self, **values: Any):
+        defaults = dict(
+            vocab_size=200064, hidden_size=2560, intermediate_size=10240,
+            num_hidden_layers=32, num_attention_heads=40,
+            num_key_value_heads=20, tp_size=1, tp_rank=0,
+            sliding_window=512, layer_norm_eps=1e-5, hidden_act="silu",
+            mb_per_layer=2, max_position_embeddings=262144,
+            embd_pdrop=0, resid_pdrop=0, mlp_bias=False, lm_head_bias=False,
+            tie_word_embeddings=True, initializer_range=0.02,
+            mamba_d_state=16, mamba_d_conv=4, mamba_expand=2,
+            mamba_dt_rank=None, mamba_conv_bias=True, mamba_proj_bias=False,
+            time_step_min=0.001, time_step_max=0.1, lambda_std=0.1,
+            scan_chunk=128, layer_types=None, layer_indices=None,
+            published_num_hidden_layers=None)
+        for key, value in {**defaults, **values}.items():
+            setattr(self, key, value)
+        layers = self.num_hidden_layers
+        if self.mamba_dt_rank is None:
+            self.mamba_dt_rank = -(-self.hidden_size // 16)
+        if self.published_num_hidden_layers is None:
+            self.published_num_hidden_layers = layers
+        if self.layer_indices is None:
+            self.layer_indices = list(range(layers))
+        if self.layer_types is None:
+            rule = phi_flash_layer_types(self.published_num_hidden_layers)
+            self.layer_types = [rule[l] for l in self.layer_indices]
+        kinds = {"mamba", "mamba_memory", "sliding_attention",
+                 "full_attention", "gmu", "cross_attention"}
+        if (len(self.layer_types) != layers or set(self.layer_types) - kinds
+                or len(self.layer_indices) != layers):
+            raise ValueError(
+                f"layer_types and layer_indices must be {layers} long, of "
+                f"{sorted(kinds)}: {self.layer_types}, {self.layer_indices}")
+        for reader, writer in (("gmu", "mamba_memory"),
+                               ("cross_attention", "full_attention")):
+            if reader in self.layer_types and (
+                    writer not in self.layer_types
+                    or self.layer_types.index(writer)
+                    > self.layer_types.index(reader)):
+                raise ValueError(
+                    f"a {reader} layer reads what a {writer} layer before it "
+                    f"hands on: {self.layer_types}")
+        if self.layer_types.count("mamba_memory") > 1 or (
+                self.layer_types.count("full_attention") > 1):
+            raise ValueError(
+                "one layer writes the memory and one the kept keys and "
+                f"values: {self.layer_types}")
+        heads, kv = self.num_attention_heads, self.num_key_value_heads
+        if (heads % 2 or kv % 2 or heads % kv
+                or self.hidden_size % (heads * self.tp_size)):
+            raise ValueError(
+                f"{heads} query heads on {kv} key-value heads (both in "
+                f"pairs) of hidden {self.hidden_size} / ({heads} x "
+                f"{self.tp_size})")
+        if not 0 <= self.tp_rank < self.tp_size:
+            raise ValueError(f"tp_rank {self.tp_rank} of tp_size {self.tp_size}")
+        if (not self.tie_word_embeddings or self.mlp_bias or self.lm_head_bias
+                or self.hidden_act != "silu" or self.mb_per_layer != 2
+                or self.embd_pdrop or self.resid_pdrop
+                or self.mamba_proj_bias or not self.mamba_conv_bias):
+            raise ValueError(
+                "phi4flash is built with a tied head without bias, a silu "
+                "MLP without bias, one Mamba layer in two, no dropout, a "
+                "convolution bias and no projection bias in the mixer")
+
+    @classmethod
+    def from_dict(cls, json_object: dict) -> "PhiFlashConfig":
+        values = {k: v for k, v in json_object.items() if k != "model_type"}
+        return cls(**values)
+
+    def to_dict(self) -> dict:
+        return dict(copy.deepcopy(self.__dict__), model_type=self.model_type)
+
+    @property
+    def head_dim(self) -> int:
+        """Width of one query or key head (the values of a pair are twice
+        as wide): over the PUBLISHED head count, ``tp_size`` shares."""
+        return self.hidden_size // (self.num_attention_heads * self.tp_size)
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_expand * self.hidden_size
+
+    def window_of(self, layer: int):
+        """The attention window of layer ``layer``: None where it sees the
+        whole prefix."""
+        return (self.sliding_window
+                if self.layer_types[layer] == "sliding_attention" else None)
+
+    def lambda_init(self, layer: int) -> float:
+        """``0.8 - 0.6 exp(-0.3 l)`` with ``l`` the published index."""
+        return 0.8 - 0.6 * math.exp(-0.3 * self.layer_indices[layer])
+
+    @property
+    def init_sample_length(self) -> int:
+        """Positions of the sample that initializes the parameters (none
+        depends on the length)."""
+        return 16
+
+
 MODEL_FAMILIES = {"bert": BertConfig, "nemotron_h": NemotronHConfig,
-                  "laguna": LagunaConfig}
+                  "laguna": LagunaConfig, "phi4flash": PhiFlashConfig}
 
 
 def load_model_config(json_file: str):

@@ -51,6 +51,7 @@ from bert_pytorch_tpu.models.losses import (
 
 from bert_pytorch_tpu.models.laguna import LagunaForCausalLM
 from bert_pytorch_tpu.models.nemotron_h import NemotronHForCausalLM
+from bert_pytorch_tpu.models.phi4flash import PhiFlashForCausalLM
 
 
 def build_pretraining_model(config, dtype, remat: str = "none",
@@ -60,10 +61,11 @@ def build_pretraining_model(config, dtype, remat: str = "none",
     ``model_type``). The model's ``objective`` attribute names what
     ``pretrain.make_train_step`` trains it on."""
     from bert_pytorch_tpu.config import (BertConfig, LagunaConfig,
-                                         NemotronHConfig)
+                                         NemotronHConfig, PhiFlashConfig)
 
     for family, model in ((NemotronHConfig, NemotronHForCausalLM),
                           (LagunaConfig, LagunaForCausalLM),
+                          (PhiFlashConfig, PhiFlashForCausalLM),
                           (BertConfig, BertForPreTraining)):
         if isinstance(config, family):
             return model(config, dtype=dtype, remat=remat,
@@ -74,6 +76,7 @@ def build_pretraining_model(config, dtype, remat: str = "none",
 __all__ = [
     "LagunaForCausalLM",
     "NemotronHForCausalLM",
+    "PhiFlashForCausalLM",
     "build_pretraining_model",
     "next_token_loss",
     "BertEmbeddings",

@@ -1,14 +1,25 @@
 """What the decoder families share (``models/nemotron_h.py``,
-``models/laguna.py``): RMSNorm, the bias-free projection, the routed expert
-layer with its shared expert, and the wrapper round a stack of unlike layers:
-embedding, ``layers_0 .. layers_{L-1}``, final RMSNorm, untied output head,
-next-token objective.
+``models/laguna.py``, ``models/phi4flash.py``): RMSNorm, LayerNorm, the
+bias-free projection, the routed expert layer with its shared expert, and the
+wrapper round a stack of unlike layers: embedding, ``layers_0 ..
+layers_{L-1}``, final norm, output head (untied, or the embedding's
+transpose), next-token objective.
 
 Layers of unlike kinds hold unlike parameters, so they cannot be stacked and
 scanned the way ``models/bert.py`` scans its encoder; each is rematerialized
 on its own (``ops/remat.py``'s policy). A layer returns ``(x, counters)``;
 the wrapper adds the layers' counters up (``*_max_over_mean``: their
 largest) and they ride out of the train step as step metrics.
+
+**The carried path.** A family whose later layers read what an earlier layer
+computed (``CARRIES``: ``models/phi4flash.py``: one layer's scan output, one
+layer's keys and values) has layers that take and return ``(x, carried)``:
+``carried`` is a dict of arrays that starts empty, a writing layer returns it
+with its entry added, and every later layer hands it on. Each block is still
+rematerialized on its own, so a carried tensor is kept once, as the output of
+the block that wrote it, and its cotangent is the sum of what every reader
+and the hand-on return, which autodiff forms. The families that carry nothing
+are called as before and lower as before.
 """
 
 from __future__ import annotations
@@ -20,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from bert_pytorch_tpu.ops import moe
+from bert_pytorch_tpu.ops.layernorm import layer_norm
 from bert_pytorch_tpu.ops.remat import remat_policy
 
 Dtype = Any
@@ -50,6 +62,21 @@ class RMSNorm(nn.Module):
         normed = x32 * jax.lax.rsqrt(
             jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.epsilon)
         return (normed * scale).astype(self.dtype)
+
+
+class LayerNorm(nn.Module):
+    """LayerNorm with weight and bias (``ops/layernorm.py``: float32
+    statistics)."""
+    epsilon: float = 1e-5
+    dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           jnp.float32)
+        bias = self.param("bias", nn.initializers.zeros, (x.shape[-1],),
+                          jnp.float32)
+        return layer_norm(x.astype(self.dtype), scale, bias, self.epsilon)
 
 
 class ExpertLayer(nn.Module):
@@ -122,9 +149,12 @@ class ExpertLayer(nn.Module):
 
 class CausalDecoder(nn.Module):
     """The wrapper: a family gives its ``blocks()`` (modules that take x and
-    return ``(x, counters)``), the norm's epsilon and any counters of its own
-    beside the expert layers' (``COUNTERS``). The model returns ``(logits
-    [B, S, V], counters)``."""
+    return ``(x, counters)``; under ``CARRIES`` they take ``(x, carried)``
+    and return ``(x, carried, counters)``), the norm's epsilon and any
+    counters of its own beside the expert layers' (``COUNTERS``). ``NORM`` is
+    the final norm's class; under ``TIED_HEAD`` the output head is the
+    embedding's transpose and the model holds no ``lm_head``. The model
+    returns ``(logits [B, S, V], counters)``."""
     config: Any
     dtype: Dtype = jnp.float32
     remat: str = "none"
@@ -133,6 +163,9 @@ class CausalDecoder(nn.Module):
     # What pretrain.make_train_step trains these families on.
     objective = "causal_lm"
     COUNTERS = MOE_COUNTERS
+    NORM = RMSNorm
+    TIED_HEAD = False
+    CARRIES = False
 
     def blocks(self, wrap) -> list:
         """The layers, in order; ``wrap`` rematerializes a block class."""
@@ -150,9 +183,16 @@ class CausalDecoder(nn.Module):
         self.layers = self.blocks(
             (lambda block: block) if policy is None else
             (lambda block: nn.remat(block, policy=policy, prevent_cse=True)))
-        self.final_norm = RMSNorm(self.norm_epsilon(), self.dtype)
-        self.lm_head = dense(cfg.vocab_size, cfg.initializer_range,
-                             self.dtype, None)
+        self.final_norm = self.NORM(self.norm_epsilon(), self.dtype)
+        if not self.TIED_HEAD:
+            self.lm_head = dense(cfg.vocab_size, cfg.initializer_range,
+                                 self.dtype, None)
+
+    def head_kernel(self, params):
+        """The output head's [H, V] matrix in a parameter tree of this
+        model (for ``pretrain``'s head in pieces)."""
+        return (params["embedding"].T if self.TIED_HEAD
+                else params["lm_head"]["kernel"])
 
     def hidden_states(self, input_ids):
         """[B, S] ids -> (the final norm's output [B, S, H], counters): all
@@ -160,8 +200,12 @@ class CausalDecoder(nn.Module):
         (models/losses.py ``chunked_next_token_loss``)."""
         x = jnp.take(self.embedding, input_ids, axis=0).astype(self.dtype)
         seen = {name: [] for name in self.COUNTERS}
+        carried = {}
         for layer in self.layers:
-            x, counters = layer(x)
+            if self.CARRIES:
+                x, carried, counters = layer(x, carried)
+            else:
+                x, counters = layer(x)
             for name, value in (counters or {}).items():
                 seen[name].append(value)
         zero = jnp.zeros((), jnp.float32)
@@ -174,4 +218,7 @@ class CausalDecoder(nn.Module):
     def __call__(self, input_ids):
         x, counters = self.hidden_states(input_ids)
         with jax.named_scope("lm_head"):
+            if self.TIED_HEAD:
+                return jnp.matmul(
+                    x, self.embedding.T.astype(self.dtype)), counters
             return self.lm_head(x), counters

@@ -72,11 +72,15 @@ def dot_product_attention(
     sequence_ids: jnp.ndarray | None = None,
     causal: bool = False,
     window: int | None = None,
+    label: str = "",
 ) -> jnp.ndarray:
     """Attention over [B, S, H, D] query/key/value tensors.
 
     Returns [B, S, H, D]. Scores are scaled by 1/sqrt(D) and softmaxed in
-    fp32 (modeling.py:403-429's score path, bf16-safe).
+    fp32 (modeling.py:403-429's score path, bf16-safe). The values may be
+    wider than the queries and keys ([B, S, H, Dv]; the 'xla' and 'pallas'
+    paths): the result is then [B, S, H, Dv]. ``label`` (static) is written
+    into the Pallas kernels' names and changes nothing else.
 
     ``causal`` (static) lets position q attend to positions <= q only: a
     mask on the XLA path; on the Pallas path a static flag of the kernels,
@@ -111,7 +115,28 @@ def dot_product_attention(
             v = jnp.repeat(v, repeats, axis=2)
         return _attention_core(q, k, v, bias, dropout_rng, dropout_rate,
                                deterministic, backend, sequence_ids, causal,
-                               window)
+                               window, label)
+
+
+def differential_attention(q, k, v, backend: str = "xla",
+                           window: int | None = None, label: str = "diff"):
+    """The two causal softmax maps of differential attention (Ye et al.
+    2024, arXiv:2410.05258, in its flash form) in ONE call of the core.
+
+    q [B, S, H, 2, D]: query pair j is (q[..., j, 0, :], q[..., j, 1, :]); k
+    [B, S, KV, 2, D] likewise, H a multiple of KV; v [B, S, KV, Dv], one value
+    a key PAIR (Dv = 2 D where the pair's two value heads are joined). Returns
+    (A1, A2), each [B, S, H, Dv]: ``A_i = softmax(q_i k_i^T / sqrt(D) + mask)
+    v``. The caller subtracts. Both maps are heads of one call: the first
+    components of every pair, then the second, over values as wide as ``v``
+    (the kernels take a value width of their own), so no score is computed
+    twice and no value half is read apart."""
+    heads = q.shape[2]
+    both = lambda t: jnp.concatenate([t[..., 0, :], t[..., 1, :]], axis=2)
+    out = dot_product_attention(
+        both(q), both(k), jnp.concatenate([v, v], axis=2), backend=backend,
+        causal=True, window=window, label=label)
+    return out[:, :, :heads], out[:, :, heads:]
 
 
 def resolve_backend(backend: str, seq: int, dropout: bool) -> str:
@@ -135,7 +160,8 @@ def resolve_backend(backend: str, seq: int, dropout: bool) -> str:
 
 
 def _attention_core(q, k, v, bias, dropout_rng, dropout_rate, deterministic,
-                    backend, sequence_ids, causal=False, window=None):
+                    backend, sequence_ids, causal=False, window=None,
+                    label=""):
     active = not deterministic and dropout_rate > 0.0
     resolved = resolve_backend(backend, q.shape[1], active)
     if window is not None and (not causal or window < 1
@@ -189,7 +215,7 @@ def _attention_core(q, k, v, bias, dropout_rng, dropout_rate, deterministic,
         if not active:
             return flash_attention(q, k, v, bias=kbias,
                                    sequence_ids=sequence_ids, causal=causal,
-                                   window=window)
+                                   window=window, label=label)
         return flash_attention(
             q, k, v, bias=kbias,
             dropout_rate=dropout_rate, dropout_rng=dropout_rng,

@@ -306,7 +306,7 @@ def _flash_fwd_kernel(
 
         m0 = jnp.full((q.shape[0],), _NEG_INF, jnp.float32)
         l0 = jnp.zeros((q.shape[0],), jnp.float32)
-        acc0 = jnp.zeros(q.shape, jnp.float32)
+        acc0 = jnp.zeros((q.shape[0], v_ref.shape[-1]), jnp.float32)
         m, l, acc = _k_loop(body, (m0, l0, acc0), qb, block_q, block_k,
                             num_kb, causal, window)
         out_ref[g] = (acc / (l[:, None] * (1.0 - rate))).astype(out_ref.dtype)
@@ -434,7 +434,7 @@ def _flash_dkv_kernel(
 
         zeros = (
             jnp.zeros((block_k, depth), jnp.float32),
-            jnp.zeros((block_k, depth), jnp.float32),
+            jnp.zeros(v.shape, jnp.float32),
             jnp.zeros((block_k,), jnp.float32),
         )
         if causal:
@@ -474,17 +474,23 @@ def _static(segmented, causal, window=None):
                 **({"window": window} if window else {}))
 
 
-def _name(kernel, window):
+def _name(kernel, window, label=""):
     """``flash_fwd`` / ``flash_window_fwd``: a trace tells the windowed
-    calls from the full ones by name."""
-    return "flash_" + ("window_" if window else "") + kernel
+    calls from the full ones by name, and a caller's ``label`` (``diff``,
+    ``diff_cross``: models/phi4flash.py) its calls from every other's:
+    ``flash_diff_window_fwd``, ``flash_diff_cross_bwd_dq`` ..."""
+    return ("flash_" + (label + "_" if label else "")
+            + ("window_" if window else "") + kernel)
 
 
 def _flash_forward(q3, k3, v3, bias3, seg3, seed, scale, rate, segmented,
-                   causal=False, window=None):
-    """q3/k3/v3: [BH, S, D]; bias3: [BH, 1, S] additive key bias; seg3:
-    [BH, 1, S] fp32 sequence ids (all-zero dummy when not segmented)."""
+                   causal=False, window=None, label=""):
+    """q3/k3: [BH, S, D]; v3: [BH, S, Dv] (the values may be wider than the
+    keys: the output is as wide as they are); bias3: [BH, 1, S] additive key
+    bias; seg3: [BH, 1, S] fp32 sequence ids (all-zero dummy when not
+    segmented)."""
     bh, seq, depth = q3.shape
+    depth_v = v3.shape[-1]
     block_q, block_k = _pick_blocks(seq)
     g = _pick_bh_block(seq, bh)
     grid = (bh // g, seq // block_q)
@@ -496,36 +502,36 @@ def _flash_forward(q3, k3, v3, bias3, seg3, seed, scale, rate, segmented,
             _seed_spec(),
             pl.BlockSpec((g, block_q, depth), lambda b, i: (b, i, 0)),
             pl.BlockSpec((g, seq, depth), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((g, seq, depth), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((g, seq, depth_v), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((g, 1, seq), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((g, 1, seq), lambda b, i: (b, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((g, block_q, depth), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((g, block_q, depth_v), lambda b, i: (b, i, 0)),
             pl.BlockSpec((g, 1, block_q), lambda b, i: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, seq, depth), q3.dtype),
+            jax.ShapeDtypeStruct((bh, seq, depth_v), q3.dtype),
             jax.ShapeDtypeStruct((bh, 1, seq), jnp.float32),
         ],
-        name=_name("fwd", window),
+        name=_name("fwd", window, label),
         interpret=interpret_mode(),
     )(seed, q3, k3, v3, bias3, seg3)
     return out, lse
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
+@partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11))
 def _flash(q3, k3, v3, bias3, seg3, seed, scale, rate, segmented,
-           causal=False, window=None):
+           causal=False, window=None, label=""):
     out, _ = _flash_forward(q3, k3, v3, bias3, seg3, seed, scale, rate,
-                            segmented, causal, window)
+                            segmented, causal, window, label)
     return out
 
 
 def _flash_fwd(q3, k3, v3, bias3, seg3, seed, scale, rate, segmented, causal,
-               window):
+               window, label):
     out, lse = _flash_forward(q3, k3, v3, bias3, seg3, seed, scale, rate,
-                              segmented, causal, window)
+                              segmented, causal, window, label)
     # Named here, in the forward RULE: remat='dots' keeps both (ops/remat.py),
     # which leaves the recomputed pallas_call without a live output, so the
     # backward pass does not run the forward kernel a second time.
@@ -534,9 +540,10 @@ def _flash_fwd(q3, k3, v3, bias3, seg3, seed, scale, rate, segmented, causal,
     return out, (q3, k3, v3, bias3, seg3, seed, out, lse)
 
 
-def _flash_bwd(scale, rate, segmented, causal, window, residuals, g):
+def _flash_bwd(scale, rate, segmented, causal, window, label, residuals, g):
     q3, k3, v3, bias3, seg3, seed, out, lse = residuals
     bh, seq, depth = q3.shape
+    depth_v = v3.shape[-1]
     block_q, block_k = _pick_blocks(seq)
     # delta = rowsum(dO ⊙ O): one cheap fused XLA reduction, [BH, 1, S].
     delta = jnp.sum(
@@ -552,16 +559,16 @@ def _flash_bwd(scale, rate, segmented, causal, window, residuals, g):
             _seed_spec(),
             pl.BlockSpec((gb, block_q, depth), lambda b, i: (b, i, 0)),
             pl.BlockSpec((gb, seq, depth), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((gb, seq, depth), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((gb, seq, depth_v), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((gb, 1, seq), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((gb, 1, seq), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((gb, 1, block_q), lambda b, i: (b, 0, i)),
             pl.BlockSpec((gb, 1, block_q), lambda b, i: (b, 0, i)),
-            pl.BlockSpec((gb, block_q, depth), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((gb, block_q, depth_v), lambda b, i: (b, i, 0)),
         ],
         out_specs=pl.BlockSpec((gb, block_q, depth), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, seq, depth), q3.dtype),
-        name=_name("bwd_dq", window),
+        name=_name("bwd_dq", window, label),
         interpret=interpret_mode(),
     )(seed, q3, k3, v3, bias3, seg3, lse, delta, g)
 
@@ -573,25 +580,25 @@ def _flash_bwd(scale, rate, segmented, causal, window, residuals, g):
             _seed_spec(),
             pl.BlockSpec((gb, seq, depth), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((gb, block_k, depth), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((gb, block_k, depth), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((gb, block_k, depth_v), lambda b, j: (b, j, 0)),
             pl.BlockSpec((gb, 1, block_k), lambda b, j: (b, 0, j)),
             # seg needs the k tile AND every q block: full row, like lse.
             pl.BlockSpec((gb, 1, seq), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((gb, 1, seq), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((gb, 1, seq), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((gb, seq, depth), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((gb, seq, depth_v), lambda b, j: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((gb, block_k, depth), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((gb, block_k, depth), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((gb, block_k, depth_v), lambda b, j: (b, j, 0)),
             pl.BlockSpec((gb, 1, block_k), lambda b, j: (b, 0, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, seq, depth), k3.dtype),
-            jax.ShapeDtypeStruct((bh, seq, depth), v3.dtype),
+            jax.ShapeDtypeStruct((bh, seq, depth_v), v3.dtype),
             jax.ShapeDtypeStruct((bh, 1, seq), jnp.float32),
         ],
-        name=_name("bwd_dkv", window),
+        name=_name("bwd_dkv", window, label),
         interpret=interpret_mode(),
     )(seed, q3, k3, v3, bias3, seg3, lse, delta, g)
 
@@ -854,8 +861,12 @@ def flash_attention_infer_int8(q, k, v, bias=None, sequence_ids=None,
 
 
 def flash_attention(q, k, v, bias=None, dropout_rate=0.0, dropout_rng=None,
-                    sequence_ids=None, causal=False, window=None):
-    """Fused attention over [B, S, H, D] tensors.
+                    sequence_ids=None, causal=False, window=None, label=""):
+    """Fused attention over [B, S, H, D] tensors. The values may be wider
+    than the queries and keys ([B, S, H, Dv], static, from shapes): the
+    output is then [B, S, H, Dv]; scores are scaled by the keys' width.
+    ``label`` (static) goes into the kernels' names (``_name``) and nowhere
+    else.
 
     ``bias`` is the [B, 1, 1, S] additive mask from
     :func:`bert_pytorch_tpu.ops.attention.make_attention_bias` (key-only
@@ -889,7 +900,8 @@ def flash_attention(q, k, v, bias=None, dropout_rate=0.0, dropout_rng=None,
         window = int(window) if window < seq else None
 
     def to3(t):
-        return t.transpose(0, 2, 1, 3).reshape(batch * heads, seq, depth)
+        return t.transpose(0, 2, 1, 3).reshape(batch * heads, seq,
+                                               t.shape[-1])
 
     segmented = sequence_ids is not None
     if segmented and bias is not None:
@@ -925,5 +937,5 @@ def flash_attention(q, k, v, bias=None, dropout_rate=0.0, dropout_rng=None,
     else:
         seed = jnp.zeros((1,), jnp.int32)
     out3 = _flash(to3(q), to3(k), to3(v), bias3, seg3, seed, scale,
-                  float(dropout_rate), segmented, bool(causal), window)
-    return out3.reshape(batch, heads, seq, depth).transpose(0, 2, 1, 3)
+                  float(dropout_rate), segmented, bool(causal), window, label)
+    return out3.reshape(batch, heads, seq, v.shape[-1]).transpose(0, 2, 1, 3)

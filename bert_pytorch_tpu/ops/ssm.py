@@ -1,6 +1,9 @@
-"""The Mamba-2 state-space mixer's core: causal depthwise convolution, the
-selective recurrence as a chunked scan (the SSD form of Dao & Gu 2024,
-arXiv:2405.21060), and the gated group RMSNorm that follows it.
+"""The state-space mixers' cores: the causal depthwise convolution both share,
+Mamba-2's selective recurrence as a chunked scan in matmul form (the SSD form
+of Dao & Gu 2024, arXiv:2405.21060) with the gated group RMSNorm that follows
+it, and Mamba-1's selective scan (``selective_scan``, at the end of the file:
+a decay for every channel and state, so no matmul form; Pallas kernels,
+``ops/pallas/selective_scan.py``).
 
 The recurrence, per head h with state [P, N] (P = head dim, N = state size):
 
@@ -22,6 +25,8 @@ tells the recurrence from the projections round it.
 """
 
 from __future__ import annotations
+
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -121,3 +126,82 @@ def gated_group_rms_norm(y, z, weight, groups: int, eps: float):
         normed = shaped * jax.lax.rsqrt(
             jnp.mean(jnp.square(shaped), axis=-1, keepdims=True) + eps)
         return (normed.reshape(gated.shape) * weight).astype(y.dtype)
+
+
+# ---------------------------------------------------------------- Mamba-1
+
+def selective_scan(u, dt, a, b, c, chunk: int = 128):
+    """Mamba-1's recurrence over a whole sequence (Gu & Dao 2023,
+    arXiv:2312.00752), a decay for every (channel, state) pair:
+
+        h_t = exp(dt_t (x) A) * h_{t-1} + (dt_t * u_t) (x) B_t    y_t = h_t C_t
+
+    u [B, S, D]; dt [B, S, D], already positive; a [D, N], negative; b, c
+    [B, S, N]. Returns y [B, S, D] float32 (the caller adds ``D * u``). State
+    and decays are float32 whatever the operands' dtype (they are cast on the
+    way into the kernels and kept for the backward as they came; the
+    cotangents go back in the operands' dtypes); the [S, D, N] states
+    never reach HBM, in either pass: the forward kernel carries the state
+    through chunks of ``chunk`` positions and keeps it at each chunk's start,
+    the backward kernel makes a chunk's states again from there
+    (``ops/pallas/selective_scan.py``). D is a multiple of 128, ``chunk`` of
+    8; a length that is no multiple of ``chunk`` is padded at the end with dt
+    = 0 (decay 1, no input), which leaves the positions before it untouched.
+    A row runs ``scan_chunks(S, chunk)`` chunks a pass.
+    """
+    with jax.named_scope("selective_scan"):
+        return _selective_scan(u, dt, a, b, c, chunk)
+
+
+def scan_chunks(seq: int, chunk: int) -> int:
+    """Chunks one row's scan runs in one pass."""
+    return -(-seq // chunk)
+
+
+def _wide(t):
+    """[B, S, N] -> [B, S, N, 128]: each number over one tile of lanes."""
+    from bert_pytorch_tpu.ops.pallas.selective_scan import LANES
+
+    return jnp.broadcast_to(t[..., None], t.shape + (LANES,))
+
+
+def _padded(chunk, *tensors):
+    """Float32, and the positions padded up to whole chunks."""
+    pad = (-tensors[0].shape[1]) % chunk
+    tensors = [t.astype(jnp.float32) for t in tensors]
+    return [jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            for t in tensors] if pad else tensors
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _selective_scan(u, dt, a, b, c, chunk):
+    return _selective_scan_fwd(u, dt, a, b, c, chunk)[0]
+
+
+def _selective_scan_fwd(u, dt, a, b, c, chunk):
+    from bert_pytorch_tpu.ops.pallas.selective_scan import scan_forward
+
+    seq = u.shape[1]
+    up, dtp, bp, cp = _padded(chunk, u, dt, b, c)
+    y, starts = scan_forward(up, dtp, _wide(bp), _wide(cp),
+                             a.astype(jnp.float32).T, chunk)
+    # the operands are kept as they came (bfloat16 where the caller's are)
+    return y[:, :seq], (u, dt, a, b, c, starts)
+
+
+def _selective_scan_bwd(chunk, residuals, dy):
+    from bert_pytorch_tpu.ops.pallas.selective_scan import scan_backward
+
+    u, dt, a, b, c, starts = residuals
+    seq = u.shape[1]
+    up, dtp, bp, cp, dyp = _padded(chunk, u, dt, b, c, dy)
+    du, ddt, db, dc, da = scan_backward(
+        up, dtp, _wide(bp), _wide(cp), a.astype(jnp.float32).T, starts, dyp,
+        chunk)
+    return (du[:, :seq].astype(u.dtype), ddt[:, :seq].astype(dt.dtype),
+            jnp.sum(da, axis=(0, 1)).T.astype(a.dtype),
+            jnp.sum(db, axis=-1)[:, :seq].astype(b.dtype),
+            jnp.sum(dc, axis=-1)[:, :seq].astype(c.dtype))
+
+
+_selective_scan.defvjp(_selective_scan_fwd, _selective_scan_bwd)

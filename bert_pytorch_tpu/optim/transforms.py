@@ -279,7 +279,9 @@ def no_decay_mask(params) -> optax.Params:
     ``nemotron_h`` family (models/nemotron_h.py) also every ``*_bias`` and
     ``*_scale`` leaf (the convolution's bias, ``dt_bias``, the gated norm's
     scale, the router's correction buffer) and the state-space mixer's
-    ``A_log`` and ``D``, as Mamba-2's own recipe exempts them."""
+    ``A_log`` and ``D``, as Mamba-2's own recipe exempts them; of the
+    ``phi4flash`` family (models/phi4flash.py) also the differential
+    attention's four ``lambda_*`` vectors."""
     import flax.traverse_util as traverse_util
 
     flat = traverse_util.flatten_dict(params)
@@ -287,6 +289,7 @@ def no_decay_mask(params) -> optax.Params:
         path: not (
             path[-1] in ("bias", "scale", "A_log", "D")
             or path[-1].endswith(("_bias", "_scale"))
+            or path[-1].startswith("lambda_")
             or any("layer_norm" in part for part in path)
         )
         for path in flat

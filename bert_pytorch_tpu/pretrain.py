@@ -58,6 +58,12 @@ LAGUNA_SCOPES = (
     "attn_qkv", "attn_rope", "attn_gate", "attn_out", "dense_mlp", "moe",
     "moe_route", "moe_dispatch", "moe_experts", "moe_combine", "moe_shared",
     "lm_head", "lm_loss")
+# ... and those of the phi4flash family's step (models/phi4flash.py,
+# ops/ssm.py selective_scan, ops/attention.py differential_attention).
+PHI_FLASH_SCOPES = (
+    "s6_mixer", "s6_in_proj", "s6_conv", "s6_dt", "selective_scan", "s6_gate",
+    "s6_out_proj", "gmu", "attn_qkv", "attn_diff", "attn_out", "dense_mlp",
+    "lm_head", "lm_loss")
 
 
 # Rows longer than this many positions take the output head and its loss in
@@ -185,7 +191,7 @@ def _apply_causal_lm_loss(model, variables, mb):
         hidden, counters = model.apply(variables, ids,
                                        method="hidden_states")
         loss, accuracy = chunked_next_token_loss(
-            hidden, variables["params"]["lm_head"]["kernel"], ids, pieces)
+            hidden, model.head_kernel(variables["params"]), ids, pieces)
     return loss, {"token_accuracy": accuracy, **counters}
 
 

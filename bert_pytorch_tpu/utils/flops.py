@@ -176,11 +176,47 @@ def laguna_forward_flops_per_token(config, seq_len: int) -> dict:
     return dict(parts, head=2.0 * h * config.vocab_size)
 
 
+def phi_flash_forward_flops_per_token(config, seq_len: int) -> dict:
+    """Forward matmul FLOPs per token of a ``phi4flash`` model on THIS chip
+    (the layers, heads and vocabulary rows it holds), by part: ``mlp`` (two
+    products, the first twice as wide), ``s6_proj`` (the Mamba-1 mixers' four
+    projections; the scan itself is elementwise work and counts nothing
+    here), ``gmu``, ``attention_proj`` (``Wqkv`` or ``Wq``, and ``out_proj``),
+    ``attention_full`` and ``attention_window`` (both maps of every pair over
+    the pairs a row really sees, the value product twice as wide as the
+    score product: 2 d + 4 d a pair and map), ``head`` (tied: the embedding's
+    rows held). Lookup, norms, convolution, activations and the optimizer are
+    left out."""
+    h, hd, inner = config.hidden_size, config.head_dim, config.mamba_inner
+    heads, kv = config.num_attention_heads, config.num_key_value_heads
+    parts = dict.fromkeys(("mlp", "s6_proj", "gmu", "attention_proj",
+                           "attention_full", "attention_window"), 0.0)
+    for layer, kind in enumerate(config.layer_types):
+        parts["mlp"] += 6 * h * config.intermediate_size
+        if kind in ("mamba", "mamba_memory"):
+            rank, states = config.mamba_dt_rank, config.mamba_d_state
+            parts["s6_proj"] += (4 * h * inner + 2 * inner * (rank + 2 * states)
+                                 + 2 * rank * inner + 2 * inner * h)
+        elif kind == "gmu":
+            parts["gmu"] += 4 * h * inner
+        else:
+            own = 0 if kind == "cross_attention" else 2 * kv
+            parts["attention_proj"] += 2 * h * hd * (heads + own) + 2 * heads * hd * h
+            window = config.window_of(layer)
+            reach = min(window or seq_len, seq_len)
+            seen = reach - reach * (reach - 1) / (2 * seq_len)  # keys a row sees
+            parts["attention_window" if window else "attention_full"] += (
+                6 * hd * heads * seen)
+    return dict(parts, head=2.0 * h * config.vocab_size)
+
+
 def causal_lm_train_flops_per_seq(config, seq_len: int) -> float:
     """Training (3x forward) matmul FLOPs of one row of ``seq_len`` tokens of
     a ``causal_lm`` family's model (by the config's ``model_type``)."""
     per_token = {"nemotron_h": nemotron_h_forward_flops_per_token,
-                 "laguna": laguna_forward_flops_per_token}[config.model_type]
+                 "laguna": laguna_forward_flops_per_token,
+                 "phi4flash": phi_flash_forward_flops_per_token,
+                 }[config.model_type]
     return 3.0 * seq_len * sum(per_token(config, seq_len).values())
 
 

@@ -465,7 +465,9 @@ def prepare_model(args, mesh):
     run_pretraining.py:233-274)."""
     # The family (and with it the model and the objective) comes from the
     # file's ``model_type``: none is BERT, ``nemotron_h`` the hybrid decoder,
-    # ``laguna`` the decoder of mixed window and full attention.
+    # ``laguna`` the decoder of mixed window and full attention, ``phi4flash``
+    # the decoder of selective scans, differential attention and one kept
+    # memory and K/V.
     config = load_model_config(args.model_config_file)
     if config.vocab_size % 8 != 0:  # MXU-friendly padding (reference :237)
         config.vocab_size += 8 - (config.vocab_size % 8)
@@ -1140,9 +1142,11 @@ def main(args) -> dict:
                                 "mlm_accuracy", 0.0),
                             grad_norm=last_metrics.get("grad_norm", 0.0),
                             # the decoder's counters: routing, score
-                            # tiles (causal_lm; pretrain._aux_metrics)
+                            # tiles, scan chunks, readers of the carried
+                            # tensors (causal_lm; pretrain._aux_metrics)
                             **{k: v for k, v in last_metrics.items()
-                               if k.startswith(("moe_", "attn_"))})
+                               if k.startswith(("moe_", "attn_", "scan_"))
+                               or k.endswith("_readers")})
 
                 if (eval_step is not None
                         and global_step % args.num_steps_per_eval == 0):

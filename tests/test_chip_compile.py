@@ -59,9 +59,10 @@ def _compile_for_the_chip(monkeypatch):
     (The persistent compile cache is off for the whole suite, conftest.py:
     an executable compiled for a described chip could be written to it but
     not read back.)"""
-    from bert_pytorch_tpu.ops.pallas import attention, common, layernorm
+    from bert_pytorch_tpu.ops.pallas import (attention, common, layernorm,
+                                             selective_scan)
 
-    for module in (common, attention, layernorm):
+    for module in (common, attention, layernorm, selective_scan):
         monkeypatch.setattr(module, "interpret_mode", lambda: False)
     with jax.default_matmul_precision("default"), \
             jax.default_prng_impl("rbg"):  # the runners' --rng_impl default
@@ -161,6 +162,47 @@ def test_windowed_flash_attention_compiles_at_8192(chip):
         ((1, 8192, 4, 128), jnp.bfloat16))
     _assert_kernel(compiled, "flash_window_fwd", "flash_window_bwd_dq",
                    "flash_window_bwd_dkv")
+
+
+@pytest.mark.parametrize("window,label,prefix", [
+    (512, "diff", "flash_diff_window_"), (None, "diff_cross", "flash_diff_cross_")])
+def test_differential_flash_attention_compiles_at_8192(chip, window, label,
+                                                       prefix):
+    """Values twice as wide as the keys at the phi4flash cell's geometry: one
+    row of 8192 tokens, 10 query pairs and 5 key pairs of 64, values of 128,
+    both maps as heads of one call (20 maps); the kernels' names say which
+    kind of layer called."""
+    from bert_pytorch_tpu.ops.attention import differential_attention
+
+    def loss(q, k, v):
+        a1, a2 = differential_attention(q, k, v, backend="pallas",
+                                        window=window, label=label)
+        return jnp.sum((a1 - 0.5 * a2).astype(jnp.float32))
+
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1, 2)), chip,
+        ((1, 8192, 10, 2, 64), jnp.bfloat16),
+        ((1, 8192, 5, 2, 64), jnp.bfloat16), ((1, 8192, 5, 128), jnp.bfloat16))
+    _assert_kernel(compiled, prefix + "fwd", prefix + "bwd_dq",
+                   prefix + "bwd_dkv")
+
+
+def test_selective_scan_compiles_at_8192(chip):
+    """The Mamba-1 scan's two kernels at the phi4flash cell's geometry: one
+    row of 8192 positions, 5120 channels, 16 states, chunks of 128: the time
+    loop's tiles, the state's scratch and the chunk's kept states have to pass
+    Mosaic and fit VMEM under the kernels' own limit."""
+    from bert_pytorch_tpu.ops import ssm
+
+    def loss(u, dt, a, b, c):
+        return jnp.sum(ssm.selective_scan(u, dt, a, b, c, chunk=128))
+
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1, 2, 3, 4)), chip,
+        ((1, 8192, 5120), jnp.bfloat16), ((1, 8192, 5120), jnp.float32),
+        ((5120, 16), jnp.float32), ((1, 8192, 16), jnp.bfloat16),
+        ((1, 8192, 16), jnp.bfloat16))
+    _assert_kernel(compiled, "selective_scan_fwd", "selective_scan_bwd")
 
 
 # -- the serving kernels ----------------------------------------------------
