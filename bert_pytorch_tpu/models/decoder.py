@@ -150,8 +150,9 @@ class ExpertLayer(nn.Module):
 class CausalDecoder(nn.Module):
     """The wrapper: a family gives its ``blocks()`` (modules that take x and
     return ``(x, counters)``; under ``CARRIES`` they take ``(x, carried)``
-    and return ``(x, carried, counters)``), the norm's epsilon and any
-    counters of its own beside the expert layers' (``COUNTERS``). ``NORM`` is
+    and return ``(x, carried, counters)``), the norm's epsilon, any
+    counters of its own beside the expert layers' (``COUNTERS``) and what
+    its layers share (``shared_inputs``). ``NORM`` is
     the final norm's class; under ``TIED_HEAD`` the output head is the
     embedding's transpose and the model holds no ``lm_head``. The model
     returns ``(logits [B, S, V], counters)``."""
@@ -173,6 +174,12 @@ class CausalDecoder(nn.Module):
 
     def norm_epsilon(self) -> float:
         raise NotImplementedError
+
+    def shared_inputs(self, seq: int) -> tuple:
+        """What every layer of one call over ``seq`` positions reads and none
+        writes, made once ahead of the layers and handed to each after its
+        other inputs (``models/laguna.py``: the rotary tables)."""
+        return ()
 
     def setup(self):
         cfg = self.config
@@ -201,11 +208,12 @@ class CausalDecoder(nn.Module):
         x = jnp.take(self.embedding, input_ids, axis=0).astype(self.dtype)
         seen = {name: [] for name in self.COUNTERS}
         carried = {}
+        shared = self.shared_inputs(input_ids.shape[1])
         for layer in self.layers:
             if self.CARRIES:
-                x, carried, counters = layer(x, carried)
+                x, carried, counters = layer(x, carried, *shared)
             else:
-                x, counters = layer(x)
+                x, counters = layer(x, *shared)
             for name, value in (counters or {}).items():
                 seen[name].append(value)
         zero = jnp.zeros((), jnp.float32)

@@ -63,15 +63,16 @@ def _out_std(config: LagunaConfig) -> float:
 
 class GatedAttention(nn.Module):
     """Layer ``layer``'s attention: grouped-query, causal (within the window
-    on a sliding layer), rotary on the first dimensions of each head, a
-    sigmoid gate per head on the output."""
+    on a sliding layer), rotary on the first dimensions of each head (by the
+    tables ``rotary`` the model made once for the layer's kind; a layer called
+    alone makes its own), a sigmoid gate per head on the output."""
     config: LagunaConfig
     layer: int
     dtype: Dtype = jnp.float32
     attention_backend: str = "xla"
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, rotary=None):
         cfg = self.config
         heads = cfg.num_attention_heads_per_layer[self.layer]
         kv, hd = cfg.num_key_value_heads, cfg.head_dim
@@ -85,7 +86,8 @@ class GatedAttention(nn.Module):
         q = q.reshape(batch, seq, heads, hd)
         k = k.reshape(batch, seq, kv, hd)
         with jax.named_scope("attn_rope"):
-            cos, sin = rope.rotary_tables(seq, *cfg.rope_of(self.layer))
+            cos, sin = rotary or rope.rotary_tables(
+                seq, *cfg.rope_of(self.layer))
             q, k = rope.apply_rotary(q, cos, sin), rope.apply_rotary(k, cos, sin)
         ctx = dot_product_attention(
             q, k, v.reshape(batch, seq, kv, hd),
@@ -145,11 +147,12 @@ class LagunaBlock(nn.Module):
     attention_backend: str = "xla"
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, tables):
         cfg = self.config
         h = RMSNorm(cfg.rms_norm_eps, self.dtype, name="attn_norm")(x)
         out, counters = GatedAttention(
-            cfg, self.layer, self.dtype, self.attention_backend, name="attn")(h)
+            cfg, self.layer, self.dtype, self.attention_backend, name="attn")(
+                h, tables[cfg.layer_types[self.layer]])
         x = x + out
         h = RMSNorm(cfg.rms_norm_eps, self.dtype, name="mlp_norm")(x)
         if cfg.mlp_layer_types[self.layer] == "dense":
@@ -172,3 +175,12 @@ class LagunaForCausalLM(CausalDecoder):
 
     def norm_epsilon(self):
         return self.config.rms_norm_eps
+
+    def shared_inputs(self, seq):
+        """The rotary tables, one (cos, sin) for each kind of layer, made
+        once a call and not in every layer of every pass."""
+        cfg = self.config
+        with jax.named_scope("attn_rope"):
+            return ({kind: rope.rotary_tables(
+                seq, *cfg.rope_of(cfg.layer_types.index(kind)))
+                for kind in dict.fromkeys(cfg.layer_types)},)

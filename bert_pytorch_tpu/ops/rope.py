@@ -23,15 +23,31 @@ once to float32; the angles, cosines and sines are float32 on the device
 whatever the compute dtype (a bfloat16 angle at position 8191 would be off by
 whole turns), and the rotated head is cast back to its own dtype.
 
-Scope: the caller's (``attn_rope`` in models/laguna.py).
+**The turn** (``apply_rotary``) reads a head once and writes it once: where
+the shapes allow (``ops/pallas/rope.py fits``: heads of whole lane tiles) it
+is one Pallas kernel over the head's full width, with no slice, split or
+concatenate in XLA; other shapes are turned by ``rotate_half`` in plain jnp.
+**Its backward is the turn itself by the negative angle** (``jax.custom_vjp``:
+the transpose of a rotation scaled by the attention factor is the rotation
+the other way, scaled alike), in float32 inside and cast to the cotangent's
+dtype; nothing is kept for it but the tables, which depend on no parameter
+and get no cotangent. **The tables are the caller's to make once**
+(``rotary_tables``: ``models/laguna.py`` makes one pair for each kind of layer
+ahead of the layers and hands them to every block).
+
+Scope: the caller's (``attn_rope`` in models/laguna.py); the backward's ops
+inherit it through the transpose's name.
 """
 
 from __future__ import annotations
 
 import math
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bert_pytorch_tpu.ops.pallas import rope as kernel
 
 
 def yarn_correction_range(rotary_dim: int, theta: float, original_max: int,
@@ -79,18 +95,36 @@ def rotary_tables(seq: int, rotary_dim: int, rope: dict) -> tuple:
     the pair's angle repeated over both halves, times the attention factor."""
     inv_freq, attention_factor = inverse_frequencies(rotary_dim, rope)
     angles = (jnp.arange(seq, dtype=jnp.float32)[:, None]
-              * jnp.asarray(inv_freq)[None, :])
-    angles = jnp.concatenate([angles, angles], axis=-1)
+              * jnp.asarray(np.concatenate([inv_freq, inv_freq]))[None, :])
     return jnp.cos(angles) * attention_factor, jnp.sin(angles) * attention_factor
 
 
+def _turn(x, cos, sin, sign: int):
+    """The rotation by the tables' angle (``sign`` 1) or by its negative."""
+    rotary_dim = cos.shape[-1]
+    if kernel.fits(x.shape):
+        return kernel.rotary_turn(x, cos, sin, sign)
+    turned = x[..., :rotary_dim].astype(jnp.float32)
+    first, second = jnp.split(turned, 2, axis=-1)
+    turned = (turned * cos[:, None, :] + sign
+              * jnp.concatenate([-second, first], axis=-1) * sin[:, None, :])
+    return jnp.concatenate(
+        [turned.astype(x.dtype), x[..., rotary_dim:]], axis=-1)
+
+
+@jax.custom_vjp
 def apply_rotary(x, cos, sin):
     """x [B, S, heads, head_dim] with its first ``cos.shape[-1]`` dimensions
     turned; the rest, if any, unchanged. In float32, back in x's dtype."""
-    rotary_dim = cos.shape[-1]
-    turned = x[..., :rotary_dim].astype(jnp.float32)
-    first, second = jnp.split(turned, 2, axis=-1)
-    turned = (turned * cos[:, None, :]
-              + jnp.concatenate([-second, first], axis=-1) * sin[:, None, :])
-    return jnp.concatenate(
-        [turned.astype(x.dtype), x[..., rotary_dim:]], axis=-1)
+    return _turn(x, cos, sin, 1)
+
+
+def _apply_rotary_fwd(x, cos, sin):
+    return _turn(x, cos, sin, 1), (cos, sin)
+
+
+def _apply_rotary_bwd(tables, cotangent):
+    return _turn(cotangent, *tables, -1), None, None
+
+
+apply_rotary.defvjp(_apply_rotary_fwd, _apply_rotary_bwd)

@@ -205,6 +205,29 @@ def test_selective_scan_compiles_at_8192(chip):
     _assert_kernel(compiled, "selective_scan_fwd", "selective_scan_bwd")
 
 
+@pytest.mark.parametrize("heads,rotary_dim", [(24, 64), (36, 128)])
+def test_rotary_turn_compiles_at_8192(chip, heads, rotary_dim):
+    """The turn's kernel at the laguna cell's geometry (queries and the four
+    key-value heads of a full layer under YaRN's half-width tables, of a
+    sliding layer under whole ones), forward and backward: the tables widened
+    in VMEM from 64 lanes, the lane rotations and the blocks' VMEM have to
+    pass Mosaic."""
+    from bert_pytorch_tpu.ops import rope
+
+    def loss(q, k, cos, sin):
+        return sum(jnp.sum(jnp.square(  # squared: the backward needs the forward
+            rope.apply_rotary(t, cos, sin).astype(jnp.float32))) for t in (q, k))
+
+    table = ((8192, rotary_dim), jnp.float32)
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1)), chip,
+        ((1, 8192, heads, 128), jnp.bfloat16),
+        ((1, 8192, 4, 128), jnp.bfloat16), table, table)
+    _assert_kernel(compiled, "rotary_turn")
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"',
+                          compiled.as_text())) == 4
+
+
 # -- the serving kernels ----------------------------------------------------
 
 @pytest.mark.parametrize("seq", [32, 128, 512])

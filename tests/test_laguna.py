@@ -483,6 +483,82 @@ def test_every_scope_of_the_family_reaches_the_compiled_step(
                for name in laguna_step_names), scope
 
 
+# -- the rotary turn in the lowered step ---------------------------------------------
+
+@pytest.fixture(scope="module")
+def lowered_rotary_ops():
+    """[(op, name)] of the StableHLO ops of a small step (heads of 128, so the
+    turn's kernel takes them; ``--remat full``) lowered for the TPU, with the
+    names JAX gave them (scopes, ``transpose(``, ``rematted_computation``)."""
+    import re
+
+    from bert_pytorch_tpu.ops.pallas import common
+
+    model = build_pretraining_model(
+        LagunaConfig(**dict(TINY, head_dim=128)), jnp.bfloat16, remat="full")
+    tx = optim.adamw(1e-3, max_grad_norm=1.0,
+                     weight_decay_mask=optim.no_decay_mask)
+    state = jax.eval_shape(pretrain.make_init_fn(
+        model, tx, (jnp.zeros((1, 16), jnp.int32),), None), jax.random.PRNGKey(0))
+    step = pretrain.make_train_step(model, tx, next_sentence=False)
+    batch = {"input_ids": jax.ShapeDtypeStruct((2, 1, 32), np.int32)}
+    with pytest.MonkeyPatch.context() as patch:
+        # the kernel compiled, as on the chip, not unrolled by the interpreter
+        patch.setattr(common, "interpret_mode", lambda: False)
+        text = step.trace(state, batch).lower(
+            lowering_platforms=("tpu",)).as_text(debug_info=True)
+    locations = dict(re.findall(r"^(#loc\d+) = loc\((.*)\)$", text, flags=re.M))
+
+    def name(reference, depth=0):
+        body = locations.get(reference, reference)
+        return body if depth > 8 else re.sub(
+            r"#loc\d+", lambda m: name(m.group(0), depth + 1), body)
+
+    return [(m.group(1), name(m.group(2))) for m in re.finditer(
+        r"(stablehlo\.\w+).* loc\((#loc\d+)\)$", text, flags=re.M)]
+
+
+def test_the_turn_is_one_kernel_a_call_with_no_concatenate_and_no_pad(
+        lowered_rotary_ops):
+    """Five layers x (q, k) x (forward, recompute, backward): thirty calls of
+    the kernel under ``attn_rope`` and, under that scope, no piece of a head
+    sliced off, padded or joined in XLA; the backward's and the recompute's
+    calls carry the markers the benchmark's trace rules class them by."""
+    mine = [(op, name) for op, name in lowered_rotary_ops if "attn_rope" in name]
+    assert not [op for op, _ in mine if op in (
+        "stablehlo.concatenate", "stablehlo.pad", "stablehlo.slice")]
+    kernels = [name for op, name in mine
+               if op == "stablehlo.custom_call" and "rotary_turn" in name]
+    assert len(kernels) == 30
+    assert sum("rematted_computation" in name for name in kernels) == 10
+    assert sum("transpose(" in name and "rematted_computation" not in name
+               for name in kernels) == 10
+
+
+def test_each_rotary_table_is_made_once_a_step(lowered_rotary_ops):
+    """Two kinds of layer, two tables: one cosine and one sine each in the
+    whole step, not one in every layer of every pass."""
+    ops = [op for op, _ in lowered_rotary_ops]
+    assert ops.count("stablehlo.cosine") == ops.count("stablehlo.sine") == 2
+
+
+def test_the_turn_is_looked_up_on_the_module_when_the_model_is_called(
+        monkeypatch):
+    """The seam the benchmark's planted fault ``rotary_dropped`` uses
+    (``benchmarks/tests/test_train_laguna.py``): the identity in
+    ``rope.apply_rotary``'s place moves the logits (by half a percent at
+    fresh weights, whose scores are nearly flat: thousands of times the
+    float32 noise)."""
+    from bert_pytorch_tpu.models import laguna
+
+    model = build_pretraining_model(LagunaConfig(**TINY), jnp.float32)
+    ids = jax.random.randint(keys(1, 2)[0], (2, 24), 0, TINY["vocab_size"])
+    params = model.init(jax.random.PRNGKey(0), ids)
+    turned = model.apply(params, ids)[0]
+    monkeypatch.setattr(laguna.rope, "apply_rotary", lambda x, cos, sin: x)
+    far(model.apply(params, ids)[0], turned, share=1e-3)
+
+
 # -- the normal path ------------------------------------------------------------------
 
 def test_run_pretraining_trains_the_family_from_its_config_file(tmp_path):

@@ -115,3 +115,105 @@ def test_the_reference_written_apart_turns_alike(rotary_dim, params):
     np.testing.assert_allclose(
         np.asarray(rope.apply_rotary(x, cos, sin)),
         np.asarray(ref.rotate(x, rotary_dim, params)), atol=1e-6)
+
+
+# -- the turn's backward: the turn by the negative angle -------------------------
+
+def _plain_turn(x, cos, sin):
+    """rotate_half written apart, in the dtype it is given: slice, the halves
+    swapped with a sign, concatenate; the rest handed on."""
+    rotary_dim = cos.shape[-1]
+    turned, rest = x[..., :rotary_dim], x[..., rotary_dim:]
+    first, second = turned[..., :rotary_dim // 2], turned[..., rotary_dim // 2:]
+    swapped = jnp.concatenate([-second, first], axis=-1)
+    return jnp.concatenate(
+        [turned * cos[:, None, :] + swapped * sin[:, None, :], rest], axis=-1)
+
+
+def _pulled_back(turn, x, cotangent, cos, sin):
+    return jax.vjp(lambda x_: turn(x_, cos, sin), x)[1](cotangent)[0]
+
+
+def _case(seq, rotary_dim, dtype, batch=2, heads=3):
+    """x, a cotangent and YaRN's tables (attention factor 1.485, not 1)."""
+    kx, kg = jax.random.split(jax.random.PRNGKey(seq + rotary_dim))
+    x = jax.random.normal(kx, (batch, seq, heads, 128), dtype)
+    cotangent = jax.random.normal(kg, x.shape, dtype)
+    return x, cotangent, rope.rotary_tables(seq, rotary_dim, YARN)
+
+
+# seq 32: the kernel (interpreted here); seq 37: rows in no whole tile, plain jnp
+@pytest.mark.parametrize("seq", [32, 37])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("rotary_dim", [128, 64])
+def test_the_gradient_is_autodiffs_of_the_plain_formulation(rotary_dim, dtype, seq):
+    assert rope.kernel.fits((2, seq, 3, 128)) == (seq == 32)
+    x, cotangent, (cos, sin) = _case(seq, rotary_dim, dtype)
+    got = _pulled_back(rope.apply_rotary, x, cotangent, cos, sin)
+    want = _pulled_back(_plain_turn, x.astype(jnp.float32),
+                        cotangent.astype(jnp.float32), cos, sin)
+    assert got.dtype == dtype and got.shape == x.shape
+    tolerance = 1e-5 if dtype == jnp.float32 else 2.0 ** -8
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),
+                               rtol=tolerance, atol=tolerance)
+
+
+@pytest.mark.parametrize("seq", [32, 37])
+@pytest.mark.parametrize("rotary_dim", [128, 64])
+def test_a_bfloat16_cotangent_is_turned_back_in_float32(rotary_dim, seq):
+    """One rounding, at the end: the result is within half a bfloat16 step
+    (at most 2^-8 of the value) of the float32 transpose of the float32
+    cotangent; products and sums rounded to bfloat16 on the way would be up
+    to three times as far."""
+    x, cotangent, (cos, sin) = _case(seq, rotary_dim, jnp.bfloat16)
+    got = _pulled_back(rope.apply_rotary, x, cotangent, cos, sin)
+    assert got.dtype == jnp.bfloat16
+    want = np.asarray(_pulled_back(
+        _plain_turn, x.astype(jnp.float32), cotangent.astype(jnp.float32),
+        cos, sin))
+    gap = np.abs(np.asarray(got, np.float32) - want)
+    assert np.all(gap <= 2.0 ** -8 * np.abs(want) + 1e-6)
+
+
+@pytest.mark.parametrize("seq", [32, 37])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_untouched_halfs_gradient_is_the_cotangent_bit_for_bit(dtype, seq):
+    x, cotangent, (cos, sin) = _case(seq, 64, dtype)
+    got = _pulled_back(rope.apply_rotary, x, cotangent, cos, sin)
+    bits = jnp.uint32 if dtype == jnp.float32 else jnp.uint16
+    np.testing.assert_array_equal(
+        np.asarray(jax.lax.bitcast_convert_type(got[..., 64:], bits)),
+        np.asarray(jax.lax.bitcast_convert_type(cotangent[..., 64:], bits)))
+    assert np.any(np.asarray(got[..., :64] != cotangent[..., :64]))
+
+
+def test_the_turn_keeps_nothing_for_its_backward_but_the_tables(capsys):
+    x, _, (cos, sin) = _case(32, 64, jnp.bfloat16)
+    jax.ad_checkpoint.print_saved_residuals(
+        lambda x_: jnp.sum(rope.apply_rotary(x_, cos, sin).astype(jnp.float32)), x)
+    kept = capsys.readouterr().out.splitlines()
+    assert [line.split()[0] for line in kept] == ["f32[32,64]"] * 2, kept
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("rotary_dim", [128, 64])
+def test_the_kernel_and_the_plain_path_turn_alike_both_ways(rotary_dim, dtype,
+                                                           monkeypatch):
+    """Two rows of a batch read the same block of the tables; the negative
+    angle undoes the turn up to the attention factor's square."""
+    x, _, (cos, sin) = _case(64, rotary_dim, dtype)
+    by_kernel = {sign: rope._turn(x, cos, sin, sign) for sign in (1, -1)}
+    monkeypatch.setattr(rope.kernel, "fits", lambda *_: False)
+    tolerance = 1e-6 if dtype == jnp.float32 else 2.0 ** -8
+    for sign, turned in by_kernel.items():
+        assert turned.dtype == dtype
+        np.testing.assert_allclose(
+            np.asarray(turned, np.float32),
+            np.asarray(rope._turn(x, cos, sin, sign), np.float32),
+            rtol=tolerance, atol=tolerance)
+    if dtype == jnp.float32:
+        factor = rope.inverse_frequencies(rotary_dim, YARN)[1]
+        back = np.asarray(rope._turn(by_kernel[1], cos, sin, -1))
+        np.testing.assert_allclose(back[..., :rotary_dim],
+                                   factor ** 2 * np.asarray(x)[..., :rotary_dim],
+                                   rtol=1e-5, atol=1e-5)

@@ -11,8 +11,10 @@ the change's, then compare the two directories file by file:
     for f in /root/scratch/low_parent/*; do cmp $f /root/scratch/low_change/$(basename $f); done
 
 What it writes: ``nemotron_h.txt``, ``laguna.txt`` (a decoder of each accepted
-family with the Pallas backend, ``--remat full``, AdamW) and
-``bert_phase2.txt`` (BERT with the flash kernel and its in-kernel dropout,
+family with the Pallas backend, ``--remat full``, AdamW), ``phi4flash.txt``
+(the family on ``models/decoder.py``'s carried path, at widths no kernel
+compiles for: XLA attention, the scan's kernels unrolled by the interpreter)
+and ``bert_phase2.txt`` (BERT with the flash kernel and its in-kernel dropout,
 ``--remat dots``): ``make_train_step(...).trace(...).lower(lowering_platforms=
 ("tpu",))`` as text, with the Mosaic payloads (the serialized kernels, which
 hold the checkout's path and line numbers) and the source locations cut out;
@@ -38,7 +40,7 @@ import numpy as np  # noqa: E402
 
 from bert_pytorch_tpu import optim, pretrain  # noqa: E402
 from bert_pytorch_tpu.config import (BertConfig, LagunaConfig,  # noqa: E402
-                                     NemotronHConfig)
+                                     NemotronHConfig, PhiFlashConfig)
 from bert_pytorch_tpu.models import build_pretraining_model  # noqa: E402
 from bert_pytorch_tpu.ops import moe  # noqa: E402
 from bert_pytorch_tpu.ops.pallas import attention, common  # noqa: E402
@@ -63,6 +65,11 @@ LAGUNA = dict(
     sliding_window=128, num_experts=4, ep_size=4, ep_rank=1,
     num_experts_per_tok=3, moe_intermediate_size=128,
     shared_expert_intermediate_size=128)
+PHI4FLASH = dict(
+    vocab_size=256, hidden_size=64, intermediate_size=96, num_hidden_layers=6,
+    num_attention_heads=8, num_key_value_heads=4, sliding_window=8,
+    layer_indices=[0, 1, 16, 17, 18, 19], published_num_hidden_layers=32,
+    mamba_dt_rank=4, scan_chunk=16)
 BERT = dict(vocab_size=512, hidden_size=128, num_hidden_layers=2,
             num_attention_heads=2, intermediate_size=256,
             max_position_embeddings=512)
@@ -112,6 +119,10 @@ for name, config in (("nemotron_h", NemotronHConfig(**NEMOTRON_H)),
         build_pretraining_model(config, jnp.bfloat16, remat="full",
                                 attention_backend="pallas"),
         {"input_ids": ids(2, 1, SEQ)}))
+write("phi4flash", lowered_step(
+    build_pretraining_model(PhiFlashConfig(**PHI4FLASH), jnp.bfloat16,
+                            remat="full", attention_backend="xla"),
+    {"input_ids": ids(2, 1, 64)}))
 write("bert_phase2", lowered_step(
     build_pretraining_model(BertConfig(**BERT), jnp.bfloat16, remat="dots",
                             attention_backend="pallas"),
