@@ -29,7 +29,8 @@ from bert_pytorch_tpu.telemetry.model_stats import (DivergenceError,
                                                     gated_grad_health,
                                                     grad_health)
 from bert_pytorch_tpu.telemetry.profiler import (SPANS, ProfilerWindow,
-                                                 parse_profile_spec, span)
+                                                 parse_profile_spec, span,
+                                                 startup_open)
 from bert_pytorch_tpu.telemetry.runner import TrainTelemetry
 from bert_pytorch_tpu.telemetry.schema import (SCHEMA_VERSION,
                                                validate_file,
@@ -70,6 +71,7 @@ __all__ = [
     "parse_profile_spec",
     "shapes_digest",
     "span",
+    "startup_open",
     "validate_file",
     "validate_record",
 ]

@@ -11,7 +11,17 @@ wrapped function and the argument-shapes digest that triggered it.
 Emitted record (``kind="compile"``, schema.py)::
 
     {"kind": "compile", "fn": "train_step", "shapes_digest": "ab12…",
-     "compile_s": 12.31, "backend_compile_s": 11.90, "cache": "miss"}
+     "compile_s": 12.31, "trace_s": 0.21, "lower_s": 0.18,
+     "backend_compile_s": 11.90, "cache_load_s": 0.0, "cache": "miss"}
+
+``compile_s`` is host time round the wrapped call, whatever the call does
+(under a wrapper that blocks or copies inside it, that too). The other four
+are measured where the work happens, from ``jax.monitoring``: ``trace_s``
+(the function traced to a jaxpr) and ``lower_s`` (the jaxpr turned into an
+MLIR module) are the time covered by those events, an event inside another
+counted once; ``backend_compile_s`` is the time in JAX's compile-or-load
+call, which on a persistent-cache hit is mostly ``cache_load_s`` (the read
+and deserialization of the entry, fired on hits only).
 
 ``cache`` is one of:
 
@@ -55,6 +65,14 @@ _BACKEND_COMPILE_EVENTS = (
     # older/newer spellings kept for forward compatibility
     "/jax/backend_compile_duration",
 )
+# Fired on the compiling thread as durations and as (start, end) spans; one
+# trace or lowering can lie inside another (a jitted function called while
+# another is traced), so these two are kept as spans and merged.
+_NESTING_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+}
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 
@@ -72,6 +90,21 @@ def _on_duration(event: str, duration_secs: float, **_kw) -> None:
     if event in _BACKEND_COMPILE_EVENTS:
         call["backend_compile_s"] += float(duration_secs)
         call["compiled"] = True
+    elif event == _CACHE_LOAD_EVENT:
+        call["cache_load_s"] += float(duration_secs)
+
+
+def _on_span(event: str, start: float, end: float, **_kw) -> None:
+    call = _current_call()
+    kind = _NESTING_EVENTS.get(event)
+    if call is None or kind is None:
+        return
+    # Events arrive as they END, so the ones inside this one are at the
+    # tail: they give way to it.
+    spans = call[kind]
+    while spans and spans[-1][0] >= start:
+        spans.pop()
+    spans.append((start, end))
 
 
 def _on_event(event: str, **_kw) -> None:
@@ -93,8 +126,24 @@ def _ensure_listeners() -> None:
     with _install_lock:
         if _installed:
             return
-        compile_cache_util.install_compile_listeners(_on_event, _on_duration)
+        compile_cache_util.install_compile_listeners(
+            _on_event, _on_duration, _on_span)
         _installed = True
+
+
+def _new_call() -> dict:
+    return {"backend_compile_s": 0.0, "compiled": False, "cache_load_s": 0.0,
+            "cache_hits": 0, "cache_misses": 0, "trace": [], "lower": []}
+
+
+def _split(call: dict) -> dict:
+    """The record's fields for where a call's compile time went."""
+    return {
+        "trace_s": round(sum((e - s for s, e in call["trace"]), 0.0), 4),
+        "lower_s": round(sum((e - s for s, e in call["lower"]), 0.0), 4),
+        "backend_compile_s": round(call["backend_compile_s"], 4),
+        "cache_load_s": round(call["cache_load_s"], 4),
+    }
 
 
 def shapes_digest(tree) -> str:
@@ -170,8 +219,7 @@ class CompileMonitor:
 
         def wrapper(*args, **kwargs):
             prev = _current_call()
-            call = {"backend_compile_s": 0.0, "compiled": False,
-                    "cache_hits": 0, "cache_misses": 0}
+            call = _new_call()
             _tls.call = call
             t0 = self._clock()
             try:
@@ -214,11 +262,12 @@ class CompileMonitor:
             "tag": "telemetry",
             "fn": name,
             "shapes_digest": digest,
-            # dispatch wall time of the call that compiled: trace + lower +
-            # backend compile (+ the async enqueue, which is noise at
-            # compile timescales)
+            # host time round the call that compiled: trace + lower +
+            # backend compile + the enqueue, and whatever else the wrapped
+            # callable does before it returns (a probe that blocks on the
+            # result or copies it to the host: benchmarks/kinds/*.py)
             "compile_s": round(elapsed, 4),
-            "backend_compile_s": round(call["backend_compile_s"], 4),
+            **_split(call),
             "cache": cache,
         }
         self.events.append(record)
@@ -233,11 +282,21 @@ class CompileMonitor:
         self._cost_done.add(key)
         from bert_pytorch_tpu.telemetry import memory as memory_util
 
-        fields = memory_util.analyze_executable(
-            fn, args, kwargs, mode=self.cost_analysis)
+        # The analysis lowers the function again and asks for its
+        # executable (memory.py): what that costs, and where, is start-up
+        # time like the first call's.
+        prev, call = _current_call(), _new_call()
+        _tls.call = call
+        t0 = self._clock()
+        try:
+            fields = memory_util.analyze_executable(
+                fn, args, kwargs, mode=self.cost_analysis)
+        finally:
+            _tls.call = prev
         if fields is None:
             return
         record = {"kind": "compile_cost", "tag": "telemetry", "fn": name,
-                  "shapes_digest": digest, **fields}
+                  "shapes_digest": digest, **fields,
+                  "analysis_s": round(self._clock() - t0, 4), **_split(call)}
         self.events.append(record)
         self._emit(record)

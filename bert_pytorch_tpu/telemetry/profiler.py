@@ -14,8 +14,17 @@ step_num=...)`` and the loop's phases in spans (:func:`span`, ``SPANS``), whoeve
 opened the trace session (``--profile_steps``, ``POST /profilez``, a
 benchmark, ``jax.profiler.start_trace`` by hand): they land in the same
 ``.xplane.pb`` as the device's ops, on the same clock, and cost a flag test
-while no session is open. The session is the only store: there is no other
-recorder.
+while no session is open. Inside the loop the session is the only store:
+no ``train:*``, ``prefetch:*`` or ``data:*`` span is kept anywhere else.
+
+The ``startup:*`` spans, and they alone, are also kept in memory, because
+no session can be open while JAX is being imported and a restarted job's
+time to train again is what they measure: ``run_pretraining.main`` opens a
+:class:`StartupSpans` on entry, :func:`span` appends (name, parent, start,
+end) to it for each ``startup:*`` span, some ten in all, and the first
+update closes it for good (``TrainTelemetry`` turns it into the one
+``kind="startup"`` record, :func:`startup_record`). After that a
+``startup:*`` span is a ``TraceAnnotation`` like any other.
 
 The startup window used to be this module's ONLY contract — one window
 per process lifetime, latched by ``done``. :meth:`ProfilerWindow.begin`
@@ -36,10 +45,15 @@ xprof. See docs/telemetry.md for the workflow.
 
 from __future__ import annotations
 
+import contextlib
+import os
 import threading
+import time
 from typing import Optional, Tuple
 
 import jax
+
+_IMPORTED_NS = time.perf_counter_ns()  # process_origin()'s fallback
 
 # Every span the program writes, on the thread that writes it. The
 # ``train:*`` spans nest in the step's ``train`` annotation on the loop's
@@ -50,18 +64,151 @@ import jax
 # (data/loader.py epoch_chain);
 # ``data:*`` are per batch / per shard on the loader's threads
 # (data/loader.py, data/dataset.py). docs/telemetry.md names what each
-# covers; benchmarks/trace/scopes.py reads them.
+# covers; benchmarks/trace/scopes.py reads them. The ``startup:*`` spans are
+# ``run_pretraining.main``'s, on the main thread, before the loop:
+# ``startup:backend`` lies in ``startup:setup`` and ``startup:restore`` in
+# ``startup:state_init``; benchmarks/trace/startup.py reads them from the
+# ``startup`` record.
+STARTUP = "startup:"
 SPANS = ("train:feed", "train:dispatch", "train:sync", "train:fetch_metrics",
          "train:log", "train:telemetry", "train:checkpoint", "train:eval",
          "prefetch:source_wait", "prefetch:h2d", "prefetch:epoch_start",
-         "data:shard_load", "data:collate")
+         "data:shard_load", "data:collate",
+         "startup:setup", "startup:backend", "startup:model",
+         "startup:optimizer", "startup:data", "startup:state_init",
+         "startup:restore", "startup:step_build")
 
 
 def span(name: str, **stats):
     """One host span in the profiler's trace: a ``TraceAnnotation`` (keyword
     arguments become the event's stats). Nothing is recorded, and nothing
-    but a flag is read, while no trace session is open."""
-    return jax.profiler.TraceAnnotation(name, **stats)
+    but a flag is read, while no trace session is open; a ``startup:*`` span
+    is kept in memory too while a :class:`StartupSpans` is open."""
+    annotation = jax.profiler.TraceAnnotation(name, **stats)
+    if _startup is not None and name.startswith(STARTUP):
+        return _startup.keep(name, annotation)
+    return annotation
+
+
+# -- start-up: the spans kept in memory until the first update ---------------
+
+_startup = None  # the open StartupSpans, if any (main thread only)
+
+
+class StartupSpans:
+    """The ``startup:*`` spans of one ``main``, as (name, parent, start_ns,
+    end_ns) on ``time.perf_counter_ns``, in the order they closed."""
+
+    def __init__(self):
+        self.entered_ns = time.perf_counter_ns()
+        self.spans: list = []
+        self._open: list = []  # names of the spans entered and not left
+
+    @contextlib.contextmanager
+    def keep(self, name: str, annotation):
+        """``annotation`` entered and left, and the span kept if the store
+        is still the open one when it ends."""
+        with annotation:
+            parent = self._open[-1] if self._open else None
+            self._open.append(name)
+            start = time.perf_counter_ns()
+            try:
+                yield
+            finally:
+                end = time.perf_counter_ns()
+                self._open.pop()
+                if _startup is self:
+                    self.spans.append((name, parent, start, end))
+
+    def close(self) -> None:
+        """No span is kept after this (the first update, or a later
+        ``main`` in the same process)."""
+        global _startup
+        if _startup is self:
+            _startup = None
+
+
+def startup_open() -> StartupSpans:
+    """Called by ``main`` on entry: from here every ``startup:*`` span is
+    kept, until the store's ``close``."""
+    global _startup
+    _startup = StartupSpans()
+    return _startup
+
+
+_origin = None
+
+
+def process_origin() -> Tuple[int, str]:
+    """(``perf_counter_ns`` at which this process was created, where that
+    comes from). On Linux the kernel's own stamp (``/proc/self/stat`` field
+    22, in clock ticks since boot: 10 ms steps) against ``CLOCK_BOOTTIME``
+    now, so interpreter start and imports are inside; elsewhere the moment
+    this module was imported, which leaves out what came before it."""
+    global _origin
+    if _origin is None:
+        _origin = (_IMPORTED_NS, "package_import")
+        try:
+            with open("/proc/self/stat", encoding="ascii") as f:
+                ticks = int(f.read().rpartition(")")[2].split()[19])
+            age_ns = time.clock_gettime_ns(time.CLOCK_BOOTTIME) - (
+                ticks * 1_000_000_000 // os.sysconf("SC_CLK_TCK"))
+            created = time.perf_counter_ns() - age_ns
+            if 0 <= age_ns and created <= _IMPORTED_NS:
+                _origin = (created, "proc_stat")
+        except (OSError, ValueError, IndexError, AttributeError):
+            pass
+    return _origin
+
+
+def startup_record(store: StartupSpans, feed_start_s: float,
+                   feed_end_s: float, call_end_s: float, sync_end_s: float,
+                   compiles: list) -> dict:
+    """The one ``kind="startup"`` record of a run (schema.py; the sibling of
+    serving's ``serve_cold_start``). The four ``*_s`` arguments are the
+    step timer's ``perf_counter`` marks of the first update (its feed
+    entered and left, its step call returned) and the end of the runner's
+    barrier on that update; ``compiles`` are the ``compile`` records so far.
+    Every stamp in the record is in seconds since the process was created."""
+    origin_ns, origin = process_origin()
+
+    def since(ns: float) -> float:
+        return round((ns - origin_ns) * 1e-9, 6)
+
+    entered = since(store.entered_ns)
+    phases = [{"name": name, "parent": parent, "start_s": since(start),
+               "end_s": since(end)}
+              for name, parent, start, end in
+              sorted(store.spans, key=lambda s: s[2])]
+    wait = round(feed_end_s - feed_start_s, 6)
+    call = round(call_end_s - feed_end_s, 6)
+    sync = round(sync_end_s - call_end_s, 6)
+    total = since(sync_end_s * 1e9)
+    named = sum(p["end_s"] - p["start_s"] for p in phases
+                if p["parent"] is None)
+    pair = (time.perf_counter_ns(), time.time_ns())
+    return {
+        "kind": "startup", "tag": "telemetry", "origin": origin,
+        "main_entered_s": entered,
+        "phases": phases,
+        "first_batch_wait_s": wait,
+        "first_call_s": call,
+        "first_sync_s": sync,
+        "time_to_first_update_s": total,
+        # what lies between the named parts: main's lines between two
+        # spans, the loop's preamble and its lines before the barrier
+        "unattributed_s": round(
+            total - entered - named - wait - call - sync, 6),
+        "compiles": len(compiles),
+        "compiles_cold": sum(1 for c in compiles
+                             if c.get("cache") in ("miss", "uncached")),
+        "compiles_warm": sum(1 for c in compiles if c.get("cache") == "hit"),
+        # perf_counter_ns and time_ns read together: a stamp s of this
+        # record is unix time time_ns + (origin + s * 1e9 - perf_counter_ns),
+        # the clock of a trace's events (docs/telemetry.md "Start-up")
+        "clock": {"perf_counter_ns": pair[0], "time_ns": pair[1],
+                  "process_created_perf_counter_ns": origin_ns},
+    }
 
 # Process-wide trace exclusivity (concurrency registry): jax.profiler
 # allows one active trace per process; flipped by whichever thread's

@@ -54,7 +54,8 @@ from bert_pytorch_tpu.telemetry.compile_events import CompileMonitor
 from bert_pytorch_tpu.telemetry.memory import MemorySampler
 from bert_pytorch_tpu.telemetry.model_stats import (DivergenceMonitor,
                                                     health_record)
-from bert_pytorch_tpu.telemetry.profiler import ProfilerWindow, span
+from bert_pytorch_tpu.telemetry.profiler import (ProfilerWindow, span,
+                                                 startup_record)
 from bert_pytorch_tpu.telemetry.sampler import CaptureController
 from bert_pytorch_tpu.telemetry.sentinels import (FailureSentinel, Heartbeat,
                                                   HeartbeatWatchdog)
@@ -194,6 +195,18 @@ class TrainTelemetry:
         ``h2d_wait`` sub-phase (data/device_prefetch.py DevicePrefetcher),
         and fold the prefetcher's gauges into window records."""
         self._prefetcher = prefetcher
+
+    def first_update_done(self, startup) -> None:
+        """The runner's own barrier on its first update has returned (the
+        ``block_until_ready`` before its throughput clock starts): close
+        ``startup`` (the ``profiler.StartupSpans`` it opened on entry) and
+        emit the run's one ``startup`` record, the update's three parts
+        from the step timer's marks and this moment."""
+        startup.close()
+        self.emit(startup_record(
+            startup, *self.timer.marks(), self._clock(),
+            [e for e in self.compile_monitor.events
+             if e.get("kind") == "compile"]))
 
     @contextlib.contextmanager
     def checkpoint_stall(self):

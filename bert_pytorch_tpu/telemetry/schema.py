@@ -69,6 +69,17 @@ KIND_REQUIRED_KEYS = {
     # the persisted winners cache, or the heuristic fallback — plus the
     # winning (block_q, block_k, bh_block) when one exists
     "autotune": ("kernel", "seq", "bh", "source"),
+    # one trainer start-up, emitted at main's barrier on its first update
+    # (telemetry/profiler.py startup_record): from the process's creation
+    # to the first update, as named phases, the first batch's wait, the
+    # first step call, the wait at that barrier, and what none of them
+    # covers; the trainer's sibling of serve_cold_start
+    "startup": (
+        "origin", "main_entered_s", "phases", "first_batch_wait_s",
+        "first_call_s", "first_sync_s", "time_to_first_update_s",
+        "unattributed_s", "compiles", "compiles_cold", "compiles_warm",
+        "clock",
+    ),
     # end-of-run rollup
     "run_summary": ("steps",),
     # -- fault-tolerance record family (docs/fault_tolerance.md) -------
@@ -350,6 +361,8 @@ def validate_record(rec) -> list:
                     _check_serve_fields(rec, errors)
                 if kind == "serve_cold_start":
                     _check_cold_start_fields(rec, errors)
+                if kind == "startup":
+                    _check_startup_fields(rec, errors)
                 if kind == "serve_trace":
                     _check_trace_fields(rec, errors)
                 if kind == "serve_phase":
@@ -511,6 +524,71 @@ def _check_cold_start_fields(rec, errors) -> None:
 
 def _is_number(v) -> bool:
     return isinstance(v, (int, float)) and not isinstance(v, bool)
+
+
+STARTUP_ORIGINS = ("proc_stat", "package_import")
+# Every stamp is rounded to a microsecond before the sums are taken.
+_STARTUP_EPS_S = 1e-4
+
+
+def _check_startup_fields(rec, errors) -> None:
+    """Start-up consistency (telemetry/profiler.py startup_record): the
+    phases lie inside [main_entered_s, time_to_first_update_s], a child
+    inside its parent, siblings one after another; and the top-level
+    phases, the first update's three parts and ``unattributed_s`` add up
+    to ``time_to_first_update_s - main_entered_s``, so a reader can take
+    any of them as a share of the whole."""
+    if rec.get("origin") not in STARTUP_ORIGINS:
+        errors.append(f"origin must be one of {STARTUP_ORIGINS}, got "
+                      f"{rec.get('origin')!r}")
+    names = ("main_entered_s", "first_batch_wait_s", "first_call_s",
+             "first_sync_s", "time_to_first_update_s")
+    bad = [k for k in names if not _is_number(rec.get(k)) or rec[k] < 0]
+    if not _is_number(rec.get("unattributed_s")):
+        bad.append("unattributed_s")
+    clock = rec.get("clock")
+    if not isinstance(clock, dict) or not all(
+            isinstance(clock.get(k), int) for k in
+            ("perf_counter_ns", "time_ns",
+             "process_created_perf_counter_ns")):
+        errors.append("clock must hold perf_counter_ns, time_ns and "
+                      "process_created_perf_counter_ns as integers")
+    phases = rec.get("phases")
+    if not isinstance(phases, list) or not all(
+            isinstance(p, dict) and isinstance(p.get("name"), str)
+            and _is_number(p.get("start_s")) and _is_number(p.get("end_s"))
+            and (p.get("parent") is None or isinstance(p["parent"], str))
+            for p in phases):
+        bad.append("phases")
+    if bad:
+        errors.append(f"startup fields malformed or negative: {bad}")
+        return
+    _check_cold_start_fields(
+        {"cold_start_s": rec["time_to_first_update_s"], **rec}, errors)
+    lo, hi = rec["main_entered_s"], rec["time_to_first_update_s"]
+    spans = {None: ("the start-up", lo, hi)}
+    spans.update({p["name"]: (p["name"], p["start_s"], p["end_s"])
+                  for p in phases})
+    last_end = {}  # parent -> where its last child so far ended
+    for p in phases:
+        outer, start, end = spans.get(p["parent"], spans[None])
+        if not (start - _STARTUP_EPS_S <= p["start_s"] <= p["end_s"]
+                <= end + _STARTUP_EPS_S):
+            errors.append(
+                f"phase {p['name']!r} [{p['start_s']}, {p['end_s']}] lies "
+                f"outside {outer} [{start}, {end}]")
+        if p["start_s"] < last_end.get(p["parent"], lo) - _STARTUP_EPS_S:
+            errors.append(
+                f"phase {p['name']!r} overlaps the phase before it")
+        last_end[p["parent"]] = p["end_s"]
+    named = sum(p["end_s"] - p["start_s"] for p in phases
+                if p["parent"] is None)
+    total = (named + rec["first_batch_wait_s"] + rec["first_call_s"]
+             + rec["first_sync_s"] + rec["unattributed_s"])
+    if abs(total - (hi - lo)) > _STARTUP_EPS_S:
+        errors.append(
+            f"startup parts add up to {total:.6f} s, not to "
+            f"time_to_first_update_s - main_entered_s = {hi - lo:.6f} s")
 
 
 def _check_trace_fields(rec, errors) -> None:

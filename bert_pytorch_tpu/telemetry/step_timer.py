@@ -144,6 +144,11 @@ class StepTimer:
     def dispatch_end(self) -> None:
         self._t_dispatch1 = self._clock()
 
+    def marks(self) -> tuple:
+        """The open step's clock reads so far: feed entered, feed left,
+        step call returned."""
+        return (self._t_data0, self._t_data1, self._t_dispatch1)
+
     def should_sync(self) -> bool:
         if self.sync_every == 0:
             return False

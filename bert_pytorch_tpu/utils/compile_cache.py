@@ -101,16 +101,22 @@ def enable_compile_cache(cache_dir: str = "",
     return target or os.environ[CACHE_DIR_ENV]
 
 
-def install_compile_listeners(event_cb, duration_cb) -> None:
+def install_compile_listeners(event_cb, duration_cb, span_cb) -> None:
     """Register ``jax.monitoring`` listeners for compile observability.
 
     ``event_cb(event, **kw)`` receives counter events (persistent-cache
     hits/misses: ``/jax/compilation_cache/cache_hits`` / ``cache_misses``);
-    ``duration_cb(event, duration_secs, **kw)`` receives durations (real XLA
-    compiles: ``/jax/core/compile/backend_compile_duration``). Registration
-    is permanent — jax.monitoring has no unregister — so callers install
+    ``duration_cb(event, duration_secs, **kw)`` receives durations (the
+    compile-or-load call: ``/jax/core/compile/backend_compile_duration``;
+    a cache entry read: ``/jax/compilation_cache/cache_retrieval_time_sec``);
+    ``span_cb(event, start, end, **kw)`` receives the events JAX times with
+    a start and an end (``/jax/core/compile/jaxpr_trace_duration``,
+    ``/jax/core/compile/jaxpr_to_mlir_module_duration``), which is what
+    tells one inside another from two in a row. All fire on the compiling
+    thread. Registration is permanent, so callers install
     once and route internally (telemetry/compile_events.py does)."""
     import jax.monitoring as monitoring
 
     monitoring.register_event_listener(event_cb)
     monitoring.register_event_duration_secs_listener(duration_cb)
+    monitoring.register_event_time_span_listener(span_cb)

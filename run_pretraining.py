@@ -346,10 +346,13 @@ def setup_training(args):
     run_pretraining.py:180-230)."""
     jax.config.update("jax_default_prng_impl", args.rng_impl)
     cache_dir = enable_compile_cache(args.compile_cache_dir)
-    launcher.initialize()
     spec = MeshSpec.parse(args.mesh)
     spec.validate(packed=bool(args.pack_sequences))
-    mesh = create_mesh(spec.mesh_config())
+    # The first touch of the devices: the rendezvous on a pod, the chip
+    # runtime's start.
+    with telemetry.span("startup:backend"):
+        launcher.initialize()
+        mesh = create_mesh(spec.mesh_config())
     # Record the RESOLVED spec (data=-1 replaced by the realized size):
     # checkpoint manifests and telemetry label topologies with it.
     args.mesh_spec = dataclasses.replace(
@@ -679,261 +682,282 @@ def prepare_dataset(args, config, checkpoint):
 
 
 def main(args) -> dict:
-    args, mesh = setup_training(args)
-    model, config, checkpoint, global_step = prepare_model(args, mesh)
-    tx, schedule = prepare_optimizer(args)
-    loader, sampler, val_loader = prepare_dataset(args, config, checkpoint)
+    # Start-up (docs/telemetry.md "Start-up"): the startup:* spans from here
+    # to the loop are kept until the first update, which emits them as the
+    # run's one ``startup`` record.
+    startup = telemetry.startup_open()
+    with telemetry.span("startup:setup"):
+        args, mesh = setup_training(args)
+    with telemetry.span("startup:model"):
+        model, config, checkpoint, global_step = prepare_model(args, mesh)
+    with telemetry.span("startup:optimizer"):
+        tx, schedule = prepare_optimizer(args)
+    with telemetry.span("startup:data"):
+        loader, sampler, val_loader = prepare_dataset(args, config, checkpoint)
 
-    rules = logical_axis_rules(args.mesh_spec)
-    causal_lm = args.objective == "causal_lm"
-    if causal_lm:
-        # No position table: the rows' own length is the sequence length, and
-        # the parameters do not depend on it (a short sample initializes).
-        seq_len = int(loader.dataset[0]["input_ids"].shape[-1])
-        sample = (jnp.zeros((1, config.init_sample_length), jnp.int32),)
-    else:
-        seq_len = config.max_position_embeddings
-        sample = (jnp.zeros((1, seq_len), jnp.int32),) * 3
-    # Packed rows: per-sequence NSP labels [B, K] + the packing arrays;
-    # max_predictions_per_seq stays a per-SEQUENCE budget, so the per-ROW
-    # MLM gather cap scales by the pack limit.
-    packed = getattr(args, "packed", False)
-    if packed:
-        # Catches OFFLINE-packed shards too (auto-detected, no flag) —
-        # setup_training's early check only sees --pack_sequences.
-        try:
-            args.mesh_spec.validate(packed=True)
-        except MeshSpecError as e:
-            raise ValueError(
-                f"packed pretraining data: {e}; re-encode the shards "
-                "unpacked or drop the seq axis") from None
-    eff_max_pred = args.max_predictions_per_seq * (
-        args.pack_k if packed else 1)
-    batch_spec = {"input_ids": 3, "segment_ids": 3, "input_mask": 3,
-                  "masked_lm_labels": 3,
-                  "next_sentence_labels": 3 if packed else 2}
-    if packed:
-        batch_spec.update({"sequence_ids": 3, "cls_positions": 3})
-    if causal_lm:
-        batch_spec = {"input_ids": 3}
-    with mesh:
-        fp16 = args.dtype == "float16"
-        shardings = pretrain.state_shardings(mesh, model, rules, sample,
-                                             loss_scaled=fp16)
-        b_shardings = pretrain.batch_shardings(
-            mesh, batch_spec, seq_sharded=args.mesh_spec.seq > 1)
-        init_fn = pretrain.make_init_fn(model, tx, sample, shardings)
-        state = init_fn(jax.random.PRNGKey(args.seed))
-
-        if checkpoint is not None:
-            # Restore onto an ABSTRACT template (shapes/dtypes only), not a
-            # device_get of the live state: on a multi-host fsdp/tp mesh the
-            # live state has non-addressable shards that device_get cannot
-            # fetch. Every process reads the full file and device_put slices
-            # out its addressable shards of the target sharding.
-            abstract = jax.eval_shape(init_fn, jax.random.PRNGKey(args.seed))
-            params = ckpt.restore_tree(abstract.params, checkpoint["model"])
-            opt_state = ckpt.restore_tree(
-                abstract.opt_state, checkpoint["optimizer"])
-            state = pretrain.TrainState(
-                params=jax.device_put(params, shardings.params),
-                opt_state=jax.device_put(opt_state, shardings.opt_state),
-                rng=state.rng)
-            if args.resume_step >= args.previous_phase_end_step > 0:
-                # Phase-2 surgery (reference run_pretraining.py:298-309):
-                # schedule hyperparams come from the new config; only the
-                # optimizer step counter is rewritten.
-                state = state.replace(
-                    opt_state=optim.reset_count(state.opt_state, global_step))
-                logger.info(f"Phase switch: optimizer count reset to {global_step}")
-
-        kfac_obj = kfac_state = kfac_shardings = None
-        kfac_fused = False
-        if args.kfac:
-            kfac_fused = args.kfac_capture == "train"
-            if kfac_fused and args.mesh_spec.pipe > 1:
-                # The pipeline step has no fused-capture path (factors
-                # would need per-stage reassembly); fall back to the
-                # decoupled stats pass.
-                logger.info("kfac_capture=train is not supported with "
-                            "pipeline parallelism; using 'stats'")
-                kfac_fused = False
-            # Tapped twin of the model (same params, factor-capture taps on;
-            # reference drives kfac_pytorch hooks at run_pretraining.py:320-355).
-            # The fused-capture twin keeps the main model's remat so the
-            # tapped microbatch-0 backward fits the same memory budget; the
-            # stats-pass twin runs a small decoupled batch where remat only
-            # costs recompute.
-            model_tapped = BertForPreTraining(
-                config, dtype=model.dtype,
-                remat=model.remat if kfac_fused else "none",
-                attention_backend=args.attention_backend, kfac_tap=True)
-            apply_loss, tap_shape_fn = pretrain.make_kfac_fns(
-                model_tapped, next_sentence=bool(config.next_sentence),
-                max_pred_per_seq=eff_max_pred)
-            kfac_obj = optim.KFAC(
-                apply_loss, tap_shape_fn,
-                factor_decay=args.kfac_stat_decay,
-                damping=args.kfac_damping,
-                kl_clip=args.kfac_kl_clip,
-                inv_method=args.kfac_inv_method,
-                skip_layers=tuple(args.kfac_skip_layers))
-            micro_b = args.global_batch_size // args.accumulation_steps
-            sample_mb = {
-                "input_ids": np.zeros((micro_b, seq_len), np.int32),
-                "segment_ids": np.zeros((micro_b, seq_len), np.int32),
-                "input_mask": np.zeros((micro_b, seq_len), np.int32),
-                "masked_lm_labels": np.zeros((micro_b, seq_len), np.int32),
-                "next_sentence_labels": np.zeros((micro_b,), np.int32),
-            }
-            kfac_state = kfac_obj.init(state.params, sample_mb)
-            kfac_shardings = optim.kfac_state_shardings(mesh, kfac_state)
-            if checkpoint is not None and "preconditioner" in checkpoint:
-                kfac_state = ckpt.restore_tree(
-                    kfac_state, checkpoint["preconditioner"])
-                kfac_state = jax.device_put(kfac_state, kfac_shardings)
-                # Recompute qa/qg from the restored factors: the checkpoint
-                # may hold the OTHER inv_method's operators (eigenvectors vs
-                # damped inverses share the same state slots/shapes), and a
-                # mid-interval resume would otherwise precondition with the
-                # wrong operator for up to inv_interval steps with no error.
-                kfac_state = kfac_obj.update_inverses(kfac_state)
-                logger.info("Restored K-FAC preconditioner state "
-                            "(inverses recomputed from factors)")
-            else:
-                kfac_state = jax.device_put(kfac_state, kfac_shardings)
-            logger.info(
-                f"K-FAC enabled: {len(kfac_obj.specs)} layer groups, "
-                f"capture={'train (fused)' if kfac_fused else 'stats'}, "
-                f"damping={args.kfac_damping}, kl_clip={args.kfac_kl_clip}, "
-                f"factor_interval={args.kfac_factor_interval}, "
-                f"inv_interval={args.kfac_inv_interval}")
-
-        # Where the state really lives (a mesh built over four devices does
-        # not by itself put anything on the last three): logged here,
-        # stamped into the run summary with the first batch's placement.
-        placement = {
-            "params_devices": _devices_holding(state.params),
-            "opt_state_devices": _devices_holding(state.opt_state),
-            "params_share_on_first_device": round(
-                _share_on_first_device(state.params), 4),
-        }
-        logger.info(
-            "state placed: params on {params_devices} device(s), optimizer "
-            "state on {opt_state_devices}; the first device holds "
-            "{params_share_on_first_device:.0%} of the parameter bytes"
-            .format(**placement))
-
-        # Grad-health due gate must count from THIS run's start: the host
-        # reads it on a run-local 0-based sync cadence, while the restored
-        # optimizer count is absolute — a resume step that is not a
-        # multiple of the cadence would otherwise push every due step
-        # onto an unsynced step (zero records for the whole resumed run).
-        stats_phase = int(jax.device_get(
-            optim.opt_step_count(state.opt_state)))
-
-        dropout.forget_draws()  # draw_shards() below speaks of this step
-        if args.mesh_spec.pipe > 1:
-            if args.accumulation_steps < mesh.shape[AXIS_PIPE]:
-                raise ValueError(
-                    f"pp needs accumulation_steps >= pipeline stages "
-                    f"({args.accumulation_steps} < "
-                    f"{mesh.shape[AXIS_PIPE]}); "
-                    "raise global_batch_size or lower local_batch_size")
-            train_step = pretrain.make_pp_train_step(
-                model, tx, mesh, schedule=schedule,
-                next_sentence=bool(config.next_sentence),
-                shardings=shardings, batch_shardings_=b_shardings,
-                max_pred_per_seq=eff_max_pred,
-                kfac=kfac_obj, kfac_shardings=kfac_shardings,
-                stats_every=telemetry.stats_every(args),
-                stats_phase=stats_phase)
+        # What the data says of the step's shapes (the decoder families read
+        # their first row for it).
+        rules = logical_axis_rules(args.mesh_spec)
+        causal_lm = args.objective == "causal_lm"
+        if causal_lm:
+            # No position table: the rows' own length is the sequence length,
+            # and the parameters do not depend on it (a short sample
+            # initializes).
+            seq_len = int(loader.dataset[0]["input_ids"].shape[-1])
+            sample = (jnp.zeros((1, config.init_sample_length), jnp.int32),)
         else:
-            train_step = pretrain.make_train_step(
-                model, tx, schedule=schedule,
-                next_sentence=bool(getattr(config, "next_sentence", False)),
-                shardings=shardings, batch_shardings_=b_shardings,
-                max_pred_per_seq=eff_max_pred,
-                kfac=kfac_obj, kfac_shardings=kfac_shardings,
-                kfac_capture_model=model_tapped if kfac_fused else None,
-                kfac_factor_interval=args.kfac_factor_interval,
-                kfac_inv_interval=args.kfac_inv_interval if kfac_fused else 0,
-                kfac_capture_microbatches=args.kfac_capture_microbatches,
-                loss_scale=fp16,
-                stats_every=telemetry.stats_every(args),
-                stats_phase=stats_phase)
+            seq_len = config.max_position_embeddings
+            sample = (jnp.zeros((1, seq_len), jnp.int32),) * 3
+        # Packed rows: per-sequence NSP labels [B, K] + the packing arrays;
+        # max_predictions_per_seq stays a per-SEQUENCE budget, so the per-ROW
+        # MLM gather cap scales by the pack limit.
+        packed = getattr(args, "packed", False)
+        if packed:
+            # Catches OFFLINE-packed shards too (auto-detected, no flag) —
+            # setup_training's early check only sees --pack_sequences.
+            try:
+                args.mesh_spec.validate(packed=True)
+            except MeshSpecError as e:
+                raise ValueError(
+                    f"packed pretraining data: {e}; re-encode the shards "
+                    "unpacked or drop the seq axis") from None
+        eff_max_pred = args.max_predictions_per_seq * (
+            args.pack_k if packed else 1)
+        batch_spec = {"input_ids": 3, "segment_ids": 3, "input_mask": 3,
+                      "masked_lm_labels": 3,
+                      "next_sentence_labels": 3 if packed else 2}
+        if packed:
+            batch_spec.update({"sequence_ids": 3, "cls_positions": 3})
+        if causal_lm:
+            batch_spec = {"input_ids": 3}
+    with mesh:
+        with telemetry.span("startup:state_init"):
+            fp16 = args.dtype == "float16"
+            shardings = pretrain.state_shardings(mesh, model, rules, sample,
+                                                 loss_scaled=fp16)
+            b_shardings = pretrain.batch_shardings(
+                mesh, batch_spec, seq_sharded=args.mesh_spec.seq > 1)
+            init_fn = pretrain.make_init_fn(model, tx, sample, shardings)
+            state = init_fn(jax.random.PRNGKey(args.seed))
 
-        # Telemetry (docs/telemetry.md): JSONL sink shared with the logger,
-        # step-time decomposition windows, profiler trace window, compile
-        # attribution, failure sentinels, rank-0 heartbeat. flops_per_seq is
-        # refreshed once the DATA sequence length is known (phase-1 data is
-        # 128 tokens while max_position_embeddings stays 512).
-        from bert_pytorch_tpu.utils import flops as flops_util
+            if checkpoint is not None:
+                with telemetry.span("startup:restore"):
+                    # Restore onto an ABSTRACT template (shapes/dtypes
+                    # only), not a device_get of the live state: on a
+                    # multi-host fsdp/tp mesh the live state has
+                    # non-addressable shards that device_get cannot fetch.
+                    # Every process reads the full file and device_put
+                    # slices out its addressable shards of the target
+                    # sharding.
+                    abstract = jax.eval_shape(
+                        init_fn, jax.random.PRNGKey(args.seed))
+                    params = ckpt.restore_tree(
+                        abstract.params, checkpoint["model"])
+                    opt_state = ckpt.restore_tree(
+                        abstract.opt_state, checkpoint["optimizer"])
+                    state = pretrain.TrainState(
+                        params=jax.device_put(params, shardings.params),
+                        opt_state=jax.device_put(
+                            opt_state, shardings.opt_state),
+                        rng=state.rng)
+                    if args.resume_step >= args.previous_phase_end_step > 0:
+                        # Phase-2 surgery (reference
+                        # run_pretraining.py:298-309): schedule hyperparams
+                        # come from the new config; only the optimizer step
+                        # counter is rewritten.
+                        state = state.replace(opt_state=optim.reset_count(
+                            state.opt_state, global_step))
+                        logger.info("Phase switch: optimizer count reset "
+                                    f"to {global_step}")
 
-        def flops_per_seq(seq):
-            if causal_lm:
-                return flops_util.causal_lm_train_flops_per_seq(config, seq)
-            return flops_util.bert_train_flops_per_seq(
-                config, seq, eff_max_pred,
-                next_sentence=bool(config.next_sentence))
+            # Where the state really lives (a mesh built over four devices does
+            # not by itself put anything on the last three): logged here,
+            # stamped into the run summary with the first batch's placement.
+            placement = {
+                "params_devices": _devices_holding(state.params),
+                "opt_state_devices": _devices_holding(state.opt_state),
+                "params_share_on_first_device": round(
+                    _share_on_first_device(state.params), 4),
+            }
+            logger.info(
+                "state placed: params on {params_devices} device(s), optimizer "
+                "state on {opt_state_devices}; the first device holds "
+                "{params_share_on_first_device:.0%} of the parameter bytes"
+                .format(**placement))
 
-        tele = telemetry.from_args(
-            args,
-            sink=args.telemetry_sink,
-            is_primary=is_main_process(),
-            seq_per_step=args.global_batch_size,
-            flops_per_seq=flops_per_seq(seq_len),
-            # Padding-aware accounting: the step's token budget; the train
-            # step's real_tokens metric divides out the pads
-            # (padding_efficiency in the window records).
-            tokens_per_step=args.global_batch_size * seq_len,
-            output_dir=args.output_dir,
-            process="pretrain")
-        tele.attach_loader(loader)
-        train_step = tele.instrument(train_step, "train_step")
+            # Grad-health due gate must count from THIS run's start: the host
+            # reads it on a run-local 0-based sync cadence, while the restored
+            # optimizer count is absolute — a resume step that is not a
+            # multiple of the cadence would otherwise push every due step
+            # onto an unsynced step (zero records for the whole resumed run).
+            stats_phase = int(jax.device_get(
+                optim.opt_step_count(state.opt_state)))
 
-        eval_step = None
-        if val_loader is not None:
-            from bert_pytorch_tpu.parallel import batch_sharding
+        with telemetry.span("startup:step_build"):
+            kfac_obj = kfac_state = kfac_shardings = None
+            kfac_fused = False
+            if args.kfac:
+                kfac_fused = args.kfac_capture == "train"
+                if kfac_fused and args.mesh_spec.pipe > 1:
+                    # The pipeline step has no fused-capture path (factors
+                    # would need per-stage reassembly); fall back to the
+                    # decoupled stats pass.
+                    logger.info("kfac_capture=train is not supported with "
+                                "pipeline parallelism; using 'stats'")
+                    kfac_fused = False
+                # Tapped twin of the model (same params, factor-capture taps on;
+                # reference drives kfac_pytorch hooks at run_pretraining.py:320-355).
+                # The fused-capture twin keeps the main model's remat so the
+                # tapped microbatch-0 backward fits the same memory budget; the
+                # stats-pass twin runs a small decoupled batch where remat only
+                # costs recompute.
+                model_tapped = BertForPreTraining(
+                    config, dtype=model.dtype,
+                    remat=model.remat if kfac_fused else "none",
+                    attention_backend=args.attention_backend, kfac_tap=True)
+                apply_loss, tap_shape_fn = pretrain.make_kfac_fns(
+                    model_tapped, next_sentence=bool(config.next_sentence),
+                    max_pred_per_seq=eff_max_pred)
+                kfac_obj = optim.KFAC(
+                    apply_loss, tap_shape_fn,
+                    factor_decay=args.kfac_stat_decay,
+                    damping=args.kfac_damping,
+                    kl_clip=args.kfac_kl_clip,
+                    inv_method=args.kfac_inv_method,
+                    skip_layers=tuple(args.kfac_skip_layers))
+                micro_b = args.global_batch_size // args.accumulation_steps
+                sample_mb = {
+                    "input_ids": np.zeros((micro_b, seq_len), np.int32),
+                    "segment_ids": np.zeros((micro_b, seq_len), np.int32),
+                    "input_mask": np.zeros((micro_b, seq_len), np.int32),
+                    "masked_lm_labels": np.zeros((micro_b, seq_len), np.int32),
+                    "next_sentence_labels": np.zeros((micro_b,), np.int32),
+                }
+                kfac_state = kfac_obj.init(state.params, sample_mb)
+                kfac_shardings = optim.kfac_state_shardings(mesh, kfac_state)
+                if checkpoint is not None and "preconditioner" in checkpoint:
+                    kfac_state = ckpt.restore_tree(
+                        kfac_state, checkpoint["preconditioner"])
+                    kfac_state = jax.device_put(kfac_state, kfac_shardings)
+                    # Recompute qa/qg from the restored factors: the checkpoint
+                    # may hold the OTHER inv_method's operators (eigenvectors vs
+                    # damped inverses share the same state slots/shapes), and a
+                    # mid-interval resume would otherwise precondition with the
+                    # wrong operator for up to inv_interval steps with no error.
+                    kfac_state = kfac_obj.update_inverses(kfac_state)
+                    logger.info("Restored K-FAC preconditioner state "
+                                "(inverses recomputed from factors)")
+                else:
+                    kfac_state = jax.device_put(kfac_state, kfac_shardings)
+                logger.info(
+                    f"K-FAC enabled: {len(kfac_obj.specs)} layer groups, "
+                    f"capture={'train (fused)' if kfac_fused else 'stats'}, "
+                    f"damping={args.kfac_damping}, kl_clip={args.kfac_kl_clip}, "
+                    f"factor_interval={args.kfac_factor_interval}, "
+                    f"inv_interval={args.kfac_inv_interval}")
 
-            eval_step = tele.instrument(
-                pretrain.make_eval_step(
-                    model, next_sentence=bool(config.next_sentence)),
-                "eval_step")
-            # Keys follow the batch (offline-packed validation shards add
-            # sequence_ids/cls_positions); every array shards the same way.
-            eval_sharding = batch_sharding(mesh)
+            dropout.forget_draws()  # draw_shards() below speaks of this step
+            if args.mesh_spec.pipe > 1:
+                if args.accumulation_steps < mesh.shape[AXIS_PIPE]:
+                    raise ValueError(
+                        f"pp needs accumulation_steps >= pipeline stages "
+                        f"({args.accumulation_steps} < "
+                        f"{mesh.shape[AXIS_PIPE]}); "
+                        "raise global_batch_size or lower local_batch_size")
+                train_step = pretrain.make_pp_train_step(
+                    model, tx, mesh, schedule=schedule,
+                    next_sentence=bool(config.next_sentence),
+                    shardings=shardings, batch_shardings_=b_shardings,
+                    max_pred_per_seq=eff_max_pred,
+                    kfac=kfac_obj, kfac_shardings=kfac_shardings,
+                    stats_every=telemetry.stats_every(args),
+                    stats_phase=stats_phase)
+            else:
+                train_step = pretrain.make_train_step(
+                    model, tx, schedule=schedule,
+                    next_sentence=bool(getattr(config, "next_sentence", False)),
+                    shardings=shardings, batch_shardings_=b_shardings,
+                    max_pred_per_seq=eff_max_pred,
+                    kfac=kfac_obj, kfac_shardings=kfac_shardings,
+                    kfac_capture_model=model_tapped if kfac_fused else None,
+                    kfac_factor_interval=args.kfac_factor_interval,
+                    kfac_inv_interval=args.kfac_inv_interval if kfac_fused else 0,
+                    kfac_capture_microbatches=args.kfac_capture_microbatches,
+                    loss_scale=fp16,
+                    stats_every=telemetry.stats_every(args),
+                    stats_phase=stats_phase)
 
-            # Every pass evaluates the SAME deterministic slice: the sampler
-            # is reset to 0 first (the loader's prefetch over-advances it by
-            # a race-dependent amount otherwise), and the batch count is a
-            # pure function of the dataset size — so multi-host runs execute
-            # the same number of collective eval steps on every host, and
-            # logged val losses are comparable across passes and reruns.
-            eval_n_batches = min(
-                args.eval_batches,
-                len(val_loader.sampler) // args.host_batch_per_step)
+            # Telemetry (docs/telemetry.md): JSONL sink shared with the logger,
+            # step-time decomposition windows, profiler trace window, compile
+            # attribution, failure sentinels, rank-0 heartbeat. flops_per_seq is
+            # refreshed once the DATA sequence length is known (phase-1 data is
+            # 128 tokens while max_position_embeddings stays 512).
+            from bert_pytorch_tpu.utils import flops as flops_util
 
-            def run_validation(params, step_no, epoch_no):
-                """Held-out MLM(+NSP) loss (the reference never evaluates
-                during pretraining)."""
-                if eval_n_batches == 0:
-                    return
-                val_loader.sampler.index = 0
-                loss_sum = acc_sum = 0.0
-                n = 0
-                for vb in val_loader:
-                    vloss, vacc = eval_step(
-                        params, pretrain.put_batch(
-                            vb, {k: eval_sharding for k in vb}))
-                    loss_sum += float(vloss)
-                    acc_sum += float(vacc)
-                    n += 1
-                    if n >= eval_n_batches:
-                        break
-                logger.log(tag="val", step=step_no, epoch=epoch_no,
-                           average_loss=loss_sum / n,
-                           mlm_accuracy=acc_sum / n)
+            def flops_per_seq(seq):
+                if causal_lm:
+                    return flops_util.causal_lm_train_flops_per_seq(config, seq)
+                return flops_util.bert_train_flops_per_seq(
+                    config, seq, eff_max_pred,
+                    next_sentence=bool(config.next_sentence))
+
+            tele = telemetry.from_args(
+                args,
+                sink=args.telemetry_sink,
+                is_primary=is_main_process(),
+                seq_per_step=args.global_batch_size,
+                flops_per_seq=flops_per_seq(seq_len),
+                # Padding-aware accounting: the step's token budget; the train
+                # step's real_tokens metric divides out the pads
+                # (padding_efficiency in the window records).
+                tokens_per_step=args.global_batch_size * seq_len,
+                output_dir=args.output_dir,
+                process="pretrain")
+            tele.attach_loader(loader)
+            train_step = tele.instrument(train_step, "train_step")
+
+            eval_step = None
+            if val_loader is not None:
+                from bert_pytorch_tpu.parallel import batch_sharding
+
+                eval_step = tele.instrument(
+                    pretrain.make_eval_step(
+                        model, next_sentence=bool(config.next_sentence)),
+                    "eval_step")
+                # Keys follow the batch (offline-packed validation shards add
+                # sequence_ids/cls_positions); every array shards the same way.
+                eval_sharding = batch_sharding(mesh)
+
+                # Every pass evaluates the SAME deterministic slice: the sampler
+                # is reset to 0 first (the loader's prefetch over-advances it by
+                # a race-dependent amount otherwise), and the batch count is a
+                # pure function of the dataset size — so multi-host runs execute
+                # the same number of collective eval steps on every host, and
+                # logged val losses are comparable across passes and reruns.
+                eval_n_batches = min(
+                    args.eval_batches,
+                    len(val_loader.sampler) // args.host_batch_per_step)
+
+                def run_validation(params, step_no, epoch_no):
+                    """Held-out MLM(+NSP) loss (the reference never evaluates
+                    during pretraining)."""
+                    if eval_n_batches == 0:
+                        return
+                    val_loader.sampler.index = 0
+                    loss_sum = acc_sum = 0.0
+                    n = 0
+                    for vb in val_loader:
+                        vloss, vacc = eval_step(
+                            params, pretrain.put_batch(
+                                vb, {k: eval_sharding for k in vb}))
+                        loss_sum += float(vloss)
+                        acc_sum += float(vacc)
+                        n += 1
+                        if n >= eval_n_batches:
+                            break
+                    logger.log(tag="val", step=step_no, epoch=epoch_no,
+                               average_loss=loss_sum / n,
+                               mlm_accuracy=acc_sum / n)
 
         steps_this_run = args.steps or (args.max_steps - global_step)
         steps_this_run = min(steps_this_run, args.max_steps - global_step)
@@ -1097,6 +1121,8 @@ def main(args) -> dict:
                     # executable load and the first execution land inside
                     # the measured window.
                     jax.block_until_ready(metrics)
+                    # Start-up ends here (the ``startup`` record).
+                    tele.first_update_done(startup)
                     train_start = time.perf_counter()
                 if fault_plan.active:
                     # Armed NaN injection replaces the fetched scalars

@@ -20,8 +20,9 @@ import pytest
 from bert_pytorch_tpu import optim, pretrain
 from bert_pytorch_tpu.telemetry.profiler import SPANS
 
-# What the tiny run below does not do: no validation set.
-NOT_EXERCISED = ("train:eval",)
+# What the tiny run below does not do: no validation set, no checkpoint to
+# resume from.
+NOT_EXERCISED = ("train:eval", "startup:restore")
 STEPS = 3
 
 
@@ -120,6 +121,9 @@ def test_every_span_the_run_exercises_is_in_the_trace(runs, name):
         assert count == 2       # --telemetry_sync_every 2: updates 1 and 3
     elif name == "train:checkpoint":
         assert count == 1       # update 2
+    elif name.startswith("startup:"):
+        assert count == 1       # once, before the loop (the session was
+        #                         opened by hand before main)
     else:
         assert count >= 1       # per batch / per shard, ahead of the loop
 
@@ -132,6 +136,9 @@ def test_train_spans_nest_in_their_step_annotation(runs):
         assert end <= start     # one step at a time
     for name, start, end, _ in loop:
         if name == "train":
+            continue
+        if name.startswith("startup:"):     # main's thread, before the loop
+            assert end <= steps[0][1]
             continue
         assert name.startswith("train:")
         inside = [s[3]["step_num"] for s in steps
