@@ -17,8 +17,10 @@ their terms.
 
 The model returns ``(logits [B, S, V], counters)``; the counters are sums and
 maxima over its expert layers (``moe_local_slots``, ``moe_dropped_slots``,
-``moe_load_max_over_mean``, ``moe_pieces_run``) and ride out of the train
-step as step metrics.
+``moe_load_max_over_mean``, ``moe_pieces_run``) and, from shapes,
+``ssd_chunks_run``: chunks the Mamba-2 scan's Pallas kernels run in one pass,
+summed over the mixers (0 where the shapes take the scan's XLA form,
+``ops/ssm.py``); they ride out of the train step as step metrics.
 Parameters carry no logical axis names: under the meshes the trainer builds
 they are replicated (data parallelism); sharding them is the expert-axis work
 ROADMAP.md queues.
@@ -34,8 +36,8 @@ import jax
 import jax.numpy as jnp
 
 from bert_pytorch_tpu.config import NemotronHConfig
-from bert_pytorch_tpu.models.decoder import (CausalDecoder, ExpertLayer,
-                                             RMSNorm)
+from bert_pytorch_tpu.models.decoder import (MOE_COUNTERS, CausalDecoder,
+                                             ExpertLayer, RMSNorm)
 from bert_pytorch_tpu.models.decoder import dense as _dense
 from bert_pytorch_tpu.models.decoder import normal as _normal
 from bert_pytorch_tpu.ops import ssm
@@ -57,6 +59,7 @@ def relu2(x):
 
 
 class Mamba2Mixer(nn.Module):
+    """Returns (output, {``ssd_chunks_run``})."""
     config: NemotronHConfig
     dtype: Dtype = jnp.float32
 
@@ -95,13 +98,14 @@ class Mamba2Mixer(nn.Module):
             xbc = jax.nn.silu(ssm.causal_depthwise_conv(xbc, conv_w, conv_b))
             xs, b, c = jnp.split(xbc, [inner, inner + groups * state], axis=-1)
             batch, seq = x.shape[:2]
+            xs = xs.reshape(batch, seq, heads, hdim)
+            b = b.reshape(batch, seq, groups, state)
+            c = c.reshape(batch, seq, groups, state)
             y = ssm.ssd_chunked_scan(
-                xs.reshape(batch, seq, heads, hdim),
-                jax.nn.softplus(dt.astype(jnp.float32) + dt_bias),
-                -jnp.exp(a_log),
-                b.reshape(batch, seq, groups, state),
-                c.reshape(batch, seq, groups, state),
-                d_skip, cfg.chunk_size)
+                xs, jax.nn.softplus(dt.astype(jnp.float32) + dt_bias),
+                -jnp.exp(a_log), b, c, d_skip, cfg.chunk_size)
+            counters = {"ssd_chunks_run": jnp.float32(
+                ssm.ssd_kernel_chunks(xs, b, c, cfg.chunk_size))}
             norm_w = self.param("norm_scale", nn.initializers.ones, (inner,),
                                 jnp.float32)
             y = ssm.gated_group_rms_norm(
@@ -109,7 +113,7 @@ class Mamba2Mixer(nn.Module):
                 cfg.layer_norm_epsilon)
             with jax.named_scope("ssm_out_proj"):
                 return _dense(cfg.hidden_size, _out_std(cfg), self.dtype,
-                              "out_proj")(y)
+                              "out_proj")(y), counters
 
 
 class CausalAttention(nn.Module):
@@ -162,7 +166,7 @@ class NemotronHBlock(nn.Module):
         h = RMSNorm(cfg.layer_norm_epsilon, self.dtype, name="norm")(x)
         counters = None
         if self.kind == "M":
-            out = Mamba2Mixer(cfg, self.dtype, name="mixer")(h)
+            out, counters = Mamba2Mixer(cfg, self.dtype, name="mixer")(h)
         elif self.kind == "*":
             out = CausalAttention(cfg, self.dtype, self.attention_backend,
                                   name="mixer")(h)
@@ -173,6 +177,8 @@ class NemotronHBlock(nn.Module):
 
 class NemotronHForCausalLM(CausalDecoder):
     config: NemotronHConfig
+
+    COUNTERS = MOE_COUNTERS + ("ssd_chunks_run",)
 
     def blocks(self, wrap):
         block = wrap(NemotronHBlock)

@@ -12,12 +12,23 @@ The recurrence, per head h with state [P, N] (P = head dim, N = state size):
 ``ssd_chunked_scan`` computes it in chunks of ``chunk`` positions, in matmul
 form: inside a chunk the outputs are a masked, decay-weighted (C B^T) X
 product; each chunk's contribution to the state is one more product; the
-states pass from chunk to chunk in a ``lax.scan`` whose carry is the float32
-state; and the carried state reaches the chunk's outputs through a last
-product. Matmul operands are in the activations' dtype with float32
-accumulation; the decays (cumulative sums of dt A and their exponentials) and
-the carried state are float32. Plain XLA: no kernel. Autodiff gives the
-backward pass (the scan over chunks reverses).
+float32 state passes from chunk to chunk; and the carried state reaches the
+chunk's outputs through a last product. Matmul operands are in the
+activations' dtype with float32 accumulation; the decays (cumulative sums of
+dt A and their exponentials) and the carried state are float32. One
+algorithm, two realisations, chosen by the shapes the call sees
+(``ssd_kernel_chunks``), as ``ops/moe.py grouped_dot`` chooses ``gmm``:
+
+* where chunks and states are whole lane tiles and a head is half a tile or a
+  whole one (``ops/pallas/ssd_scan.py fits``: the published Mamba-2 widths),
+  a ``jax.custom_vjp`` over the Pallas kernels ``ssd_scan_fwd`` and
+  ``ssd_scan_bwd``: a chunk's decay matrix and the running state stay in
+  VMEM, x, B, C and y keep the layouts of the ops round the scan, and XLA is
+  left with the cumulative sum of dt A over each chunk (and its transpose in
+  the backward) on [B, S, H] float32;
+* every other shape (the tiny models of the CPU tests) in plain XLA
+  (``_ssd``), with autodiff's backward; it is also the tests' second opinion
+  on the kernels.
 
 Everything runs under ``jax.named_scope`` names that ``pretrain.CAUSAL_LM_SCOPES``
 lists (``ssm_conv``, ``ssd_scan``, ``ssm_gate_norm``), so a profiler trace
@@ -55,7 +66,26 @@ def ssd_chunked_scan(x, dt, a, b, c, d, chunk: int):
     input), which leaves the positions before it untouched.
     """
     with jax.named_scope("ssd_scan"):
+        if ssd_kernel_chunks(x, b, c, chunk):
+            return _ssd_kernels(x, dt, a, b, c, d, chunk)
         return _ssd(x, dt, a, b, c, d, chunk)
+
+
+def ssd_kernel_chunks(x, b, c, chunk: int) -> int:
+    """Chunks the Pallas kernels run in one pass of ``ssd_chunked_scan`` over
+    these operands (shapes and dtypes are all it looks at): rows x chunks a
+    row, or 0 where the call takes the XLA form."""
+    from bert_pytorch_tpu.ops.pallas.ssd_scan import fits
+
+    if not (x.dtype == b.dtype == c.dtype and fits(x.shape, b.shape, chunk)):
+        return 0
+    return x.shape[0] * scan_chunks(x.shape[1], chunk)
+
+
+def _pad_positions(t, pad: int):
+    """[B, S, ...] with ``pad`` zero positions after the last."""
+    return jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2)
+                   ) if pad else t
 
 
 def _ssd(x, dt, a, b, c, d, chunk):
@@ -64,9 +94,7 @@ def _ssd(x, dt, a, b, c, d, chunk):
     per = heads // groups
     dtype = x.dtype
     pad = (-seq) % chunk
-    if pad:
-        widths = lambda t: ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2)
-        x, dt, b, c = (jnp.pad(t, widths(t)) for t in (x, dt, b, c))
+    x, dt, b, c = (_pad_positions(t, pad) for t in (x, dt, b, c))
     n = (seq + pad) // chunk
     # Heads before positions, so that the two minor axes of every large
     # tensor are (position, position), (position, width) or (width, state).
@@ -115,6 +143,73 @@ def _ssd(x, dt, a, b, c, d, chunk):
         groups, per, 1, 1).astype(jnp.float32)
     y = y.transpose(0, 1, 4, 2, 3, 5).reshape(batch, n * chunk, heads, hdim)
     return y[:, :seq].astype(dtype)
+
+
+def _chunk_sums(t, chunk, reverse=False):
+    """[B, S, H] -> the running sum inside each chunk of ``chunk`` positions
+    (``reverse``: from the chunk's end, the transpose)."""
+    batch, seq, heads = t.shape
+    return jax.lax.cumsum(t.reshape(batch, seq // chunk, chunk, heads),
+                          axis=2, reverse=reverse).reshape(t.shape)
+
+
+def _ssd_operands(x, dt, a, b, c, d, chunk, *more):
+    """The kernels' operands: positions padded up to whole chunks (and
+    ``more``, which is dy, with them), x, B and C flat as the layers round
+    the scan hold them, dt and the log decays' running sum float32 with the
+    heads on 128 lanes, the sum again with the heads on rows, D on lanes."""
+    from bert_pytorch_tpu.ops.pallas.ssd_scan import LANES, SUBLANES
+
+    batch, seq, heads, hdim = x.shape
+    pad = (-seq) % chunk
+    flat = lambda t: _pad_positions(t, pad).reshape(batch, seq + pad, -1)
+    on_lanes = lambda t: jnp.pad(t, ((0, 0),) * (t.ndim - 1)
+                                 + ((0, LANES - heads),))
+    dt = _pad_positions(dt.astype(jnp.float32), pad)
+    cum = _chunk_sums(dt * a.astype(jnp.float32), chunk)
+    cum_rows = jnp.pad(cum.swapaxes(1, 2),
+                       ((0, 0), (0, (-heads) % SUBLANES), (0, 0)))
+    d_lanes = jnp.broadcast_to(on_lanes(d.astype(jnp.float32)),
+                               (SUBLANES, LANES))
+    return (flat(x), on_lanes(dt), on_lanes(cum), cum_rows, flat(b), flat(c),
+            d_lanes) + tuple(flat(t) for t in more)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _ssd_kernels(x, dt, a, b, c, d, chunk):
+    return _ssd_kernels_fwd(x, dt, a, b, c, d, chunk)[0]
+
+
+def _ssd_kernels_fwd(x, dt, a, b, c, d, chunk):
+    from bert_pytorch_tpu.ops.pallas.ssd_scan import ssd_forward
+
+    y, starts = ssd_forward(*_ssd_operands(x, dt, a, b, c, d, chunk),
+                            x.shape[2], b.shape[2], chunk)
+    # the operands are kept as they came; the backward pads them again
+    return (y[:, :x.shape[1]].reshape(x.shape), (x, dt, a, b, c, d, starts))
+
+
+def _ssd_kernels_bwd(chunk, residuals, dy):
+    from bert_pytorch_tpu.ops.pallas.ssd_scan import ssd_backward
+
+    x, dt, a, b, c, d, starts = residuals
+    (batch, seq, heads, hdim), f32 = x.shape, jnp.float32
+    operands = _ssd_operands(x, dt, a, b, c, d, chunk, dy.astype(x.dtype))
+    dx, ddt, dcum, dcum_rows, db, dc, dd = ssd_backward(
+        *operands[:7], starts, operands[7], heads, b.shape[2], chunk)
+    # cum is the running sum of dt a: its cotangent runs back through the sum
+    dlog = _chunk_sums(dcum[..., :heads] + dcum_rows[:, :heads].swapaxes(1, 2),
+                       chunk, reverse=True)[:, :seq]
+    ddt = ddt[:, :seq, :heads] + dlog * a.astype(f32)
+    da = jnp.sum(dlog * dt.astype(f32), axis=(0, 1))
+    return (dx[:, :seq].reshape(x.shape), ddt.astype(dt.dtype),
+            da.astype(a.dtype), db[:, :seq].reshape(b.shape),
+            dc[:, :seq].reshape(c.shape),
+            jnp.sum(dd.reshape(batch, heads, hdim), axis=(0, 2)).astype(
+                d.dtype))
+
+
+_ssd_kernels.defvjp(_ssd_kernels_fwd, _ssd_kernels_bwd)
 
 
 def gated_group_rms_norm(y, z, weight, groups: int, eps: float):
@@ -168,9 +263,7 @@ def _wide(t):
 def _padded(chunk, *tensors):
     """Float32, and the positions padded up to whole chunks."""
     pad = (-tensors[0].shape[1]) % chunk
-    tensors = [t.astype(jnp.float32) for t in tensors]
-    return [jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
-            for t in tensors] if pad else tensors
+    return [_pad_positions(t.astype(jnp.float32), pad) for t in tensors]
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(5,))

@@ -1171,7 +1171,8 @@ def main(args) -> dict:
                             # tiles, scan chunks, readers of the carried
                             # tensors (causal_lm; pretrain._aux_metrics)
                             **{k: v for k, v in last_metrics.items()
-                               if k.startswith(("moe_", "attn_", "scan_"))
+                               if k.startswith(("moe_", "attn_", "scan_",
+                                                "ssd_"))
                                or k.endswith("_readers")})
 
                 if (eval_step is not None

@@ -205,6 +205,28 @@ def test_selective_scan_compiles_at_8192(chip):
     _assert_kernel(compiled, "selective_scan_fwd", "selective_scan_bwd")
 
 
+def test_ssd_scan_compiles_at_8192(chip):
+    """The Mamba-2 scan's two kernels at the hybrid decoder cell's geometry:
+    one row of 8192 positions, 64 heads of 64 in 8 groups, 128 states, chunks
+    of 128: the dynamic lane offsets of a tile and a group, the lane gather
+    that spreads a head's number over its lanes, the float32 state scratch and
+    a chunk's blocks have to pass Mosaic and fit VMEM under the kernels' own
+    limit."""
+    from bert_pytorch_tpu.ops import ssm
+
+    def loss(x, dt, a, b, c, d):
+        return jnp.sum(jnp.square(ssm.ssd_chunked_scan(
+            x, dt, a, b, c, d, 128).astype(jnp.float32)))
+
+    heads = ((64,), jnp.float32)
+    compiled = _compile(
+        jax.grad(loss, argnums=tuple(range(6))), chip,
+        ((1, 8192, 64, 64), jnp.bfloat16), ((1, 8192, 64), jnp.float32), heads,
+        ((1, 8192, 8, 128), jnp.bfloat16), ((1, 8192, 8, 128), jnp.bfloat16),
+        heads)
+    _assert_kernel(compiled, "ssd_scan_fwd", "ssd_scan_bwd")
+
+
 @pytest.mark.parametrize("heads,rotary_dim", [(24, 64), (36, 128)])
 def test_rotary_turn_compiles_at_8192(chip, heads, rotary_dim):
     """The turn's kernel at the laguna cell's geometry (queries and the four

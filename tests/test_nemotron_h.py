@@ -106,9 +106,10 @@ def test_gated_norm_and_conv_match_the_reference():
     tree = nemotron_h_map.to_program(p, c)
     from bert_pytorch_tpu.models.nemotron_h import Mamba2Mixer
 
-    mine = Mamba2Mixer(cfg, jnp.float32).apply(
+    mine, counters = Mamba2Mixer(cfg, jnp.float32).apply(
         {"params": tree["layers_0"]["mixer"]}, x)
     close(mine, ref.mamba_mixer(p, "l0.", c, x, "f32"))
+    assert float(counters["ssd_chunks_run"]) == 0  # heads of 16: the XLA form
     assert model.objective == "causal_lm"
 
 
