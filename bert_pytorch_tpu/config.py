@@ -464,8 +464,126 @@ class PhiFlashConfig:
         return 16
 
 
+class ZayaConfig:
+    """Configuration of the ``zaya`` family: a residual decoder whose every
+    layer (``layer_types``: all ``hybrid``) is compressed convolutional
+    attention, then a routed expert layer with NO shared expert, each joined
+    to the stream by a learned merge (``scale_residual_merge``). The
+    attention lives in a latent: ``num_attention_heads`` query heads on
+    ``num_key_value_heads`` key-value heads of ``head_dim``, together
+    narrower than ``hidden_size``, behind two causal convolutions over
+    positions of ``cca_time0`` and ``cca_time1`` taps (``models/zaya.py``).
+    The router is an MLP ``router_hidden_size`` wide whose state each layer
+    hands to the next (``zaya_use_eda``); it has one output for every expert
+    of the layer and, under ``zaya_use_mod``, one more that skips the layer;
+    one expert a token. Keys and defaults are the published ``config.json``'s
+    (Zyphra/ZAYA1-8B) and, for the four switches that file leaves implicit,
+    its siblings' (ZAYA1-base); extra keys ride along as on
+    :class:`BertConfig`.
+
+    The chip's share is stated here, as :class:`LagunaConfig` states it:
+    ``num_experts`` experts are HELD of ``num_experts * ep_size``, ``ep_rank``
+    says which; the router keeps its published width.
+    """
+
+    model_type = "zaya"
+
+    def __init__(self, **values: Any):
+        defaults = dict(
+            vocab_size=262272, hidden_size=2048, num_hidden_layers=40,
+            num_attention_heads=8, num_key_value_heads=2, head_dim=128,
+            cca=True, cca_time0=2, cca_time1=2, layer_types=None,
+            sliding_window=None, partial_rotary_factor=0.5,
+            rope_parameters={"hybrid": {
+                "partial_rotary_factor": 0.5, "rope_theta": 5000000,
+                "rope_type": "default"}},
+            num_experts=16, ep_size=1, ep_rank=0, num_experts_per_tok=1,
+            moe_intermediate_size=2048, router_hidden_size=256,
+            zaya_use_eda=True, zaya_use_mod=True, scale_residual_merge=True,
+            hidden_act="silu", attention_bias=False, lm_head_bias=False,
+            rms_norm_eps=1e-5, initializer_range=0.02,
+            tie_word_embeddings=True, max_position_embeddings=131072)
+        for key, value in {**defaults, **values}.items():
+            setattr(self, key, value)
+        layers = self.num_hidden_layers
+        if self.layer_types is None:
+            self.layer_types = ["hybrid"] * layers
+        if len(self.layer_types) != layers or set(self.layer_types) - {"hybrid"}:
+            raise ValueError(
+                f"layer_types must be {layers} of ['hybrid'] (a layer under a "
+                f"sliding window is not built): {self.layer_types}")
+        if self.sliding_window is not None:
+            raise ValueError(
+                "zaya is built without a sliding_window: every layer attends "
+                f"to the whole prefix (got {self.sliding_window})")
+        if self.num_experts_per_tok != 1:
+            raise ValueError(
+                "zaya routes one expert a token, weighted by its own "
+                "probability and never renormalised (num_experts_per_tok "
+                f"{self.num_experts_per_tok})")
+        if not self.tie_word_embeddings:
+            raise ValueError(
+                "zaya is built with its head tied to the embedding")
+        if (not self.cca or not self.zaya_use_eda or not self.zaya_use_mod
+                or not self.scale_residual_merge or self.attention_bias
+                or self.lm_head_bias or self.hidden_act != "silu"):
+            raise ValueError(
+                "zaya is built with compressed convolutional attention, the "
+                "router's state handed from layer to layer, the skip among "
+                "the router's outputs, learned residual merges, silu experts "
+                "and no bias on attention or head")
+        heads, kv = self.num_attention_heads, self.num_key_value_heads
+        if heads % kv or kv % 2 or self.head_dim % 4:
+            raise ValueError(
+                f"{heads} query heads on {kv} key-value heads of "
+                f"{self.head_dim}: whole groups, and an even number of "
+                "key-value heads (half of them read the previous token)")
+        if self.cca_time0 < 1 or self.cca_time1 < 1:
+            raise ValueError(
+                f"cca_time0 {self.cca_time0}, cca_time1 {self.cca_time1}: "
+                "a convolution has at least one tap")
+        if not 0 <= self.ep_rank < self.ep_size:
+            raise ValueError(f"ep_rank {self.ep_rank} of ep_size {self.ep_size}")
+
+    @classmethod
+    def from_dict(cls, json_object: dict) -> "ZayaConfig":
+        values = {k: v for k, v in json_object.items() if k != "model_type"}
+        return cls(**values)
+
+    def to_dict(self) -> dict:
+        return dict(copy.deepcopy(self.__dict__), model_type=self.model_type)
+
+    @property
+    def router_experts(self) -> int:
+        """Every expert of the layer, held or not."""
+        return self.num_experts * self.ep_size
+
+    @property
+    def router_outputs(self) -> int:
+        """The router's width: every expert and the skip after them."""
+        return self.router_experts + 1
+
+    @property
+    def first_expert(self) -> int:
+        return self.ep_rank * self.num_experts
+
+    @property
+    def rope(self) -> tuple:
+        """(rotary dimensions of a head, the ``rope_parameters`` entry)."""
+        rope = self.rope_parameters["hybrid"]
+        return (int(self.head_dim * rope.get(
+            "partial_rotary_factor", self.partial_rotary_factor)), rope)
+
+    @property
+    def init_sample_length(self) -> int:
+        """Positions of the sample that initializes the parameters (none
+        depends on the length)."""
+        return 16
+
+
 MODEL_FAMILIES = {"bert": BertConfig, "nemotron_h": NemotronHConfig,
-                  "laguna": LagunaConfig, "phi4flash": PhiFlashConfig}
+                  "laguna": LagunaConfig, "phi4flash": PhiFlashConfig,
+                  "zaya": ZayaConfig}
 
 
 def load_model_config(json_file: str):

@@ -52,6 +52,7 @@ from bert_pytorch_tpu.models.losses import (
 from bert_pytorch_tpu.models.laguna import LagunaForCausalLM
 from bert_pytorch_tpu.models.nemotron_h import NemotronHForCausalLM
 from bert_pytorch_tpu.models.phi4flash import PhiFlashForCausalLM
+from bert_pytorch_tpu.models.zaya import ZayaForCausalLM
 
 
 def build_pretraining_model(config, dtype, remat: str = "none",
@@ -61,11 +62,13 @@ def build_pretraining_model(config, dtype, remat: str = "none",
     ``model_type``). The model's ``objective`` attribute names what
     ``pretrain.make_train_step`` trains it on."""
     from bert_pytorch_tpu.config import (BertConfig, LagunaConfig,
-                                         NemotronHConfig, PhiFlashConfig)
+                                         NemotronHConfig, PhiFlashConfig,
+                                         ZayaConfig)
 
     for family, model in ((NemotronHConfig, NemotronHForCausalLM),
                           (LagunaConfig, LagunaForCausalLM),
                           (PhiFlashConfig, PhiFlashForCausalLM),
+                          (ZayaConfig, ZayaForCausalLM),
                           (BertConfig, BertForPreTraining)):
         if isinstance(config, family):
             return model(config, dtype=dtype, remat=remat,
@@ -77,6 +80,7 @@ __all__ = [
     "LagunaForCausalLM",
     "NemotronHForCausalLM",
     "PhiFlashForCausalLM",
+    "ZayaForCausalLM",
     "build_pretraining_model",
     "next_token_loss",
     "BertEmbeddings",

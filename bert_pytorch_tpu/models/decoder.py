@@ -1,6 +1,7 @@
 """What the decoder families share (``models/nemotron_h.py``,
-``models/laguna.py``, ``models/phi4flash.py``): RMSNorm, LayerNorm, the
-bias-free projection, the routed expert layer with its shared expert, and the
+``models/laguna.py``, ``models/phi4flash.py``, ``models/zaya.py``): RMSNorm,
+LayerNorm, the bias-free projection, the routed expert layer with or without
+a shared expert and with a router of its own or one handed in, and the
 wrapper round a stack of unlike layers: embedding, ``layers_0 ..
 layers_{L-1}``, final norm, output head (untied, or the embedding's
 transpose), next-token objective.
@@ -13,9 +14,11 @@ largest) and they ride out of the train step as step metrics.
 
 **The carried path.** A family whose later layers read what an earlier layer
 computed (``CARRIES``: ``models/phi4flash.py``: one layer's scan output, one
-layer's keys and values) has layers that take and return ``(x, carried)``:
-``carried`` is a dict of arrays that starts empty, a writing layer returns it
-with its entry added, and every later layer hands it on. Each block is still
+layer's keys and values; ``models/zaya.py``: the router's state, which every
+layer reads from the one before and writes for the next) has layers that
+take and return ``(x, carried)``: ``carried`` is a dict of arrays that starts
+empty, a writing layer returns it with its entry added (or replaced), and
+every later layer hands it on. Each block is still
 rematerialized on its own, so a carried tensor is kept once, as the output of
 the block that wrote it, and its cotangent is the sum of what every reader
 and the hand-on return, which autodiff forms. The families that carry nothing
@@ -86,6 +89,12 @@ class ExpertLayer(nn.Module):
     experts' form (``ops/moe.py``): ``sigmoid`` with its correction bias and
     plain ``activation`` experts for ``nemotron_h``, ``softmax`` and gated
     experts for ``laguna``; the shared expert has the routed experts' form.
+    ``shared_width`` 0 builds no shared expert (``zaya``): the output is the
+    held experts' terms alone. A family whose router is not one matrix
+    computes its routing itself and hands it in (``routing``: ids and weights
+    [T, k] from ``moe.choose``); the layer then holds no router parameter,
+    and ``router_experts`` is that router's width, a skip it may have among
+    its outputs included (no chip holds it: its slots add nothing).
     Returns (output, the layer's ``moe_*`` counters)."""
     width: int
     shared_width: int
@@ -105,17 +114,19 @@ class ExpertLayer(nn.Module):
     dtype: Dtype = jnp.float32
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, routing=None):
         hidden, fan = x.shape[-1], 2 if self.gated else 1
-        router_w = self.param("router_kernel", normal(self.std),
-                              (hidden, self.router_experts), jnp.float32)
-        correction = None
-        if self.score == "sigmoid":
-            # The published rule moves this bias outside the gradient, towards
-            # balance; here it is a buffer at zero (route() stops its gradient).
-            correction = self.param(
-                "router_correction_bias", nn.initializers.zeros,
-                (self.router_experts,), jnp.float32)
+        if routing is None:
+            router_w = self.param("router_kernel", normal(self.std),
+                                  (hidden, self.router_experts), jnp.float32)
+            correction = None
+            if self.score == "sigmoid":
+                # The published rule moves this bias outside the gradient,
+                # towards balance; here it is a buffer at zero (route() stops
+                # its gradient).
+                correction = self.param(
+                    "router_correction_bias", nn.initializers.zeros,
+                    (self.router_experts,), jnp.float32)
         w_up = self.param("experts_up", normal(self.std),
                           (self.held, hidden, fan * self.width), jnp.float32)
         w_down = self.param("experts_down", normal(self.out_std),
@@ -123,7 +134,7 @@ class ExpertLayer(nn.Module):
         batch, seq = x.shape[:2]
         flat = x.reshape(batch * seq, hidden)
         with jax.named_scope("moe"):
-            chosen, weights = moe.route(
+            chosen, weights = routing or moe.route(
                 flat, router_w, correction, self.top_k, self.route_scale,
                 self.norm_topk, self.score)
             # for a caller that asks (``mutable=["intermediates"]``): which
@@ -133,6 +144,10 @@ class ExpertLayer(nn.Module):
                 flat, chosen, weights, w_up, w_down, self.first_expert,
                 self.router_experts, self.activation,
                 multiple=self.piece_multiple, gated=self.gated)
+            counters = {"moe_" + name: value
+                        for name, value in counters.items()}
+            if not self.shared_width:
+                return routed.reshape(x.shape), counters
             with jax.named_scope("moe_shared"):
                 mid = dense(fan * self.shared_width, self.std, self.dtype,
                             "shared_up")(x)
@@ -143,8 +158,7 @@ class ExpertLayer(nn.Module):
                     mid = self.activation(mid)
                 shared = dense(hidden, self.out_std, self.dtype,
                                "shared_down")(mid)
-            return shared + routed.reshape(x.shape), {
-                "moe_" + name: value for name, value in counters.items()}
+            return shared + routed.reshape(x.shape), counters
 
 
 class CausalDecoder(nn.Module):

@@ -7,13 +7,27 @@ what the absent experts would add lies on other chips (expert parallelism;
 the exchange between chips is not in this file, and on one chip the layer runs
 without it). Nothing stands in for the absent chips.
 
-Two routing rules (``route``'s ``score``), both in float32. ``sigmoid``
-(DeepSeek-V3's, as ``nemotron_h`` uses it): ``s = sigmoid(logits)``, the
-``top_k`` largest of ``s + correction_bias`` choose. ``softmax`` (as
-``laguna`` uses it): ``s = softmax(logits)`` over all the experts, the
-``top_k`` largest choose, and there is no correction bias. Under either the
-weights are ``s`` of the chosen, divided by their sum when ``norm_topk`` is
-set, times ``scale``.
+Three routing rules, all in float32, all through ``choose`` (``route`` is
+``choose`` of ``x router_w``). ``sigmoid`` (DeepSeek-V3's, as ``nemotron_h``
+uses it): ``s = sigmoid(logits)``, the ``top_k`` largest of ``s +
+correction_bias`` choose. ``softmax`` over a matrix's logits (as ``laguna``
+uses it): ``s = softmax(logits)`` over all the experts, the ``top_k`` largest
+choose, no correction bias. ``softmax`` over logits that were computed
+elsewhere (``zaya``: an MLP whose state is handed from layer to layer,
+``models/zaya.py``), with a correction bias, ``top_k`` 1 and ``norm_topk``
+off: the weight is the chosen probability itself, the only way a gradient
+reaches that router. Under each the weights are ``s`` of the chosen, divided
+by their sum when ``norm_topk`` is set, times ``scale``.
+
+**The skip.** A router may have more outputs than the layer has experts
+(``zaya``: one more, chosen by the tokens that pass the layer by).
+``held_experts``'s ``n_experts`` is the router's width, the expected load is
+counted over it, and a slot whose choice no chip holds sorts with the absent
+experts' and adds nothing anywhere.
+
+Experts come with or without a shared expert beside them
+(``models/decoder.py ExpertLayer``: ``shared_width`` 0 builds none); this file
+holds the routed ones alone.
 
 Two forms of expert (``held_experts``'s ``gated``). Plain: ``w_down
 activation(w_up x)``, two products. Gated: ``w_up`` is ``[E, H, 2F]``, the
@@ -45,7 +59,8 @@ cotangents of x and of both weight tensors, 584 MB a skipped piece over the
 three passes (PERF.md 5).
 
 Scopes (``pretrain.CAUSAL_LM_SCOPES``): ``moe_route``, ``moe_dispatch``,
-``moe_experts``, ``moe_combine``.
+``moe_experts``, ``moe_combine`` (a router of its own opens ``moe_route``
+itself: ``pretrain.ZAYA_SCOPES``).
 """
 
 from __future__ import annotations
@@ -76,23 +91,33 @@ def route(x, router_w, correction_bias, top_k: int, scale: float,
           norm_topk: bool = True, score: str = "sigmoid"):
     """x [T, H] -> (expert ids [T, k] int32, weights [T, k] float32).
     ``score``: ``sigmoid`` (with its ``correction_bias`` [experts]) or
-    ``softmax`` (``correction_bias`` None)."""
+    ``softmax`` (``correction_bias`` None): :func:`choose` of the matrix's
+    logits."""
     with jax.named_scope("moe_route"):
         logits = jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
                             precision="highest")
-        if score == "softmax":
-            scores = jax.nn.softmax(logits, axis=-1)
-            _, chosen = jax.lax.top_k(scores, top_k)
-        elif score == "sigmoid":
-            scores = jax.nn.sigmoid(logits)
-            _, chosen = jax.lax.top_k(
-                scores + jax.lax.stop_gradient(correction_bias), top_k)
-        else:
-            raise ValueError(f"score must be sigmoid|softmax, got {score!r}")
-        weights = jnp.take_along_axis(scores, chosen, axis=-1)
-        if norm_topk:
-            weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
-        return chosen.astype(jnp.int32), weights * scale
+        return choose(logits, correction_bias, top_k, scale, norm_topk, score)
+
+
+def choose(logits, correction_bias, top_k: int, scale: float,
+           norm_topk: bool = True, score: str = "sigmoid"):
+    """Router logits [T, outputs] float32, wherever they were computed ->
+    (ids [T, k] int32, weights [T, k] float32). ``correction_bias``
+    [outputs] or None is added to the scores for the choice alone, outside
+    the gradient. Under the caller's scope."""
+    if score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"score must be sigmoid|softmax, got {score!r}")
+    _, chosen = jax.lax.top_k(
+        scores if correction_bias is None
+        else scores + jax.lax.stop_gradient(correction_bias), top_k)
+    weights = jnp.take_along_axis(scores, chosen, axis=-1)
+    if norm_topk:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return chosen.astype(jnp.int32), weights * scale
 
 
 def chunk_rows(tokens: int, top_k: int, n_experts: int, held: int,

@@ -64,6 +64,13 @@ PHI_FLASH_SCOPES = (
     "s6_mixer", "s6_in_proj", "s6_conv", "s6_dt", "selective_scan", "s6_gate",
     "s6_out_proj", "gmu", "attn_qkv", "attn_diff", "attn_out", "dense_mlp",
     "lm_head", "lm_loss")
+# ... and those of the zaya family's step (models/zaya.py: attention inside a
+# latent, a router of its own under ``moe_route``, the learned merges).
+ZAYA_SCOPES = (
+    "cca", "attn_qkv", "cca_conv", "cca_qk_mean", "cca_value_shift",
+    "cca_norm", "attn_rope", "attn_out", "moe", "moe_route", "router_down",
+    "router_eda", "router_mlp", "moe_dispatch", "moe_experts", "moe_combine",
+    "residual_merge", "lm_head", "lm_loss")
 
 
 # Rows longer than this many positions take the output head and its loss in

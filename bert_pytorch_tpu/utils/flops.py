@@ -210,12 +210,39 @@ def phi_flash_forward_flops_per_token(config, seq_len: int) -> dict:
     return dict(parts, head=2.0 * h * config.vocab_size)
 
 
+def zaya_forward_flops_per_token(config, seq_len: int) -> dict:
+    """Forward matmul FLOPs per token of a ``zaya`` model on THIS chip (the
+    experts and vocabulary rows it holds), by part: ``cca_proj`` (q, k, v
+    into the latent, the output projection back, and the second
+    convolution's per-head products), ``cca_core`` (the two S x S products
+    over the causal half), ``router`` (down projection, two hidden layers,
+    the output), ``experts`` (three products by the EXPECTED held / router
+    outputs of the tokens: one expert a token, the skip among the outputs),
+    ``head`` (tied: the embedding's rows held). Lookup, norms, the depthwise
+    convolution, rotary, merges and the optimizer are left out."""
+    h, hd = config.hidden_size, config.head_dim
+    heads, kv = config.num_attention_heads, config.num_key_value_heads
+    rw = config.router_hidden_size
+    layer = {
+        "cca_proj": (4 * h * heads * hd + 4 * h * kv * hd
+                     + 2 * config.cca_time1 * (heads + kv) * hd * hd),
+        "cca_core": 4 * heads * hd * (seq_len + 1) / 2,
+        "router": 2 * h * rw + 4 * rw * rw + 2 * rw * config.router_outputs,
+        "experts": (config.num_experts / config.router_outputs
+                    * 6 * h * config.moe_intermediate_size),
+    }
+    return dict({k: 1.0 * config.num_hidden_layers * v
+                 for k, v in layer.items()},
+                head=2.0 * h * config.vocab_size)
+
+
 def causal_lm_train_flops_per_seq(config, seq_len: int) -> float:
     """Training (3x forward) matmul FLOPs of one row of ``seq_len`` tokens of
     a ``causal_lm`` family's model (by the config's ``model_type``)."""
     per_token = {"nemotron_h": nemotron_h_forward_flops_per_token,
                  "laguna": laguna_forward_flops_per_token,
                  "phi4flash": phi_flash_forward_flops_per_token,
+                 "zaya": zaya_forward_flops_per_token,
                  }[config.model_type]
     return 3.0 * seq_len * sum(per_token(config, seq_len).values())
 

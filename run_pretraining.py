@@ -470,7 +470,8 @@ def prepare_model(args, mesh):
     # file's ``model_type``: none is BERT, ``nemotron_h`` the hybrid decoder,
     # ``laguna`` the decoder of mixed window and full attention, ``phi4flash``
     # the decoder of selective scans, differential attention and one kept
-    # memory and K/V.
+    # memory and K/V, ``zaya`` the decoder of attention inside a latent and a
+    # router that hands its state from layer to layer.
     config = load_model_config(args.model_config_file)
     if config.vocab_size % 8 != 0:  # MXU-friendly padding (reference :237)
         config.vocab_size += 8 - (config.vocab_size % 8)
@@ -1170,10 +1171,9 @@ def main(args) -> dict:
                             # the decoder's counters: routing, score
                             # tiles, scan chunks, readers of the carried
                             # tensors (causal_lm; pretrain._aux_metrics)
-                            **{k: v for k, v in last_metrics.items()
-                               if k.startswith(("moe_", "attn_", "scan_",
-                                                "ssd_"))
-                               or k.endswith("_readers")})
+                            **{k: last_metrics[k]
+                               for k in getattr(model, "COUNTERS", ())
+                               if k in last_metrics})
 
                 if (eval_step is not None
                         and global_step % args.num_steps_per_eval == 0):

@@ -250,6 +250,37 @@ def test_rotary_turn_compiles_at_8192(chip, heads, rotary_dim):
                           compiled.as_text())) == 4
 
 
+def test_latent_attention_compiles_at_8192(chip):
+    """The zaya cell's attention layer whole at its geometry (a micro-batch
+    of 2 rows of 8192 tokens, 8 / 2 heads of 128 in a latent of 1024 + 256
+    beside a stream of 2048), forward and backward: the causal flash kernels
+    under the caller's label ``cca`` on 4 query heads a key-value head, the
+    rotary turn on 8 and on 2 heads under half-width tables, and XLA's part
+    between them (both convolutions, the q-k mean, the norm, the shift)."""
+    from bert_pytorch_tpu.config import ZayaConfig
+    from bert_pytorch_tpu.models import zaya
+
+    layer = zaya.CompressedConvAttention(ZayaConfig(), jnp.bfloat16, "pallas")
+    params = jax.eval_shape(lambda: layer.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 16, 2048), jnp.bfloat16)))
+    sharding = SingleDeviceSharding(chip)
+    place = lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                              sharding=sharding)
+
+    def loss(variables, x):
+        return jnp.sum(jnp.square(layer.apply(variables, x).astype(jnp.float32)))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        jax.tree_util.tree_map(place, params),
+        place(jax.ShapeDtypeStruct((2, 8192, 2048), jnp.bfloat16))).compile()
+    _assert_kernel(compiled, "flash_cca_fwd", "flash_cca_bwd_dq",
+                   "flash_cca_bwd_dkv", "rotary_turn")
+    names = set(re.findall(r'op_name="([^"]+)"', compiled.as_text()))
+    for scope in ("attn_qkv", "cca_conv", "cca_qk_mean", "cca_value_shift",
+                  "cca_norm", "attn_rope", "attention_core", "attn_out"):
+        assert any(f"/cca/{scope}/" in name for name in names), scope
+
+
 # -- the serving kernels ----------------------------------------------------
 
 @pytest.mark.parametrize("seq", [32, 128, 512])
