@@ -10,7 +10,8 @@ Layers of unlike kinds hold unlike parameters, so they cannot be stacked and
 scanned the way ``models/bert.py`` scans its encoder; each is rematerialized
 on its own (``ops/remat.py``'s policy). A layer returns ``(x, counters)``;
 the wrapper adds the layers' counters up (``*_max_over_mean``: their
-largest) and they ride out of the train step as step metrics.
+largest; ``*_fill``: their mean) and they ride out of the train step as step
+metrics.
 
 **The carried path.** A family whose later layers read what an earlier layer
 computed (``CARRIES``: ``models/phi4flash.py``: one layer's scan output, one
@@ -40,7 +41,7 @@ from bert_pytorch_tpu.ops.remat import remat_policy
 Dtype = Any
 
 MOE_COUNTERS = ("moe_local_slots", "moe_dropped_slots",
-                "moe_load_max_over_mean", "moe_pieces_run")
+                "moe_load_max_over_mean", "moe_pieces_run", "moe_tile_fill")
 
 
 def normal(std: float):
@@ -234,7 +235,9 @@ class CausalDecoder(nn.Module):
         return self.final_norm(x), {
             name: (zero if not values else
                    jnp.max(jnp.stack(values))
-                   if name.endswith("_max_over_mean") else sum(values, zero))
+                   if name.endswith("_max_over_mean") else
+                   jnp.mean(jnp.stack(values))
+                   if name.endswith("_fill") else sum(values, zero))
             for name, values in seen.items()}
 
     def __call__(self, input_ids):

@@ -15,9 +15,10 @@ holds the chip's share of the experts (``NemotronHConfig``:
 ``n_routed_experts`` held of ``n_routed_experts * ep_size``) and adds only
 their terms.
 
-The model returns ``(logits [B, S, V], counters)``; the counters are sums and
-maxima over its expert layers (``moe_local_slots``, ``moe_dropped_slots``,
-``moe_load_max_over_mean``, ``moe_pieces_run``) and, from shapes,
+The model returns ``(logits [B, S, V], counters)``; the counters are sums,
+maxima and a mean over its expert layers (``moe_local_slots``,
+``moe_dropped_slots``, ``moe_load_max_over_mean``, ``moe_pieces_run``,
+``moe_tile_fill``) and, from shapes,
 ``ssd_chunks_run``: chunks the Mamba-2 scan's Pallas kernels run in one pass,
 summed over the mixers (0 where the shapes take the scan's XLA form,
 ``ops/ssm.py``); they ride out of the train step as step metrics.

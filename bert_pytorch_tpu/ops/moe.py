@@ -40,10 +40,20 @@ that the slots of held experts come first, expert by expert; a token picks
 distinct experts, so at most all of them are local. The grouped products run
 over those sorted rows with the experts' true group sizes and visit only the
 row tiles the groups fill, so the matmul work follows the slots really routed
-here: on a TPU the grouped matmul JAX ships (``megablox`` ``gmm``, with its
-own backward products) at 512-row tiles, which at this layer's shapes runs
-the two products forward and backward in a third of the time of
-``jax.lax.ragged_dot`` (3.5 against 11.1 ms at 3200 live rows, PERF.md 6);
+here: on a TPU megablox's grouped kernels (``gmm`` for the product and for the
+rows' cotangent, ``tgmm`` for the weights'), under this file's own
+``jax.custom_vjp`` (``_tiled_grouped_dot``) so that EACH of the three gets
+tiles chosen for its own (m, k, n) and for the rows a group is expected to
+hold (``gmm_tiles``). Megablox rounds k and n up to whole tiles and computes
+whole every row tile a group touches, and its own ``custom_vjp`` hands one
+triple to all three products: under PR 27's (512, 896, 640), fitted to the
+hybrid decoder's widths, the kernels multiplied 2.3 to 4 times the live work.
+By the sweep of the three cells' six products alone on a v5e (PERF.md 6, PR
+40; ms a call, that triple -> the rule's tiles): 2048 x 4096 at 964 rows a
+group 1.79 -> 1.01 forward, 1.66 -> 1.01 rows' cotangent, 1.91 -> 1.18
+weights'; 3072 x 2048 at 320 rows 0.73 -> 0.43, 0.71 -> 0.41, 0.85 -> 0.48;
+1856 x 2688 at 384 rows 0.81 -> 0.42, 0.51 -> 0.41, 0.90 -> 0.48 (and a third
+of ``jax.lax.ragged_dot``'s time before that: 3.5 against 11.1 ms, PR 27);
 ``ragged_dot`` elsewhere (the CPU tests). The sorted rows are worked through
 in PIECES of a static size (twice the expected local load), and only the
 pieces that hold a local slot are run: a loop of ``ceil(local slots / rows)``
@@ -73,18 +83,121 @@ import jax.numpy as jnp
 from bert_pytorch_tpu.ops.pallas.common import interpret_mode
 
 GMM_TILE_ROWS = 512
+LANES = 128
+# What one grid step of a grouped product may hold by ``gmm_vmem_bytes``'s
+# count: megablox sets no ``vmem_limit_bytes``, so Mosaic compiles it under a
+# v5e's 16 MiB of scoped VMEM. Of the sweep's 440 candidates every one under
+# 15.8 MiB by that count compiled and the four refused stood at 16.9 and over
+# (PERF.md 6, PR 40).
+GMM_VMEM_BYTES = 15 * 2 ** 20
 
 
-def grouped_dot(rows, weights, sizes):
+def _widths(size: int):
+    """Tile widths for one dimension, widest first: the multiples of 128 that
+    cover ``size`` in equal tiles with the least padding there is (none where
+    ``size`` is a multiple of 128; 1856 -> 1920, 640, 384, 128)."""
+    lanes = -(-size // LANES)
+    return [lanes // parts * LANES for parts in range(1, lanes + 1)
+            if lanes % parts == 0]
+
+
+def gmm_vmem_bytes(tm: int, tk: int, tn: int, itemsize: int,
+                   weights_out: bool = False) -> int:
+    """Bytes a grid step of megablox's ``gmm`` (``tgmm``: ``weights_out``)
+    holds at these tiles: both operands' blocks and the result's, each twice
+    (the pipeline fetches the next while this one is worked on), the float32
+    accumulator, and what the body keeps of its operands beside the blocks:
+    ``gmm`` the rows' block once more, ``tgmm`` a float32 copy of each (it
+    masks them as float32)."""
+    if weights_out:
+        return (2 * tm * (tk + tn) * itemsize + 2 * tk * tn * itemsize
+                + 4 * tk * tn + 4 * tm * (tk + tn))
+    return (2 * (tm * tk + tk * tn + tm * tn) * itemsize + 4 * tm * tn
+            + tm * tk * itemsize)
+
+
+def gmm_tiles(k: int, n: int, group_rows: int, itemsize: int,
+              weights_out: bool = False):
+    """(tm, tk, tn) for ONE grouped product of sorted rows in groups of about
+    ``group_rows``: rows [m, k] x weights[g] [k, n] -> [m, n], or, with
+    ``weights_out``, rows^T [k, m] x rows' [m, n] -> weights [g, k, n]
+    (``tgmm``); m a multiple of ``GMM_TILE_ROWS``. From shapes alone; the
+    constants are the sweep's (PERF.md 6, PR 40).
+
+    ``tm``: every group visits ``group_rows / tm + 1`` row tiles and each is
+    computed whole, so 256 rows where a group holds two such tiles and 128
+    under that (512 was never faster, and costs the VMEM ``tn`` uses better).
+    ``tk``, ``tn``: widths that waste the least of k and n (``_widths``),
+    a grid step under ``GMM_VMEM_BYTES``. The row product takes the pair that
+    moves the fewest bytes a FLOP: the rows' block is read again for every n
+    tile (1 / tn), and the weights' block once a group where k is ONE tile
+    (it then stays in VMEM across the group's row tiles: 1 / group_rows) and
+    once a row tile where it is not (1 / tm); so k whole where that leaves a
+    wide ``tn``. The weights' product accumulates a [tk, tn] block over a
+    group's rows and pays its masks and the accumulator's read and write by
+    the grid step: the largest block that fits, the squarer the better.
+    """
+    tm = 256 if group_rows >= 512 else LANES
+    pairs = [(tk, tn) for tk in _widths(k) for tn in _widths(n)
+             if gmm_vmem_bytes(tm, tk, tn, itemsize, weights_out)
+             <= GMM_VMEM_BYTES]
+    if weights_out:
+        best = max(pairs, key=lambda p: (p[0] * p[1], -abs(p[0] - p[1])))
+    else:
+        best = min(pairs, key=lambda p: (
+            1 / p[1] + 1 / (group_rows if p[0] >= k else tm), -p[0]))
+    return (tm, *best)
+
+
+def grouped_dot(rows, weights, sizes, group_rows: int):
     """rows [M, K] @ weights[g] [K, N] for the rows of group g (consecutive,
-    ``sizes`` [G] of them each); rows past the groups are left undefined."""
+    ``sizes`` [G] of them each, about ``group_rows`` expected); rows past the
+    groups are left undefined."""
     if interpret_mode() or rows.shape[0] % GMM_TILE_ROWS:
         return jax.lax.ragged_dot(rows, weights, sizes,
                                   preferred_element_type=rows.dtype)
-    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+    return _tiled_grouped_dot(rows, weights, sizes, group_rows)
 
-    tiling = (GMM_TILE_ROWS, min(896, rows.shape[1]), min(640, weights.shape[2]))
-    return megablox.gmm(rows, weights, sizes, rows.dtype, tiling)
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _tiled_grouped_dot(rows, weights, sizes, group_rows):
+    """:func:`grouped_dot` through megablox's kernels, each of the three
+    products (this one, the rows' cotangent, the weights') at tiles of its
+    own (:func:`gmm_tiles`). Called on the CPU (the tests do) it runs them in
+    the Pallas interpreter."""
+    return _tiled_forward(rows, weights, sizes, group_rows)[0]
+
+
+def _tiled_forward(rows, weights, sizes, group_rows):
+    from jax.experimental.pallas.ops.tpu.megablox.ops import backend as megablox
+
+    k, n = weights.shape[1:]
+    out = megablox.gmm(
+        rows, weights, sizes, rows.dtype,
+        gmm_tiles(k, n, group_rows, rows.dtype.itemsize),
+        interpret=interpret_mode())
+    return out, (rows, weights, sizes)
+
+
+def _tiled_backward(group_rows, kept, d_out):
+    from jax.experimental.pallas.ops.tpu.megablox.ops import backend as megablox
+
+    rows, weights, sizes = kept
+    (k, n), itemsize = weights.shape[1:], rows.dtype.itemsize
+    # d_out [m, n] x weights[g]^T [n, k]: n is the contraction now
+    d_rows = megablox.gmm(
+        d_out, weights, sizes, rows.dtype,
+        gmm_tiles(n, k, group_rows, itemsize),
+        transpose_rhs=True, interpret=interpret_mode())
+    # (tgmm takes rows^T and turns it back itself: XLA drops the pair)
+    d_weights = megablox.tgmm(
+        rows.swapaxes(0, 1), d_out, sizes, weights.dtype,
+        gmm_tiles(k, n, group_rows, itemsize, weights_out=True),
+        num_actual_groups=weights.shape[0], interpret=interpret_mode())
+    return d_rows, d_weights, None
+
+
+_tiled_grouped_dot.defvjp(_tiled_forward, _tiled_backward)
 
 
 def route(x, router_w, correction_bias, top_k: int, scale: float,
@@ -130,11 +243,13 @@ def chunk_rows(tokens: int, top_k: int, n_experts: int, held: int,
     return min(rows, -(-most // multiple) * multiple)
 
 
-def _held_sum(rows: int, top_k: int, activation, gated: bool = False):
+def _held_sum(rows: int, top_k: int, activation, gated: bool,
+              group_rows: int):
     """``total(x, slot_weights, w_up, w_down, order, sizes, ends, trips) ->
     [T, H] float32``: the held experts' terms, summed over the first ``trips``
     pieces of ``rows`` sorted slots (those that hold a local slot), with a
-    backward pass of its own.
+    backward pass of its own. ``group_rows``: the rows an expert expects of a
+    call, for the grouped products' tiles.
 
     Both passes are a loop whose trip count is the number of such pieces (a
     traced value; JAX cannot differentiate such a loop, hence the two rules).
@@ -171,13 +286,14 @@ def _held_sum(rows: int, top_k: int, activation, gated: bool = False):
         with jax.named_scope("moe_dispatch"):
             rows_in = jnp.where(live, rows_in, 0)
         with jax.named_scope("moe_experts"):
-            mid = jnp.where(live, grouped_dot(rows_in, w_up, mine), 0)
+            mid = jnp.where(
+                live, grouped_dot(rows_in, w_up, mine, group_rows), 0)
             if gated:
                 gate, up = jnp.split(mid, 2, axis=-1)
                 mid = activation(gate) * up
             else:
                 mid = activation(mid)
-            rows_out = grouped_dot(mid, w_down, mine)
+            rows_out = grouped_dot(mid, w_down, mine, group_rows)
         with jax.named_scope("moe_combine"):
             return jnp.where(live, rows_out, 0).astype(
                 jnp.float32) * slot_w[:, None]
@@ -236,6 +352,26 @@ def _held_sum(rows: int, top_k: int, activation, gated: bool = False):
     return total
 
 
+def _tile_fill(sizes, ends, rows: int, pieces: int, group_rows: int,
+               products, itemsize: int):
+    """Live work over the work of the tiles visited, for the forward products
+    ``products`` ((k, n) each) over every piece of ``rows`` sorted slots: a
+    group visits, in each piece it reaches, every row tile it touches, and a
+    visited tile is computed whole over k and n rounded up to their tiles."""
+    lo = jnp.arange(pieces)[:, None] * rows
+    start = jnp.clip(ends - sizes - lo, 0, rows)      # [pieces, E], in a piece
+    stop = jnp.clip(ends - lo, 0, rows)
+    live = visited = jnp.zeros((), jnp.float32)
+    for k, n in products:
+        tm, tk, tn = gmm_tiles(k, n, group_rows, itemsize)
+        tiles = jnp.sum(jnp.where(
+            stop > start, -(-stop // tm) - start // tm, 0))
+        live += ends[-1].astype(jnp.float32) * float(k * n)
+        visited += tiles.astype(jnp.float32) * float(
+            tm * (-(-k // tk) * tk) * (-(-n // tn) * tn))
+    return live / jnp.maximum(visited, 1.0)
+
+
 def held_experts(x, chosen, weights, w_up, w_down, first: int,
                  n_experts: int, activation, multiple: int = GMM_TILE_ROWS,
                  gated: bool = False):
@@ -247,13 +383,17 @@ def held_experts(x, chosen, weights, w_up, w_down, first: int,
     (out [T, H] in x's dtype, counters): ``local_slots`` (slots routed to held
     experts), ``load_max_over_mean`` (largest group over the mean group),
     ``dropped_slots`` (local slots that no piece reached: 0, since the pieces
-    cover every slot there is) and ``pieces_run`` (the pieces that hold a
-    local slot: the trips of the loop over them).
+    cover every slot there is), ``pieces_run`` (the pieces that hold a
+    local slot: the trips of the loop over them) and ``tile_fill`` (the two
+    forward products' live rows x k x n over the rows x k x n, both rounded up
+    to their tiles, of the tiles :func:`gmm_tiles` has the kernels visit: 1
+    would be no padding; 0 without a local slot).
     """
     tokens, top_k = chosen.shape
     held = w_up.shape[0]
     rows = chunk_rows(tokens, top_k, n_experts, held, multiple)
     pieces = -(-tokens * top_k // rows)
+    group_rows = max(tokens * top_k // n_experts, 1)
     with jax.named_scope("moe_dispatch"):
         local = chosen - first                                # [T, k]
         here = (local >= 0) & (local < held)
@@ -266,7 +406,7 @@ def held_experts(x, chosen, weights, w_up, w_down, first: int,
         pieces_run = (n_local + rows - 1) // rows
         order = jnp.pad(order, (0, pieces * rows - tokens * top_k))
     w_up, w_down = w_up.astype(x.dtype), w_down.astype(x.dtype)
-    out = _held_sum(rows, top_k, activation, gated)(
+    out = _held_sum(rows, top_k, activation, gated, group_rows)(
         x, weights.reshape(-1), w_up, w_down, order, sizes, ends, pieces_run)
     counters = {
         "local_slots": n_local.astype(jnp.float32),
@@ -275,5 +415,8 @@ def held_experts(x, chosen, weights, w_up, w_down, first: int,
         "dropped_slots": jnp.maximum(
             n_local - pieces * rows, 0).astype(jnp.float32),
         "pieces_run": pieces_run.astype(jnp.float32),
+        "tile_fill": _tile_fill(
+            sizes, ends, rows, pieces, group_rows,
+            (w_up.shape[1:], w_down.shape[1:]), x.dtype.itemsize),
     }
     return out.astype(x.dtype), counters

@@ -227,6 +227,37 @@ def test_ssd_scan_compiles_at_8192(chip):
     _assert_kernel(compiled, "ssd_scan_fwd", "ssd_scan_bwd")
 
 
+@pytest.mark.parametrize("rows,group,k,n", [
+    pytest.param(15872, 963, 2048, 4096, id="zaya-up"),
+    pytest.param(15872, 963, 2048, 2048, id="zaya-down"),
+    pytest.param(5120, 320, 3072, 2048, id="laguna-up"),
+    pytest.param(5120, 320, 1024, 3072, id="laguna-down"),
+    pytest.param(6144, 384, 2688, 1856, id="hybrid-up"),
+    pytest.param(6144, 384, 1856, 2688, id="hybrid-down"),  # k 14.5 lane tiles
+])
+def test_grouped_products_compile_at_the_cells_shapes(chip, monkeypatch, rows,
+                                                      group, k, n):
+    """A grouped expert product and its two cotangents (megablox's ``gmm``
+    twice, ``tgmm`` once) at a cell's piece of sorted slots, each at the
+    tiles ``ops/moe.py gmm_tiles`` chooses for it: Mosaic, not the rule's
+    arithmetic, says whether a grid step fits the scoped VMEM."""
+    from bert_pytorch_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "interpret_mode", lambda: False)
+
+    def fn(rows_in, weights, sizes, d_out):
+        out, pull = jax.vjp(
+            lambda r, w: moe.grouped_dot(r, w, sizes, group), rows_in,
+            weights)
+        return out, pull(d_out)
+
+    compiled = _compile(
+        fn, chip, ((rows, k), jnp.bfloat16), ((8, k, n), jnp.bfloat16),
+        ((8,), jnp.int32), ((rows, n), jnp.bfloat16))
+    _assert_kernel(compiled, "gmm", "tgmm")
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
 @pytest.mark.parametrize("heads,rotary_dim", [(24, 64), (36, 128)])
 def test_rotary_turn_compiles_at_8192(chip, heads, rotary_dim):
     """The turn's kernel at the laguna cell's geometry (queries and the four
