@@ -581,9 +581,110 @@ class ZayaConfig:
         return 16
 
 
+class Qwen3NextConfig:
+    """Configuration of the ``qwen3_next`` family: a pre-norm residual decoder
+    whose every layer is a token mixer, then a routed expert layer with a
+    GATED shared expert. The mixer (``layer_types``; by default layer ``l`` is
+    ``full_attention`` where ``(l + 1) % full_attention_interval == 0`` and
+    ``linear_attention`` elsewhere) is either a gated delta-rule layer
+    (``linear_num_key_heads`` key heads and ``linear_num_value_heads`` value
+    heads of ``linear_key_head_dim`` / ``linear_value_head_dim`` behind a
+    causal convolution of ``linear_conv_kernel_dim`` taps, a gated norm:
+    ``ops/delta_rule.py``) or gated softmax attention
+    (``num_attention_heads`` on ``num_key_value_heads`` heads of ``head_dim``,
+    ``partial_rotary_factor`` of each turned, normed queries and keys, a
+    sigmoid gate as wide as the output). Every norm multiplies by ``1 + w``
+    but the delta-rule layer's gated one. Keys and defaults are the published
+    ``config.json``'s (Qwen/Qwen3-Next-80B-A3B-Instruct); extra keys ride
+    along as on :class:`BertConfig`.
+
+    The chip's share is stated here, as :class:`LagunaConfig` states it:
+    ``num_experts`` experts are HELD of ``num_experts * ep_size``, ``ep_rank``
+    says which; the router keeps its published width. Heads are whole.
+    """
+
+    model_type = "qwen3_next"
+
+    def __init__(self, **values: Any):
+        defaults = dict(
+            vocab_size=151936, hidden_size=2048, intermediate_size=5120,
+            num_hidden_layers=48, num_attention_heads=16,
+            num_key_value_heads=2, head_dim=256, hidden_act="silu",
+            partial_rotary_factor=0.25, rope_theta=10000000,
+            rope_scaling=None, rms_norm_eps=1e-6, full_attention_interval=4,
+            layer_types=None, linear_conv_kernel_dim=4,
+            linear_key_head_dim=128, linear_value_head_dim=128,
+            linear_num_key_heads=16, linear_num_value_heads=32,
+            delta_chunk=64, decoder_sparse_step=1, mlp_only_layers=[],
+            num_experts=512, ep_size=1, ep_rank=0, num_experts_per_tok=10,
+            moe_intermediate_size=512, shared_expert_intermediate_size=512,
+            norm_topk_prob=True, tie_word_embeddings=False,
+            use_sliding_window=False, initializer_range=0.02,
+            max_position_embeddings=262144)
+        for key, value in {**defaults, **values}.items():
+            setattr(self, key, value)
+        layers = self.num_hidden_layers
+        if self.layer_types is None:
+            self.layer_types = [
+                "full_attention" if (l + 1) % self.full_attention_interval == 0
+                else "linear_attention" for l in range(layers)]
+        kinds = {"linear_attention", "full_attention"}
+        if len(self.layer_types) != layers or set(self.layer_types) - kinds:
+            raise ValueError(
+                f"layer_types must be {layers} of {sorted(kinds)}: "
+                f"{self.layer_types}")
+        if (self.decoder_sparse_step != 1 or self.mlp_only_layers
+                or self.tie_word_embeddings or self.use_sliding_window
+                or self.rope_scaling or self.hidden_act != "silu"):
+            raise ValueError(
+                "qwen3_next is built with an expert layer in every layer, an "
+                "untied head, silu experts, the default rotary table and no "
+                "sliding window")
+        heads, kv = self.num_attention_heads, self.num_key_value_heads
+        if heads % kv or int(self.head_dim * self.partial_rotary_factor) % 2:
+            raise ValueError(
+                f"{heads} query heads on {kv} key-value heads of "
+                f"{self.head_dim}, {self.partial_rotary_factor} of it turned")
+        if self.linear_num_value_heads % self.linear_num_key_heads:
+            raise ValueError(
+                f"{self.linear_num_value_heads} value heads on "
+                f"{self.linear_num_key_heads} key heads: whole groups")
+        if not 0 <= self.ep_rank < self.ep_size:
+            raise ValueError(f"ep_rank {self.ep_rank} of ep_size {self.ep_size}")
+
+    @classmethod
+    def from_dict(cls, json_object: dict) -> "Qwen3NextConfig":
+        values = {k: v for k, v in json_object.items() if k != "model_type"}
+        return cls(**values)
+
+    def to_dict(self) -> dict:
+        return dict(copy.deepcopy(self.__dict__), model_type=self.model_type)
+
+    @property
+    def router_experts(self) -> int:
+        """Every expert of the layer, held or not."""
+        return self.num_experts * self.ep_size
+
+    @property
+    def first_expert(self) -> int:
+        return self.ep_rank * self.num_experts
+
+    @property
+    def rope(self) -> tuple:
+        """(rotary dimensions of a head, a ``rope_parameters``-style entry)."""
+        return (int(self.head_dim * self.partial_rotary_factor),
+                {"rope_theta": self.rope_theta, "rope_type": "default"})
+
+    @property
+    def init_sample_length(self) -> int:
+        """Positions of the sample that initializes the parameters (none
+        depends on the length)."""
+        return 16
+
+
 MODEL_FAMILIES = {"bert": BertConfig, "nemotron_h": NemotronHConfig,
                   "laguna": LagunaConfig, "phi4flash": PhiFlashConfig,
-                  "zaya": ZayaConfig}
+                  "zaya": ZayaConfig, "qwen3_next": Qwen3NextConfig}
 
 
 def load_model_config(json_file: str):

@@ -52,6 +52,7 @@ from bert_pytorch_tpu.models.losses import (
 from bert_pytorch_tpu.models.laguna import LagunaForCausalLM
 from bert_pytorch_tpu.models.nemotron_h import NemotronHForCausalLM
 from bert_pytorch_tpu.models.phi4flash import PhiFlashForCausalLM
+from bert_pytorch_tpu.models.qwen3_next import Qwen3NextForCausalLM
 from bert_pytorch_tpu.models.zaya import ZayaForCausalLM
 
 
@@ -63,12 +64,13 @@ def build_pretraining_model(config, dtype, remat: str = "none",
     ``pretrain.make_train_step`` trains it on."""
     from bert_pytorch_tpu.config import (BertConfig, LagunaConfig,
                                          NemotronHConfig, PhiFlashConfig,
-                                         ZayaConfig)
+                                         Qwen3NextConfig, ZayaConfig)
 
     for family, model in ((NemotronHConfig, NemotronHForCausalLM),
                           (LagunaConfig, LagunaForCausalLM),
                           (PhiFlashConfig, PhiFlashForCausalLM),
                           (ZayaConfig, ZayaForCausalLM),
+                          (Qwen3NextConfig, Qwen3NextForCausalLM),
                           (BertConfig, BertForPreTraining)):
         if isinstance(config, family):
             return model(config, dtype=dtype, remat=remat,
@@ -80,6 +82,7 @@ __all__ = [
     "LagunaForCausalLM",
     "NemotronHForCausalLM",
     "PhiFlashForCausalLM",
+    "Qwen3NextForCausalLM",
     "ZayaForCausalLM",
     "build_pretraining_model",
     "next_token_loss",

@@ -1,5 +1,6 @@
 """What the decoder families share (``models/nemotron_h.py``,
-``models/laguna.py``, ``models/phi4flash.py``, ``models/zaya.py``): RMSNorm,
+``models/laguna.py``, ``models/phi4flash.py``, ``models/zaya.py``,
+``models/qwen3_next.py``): RMSNorm,
 LayerNorm, the bias-free projection, the routed expert layer with or without
 a shared expert and with a router of its own or one handed in, and the
 wrapper round a stack of unlike layers: embedding, ``layers_0 ..
@@ -55,13 +56,21 @@ def dense(features: int, std: float, dtype, name):
 
 
 class RMSNorm(nn.Module):
+    """``x rsqrt(mean(x^2) + epsilon) (offset + scale)`` in float32. With
+    ``offset`` 0 the learned scale starts at one; with ``offset`` 1
+    (``models/qwen3_next.py``: the norm multiplies by ``1 + w``) at zero, so
+    both start as the plain norm."""
     epsilon: float = 1e-5
     dtype: Dtype = jnp.float32
+    offset: int = 0
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
-                           jnp.float32)
+        scale = self.param(
+            "scale", nn.initializers.zeros if self.offset
+            else nn.initializers.ones, (x.shape[-1],), jnp.float32)
+        if self.offset:
+            scale = self.offset + scale
         x32 = x.astype(jnp.float32)
         normed = x32 * jax.lax.rsqrt(
             jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.epsilon)
@@ -83,6 +92,15 @@ class LayerNorm(nn.Module):
         return layer_norm(x.astype(self.dtype), scale, bias, self.epsilon)
 
 
+def gate_by_token(y, x, vector):
+    """``y * sigmoid(x . vector)`` a token (y, x [B, S, H]; the product in
+    x's dtype as a projection's, the sigmoid float32), in y's dtype."""
+    with jax.named_scope("moe_shared_gate"):
+        gate = jnp.einsum("bsh,h->bs", x, vector.astype(x.dtype))
+        return (y * jax.nn.sigmoid(gate.astype(jnp.float32))[..., None]
+                ).astype(y.dtype)
+
+
 class ExpertLayer(nn.Module):
     """Router over every expert of the layer (``router_experts`` wide), the
     terms of the ``held`` experts from ``first_expert`` on, and the shared
@@ -96,6 +114,8 @@ class ExpertLayer(nn.Module):
     [T, k] from ``moe.choose``); the layer then holds no router parameter,
     and ``router_experts`` is that router's width, a skip it may have among
     its outputs included (no chip holds it: its slots add nothing).
+    ``shared_gate`` (``qwen3_next``) multiplies the shared expert's output by
+    ``sigmoid(x w_g)`` a token, ``w_g`` one vector of the stream's width.
     Returns (output, the layer's ``moe_*`` counters)."""
     width: int
     shared_width: int
@@ -110,6 +130,7 @@ class ExpertLayer(nn.Module):
     out_std: float
     score: str = "sigmoid"
     gated: bool = False
+    shared_gate: bool = False
     # (tests at a small size set a smaller rounding of the pieces)
     piece_multiple: int = moe.GMM_TILE_ROWS
     dtype: Dtype = jnp.float32
@@ -159,6 +180,10 @@ class ExpertLayer(nn.Module):
                     mid = self.activation(mid)
                 shared = dense(hidden, self.out_std, self.dtype,
                                "shared_down")(mid)
+                if self.shared_gate:
+                    shared = gate_by_token(shared, x, self.param(
+                        "shared_gate", normal(self.std), (hidden,),
+                        jnp.float32))
             return shared + routed.reshape(x.shape), counters
 
 

@@ -1,0 +1,273 @@
+"""The ``qwen3_next`` family (Qwen3-Next: the published ``transformers`` model
+of the same ``model_type``; its linear layers: Gated DeltaNet,
+arXiv:2412.06464): a pre-norm residual decoder for next-token prediction whose
+every layer is a token mixer, then a routed expert layer,
+
+    x <- x + Mixer_l(norm(x));   x <- x + MoE_l(norm(x))
+
+with ``norm`` an RMSNorm that multiplies by ``1 + w`` (w from zero:
+``decoder.RMSNorm(offset=1)``). Three layers in four mix by the GATED DELTA
+RULE, the fourth by GATED SOFTMAX ATTENTION (``Qwen3NextConfig.layer_types``).
+
+**Delta-rule mixer** (``GatedDeltaNet``), ``h`` the layer's normalised input:
+``[q, k, v, z] = h W_qkvz`` and ``[b, a] = h W_ba`` without bias; ``[q, k, v]``
+pass one causal depthwise convolution of 4 taps together and a silu
+(``ops/ssm.py causal_depthwise_conv``); in float32 from there ``beta =
+sigmoid(b)``, ``g = -exp(A_log) softplus(a + dt_bias)``, q and k a head at a
+time to unit length (``u rsqrt(sum u^2 + 1e-6)``), q over ``sqrt(d)``; key
+head j serves value heads 2j and 2j + 1; the rule in chunks of 64
+(``ops/delta_rule.py gated_delta_rule``); a value head at a time ``rmsnorm(o)
+w_n silu(z)`` (w_n from ONE: this norm has no offset); ``o W_o``.
+
+**Attention mixer** (``GatedSoftmaxAttention``): ``[q, gate] = h W_q`` side by
+side a head, k and v on fewer heads; q and k normed over a head (``1 + w``);
+the first quarter of each head turned (``ops/rope.py``); causal softmax
+attention (``ops/attention.py``; the flash kernels under the label
+``gated``); ``(o * sigmoid(gate)) W_o``.
+
+**Expert layer**: ``models/decoder.py ExpertLayer`` as the ``laguna`` family
+builds it (softmax over every expert of the layer, the largest
+``num_experts_per_tok`` renormalised, gated silu experts) with
+``shared_gate``: the shared expert's output times ``sigmoid(h w_g)`` a token.
+
+The chip's share is the config's: ``num_experts`` of ``num_experts * ep_size``
+experts; mixers, router and shared expert are whole on every chip. What the
+absent experts would add lies on other chips and nothing stands in for it.
+
+Counter beside the expert layers' (``decoder.MOE_COUNTERS``):
+``delta_chunks_run``, the chunks the rule runs in one pass (delta-rule layers
+x rows x chunks a row, from shapes), summed over micro-batches.
+
+Scopes (``pretrain.QWEN3_NEXT_SCOPES``): ``gdn`` > ``gdn_in_proj``,
+``gdn_conv``, ``gdn_gates``, ``delta_rule``, ``gdn_gate_norm``,
+``gdn_out_proj``; ``attn_qkv``, ``attn_qk_norm``, ``attn_rope``,
+``attention_core``, ``attn_gate``, ``attn_out``; the expert layer's ``moe_*``
+and ``moe_shared_gate``.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from bert_pytorch_tpu.config import Qwen3NextConfig
+from bert_pytorch_tpu.models.decoder import (MOE_COUNTERS, CausalDecoder,
+                                             ExpertLayer, RMSNorm, dense,
+                                             normal)
+from bert_pytorch_tpu.ops import delta_rule, rope, ssm
+from bert_pytorch_tpu.ops.attention import dot_product_attention
+
+Dtype = Any
+COUNTERS = MOE_COUNTERS + ("delta_chunks_run",)
+L2_EPSILON = 1e-6
+
+
+def _out_std(config: Qwen3NextConfig) -> float:
+    """The projections that write into the residual stream (two a layer)
+    start smaller by sqrt(2 x number of layers)."""
+    return config.initializer_range / math.sqrt(2 * config.num_hidden_layers)
+
+
+def unit_length(t):
+    """Every head of t [..., d] to unit length, in float32."""
+    t = t.astype(jnp.float32)
+    return t * jax.lax.rsqrt(
+        jnp.sum(jnp.square(t), axis=-1, keepdims=True) + L2_EPSILON)
+
+
+def a_log_init(key, shape, dtype=jnp.float32):
+    """The published initialisation: the log of a uniform draw on (0, 16)."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1e-6, 16.0))
+
+
+def conv_init(key, shape, dtype=jnp.float32):
+    """torch's ``Conv1d`` default for a depthwise [taps, channels] kernel:
+    uniform within 1 / sqrt(taps)."""
+    bound = 1.0 / math.sqrt(shape[0])
+    return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+
+@functools.partial(jax.checkpoint, static_argnums=(3,))
+def gated_head_norm(o, z, scale, epsilon: float):
+    """``rmsnorm(o) * scale * silu(z)`` over the last axis, in float32 inside
+    and in o's dtype outside (rematerialized: the backward keeps o and z as
+    they came, not their float32 copies)."""
+    o32, z32 = o.astype(jnp.float32), z.astype(jnp.float32)
+    normed = o32 * jax.lax.rsqrt(
+        jnp.mean(jnp.square(o32), axis=-1, keepdims=True) + epsilon)
+    return (normed * scale * jax.nn.silu(z32)).astype(o.dtype)
+
+
+class GatedDeltaNet(nn.Module):
+    """The delta-rule mixer (the module's docstring). Returns (output, the
+    chunks the rule ran)."""
+    config: Qwen3NextConfig
+    dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, h):
+        cfg = self.config
+        key_heads, value_heads = (cfg.linear_num_key_heads,
+                                  cfg.linear_num_value_heads)
+        dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+        key_w, value_w = key_heads * dk, value_heads * dv
+        conv_w = 2 * key_w + value_w
+        batch, seq = h.shape[:2]
+        std = cfg.initializer_range
+        # One published tensor each, read in column blocks: slicing a weight
+        # is cheap, slicing [rows, 12288] activations along lanes is a copy
+        # a block on a TPU (and a pad a block in the backward).
+        w_qkvz = self.param("in_proj_qkvz", normal(std),
+                            (cfg.hidden_size, conv_w + value_w), jnp.float32)
+        taps = self.param("conv_kernel", conv_init,
+                          (cfg.linear_conv_kernel_dim, conv_w), jnp.float32)
+        blocks = {"q": (0, key_w), "k": (key_w, 2 * key_w),
+                  "v": (2 * key_w, conv_w), "z": (conv_w, conv_w + value_w)}
+        with jax.named_scope("gdn"):
+            with jax.named_scope("gdn_in_proj"):
+                q, k, v, z = (jnp.matmul(h, w_qkvz[:, lo:hi].astype(self.dtype))
+                              for lo, hi in blocks.values())
+                ba = dense(2 * value_heads, std, self.dtype, "in_proj_ba")(h)
+            with jax.named_scope("gdn_conv"):
+                # depthwise: the blocks pass their own channels' taps apart
+                q, k, v = (jax.nn.silu(ssm.causal_depthwise_conv(
+                    t, taps[:, lo:hi], jnp.zeros((hi - lo,), jnp.float32)))
+                    for t, (lo, hi) in zip((q, k, v), blocks.values()))
+            with jax.named_scope("gdn_gates"):
+                b, a = jnp.split(ba.astype(jnp.float32), 2, axis=-1)
+                beta = jax.nn.sigmoid(b)
+                g = -jnp.exp(self.param(
+                    "A_log", a_log_init, (value_heads,), jnp.float32)
+                ) * jax.nn.softplus(a + self.param(
+                    "dt_bias", nn.initializers.ones, (value_heads,),
+                    jnp.float32))
+                q = (unit_length(q.reshape(batch, seq, key_heads, dk))
+                     / math.sqrt(dk)).astype(self.dtype)
+                k = unit_length(
+                    k.reshape(batch, seq, key_heads, dk)).astype(self.dtype)
+            with jax.named_scope("delta_rule"):
+                o = delta_rule.gated_delta_rule(
+                    q, k, v.reshape(batch, seq, value_heads, dv), g, beta,
+                    cfg.delta_chunk)
+            with jax.named_scope("gdn_gate_norm"):
+                o = gated_head_norm(
+                    o, z.reshape(batch, seq, value_heads, dv),
+                    self.param("norm_scale", nn.initializers.ones, (dv,),
+                               jnp.float32), cfg.rms_norm_eps)
+            with jax.named_scope("gdn_out_proj"):
+                out = dense(cfg.hidden_size, _out_std(cfg), self.dtype,
+                            "out_proj")(
+                    o.reshape(batch, seq, value_w))
+        return out, jnp.asarray(
+            delta_rule.delta_chunks(batch, seq, cfg.delta_chunk), jnp.float32)
+
+
+class GatedSoftmaxAttention(nn.Module):
+    """The attention mixer (the module's docstring); ``rotary`` is the (cos,
+    sin) the model made once (a layer called alone makes its own)."""
+    config: Qwen3NextConfig
+    dtype: Dtype = jnp.float32
+    attention_backend: str = "xla"
+
+    @nn.compact
+    def __call__(self, h, rotary=None):
+        cfg = self.config
+        heads, kv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                         cfg.head_dim)
+        batch, seq = h.shape[:2]
+        std = cfg.initializer_range
+        norm = functools.partial(RMSNorm, cfg.rms_norm_eps, self.dtype,
+                                 offset=1)
+        # (a head's query columns, then its gate's: read as two weight blocks,
+        # not as lane slices of the activations)
+        w_q = self.param("q_proj", normal(std),
+                         (cfg.hidden_size, heads * 2 * hd), jnp.float32).reshape(
+                             cfg.hidden_size, heads, 2, hd).astype(self.dtype)
+        with jax.named_scope("attn_qkv"):
+            q, gate = (jnp.einsum("bsh,hnd->bsnd", h, w_q[:, :, part])
+                       for part in (0, 1))
+            k = dense(kv * hd, std, self.dtype, "k_proj")(h).reshape(
+                batch, seq, kv, hd)
+            v = dense(kv * hd, std, self.dtype, "v_proj")(h).reshape(
+                batch, seq, kv, hd)
+        with jax.named_scope("attn_qk_norm"):
+            q, k = norm(name="q_norm")(q), norm(name="k_norm")(k)
+        with jax.named_scope("attn_rope"):
+            cos, sin = rotary or rope.rotary_tables(seq, *cfg.rope)
+            q, k = rope.apply_rotary(q, cos, sin), rope.apply_rotary(k, cos, sin)
+        ctx = dot_product_attention(
+            q, k, v, backend=self.attention_backend, causal=True,
+            label="gated")
+        with jax.named_scope("attn_gate"):
+            ctx = (ctx.astype(jnp.float32) * jax.nn.sigmoid(
+                gate.astype(jnp.float32))).astype(self.dtype)
+        with jax.named_scope("attn_out"):
+            return dense(cfg.hidden_size, _out_std(cfg), self.dtype,
+                         "o_proj")(ctx.reshape(batch, seq, heads * hd))
+
+
+def expert_layer(cfg: Qwen3NextConfig, dtype, name=None) -> ExpertLayer:
+    """The family's expert layer: softmax scores, gated silu experts, a gate
+    on the shared expert, the share ``cfg`` states."""
+    return ExpertLayer(
+        width=cfg.moe_intermediate_size,
+        shared_width=cfg.shared_expert_intermediate_size,
+        held=cfg.num_experts, router_experts=cfg.router_experts,
+        first_expert=cfg.first_expert, top_k=cfg.num_experts_per_tok,
+        route_scale=1.0, norm_topk=cfg.norm_topk_prob,
+        activation=jax.nn.silu, std=cfg.initializer_range,
+        out_std=_out_std(cfg), score="softmax", gated=True, shared_gate=True,
+        piece_multiple=getattr(cfg, "moe_piece_multiple",
+                               ExpertLayer.piece_multiple),
+        dtype=dtype, name=name)
+
+
+class Qwen3NextBlock(nn.Module):
+    config: Qwen3NextConfig
+    layer: int
+    dtype: Dtype = jnp.float32
+    attention_backend: str = "xla"
+
+    @nn.compact
+    def __call__(self, x, rotary=None):
+        cfg = self.config
+        norm = functools.partial(RMSNorm, cfg.rms_norm_eps, self.dtype,
+                                 offset=1)
+        h = norm(name="mixer_norm")(x)
+        chunks = jnp.zeros((), jnp.float32)
+        if cfg.layer_types[self.layer] == "linear_attention":
+            out, chunks = GatedDeltaNet(cfg, self.dtype, name="mixer")(h)
+        else:
+            out = GatedSoftmaxAttention(
+                cfg, self.dtype, self.attention_backend, name="mixer")(
+                    h, rotary)
+        x = x + out
+        out, counters = expert_layer(cfg, self.dtype, name="mlp")(
+            norm(name="mlp_norm")(x))
+        return x + out, {**counters, "delta_chunks_run": chunks}
+
+
+class Qwen3NextForCausalLM(CausalDecoder):
+    config: Qwen3NextConfig
+
+    COUNTERS = COUNTERS
+    NORM = staticmethod(functools.partial(RMSNorm, offset=1))
+
+    def blocks(self, wrap):
+        block = wrap(Qwen3NextBlock)
+        return [block(self.config, layer, self.dtype, self.attention_backend)
+                for layer in range(self.config.num_hidden_layers)]
+
+    def norm_epsilon(self):
+        return self.config.rms_norm_eps
+
+    def shared_inputs(self, seq):
+        """The rotary tables, made once a call and not in every attention
+        layer of every pass."""
+        with jax.named_scope("attn_rope"):
+            return (rope.rotary_tables(seq, *self.config.rope),)

@@ -467,6 +467,25 @@ def _seed_spec():
     return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
+# Mosaic's default scoped VMEM a kernel: what the blocks above were sized for.
+_DEFAULT_SCOPED_VMEM = 16 * 1024 ** 2
+
+
+def _wide_head_params(g, seq, depth, depth_v, itemsize):
+    """``compiler_params`` for a call whose two whole-sequence operands (K and
+    V in the forward and dq kernels, Q and dO in the dkv kernel: [g, seq,
+    depth] and [g, seq, depth_v], each double-buffered) leave the other
+    blocks less than a quarter of the default scoped VMEM: a head of 256 at
+    8192 positions (16 MiB of the 16). The limit is then raised to twice those
+    operands (the tiles and the float32 scores take the rest; the chip has
+    128 MiB). Every narrower call passes nothing and lowers as before."""
+    whole = 2 * g * seq * (depth + depth_v) * itemsize
+    if whole <= 3 * _DEFAULT_SCOPED_VMEM // 4:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=2 * whole)}
+
+
 def _static(segmented, causal, window=None):
     """The kernels' static flags. ``causal`` and ``window`` are passed only
     when set, so the call sites without them stay as they were."""
@@ -516,6 +535,7 @@ def _flash_forward(q3, k3, v3, bias3, seg3, seed, scale, rate, segmented,
         ],
         name=_name("fwd", window, label),
         interpret=interpret_mode(),
+        **_wide_head_params(g, seq, depth, depth_v, q3.dtype.itemsize),
     )(seed, q3, k3, v3, bias3, seg3)
     return out, lse
 
@@ -551,6 +571,7 @@ def _flash_bwd(scale, rate, segmented, causal, window, label, residuals, g):
     )[:, None, :]
 
     gb = _pick_bh_block(seq, bh)
+    wide = _wide_head_params(gb, seq, depth, depth_v, q3.dtype.itemsize)
     dq = pl.pallas_call(
         partial(_flash_dq_kernel, block_k=block_k, scale=scale, rate=rate,
                 bh_block=gb, **_static(segmented, causal, window)),
@@ -570,6 +591,7 @@ def _flash_bwd(scale, rate, segmented, causal, window, label, residuals, g):
         out_shape=jax.ShapeDtypeStruct((bh, seq, depth), q3.dtype),
         name=_name("bwd_dq", window, label),
         interpret=interpret_mode(),
+        **wide,
     )(seed, q3, k3, v3, bias3, seg3, lse, delta, g)
 
     dk, dv, dbias = pl.pallas_call(
@@ -600,6 +622,7 @@ def _flash_bwd(scale, rate, segmented, causal, window, label, residuals, g):
         ],
         name=_name("bwd_dkv", window, label),
         interpret=interpret_mode(),
+        **wide,
     )(seed, q3, k3, v3, bias3, seg3, lse, delta, g)
 
     dseed = np.zeros(seed.shape, dtype=jax.dtypes.float0)

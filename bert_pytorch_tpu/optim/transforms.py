@@ -281,13 +281,16 @@ def no_decay_mask(params) -> optax.Params:
     scale, the router's correction buffer) and the state-space mixer's
     ``A_log`` and ``D``, as Mamba-2's own recipe exempts them; of the
     ``phi4flash`` family (models/phi4flash.py) also the differential
-    attention's four ``lambda_*`` vectors."""
+    attention's four ``lambda_*`` vectors; of the ``qwen3_next`` family
+    (models/qwen3_next.py) also the shared expert's gate vector
+    ``shared_gate`` (its ``A_log``, ``dt_bias`` and norm scales go by the
+    names above)."""
     import flax.traverse_util as traverse_util
 
     flat = traverse_util.flatten_dict(params)
     mask = {
         path: not (
-            path[-1] in ("bias", "scale", "A_log", "D")
+            path[-1] in ("bias", "scale", "A_log", "D", "shared_gate")
             or path[-1].endswith(("_bias", "_scale"))
             or path[-1].startswith("lambda_")
             or any("layer_norm" in part for part in path)

@@ -72,6 +72,15 @@ ZAYA_SCOPES = (
     "router_eda", "router_mlp", "moe_dispatch", "moe_experts", "moe_combine",
     "residual_merge", "lm_head", "lm_loss")
 
+# ... and those of the qwen3_next family's step (models/qwen3_next.py: the
+# delta-rule mixer under ``gdn``, gated softmax attention, the expert layer
+# above with a gate on its shared expert).
+QWEN3_NEXT_SCOPES = (
+    "gdn", "gdn_in_proj", "gdn_conv", "gdn_gates", "delta_rule",
+    "gdn_gate_norm", "gdn_out_proj", "attn_qkv", "attn_qk_norm", "attn_rope",
+    "attn_gate", "attn_out", "moe", "moe_route", "moe_dispatch",
+    "moe_experts", "moe_combine", "moe_shared", "moe_shared_gate", "lm_head",
+    "lm_loss")
 
 # Rows longer than this many positions take the output head and its loss in
 # pieces of this length (models/losses.py chunked_next_token_loss).

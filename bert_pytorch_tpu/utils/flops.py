@@ -236,6 +236,48 @@ def zaya_forward_flops_per_token(config, seq_len: int) -> dict:
                 head=2.0 * h * config.vocab_size)
 
 
+def qwen3_next_forward_flops_per_token(config, seq_len: int) -> dict:
+    """Forward matmul FLOPs per token of a ``qwen3_next`` model on THIS chip
+    (the experts and vocabulary rows it holds), by part: ``gdn_proj`` (the
+    delta-rule mixers' three projections), ``delta_rule`` (the chunked rule's
+    products at ``delta_chunk``: a chunk's K K^T and Q K^T, the two products
+    with the inverse, the four with the state; the inverse itself is left
+    out), ``attention_proj`` (q with its gate, k, v, o), ``attention_core``
+    (the two S x S products over the causal half), ``experts`` (router,
+    shared expert with its gate vector, and three products by the EXPECTED
+    top_k x held / experts of the tokens), ``head``. Lookup, norms, the
+    convolution, rotary, activations and the optimizer are left out."""
+    h, hd = config.hidden_size, config.head_dim
+    heads, kv = config.num_attention_heads, config.num_key_value_heads
+    key_heads, value_heads = (config.linear_num_key_heads,
+                              config.linear_num_value_heads)
+    dk, dv, chunk = (config.linear_key_head_dim, config.linear_value_head_dim,
+                     config.delta_chunk)
+    key_w, value_w = key_heads * dk, value_heads * dv
+    mixers = {
+        "linear_attention": {
+            "gdn_proj": (2 * h * (2 * key_w + 2 * value_w)
+                         + 4 * h * value_heads + 2 * value_w * h),
+            "delta_rule": (4 * chunk * key_w
+                           + value_heads * (2 * chunk * (2 * dv + dk)
+                                            + 6 * dk * dv))},
+        "full_attention": {
+            "attention_proj": 4 * h * heads * hd + 4 * h * kv * hd
+                              + 2 * heads * hd * h,
+            "attention_core": 4 * heads * hd * (seq_len + 1) / 2}}
+    parts = dict.fromkeys(("gdn_proj", "delta_rule", "attention_proj",
+                           "attention_core"), 0.0)
+    for kind in config.layer_types:
+        for name, value in mixers[kind].items():
+            parts[name] += value
+    parts["experts"] = config.num_hidden_layers * (
+        2.0 * h * config.router_experts
+        + 6 * h * config.shared_expert_intermediate_size + 2 * h
+        + config.num_experts_per_tok * config.num_experts
+        / config.router_experts * 6 * h * config.moe_intermediate_size)
+    return dict(parts, head=2.0 * h * config.vocab_size)
+
+
 def causal_lm_train_flops_per_seq(config, seq_len: int) -> float:
     """Training (3x forward) matmul FLOPs of one row of ``seq_len`` tokens of
     a ``causal_lm`` family's model (by the config's ``model_type``)."""
@@ -243,6 +285,7 @@ def causal_lm_train_flops_per_seq(config, seq_len: int) -> float:
                  "laguna": laguna_forward_flops_per_token,
                  "phi4flash": phi_flash_forward_flops_per_token,
                  "zaya": zaya_forward_flops_per_token,
+                 "qwen3_next": qwen3_next_forward_flops_per_token,
                  }[config.model_type]
     return 3.0 * seq_len * sum(per_token(config, seq_len).values())
 

@@ -471,7 +471,8 @@ def prepare_model(args, mesh):
     # ``laguna`` the decoder of mixed window and full attention, ``phi4flash``
     # the decoder of selective scans, differential attention and one kept
     # memory and K/V, ``zaya`` the decoder of attention inside a latent and a
-    # router that hands its state from layer to layer.
+    # router that hands its state from layer to layer, ``qwen3_next`` the
+    # decoder of gated delta-rule layers and gated softmax attention.
     config = load_model_config(args.model_config_file)
     if config.vocab_size % 8 != 0:  # MXU-friendly padding (reference :237)
         config.vocab_size += 8 - (config.vocab_size % 8)

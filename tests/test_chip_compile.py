@@ -144,6 +144,93 @@ def test_causal_flash_attention_compiles_at_8192(chip):
     _assert_kernel(compiled, "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
 
 
+def test_gated_flash_attention_compiles_at_a_head_of_256(chip):
+    """The qwen3_next cell's attention core: a micro-batch of 2 rows of 8192
+    tokens, 16 query heads of 256 on 2 key-value heads, under the caller's
+    label ``gated``. K and V of one head group, whole and double-buffered, are
+    16 MiB, Mosaic's whole default scoped VMEM: the three kernels pass only
+    under the limit ``_wide_head_params`` raises for them, and every narrower
+    head passes no compiler parameter at all."""
+    from bert_pytorch_tpu.ops.attention import dot_product_attention
+    from bert_pytorch_tpu.ops.pallas import attention
+
+    def loss(q, k, v):
+        return jnp.sum(dot_product_attention(
+            q, k, v, backend="pallas", causal=True,
+            label="gated").astype(jnp.float32))
+
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1, 2)), chip,
+        ((2, 8192, 16, 256), jnp.bfloat16), ((2, 8192, 2, 256), jnp.bfloat16),
+        ((2, 8192, 2, 256), jnp.bfloat16))
+    _assert_kernel(compiled, "flash_gated_fwd", "flash_gated_bwd_dq",
+                   "flash_gated_bwd_dkv")
+    wide = attention._wide_head_params(1, 8192, 256, 256, 2)
+    assert wide["compiler_params"].vmem_limit_bytes == 32 * 1024 ** 2
+    for seq, depth, depth_v, g in ((8192, 128, 128, 1), (8192, 64, 128, 1),
+                                   (512, 64, 64, 8)):  # the accepted cells'
+        assert attention._wide_head_params(g, seq, depth, depth_v, 2) == {}
+
+
+def test_delta_rule_mixer_compiles_at_8192(chip):
+    """The qwen3_next cell's delta-rule mixer whole at its geometry (a
+    micro-batch of 2 rows of 8192 tokens, 16 key / 32 value heads of 128
+    beside a stream of 2048), forward and backward: the chunked rule's
+    products, the triangular inverse's float32 products at ``highest`` and
+    the scan over 128 chunks, every scope under ``gdn``."""
+    from bert_pytorch_tpu.config import Qwen3NextConfig
+    from bert_pytorch_tpu.models import qwen3_next
+
+    layer = qwen3_next.GatedDeltaNet(Qwen3NextConfig(), jnp.bfloat16)
+    params = jax.eval_shape(lambda: layer.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 16, 2048), jnp.bfloat16)))
+    sharding = SingleDeviceSharding(chip)
+    place = lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                              sharding=sharding)
+
+    def loss(variables, x):
+        return jnp.sum(jnp.square(
+            layer.apply(variables, x)[0].astype(jnp.float32)))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        jax.tree_util.tree_map(place, params),
+        place(jax.ShapeDtypeStruct((2, 8192, 2048), jnp.bfloat16))).compile()
+    names = set(re.findall(r'op_name="([^"]+)"', compiled.as_text()))
+    for scope in ("gdn_in_proj", "gdn_conv", "gdn_gates", "delta_rule",
+                  "gdn_gate_norm", "gdn_out_proj"):
+        assert any(f"/gdn/{scope}/" in name for name in names), scope
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 4 * 1024 ** 3
+
+
+def test_qwen3_next_step_compiles_at_the_published_widths(topo, monkeypatch):
+    """The qwen3_next cell's whole train step at its real size (626 M
+    parameters, 4 micro-batches of 2 rows of 8192 tokens, ``--remat full``,
+    AdamW) through the rehearsal's own ``compile_step``: the TPU's compiler
+    takes it within a 16 GB chip WITHOUT rematerializing on its own account
+    (a ``.remat`` fusion is the compiler making room: the rule's rows one at
+    a time, the weights read in column blocks and the rematerialized gated
+    norm are what keep it from having to), and the step holds the three
+    ``flash_gated_*`` kernels and the grouped products. A compile that passes
+    here is not a fit (PERF.md 4): the chip's own compiler has the last word."""
+    import benchmarks.run as bench_run
+    from benchmarks.rehearse.compile_real_laguna import compile_step
+    from bert_pytorch_tpu.ops import moe
+    from bert_pytorch_tpu.ops.pallas import attention, common
+
+    # (the rehearsal sets these for good, for its own process: here they are
+    # put back when the test ends, or every later test of this worker would
+    # compile its kernels for a CPU)
+    for module in (common, attention, moe):
+        monkeypatch.setattr(module, "interpret_mode", lambda: False)
+    ctx = bench_run.context(bench_run.ROOT, "train-qwen3-next-80b-seq8192")
+    step = compile_step(ctx, topo)
+    assert step["parameters"] == 625_994_816
+    assert step["remat_fusions"] == 0
+    assert step["argument_bytes"] == pytest.approx(12 * 625_994_816, rel=1e-3)
+    assert step["tpu_custom_calls"] >= 3 + 3 * 4  # the flash kernels, gmm x 4
+
+
 def test_windowed_flash_attention_compiles_at_8192(chip):
     """The window flag at the laguna cell's geometry: one row of 8192 tokens,
     36 query heads of 128 on 4 key-value heads, a window of one 512-wide
@@ -234,6 +321,8 @@ def test_ssd_scan_compiles_at_8192(chip):
     pytest.param(5120, 320, 1024, 3072, id="laguna-down"),
     pytest.param(6144, 384, 2688, 1856, id="hybrid-up"),
     pytest.param(6144, 384, 1856, 2688, id="hybrid-down"),  # k 14.5 lane tiles
+    pytest.param(10240, 320, 2048, 1024, id="qwen-up"),
+    pytest.param(10240, 320, 512, 2048, id="qwen-down"),
 ])
 def test_grouped_products_compile_at_the_cells_shapes(chip, monkeypatch, rows,
                                                       group, k, n):

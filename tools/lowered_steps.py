@@ -10,8 +10,9 @@ the change's, then compare the two directories file by file:
     JAX_PLATFORMS=cpu python tools/lowered_steps.py . /root/scratch/low_change
     for f in /root/scratch/low_parent/*; do cmp $f /root/scratch/low_change/$(basename $f); done
 
-What it writes: ``nemotron_h.txt``, ``laguna.txt`` (a decoder of each accepted
-family with the Pallas backend, ``--remat full``, AdamW), ``phi4flash.txt``
+What it writes: ``nemotron_h.txt``, ``laguna.txt``, ``zaya.txt`` (a decoder of
+each accepted family with the Pallas backend, ``--remat full``, AdamW; the
+last on the carried path with the labelled kernels), ``phi4flash.txt``
 (the family on ``models/decoder.py``'s carried path, at widths no kernel
 compiles for: XLA attention, the scan's kernels unrolled by the interpreter)
 and ``bert_phase2.txt`` (BERT with the flash kernel and its in-kernel dropout,
@@ -40,7 +41,8 @@ import numpy as np  # noqa: E402
 
 from bert_pytorch_tpu import optim, pretrain  # noqa: E402
 from bert_pytorch_tpu.config import (BertConfig, LagunaConfig,  # noqa: E402
-                                     NemotronHConfig, PhiFlashConfig)
+                                     NemotronHConfig, PhiFlashConfig,
+                                     ZayaConfig)
 from bert_pytorch_tpu.models import build_pretraining_model  # noqa: E402
 from bert_pytorch_tpu.ops import moe  # noqa: E402
 from bert_pytorch_tpu.ops.pallas import attention, common  # noqa: E402
@@ -70,6 +72,11 @@ PHI4FLASH = dict(
     num_attention_heads=8, num_key_value_heads=4, sliding_window=8,
     layer_indices=[0, 1, 16, 17, 18, 19], published_num_hidden_layers=32,
     mamba_dt_rank=4, scan_chunk=16)
+ZAYA = dict(
+    vocab_size=256, hidden_size=128, num_hidden_layers=3,
+    num_attention_heads=4, num_key_value_heads=2, head_dim=128,
+    layer_types=["hybrid"] * 3, num_experts=4, ep_size=2, ep_rank=1,
+    moe_intermediate_size=128, router_hidden_size=16)
 BERT = dict(vocab_size=512, hidden_size=128, num_hidden_layers=2,
             num_attention_heads=2, intermediate_size=256,
             max_position_embeddings=512)
@@ -114,7 +121,8 @@ def kernel_jaxpr(**kwargs):
 
 
 for name, config in (("nemotron_h", NemotronHConfig(**NEMOTRON_H)),
-                     ("laguna", LagunaConfig(**LAGUNA))):
+                     ("laguna", LagunaConfig(**LAGUNA)),
+                     ("zaya", ZayaConfig(**ZAYA))):
     write(name, lowered_step(
         build_pretraining_model(config, jnp.bfloat16, remat="full",
                                 attention_backend="pallas"),
