@@ -16,7 +16,10 @@ pass one causal depthwise convolution of 4 taps together and a silu
 sigmoid(b)``, ``g = -exp(A_log) softplus(a + dt_bias)``, q and k a head at a
 time to unit length (``u rsqrt(sum u^2 + 1e-6)``), q over ``sqrt(d)``; key
 head j serves value heads 2j and 2j + 1; the rule in chunks of 64
-(``ops/delta_rule.py gated_delta_rule``); a value head at a time ``rmsnorm(o)
+(``ops/delta_rule.py gated_delta_rule``: at these widths in one dtype its
+Pallas kernels, every row at once, which keep the state each chunk started
+from for the backward; at any other shape plain XLA, the rows one at a time,
+each rematerialized); a value head at a time ``rmsnorm(o)
 w_n silu(z)`` (w_n from ONE: this norm has no offset); ``o W_o``.
 
 **Attention mixer** (``GatedSoftmaxAttention``): ``[q, gate] = h W_q`` side by
@@ -34,9 +37,12 @@ The chip's share is the config's: ``num_experts`` of ``num_experts * ep_size``
 experts; mixers, router and shared expert are whole on every chip. What the
 absent experts would add lies on other chips and nothing stands in for it.
 
-Counter beside the expert layers' (``decoder.MOE_COUNTERS``):
-``delta_chunks_run``, the chunks the rule runs in one pass (delta-rule layers
-x rows x chunks a row, from shapes), summed over micro-batches.
+Counters beside the expert layers' (``decoder.MOE_COUNTERS``), summed over
+micro-batches: ``delta_chunks_run``, the chunks the rule runs in one pass
+(delta-rule layers x rows x chunks a row, from shapes), and
+``delta_kernel_chunks_run``, those of them that the Pallas kernels run
+(``ops/delta_rule.py kernel_chunks``: all of them at the published widths in
+bfloat16, 0 where a call takes the XLA form).
 
 Scopes (``pretrain.QWEN3_NEXT_SCOPES``): ``gdn`` > ``gdn_in_proj``,
 ``gdn_conv``, ``gdn_gates``, ``delta_rule``, ``gdn_gate_norm``,
@@ -63,7 +69,8 @@ from bert_pytorch_tpu.ops import delta_rule, rope, ssm
 from bert_pytorch_tpu.ops.attention import dot_product_attention
 
 Dtype = Any
-COUNTERS = MOE_COUNTERS + ("delta_chunks_run",)
+DELTA_COUNTERS = ("delta_chunks_run", "delta_kernel_chunks_run")
+COUNTERS = MOE_COUNTERS + DELTA_COUNTERS
 L2_EPSILON = 1e-6
 
 
@@ -104,8 +111,8 @@ def gated_head_norm(o, z, scale, epsilon: float):
 
 
 class GatedDeltaNet(nn.Module):
-    """The delta-rule mixer (the module's docstring). Returns (output, the
-    chunks the rule ran)."""
+    """The delta-rule mixer (the module's docstring). Returns (output,
+    ``DELTA_COUNTERS``' values)."""
     config: Qwen3NextConfig
     dtype: Dtype = jnp.float32
 
@@ -150,10 +157,10 @@ class GatedDeltaNet(nn.Module):
                      / math.sqrt(dk)).astype(self.dtype)
                 k = unit_length(
                     k.reshape(batch, seq, key_heads, dk)).astype(self.dtype)
+            v = v.reshape(batch, seq, value_heads, dv)
             with jax.named_scope("delta_rule"):
-                o = delta_rule.gated_delta_rule(
-                    q, k, v.reshape(batch, seq, value_heads, dv), g, beta,
-                    cfg.delta_chunk)
+                o = delta_rule.gated_delta_rule(q, k, v, g, beta,
+                                                cfg.delta_chunk)
             with jax.named_scope("gdn_gate_norm"):
                 o = gated_head_norm(
                     o, z.reshape(batch, seq, value_heads, dv),
@@ -163,8 +170,11 @@ class GatedDeltaNet(nn.Module):
                 out = dense(cfg.hidden_size, _out_std(cfg), self.dtype,
                             "out_proj")(
                     o.reshape(batch, seq, value_w))
-        return out, jnp.asarray(
-            delta_rule.delta_chunks(batch, seq, cfg.delta_chunk), jnp.float32)
+        return out, {
+            "delta_chunks_run": jnp.float32(
+                delta_rule.delta_chunks(batch, seq, cfg.delta_chunk)),
+            "delta_kernel_chunks_run": jnp.float32(
+                delta_rule.kernel_chunks(q, k, v, cfg.delta_chunk))}
 
 
 class GatedSoftmaxAttention(nn.Module):
@@ -239,7 +249,7 @@ class Qwen3NextBlock(nn.Module):
         norm = functools.partial(RMSNorm, cfg.rms_norm_eps, self.dtype,
                                  offset=1)
         h = norm(name="mixer_norm")(x)
-        chunks = jnp.zeros((), jnp.float32)
+        chunks = dict.fromkeys(DELTA_COUNTERS, jnp.zeros((), jnp.float32))
         if cfg.layer_types[self.layer] == "linear_attention":
             out, chunks = GatedDeltaNet(cfg, self.dtype, name="mixer")(h)
         else:
@@ -249,7 +259,7 @@ class Qwen3NextBlock(nn.Module):
         x = x + out
         out, counters = expert_layer(cfg, self.dtype, name="mlp")(
             norm(name="mlp_norm")(x))
-        return x + out, {**counters, "delta_chunks_run": chunks}
+        return x + out, {**counters, **chunks}
 
 
 class Qwen3NextForCausalLM(CausalDecoder):

@@ -21,30 +21,54 @@ i >= j (0 above the diagonal), a chunk that starts from the state S0 has
     O  = diag(exp(G)) Q S0 + (Q K^T * L) V'
     S1 = exp(G_last) S0 + K^T diag(exp(G_last - G)) V'
 
-Everything before ``V'`` is computed for all chunks at once; the state then
-passes from chunk to chunk in a ``lax.scan``. Decays are only ever
-exponentiated as differences ``G_i - G_j`` with i >= j, as ``G_i`` itself
-(the distance to the chunk's start) or as ``G_last - G_i``: all <= 0, so a
-head whose decay reaches e^-20 a token gives zeros, never an inf, and the
-masked half of L is masked BEFORE the exponential, so its gradient is finite
-too. The state, the decays and the inverse are float32 (the inverse's
-products at ``highest``); the other products take operands in ``q``'s dtype
-and sum in float32, and U, W and the chunks' scores are kept in that dtype.
-The backward is autodiff's, except through the inverse, which has its own
-rule (``dA = -T^T dT T^T``: the forward's five squarings are not kept), and
-with each step of the scan over chunks rematerialized (it keeps the state a
-chunk started from and computes the chunk's products again).
+Decays are only ever exponentiated as differences ``G_i - G_j`` with i >= j,
+as ``G_i`` itself (the distance to the chunk's start) or as ``G_last - G_i``:
+all <= 0, so a head whose decay reaches e^-20 a token gives zeros, never an
+inf, and the masked half of L is masked BEFORE the exponential, so its
+gradient is finite too. The state, the decays and the inverse are float32
+(the inverse's products at ``highest``); the other products take operands in
+``q``'s dtype and sum in float32, and U, W and the chunks' scores are kept in
+that dtype. One algorithm, two realisations, chosen by the shapes and dtypes
+the call sees (``kernel_chunks``), as ``ops/ssm.py`` chooses for its scan:
 
-The inverse of a unit lower-triangular [C, C] matrix is the finite Neumann
-product ``(I + X)(I + X^2)(I + X^4)...`` with X = -A (A is nilpotent: the
-series ends at X^(C-1)): log2(C) steps of two [C, C] products each, all
-chunks and heads at once, where forward substitution takes C dependent steps.
+* where the chunk is 64, keys and values are 128 wide in one dtype and the
+  value heads come in pairs a key head (``ops/pallas/delta_rule.py fits``:
+  the published widths), a ``jax.custom_vjp`` over the Pallas kernels
+  ``delta_rule_fwd`` and ``delta_rule_bwd``, every row of the batch in one
+  call: a chunk's decays, A, T, U, W, scores and corrected values stay in
+  VMEM and so does the running state; q, k, v and o keep the layouts of the
+  convolution and the gated norm; XLA is left with the running sum of g over
+  each chunk (and its transpose in the backward) on [B, S, Hv] float32.
+  Between forward and backward the call keeps its operands and the state each
+  chunk started from ([B, S / 64, Hv, 128, 128] float32: 268 MB a row of
+  8192 at 32 value heads); the backward makes a chunk's factors again.
+* every other shape (float32 runs with mixed dtypes, chunks of 8, the CPU
+  tests' tiny widths) in plain XLA (``_rule_of_rows``): everything before
+  ``V'`` for all chunks at once, then the state from chunk to chunk in a
+  ``lax.scan``. Its backward is autodiff's, except through the inverse, which
+  has its own rule (``dA = -T^T dT T^T``: the forward's five squarings are
+  not kept), and with each step of the scan over chunks rematerialized (it
+  keeps the state a chunk started from and computes the chunk's products
+  again); the rows of a batch pass one at a time, each rematerialized
+  (``gated_delta_rule``). It is also the tests' second opinion on the
+  kernels.
+
+The inverse of a unit lower-triangular [C, C] matrix is, in the XLA form, the
+finite Neumann product ``(I + X)(I + X^2)(I + X^4)...`` with X = -A (A is
+nilpotent: the series ends at X^(C-1)): log2(C) steps of two [C, C] products
+each, all chunks and heads at once, where forward substitution takes C
+dependent steps; the kernels substitute, sixteen rows at a time
+(``ops/pallas/delta_rule.py unit_lower_inverse_pairs``).
 """
 
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
+
+from bert_pytorch_tpu.ops.ssm import _chunk_sums, _pad_positions
 
 CHUNK = 64
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -102,17 +126,109 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK):
     with k = 0, beta = 0, g = 0 (no write, no decay), which leaves the
     positions before it untouched.
 
-    The rows of a batch pass ONE AT A TIME, each rematerialized
+    One algorithm, two realisations, chosen by the shapes and dtypes the
+    call sees (``kernel_chunks``): the Pallas kernels where they fit, every
+    row at once (between forward and backward they keep the operands and the
+    state each chunk started from, 64 KB a value head and chunk); else the
+    XLA form, the rows of a batch ONE AT A TIME, each rematerialized
     (``lax.map`` over ``jax.checkpoint``): a row of 8192 positions at the
-    published widths keeps about 1.5 GB between its forward and its backward
-    (the decays, the inverse, U, W, the scores and a state a chunk), and two
-    rows at once do not fit beside 10 GB of training state. The price is one
-    more forward of the rule in the backward."""
+    published widths keeps about 1.5 GB there (the decays, the inverse, U, W,
+    the scores and a state a chunk), at the price of one more forward of the
+    rule in the backward."""
+    if kernel_chunks(q, k, v, chunk):
+        return _rule_kernels(q, k, v, g, beta, chunk)
     if q.shape[0] == 1:
         return _rule_of_rows(q, k, v, g, beta, chunk)
     one_row = jax.checkpoint(lambda *row: _rule_of_rows(
         *(t[None] for t in row), chunk)[0])
     return jax.lax.map(lambda row: one_row(*row), (q, k, v, g, beta))
+
+
+def kernel_chunks(q, k, v, chunk: int = CHUNK) -> int:
+    """Chunks the Pallas kernels run in one pass of ``gated_delta_rule`` over
+    these operands (shapes and dtypes are all it looks at): ``delta_chunks``,
+    or 0 where the call takes the XLA form."""
+    from bert_pytorch_tpu.ops.pallas.delta_rule import fits
+
+    if not (q.dtype == k.dtype == v.dtype and q.shape == k.shape
+            and fits(k.shape, v.shape, chunk)):
+        return 0
+    return delta_chunks(q.shape[0], q.shape[1], chunk)
+
+
+def _rows_of_pairs(t, chunk: int):
+    """[B, S, H] -> [B, S / chunk, H / 2, 2 chunk]: a chunk's positions on
+    the lanes, heads 2p and 2p + 1 side by side on row p."""
+    batch, seq, heads = t.shape
+    return t.reshape(batch, seq // chunk, chunk, heads).swapaxes(2, 3).reshape(
+        batch, seq // chunk, heads // 2, 2 * chunk)
+
+
+def _kernel_operands(q, k, v, g, beta, chunk, *more):
+    """The kernels' operands: positions padded up to whole chunks (and
+    ``more``, which is do, with them), q, k and v flat as the layers round
+    the rule hold them, the log decays' running sum G and beta float32 with
+    the heads on 128 lanes, both again with a chunk's positions on the
+    lanes."""
+    from bert_pytorch_tpu.ops.pallas.delta_rule import LANES
+
+    batch, seq = q.shape[:2]
+    pad = (-seq) % chunk
+    padded = lambda t: _pad_positions(t, pad)
+    flat = lambda t: padded(t).reshape(batch, seq + pad, -1)
+    on_lanes = lambda t: jnp.pad(
+        t, ((0, 0), (0, 0), (0, LANES - t.shape[2])))
+    run = _chunk_sums(padded(g.astype(jnp.float32)), chunk)
+    beta = padded(beta.astype(jnp.float32))
+    return (flat(q), flat(k), flat(v), on_lanes(run), on_lanes(beta),
+            _rows_of_pairs(run, chunk), _rows_of_pairs(beta, chunk)
+            ) + tuple(flat(t) for t in more)
+
+
+def _forward(q, k, v, g, beta, chunk, keep):
+    """[o, and with ``keep`` the state each chunk started from]."""
+    from bert_pytorch_tpu.ops.pallas.delta_rule import delta_rule_forward
+
+    out, *starts = delta_rule_forward(
+        *_kernel_operands(q, k, v, g, beta, chunk), key_heads=k.shape[2],
+        value_heads=v.shape[2], keep=keep)
+    return [out[:, :q.shape[1]].reshape(v.shape)] + starts
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _rule_kernels(q, k, v, g, beta, chunk):
+    return _forward(q, k, v, g, beta, chunk, keep=False)[0]
+
+
+def _rule_kernels_fwd(q, k, v, g, beta, chunk):
+    out, starts = _forward(q, k, v, g, beta, chunk, keep=True)
+    # the operands are kept as they came; the backward pads them again
+    return out, (q, k, v, g, beta, starts)
+
+
+def _rule_kernels_bwd(chunk, residuals, do):
+    from bert_pytorch_tpu.ops.pallas.delta_rule import delta_rule_backward
+
+    q, k, v, g, beta, starts = residuals
+    seq, heads = v.shape[1:3]
+    operands = _kernel_operands(q, k, v, g, beta, chunk, do.astype(q.dtype))
+    dq, dk, dv, drun, dbeta, drun_rows, dbeta_rows = delta_rule_backward(
+        *operands[:7], starts, operands[7], key_heads=k.shape[2],
+        value_heads=heads)
+    batch, chunks = drun_rows.shape[:2]
+    # the two forms each of G and beta came in, back in one: [B, S, Hv]
+    whole = lambda on_lanes, on_rows: (
+        jnp.sum(on_lanes, axis=1)[..., :heads] + on_rows.reshape(
+            batch, chunks, heads, chunk).swapaxes(2, 3).reshape(
+                batch, chunks * chunk, heads))
+    # G is the running sum of g: its cotangent runs back through the sum
+    dg = _chunk_sums(whole(drun, drun_rows), chunk, reverse=True)[:, :seq]
+    return (dq[:, :seq].reshape(q.shape), dk[:, :seq].reshape(k.shape),
+            dv[:, :seq].reshape(v.shape), dg.astype(g.dtype),
+            whole(dbeta, dbeta_rows)[:, :seq].astype(beta.dtype))
+
+
+_rule_kernels.defvjp(_rule_kernels_fwd, _rule_kernels_bwd)
 
 
 def _rule_of_rows(q, k, v, g, beta, chunk: int):

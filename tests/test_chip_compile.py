@@ -175,9 +175,10 @@ def test_gated_flash_attention_compiles_at_a_head_of_256(chip):
 def test_delta_rule_mixer_compiles_at_8192(chip):
     """The qwen3_next cell's delta-rule mixer whole at its geometry (a
     micro-batch of 2 rows of 8192 tokens, 16 key / 32 value heads of 128
-    beside a stream of 2048), forward and backward: the chunked rule's
-    products, the triangular inverse's float32 products at ``highest`` and
-    the scan over 128 chunks, every scope under ``gdn``."""
+    beside a stream of 2048), forward and backward: every scope under
+    ``gdn``, and under ``delta_rule`` the chunked rule's two Pallas kernels
+    (``ops/pallas/delta_rule.py``: Mosaic takes their lane gathers, the
+    inverse's float32 products and the [16, 128, 128] float32 scratch)."""
     from bert_pytorch_tpu.config import Qwen3NextConfig
     from bert_pytorch_tpu.models import qwen3_next
 
@@ -199,6 +200,7 @@ def test_delta_rule_mixer_compiles_at_8192(chip):
     for scope in ("gdn_in_proj", "gdn_conv", "gdn_gates", "delta_rule",
                   "gdn_gate_norm", "gdn_out_proj"):
         assert any(f"/gdn/{scope}/" in name for name in names), scope
+    _assert_kernel(compiled, "delta_rule_fwd", "delta_rule_bwd")
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 4 * 1024 ** 3
 

@@ -641,20 +641,26 @@ def test_no_decay_mask_leaves_out_the_new_vectors():
 
 # -- the family's scopes reach the compiled step ------------------------------------
 
-@pytest.fixture(scope="module")
-def step_names():
+def _compiled_step_names(widths, seq):
+    """Every ``op_name`` of the family's compiled train step (bfloat16,
+    ``--remat full``, 2 micro-batches of one row of ``seq`` tokens)."""
     import re
 
-    model = build_pretraining_model(Qwen3NextConfig(**TINY), jnp.bfloat16,
+    model = build_pretraining_model(Qwen3NextConfig(**widths), jnp.bfloat16,
                                     remat="full")
     tx = optim.adamw(1e-3, max_grad_norm=1.0,
                      weight_decay_mask=optim.no_decay_mask)
     state = pretrain.make_init_fn(
         model, tx, (jnp.zeros((1, 8), jnp.int32),), None)(jax.random.PRNGKey(0))
     step = pretrain.make_train_step(model, tx, next_sentence=False)
-    batch = {"input_ids": np.zeros((2, 1, 24), np.int32)}
+    batch = {"input_ids": np.zeros((2, 1, seq), np.int32)}
     text = step.lower(state, batch).compile().as_text()
     return set(re.findall(r'op_name="([^"]+)"', text))
+
+
+@pytest.fixture(scope="module")
+def step_names():
+    return _compiled_step_names(TINY, 24)
 
 
 @pytest.mark.parametrize("scope",
@@ -671,6 +677,29 @@ def test_the_mixers_parts_lie_under_gdn_and_the_gate_under_moe_shared(
         assert any(f"/gdn/{inner}/" in name for name in step_names), inner
     assert any("/moe/moe_shared/moe_shared_gate/" in name
                for name in step_names)
+
+
+def test_at_fitting_shapes_the_rules_scope_holds_the_kernels_and_no_loop():
+    """With keys and values of 128 in chunks of 64 (one delta-rule layer, 3
+    chunks a row) the compiled step's ``delta_rule`` scope holds the calls of
+    ``delta_rule_fwd`` (forward and recompute) and ``delta_rule_bwd``, and no
+    loop but theirs (here the interpreter's walk over each kernel's grid): no
+    ``lax.map`` over rows, no scan over chunks. The tiny widths' step keeps
+    both loops of the XLA form."""
+    fitting = dict(TINY, num_hidden_layers=1, linear_num_key_heads=1,
+                   linear_num_value_heads=2, linear_key_head_dim=128,
+                   linear_value_head_dim=128, delta_chunk=64)
+    under = lambda names: {n.split("/delta_rule/", 1)[1] for n in names
+                           if "/delta_rule/" in n}
+    rule = under(_compiled_step_names(fitting, 192))
+    for kernel in ("delta_rule_fwd/", "delta_rule_bwd/"):
+        assert any(kernel in n for n in rule), kernel
+    loops = {n for n in rule if "while" in n}
+    assert loops and all(
+        "while" not in n.split("delta_rule_fwd/")[0].split("delta_rule_bwd/")[0]
+        for n in loops)
+    assert any(n.startswith("while") for n in under(
+        _compiled_step_names(dict(TINY, num_hidden_layers=1), 24)))
 
 
 # -- the normal path ------------------------------------------------------------------

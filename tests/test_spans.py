@@ -112,8 +112,13 @@ def test_every_span_the_run_exercises_is_in_the_trace(runs, name):
     if name in NOT_EXERCISED:
         assert not found
         return
-    assert len(found) == 1, f"{name} on {len(found)} threads"
-    count = sum(1 for e in found[0] if e[0] == name)
+    # (a shard's load is a thread of its own, ``data/dataset.py
+    # _async_load_file``: two loads share a line only where the first thread's
+    # id is free again before the second starts, which a loaded host does not
+    # promise)
+    assert found if name == "data:shard_load" else len(found) == 1, (
+        f"{name} on {len(found)} threads")
+    count = sum(1 for line in found for e in line if e[0] == name)
     if name in ("train:feed", "train:dispatch", "train:telemetry",
                 "train:fetch_metrics", "train:log"):
         assert count == STEPS
@@ -165,8 +170,10 @@ def test_feeding_threads_write_their_own_spans(runs):
     assert any(s <= start and end <= e for name, s, e, _ in producer
                if name == "prefetch:source_wait")
     [loader] = _line_of(runs, "data:collate")
-    [shards] = _line_of(runs, "data:shard_load")
-    assert len({id(loop), id(producer), id(loader), id(shards)}) == 4
+    shards = _line_of(runs, "data:shard_load")  # a thread a load
+    assert len({id(loop), id(producer), id(loader)}) == 3
+    assert shards and not {id(loop), id(producer), id(loader)} & {
+        id(line) for line in shards}
 
 
 def test_without_a_session_nothing_is_traced_and_the_losses_are_the_same(runs):
