@@ -11,16 +11,21 @@ RULE, the fourth by GATED SOFTMAX ATTENTION (``Qwen3NextConfig.layer_types``).
 
 **Delta-rule mixer** (``GatedDeltaNet``), ``h`` the layer's normalised input:
 ``[q, k, v, z] = h W_qkvz`` and ``[b, a] = h W_ba`` without bias; ``[q, k, v]``
-pass one causal depthwise convolution of 4 taps together and a silu
-(``ops/ssm.py causal_depthwise_conv``); in float32 from there ``beta =
-sigmoid(b)``, ``g = -exp(A_log) softplus(a + dt_bias)``, q and k a head at a
-time to unit length (``u rsqrt(sum u^2 + 1e-6)``), q over ``sqrt(d)``; key
+pass one causal depthwise convolution of 4 taps together and a silu, then q
+and k a head at a time to unit length (``u rsqrt(sum u^2 + 1e-6)``, float32),
+q over ``sqrt(d)`` (``ops/gdn_mix.py conv_silu_unit``: at these widths ONE
+pass of the Pallas kernels ``gdn_mix_fwd`` / ``gdn_mix_bwd``, float32 from
+the load to the store; at any other shape ``ops/ssm.py
+causal_depthwise_conv`` and plain XLA); in float32 ``beta = sigmoid(b)``,
+``g = -exp(A_log) softplus(a + dt_bias)``; key
 head j serves value heads 2j and 2j + 1; the rule in chunks of 64
 (``ops/delta_rule.py gated_delta_rule``: at these widths in one dtype its
 Pallas kernels, every row at once, which keep the state each chunk started
 from for the backward; at any other shape plain XLA, the rows one at a time,
 each rematerialized); a value head at a time ``rmsnorm(o)
-w_n silu(z)`` (w_n from ONE: this norm has no offset); ``o W_o``.
+w_n silu(z)`` (w_n from ONE: this norm has no offset;
+``ops/gdn_mix.py gated_head_norm``: the kernels ``gated_norm_fwd`` /
+``gated_norm_bwd`` or rematerialized XLA, by the same rule); ``o W_o``.
 
 **Attention mixer** (``GatedSoftmaxAttention``): ``[q, gate] = h W_q`` side by
 side a head, k and v on fewer heads; q and k normed over a head (``1 + w``);
@@ -42,7 +47,10 @@ micro-batches: ``delta_chunks_run``, the chunks the rule runs in one pass
 (delta-rule layers x rows x chunks a row, from shapes), and
 ``delta_kernel_chunks_run``, those of them that the Pallas kernels run
 (``ops/delta_rule.py kernel_chunks``: all of them at the published widths in
-bfloat16, 0 where a call takes the XLA form).
+bfloat16, 0 where a call takes the XLA form), and
+``delta_mix_kernel_chunks_run``, the chunks of the calls whose two
+element-wise sides ran in THEIR kernels (``ops/gdn_mix.py kernel_fit``: heads
+of 128 in one dtype and positions in whole blocks of 16; 0 otherwise).
 
 Scopes (``pretrain.QWEN3_NEXT_SCOPES``): ``gdn`` > ``gdn_in_proj``,
 ``gdn_conv``, ``gdn_gates``, ``delta_rule``, ``gdn_gate_norm``,
@@ -65,26 +73,19 @@ from bert_pytorch_tpu.config import Qwen3NextConfig
 from bert_pytorch_tpu.models.decoder import (MOE_COUNTERS, CausalDecoder,
                                              ExpertLayer, RMSNorm, dense,
                                              normal)
-from bert_pytorch_tpu.ops import delta_rule, rope, ssm
+from bert_pytorch_tpu.ops import delta_rule, gdn_mix, rope
 from bert_pytorch_tpu.ops.attention import dot_product_attention
 
 Dtype = Any
-DELTA_COUNTERS = ("delta_chunks_run", "delta_kernel_chunks_run")
+DELTA_COUNTERS = ("delta_chunks_run", "delta_kernel_chunks_run",
+                  "delta_mix_kernel_chunks_run")
 COUNTERS = MOE_COUNTERS + DELTA_COUNTERS
-L2_EPSILON = 1e-6
 
 
 def _out_std(config: Qwen3NextConfig) -> float:
     """The projections that write into the residual stream (two a layer)
     start smaller by sqrt(2 x number of layers)."""
     return config.initializer_range / math.sqrt(2 * config.num_hidden_layers)
-
-
-def unit_length(t):
-    """Every head of t [..., d] to unit length, in float32."""
-    t = t.astype(jnp.float32)
-    return t * jax.lax.rsqrt(
-        jnp.sum(jnp.square(t), axis=-1, keepdims=True) + L2_EPSILON)
 
 
 def a_log_init(key, shape, dtype=jnp.float32):
@@ -97,17 +98,6 @@ def conv_init(key, shape, dtype=jnp.float32):
     uniform within 1 / sqrt(taps)."""
     bound = 1.0 / math.sqrt(shape[0])
     return jax.random.uniform(key, shape, dtype, -bound, bound)
-
-
-@functools.partial(jax.checkpoint, static_argnums=(3,))
-def gated_head_norm(o, z, scale, epsilon: float):
-    """``rmsnorm(o) * scale * silu(z)`` over the last axis, in float32 inside
-    and in o's dtype outside (rematerialized: the backward keeps o and z as
-    they came, not their float32 copies)."""
-    o32, z32 = o.astype(jnp.float32), z.astype(jnp.float32)
-    normed = o32 * jax.lax.rsqrt(
-        jnp.mean(jnp.square(o32), axis=-1, keepdims=True) + epsilon)
-    return (normed * scale * jax.nn.silu(z32)).astype(o.dtype)
 
 
 class GatedDeltaNet(nn.Module):
@@ -142,9 +132,9 @@ class GatedDeltaNet(nn.Module):
                 ba = dense(2 * value_heads, std, self.dtype, "in_proj_ba")(h)
             with jax.named_scope("gdn_conv"):
                 # depthwise: the blocks pass their own channels' taps apart
-                q, k, v = (jax.nn.silu(ssm.causal_depthwise_conv(
-                    t, taps[:, lo:hi], jnp.zeros((hi - lo,), jnp.float32)))
-                    for t, (lo, hi) in zip((q, k, v), blocks.values()))
+                q, k, v = gdn_mix.conv_silu_unit(q, k, v, *(
+                    taps[:, slice(*blocks[name])] for name in "qkv"),
+                    key_heads, value_heads)
             with jax.named_scope("gdn_gates"):
                 b, a = jnp.split(ba.astype(jnp.float32), 2, axis=-1)
                 beta = jax.nn.sigmoid(b)
@@ -153,28 +143,25 @@ class GatedDeltaNet(nn.Module):
                 ) * jax.nn.softplus(a + self.param(
                     "dt_bias", nn.initializers.ones, (value_heads,),
                     jnp.float32))
-                q = (unit_length(q.reshape(batch, seq, key_heads, dk))
-                     / math.sqrt(dk)).astype(self.dtype)
-                k = unit_length(
-                    k.reshape(batch, seq, key_heads, dk)).astype(self.dtype)
-            v = v.reshape(batch, seq, value_heads, dv)
             with jax.named_scope("delta_rule"):
                 o = delta_rule.gated_delta_rule(q, k, v, g, beta,
                                                 cfg.delta_chunk)
             with jax.named_scope("gdn_gate_norm"):
-                o = gated_head_norm(
-                    o, z.reshape(batch, seq, value_heads, dv),
+                o = gdn_mix.gated_head_norm(
+                    o, z.reshape(o.shape),
                     self.param("norm_scale", nn.initializers.ones, (dv,),
                                jnp.float32), cfg.rms_norm_eps)
             with jax.named_scope("gdn_out_proj"):
                 out = dense(cfg.hidden_size, _out_std(cfg), self.dtype,
                             "out_proj")(
                     o.reshape(batch, seq, value_w))
+        chunks = delta_rule.delta_chunks(batch, seq, cfg.delta_chunk)
         return out, {
-            "delta_chunks_run": jnp.float32(
-                delta_rule.delta_chunks(batch, seq, cfg.delta_chunk)),
+            "delta_chunks_run": jnp.float32(chunks),
             "delta_kernel_chunks_run": jnp.float32(
-                delta_rule.kernel_chunks(q, k, v, cfg.delta_chunk))}
+                delta_rule.kernel_chunks(q, k, v, cfg.delta_chunk)),
+            "delta_mix_kernel_chunks_run": jnp.float32(
+                chunks * gdn_mix.kernel_fit(q, k, v, taps.shape[0]))}
 
 
 class GatedSoftmaxAttention(nn.Module):

@@ -178,7 +178,11 @@ def test_delta_rule_mixer_compiles_at_8192(chip):
     beside a stream of 2048), forward and backward: every scope under
     ``gdn``, and under ``delta_rule`` the chunked rule's two Pallas kernels
     (``ops/pallas/delta_rule.py``: Mosaic takes their lane gathers, the
-    inverse's float32 products and the [16, 128, 128] float32 scratch)."""
+    inverse's float32 products and the [16, 128, 128] float32 scratch); under
+    ``gdn_conv`` and ``gdn_gate_norm`` the element-wise kernels of
+    ``ops/pallas/gdn_mix.py`` (the sublane rotations, blocks of [256, 4096],
+    the rows before a block by a second index map), each between neighbours
+    whose layout it shares: no copy and no transpose under either scope."""
     from bert_pytorch_tpu.config import Qwen3NextConfig
     from bert_pytorch_tpu.models import qwen3_next
 
@@ -200,7 +204,12 @@ def test_delta_rule_mixer_compiles_at_8192(chip):
     for scope in ("gdn_in_proj", "gdn_conv", "gdn_gates", "delta_rule",
                   "gdn_gate_norm", "gdn_out_proj"):
         assert any(f"/gdn/{scope}/" in name for name in names), scope
-    _assert_kernel(compiled, "delta_rule_fwd", "delta_rule_bwd")
+    _assert_kernel(compiled, "delta_rule_fwd", "delta_rule_bwd",
+                   "gdn_mix_fwd", "gdn_mix_bwd", "gated_norm_fwd",
+                   "gated_norm_bwd")
+    for line in compiled.as_text().splitlines():
+        if re.search(r"/gdn/(gdn_conv|gdn_gate_norm)/", line):
+            assert not re.search(r" = \S+ (copy|transpose)\(", line), line
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 4 * 1024 ** 3
 

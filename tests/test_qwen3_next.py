@@ -33,7 +33,7 @@ from bert_pytorch_tpu import optim, pretrain
 from bert_pytorch_tpu.config import Qwen3NextConfig, load_model_config
 from bert_pytorch_tpu.models import build_pretraining_model, decoder, qwen3_next
 from bert_pytorch_tpu.models.losses import next_token_loss
-from bert_pytorch_tpu.ops import delta_rule, rope
+from bert_pytorch_tpu.ops import delta_rule, gdn_mix, rope
 from bert_pytorch_tpu.utils import flops
 
 # the published layer at a small size: 2 key / 4 value heads of 16 behind 4
@@ -113,9 +113,9 @@ def _recurrence(q, k, v, g, beta):
 def _rule_inputs(seq, a_values, seed=0, batch=2, key_heads=2, dim=16):
     value_heads = len(a_values)
     k = keys(6, seed)
-    q = qwen3_next.unit_length(
+    q = gdn_mix.unit_length(
         jax.random.normal(k[0], (batch, seq, key_heads, dim))) / math.sqrt(dim)
-    key = qwen3_next.unit_length(
+    key = gdn_mix.unit_length(
         jax.random.normal(k[1], (batch, seq, key_heads, dim)))
     v = jax.random.normal(k[2], (batch, seq, value_heads, dim))
     g = -jnp.asarray(a_values, jnp.float32) * jax.nn.softplus(
@@ -250,7 +250,7 @@ def test_the_gated_norm_has_no_offset_and_gates_by_silu_z():
     scale = 1.0 + 0.3 * jax.random.normal(keys(1, 6)[0], (16,))
     want = (np.asarray(o) / np.sqrt(np.mean(np.square(o), -1, keepdims=True) + 1e-6)
             * np.asarray(scale) * np.asarray(z) / (1 + np.exp(-np.asarray(z))))
-    close(qwen3_next.gated_head_norm(o, z, scale, 1e-6), want)
+    close(gdn_mix.gated_head_norm(o, z, scale, 1e-6), want)
     # the mixer's scale starts at ONE (the other norms' w starts at zero)
     shapes = _model().init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
     tree = nn.unbox(shapes)["params"]["layers_0"]
@@ -269,14 +269,14 @@ def test_a_dropped_part_of_the_delta_mixer_is_seen(dropped):
     want = ref.delta_mixer(p, "l0.", c, x, "f32")
     with pytest.MonkeyPatch.context() as patch:
         if dropped == "conv":
-            patch.setattr(qwen3_next.ssm, "causal_depthwise_conv",
+            patch.setattr(gdn_mix.ssm, "causal_depthwise_conv",
                           lambda t, w, b: t * w[-1])
         elif dropped == "silu":
             patch.setattr(qwen3_next.jax.nn, "silu", lambda t: t)
         elif dropped == "l2":
-            patch.setattr(qwen3_next, "unit_length", lambda t: t)
+            patch.setattr(gdn_mix, "unit_length", lambda t: t)
         elif dropped == "gate":
-            patch.setattr(qwen3_next, "gated_head_norm",
+            patch.setattr(gdn_mix, "gated_head_norm",
                           lambda o, z, s, e: o * s)
         else:
             rule = delta_rule.gated_delta_rule

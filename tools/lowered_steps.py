@@ -21,7 +21,10 @@ and ``bert_phase2.txt`` (BERT with the flash kernel and its in-kernel dropout,
 hold the checkout's path and line numbers) and the source locations cut out;
 and ``kernel_*.txt``: the jaxpr of ``flash_attention``'s forward and backward
 for the bidirectional call with bias and dropout, the packed call, the causal
-and the windowed one, which is how the kernels' BODIES are compared. Nothing
+and the windowed one, which is how the kernels' BODIES are compared; since
+PR 43 also ``kernel_gdn_mix.txt`` and ``kernel_gated_norm.txt``, the delta-rule
+mixer's element-wise kernel pairs (``ops/gdn_mix.py``) the same way, where the
+checkout has them. Nothing
 runs on a device and nothing here is a test: equal files say the programs the
 accepted cells compile did not change; they say nothing of speed.
 """
@@ -145,3 +148,24 @@ for name, kwargs in (
         ("causal", dict(causal=True)),
         ("window", dict(causal=True, window=128))):
     write("kernel_" + name, kernel_jaxpr(**kwargs))
+
+try:
+    from bert_pytorch_tpu.ops import gdn_mix  # noqa: E402
+except ImportError:  # a checkout from before PR 43
+    print("kernel_gdn_mix, kernel_gated_norm: not in this checkout")
+else:
+    def total(outs):
+        return sum(jnp.sum(t.astype(jnp.float32))
+                   for t in jax.tree_util.tree_leaves(outs))
+
+    heads, lanes = (2, 4), 128
+    raw = [jnp.zeros((2, SEQ, count * lanes), jnp.bfloat16)
+           for count in heads[:1] + heads]
+    taps = [jnp.zeros((4, t.shape[-1]), jnp.float32) for t in raw]
+    write("kernel_gdn_mix", str(jax.make_jaxpr(jax.grad(
+        lambda *args: total(gdn_mix.conv_silu_unit(*args, *heads)),
+        argnums=tuple(range(6))))(*raw, *taps)))
+    o = jnp.zeros((2, SEQ, heads[1], lanes), jnp.bfloat16)
+    write("kernel_gated_norm", str(jax.make_jaxpr(jax.grad(
+        lambda *args: total(gdn_mix.gated_head_norm(*args, 1e-6)),
+        argnums=(0, 1, 2)))(o, o, jnp.ones((lanes,), jnp.float32))))
