@@ -33,9 +33,9 @@ those winners too. That is safe — the serve engine folds the winner
 digest into its forward names regardless of its own autotune mode, so
 names always describe the geometry actually compiled and the compile
 cache never aliases — but it means heuristic-vs-winner A/B comparisons
-must isolate processes or :func:`clear_winners` between legs (the
-BENCH_KERNELS leg orders its engines accordingly; tests use a
-clear_winners fixture).
+must isolate processes or :func:`clear_winners` between legs
+(tests/test_kernels_fastpath.py clears them in its ``clean_registry``
+fixture, before and after a test).
 
 On CPU the kernels run in interpret mode, so measured timings rank
 pure-Python emulation, not MXU behavior — the mechanism (measure,

@@ -32,7 +32,7 @@ arrive, so the full fp32 tree never exists on the serving host.
 Measured on this repo's CPU CI box (XLA CPU has no fast s8 GEMM): int8
 is ~3x SLOWER than fp32 per matmul — the latency win is a TPU(MXU)
 property; CPU tests prove parity and the 4x weight-byte reduction
-(tests/test_inference_fastpath.py, bench.py BENCH_SERVE_QUANT leg).
+(tests/test_inference_fastpath.py).
 """
 
 from __future__ import annotations

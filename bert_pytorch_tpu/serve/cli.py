@@ -21,8 +21,8 @@ AUTOTUNE_MODES = ("off", "load", "measure")
 
 def add_dispatch_args(parser: argparse.ArgumentParser) -> None:
     """The dispatch-plane knob (serve/service.py, docs/serving.md
-    "Continuous batching"), shared by run_server.py and the BENCH_SERVE
-    legs so the A/B comparison uses one spelling."""
+    "Continuous batching"), shared by run_server.py and
+    tools/batch_infer.py so an A/B comparison uses one spelling."""
     parser.add_argument(
         "--dispatch_mode", type=str, default="pipelined",
         choices=DISPATCH_MODES,
@@ -83,8 +83,8 @@ def add_fast_path_args(parser: argparse.ArgumentParser) -> None:
 
 def add_tracing_args(parser: argparse.ArgumentParser) -> None:
     """The request-tracing / metrics-plane knobs (serve/tracing.py),
-    shared by run_server.py, tools/batch_infer.py (its engine flags flow
-    through run_server.parse_arguments), and the BENCH_SERVE legs."""
+    shared by run_server.py and tools/batch_infer.py (its engine flags
+    flow through run_server.parse_arguments)."""
     parser.add_argument(
         "--trace_sample_rate", type=float, default=0.01,
         help="fraction of requests exported as serve_trace span trees "

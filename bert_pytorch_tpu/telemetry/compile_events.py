@@ -211,7 +211,7 @@ class CompileMonitor:
         record might be emitted: on the wrapper's first call, or when
         compile/cache activity actually fired during the call (a new shape
         signature always triggers a real trace+compile, so it can't slip
-        by). Steady-state calls — the ones inside bench.py's measured
+        by). Steady-state calls — the ones inside a benchmark's measured
         window and the StepTimer's host-dispatch segment — add only a
         thread-local set/restore and two clock reads.
         """

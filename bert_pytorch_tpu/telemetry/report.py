@@ -35,7 +35,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import sys
 from typing import Optional
 
 # Relative tolerances (fraction of the baseline value) per check; chosen
@@ -451,8 +450,8 @@ def summarize_records(records, name: str = "") -> dict:
                 sorted(path.items(), key=lambda kv: -kv[1]))
 
     if serve_cold_starts:
-        # A multi-start artifact (e.g. the BENCH_SERVE quant leg runs
-        # fp32 then int8 engines) gates on the WORST start; the cold
+        # A multi-start artifact (e.g. fp32 then int8 engines in one
+        # file) gates on the WORST start; the cold
         # compile count sums — the warm-restart acceptance is "zero cold
         # compiles", and any start that compiled breaks it.
         out["serve_cold_start_s"] = round(max(
@@ -779,9 +778,8 @@ def summarize_records(records, name: str = "") -> dict:
             if isinstance(value, (int, float)) and not isinstance(value, bool):
                 out.setdefault(key, value)
             elif key == "metric" and isinstance(value, str):
-                # Bench runs stamp their config's metric name; consumers
-                # (bench.py's regression gate) use it to refuse diffing
-                # incomparable configurations.
+                # A run may stamp its config's metric name; a consumer
+                # uses it to refuse diffing incomparable configurations.
                 out.setdefault("metric", value)
     return out
 
@@ -1061,40 +1059,14 @@ def format_checks(checks) -> str:
     return "\n".join(lines)
 
 
-def _load_ledger():
-    """Ledger module both ways (the collector's _load_schema pattern):
-    package import when report.py was imported normally, sibling
-    file-path import when report.py was itself loaded by path
-    (tools/telemetry_report.py on a jax-free box)."""
-    if __package__:
-        import importlib
-
-        return importlib.import_module(
-            "bert_pytorch_tpu.telemetry.ledger")
-    import importlib.util
-
-    module = sys.modules.get("_report_ledger")
-    if module is not None:
-        return module
-    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "ledger.py")
-    spec = importlib.util.spec_from_file_location("_report_ledger", path)
-    module = importlib.util.module_from_spec(spec)
-    sys.modules["_report_ledger"] = module
-    spec.loader.exec_module(module)
-    return module
-
-
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(
         prog="telemetry-report",
         description="Summarize a telemetry JSONL artifact; with a "
                     "baseline, diff the two and exit 1 on regression "
                     "(docs/telemetry.md).")
-    parser.add_argument("run", nargs="?", default=None,
-                        help="telemetry JSONL of the run under test "
-                             "(optional with --ledger: a bare drift "
-                             "check over the existing trajectory)")
+    parser.add_argument("run",
+                        help="telemetry JSONL of the run under test")
     parser.add_argument("baseline", nargs="?", default=None,
                         help="baseline telemetry JSONL to diff against")
     parser.add_argument("--baseline", dest="baseline_flag", default=None,
@@ -1102,34 +1074,14 @@ def main(argv=None) -> int:
     parser.add_argument("--json", action="store_true",
                         help="legacy machine-readable output (summaries + "
                              "checks + verdict) instead of the human "
-                             "tables (bench.py's regression attachment "
-                             "depends on its exact keys; --format json "
-                             "is the stable-contract successor)")
+                             "tables (--format json is the "
+                             "stable-contract successor)")
     parser.add_argument("--format", choices=("text", "json"),
                         default="text", dest="out_format",
                         help="output format; 'json' emits one stable "
                              "versioned object ({\"version\": 1, ..., "
                              "\"rc\": N} — the tools/check_all.py "
                              "contract)")
-    parser.add_argument("--ledger", default=None, metavar="PATH",
-                        help="longitudinal perf ledger JSONL "
-                             "(telemetry/ledger.py): append the run "
-                             "under test as a ledger_entry, then gate "
-                             "the newest entry of every (leg, config) "
-                             "trajectory against its rolling median — "
-                             "'perf ledger drift' by name, exit 1")
-    parser.add_argument("--ledger-leg", default="report",
-                        help="ledger leg name for the appended entry "
-                             "(default %(default)s)")
-    parser.add_argument("--ledger-window", type=int, default=None,
-                        help="rolling-median history depth per "
-                             "trajectory (default: the ledger module's)")
-    parser.add_argument("--ledger-tol", type=float, default=None,
-                        help="relative drift tolerance vs the rolling "
-                             "median (default: the ledger module's)")
-    parser.add_argument("--no-ledger-append", action="store_true",
-                        help="gate the existing trajectory without "
-                             "appending the run under test")
     parser.add_argument("--last-run", action="store_true",
                         help="summarize only each artifact's FINAL run "
                              "(append-mode artifacts accumulate runs, "
@@ -1155,64 +1107,21 @@ def main(argv=None) -> int:
                              "envelopes (1.0 = 2x the baseline max)")
     args = parser.parse_args(argv)
     baseline = args.baseline_flag or args.baseline
-    if args.run is None and not args.ledger:
-        parser.error("need a run artifact (or --ledger for a bare "
-                     "drift check)")
-    if args.run is None and baseline is not None:
-        parser.error("a baseline needs a run artifact to diff against")
 
     for path in filter(None, (args.run, baseline)):
         if not os.path.exists(path):
             print(f"telemetry-report: {path}: no such file")
             return 2
-    new = summarize_file(args.run, last_run=args.last_run) \
-        if args.run else None
+    new = summarize_file(args.run, last_run=args.last_run)
     base = summarize_file(baseline, last_run=args.last_run) \
         if baseline else None
     regressions: list = []
     checks: list = []
-    if base is not None and new is not None:
+    if base is not None:
         tolerances = {"step": args.step_tol, "p95": args.p95_tol,
                       "mfu": args.mfu_tol, "mem": args.mem_tol,
                       "grad": args.grad_tol}
         regressions, checks = compare(base, new, tolerances)
-
-    # -- perf ledger gate (telemetry/ledger.py, docs/telemetry.md) ------
-    # Append the run under test (one ledger_entry per report run — the
-    # trajectory is the point), then gate the NEWEST entry of every
-    # (leg, config) trajectory against its rolling median: the named
-    # "perf ledger drift" regression a single hand-picked baseline can
-    # never catch (a slow drift walks in one in-tolerance step at a
-    # time).
-    ledger_info = None
-    if args.ledger:
-        ledger = _load_ledger()
-        window = args.ledger_window if args.ledger_window is not None \
-            else ledger.DEFAULT_WINDOW
-        tol = args.ledger_tol if args.ledger_tol is not None \
-            else ledger.DEFAULT_TOLERANCE
-        appended = None
-        if new is not None and not args.no_ledger_append:
-            metrics = ledger.metrics_from_summary(new)
-            appended = ledger.append_entry(
-                args.ledger, args.ledger_leg, metrics,
-                extra={"source": new.get("name") or args.run})
-        entries = ledger.read_entries(args.ledger)
-        findings = ledger.check_drift(entries, window=window,
-                                      tolerance=tol)
-        ledger_info = {"path": args.ledger, "entries": len(entries),
-                       "appended": appended is not None,
-                       "findings": findings}
-        for f in findings:
-            entry = {
-                "metric": f"ledger:{f['leg']}:{f['metric']}",
-                "label": "perf ledger drift",
-                "base": f["median"], "new": f["latest"],
-                "change": f["change"], "tolerance": f["tolerance"],
-                "verdict": "regression",
-            }
-            checks.append(entry)
-            regressions.append(entry)
 
     verdict = "regression" if regressions else "ok"
     rc = 1 if regressions else 0
@@ -1222,49 +1131,30 @@ def main(argv=None) -> int:
         # versioned object, rc mirrored inside so a pipe consumer never
         # needs the process exit code.
         combined: dict = {"version": 1, "verdict": verdict,
-                          "regressions": regressions, "checks": checks}
-        if new is not None:
-            combined["run"] = new
+                          "regressions": regressions, "checks": checks,
+                          "run": new}
         if base is not None:
             combined["baseline"] = base
-        if ledger_info is not None:
-            combined["ledger"] = ledger_info
         combined["rc"] = rc
         print(json.dumps(combined, indent=2))
         return rc
     if args.json:
-        # Legacy shapes, preserved exactly (bench.py parses them); the
-        # ledger verdict rides as extra keys only when requested.
+        # The legacy shapes, kept as they were.
         if base is not None:
             out = {"verdict": verdict, "regressions": regressions,
                    "checks": checks, "run": new, "baseline": base}
         else:
-            out = {"run": new} if new is not None else {}
-            if args.ledger:
-                out["verdict"] = verdict
-                out["regressions"] = regressions
-        if ledger_info is not None:
-            out["ledger"] = ledger_info
+            out = {"run": new}
         print(json.dumps(out))
         return rc
 
-    if base is not None and new is not None:
+    if base is not None:
         print(format_summary(base))
         print(format_summary(new))
         print(f"== regression check (run vs baseline: {verdict})")
         print(format_checks(checks))
-    elif new is not None:
+    else:
         print(format_summary(new))
-    if ledger_info is not None:
-        state = "DRIFT" if ledger_info["findings"] else "ok"
-        print(f"== perf ledger ({ledger_info['path']}: "
-              f"{ledger_info['entries']} entries, {state})")
-        for f in ledger_info["findings"]:
-            print(f"  REGRESSION perf ledger drift: "
-                  f"{f['leg']}/{f['metric']} [{f['digest']}]: "
-                  f"median {f['median']:g} -> {f['latest']:g} "
-                  f"({f['change']:+.1%}, tolerance {f['tolerance']:.0%}, "
-                  f"window {f['window']})")
     if regressions:
         names = ", ".join(dict.fromkeys(r["label"] for r in regressions))
         print(f"telemetry-report: REGRESSION in: {names}")

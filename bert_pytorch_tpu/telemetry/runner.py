@@ -1,5 +1,5 @@
 """TrainTelemetry — the facade every runner threads its training loop
-through (run_pretraining, run_squad, run_glue, run_ner, run_swag, bench.py).
+through (run_pretraining, run_squad, run_glue, run_ner, run_swag).
 
 One object owns the telemetry pieces and their lifecycle:
 

@@ -194,12 +194,6 @@ KIND_REQUIRED_KEYS = {
         "source", "trigger", "covered", "covered_unit", "duration_s",
         "samples", "top_frames", "trace_path", "trace_bytes",
     ),
-    # one point on the longitudinal perf trajectory (telemetry/ledger.py,
-    # tools/perf_ledger.py): a named bench/report leg's headline numbers
-    # plus the config digest that makes entries comparable — the
-    # "perf ledger drift" gate regresses the newest entry against the
-    # rolling median of its leg's history
-    "ledger_entry": ("leg", "config_digest", "metrics"),
     # -- deployment plane (serve/registry.py, serve/rollout.py,
     # docs/serving.md "Model registry & canary rollouts") ---------------
     # one model-registry lifecycle event: a version published into the
@@ -243,21 +237,6 @@ PROFILE_TRIGGERS = ("startup", "ondemand", "fleet")
 # What a profile_window's ``covered`` counts: training steps (trainer
 # captures) or completed dispatch batches' requests (replica captures).
 PROFILE_COVERED_UNITS = ("steps", "requests")
-
-# The ledger metrics the drift gate knows a direction for
-# (telemetry/ledger.py): "up" metrics regress by growing (latencies,
-# cold start), "down" metrics regress by shrinking (MFU, padding
-# efficiency). Extra metric keys are allowed in entries — they are
-# recorded but not drift-gated.
-LEDGER_METRIC_DIRECTIONS = {
-    "step_ms_p50": "up",
-    "step_ms_p95": "up",
-    "mfu": "down",
-    "serve_p50_ms": "up",
-    "serve_p99_ms": "up",
-    "cold_start_s": "up",
-    "padding_efficiency": "down",
-}
 
 # Model-registry version lifecycle (serve/registry.py; mirrored here so
 # the schema lint stays stdlib-only/jax-free like TRACE_PHASES). A
@@ -387,8 +366,6 @@ def validate_record(rec) -> list:
                     _check_autotune_fields(rec, errors)
                 if kind == "profile_window":
                     _check_profile_fields(rec, errors)
-                if kind == "ledger_entry":
-                    _check_ledger_fields(rec, errors)
                 if kind == "registry_event":
                     _check_registry_event_fields(rec, errors)
                 if kind == "rollout_window":
@@ -1216,42 +1193,6 @@ def _check_profile_fields(rec, errors) -> None:
         errors.append(
             f"top_frames shares sum to {share_sum:.4f} > 1: self-time "
             "attribution must decompose the capture, not exceed it")
-
-
-def _check_ledger_fields(rec, errors) -> None:
-    """ledger_entry consistency (telemetry/ledger.py): the trajectory
-    point names its leg and config digest (the comparability join keys
-    the drift gate filters on) and carries a non-empty metrics object of
-    finite non-negative numbers, with the same percentile-ordering and
-    ratio-domain rules the live record kinds obey — a ledger whose
-    history is internally inconsistent cannot anchor a drift verdict."""
-    for key in ("leg", "config_digest"):
-        v = rec.get(key)
-        if not isinstance(v, str) or not v:
-            errors.append(f"{key} must be a non-empty string, got {v!r}")
-    metrics = rec.get("metrics")
-    if not isinstance(metrics, dict) or not metrics:
-        errors.append(
-            f"metrics must be a non-empty object, got {metrics!r}")
-        return
-    nums = {}
-    for key, v in metrics.items():
-        if not _is_number(v) or v < 0:
-            errors.append(
-                f"metrics.{key} must be a non-negative number, got {v!r}")
-        else:
-            nums[key] = v
-    for lo, hi in (("step_ms_p50", "step_ms_p95"),
-                   ("serve_p50_ms", "serve_p99_ms")):
-        if {lo, hi} <= set(nums) and nums[lo] > nums[hi]:
-            errors.append(
-                f"metrics.{lo} ({nums[lo]}) exceeds metrics.{hi} "
-                f"({nums[hi]}): percentiles must be ordered")
-    for key in ("padding_efficiency", "mfu"):
-        if key in nums and nums[key] > 1:
-            errors.append(
-                f"metrics.{key} must be a ratio in [0, 1], "
-                f"got {nums[key]!r}")
 
 
 def _check_registry_event_fields(rec, errors) -> None:

@@ -1,4 +1,4 @@
-"""Generate synthetic pretraining shards for smoke tests and benchmarks.
+"""Generate synthetic pretraining shards for smoke tests and rehearsals.
 
 Writes HDF5 shards in the same formats the real pipeline produces
 (reference utils/encode_data.py:183-210 for the new
@@ -10,8 +10,8 @@ runtime and runners can be exercised end-to-end without the real corpus.
 trace of N online-inference requests — mixed task heads, short-biased
 text lengths (the same u^2 draw as ``--mixed_lengths``, which is what
 makes request packing worth testing), Poisson arrival offsets — plus a
-``vocab.txt`` covering the trace's word list, consumed by bench.py's
-``BENCH_SERVE`` leg and the serving smoke test (tests/test_serve.py).
+``vocab.txt`` covering the trace's word list, consumed by the serving
+smoke test (tests/test_serve.py).
 """
 
 from __future__ import annotations
@@ -62,7 +62,7 @@ def make_request_trace(
     short-biased (``lo + (max-lo) * u^2`` words — the Wikipedia-style
     spread of ``--mixed_lengths``, so packing has headroom); arrivals are
     Poisson (exponential inter-arrival at ``rate_rps``; 0 = all at t=0,
-    the closed-loop saturation replay bench.py uses by default).
+    a closed-loop saturation replay).
     """
     rng = np.random.default_rng(seed)
     lines = []
@@ -113,8 +113,8 @@ def make_shard(
     """``mixed_lengths`` draws content lengths uniformly from nearly the
     whole range (instead of the [S/2, S) default) — a stand-in for the
     Wikipedia-style length distribution that makes sequence packing
-    (docs/packing.md) worth ~2x, so packing is exercisable in tests and
-    bench.py. ``packed`` additionally packs the generated samples
+    (docs/packing.md) worth ~2x, so packing is exercisable in tests.
+    ``packed`` additionally packs the generated samples
     first-fit-decreasing and writes an OFFLINE-PACKED shard
     (data/packing.py layout) instead of the unpacked one."""
     if packed and legacy:
@@ -220,8 +220,7 @@ def main(argv=None):
                         "online-inference requests (mixed tasks, short-"
                         "biased lengths, Poisson arrivals) plus a "
                         "covering vocab.txt into --output_dir, for "
-                        "BENCH_SERVE and the serving smoke test "
-                        "(docs/serving.md)")
+                        "the serving smoke test (docs/serving.md)")
     p.add_argument("--request_rate", type=float, default=100.0,
                    help="Poisson arrival rate (req/s) for --requests; "
                         "0 = all arrivals at t=0 (saturation replay)")

@@ -177,7 +177,7 @@ class CSVHandler(Handler):
 
 class JSONLHandler(Handler):
     """One JSON object per line — the machine-readable sink the telemetry
-    layer, bench.py, and the NOTES/PARITY tooling parse.
+    layer and the NOTES/PARITY tooling parse.
 
     Every line carries ``schema`` (the telemetry record schema version,
     ``telemetry/schema.py``) and ``ts`` (unix seconds) in addition to the

@@ -105,10 +105,10 @@ def parse_arguments(argv=None) -> argparse.Namespace:
         "--num_workers", type=int, default=0,
         help="DataLoader producer processes (reference run_pretraining.py:"
              "394-395 num_workers=4). 0 = single background thread — KEEP "
-             "THE DEFAULT at BERT shapes: the measured thread path is ~2x "
-             "FASTER than process workers (14.4k vs 7.2k seq/s, "
-             "LOADER_BENCH_r02.jsonl — strided workers re-read every "
-             "shard). >0 pays off only if per-sample featurization grows "
+             "THE DEFAULT at BERT shapes: the thread path reads each "
+             "shard once, where strided process workers each re-read "
+             "every shard (about half the thread path's rate in a CPU "
+             "run). >0 pays off only if per-sample featurization grows "
              "to dominate IO (data/loader.py docstring).")
     # held-out evaluation (beyond the reference, which never evaluates
     # during pretraining; uses pretrain.make_eval_step)
@@ -134,9 +134,9 @@ def parse_arguments(argv=None) -> argparse.Namespace:
                              "from a background thread (the step pays only "
                              "the device-side copy; utils/checkpoint.py), "
                              "'sync' blocks the step for the full "
-                             "fetch+serialize+write — the before/after the "
-                             "BENCH_ASYNC leg and checkpoint-step p95 "
-                             "telemetry compare. Final/emergency "
+                             "fetch+serialize+write — the before/after "
+                             "that the checkpoint-step p95 in the "
+                             "telemetry compares. Final/emergency "
                              "checkpoints are always synchronous")
     parser.add_argument("--checkpoint_layout", type=str, default="gathered",
                         choices=["gathered", "sharded"],

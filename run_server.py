@@ -147,8 +147,8 @@ def parse_arguments(argv=None):
 
 
 def build_service(args):
-    """(service, telemetry_sink) — separated from main() so bench.py and
-    tests can build the serving stack without binding a socket."""
+    """(service, telemetry_sink) — separated from main() so batch_infer
+    and tests can build the serving stack without binding a socket."""
     import jax.numpy as jnp
 
     from bert_pytorch_tpu.config import BertConfig

@@ -4,7 +4,7 @@
 # document-structured corpus — with loss-at-milestone targets stated IN
 # ADVANCE (written to a milestones JSON before the run starts; the final
 # artifact records pass/fail against it). This is the single-chip proxy
-# for BASELINE.md's phase-1+2-to-reference-loss north star; the model's
+# for the phase-1+2-to-reference-loss target (PARITY.md); the model's
 # numerical agreement with the HF torch forward (tests/test_convert.py)
 # anchors the loss scale to an external implementation.
 #

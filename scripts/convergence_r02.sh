@@ -5,8 +5,8 @@
 #   bash scripts/convergence_r02.sh [workdir] [out_csv]
 #
 # Produces <out_csv> with columns optimizer,step,loss,mlm_accuracy,
-# learning_rate — the driver-committable artifact behind BASELINE.md's
-# "reference MLM loss @ step" north star (VERDICT r1 next-step #2).
+# learning_rate — the committable artifact behind PARITY.md's
+# convergence rows (VERDICT r1 next-step #2).
 #
 # Time-boxing: the full phase-1 recipe (gbs 65536, LR 6e-3, 7038 steps)
 # is a multi-day run; this capture keeps the recipe's SHAPE — LAMB +
@@ -31,7 +31,7 @@ LOCAL_BATCH=${CONV_LOCAL_BATCH:-64}
 GLOBAL_BATCH=${CONV_GLOBAL_BATCH:-512}
 LR=${CONV_LR:-5.3e-4}
 # Per-user scratch cache shared by the runner-based capture legs
-# (bench.py itself uses the committed in-repo .jax_cache/ default).
+# (the runners' own default is the checkout's gitignored .jax_cache/).
 CACHE=${BENCH_COMPILE_CACHE_DIR:-${XDG_CACHE_HOME:-$HOME/.cache}/bert_tpu_jax_cache}
 mkdir -p "$W"
 

@@ -23,7 +23,7 @@
 set -euo pipefail
 # Per-user scratch cache for the runner legs (not the world-shared /tmp,
 # where another user could pre-seed entries that JAX deserializes as
-# executables). bench.py itself uses the committed in-repo .jax_cache/.
+# executables). The runners' own default is the checkout's .jax_cache/.
 CACHE=${BENCH_COMPILE_CACHE_DIR:-${XDG_CACHE_HOME:-$HOME/.cache}/bert_tpu_jax_cache}
 cd "$(dirname "$0")/.."
 W=${1:-/tmp/bert_e2e}

@@ -3,10 +3,11 @@
 Covers the two overlapped phases end to end on CPU:
 
 * async checkpointing — per-directory pending-save keying, device-snapshot
-  donation safety, and the acceptance comparison: with a deliberately
-  large injected state, checkpoint-step p95 collapses from a multiple of
-  the steady-state step p95 (blocking writes) to within 20% of it (async
-  writes), gated through the telemetry-report regression path by name;
+  donation safety, and the acceptance comparison: a blocking save writes
+  on the loop's thread before it returns, an async save's write runs on
+  its own thread beside the steps that follow (by count, on the loop's own
+  clock: no host time is asserted), and the loop that waits for its writes
+  is named by the telemetry-report regression path;
 * double-buffered device prefetch — a fast producer drives data_wait p50
   to ~0, a slow producer still attributes the stall to data_wait, and a
   slow staging function reports as the h2d_wait sub-phase (always <= the
@@ -218,73 +219,110 @@ def test_prefetch_propagates_producer_error():
 
 
 # ---------------------------------------------------------------------------
-# acceptance: checkpoint-step p95 collapses under async writes
+# acceptance: an async save's write runs beside the loop, and the report
+# names a loop that waits for its writes
+
+STEP_S, WRITE_S = 0.45, 1.0  # on the loop's own clock below, not the host's
+_ATOMIC_WRITE = ckpt._atomic_write
 
 
-def _ckpt_run(jsonl_path, async_write, state, n_steps=12, step_s=0.45,
+def _ckpt_run(jsonl_path, async_write, state, monkeypatch, n_steps=12,
               every=4):
-    """Paced synthetic training loop with periodic saves of a large
-    state, emitting real step_window records (the bench BENCH_ASYNC leg's
-    shape, through the same StepTimer + ckpt_step accounting)."""
+    """A training loop with periodic saves on a clock of its OWN: a step
+    costs ``STEP_S`` of it and a write made on the loop's thread ``WRITE_S``,
+    a write made on any other thread nothing (and that write is held until
+    ``every - 1`` more steps are done). Emits real step_window records
+    (through the same StepTimer + ckpt_step accounting as the trainer's
+    loop). Returns (the threads that wrote, whether each save's file was
+    there when the save returned, the steps that finished while a write was
+    still to come)."""
     import shutil
     import tempfile
+    import threading
 
+    now = [0.0]
+    loop = threading.current_thread()
+    writers, gates = [], {}  # gates: a checkpoint's path -> its write may go
+    def write(path, blob):
+        writers.append(threading.current_thread().name)
+        if threading.current_thread() is loop:
+            now[0] += WRITE_S
+        else:
+            assert gates[path].wait(60.0)
+        _ATOMIC_WRITE(path, blob)
+
+    monkeypatch.setattr(ckpt, "_atomic_write", write)
     sink = JSONLHandler(jsonl_path, overwrite=False)
-    timer = StepTimer(window=8, sync_every=0)
+    timer = StepTimer(window=8, sync_every=0, clock=lambda: now[0])
     out_dir = tempfile.mkdtemp(prefix="ckpt_accept_")
+    key = ckpt._pending_key(out_dir)
+    written_on_return, overlapped = [], 0
     try:
-        # Un-measured warmup save: first-call effects (allocator growth,
-        # directory creation, thread spawn) must not land in the measured
-        # p95 — with a handful of saves, p95 is the max.
-        warm_dir = tempfile.mkdtemp(prefix="ckpt_accept_warm_")
-        ckpt.save_checkpoint(warm_dir, 0, state, async_write=async_write)
-        ckpt.wait_for_pending_save(warm_dir)
-        shutil.rmtree(warm_dir, ignore_errors=True)
         for step in range(1, n_steps + 1):
             timer.data_start()
             timer.data_end()
-            time.sleep(step_s)
+            now[0] += STEP_S
             timer.dispatch_end()
             rec = timer.step_done(step)
             if rec:
                 sink.write_record(rec)
+            if async_write and not all(g.is_set() for g in gates.values()):
+                assert ckpt._pending_saves[key].is_alive()  # a write is held
+                overlapped += 1
+            if step % every == every - 1:
+                for gate in gates.values():
+                    gate.set()
             if step % every == 0:
-                t0 = time.perf_counter()
-                ckpt.save_checkpoint(out_dir, step, state, keep=2,
-                                     async_write=async_write)
-                timer.note_ckpt_stall(time.perf_counter() - t0)
+                gates[ckpt.checkpoint_path(out_dir, step)] = threading.Event()
+                t0 = now[0]
+                path = ckpt.save_checkpoint(out_dir, step, state, keep=2,
+                                            async_write=async_write)
+                timer.note_ckpt_stall(now[0] - t0)
+                written_on_return.append(os.path.exists(path))
+        for gate in gates.values():
+            gate.set()
         ckpt.wait_for_pending_save(out_dir)
+        assert ckpt.find_resume_step(out_dir, verify=True) == n_steps
         rec = timer.flush(n_steps)
         if rec:
             sink.write_record(rec)
         sink.write_record({"kind": "run_summary", "tag": "telemetry",
                            "step": n_steps, "steps": n_steps})
     finally:
+        for gate in gates.values():
+            gate.set()
         ckpt.wait_for_pending_save()
         shutil.rmtree(out_dir, ignore_errors=True)
         sink.close()
+    return writers, written_on_return, overlapped
 
 
-def test_checkpoint_step_p95_collapses_and_report_gates(tmp_path):
-    """ISSUE 6 acceptance: with async checkpointing and a deliberately
-    large state, checkpoint-step p95 lands within 20% of steady-state p95
-    while blocking writes hold it at >= 2x — and diffing the blocking run
-    against the async baseline trips the telemetry-report regression gate
-    BY NAME (the same path the bench gate uses)."""
-    # ~96 MB of DEVICE state, like a real runner's: the async foreground
-    # cost is the jitted-identity snapshot DISPATCH (enqueued, ms-scale —
-    # the copy itself executes on the backend while the step sleeps),
-    # while a blocking save pays the full device_get + serialize + hash +
-    # write (~7-10 ms/MB on this box) — both ratio thresholds keep a
-    # wide margin.
+def test_async_save_writes_beside_the_loop_and_report_gates(tmp_path,
+                                                            monkeypatch):
+    """ISSUE 6 acceptance, by what the two paths DO and not by the host's
+    clock (how long a stall lasts is a chip cell's to measure): a blocking
+    save writes on the loop's thread and its file is there when it returns;
+    an async save returns with nothing written, its write runs on a
+    ``ckpt-write-*`` thread while three more steps finish, and the
+    checkpoint lands whole. On the loop's own clock the blocking run's
+    checkpoint steps then cost a step and a write, the async run's a step
+    — and diffing the blocking run against the async baseline trips the
+    telemetry-report regression gate BY NAME."""
     import jax.numpy as jnp
 
-    state = {"model": {f"w{i}": jnp.ones((4_000_000,), jnp.float32)
+    state = {"model": {f"w{i}": jnp.ones((1000,), jnp.float32)
                        for i in range(6)}, "epoch": 1}
     sync_jsonl = str(tmp_path / "sync_telemetry.jsonl")
     async_jsonl = str(tmp_path / "async_telemetry.jsonl")
-    _ckpt_run(sync_jsonl, async_write=False, state=state)
-    _ckpt_run(async_jsonl, async_write=True, state=state)
+    writers, written, overlapped = _ckpt_run(
+        sync_jsonl, False, state, monkeypatch)
+    assert set(writers) == {"MainThread"} and len(writers) == 3
+    assert written == [True] * 3 and overlapped == 0
+    writers, written, overlapped = _ckpt_run(
+        async_jsonl, True, state, monkeypatch)
+    assert writers == ["ckpt-write-4", "ckpt-write-8", "ckpt-write-12"]
+    assert written == [False] * 3  # the foreground made no write
+    assert overlapped == 2 * 3     # steps 5-7 and 9-11 ran beside a write
     for path in (sync_jsonl, async_jsonl):
         assert tschema.validate_file(path) == []
 
@@ -294,18 +332,8 @@ def test_checkpoint_step_p95_collapses_and_report_gates(tmp_path):
     sync_ratio, sync_sum = ratios(treport.summarize_file(sync_jsonl))
     async_ratio, async_sum = ratios(treport.summarize_file(async_jsonl))
     assert sync_sum["ckpt_steps"] == async_sum["ckpt_steps"] == 3
-    assert sync_ratio >= 2.0, (sync_sum, "blocking saves should stall")
-    if async_ratio > 1.2:
-        # p95 over 3 saves is the max: one background-load spike on this
-        # throttled 2-core box (another test's teardown, a page-cache
-        # flush) can poison a single snapshot memcpy. Re-measure once —
-        # a real regression (a blocking write on the async path) fails
-        # both times by a wide margin, noise doesn't.
-        async_jsonl = str(tmp_path / "async_retry_telemetry.jsonl")
-        _ckpt_run(async_jsonl, async_write=True, state=state)
-        assert tschema.validate_file(async_jsonl) == []
-        async_ratio, async_sum = ratios(treport.summarize_file(async_jsonl))
-    assert async_ratio <= 1.2, (async_sum, "async saves should overlap")
+    assert sync_ratio == pytest.approx((STEP_S + WRITE_S) / STEP_S, rel=1e-3)
+    assert async_ratio == pytest.approx(1.0, rel=1e-3)
 
     # Injected-regression gating path: blocking run vs async baseline
     # must exit nonzero and NAME the checkpoint-step regression.

@@ -3,70 +3,31 @@
 The TPU's compiler is installed here and compiles for a chip that is
 described and not attached (``jax.experimental.topologies``). These tests
 hand it every Pallas kernel of the main paths at BERT-large head geometry
-(16 heads of 64) and the whole phase-2 train step, and fail on what the
+(16 heads of 64) and at the decoder cells' shapes, and fail on what the
 chip's compiler would refuse: a misaligned tile, a kernel that wants more
-fast memory than it may use, a step that does not fit a 16 GB chip.
-Interpret-mode tests cannot see any of that.
+fast memory than it may use. Interpret-mode tests cannot see any of that.
+The whole train steps (the phase-2 step against a 16 GB chip, the qwen3_next
+cell's, the dp4 step's masks) are in ``tests/test_chip_compile_steps.py``, so
+that the two files run on two workers at once.
 
 Nothing runs — a compile that passes is not a chip run (chip_smoke.py is).
-Skipped where the topology cannot be described. One file and one process on
-purpose: two such compiles in two processes at once fail on libtpu's lock
-file.
+Skipped where the topology cannot be described. Two processes may compile at
+once only where ``ALLOW_MULTIPLE_LIBTPU_LOAD=1`` is set (``described_chip.py``
+sets it; the driver's command does too): without it the second fails on
+libtpu's lock file and skips every test, which is not a pass.
 """
 
-import os
 import re
 
-os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs under /tmp
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
 
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import numpy as np  # noqa: E402
-import pytest  # noqa: E402
-from jax.sharding import SingleDeviceSharding  # noqa: E402
+from described_chip import (TRAIN_SHAPES, _assert_kernel,  # noqa: F401
+                            _compile_for_the_chip, chip, topo)
 
 HEADS, DEPTH, HIDDEN = 16, 64, 1024
-# (seq, batch): the largest single-chip microbatches bench.py documents
-# for the phase-1 and phase-2 shapes.
-TRAIN_SHAPES = {128: 56, 512: 28}
-HBM_BYTES = 16 * 1024 ** 3  # one v5e chip
-
-
-@pytest.fixture(scope="module")
-def topo():
-    """A described host of four v5e chips (2x2)."""
-    from jax.experimental import topologies
-
-    try:
-        return topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2")
-    except Exception as exc:  # no libtpu / no such topology here
-        pytest.skip(f"cannot describe a TPU topology: {exc}")
-
-
-@pytest.fixture(scope="module")
-def chip(topo):
-    """One described v5e chip."""
-    return topo.devices[0]
-
-
-@pytest.fixture(autouse=True)
-def _compile_for_the_chip(monkeypatch):
-    """The kernels ask ``interpret_mode()`` — which sees the CPU backend
-    here — so the tests steer it themselves: compiled, as on the chip. And
-    conftest's fp32 matmul precision is for CPU numerics; the runners leave
-    the default, and Mosaic refuses an fp32-precision matmul of bf16 tiles.
-    (The persistent compile cache is off for the whole suite, conftest.py:
-    an executable compiled for a described chip could be written to it but
-    not read back.)"""
-    from bert_pytorch_tpu.ops.pallas import (attention, common, layernorm,
-                                             selective_scan)
-
-    for module in (common, attention, layernorm, selective_scan):
-        monkeypatch.setattr(module, "interpret_mode", lambda: False)
-    with jax.default_matmul_precision("default"), \
-            jax.default_prng_impl("rbg"):  # the runners' --rng_impl default
-        yield
 
 
 def _compile(fn, chip, *shapes):
@@ -80,18 +41,6 @@ def _compile(fn, chip, *shapes):
 
 def _qkv(batch, seq):
     return [((batch, seq, HEADS, DEPTH), jnp.bfloat16)] * 3
-
-
-def _assert_kernel(compiled, *names):
-    """The program holds a Mosaic kernel, and each ``name=`` its
-    ``pl.pallas_call`` gave reached the custom call's ``op_name`` (what a
-    profiler trace of the chip shows in place of a number XLA chose)."""
-    text = compiled.as_text()
-    assert "tpu_custom_call" in text
-    for name in names:
-        assert re.search(
-            r'custom_call_target="tpu_custom_call"[^\n]*'
-            rf'op_name="[^"]*[/(]{name}[/)]', text), name  # jvp(name) too
 
 
 # -- the training kernel: forward, forward+backward, dropout, packed -------
@@ -212,34 +161,6 @@ def test_delta_rule_mixer_compiles_at_8192(chip):
             assert not re.search(r" = \S+ (copy|transpose)\(", line), line
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 4 * 1024 ** 3
-
-
-def test_qwen3_next_step_compiles_at_the_published_widths(topo, monkeypatch):
-    """The qwen3_next cell's whole train step at its real size (626 M
-    parameters, 4 micro-batches of 2 rows of 8192 tokens, ``--remat full``,
-    AdamW) through the rehearsal's own ``compile_step``: the TPU's compiler
-    takes it within a 16 GB chip WITHOUT rematerializing on its own account
-    (a ``.remat`` fusion is the compiler making room: the rule's rows one at
-    a time, the weights read in column blocks and the rematerialized gated
-    norm are what keep it from having to), and the step holds the three
-    ``flash_gated_*`` kernels and the grouped products. A compile that passes
-    here is not a fit (PERF.md 4): the chip's own compiler has the last word."""
-    import benchmarks.run as bench_run
-    from benchmarks.rehearse.compile_real_laguna import compile_step
-    from bert_pytorch_tpu.ops import moe
-    from bert_pytorch_tpu.ops.pallas import attention, common
-
-    # (the rehearsal sets these for good, for its own process: here they are
-    # put back when the test ends, or every later test of this worker would
-    # compile its kernels for a CPU)
-    for module in (common, attention, moe):
-        monkeypatch.setattr(module, "interpret_mode", lambda: False)
-    ctx = bench_run.context(bench_run.ROOT, "train-qwen3-next-80b-seq8192")
-    step = compile_step(ctx, topo)
-    assert step["parameters"] == 625_994_816
-    assert step["remat_fusions"] == 0
-    assert step["argument_bytes"] == pytest.approx(12 * 625_994_816, rel=1e-3)
-    assert step["tpu_custom_calls"] >= 3 + 3 * 4  # the flash kernels, gmm x 4
 
 
 def test_windowed_flash_attention_compiles_at_8192(chip):
@@ -444,95 +365,3 @@ def test_layer_norm_compiles(chip, direction):
         fn, chip, ((28, 512, HIDDEN), jnp.bfloat16),
         ((HIDDEN,), jnp.float32), ((HIDDEN,), jnp.float32)),
         "layernorm_fwd")
-
-
-# -- the whole phase-2 train step ---------------------------------------------
-
-def _compile_train_step(model, tx, devices, accum, rows, seq, max_pred,
-                        schedule=None):
-    """``pretrain.make_train_step`` as run_pretraining.py builds it, under a
-    ``dp`` mesh of the described ``devices``, lowered for ``accum``
-    micro-batches of ``rows`` sequences and compiled."""
-    from bert_pytorch_tpu import pretrain
-    from bert_pytorch_tpu.parallel import (MeshConfig, create_mesh,
-                                           logical_axis_rules)
-
-    mesh = create_mesh(MeshConfig(data=-1), devices=list(devices))
-    sample = (jnp.zeros((1, seq), jnp.int32),) * 3
-    batch_spec = {"input_ids": 3, "segment_ids": 3, "input_mask": 3,
-                  "masked_lm_labels": 3, "next_sentence_labels": 2}
-    with mesh:
-        shardings = pretrain.state_shardings(
-            mesh, model, logical_axis_rules("dp"), sample)
-        b_shardings = pretrain.batch_shardings(mesh, batch_spec)
-        state = jax.eval_shape(
-            pretrain.make_init_fn(model, tx, sample, shardings),
-            jax.random.PRNGKey(0))
-        step = pretrain.make_train_step(
-            model, tx, schedule=schedule, next_sentence=True,
-            shardings=shardings, batch_shardings_=b_shardings,
-            max_pred_per_seq=max_pred, mesh=mesh)
-        batch = {key: jax.ShapeDtypeStruct(
-            (accum, rows) + (seq,) * (ndim - 2), np.int32)
-            for key, ndim in batch_spec.items()}
-        return step.lower(state, batch).compile()
-
-
-def test_phase2_train_step_fits_one_chip(chip):
-    """``pretrain.make_train_step`` as run_pretraining.py builds it for the
-    phase-2 recipe — BERT-large, seq 512, 80 predictions, local batch 28,
-    ``remat='dots'``, the fused kernel — compiled for one described chip:
-    the kernel is in the program, and arguments plus temporaries stay under
-    the chip's 16 GB. ``memory_analysis`` counts this one program, not what
-    else the process keeps on the device."""
-    from bert_pytorch_tpu import optim
-    from bert_pytorch_tpu.config import BertConfig
-    from bert_pytorch_tpu.models import BertForPreTraining
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    config = BertConfig.from_json_file(
-        os.path.join(repo, "configs", "bert_large_uncased_config.json"))
-    config.vocab_size += -config.vocab_size % 8
-    model = BertForPreTraining(config, dtype=jnp.bfloat16, remat="dots",
-                               attention_backend="pallas")
-    schedule = optim.warmup_poly_schedule(4e-3, 0.128, 1563)
-    tx = optim.lamb(schedule, weight_decay_mask=optim.no_decay_mask)
-    compiled = _compile_train_step(
-        model, tx, [chip], accum=1, rows=TRAIN_SHAPES[512], seq=512,
-        max_pred=80, schedule=schedule)
-    _assert_kernel(compiled, "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
-    mem = compiled.memory_analysis()
-    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
-    assert total < HBM_BYTES, (
-        f"phase-2 step needs {total / 2**30:.2f} GiB "
-        f"({mem.argument_size_in_bytes / 2**30:.2f} arguments + "
-        f"{mem.temp_size_in_bytes / 2**30:.2f} temporaries) of a "
-        f"{HBM_BYTES / 2**30:.0f} GiB chip")
-
-
-# -- the data-parallel step draws each chip's dropout masks on that chip ------
-
-def test_dp4_step_draws_local_masks(topo):
-    """A small ``make_train_step`` with ``rbg`` dropout under ``dp=4`` on the
-    described 2x2, read after the TPU compiler's SPMD partitioner: every
-    ``rng-bit-generator`` makes ONE chip's share of a mask (ops/dropout.py).
-    The partitioner does not split that instruction, so a mask asked for at
-    the global batch size would show here at four times the size."""
-    from bert_pytorch_tpu import optim
-    from bert_pytorch_tpu.config import BertConfig
-    from bert_pytorch_tpu.models import BertForPreTraining
-
-    seq, rows, heads, hidden = 128, 8, 2, 128  # rows a chip
-    config = BertConfig(
-        vocab_size=512, hidden_size=hidden, num_hidden_layers=2,
-        num_attention_heads=heads, intermediate_size=256,
-        max_position_embeddings=seq)
-    model = BertForPreTraining(config, dtype=jnp.bfloat16, remat="dots",
-                               attention_backend="xla")
-    text = _compile_train_step(
-        model, optim.lamb(1e-3), topo.devices, accum=2, rows=rows * 4,
-        seq=seq, max_pred=20).as_text()
-    drawn = {tuple(int(d) for d in dims.split(","))
-             for dims in re.findall(
-                 r"u32\[([0-9,]+)\]\S* rng-bit-generator\(", text)}
-    assert drawn == {(rows, heads, seq, seq), (rows, seq, hidden)}, drawn

@@ -190,3 +190,13 @@ def test_pallas_dropout_on_tpu():
     )
     rel = float(jnp.abs(acc / 32 - base).mean() / jnp.abs(base).mean())
     assert rel < 0.1
+
+
+class TestPallasBhBlock:
+    def test_cap_and_divisibility_walk(self):
+        from bert_pytorch_tpu.ops.pallas.attention import _pick_bh_block
+
+        # the heuristic caps at 16 (the 4096 VMEM budget)
+        assert _pick_bh_block(128, 896) == 16
+        # and the divisibility walk rules: bh % g == 0
+        assert _pick_bh_block(128, 48) == 16

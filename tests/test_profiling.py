@@ -1,13 +1,12 @@
 """Profiling-plane tests (ISSUE 17, docs/observability.md "Profiling
-plane" + docs/telemetry.md "Perf ledger"): the stdlib host thread
+plane"): the stdlib host thread
 sampler (bounded, self-excluding), the arm-at-boundary capture
 controller (idle -> armed -> active -> idle, the double-arm 409 guard),
 the process-wide trace latch, ``POST /profilez`` on BOTH HTTP planes
 (trainer introspection hub + serving replica) with live-server status
-codes, the collector's coordinated fleet-wide trigger, the longitudinal
-perf ledger (append/read/drift direction-awareness, the CLI, the
-telemetry-report "perf ledger drift" gate, ``--format json``), the
-router heartbeat, and the schema fixtures for both new record kinds.
+codes, the collector's coordinated fleet-wide trigger, telemetry-report's
+``--format json``, the router heartbeat, and the schema fixtures for the
+``profile_window`` record kind.
 
 The jax-trace-artifact proof (real ``jax.profiler`` trace directory
 with nonzero bytes) is slow-gated at the bottom."""
@@ -26,7 +25,6 @@ import urllib.request
 import pytest
 
 from bert_pytorch_tpu.telemetry import profiler, schema
-from bert_pytorch_tpu.telemetry import ledger as ledger_mod
 from bert_pytorch_tpu.telemetry.collector import FleetCollector, Target
 from bert_pytorch_tpu.telemetry.introspect import (IntrospectionHub,
                                                    start_debug_server)
@@ -37,7 +35,6 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 REPO_ROOT = os.path.dirname(HERE)
 FIXTURES = os.path.join(HERE, "fixtures", "telemetry")
 REPORT_TOOL = os.path.join(REPO_ROOT, "tools", "telemetry_report.py")
-LEDGER_TOOL = os.path.join(REPO_ROOT, "tools", "perf_ledger.py")
 TOOLS_DIR = os.path.join(REPO_ROOT, "tools")
 
 
@@ -416,119 +413,7 @@ def test_obs_collect_cli_profile_flag(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# telemetry/ledger.py: the longitudinal perf ledger
-
-
-def test_ledger_append_read_roundtrip_and_digest_stability(tmp_path):
-    path = tmp_path / "ledger.jsonl"
-    cfg = {"seq_len": "128", "batch": "256"}
-    a = ledger_mod.append_entry(str(path), "train",
-                                {"step_ms_p50": 41.0, "mfu": 0.38},
-                                config=cfg, ts=1.0)
-    b = ledger_mod.append_entry(str(path), "train",
-                                {"step_ms_p50": 42.0, "mfu": 0.38},
-                                config=dict(cfg), ts=2.0)
-    other = ledger_mod.append_entry(str(path), "train",
-                                    {"step_ms_p50": 39.0},
-                                    config={"seq_len": "512"}, ts=3.0)
-    assert a["config_digest"] == b["config_digest"]
-    assert other["config_digest"] != a["config_digest"]
-    entries = ledger_mod.read_entries(str(path))
-    assert [e["metrics"]["step_ms_p50"] for e in entries] == \
-        [41.0, 42.0, 39.0]
-    assert ledger_mod.read_entries(str(path), leg="serve") == []
-    assert schema.validate_file(str(path)) == []
-    # Non-finite / negative metrics are dropped, never written.
-    bad = ledger_mod.append_entry(str(path), "train",
-                                  {"step_ms_p50": float("nan"),
-                                   "mfu": -0.5}, ts=4.0)
-    assert bad is None
-    assert len(ledger_mod.read_entries(str(path))) == 3
-
-
-def test_ledger_drift_is_direction_aware(tmp_path):
-    path = tmp_path / "ledger.jsonl"
-    for i, p50 in enumerate((40.0, 41.0, 40.0, 39.0)):
-        ledger_mod.append_entry(str(path), "train",
-                                {"step_ms_p50": p50, "mfu": 0.40},
-                                ts=float(i))
-    entries = ledger_mod.read_entries(str(path))
-    assert ledger_mod.check_drift(entries) == []  # steady: clean
-    # Latency UP is drift...
-    ledger_mod.append_entry(str(path), "train",
-                            {"step_ms_p50": 60.0, "mfu": 0.40}, ts=10.0)
-    findings = ledger_mod.check_drift(ledger_mod.read_entries(str(path)))
-    assert [f["metric"] for f in findings] == ["step_ms_p50"]
-    assert findings[0]["change"] > 0.25 and findings[0]["leg"] == "train"
-    # ...latency DOWN is an improvement, not drift.
-    path2 = tmp_path / "faster.jsonl"
-    for i, p50 in enumerate((40.0, 41.0, 40.0, 20.0)):
-        ledger_mod.append_entry(str(path2), "train",
-                                {"step_ms_p50": p50}, ts=float(i))
-    assert ledger_mod.check_drift(
-        ledger_mod.read_entries(str(path2))) == []
-    # mfu is inverted: DOWN is the regression.
-    path3 = tmp_path / "mfu.jsonl"
-    for i, mfu in enumerate((0.40, 0.41, 0.40, 0.20)):
-        ledger_mod.append_entry(str(path3), "train", {"mfu": mfu},
-                                ts=float(i))
-    findings = ledger_mod.check_drift(ledger_mod.read_entries(str(path3)))
-    assert [f["metric"] for f in findings] == ["mfu"]
-
-
-def test_ledger_needs_history_before_gating(tmp_path):
-    path = tmp_path / "ledger.jsonl"
-    for i, p50 in enumerate((40.0, 80.0, 160.0)):  # wild, but < min history
-        ledger_mod.append_entry(str(path), "train",
-                                {"step_ms_p50": p50}, ts=float(i))
-    assert ledger_mod.check_drift(ledger_mod.read_entries(str(path))) == []
-
-
-def test_ledger_metrics_from_summary_maps_and_scales():
-    metrics = ledger_mod.metrics_from_summary(
-        {"step_p50_s": 0.1, "step_p95_s": 0.15, "mfu": 0.4,
-         "serve_latency_p99_ms": 33.0, "steps": 30,
-         "name": "run", "peak_bytes_in_use": None})
-    assert metrics == {"step_ms_p50": pytest.approx(100.0),
-                       "step_ms_p95": pytest.approx(150.0),
-                       "mfu": pytest.approx(0.4),
-                       "serve_p99_ms": pytest.approx(33.0)}
-
-
-def test_perf_ledger_cli_show_append_check(tmp_path):
-    path = str(tmp_path / "ledger.jsonl")
-
-    def run(*args):
-        return subprocess.run(
-            [sys.executable, LEDGER_TOOL, *args],
-            capture_output=True, text=True, timeout=60, cwd=TOOLS_DIR)
-
-    for p50 in ("41.0", "40.5", "41.2", "40.8"):
-        proc = run("append", path, "--leg", "train",
-                   "--metric", f"step_ms_p50={p50}",
-                   "--config", "seq_len=128")
-        assert proc.returncode == 0, proc.stderr
-        assert "appended train" in proc.stdout
-    proc = run("check", path)
-    assert proc.returncode == 0 and "no drift" in proc.stdout
-    proc = run("show", path, "--leg", "train")
-    assert proc.returncode == 0 and "step_ms_p50=41" in proc.stdout
-    # Doctor one slow entry onto the trajectory: named drift, exit 1.
-    proc = run("append", path, "--leg", "train",
-               "--metric", "step_ms_p50=70.0", "--config", "seq_len=128")
-    assert proc.returncode == 0
-    proc = run("check", path)
-    assert proc.returncode == 1
-    assert "REGRESSION perf ledger drift: train/step_ms_p50" in proc.stdout
-    # Bad input is 2, not a traceback.
-    proc = run("append", path, "--leg", "train", "--metric", "nonsense")
-    assert proc.returncode == 2
-    proc = run("check", str(tmp_path / "missing.jsonl"))
-    assert proc.returncode == 2
-
-
-# ---------------------------------------------------------------------------
-# telemetry-report: the "perf ledger drift" gate + --format json
+# telemetry-report: --format json and the profile section
 
 
 def _window(step, p50, mfu=0.4):
@@ -562,38 +447,11 @@ def _report(*args):
         capture_output=True, text=True, timeout=60, cwd=TOOLS_DIR)
 
 
-def test_report_ledger_gate_names_drift_and_self_diffs_green(tmp_path):
-    """The acceptance property: a clean trajectory stays green run after
-    run; ONE doctored slow entry makes the report exit 1 naming 'perf
-    ledger drift'."""
-    clean = _run_artifact(tmp_path / "clean.jsonl", p50=0.1)
-    slow = _run_artifact(tmp_path / "slow.jsonl", p50=0.14)
-    ledger = str(tmp_path / "ledger.jsonl")
-    for _ in range(4):
-        proc = _report(clean, "--ledger", ledger)
-        assert proc.returncode == 0, (proc.stdout, proc.stderr)
-        assert "perf ledger" in proc.stdout
-    assert len(ledger_mod.read_entries(ledger)) == 4
-    assert schema.validate_file(ledger) == []
-    proc = _report(slow, "--ledger", ledger)
-    assert proc.returncode == 1, (proc.stdout, proc.stderr)
-    assert "REGRESSION perf ledger drift" in proc.stdout
-    assert "step_ms_p50" in proc.stdout
-    # Bare drift check (no run artifact): same verdict off the ledger.
-    proc = _report("--ledger", ledger)
-    assert proc.returncode == 1
-    assert "perf ledger drift" in proc.stdout
-    # The doctored entry is history now; do NOT append the probe run.
-    proc = _report(clean, "--ledger", ledger, "--no-ledger-append")
-    assert len(ledger_mod.read_entries(ledger)) == 5
-
-
 def test_report_format_json_stable_contract(tmp_path):
     """--format json prints the check_all contract: one versioned object
     with rc both inside and as the exit code."""
     clean = _run_artifact(tmp_path / "clean.jsonl", p50=0.1)
-    ledger = str(tmp_path / "ledger.jsonl")
-    proc = _report(clean, "--ledger", ledger, "--format", "json")
+    proc = _report(clean, "--format", "json")
     assert proc.returncode == 0, (proc.stdout, proc.stderr)
     obj = json.loads(proc.stdout)
     assert obj["version"] == 1
@@ -601,16 +459,14 @@ def test_report_format_json_stable_contract(tmp_path):
     assert obj["verdict"] == "ok"
     assert obj["regressions"] == []
     assert isinstance(obj["checks"], list)
-    assert obj["ledger"]["entries"] >= 1
-    # Drift flows into the same shape with rc=1.
-    for _ in range(3):
-        _report(clean, "--ledger", ledger)
+    assert obj["run"]["step_p50_s"] == 0.1
+    # A regression against a baseline flows into the same shape with rc=1.
     slow = _run_artifact(tmp_path / "slow.jsonl", p50=0.14)
-    proc = _report(slow, "--ledger", ledger, "--format", "json")
+    proc = _report(slow, clean, "--format", "json")
     obj = json.loads(proc.stdout)
     assert proc.returncode == 1 and obj["rc"] == 1
-    assert any(r["label"] == "perf ledger drift"
-               for r in obj["regressions"])
+    assert any(r["label"] == "step-time p50" for r in obj["regressions"])
+    assert obj["baseline"]["step_p50_s"] == 0.1
 
 
 def test_report_profile_section_joins_host_and_device(tmp_path):
@@ -653,7 +509,7 @@ def test_report_profile_section_joins_host_and_device(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# schema fixtures for both new kinds
+# schema fixtures for the profile_window kind
 
 
 def test_profile_window_fixtures_lint_as_expected():
@@ -675,26 +531,6 @@ def test_profile_window_fixtures_lint_as_expected():
     assert proc.returncode == 1
     assert "profile_window_good.jsonl: ok" in proc.stdout
     assert "trigger must be one of" in proc.stdout
-
-
-def test_ledger_fixtures_lint_as_expected():
-    good = os.path.join(FIXTURES, "ledger_good.jsonl")
-    bad = os.path.join(FIXTURES, "ledger_bad.jsonl")
-    assert schema.validate_file(good) == []
-    errors = schema.validate_file(bad)
-    assert len(errors) >= 7
-    text = " ".join(err for _, err in errors)
-    assert "leg must be a non-empty string" in text
-    assert "percentiles must be ordered" in text
-    assert "ratio in [0, 1]" in text
-    assert "metrics must be a non-empty object" in text
-    proc = subprocess.run(
-        [sys.executable,
-         os.path.join(TOOLS_DIR, "check_telemetry_schema.py"), good, bad],
-        capture_output=True, text=True, timeout=60)
-    assert proc.returncode == 1
-    assert "ledger_good.jsonl: ok" in proc.stdout
-    assert "percentiles must be ordered" in proc.stdout
 
 
 # ---------------------------------------------------------------------------
@@ -729,40 +565,6 @@ def test_router_writes_resumable_heartbeat_with_routed_requests(tmp_path):
     router2.stop()
     payload = Heartbeat.read(str(hb))
     assert payload["counter"] == 3 and payload["step"] == 0
-
-
-# ---------------------------------------------------------------------------
-# bench.py: automatic ledger append (jax-free parent path)
-
-
-def test_bench_append_ledger_maps_result_keys(tmp_path, monkeypatch):
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "_bench_under_test", os.path.join(REPO_ROOT, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    path = str(tmp_path / "ledger.jsonl")
-    monkeypatch.setattr(bench, "LEDGER_PATH", path)
-    bench._append_ledger({"metric": "serve_p99_latency_ms", "value": 30.0,
-                          "latency_p50_ms": 12.0, "latency_p99_ms": 30.0,
-                          "cold_start_s": 2.5})
-    entries = ledger_mod.read_entries(path)
-    assert len(entries) == 1
-    entry = entries[0]
-    assert entry["leg"] == "train"  # no serve/kernels env flags set
-    assert entry["metrics"]["serve_p50_ms"] == 12.0
-    assert entry["metrics"]["serve_p99_ms"] == 30.0
-    assert entry["metrics"]["cold_start_s"] == 2.5
-    assert entry["metrics"]["headline"] == 30.0
-    assert entry["config_digest"] == bench._config_digest()
-    assert entry["metric"] == "serve_p99_latency_ms"  # extras merge flat
-    assert schema.validate_file(path) == []
-    # Error results and a disabled ledger never append.
-    bench._append_ledger({"error": "no backend"})
-    monkeypatch.setattr(bench, "LEDGER_PATH", "")
-    bench._append_ledger({"value": 1.0})
-    assert len(ledger_mod.read_entries(path)) == 1
 
 
 # ---------------------------------------------------------------------------

@@ -199,9 +199,13 @@ def test_a_first_call_says_where_its_time_went(cadence_run):
     assert compiled["trace_s"] > 0 and compiled["lower_s"] > 0
     assert compiled["backend_compile_s"] > 0
     assert compiled["cache_load_s"] == 0    # the suite's cache is off
-    # every part lies inside the call
-    assert (compiled["trace_s"] + compiled["lower_s"]
-            + compiled["backend_compile_s"]) <= compiled["compile_s"] + 1e-3
+    # every part lies inside the call. Each on its own: the parts are not
+    # disjoint (a small program compiled while the step is traced counts
+    # under trace_s and under backend_compile_s), so their sum may pass the
+    # whole by as much as the scheduler gives that compile; 2e-4 is the two
+    # roundings to four places.
+    for part in ("trace_s", "lower_s", "backend_compile_s"):
+        assert compiled[part] <= compiled["compile_s"] + 2e-4, part
 
 
 def test_the_cost_record_carries_the_time_of_its_analysis(cadence_run):

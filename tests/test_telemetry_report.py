@@ -187,51 +187,3 @@ def test_cli_tolerance_knobs(tmp_path):
     mild = _artifact(tmp_path / "mild.jsonl", p50=0.115)  # +15%
     assert report.main([mild, base]) == 1                 # default 10%
     assert report.main([mild, base, "--step-tol", "0.2"]) == 0
-
-
-def test_bench_attach_regression_gate(tmp_path, monkeypatch):
-    """bench.py's parent attaches the report verdict to its result JSON
-    when a committed baseline exists — and never fails the bench."""
-    import bench
-
-    base = _artifact(tmp_path / "base.jsonl", p50=0.1)
-    slow = _artifact(tmp_path / "slow.jsonl", p50=0.2)
-    monkeypatch.setattr(bench, "TELEMETRY_JSONL", slow)
-    monkeypatch.setattr(bench, "TELEMETRY_BASELINE", base)
-    result = bench._attach_regression({"metric": "m", "value": 1.0})
-    assert result["regression"]["verdict"] == "regression"
-    assert "step_p50_s" in [
-        r["metric"] for r in result["regression"]["regressions"]]
-    assert result["regression"]["baseline"] == "base.jsonl"
-    # clean pair: verdict ok, still attached for the artifact trail
-    monkeypatch.setattr(
-        bench, "TELEMETRY_JSONL", _artifact(tmp_path / "same.jsonl", p50=0.1))
-    assert bench._attach_regression({})["regression"]["verdict"] == "ok"
-    # no baseline on disk: result passes through untouched
-    monkeypatch.setattr(
-        bench, "TELEMETRY_BASELINE", str(tmp_path / "absent.jsonl"))
-    assert "regression" not in bench._attach_regression({"metric": "m"})
-
-
-def test_bench_gate_refuses_mismatched_configs(tmp_path, monkeypatch):
-    """Different bench legs (phase2, seq2048, degraded) share the default
-    baseline path; the gate must refuse to diff incomparable configs
-    instead of flagging a bogus regression."""
-    import bench
-
-    def _stamped(path, metric, p50):
-        art = _artifact(tmp_path / path, p50=p50)
-        with open(art, "a") as f:
-            f.write(json.dumps({
-                "schema": 1, "ts": 0.0, "kind": "run_summary", "tag":
-                "telemetry", "step": 30, "steps": 30, "metric": metric,
-            }) + "\n")
-        return art
-
-    base = _stamped("base.jsonl", "bert_large_phase1_seq_per_sec", 0.1)
-    other = _stamped("other.jsonl", "bert_large_phase2_seq_per_sec", 0.5)
-    monkeypatch.setattr(bench, "TELEMETRY_JSONL", other)
-    monkeypatch.setattr(bench, "TELEMETRY_BASELINE", base)
-    verdict = bench._attach_regression({})["regression"]
-    assert verdict["verdict"] == "n/a"
-    assert "not comparable" in verdict["note"]

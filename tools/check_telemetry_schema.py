@@ -59,10 +59,7 @@ and its host-frame table must be internally consistent — every frame a
 positive sample count bounded by the capture's total, shares in (0, 1]
 summing to no more than 1 (a frame over the total would mean two
 captures folded together — the double-arm race the 409 guard
-prevents); a ``ledger_entry`` (telemetry/ledger.py, the longitudinal
-perf ledger) must name its leg and config digest and carry a non-empty
-metrics object of non-negative numbers with ordered percentiles and
-ratio metrics (mfu/padding_efficiency) in [0, 1]. The deployment-plane
+prevents). The deployment-plane
 kinds (docs/serving.md "Model registry & canary rollouts") carry
 theirs: a ``registry_event`` must name its version, a non-empty event,
 and a legal lifecycle state (staged/canary/live/retired), with

@@ -7,7 +7,7 @@ equal STEPS and — when the CSV carries samples_per_second — at equal
 WALLCLOCK. Both comparisons matter: the reference wires K-FAC for
 quality-per-step (run_pretraining.py:320-355), but the preconditioner
 only pays for itself if the per-step cost doesn't erase the advantage in
-wall-clock terms (BASELINE.md north star is loss @ step).
+wall-clock terms (PARITY.md compares loss @ step).
 
   python tools/summarize_convergence.py CONVERGENCE_r03.csv
 """

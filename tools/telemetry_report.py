@@ -11,18 +11,13 @@ Usage::
 
     python tools/telemetry_report.py RUN.jsonl              # summary
     python tools/telemetry_report.py RUN.jsonl BASE.jsonl   # diff + verdict
-    python tools/telemetry_report.py RUN.jsonl --ledger my_ledger.jsonl
-    python tools/telemetry_report.py --ledger my_ledger.jsonl  # drift only
 
 Exit 0 = no regression, 1 = regression (named in the output),
 2 = missing file. ``--format json`` prints one stable versioned object
 (``{"version": 1, ..., "rc": N}`` — the tools/check_all.py contract);
-``--json`` is the legacy machine shape bench.py parses. ``--ledger``
-appends the run to the longitudinal perf ledger and gates its rolling
-median — "perf ledger drift" by name (telemetry/ledger.py,
-docs/telemetry.md). Tolerance knobs: ``--step-tol --p95-tol --mfu-tol
---mem-tol --grad-tol --ledger-tol`` (docs/telemetry.md has a worked
-example).
+``--json`` is the legacy machine shape. Tolerance knobs: ``--step-tol
+--p95-tol --mfu-tol --mem-tol --grad-tol`` (docs/telemetry.md has a
+worked example).
 """
 
 from __future__ import annotations
