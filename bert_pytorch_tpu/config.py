@@ -682,9 +682,121 @@ class Qwen3NextConfig:
         return 16
 
 
+class KeyeVLConfig:
+    """Configuration of the ``KeyeVL2`` family's language model: a pre-norm
+    residual decoder whose every layer is SPARSE softmax attention, then a
+    routed expert layer without a shared expert. Attention
+    (``num_attention_heads`` query heads on ``num_key_value_heads`` heads of
+    ``head_dim``, q and k normed a head at a time, rotary on the whole head)
+    runs, for each query, over the ``sa_config["topk"]`` keys that a learned
+    indexer (``indexer_num_heads`` heads of ``indexer_head_dim`` against
+    ``indexer_num_kv_heads`` = 1 key head, a weight a head and token) scores
+    highest among the causal ones; the indexer learns from a KL term of its
+    own that the model returns beside its counters
+    (``models/keye_vl.py``, ``ops/sparse_attention.py``). Keys and defaults
+    are those of the published ``config.json``'s language model
+    (Kwai-Keye/Keye-VL-2.0-30B-A3B; ``text_config`` and ``sa_config`` may be
+    given nested as there, or their keys at the top level); the vision tower
+    is not built; extra keys ride along as on :class:`BertConfig`.
+
+    The chip's share is stated here, as :class:`LagunaConfig` states it:
+    ``num_experts`` experts are HELD of ``num_experts * ep_size``, ``ep_rank``
+    says which; the router keeps its published width. Heads are whole.
+    """
+
+    model_type = "KeyeVL2"
+
+    def __init__(self, **values: Any):
+        defaults = dict(
+            vocab_size=151936, hidden_size=2048, intermediate_size=6144,
+            num_hidden_layers=48, num_attention_heads=32,
+            num_key_value_heads=4, head_dim=128, hidden_act="silu",
+            rope_theta=10000000, rope_scaling=None, rms_norm_eps=1e-6,
+            decoder_sparse_step=1, mlp_only_layers=[], num_experts=128,
+            ep_size=1, ep_rank=0, num_experts_per_tok=8,
+            moe_intermediate_size=768, norm_topk_prob=True,
+            tie_word_embeddings=False, use_sliding_window=False,
+            attention_bias=False, initializer_range=0.02,
+            max_position_embeddings=262144,
+            sa_config=dict(indexer_num_heads=16, indexer_head_dim=64,
+                           indexer_num_kv_heads=1, topk=2048,
+                           q_chunk_size=512, kv_chunk_size=512),
+            index_loss_coef=1.0)
+        nested = dict(values.pop("text_config", None) or {})
+        for key, value in {**defaults, **nested, **values}.items():
+            setattr(self, key, value)
+        self.sa_config = {**defaults["sa_config"], **(self.sa_config or {})}
+        if (self.decoder_sparse_step != 1 or self.mlp_only_layers
+                or self.tie_word_embeddings or self.use_sliding_window
+                or self.attention_bias or self.hidden_act != "silu"):
+            raise ValueError(
+                "KeyeVL2 is built with an expert layer in every layer, an "
+                "untied head, silu experts, no bias and no sliding window")
+        scaling = self.rope_scaling or {}
+        if scaling.get("rope_type", scaling.get("type", "default")) not in (
+                "default", "mrope"):
+            raise ValueError(
+                "KeyeVL2 is built with the default rotary table (on text "
+                "rows the three position streams of a multi-axis rotary "
+                f"are equal and it IS that table): {scaling}")
+        heads, kv = self.num_attention_heads, self.num_key_value_heads
+        if heads % kv or self.head_dim % 2 or self.indexer_head_dim % 2:
+            raise ValueError(
+                f"{heads} query heads on {kv} key-value heads of "
+                f"{self.head_dim}; indexer heads of {self.indexer_head_dim}")
+        if self.sa_config["indexer_num_kv_heads"] != 1 or self.topk < 1:
+            raise ValueError(
+                "the indexer is built with one key head and a positive "
+                f"topk: {self.sa_config}")
+        if not 0 <= self.ep_rank < self.ep_size:
+            raise ValueError(f"ep_rank {self.ep_rank} of ep_size {self.ep_size}")
+
+    @classmethod
+    def from_dict(cls, json_object: dict) -> "KeyeVLConfig":
+        values = {k: v for k, v in json_object.items() if k != "model_type"}
+        return cls(**values)
+
+    def to_dict(self) -> dict:
+        return dict(copy.deepcopy(self.__dict__), model_type=self.model_type)
+
+    @property
+    def indexer_num_heads(self) -> int:
+        return int(self.sa_config["indexer_num_heads"])
+
+    @property
+    def indexer_head_dim(self) -> int:
+        return int(self.sa_config["indexer_head_dim"])
+
+    @property
+    def topk(self) -> int:
+        """Keys a query attends to (all its causal keys where fewer)."""
+        return int(self.sa_config["topk"])
+
+    @property
+    def router_experts(self) -> int:
+        """Every expert of the layer, held or not."""
+        return self.num_experts * self.ep_size
+
+    @property
+    def first_expert(self) -> int:
+        return self.ep_rank * self.num_experts
+
+    def rope_of(self, width: int) -> tuple:
+        """(rotary dimensions, a ``rope_parameters``-style entry) for a head
+        of ``width`` turned whole (the core's 128, the indexer's 64)."""
+        return (width, {"rope_theta": self.rope_theta, "rope_type": "default"})
+
+    @property
+    def init_sample_length(self) -> int:
+        """Positions of the sample that initializes the parameters (none
+        depends on the length)."""
+        return 16
+
+
 MODEL_FAMILIES = {"bert": BertConfig, "nemotron_h": NemotronHConfig,
                   "laguna": LagunaConfig, "phi4flash": PhiFlashConfig,
-                  "zaya": ZayaConfig, "qwen3_next": Qwen3NextConfig}
+                  "zaya": ZayaConfig, "qwen3_next": Qwen3NextConfig,
+                  "KeyeVL2": KeyeVLConfig}
 
 
 def load_model_config(json_file: str):

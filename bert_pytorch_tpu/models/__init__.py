@@ -52,6 +52,7 @@ from bert_pytorch_tpu.models.losses import (
 from bert_pytorch_tpu.models.laguna import LagunaForCausalLM
 from bert_pytorch_tpu.models.nemotron_h import NemotronHForCausalLM
 from bert_pytorch_tpu.models.phi4flash import PhiFlashForCausalLM
+from bert_pytorch_tpu.models.keye_vl import KeyeVLForCausalLM
 from bert_pytorch_tpu.models.qwen3_next import Qwen3NextForCausalLM
 from bert_pytorch_tpu.models.zaya import ZayaForCausalLM
 
@@ -62,15 +63,17 @@ def build_pretraining_model(config, dtype, remat: str = "none",
     (``config.load_model_config`` chose the class from the file's
     ``model_type``). The model's ``objective`` attribute names what
     ``pretrain.make_train_step`` trains it on."""
-    from bert_pytorch_tpu.config import (BertConfig, LagunaConfig,
-                                         NemotronHConfig, PhiFlashConfig,
-                                         Qwen3NextConfig, ZayaConfig)
+    from bert_pytorch_tpu.config import (BertConfig, KeyeVLConfig,
+                                         LagunaConfig, NemotronHConfig,
+                                         PhiFlashConfig, Qwen3NextConfig,
+                                         ZayaConfig)
 
     for family, model in ((NemotronHConfig, NemotronHForCausalLM),
                           (LagunaConfig, LagunaForCausalLM),
                           (PhiFlashConfig, PhiFlashForCausalLM),
                           (ZayaConfig, ZayaForCausalLM),
                           (Qwen3NextConfig, Qwen3NextForCausalLM),
+                          (KeyeVLConfig, KeyeVLForCausalLM),
                           (BertConfig, BertForPreTraining)):
         if isinstance(config, family):
             return model(config, dtype=dtype, remat=remat,
@@ -79,6 +82,7 @@ def build_pretraining_model(config, dtype, remat: str = "none",
 
 
 __all__ = [
+    "KeyeVLForCausalLM",
     "LagunaForCausalLM",
     "NemotronHForCausalLM",
     "PhiFlashForCausalLM",

@@ -1,6 +1,6 @@
 """What the decoder families share (``models/nemotron_h.py``,
 ``models/laguna.py``, ``models/phi4flash.py``, ``models/zaya.py``,
-``models/qwen3_next.py``): RMSNorm,
+``models/qwen3_next.py``, ``models/keye_vl.py``): RMSNorm,
 LayerNorm, the bias-free projection, the routed expert layer with or without
 a shared expert and with a router of its own or one handed in, and the
 wrapper round a stack of unlike layers: embedding, ``layers_0 ..
@@ -214,6 +214,13 @@ class CausalDecoder(nn.Module):
 
     def norm_epsilon(self) -> float:
         raise NotImplementedError
+
+    def objective_terms(self) -> dict:
+        """{counter: coefficient}: what the model returns beside its counters
+        that belongs to the OBJECTIVE (``pretrain._apply_causal_lm_loss``
+        adds each, times its coefficient, to the next-token loss:
+        ``models/keye_vl.py``, the indexer's KL). None for most families."""
+        return {}
 
     def shared_inputs(self, seq: int) -> tuple:
         """What every layer of one call over ``seq`` positions reads and none

@@ -15,9 +15,16 @@ again. So the ops name them where they are made
     ([B*H, 1, S], fp32), the residuals its backward kernels read.
     Recomputed, the forward kernel runs a second time to reproduce them.
 
-The names do nothing under ``remat='none'`` (no policy), under
-``remat='full'`` (nothing is kept: the user asked for least memory) and where
-no gradient is taken. ``remat_policy`` is the ONE place that builds the
+  - ``DSA_CHOICE`` (ops/sparse_attention.py, kernel path): the keys each
+    query's indexer chose, one BIT a query-key pair ([B, S, 512] int32 for
+    rows of up to 16,384: 33.5 MB a layer and row there). Recomputed, every
+    layer scores all its causal pairs and makes its exact choice a second
+    time. Kept under ``remat='full'`` too: it is an eighth of what the layer's
+    input costs, and the choice is the dearest thing in the layer by the byte.
+
+The other names do nothing under ``remat='none'`` (no policy), under
+``remat='full'`` (nothing else is kept: the user asked for least memory) and
+where no gradient is taken. ``remat_policy`` is the ONE place that builds the
 policy: the scanned encoder (models/bert.py) and the pipeline's stages
 (pretrain.py) both call it.
 """
@@ -30,14 +37,15 @@ import numpy as np
 KEEP_MASK = "attention_dropout_keep"
 FLASH_OUT = "flash_out"
 FLASH_LSE = "flash_lse"
-KEPT_NAMES = (KEEP_MASK, FLASH_OUT, FLASH_LSE)
+DSA_CHOICE = "dsa_choice"
+KEPT_NAMES = (KEEP_MASK, FLASH_OUT, FLASH_LSE, DSA_CHOICE)
 
 def remat_policy(remat: str):
     """The ``jax.checkpoint`` policy for a ``remat`` value; None for 'none'."""
     if remat == "none":
         return None
     if remat == "full":
-        return jax.checkpoint_policies.nothing_saveable
+        return jax.checkpoint_policies.save_only_these_names(DSA_CHOICE)
     if remat == "dots":
         return jax.checkpoint_policies.save_from_both_policies(
             jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,

@@ -82,6 +82,14 @@ QWEN3_NEXT_SCOPES = (
     "moe_experts", "moe_combine", "moe_shared", "moe_shared_gate", "lm_head",
     "lm_loss")
 
+# ... and those of the KeyeVL2 family's step (models/keye_vl.py: attention
+# over the keys an indexer chooses, under ``dsa``, and the indexer's KL).
+KEYE_SCOPES = (
+    "attn_qkv", "attn_qk_norm", "attn_rope", "attn_out", "dsa",
+    "dsa_index_proj", "dsa_scores", "dsa_select", "dsa_core",
+    "dsa_index_loss", "moe", "moe_route", "moe_dispatch", "moe_experts",
+    "moe_combine", "lm_head", "lm_loss")
+
 # Rows longer than this many positions take the output head and its loss in
 # pieces of this length (models/losses.py chunked_next_token_loss).
 LM_HEAD_PIECE = 2048
@@ -196,8 +204,10 @@ def _apply_pretraining_loss(model, variables, mb, rng, next_sentence,
 def _apply_causal_lm_loss(model, variables, mb):
     """The ``causal_lm`` objective's counterpart of
     :func:`_apply_pretraining_loss`: rows of token ids in, next-token loss
-    out. Returns (loss, aux); ``aux`` holds the token accuracy and the
-    model's routing counters, one scalar each per micro-batch."""
+    out, plus what the model names as terms of its objective among its
+    counters (``CausalDecoder.objective_terms``: none for most families).
+    Returns (loss, aux); ``aux`` holds the token accuracy and the model's
+    counters, one scalar each per micro-batch."""
     ids = mb["input_ids"]
     pieces, ragged = divmod(ids.shape[-1], LM_HEAD_PIECE)
     if ragged or pieces < 2:
@@ -208,6 +218,8 @@ def _apply_causal_lm_loss(model, variables, mb):
                                        method="hidden_states")
         loss, accuracy = chunked_next_token_loss(
             hidden, model.head_kernel(variables["params"]), ids, pieces)
+    for name, coefficient in model.objective_terms().items():
+        loss = loss + coefficient * counters[name]
     return loss, {"token_accuracy": accuracy, **counters}
 
 

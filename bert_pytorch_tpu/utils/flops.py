@@ -278,6 +278,38 @@ def qwen3_next_forward_flops_per_token(config, seq_len: int) -> dict:
     return dict(parts, head=2.0 * h * config.vocab_size)
 
 
+def keye_vl_forward_flops_per_token(config, seq_len: int) -> dict:
+    """Forward matmul FLOPs per token of a ``KeyeVL2`` model on THIS chip (the
+    experts and vocabulary rows it holds), by part: ``attention_proj`` (q, k,
+    v, o), ``indexer_proj`` (qI, kI, w), ``indexer_scores`` (every CAUSAL
+    pair of the row, ``indexer_num_heads x indexer_head_dim`` a pair),
+    ``sparse_core`` (the two products over the CHOSEN pairs, ``min(t + 1,
+    topk)`` a query: the model's pairs, whatever tiles a kernel runs),
+    ``experts`` (router and three products by the EXPECTED top_k x held /
+    experts of the tokens; no shared expert), ``head``. Lookup, norms,
+    rotary, the choice, the second scoring the indexer's KL makes,
+    activations and the optimizer are left out."""
+    h, hd = config.hidden_size, config.head_dim
+    heads, kv = config.num_attention_heads, config.num_key_value_heads
+    index_w = config.indexer_num_heads * config.indexer_head_dim
+    full = min(seq_len, config.topk)
+    chosen = full * (full + 1) / 2 + (seq_len - full) * config.topk
+    layer = {
+        "attention_proj": 4 * h * heads * hd + 4 * h * kv * hd,
+        "indexer_proj": 2 * h * (index_w + config.indexer_head_dim
+                                 + config.indexer_num_heads),
+        "indexer_scores": 2 * index_w * (seq_len + 1) / 2,
+        "sparse_core": 4 * heads * hd * chosen / seq_len,
+        "experts": (2.0 * h * config.router_experts
+                    + config.num_experts_per_tok * config.num_experts
+                    / config.router_experts
+                    * 6 * h * config.moe_intermediate_size),
+    }
+    return dict({k: 1.0 * config.num_hidden_layers * v
+                 for k, v in layer.items()},
+                head=2.0 * h * config.vocab_size)
+
+
 def causal_lm_train_flops_per_seq(config, seq_len: int) -> float:
     """Training (3x forward) matmul FLOPs of one row of ``seq_len`` tokens of
     a ``causal_lm`` family's model (by the config's ``model_type``)."""
@@ -286,6 +318,7 @@ def causal_lm_train_flops_per_seq(config, seq_len: int) -> float:
                  "phi4flash": phi_flash_forward_flops_per_token,
                  "zaya": zaya_forward_flops_per_token,
                  "qwen3_next": qwen3_next_forward_flops_per_token,
+                 "KeyeVL2": keye_vl_forward_flops_per_token,
                  }[config.model_type]
     return 3.0 * seq_len * sum(per_token(config, seq_len).values())
 

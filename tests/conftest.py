@@ -79,6 +79,14 @@ def persistent_cache(tmp_path):
     # jax latches cache-enablement at the first compile of the process;
     # the reset makes it read the config again.
     compilation_cache.reset_cache()
+    # What this process traced before is forgotten too: a library function
+    # an earlier test of this worker already traced (``lax.top_k``,
+    # ``jnp.cumsum`` ...) is otherwise lowered from that trace, and the
+    # module a fresh process lowers for the same program then has another
+    # key (tests/test_inference_fastpath.py's second process missed the cache
+    # after tests/test_keye_vl.py's tests of the exact choice: which worker
+    # runs which file after which is xdist's choice).
+    jax.clear_caches()
     yield cache_dir
     jax.config.update("jax_enable_compilation_cache", False)
     for key, value in before.items():

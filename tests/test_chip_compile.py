@@ -255,6 +255,8 @@ def test_ssd_scan_compiles_at_8192(chip):
     pytest.param(6144, 384, 1856, 2688, id="hybrid-down"),  # k 14.5 lane tiles
     pytest.param(10240, 320, 2048, 1024, id="qwen-up"),
     pytest.param(10240, 320, 512, 2048, id="qwen-down"),
+    pytest.param(8192, 1024, 2048, 1536, id="keye-up"),
+    pytest.param(8192, 1024, 768, 2048, id="keye-down"),
 ])
 def test_grouped_products_compile_at_the_cells_shapes(chip, monkeypatch, rows,
                                                       group, k, n):
@@ -277,6 +279,45 @@ def test_grouped_products_compile_at_the_cells_shapes(chip, monkeypatch, rows,
         ((8,), jnp.int32), ((rows, n), jnp.bfloat16))
     _assert_kernel(compiled, "gmm", "tgmm")
     assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_sparse_attention_compiles_at_16384(chip):
+    """The KeyeVL2 cell's attention layer whole at its geometry (one row of
+    16,384 tokens, 32 / 4 heads of 128 over the 2048 keys a 16 x 64 indexer
+    chooses, beside a stream of 2048), forward and backward with the
+    indexer's KL in the objective: every scope of the layer, and under ``dsa``
+    the five Pallas kernels of ``ops/pallas/sparse_attention.py`` (Mosaic
+    takes the [32, 128, 512] int32 scratch of ordered scores, the bit-plane
+    shifts, K and V of a whole row and all 32 heads of a query block in
+    VMEM under the raised limit); no [S, S] tensor in HBM but the bits:
+    the layer's temporaries stay under 2 GB."""
+    from bert_pytorch_tpu.config import KeyeVLConfig
+    from bert_pytorch_tpu.models import keye_vl
+
+    layer = keye_vl.SparseAttention(KeyeVLConfig(), jnp.bfloat16, "pallas")
+    params = jax.eval_shape(lambda: layer.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 512, 2048), jnp.bfloat16)))
+    sharding = SingleDeviceSharding(chip)
+    place = lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                              sharding=sharding)
+
+    def loss(variables, x):
+        out, counters = layer.apply(variables, x)
+        return jnp.sum(jnp.square(out.astype(jnp.float32))) + counters[
+            "dsa_index_kl"]
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        jax.tree_util.tree_map(place, params),
+        place(jax.ShapeDtypeStruct((1, 16384, 2048), jnp.bfloat16))).compile()
+    names = set(re.findall(r'op_name="([^"]+)"', compiled.as_text()))
+    for scope in ("attn_qkv", "attn_qk_norm", "attn_rope", "attn_out"):
+        assert any(f"/{scope}/" in name for name in names), scope
+    for scope in ("dsa_index_proj", "dsa_select", "dsa_core", "dsa_index_loss"):
+        assert any(f"/dsa/{scope}/" in name for name in names), scope
+    _assert_kernel(compiled, "dsa_select", "dsa_core_fwd", "dsa_core_bwd_dq",
+                   "dsa_core_bwd_dkv", "dsa_index_loss")
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 2 * 1024 ** 3
 
 
 @pytest.mark.parametrize("heads,rotary_dim", [(24, 64), (36, 128)])

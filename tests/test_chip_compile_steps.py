@@ -1,6 +1,7 @@
 """Whole train steps compiled for the chip without the chip: the phase-2
-step against a 16 GB chip, the qwen3_next cell's step at its published widths
-(150 s of compiling, the longest test of the suite), and the dp4 step's
+step against a 16 GB chip, the qwen3_next and the KeyeVL2 cells' steps at
+their published widths (150 s of compiling each, the longest tests of the
+suite), and the dp4 step's
 dropout masks after the SPMD partitioner. Apart from
 ``tests/test_chip_compile.py`` (the kernels) so that xdist's ``--dist
 loadfile`` runs the two on two workers; ``described_chip.py`` says what lets
@@ -44,6 +45,31 @@ def test_qwen3_next_step_compiles_at_the_published_widths(topo, monkeypatch):
     assert step["remat_fusions"] == 0
     assert step["argument_bytes"] == pytest.approx(12 * 625_994_816, rel=1e-3)
     assert step["tpu_custom_calls"] >= 3 + 3 * 4  # the flash kernels, gmm x 4
+
+
+def test_keye_vl_step_compiles_at_the_published_widths(topo, monkeypatch):
+    """The KeyeVL2 cell's whole train step at its real size (610 M
+    parameters, 2 micro-batches of 1 row of 16,384 tokens, ``--remat full``,
+    AdamW) through the rehearsal's own ``compile_step``: the TPU's compiler
+    takes it within a 16 GB chip without rematerializing on its own account,
+    and the step holds the sparse attention's kernels (the choice once a
+    layer and micro-batch: it is kept across remat; the core's forward and
+    the objective's kernel twice) and the grouped products."""
+    import benchmarks.run as bench_run
+    from benchmarks.rehearse.compile_real_laguna import compile_step
+    from bert_pytorch_tpu.ops import moe
+    from bert_pytorch_tpu.ops.pallas import attention, common
+
+    for module in (common, attention, moe):
+        monkeypatch.setattr(module, "interpret_mode", lambda: False)
+    ctx = bench_run.context(bench_run.ROOT, "train-keye-vl2-30b-seq16384")
+    step = compile_step(ctx, topo)
+    assert step["parameters"] == 610_476_288
+    assert step["remat_fusions"] == 0
+    assert step["argument_bytes"] == pytest.approx(12 * 610_476_288, rel=1e-3)
+    # a layer: the choice, the core's forward twice and its two backward
+    # kernels, the objective's twice, the rotary turns, the grouped products
+    assert step["tpu_custom_calls"] >= 9 * (1 + 2 + 2 + 2)
 
 
 # -- the whole phase-2 train step ---------------------------------------------

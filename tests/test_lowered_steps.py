@@ -46,6 +46,9 @@ PATHS = {
                     '"flash_gated_fwd"', '"flash_gated_bwd_dq"', "@tgmm"),
                    ('"flash_fwd"',)),
     "bert_phase1": (("rng_bit_generator",), ("flash_fwd", "tpu_custom_call")),
+    "KeyeVL2": (('"dsa_select"', '"dsa_core_fwd"', '"dsa_core_bwd_dq"',
+                 '"dsa_core_bwd_dkv"', '"dsa_index_loss"', "@tgmm"),
+                ('"flash_fwd"', "flash_gated")),
     "kernel_bidirectional_dropout": (
         ("name=flash_fwd", "name=flash_bwd_dq", "name=flash_bwd_dkv",
          "prng_seed"), ("flash_window",)),
@@ -63,7 +66,7 @@ PATHS = {
 # family -> the tuple of pretrain that names its step's scopes
 SCOPES = {"nemotron_h": "CAUSAL_LM_SCOPES", "laguna": "LAGUNA_SCOPES",
           "phi4flash": "PHI_FLASH_SCOPES", "zaya": "ZAYA_SCOPES",
-          "qwen3_next": "QWEN3_NEXT_SCOPES"}
+          "qwen3_next": "QWEN3_NEXT_SCOPES", "KeyeVL2": "KEYE_SCOPES"}
 
 
 @pytest.fixture(scope="module")

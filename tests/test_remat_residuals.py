@@ -161,8 +161,14 @@ def test_one_function_builds_the_policy_for_both_sites():
     assert bert.remat_policy is remat.remat_policy
     assert pretrain.remat_policy is remat.remat_policy
     assert remat.remat_policy("none") is None
-    assert (remat.remat_policy("full")
-            is jax.checkpoint_policies.nothing_saveable)
+    # 'full' keeps nothing but the sparse attention's choice, one bit a pair
+    from jax._src.ad_checkpoint import name_p
+
+    full = remat.remat_policy("full")
+    assert full(name_p, name=remat.DSA_CHOICE)
+    assert not any(full(name_p, name=name) for name in (
+        remat.KEEP_MASK, remat.FLASH_OUT, remat.FLASH_LSE))
+    assert not full(jax.lax.dot_general_p) and not full(jax.lax.exp_p)
     with pytest.raises(ValueError, match="none|dots|full"):
         remat.remat_policy("some")
 

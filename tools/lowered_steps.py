@@ -19,9 +19,11 @@ attention, the scan's kernels unrolled by the interpreter), ``bert_phase2.txt``
 (BERT with the flash kernel and its in-kernel dropout, ``--remat dots``),
 ``qwen3_next.txt`` (a period of the family, three delta-rule layers to one of
 gated attention, at the smallest shapes its kernels take: the rule's pair, the
-element-wise pairs round it and the flash kernels at a head of 256) and
+element-wise pairs round it and the flash kernels at a head of 256),
 ``bert_phase1.txt`` (BERT at seq 128 with XLA attention, ``--remat dots``,
-LAMB, dropout drawn by ``rbg``: the phase-1 cells' path):
+LAMB, dropout drawn by ``rbg``: the phase-1 cells' path) and ``KeyeVL2.txt``
+(two layers of attention over the keys an indexer chooses, at the smallest
+shapes its kernels take: the choice's, the core's three and the objective's):
 ``make_train_step(...).trace(...).lower(lowering_platforms=("tpu",))`` as
 text, with the Mosaic payloads (the serialized kernels, which hold the
 checkout's path and line numbers) and the source locations cut out; and
@@ -88,6 +90,15 @@ SIZES = {
         linear_num_key_heads=2, linear_num_value_heads=4, num_experts=4,
         ep_size=4, ep_rank=1, num_experts_per_tok=3,
         moe_intermediate_size=128, shared_expert_intermediate_size=128),
+    # heads of 128 over rows of 512: the least that
+    # ops/pallas/sparse_attention.py fits take (one bit plane of keys)
+    "KeyeVL2": dict(
+        vocab_size=256, hidden_size=128, num_hidden_layers=2,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=128,
+        num_experts=4, ep_size=4, ep_rank=1, num_experts_per_tok=3,
+        moe_intermediate_size=128,
+        sa_config=dict(indexer_num_heads=4, indexer_head_dim=64,
+                       indexer_num_kv_heads=1, topk=128)),
     "bert": dict(
         vocab_size=512, hidden_size=128, num_hidden_layers=2,
         num_attention_heads=2, intermediate_size=256,
@@ -206,6 +217,7 @@ def steps():
         ("kernel_gated_norm", gated_norm_jaxpr),
         ("qwen3_next", lambda: decoder_step("qwen3_next")),
         ("bert_phase1", lambda: bert_step(128, 20, "xla", lamb=True)),
+        ("KeyeVL2", lambda: decoder_step("KeyeVL2")),
     )
 
 
