@@ -35,8 +35,12 @@ this and the XLA form's [B, S, S] booleans.
   keys (scored again, a tile at a time; their log-sum-exp in a first walk of
   the tiles), and in the same walk its gradient to qI, kI and w: the KL's
   inputs from the core are constants, so its whole backward is known in the
-  forward. The differentiated call keeps the three gradients and scales them
-  by the cotangent; the plain call (the forward pass under remat) skips them.
+  forward. Under a gradient the forward pass runs the forward RULE (also
+  inside ``jax.checkpoint``, whose recompute runs it again): it keeps the
+  three gradients, named ``ops/remat.py DSA_INDEX_GRADS`` so that a remat
+  policy which keeps the name does not run the kernel a second time, and the
+  backward rule scales them by the cotangent. The plain call, which skips
+  them, is what evaluation runs.
 
 Operands in the activations' dtype, sums, softmaxes and the KL in float32, as
 the flash kernels of ``ops/pallas/attention.py``.
@@ -50,10 +54,12 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from bert_pytorch_tpu.ops.pallas import common
+from bert_pytorch_tpu.ops.remat import DSA_INDEX_GRADS
 
 WORD_LANES = 512          # keys a bit plane covers: the kernels' key tile
 WORD_BITS = 32
@@ -535,8 +541,11 @@ def _index_loss(qi, ki, w, q, k, lse, words):
 
 def _index_loss_fwd(qi, ki, w, q, k, lse, words):
     kl, dqi, dki, dw = _index_loss_call(qi, ki, w, q, k, lse, words, True)
-    return jnp.sum(kl), (dqi.astype(qi.dtype), dki.astype(ki.dtype), dw,
-                         q, k, lse, words)
+    # kept across remat by name (ops/remat.py): the recompute would run the
+    # kernel a second time to make them again
+    grads = tuple(checkpoint_name(t, DSA_INDEX_GRADS) for t in (
+        dqi.astype(qi.dtype), dki.astype(ki.dtype), dw))
+    return jnp.sum(kl), grads + (q, k, lse, words)
 
 
 def _index_loss_bwd(residuals, g):

@@ -21,12 +21,26 @@ again. So the ops name them where they are made
     layer scores all its causal pairs and makes its exact choice a second
     time. Kept under ``remat='full'`` too: it is an eighth of what the layer's
     input costs, and the choice is the dearest thing in the layer by the byte.
+  - ``DSA_INDEX_GRADS`` (ops/pallas/sparse_attention.py): the gradients of the
+    indexer's KL to qI ([B, J, S, E]), kI ([B, S, E]), both in the
+    activations' dtype, and w ([B, J, S] float32), which the objective's
+    kernel makes in the same walk as the KL's value (its inputs from the core
+    are constants, so its backward is known in the forward): 36.7 MB a layer
+    and row of 16,384 in bfloat16 at 16 indexer heads of 64. Recomputed, the
+    forward rule of the kernel's ``custom_vjp`` runs a second time inside the
+    layer's recompute, the with-gradients kernel whole (the heads'
+    probabilities rebuilt, the indexer's pairs scored three times), to make
+    again what the forward pass threw away. Kept under ``remat='full'``
+    too, as the choice is: the dearest thing left in the layer to make again
+    by the byte.
 
-The other names do nothing under ``remat='none'`` (no policy), under
-``remat='full'`` (nothing else is kept: the user asked for least memory) and
-where no gradient is taken. ``remat_policy`` is the ONE place that builds the
-policy: the scanned encoder (models/bert.py) and the pipeline's stages
-(pretrain.py) both call it.
+``remat='full'`` keeps these two names of the sparse attention's and nothing
+else: the user asked for least memory. The other names do nothing there, none
+does anything under ``remat='none'`` (no policy) or where no gradient is
+taken, and a name that no tensor of a program carries changes nothing in that
+program. ``remat_policy`` is the ONE place that builds the policy: the
+scanned encoder (models/bert.py), the decoders' layers (models/decoder.py)
+and the pipeline's stages (pretrain.py) all call it.
 """
 
 from __future__ import annotations
@@ -38,14 +52,16 @@ KEEP_MASK = "attention_dropout_keep"
 FLASH_OUT = "flash_out"
 FLASH_LSE = "flash_lse"
 DSA_CHOICE = "dsa_choice"
-KEPT_NAMES = (KEEP_MASK, FLASH_OUT, FLASH_LSE, DSA_CHOICE)
+DSA_INDEX_GRADS = "dsa_index_grads"
+KEPT_UNDER_FULL = (DSA_CHOICE, DSA_INDEX_GRADS)
+KEPT_NAMES = (KEEP_MASK, FLASH_OUT, FLASH_LSE) + KEPT_UNDER_FULL
 
 def remat_policy(remat: str):
     """The ``jax.checkpoint`` policy for a ``remat`` value; None for 'none'."""
     if remat == "none":
         return None
     if remat == "full":
-        return jax.checkpoint_policies.save_only_these_names(DSA_CHOICE)
+        return jax.checkpoint_policies.save_only_these_names(*KEPT_UNDER_FULL)
     if remat == "dots":
         return jax.checkpoint_policies.save_from_both_policies(
             jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,

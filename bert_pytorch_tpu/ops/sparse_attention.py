@@ -39,7 +39,8 @@ second time); the core's forward and two backward kernels take a tile's mask
 from those bits and run the dense tiles up to the diagonal (a gather of
 ``topk`` rows a query is the vector unit's slow operation: PERF.md 6); the
 objective's kernel rebuilds scores and probabilities a tile at a time and
-returns the KL with its whole backward.
+returns the KL with its whole backward (three gradients, kept across remat
+by name too, ``DSA_INDEX_GRADS``: the kernel runs once a gradient step).
 
 Scopes: ``dsa_scores`` and ``dsa_select`` (the choice), ``dsa_core``,
 ``dsa_index_loss``; the caller wraps them in ``dsa``.

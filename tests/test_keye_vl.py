@@ -31,6 +31,7 @@ from bert_pytorch_tpu.config import KeyeVLConfig, load_model_config
 from bert_pytorch_tpu.models import build_pretraining_model, keye_vl
 from bert_pytorch_tpu.ops import sparse_attention as sparse
 from bert_pytorch_tpu.ops.pallas import sparse_attention as kernels
+from bert_pytorch_tpu.ops.remat import remat_policy
 from bert_pytorch_tpu.utils import flops
 
 # the published layer at a small size: 4 / 2 heads of 16 over the 8 keys a
@@ -209,6 +210,62 @@ def test_the_objectives_kernel_matches_the_xla_form_with_its_backward(
     through = jax.grad(lambda q, k, lse: kernels.index_loss(
         qi, ki, w, q, k, lse, words), argnums=(0, 1, 2))(q, k, lse)
     assert all(not np.asarray(t).any() for t in through)
+
+
+def _layer_grad(kernel_case, remat):
+    """The gradient to qi, ki and w of a sparse-attention layer (the kernels)
+    under ``jax.checkpoint`` with ``remat``'s policy."""
+    q, k, v, *_ = kernel_case
+
+    def layer(qi, ki, w):
+        ctx, kl, _, _ = sparse.sparse_attention(q, k, v, qi, ki, w, 300,
+                                                backend="pallas")
+        return jnp.sum(ctx * jnp.cos(ctx)) + kl
+
+    policy = remat_policy(remat)
+    if policy is not None:
+        layer = jax.checkpoint(layer, policy=policy)
+    return jax.grad(layer, argnums=(0, 1, 2))
+
+
+@pytest.fixture(scope="module")
+def grads_without_remat(kernel_case):
+    return jax.jit(_layer_grad(kernel_case, "none"))(*kernel_case[3:6])
+
+
+@pytest.mark.parametrize("remat", ["full", "dots", "none"])
+def test_the_objectives_kernel_runs_once_under_a_gradient_whatever_the_remat(
+        remat, kernel_case, grads_without_remat):
+    """Under ``jax.grad`` of a ``jax.checkpoint`` the forward pass runs the
+    objective's forward RULE, and the recompute would run it again: the three
+    gradients it makes are kept by name (``ops/remat.py DSA_INDEX_GRADS``), so
+    the gradient program holds ONE ``dsa_index_loss`` call (four outputs)
+    under 'full' and 'dots' as it does without remat, and the gradients to
+    qi, ki and w are those of ``remat='none'`` bit for bit (in float32, as
+    here, the casts of the kept gradients are identities; in bfloat16 on the
+    chip a kept gradient is rounded once more than one whose cast fuses with
+    the scaling by the cotangent: PERF.md 6, "PR 46")."""
+    qi, ki, w = kernel_case[3:6]
+    grad = _layer_grad(kernel_case, remat)
+
+    def calls(jaxpr, found):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append((eqn.params["name"], len(eqn.outvars)))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                calls(sub, found)
+        return found
+
+    made = calls(jax.make_jaxpr(grad)(qi, ki, w).jaxpr, [])
+    assert [c for c in made if c[0] == "dsa_index_loss"] == [
+        ("dsa_index_loss", 4)], made
+    # (the core's forward kernel IS run again: its output is not kept)
+    assert made.count(("dsa_core_fwd", 2)) == (1 if remat == "none" else 2)
+    assert made.count(("dsa_select", 1)) == 1
+    if remat != "none":
+        for got, want in zip(jax.jit(grad)(qi, ki, w), grads_without_remat):
+            assert np.asarray(want).any()
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_kernels_take_the_published_shapes_and_refuse_others():
@@ -620,8 +677,9 @@ def test_the_sparse_attentions_parts_lie_under_dsa(step_names):
 def test_at_fitting_shapes_the_scopes_hold_the_kernels_and_the_choice_is_kept():
     """With heads of 128 over rows of 512 the compiled step's ``dsa_select``,
     ``dsa_core`` and ``dsa_index_loss`` scopes hold the kernels' calls; the
-    choice is made in the forward pass alone (kept across remat by name), the
-    core's forward kernel runs again in the recompute."""
+    choice and the objective's gradients are made in the forward pass alone
+    (kept across remat by name), the core's forward kernel runs again in the
+    recompute."""
     fitting = dict(TINY, num_hidden_layers=1, head_dim=128,
                    num_attention_heads=2, num_key_value_heads=1,
                    sa_config=dict(TINY["sa_config"], topk=64))
@@ -634,8 +692,10 @@ def test_at_fitting_shapes_the_scopes_hold_the_kernels_and_the_choice_is_kept():
                           ("dsa_core", "dsa_core_bwd_dkv"),
                           ("dsa_index_loss", "dsa_index_loss")):
         assert under(scope, kernel), (scope, kernel)
-    assert not any("rematted_computation" in n
-                   for n in under("dsa_select", "dsa_select"))
+    for scope, kernel in (("dsa_select", "dsa_select"),
+                          ("dsa_index_loss", "dsa_index_loss")):
+        assert not any("rematted_computation" in n
+                       for n in under(scope, kernel)), kernel
     assert any("rematted_computation" in n
                for n in under("dsa_core", "dsa_core_fwd"))
 

@@ -107,6 +107,38 @@ def test_a_written_file_compares_across_checkouts_and_names_its_path(
     assert [n for n in lacks if n in text] == []
 
 
+def _kernel_call_sites(text, kernel):
+    """How often the step's text runs ``kernel``: a jitted entry point is ONE
+    private function that holds the custom call, called from each place the
+    trace reached it."""
+    holders, inside = set(), None
+    for line in text.splitlines():
+        header = re.match(r"\s*func\.func (?:private |public )?@([\w.]+)\(",
+                          line)
+        if header:
+            inside = header.group(1)
+        elif f'kernel_name = "{kernel}"' in line:
+            holders.add(inside)
+    return sum(len(re.findall(rf"call @{re.escape(name)}\(", text))
+               for name in holders)
+
+
+def test_the_objectives_kernel_is_called_once_a_layer_and_micro_batch_trip(
+        runs):
+    """``KeyeVL2.txt`` is two layers under ``--remat full`` with the
+    micro-batches as ONE loop: the choice's and the objective's kernels each
+    run once a layer there (what they make is kept across remat by name,
+    ``ops/remat.py``), the core's forward kernel twice (its output is made
+    again in the recompute)."""
+    with open(os.path.join(runs[0], "KeyeVL2.txt")) as f:
+        text = f.read()
+    layers = tool.SIZES["KeyeVL2"]["num_hidden_layers"]
+    assert _kernel_call_sites(text, "dsa_index_loss") == layers
+    assert _kernel_call_sites(text, "dsa_select") == layers
+    assert _kernel_call_sites(text, "dsa_core_fwd") == 2 * layers
+    assert _kernel_call_sites(text, "dsa_core_bwd_dq") == layers
+
+
 def test_a_second_run_writes_the_same_bytes(runs):
     assert sorted(os.listdir(runs[0])) == sorted(os.listdir(runs[1])) == sorted(
         name + ".txt" for name in FILES)

@@ -142,9 +142,10 @@ def test_the_kept_mask_is_one_byte_an_element():
 
 @pytest.mark.parametrize("path", sorted(PATHS))
 def test_full_still_keeps_nothing(path):
-    """The names add nothing to 'full': its policy is the parent's own
-    (asserted below), the costly op runs twice, and only the layer's inputs
-    cross the scan."""
+    """Attention's three names add nothing to 'full' (its policy keeps two
+    names, both the sparse attention's: asserted below, and no tensor of an
+    encoder carries either): the costly op runs twice, and only the layer's
+    inputs cross the scan."""
     _, _, costly, _ = PATHS[path]
     _, program = _grad_program("full", path, with_grads=False)
     assert _ops(program)[costly] == 2
@@ -161,13 +162,19 @@ def test_one_function_builds_the_policy_for_both_sites():
     assert bert.remat_policy is remat.remat_policy
     assert pretrain.remat_policy is remat.remat_policy
     assert remat.remat_policy("none") is None
-    # 'full' keeps nothing but the sparse attention's choice, one bit a pair
+    # 'full' keeps two names and nothing else, both the sparse attention's:
+    # the choice (one bit a pair) and the gradients of the indexer's KL
     from jax._src.ad_checkpoint import name_p
 
     full = remat.remat_policy("full")
-    assert full(name_p, name=remat.DSA_CHOICE)
+    assert remat.KEPT_UNDER_FULL == (remat.DSA_CHOICE, remat.DSA_INDEX_GRADS)
+    assert all(full(name_p, name=name) for name in remat.KEPT_UNDER_FULL)
     assert not any(full(name_p, name=name) for name in (
-        remat.KEEP_MASK, remat.FLASH_OUT, remat.FLASH_LSE))
+        remat.KEEP_MASK, remat.FLASH_OUT, remat.FLASH_LSE, "some_other_name"))
+    dots = remat.remat_policy("dots")
+    assert set(remat.KEPT_UNDER_FULL) < set(remat.KEPT_NAMES)
+    assert all(dots(name_p, name=name) for name in remat.KEPT_NAMES)
+    assert not dots(name_p, name="some_other_name")
     assert not full(jax.lax.dot_general_p) and not full(jax.lax.exp_p)
     with pytest.raises(ValueError, match="none|dots|full"):
         remat.remat_policy("some")
