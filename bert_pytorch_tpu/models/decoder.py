@@ -209,7 +209,8 @@ class CausalDecoder(nn.Module):
     CARRIES = False
 
     def blocks(self, wrap) -> list:
-        """The layers, in order; ``wrap`` rematerializes a block class."""
+        """The layers, in order; ``wrap`` rematerializes a block class, under
+        the policy less the names in its ``without`` (``ops/remat.py``)."""
         raise NotImplementedError
 
     def norm_epsilon(self) -> float:
@@ -233,10 +234,13 @@ class CausalDecoder(nn.Module):
         self.embedding = self.param(
             "embedding", normal(cfg.initializer_range),
             (cfg.vocab_size, cfg.hidden_size), jnp.float32)
-        policy = remat_policy(self.remat)
-        self.layers = self.blocks(
-            (lambda block: block) if policy is None else
-            (lambda block: nn.remat(block, policy=policy, prevent_cse=True)))
+
+        def wrap(block, without=()):
+            policy = remat_policy(self.remat, without)
+            return block if policy is None else nn.remat(
+                block, policy=policy, prevent_cse=True)
+
+        self.layers = self.blocks(wrap)
         self.final_norm = self.NORM(self.norm_epsilon(), self.dtype)
         if not self.TIED_HEAD:
             self.lm_head = dense(cfg.vocab_size, cfg.initializer_range,

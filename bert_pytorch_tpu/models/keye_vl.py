@@ -66,6 +66,7 @@ from bert_pytorch_tpu.models.decoder import (MOE_COUNTERS, CausalDecoder,
                                              ExpertLayer, RMSNorm, dense)
 from bert_pytorch_tpu.ops import rope
 from bert_pytorch_tpu.ops.attention import resolve_backend
+from bert_pytorch_tpu.ops.remat import DSA_CORE_LSE, DSA_CORE_OUT
 from bert_pytorch_tpu.ops.sparse_attention import sparse_attention
 
 Dtype = Any
@@ -175,15 +176,34 @@ class KeyeBlock(nn.Module):
         return x + out, {**counters, **chosen}
 
 
+# How many layers keep the sparse core's output and log-sum-exps across remat
+# (ops/remat.py DSA_CORE_OUT, DSA_CORE_LSE), by what ONE chip holds at the
+# published widths on a micro-batch of one row of 16,384 tokens (PERF.md 6,
+# "PR 47"): a layer's two tensors are 32 x 16,384 x 128 bfloat16 + 32 x 16,384
+# float32 = 136.3 MB, and the step's temporaries rose by 158 MB a layer that
+# keeps them (1,106 MB for seven). With none kept 1,194 MB of the chip's
+# 16,909 MB are free: seven layers leave 90 MB, and with eight or nine the
+# chip's compiler makes room by rematerializing on its own account. A model
+# of fewer layers keeps them in all.
+CORE_KEPT_LAYERS = 7
+
+
 class KeyeVLForCausalLM(CausalDecoder):
     config: KeyeVLConfig
 
     COUNTERS = COUNTERS
 
     def blocks(self, wrap):
-        block = wrap(KeyeBlock)
+        """The last ``CORE_KEPT_LAYERS`` layers keep the core's output and
+        log-sum-exps across remat, the layers before them make them again:
+        the backward pass comes to the last layers first, so what they keep
+        is gone soonest."""
+        layers = self.config.num_hidden_layers
+        again = max(0, layers - CORE_KEPT_LAYERS)
+        classes = ([wrap(KeyeBlock, without=(DSA_CORE_OUT, DSA_CORE_LSE))]
+                   * again + [wrap(KeyeBlock)] * (layers - again))
         return [block(self.config, self.dtype, self.attention_backend)
-                for _ in range(self.config.num_hidden_layers)]
+                for block in classes]
 
     def norm_epsilon(self):
         return self.config.rms_norm_eps

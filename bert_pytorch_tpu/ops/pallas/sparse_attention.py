@@ -28,7 +28,11 @@ this and the XLA form's [B, S, S] booleans.
   blocks from the diagonal down for one key tile of 512, summing over the
   group's heads in float32 scratch. The dense tiles up to the diagonal are
   run and masked: the model's work is the chosen pairs, a quarter of that at
-  16,384 (``benchmarks/trace/flops_keye.py``).
+  16,384 (``benchmarks/trace/flops_keye.py``). The forward RULE names the
+  forward kernel's output and log-sum-exps, the residuals the two backward
+  kernels read (``ops/remat.py DSA_CORE_OUT``, ``DSA_CORE_LSE``), so under a
+  remat policy that keeps the names the recompute does not run the forward
+  kernel again: once a gradient step.
 * ``dsa_index_loss`` (``index_loss``): a token's KL from the core's
   probabilities summed over the heads (rebuilt from q, k and the log-sum-exps
   a tile at a time) to the softmax of the indexer's scores over the chosen
@@ -59,7 +63,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from bert_pytorch_tpu.ops.pallas import common
-from bert_pytorch_tpu.ops.remat import DSA_INDEX_GRADS
+from bert_pytorch_tpu.ops.remat import (DSA_CORE_LSE, DSA_CORE_OUT,
+                                        DSA_INDEX_GRADS)
 
 WORD_LANES = 512          # keys a bit plane covers: the kernels' key tile
 WORD_BITS = 32
@@ -358,6 +363,12 @@ def _core(q4, k3, v3, words, scale):
 
 def _core_fwd(q4, k3, v3, words, scale):
     out, lse = _core_forward(q4, k3, v3, words, scale)
+    # Named here, in the forward RULE and in the kernel's own layout: the
+    # named tensors ARE the residuals the backward rule reads, so a policy
+    # that keeps the names (ops/remat.py) leaves the recompute's forward
+    # kernel without a reader and it is not run a second time.
+    out = checkpoint_name(out, DSA_CORE_OUT)
+    lse = checkpoint_name(lse, DSA_CORE_LSE)
     return (out, lse), (q4, k3, v3, words, out, lse)
 
 

@@ -34,7 +34,21 @@ again. So the ops name them where they are made
     too, as the choice is: the dearest thing left in the layer to make again
     by the byte.
 
-``remat='full'`` keeps these two names of the sparse attention's and nothing
+  - ``DSA_CORE_OUT``, ``DSA_CORE_LSE`` (ops/pallas/sparse_attention.py): the
+    output of the core's forward kernel ([B * KV, G, S, D], activation dtype)
+    and each head's log-sum-exp over its chosen keys ([B * KV, G, 1, S]
+    float32), in the kernel's own layout: the residuals its two backward
+    kernels read (the output for ``delta``). 134.2 MB + 2.1 MB a layer and
+    row of 16,384 in bfloat16 at 32 heads of 128. Recomputed, the forward
+    kernel runs a second time inside the layer's recompute (every causal
+    tile up to the diagonal, a quarter of the core's time) only to hand the
+    backward rule what the forward pass threw away. Kept under
+    ``remat='full'`` too: the largest thing left in that recompute. They are
+    also the largest thing kept, so the model says in how many of its layers
+    (models/keye_vl.py ``CORE_KEPT_LAYERS``) and builds the other layers'
+    policy ``without`` the two names.
+
+``remat='full'`` keeps these four names of the sparse attention's and nothing
 else: the user asked for least memory. The other names do nothing there, none
 does anything under ``remat='none'`` (no policy) or where no gradient is
 taken, and a name that no tensor of a program carries changes nothing in that
@@ -53,19 +67,28 @@ FLASH_OUT = "flash_out"
 FLASH_LSE = "flash_lse"
 DSA_CHOICE = "dsa_choice"
 DSA_INDEX_GRADS = "dsa_index_grads"
-KEPT_UNDER_FULL = (DSA_CHOICE, DSA_INDEX_GRADS)
+DSA_CORE_OUT = "dsa_core_out"
+DSA_CORE_LSE = "dsa_core_lse"
+KEPT_UNDER_FULL = (DSA_CHOICE, DSA_INDEX_GRADS, DSA_CORE_OUT, DSA_CORE_LSE)
 KEPT_NAMES = (KEEP_MASK, FLASH_OUT, FLASH_LSE) + KEPT_UNDER_FULL
 
-def remat_policy(remat: str):
-    """The ``jax.checkpoint`` policy for a ``remat`` value; None for 'none'."""
+def remat_policy(remat: str, without: tuple = ()):
+    """The ``jax.checkpoint`` policy for a ``remat`` value; None for 'none'.
+    ``without``: names of ``KEPT_NAMES`` that this policy does not keep (a
+    model whose layers cannot all afford a name: models/keye_vl.py)."""
+    unknown = set(without) - set(KEPT_NAMES)
+    if unknown:
+        raise ValueError(f"no kept name {sorted(unknown)}: {KEPT_NAMES}")
+    kept = lambda names: [name for name in names if name not in without]
     if remat == "none":
         return None
     if remat == "full":
-        return jax.checkpoint_policies.save_only_these_names(*KEPT_UNDER_FULL)
+        return jax.checkpoint_policies.save_only_these_names(
+            *kept(KEPT_UNDER_FULL))
     if remat == "dots":
         return jax.checkpoint_policies.save_from_both_policies(
             jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
-            jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES))
+            jax.checkpoint_policies.save_only_these_names(*kept(KEPT_NAMES)))
     raise ValueError(f"remat must be none|dots|full, got {remat!r}")
 
 

@@ -37,7 +37,11 @@ as one bit a pair (kept across remat by name, ``ops/remat.py DSA_CHOICE``:
 33.5 MB a layer and row of 16,384, so the backward pass does not choose a
 second time); the core's forward and two backward kernels take a tile's mask
 from those bits and run the dense tiles up to the diagonal (a gather of
-``topk`` rows a query is the vector unit's slow operation: PERF.md 6); the
+``topk`` rows a query is the vector unit's slow operation: PERF.md 6), and
+the forward kernel's output and log-sum-exps, which the backward kernels
+read, are kept across remat by name as well (``DSA_CORE_OUT``,
+``DSA_CORE_LSE``: the forward kernel runs once a gradient step in the layers
+whose policy keeps them, models/keye_vl.py ``CORE_KEPT_LAYERS``); the
 objective's kernel rebuilds scores and probabilities a tile at a time and
 returns the KL with its whole backward (three gradients, kept across remat
 by name too, ``DSA_INDEX_GRADS``: the kernel runs once a gradient step).

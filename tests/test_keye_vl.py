@@ -213,11 +213,9 @@ def test_the_objectives_kernel_matches_the_xla_form_with_its_backward(
 
 
 def _layer_grad(kernel_case, remat):
-    """The gradient to qi, ki and w of a sparse-attention layer (the kernels)
-    under ``jax.checkpoint`` with ``remat``'s policy."""
-    q, k, v, *_ = kernel_case
-
-    def layer(qi, ki, w):
+    """The gradient to q, k, v, qi, ki and w of a sparse-attention layer (the
+    kernels) under ``jax.checkpoint`` with ``remat``'s policy."""
+    def layer(q, k, v, qi, ki, w):
         ctx, kl, _, _ = sparse.sparse_attention(q, k, v, qi, ki, w, 300,
                                                 backend="pallas")
         return jnp.sum(ctx * jnp.cos(ctx)) + kl
@@ -225,17 +223,37 @@ def _layer_grad(kernel_case, remat):
     policy = remat_policy(remat)
     if policy is not None:
         layer = jax.checkpoint(layer, policy=policy)
-    return jax.grad(layer, argnums=(0, 1, 2))
+    return jax.grad(layer, argnums=tuple(range(6)))
+
+
+def _kernel_calls(jaxpr):
+    """(name, number of outputs) of every ``pallas_call`` a jaxpr holds, the
+    nested jaxprs' too."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append((eqn.params["name"], len(eqn.outvars)))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _kernel_calls(sub)
+    return found
 
 
 @pytest.fixture(scope="module")
-def grads_without_remat(kernel_case):
-    return jax.jit(_layer_grad(kernel_case, "none"))(*kernel_case[3:6])
+def layer_grads(kernel_case):
+    """remat -> the six gradients, each policy's program run once a module."""
+    made = {}
+
+    def of(remat):
+        if remat not in made:
+            made[remat] = jax.jit(_layer_grad(kernel_case, remat))(
+                *kernel_case[:6])
+        return made[remat]
+    return of
 
 
 @pytest.mark.parametrize("remat", ["full", "dots", "none"])
 def test_the_objectives_kernel_runs_once_under_a_gradient_whatever_the_remat(
-        remat, kernel_case, grads_without_remat):
+        remat, kernel_case, layer_grads):
     """Under ``jax.grad`` of a ``jax.checkpoint`` the forward pass runs the
     objective's forward RULE, and the recompute would run it again: the three
     gradients it makes are kept by name (``ops/remat.py DSA_INDEX_GRADS``), so
@@ -244,28 +262,64 @@ def test_the_objectives_kernel_runs_once_under_a_gradient_whatever_the_remat(
     qi, ki and w are those of ``remat='none'`` bit for bit (in float32, as
     here, the casts of the kept gradients are identities; in bfloat16 on the
     chip a kept gradient is rounded once more than one whose cast fuses with
-    the scaling by the cotangent: PERF.md 6, "PR 46")."""
-    qi, ki, w = kernel_case[3:6]
+    the scaling by the cotangent: PERF.md 6, "PR 46"). The core's forward
+    kernel runs once too: its output and log-sum-exps, the residuals of its
+    backward kernels, are kept by name (``DSA_CORE_OUT``, ``DSA_CORE_LSE``)."""
     grad = _layer_grad(kernel_case, remat)
-
-    def calls(jaxpr, found):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                found.append((eqn.params["name"], len(eqn.outvars)))
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                calls(sub, found)
-        return found
-
-    made = calls(jax.make_jaxpr(grad)(qi, ki, w).jaxpr, [])
+    made = _kernel_calls(jax.make_jaxpr(grad)(*kernel_case[:6]).jaxpr)
     assert [c for c in made if c[0] == "dsa_index_loss"] == [
         ("dsa_index_loss", 4)], made
-    # (the core's forward kernel IS run again: its output is not kept)
-    assert made.count(("dsa_core_fwd", 2)) == (1 if remat == "none" else 2)
+    assert made.count(("dsa_core_fwd", 2)) == 1, made
     assert made.count(("dsa_select", 1)) == 1
     if remat != "none":
-        for got, want in zip(jax.jit(grad)(qi, ki, w), grads_without_remat):
+        for got, want in zip(layer_grads(remat)[3:], layer_grads("none")[3:]):
             assert np.asarray(want).any()
             np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("remat", ["full", "dots"])
+def test_the_cores_gradients_through_a_rematerialized_layer_are_those_without(
+        remat, kernel_case, layer_grads):
+    """With the forward kernel's output and log-sum-exps kept by name the
+    backward kernels read the forward pass's own tensors: the gradients to q,
+    k and v through ``jax.checkpoint`` equal ``remat='none'``'s bit for bit,
+    and the two backward kernels run once each."""
+    made = _kernel_calls(jax.make_jaxpr(_layer_grad(kernel_case, remat))(
+        *kernel_case[:6]).jaxpr)
+    assert made.count(("dsa_core_bwd_dq", 1)) == 1, made
+    assert made.count(("dsa_core_bwd_dkv", 2)) == 1, made
+    for got, want in zip(layer_grads(remat)[:3], layer_grads("none")[:3]):
+        assert np.asarray(want).any()
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("keeping", [1, 2])
+def test_the_layers_that_keep_the_cores_output_are_the_last_ones(
+        keeping, monkeypatch):
+    """``keye_vl.CORE_KEPT_LAYERS`` of a model's layers keep the core's output
+    and log-sum-exps under ``--remat full``, and they are the LAST: read from
+    the gradient program of three layers at fitting shapes, whose backward
+    pass (last layer first) runs ``dsa_core_fwd`` again before a layer's two
+    backward kernels in the layers that do not keep, and not in those that
+    do; the choice and the objective's kernel run once in every layer."""
+    monkeypatch.setattr(keye_vl, "CORE_KEPT_LAYERS", keeping)
+    layers = 3
+    model = _model(backend="pallas", num_hidden_layers=layers, head_dim=128,
+                   num_attention_heads=2, num_key_value_heads=1, topk=64)
+    ids = jnp.zeros((1, 512), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), ids)["params"]
+
+    def loss(params):
+        logits, counters = model.apply({"params": params}, ids)
+        return jnp.sum(logits) + counters["dsa_index_kl"]
+
+    made = [name for name, _ in _kernel_calls(
+        jax.make_jaxpr(jax.grad(loss))(nn.unbox(params)).jaxpr)]
+    assert made.count("dsa_select") == made.count("dsa_index_loss") == layers
+    core = [name for name in made if name.startswith("dsa_core_")]
+    backward = ["dsa_core_bwd_dq", "dsa_core_bwd_dkv"]
+    assert core == (["dsa_core_fwd"] * layers + backward * keeping
+                    + (["dsa_core_fwd"] + backward) * (layers - keeping)), core
 
 
 def test_kernels_take_the_published_shapes_and_refuse_others():
@@ -677,9 +731,10 @@ def test_the_sparse_attentions_parts_lie_under_dsa(step_names):
 def test_at_fitting_shapes_the_scopes_hold_the_kernels_and_the_choice_is_kept():
     """With heads of 128 over rows of 512 the compiled step's ``dsa_select``,
     ``dsa_core`` and ``dsa_index_loss`` scopes hold the kernels' calls; the
-    choice and the objective's gradients are made in the forward pass alone
-    (kept across remat by name), the core's forward kernel runs again in the
-    recompute."""
+    choice, the objective's gradients and the core's output and log-sum-exps
+    are made in the forward pass alone (kept across remat by name): the
+    recompute holds no kernel of the three, and the core's two backward
+    kernels still run."""
     fitting = dict(TINY, num_hidden_layers=1, head_dim=128,
                    num_attention_heads=2, num_key_value_heads=1,
                    sa_config=dict(TINY["sa_config"], topk=64))
@@ -693,11 +748,10 @@ def test_at_fitting_shapes_the_scopes_hold_the_kernels_and_the_choice_is_kept():
                           ("dsa_index_loss", "dsa_index_loss")):
         assert under(scope, kernel), (scope, kernel)
     for scope, kernel in (("dsa_select", "dsa_select"),
+                          ("dsa_core", "dsa_core_fwd"),
                           ("dsa_index_loss", "dsa_index_loss")):
         assert not any("rematted_computation" in n
                        for n in under(scope, kernel)), kernel
-    assert any("rematted_computation" in n
-               for n in under("dsa_core", "dsa_core_fwd"))
 
 
 # -- the normal path ------------------------------------------------------------------

@@ -126,16 +126,15 @@ def _kernel_call_sites(text, kernel):
 def test_the_objectives_kernel_is_called_once_a_layer_and_micro_batch_trip(
         runs):
     """``KeyeVL2.txt`` is two layers under ``--remat full`` with the
-    micro-batches as ONE loop: the choice's and the objective's kernels each
-    run once a layer there (what they make is kept across remat by name,
-    ``ops/remat.py``), the core's forward kernel twice (its output is made
-    again in the recompute)."""
+    micro-batches as ONE loop: the choice's, the objective's and the core's
+    forward kernels each run once a layer there (what they make is kept
+    across remat by name, ``ops/remat.py``), as the two backward kernels."""
     with open(os.path.join(runs[0], "KeyeVL2.txt")) as f:
         text = f.read()
     layers = tool.SIZES["KeyeVL2"]["num_hidden_layers"]
     assert _kernel_call_sites(text, "dsa_index_loss") == layers
     assert _kernel_call_sites(text, "dsa_select") == layers
-    assert _kernel_call_sites(text, "dsa_core_fwd") == 2 * layers
+    assert _kernel_call_sites(text, "dsa_core_fwd") == layers
     assert _kernel_call_sites(text, "dsa_core_bwd_dq") == layers
 
 

@@ -142,10 +142,10 @@ def test_the_kept_mask_is_one_byte_an_element():
 
 @pytest.mark.parametrize("path", sorted(PATHS))
 def test_full_still_keeps_nothing(path):
-    """Attention's three names add nothing to 'full' (its policy keeps two
-    names, both the sparse attention's: asserted below, and no tensor of an
-    encoder carries either): the costly op runs twice, and only the layer's
-    inputs cross the scan."""
+    """Attention's three names add nothing to 'full' (its policy keeps four
+    names, all the sparse attention's: asserted below, and no tensor of an
+    encoder carries any of them): the costly op runs twice, and only the
+    layer's inputs cross the scan."""
     _, _, costly, _ = PATHS[path]
     _, program = _grad_program("full", path, with_grads=False)
     assert _ops(program)[costly] == 2
@@ -162,12 +162,14 @@ def test_one_function_builds_the_policy_for_both_sites():
     assert bert.remat_policy is remat.remat_policy
     assert pretrain.remat_policy is remat.remat_policy
     assert remat.remat_policy("none") is None
-    # 'full' keeps two names and nothing else, both the sparse attention's:
-    # the choice (one bit a pair) and the gradients of the indexer's KL
+    # 'full' keeps four names and nothing else, all the sparse attention's:
+    # the choice (one bit a pair), the gradients of the indexer's KL, and
+    # the core's output and log-sum-exps (its backward kernels' residuals)
     from jax._src.ad_checkpoint import name_p
 
     full = remat.remat_policy("full")
-    assert remat.KEPT_UNDER_FULL == (remat.DSA_CHOICE, remat.DSA_INDEX_GRADS)
+    assert remat.KEPT_UNDER_FULL == (remat.DSA_CHOICE, remat.DSA_INDEX_GRADS,
+                                     remat.DSA_CORE_OUT, remat.DSA_CORE_LSE)
     assert all(full(name_p, name=name) for name in remat.KEPT_UNDER_FULL)
     assert not any(full(name_p, name=name) for name in (
         remat.KEEP_MASK, remat.FLASH_OUT, remat.FLASH_LSE, "some_other_name"))
@@ -176,6 +178,16 @@ def test_one_function_builds_the_policy_for_both_sites():
     assert all(dots(name_p, name=name) for name in remat.KEPT_NAMES)
     assert not dots(name_p, name="some_other_name")
     assert not full(jax.lax.dot_general_p) and not full(jax.lax.exp_p)
+    # a model may leave names out of a block's policy, and only kept names
+    core = (remat.DSA_CORE_OUT, remat.DSA_CORE_LSE)
+    for value in ("full", "dots"):
+        fewer = remat.remat_policy(value, without=core)
+        assert not any(fewer(name_p, name=name) for name in core)
+        assert all(fewer(name_p, name=name)
+                   for name in (remat.DSA_CHOICE, remat.DSA_INDEX_GRADS))
+    assert remat.remat_policy("none", without=core) is None
+    with pytest.raises(ValueError, match="some_other_name"):
+        remat.remat_policy("full", without=("some_other_name",))
     with pytest.raises(ValueError, match="none|dots|full"):
         remat.remat_policy("some")
 
