@@ -793,10 +793,126 @@ class KeyeVLConfig:
         return 16
 
 
+class JoyAIConfig:
+    """Configuration of the ``joyai_llm_flash`` family: a pre-norm residual
+    decoder whose every layer is LATENT attention, then an MLP: dense SwiGLU
+    in the first ``first_k_dense_replace`` layers, a routed expert layer with
+    a shared expert after them; and ``num_nextn_predict_layers`` = 1
+    multi-token-prediction module in the objective (``models/joyai.py``).
+    Attention's queries come through a ``q_lora_rank`` latent and its keys
+    and values through a ``kv_lora_rank`` one, each normed; a head's query and
+    key are ``qk_nope_head_dim`` dimensions of its own beside
+    ``qk_rope_head_dim`` turned ones, the key's turned part ONE vector that
+    every head reads; values are ``v_head_dim`` wide. Keys and defaults are
+    the published ``config.json``'s (jdopensource/JoyAI-LLM-Flash, whose keys
+    are DeepSeek-V3's); extra keys ride along as on :class:`BertConfig`.
+
+    The chip's share is stated here, as :class:`NemotronHConfig` states it:
+    ``n_routed_experts`` experts are HELD of ``n_routed_experts * ep_size``,
+    ``ep_rank`` says which; the router keeps its published width. (The
+    published file's own ``ep_size: 1`` is a key of the released serving code
+    and belongs under a configuration file's ``published``.) Heads are whole.
+    """
+
+    model_type = "joyai_llm_flash"
+
+    def __init__(self, **values: Any):
+        defaults = dict(
+            vocab_size=129280, hidden_size=2048, intermediate_size=7168,
+            num_hidden_layers=40, first_k_dense_replace=1, moe_layer_freq=1,
+            num_attention_heads=32, num_key_value_heads=32,
+            q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+            qk_rope_head_dim=64, qk_head_dim=None, v_head_dim=128,
+            rope_theta=32000000,
+            rope_interleave=True, rope_scaling=None, attention_bias=False,
+            hidden_act="silu", rms_norm_eps=1e-6,
+            n_routed_experts=256, ep_size=1, ep_rank=0,
+            num_experts_per_tok=8, moe_intermediate_size=768,
+            n_shared_experts=1, scoring_func="sigmoid",
+            topk_method="noaux_tc", n_group=1, topk_group=1,
+            norm_topk_prob=True, routed_scaling_factor=2.5,
+            num_nextn_predict_layers=1, mtp_loss_coef=0.3,
+            tie_word_embeddings=False, initializer_range=0.02,
+            max_position_embeddings=131072)
+        for key, value in {**defaults, **values}.items():
+            setattr(self, key, value)
+        if (self.rope_scaling or self.attention_bias or self.moe_layer_freq != 1
+                or self.tie_word_embeddings or self.hidden_act != "silu"
+                or self.scoring_func != "sigmoid"
+                or self.topk_method != "noaux_tc"):
+            raise ValueError(
+                "joyai_llm_flash is built with the default rotary table, no "
+                "bias, an expert layer in every layer after the dense ones, "
+                "silu, sigmoid scores under noaux_tc and an untied head")
+        if self.qk_head_dim is None:
+            self.qk_head_dim = self.qk_nope_head_dim + self.qk_rope_head_dim
+        if self.qk_head_dim != self.qk_nope_head_dim + self.qk_rope_head_dim:
+            raise ValueError(
+                f"qk_head_dim {self.qk_head_dim} is not qk_nope_head_dim "
+                f"{self.qk_nope_head_dim} + qk_rope_head_dim "
+                f"{self.qk_rope_head_dim}")
+        if self.n_group != 1 or self.topk_group != 1:
+            raise ValueError(
+                "the group-limited choice is not built: n_group "
+                f"{self.n_group}, topk_group {self.topk_group}")
+        if self.num_nextn_predict_layers not in (0, 1):
+            raise ValueError(
+                "multi-token prediction is built at depth 1 (one module), "
+                f"not {self.num_nextn_predict_layers}")
+        if self.qk_rope_head_dim % 2 or not (
+                0 <= self.first_k_dense_replace <= self.num_hidden_layers):
+            raise ValueError(
+                f"turned dimensions {self.qk_rope_head_dim}; "
+                f"{self.first_k_dense_replace} dense layers of "
+                f"{self.num_hidden_layers}")
+        if self.num_key_value_heads != self.num_attention_heads:
+            raise ValueError(
+                "latent attention gives every query head a key and a value "
+                f"of its own: {self.num_key_value_heads} key-value heads on "
+                f"{self.num_attention_heads}")
+        if not 0 <= self.ep_rank < self.ep_size:
+            raise ValueError(f"ep_rank {self.ep_rank} of ep_size {self.ep_size}")
+
+    @classmethod
+    def from_dict(cls, json_object: dict) -> "JoyAIConfig":
+        values = {k: v for k, v in json_object.items() if k != "model_type"}
+        return cls(**values)
+
+    def to_dict(self) -> dict:
+        return dict(copy.deepcopy(self.__dict__), model_type=self.model_type)
+
+    @property
+    def router_experts(self) -> int:
+        """Every expert of the layer, held or not."""
+        return self.n_routed_experts * self.ep_size
+
+    @property
+    def first_expert(self) -> int:
+        return self.ep_rank * self.n_routed_experts
+
+    @property
+    def shared_width(self) -> int:
+        return self.n_shared_experts * self.moe_intermediate_size
+
+    @property
+    def rope(self) -> tuple:
+        """(rotary dimensions, a ``rope_parameters``-style entry) of the
+        turned part of a head."""
+        return (self.qk_rope_head_dim,
+                {"rope_theta": self.rope_theta, "rope_type": "default"})
+
+    @property
+    def init_sample_length(self) -> int:
+        """Positions of the sample that initializes the parameters (none
+        depends on the length)."""
+        return 16
+
+
 MODEL_FAMILIES = {"bert": BertConfig, "nemotron_h": NemotronHConfig,
                   "laguna": LagunaConfig, "phi4flash": PhiFlashConfig,
                   "zaya": ZayaConfig, "qwen3_next": Qwen3NextConfig,
-                  "KeyeVL2": KeyeVLConfig}
+                  "KeyeVL2": KeyeVLConfig,
+                  "joyai_llm_flash": JoyAIConfig}
 
 
 def load_model_config(json_file: str):

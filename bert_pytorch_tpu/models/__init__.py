@@ -49,10 +49,11 @@ from bert_pytorch_tpu.models.losses import (
     token_classification_loss,
 )
 
+from bert_pytorch_tpu.models.joyai import JoyAIForCausalLM
+from bert_pytorch_tpu.models.keye_vl import KeyeVLForCausalLM
 from bert_pytorch_tpu.models.laguna import LagunaForCausalLM
 from bert_pytorch_tpu.models.nemotron_h import NemotronHForCausalLM
 from bert_pytorch_tpu.models.phi4flash import PhiFlashForCausalLM
-from bert_pytorch_tpu.models.keye_vl import KeyeVLForCausalLM
 from bert_pytorch_tpu.models.qwen3_next import Qwen3NextForCausalLM
 from bert_pytorch_tpu.models.zaya import ZayaForCausalLM
 
@@ -63,8 +64,9 @@ def build_pretraining_model(config, dtype, remat: str = "none",
     (``config.load_model_config`` chose the class from the file's
     ``model_type``). The model's ``objective`` attribute names what
     ``pretrain.make_train_step`` trains it on."""
-    from bert_pytorch_tpu.config import (BertConfig, KeyeVLConfig,
-                                         LagunaConfig, NemotronHConfig,
+    from bert_pytorch_tpu.config import (BertConfig, JoyAIConfig,
+                                         KeyeVLConfig, LagunaConfig,
+                                         NemotronHConfig,
                                          PhiFlashConfig, Qwen3NextConfig,
                                          ZayaConfig)
 
@@ -74,6 +76,7 @@ def build_pretraining_model(config, dtype, remat: str = "none",
                           (ZayaConfig, ZayaForCausalLM),
                           (Qwen3NextConfig, Qwen3NextForCausalLM),
                           (KeyeVLConfig, KeyeVLForCausalLM),
+                          (JoyAIConfig, JoyAIForCausalLM),
                           (BertConfig, BertForPreTraining)):
         if isinstance(config, family):
             return model(config, dtype=dtype, remat=remat,
@@ -82,6 +85,7 @@ def build_pretraining_model(config, dtype, remat: str = "none",
 
 
 __all__ = [
+    "JoyAIForCausalLM",
     "KeyeVLForCausalLM",
     "LagunaForCausalLM",
     "NemotronHForCausalLM",

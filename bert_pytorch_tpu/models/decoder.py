@@ -1,11 +1,11 @@
 """What the decoder families share (``models/nemotron_h.py``,
 ``models/laguna.py``, ``models/phi4flash.py``, ``models/zaya.py``,
-``models/qwen3_next.py``, ``models/keye_vl.py``): RMSNorm,
-LayerNorm, the bias-free projection, the routed expert layer with or without
-a shared expert and with a router of its own or one handed in, and the
-wrapper round a stack of unlike layers: embedding, ``layers_0 ..
-layers_{L-1}``, final norm, output head (untied, or the embedding's
-transpose), next-token objective.
+``models/qwen3_next.py``, ``models/keye_vl.py``, ``models/joyai.py``): RMSNorm,
+LayerNorm, the bias-free projection, the dense gated MLP, the routed expert
+layer with or without a shared expert and with a router of its own or one
+handed in, and the wrapper round a stack of unlike layers: embedding,
+``layers_0 .. layers_{L-1}``, final norm, output head (untied, or the
+embedding's transpose), next-token objective.
 
 Layers of unlike kinds hold unlike parameters, so they cannot be stacked and
 scanned the way ``models/bert.py`` scans its encoder; each is rematerialized
@@ -29,6 +29,7 @@ are called as before and lower as before.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable
 
 import flax.linen as nn
@@ -99,6 +100,26 @@ def gate_by_token(y, x, vector):
         gate = jnp.einsum("bsh,h->bs", x, vector.astype(x.dtype))
         return (y * jax.nn.sigmoid(gate.astype(jnp.float32))[..., None]
                 ).astype(y.dtype)
+
+
+class DenseMLP(nn.Module):
+    """``W_down (silu(W_gate h) * W_up h)``, ``width`` wide; gate and up are
+    one projection, the gate's columns first. ``out_std`` is the down
+    projection's (it writes into the residual stream)."""
+    width: int
+    hidden: int
+    std: float
+    out_std: float
+    dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        with jax.named_scope("dense_mlp"):
+            gate, up = jnp.split(dense(
+                2 * self.width, self.std, self.dtype, "gate_up_proj")(x),
+                2, axis=-1)
+            return dense(self.hidden, self.out_std, self.dtype,
+                         "down_proj")(jax.nn.silu(gate) * up)
 
 
 class ExpertLayer(nn.Module):
@@ -187,6 +208,14 @@ class ExpertLayer(nn.Module):
             return shared + routed.reshape(x.shape), counters
 
 
+def rematerialized(remat: str, block, without=()):
+    """``block`` (a module class) under ``remat``'s policy less the names in
+    ``without`` (``ops/remat.py``); the class itself under 'none'."""
+    policy = remat_policy(remat, without)
+    return block if policy is None else nn.remat(
+        block, policy=policy, prevent_cse=True)
+
+
 class CausalDecoder(nn.Module):
     """The wrapper: a family gives its ``blocks()`` (modules that take x and
     return ``(x, counters)``; under ``CARRIES`` they take ``(x, carried)``
@@ -223,6 +252,23 @@ class CausalDecoder(nn.Module):
         ``models/keye_vl.py``, the indexer's KL). None for most families."""
         return {}
 
+    def prediction_streams(self) -> dict:
+        """{name: (shift, coefficient)}: further streams of hidden states
+        through the SHARED output head that belong to the objective: stream
+        ``name`` (what ``further_streams`` returns under that name) predicts
+        token t + ``shift`` at position t, and
+        ``pretrain._apply_causal_lm_loss`` adds ``coefficient`` times its mean
+        loss to the next-token loss (``models/joyai.py``: the
+        multi-token-prediction module, shift 2). None for most families."""
+        return {}
+
+    def further_streams(self, x, embedded, shared) -> tuple:
+        """({name: hidden [B, S, H]}, counters) of ``prediction_streams``'
+        names, from the last layer's output ``x`` BEFORE the final norm, the
+        embedded input ``embedded`` and ``shared_inputs``' tuple. A model
+        that names a stream builds it here, from modules of its own."""
+        raise NotImplementedError
+
     def shared_inputs(self, seq: int) -> tuple:
         """What every layer of one call over ``seq`` positions reads and none
         writes, made once ahead of the layers and handed to each after its
@@ -234,13 +280,7 @@ class CausalDecoder(nn.Module):
         self.embedding = self.param(
             "embedding", normal(cfg.initializer_range),
             (cfg.vocab_size, cfg.hidden_size), jnp.float32)
-
-        def wrap(block, without=()):
-            policy = remat_policy(self.remat, without)
-            return block if policy is None else nn.remat(
-                block, policy=policy, prevent_cse=True)
-
-        self.layers = self.blocks(wrap)
+        self.layers = self.blocks(functools.partial(rematerialized, self.remat))
         self.final_norm = self.NORM(self.norm_epsilon(), self.dtype)
         if not self.TIED_HEAD:
             self.lm_head = dense(cfg.vocab_size, cfg.initializer_range,
@@ -256,25 +296,12 @@ class CausalDecoder(nn.Module):
         """[B, S] ids -> (the final norm's output [B, S, H], counters): all
         but the head, for a caller that takes the head in pieces
         (models/losses.py ``chunked_next_token_loss``)."""
-        x = jnp.take(self.embedding, input_ids, axis=0).astype(self.dtype)
-        seen = {name: [] for name in self.COUNTERS}
-        carried = {}
-        shared = self.shared_inputs(input_ids.shape[1])
-        for layer in self.layers:
-            if self.CARRIES:
-                x, carried, counters = layer(x, carried, *shared)
-            else:
-                x, counters = layer(x, *shared)
-            for name, value in (counters or {}).items():
-                seen[name].append(value)
-        zero = jnp.zeros((), jnp.float32)
-        return self.final_norm(x), {
-            name: (zero if not values else
-                   jnp.max(jnp.stack(values))
-                   if name.endswith("_max_over_mean") else
-                   jnp.mean(jnp.stack(values))
-                   if name.endswith("_fill") else sum(values, zero))
-            for name, values in seen.items()}
+        return _run_layers(self, input_ids)[:2]
+
+    def streams(self, input_ids):
+        """``hidden_states`` and, third, ``further_streams``' hidden states
+        by name (their layers' counters added to the others)."""
+        return _run_layers(self, input_ids, further=True)
 
     def __call__(self, input_ids):
         x, counters = self.hidden_states(input_ids)
@@ -283,3 +310,34 @@ class CausalDecoder(nn.Module):
                 return jnp.matmul(
                     x, self.embedding.T.astype(self.dtype)), counters
             return self.lm_head(x), counters
+
+
+def _run_layers(model: CausalDecoder, input_ids, further: bool = False):
+    """(the final norm's output, counters, {stream: hidden}) of ``model``
+    bound to its parameters. A plain function, so that the two methods that
+    call it write the same scopes as when each held this body."""
+    embedded = jnp.take(model.embedding, input_ids, axis=0).astype(model.dtype)
+    x = embedded
+    seen = {name: [] for name in model.COUNTERS}
+    carried = {}
+    shared = model.shared_inputs(input_ids.shape[1])
+    for layer in model.layers:
+        if model.CARRIES:
+            x, carried, counters = layer(x, carried, *shared)
+        else:
+            x, counters = layer(x, *shared)
+        for name, value in (counters or {}).items():
+            seen[name].append(value)
+    streams = {}
+    if further:
+        streams, counters = model.further_streams(x, embedded, shared)
+        for name, value in counters.items():
+            seen[name].append(value)
+    zero = jnp.zeros((), jnp.float32)
+    return model.final_norm(x), {
+        name: (zero if not values else
+               jnp.max(jnp.stack(values))
+               if name.endswith("_max_over_mean") else
+               jnp.mean(jnp.stack(values))
+               if name.endswith("_fill") else sum(values, zero))
+        for name, values in seen.items()}, streams

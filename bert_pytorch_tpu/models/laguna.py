@@ -46,7 +46,8 @@ import jax.numpy as jnp
 
 from bert_pytorch_tpu.config import LagunaConfig
 from bert_pytorch_tpu.models.decoder import (MOE_COUNTERS, CausalDecoder,
-                                             ExpertLayer, RMSNorm, dense)
+                                             DenseMLP, ExpertLayer, RMSNorm,
+                                             dense)
 from bert_pytorch_tpu.ops import rope
 from bert_pytorch_tpu.ops.attention import (dot_product_attention,
                                             resolve_backend)
@@ -106,23 +107,6 @@ class GatedAttention(nn.Module):
         return out, {name: jnp.asarray(tiles, jnp.float32)}
 
 
-class DenseMLP(nn.Module):
-    """``W2 (silu(W1 h) * W3 h)``; gate and up are one projection, the gate's
-    columns first."""
-    config: LagunaConfig
-    dtype: Dtype = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.config
-        with jax.named_scope("dense_mlp"):
-            gate, up = jnp.split(dense(
-                2 * cfg.intermediate_size, cfg.initializer_range, self.dtype,
-                "gate_up_proj")(x), 2, axis=-1)
-            return dense(cfg.hidden_size, _out_std(cfg), self.dtype,
-                         "down_proj")(jax.nn.silu(gate) * up)
-
-
 def expert_layer(cfg: LagunaConfig, dtype, name=None) -> ExpertLayer:
     """The family's expert layer: softmax scores, gated silu experts, the
     share ``cfg`` states."""
@@ -156,7 +140,9 @@ class LagunaBlock(nn.Module):
         x = x + out
         h = RMSNorm(cfg.rms_norm_eps, self.dtype, name="mlp_norm")(x)
         if cfg.mlp_layer_types[self.layer] == "dense":
-            out = DenseMLP(cfg, self.dtype, name="mlp")(h)
+            out = DenseMLP(cfg.intermediate_size, cfg.hidden_size,
+                           cfg.initializer_range, _out_std(cfg),
+                           self.dtype, name="mlp")(h)
         else:
             out, routed = expert_layer(cfg, self.dtype, name="mlp")(h)
             counters = {**counters, **routed}

@@ -65,15 +65,18 @@ def pretraining_loss(
     return loss
 
 
-def next_token_loss(logits, input_ids):
+def next_token_loss(logits, input_ids, shift: int = 1, scope: str = "lm"):
     """Causal-LM objective: the mean fp32 cross entropy of position t's
-    logits against token t + 1, over the S - 1 predicted positions of every
-    row, and the share of them whose arg-max is right. The vocabulary is the
-    logits' last axis (a slice of a published vocabulary is a smaller
-    vocabulary: ids, logits and loss are over the slice)."""
-    with jax.named_scope("lm_loss"):
-        labels = jnp.roll(input_ids, -1, axis=-1)
-        predicted = jnp.arange(input_ids.shape[-1]) < input_ids.shape[-1] - 1
+    logits against token t + ``shift``, over the S - ``shift`` predicted
+    positions of every row, and the share of them whose arg-max is right. The
+    vocabulary is the logits' last axis (a slice of a published vocabulary is
+    a smaller vocabulary: ids, logits and loss are over the slice). ``shift``
+    2 is a multi-token-prediction module's target (models/joyai.py); the loss
+    runs under the scope ``<scope>_loss``."""
+    with jax.named_scope(scope + "_loss"):
+        labels = jnp.roll(input_ids, -shift, axis=-1)
+        predicted = (jnp.arange(input_ids.shape[-1])
+                     < input_ids.shape[-1] - shift)
         per_pos = optax.softmax_cross_entropy_with_integer_labels(
             logits.astype(jnp.float32), labels)
         count = jnp.maximum(jnp.sum(predicted) * (labels.size // labels.shape[-1]), 1)
@@ -82,26 +85,28 @@ def next_token_loss(logits, input_ids):
         return loss, jnp.sum(right) / count
 
 
-def chunked_next_token_loss(hidden, head_kernel, input_ids, chunks: int):
+def chunked_next_token_loss(hidden, head_kernel, input_ids, chunks: int,
+                            shift: int = 1, scope: str = "lm"):
     """:func:`next_token_loss` of ``hidden @ head_kernel`` without ever
     holding every position's logits: the head and the cross entropy run over
     ``chunks`` equal pieces of the sequence, one after the other, each
     rematerialized, so the backward pass holds one piece's logits at a time
     (at 8192 x 16384 in float32 the whole is 0.5 GB, several times over).
     Same loss, same accuracy, the head's forward once more in the backward
-    pass. ``chunks`` must divide the sequence length."""
+    pass. ``chunks`` must divide the sequence length. The head runs under the
+    scope ``<scope>_head`` and the loss under ``<scope>_loss``."""
     batch, seq, width = hidden.shape
-    labels = jnp.roll(input_ids, -1, axis=-1)
-    predicted = jnp.broadcast_to(jnp.arange(seq) < seq - 1, labels.shape)
+    labels = jnp.roll(input_ids, -shift, axis=-1)
+    predicted = jnp.broadcast_to(jnp.arange(seq) < seq - shift, labels.shape)
     pieces = lambda t: jnp.moveaxis(
         t.reshape((batch, chunks, seq // chunks) + t.shape[2:]), 1, 0)
 
     @jax.checkpoint
     def piece(carry, xs):
         h, lab, keep = xs
-        with jax.named_scope("lm_head"):
+        with jax.named_scope(scope + "_head"):
             logits = jnp.matmul(h, head_kernel.astype(h.dtype))
-        with jax.named_scope("lm_loss"):
+        with jax.named_scope(scope + "_loss"):
             per_pos = optax.softmax_cross_entropy_with_integer_labels(
                 logits.astype(jnp.float32), lab)
             right = (jnp.argmax(logits, axis=-1) == lab) & keep
@@ -111,7 +116,7 @@ def chunked_next_token_loss(hidden, head_kernel, input_ids, chunks: int):
     (total, right), _ = jax.lax.scan(
         piece, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32)),
         (pieces(hidden), pieces(labels), pieces(predicted)))
-    count = jnp.maximum(batch * (seq - 1), 1)
+    count = jnp.maximum(batch * (seq - shift), 1)
     return total / count, right / count
 
 

@@ -76,11 +76,16 @@ def dot_product_attention(
 ) -> jnp.ndarray:
     """Attention over [B, S, H, D] query/key/value tensors.
 
-    Returns [B, S, H, D]. Scores are scaled by 1/sqrt(D) and softmaxed in
-    fp32 (modeling.py:403-429's score path, bf16-safe). The values may be
-    wider than the queries and keys ([B, S, H, Dv]; the 'xla' and 'pallas'
-    paths): the result is then [B, S, H, Dv]. ``label`` (static) is written
-    into the Pallas kernels' names and changes nothing else.
+    Returns [B, S, H, D]. Scores are scaled by 1/sqrt(D), D the width of the
+    queries and keys, and softmaxed in fp32 (modeling.py:403-429's score path,
+    bf16-safe). The values may be of another width than the queries and keys
+    ([B, S, H, Dv]; the 'xla' and 'pallas' paths): wider (the differential
+    attention's joined value heads) or narrower (latent attention: queries
+    and keys of 128 + 64 turned dimensions over values of 128, models/joyai.py);
+    the result is then [B, S, H, Dv]. The key comes in ONE part: a caller
+    whose heads share a part of it (the latent attention's one turned key)
+    builds each head's key first. ``label`` (static) is written into the
+    Pallas kernels' names and changes nothing else.
 
     ``causal`` (static) lets position q attend to positions <= q only: a
     mask on the XLA path; on the Pallas path a static flag of the kernels,

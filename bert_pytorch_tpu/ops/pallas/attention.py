@@ -474,13 +474,16 @@ _DEFAULT_SCOPED_VMEM = 16 * 1024 ** 2
 def _wide_head_params(g, seq, depth, depth_v, itemsize):
     """``compiler_params`` for a call whose two whole-sequence operands (K and
     V in the forward and dq kernels, Q and dO in the dkv kernel: [g, seq,
-    depth] and [g, seq, depth_v], each double-buffered) leave the other
-    blocks less than a quarter of the default scoped VMEM: a head of 256 at
-    8192 positions (16 MiB of the 16). The limit is then raised to twice those
+    depth] and [g, seq, depth_v], each double-buffered, a head's width rounded
+    up to whole lane tiles as VMEM holds it) leave the other blocks a quarter
+    of the default scoped VMEM or less: a head of 256 at 8192 positions (16
+    MiB of the 16), and a head of 192 against values of 128 there (12 MiB:
+    Mosaic asked for 16.11 of the 16). The limit is then raised to twice those
     operands (the tiles and the float32 scores take the rest; the chip has
     128 MiB). Every narrower call passes nothing and lowers as before."""
-    whole = 2 * g * seq * (depth + depth_v) * itemsize
-    if whole <= 3 * _DEFAULT_SCOPED_VMEM // 4:
+    lanes = lambda width: -(-width // 128) * 128
+    whole = 2 * g * seq * (lanes(depth) + lanes(depth_v)) * itemsize
+    if whole < 3 * _DEFAULT_SCOPED_VMEM // 4:
         return {}
     return {"compiler_params": pltpu.CompilerParams(
         vmem_limit_bytes=2 * whole)}
@@ -504,10 +507,10 @@ def _name(kernel, window, label=""):
 
 def _flash_forward(q3, k3, v3, bias3, seg3, seed, scale, rate, segmented,
                    causal=False, window=None, label=""):
-    """q3/k3: [BH, S, D]; v3: [BH, S, Dv] (the values may be wider than the
-    keys: the output is as wide as they are); bias3: [BH, 1, S] additive key
-    bias; seg3: [BH, 1, S] fp32 sequence ids (all-zero dummy when not
-    segmented)."""
+    """q3/k3: [BH, S, D]; v3: [BH, S, Dv] (the values may be wider or narrower
+    than the keys: the output is as wide as they are); bias3: [BH, 1, S]
+    additive key bias; seg3: [BH, 1, S] fp32 sequence ids (all-zero dummy when
+    not segmented)."""
     bh, seq, depth = q3.shape
     depth_v = v3.shape[-1]
     block_q, block_k = _pick_blocks(seq)
@@ -885,9 +888,9 @@ def flash_attention_infer_int8(q, k, v, bias=None, sequence_ids=None,
 
 def flash_attention(q, k, v, bias=None, dropout_rate=0.0, dropout_rng=None,
                     sequence_ids=None, causal=False, window=None, label=""):
-    """Fused attention over [B, S, H, D] tensors. The values may be wider
-    than the queries and keys ([B, S, H, Dv], static, from shapes): the
-    output is then [B, S, H, Dv]; scores are scaled by the keys' width.
+    """Fused attention over [B, S, H, D] tensors. The values may be wider or
+    narrower than the queries and keys ([B, S, H, Dv], static, from shapes):
+    the output is then [B, S, H, Dv]; scores are scaled by the keys' width.
     ``label`` (static) goes into the kernels' names (``_name``) and nowhere
     else.
 

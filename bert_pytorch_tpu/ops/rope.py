@@ -35,6 +35,13 @@ and get no cotangent. **The tables are the caller's to make once**
 (``rotary_tables``: ``models/laguna.py`` makes one pair for each kind of layer
 ahead of the layers and hands them to every block).
 
+**Interleaved pairs** (``apply_rotary_interleaved``; a config's
+``rope_interleave``: models/joyai.py): the WHOLE of x's last axis is taken in
+pairs ``(2 i, 2 i + 1)``, each turned by ``position * inv_freq[i]``, from the
+same tables (their first half holds each pair's angle once). Plain jnp, the
+same backward by the negative angle; ``ops/pallas/rope.py`` knows the
+``rotate_half`` pairs only.
+
 Scope: the caller's (``attn_rope`` in models/laguna.py); the backward's ops
 inherit it through the transpose's name.
 """
@@ -128,3 +135,33 @@ def _apply_rotary_bwd(tables, cotangent):
 
 
 apply_rotary.defvjp(_apply_rotary_fwd, _apply_rotary_bwd)
+
+
+def _turn_pairs(x, cos, sin, sign: int):
+    """The rotation of the pairs (2 i, 2 i + 1) of x's last axis by the
+    tables' angle (``sign`` 1) or by its negative."""
+    half = x.shape[-1] // 2
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (half, 2))
+    even, odd = pairs[..., 0], pairs[..., 1]
+    cos, sin = cos[:, None, :half], sign * sin[:, None, :half]
+    return jnp.stack([even * cos - odd * sin, odd * cos + even * sin],
+                     axis=-1).reshape(x.shape).astype(x.dtype)
+
+
+@jax.custom_vjp
+def apply_rotary_interleaved(x, cos, sin):
+    """x [B, S, heads, rotary_dim] with its pairs (2 i, 2 i + 1) turned, by
+    the tables of ``rotary_tables(seq, rotary_dim, ...)``. In float32, back
+    in x's dtype."""
+    return _turn_pairs(x, cos, sin, 1)
+
+
+def _apply_interleaved_fwd(x, cos, sin):
+    return _turn_pairs(x, cos, sin, 1), (cos, sin)
+
+
+def _apply_interleaved_bwd(tables, cotangent):
+    return _turn_pairs(cotangent, *tables, -1), None, None
+
+
+apply_rotary_interleaved.defvjp(_apply_interleaved_fwd, _apply_interleaved_bwd)

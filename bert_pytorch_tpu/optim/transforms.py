@@ -284,7 +284,9 @@ def no_decay_mask(params) -> optax.Params:
     attention's four ``lambda_*`` vectors; of the ``qwen3_next`` family
     (models/qwen3_next.py) also the shared expert's gate vector
     ``shared_gate`` (its ``A_log``, ``dt_bias`` and norm scales go by the
-    names above)."""
+    names above); of the ``joyai_llm_flash`` family (models/joyai.py) the two
+    latent norms, the multi-token-prediction module's ``enorm`` and ``hnorm``
+    and the router's correction buffer go by the names above too."""
     import flax.traverse_util as traverse_util
 
     flat = traverse_util.flatten_dict(params)

@@ -90,6 +90,15 @@ KEYE_SCOPES = (
     "dsa_index_loss", "moe", "moe_route", "moe_dispatch", "moe_experts",
     "moe_combine", "lm_head", "lm_loss")
 
+# ... and those of the joyai_llm_flash family's step (models/joyai.py: latent
+# attention under ``mla``, the multi-token-prediction module under ``mtp``
+# and its pass of the shared head).
+JOYAI_SCOPES = (
+    "mla", "mla_q_proj", "mla_kv_proj", "attn_rope", "mla_core", "attn_out",
+    "dense_mlp", "moe", "moe_route", "moe_dispatch", "moe_experts",
+    "moe_combine", "moe_shared", "mtp", "mtp_merge", "mtp_head", "mtp_loss",
+    "lm_head", "lm_loss")
+
 # Rows longer than this many positions take the output head and its loss in
 # pieces of this length (models/losses.py chunked_next_token_loss).
 LM_HEAD_PIECE = 2048
@@ -204,23 +213,55 @@ def _apply_pretraining_loss(model, variables, mb, rng, next_sentence,
 def _apply_causal_lm_loss(model, variables, mb):
     """The ``causal_lm`` objective's counterpart of
     :func:`_apply_pretraining_loss`: rows of token ids in, next-token loss
-    out, plus what the model names as terms of its objective among its
-    counters (``CausalDecoder.objective_terms``: none for most families).
-    Returns (loss, aux); ``aux`` holds the token accuracy and the model's
-    counters, one scalar each per micro-batch."""
+    out, plus what the model names as terms of its objective: scalars among
+    its counters (``CausalDecoder.objective_terms``) and further streams
+    through the shared head (``CausalDecoder.prediction_streams``: stream
+    ``name`` predicts token t + shift at position t; its loss and accuracy go
+    into the counters as ``<name>_loss`` and ``<name>_token_accuracy``, under
+    the scopes ``<name>_head`` and ``<name>_loss``). Most families name
+    neither. Returns (loss, aux); ``aux`` holds the token accuracy and the
+    model's counters, one scalar each per micro-batch."""
     ids = mb["input_ids"]
     pieces, ragged = divmod(ids.shape[-1], LM_HEAD_PIECE)
-    if ragged or pieces < 2:
-        logits, counters = model.apply(variables, ids)
-        loss, accuracy = next_token_loss(logits, ids)
-    else:  # long rows: the head and the loss in pieces of the sequence
-        hidden, counters = model.apply(variables, ids,
-                                       method="hidden_states")
-        loss, accuracy = chunked_next_token_loss(
-            hidden, model.head_kernel(variables["params"]), ids, pieces)
+    whole = bool(ragged) or pieces < 2
+    streams = model.prediction_streams()
+    if not streams:
+        if whole:
+            logits, counters = model.apply(variables, ids)
+            loss, accuracy = next_token_loss(logits, ids)
+        else:  # long rows: the head and the loss in pieces of the sequence
+            hidden, counters = model.apply(variables, ids,
+                                           method="hidden_states")
+            loss, accuracy = chunked_next_token_loss(
+                hidden, model.head_kernel(variables["params"]), ids, pieces)
+    else:
+        hidden, counters, further = model.apply(variables, ids,
+                                                method="streams")
+        loss, accuracy = _shared_head_loss(
+            model, variables, hidden, ids, 1 if whole else pieces)
+    for name, (shift, coefficient) in streams.items():
+        term, right = _shared_head_loss(
+            model, variables, further[name], ids, 1 if whole else pieces,
+            shift, name)
+        loss = loss + coefficient * term
+        counters = {**counters, name + "_loss": term,
+                    name + "_token_accuracy": right}
     for name, coefficient in model.objective_terms().items():
         loss = loss + coefficient * counters[name]
     return loss, {"token_accuracy": accuracy, **counters}
+
+
+def _shared_head_loss(model, variables, hidden, ids, pieces: int,
+                      shift: int = 1, scope: str = "lm"):
+    """(loss, accuracy) of one stream through the model's output head against
+    token t + ``shift``: whole, or in ``pieces`` of the sequence."""
+    kernel = model.head_kernel(variables["params"])
+    if pieces > 1:
+        return chunked_next_token_loss(hidden, kernel, ids, pieces, shift,
+                                       scope)
+    with jax.named_scope(scope + "_head"):
+        logits = jnp.matmul(hidden, kernel.astype(hidden.dtype))
+    return next_token_loss(logits, ids, shift, scope)
 
 
 def _aux_metrics(aux) -> dict:

@@ -310,6 +310,40 @@ def keye_vl_forward_flops_per_token(config, seq_len: int) -> dict:
                 head=2.0 * h * config.vocab_size)
 
 
+def joyai_forward_flops_per_token(config, seq_len: int) -> dict:
+    """Forward matmul FLOPs per token of a ``joyai_llm_flash`` model on THIS
+    chip (the experts and vocabulary rows it holds), by part, the
+    multi-token-prediction module's block counted with the layers:
+    ``mla_proj`` (the queries' two products, the keys' and values' two, the
+    output's), ``mla_core`` (the two S x S products over the causal half at
+    192 and 128 a pair), ``dense_mlp`` (the leading dense layers),
+    ``experts`` (router, shared expert and three products by the EXPECTED
+    top_k x held / experts of the tokens), ``mtp_merge`` (``W_eh``), ``head``
+    and ``mtp_head`` (the shared head's two passes). Lookup, norms, rotary,
+    activations and the optimizer are left out."""
+    h, heads = config.hidden_size, config.num_attention_heads
+    qk, wide = config.qk_head_dim, config.v_head_dim
+    module = config.num_nextn_predict_layers
+    blocks = config.num_hidden_layers + module
+    routed = blocks - config.first_k_dense_replace
+    head = 2.0 * h * config.vocab_size
+    return {
+        "mla_proj": blocks * 2.0 * (
+            h * config.q_lora_rank + config.q_lora_rank * heads * qk
+            + h * (config.kv_lora_rank + config.qk_rope_head_dim)
+            + config.kv_lora_rank * heads * (config.qk_nope_head_dim + wide)
+            + heads * wide * h),
+        "mla_core": blocks * 2.0 * heads * (qk + wide) * (seq_len + 1) / 2,
+        "dense_mlp": (config.first_k_dense_replace
+                      * 6.0 * h * config.intermediate_size),
+        "experts": routed * (
+            2.0 * h * config.router_experts + 6 * h * config.shared_width
+            + config.num_experts_per_tok * config.n_routed_experts
+            / config.router_experts * 6 * h * config.moe_intermediate_size),
+        "mtp_merge": module * 4.0 * h * h,
+        "head": head, "mtp_head": module * head}
+
+
 def causal_lm_train_flops_per_seq(config, seq_len: int) -> float:
     """Training (3x forward) matmul FLOPs of one row of ``seq_len`` tokens of
     a ``causal_lm`` family's model (by the config's ``model_type``)."""
@@ -319,6 +353,7 @@ def causal_lm_train_flops_per_seq(config, seq_len: int) -> float:
                  "zaya": zaya_forward_flops_per_token,
                  "qwen3_next": qwen3_next_forward_flops_per_token,
                  "KeyeVL2": keye_vl_forward_flops_per_token,
+                 "joyai_llm_flash": joyai_forward_flops_per_token,
                  }[config.model_type]
     return 3.0 * seq_len * sum(per_token(config, seq_len).values())
 

@@ -257,6 +257,8 @@ def test_ssd_scan_compiles_at_8192(chip):
     pytest.param(10240, 320, 512, 2048, id="qwen-down"),
     pytest.param(8192, 1024, 2048, 1536, id="keye-up"),
     pytest.param(8192, 1024, 768, 2048, id="keye-down"),
+    pytest.param(2048, 256, 2048, 1536, id="joyai-up"),  # a quarter of keye's
+    pytest.param(2048, 256, 768, 2048, id="joyai-down"),  # fill, its shapes
 ])
 def test_grouped_products_compile_at_the_cells_shapes(chip, monkeypatch, rows,
                                                       group, k, n):
@@ -316,6 +318,41 @@ def test_sparse_attention_compiles_at_16384(chip):
         assert any(f"/dsa/{scope}/" in name for name in names), scope
     _assert_kernel(compiled, "dsa_select", "dsa_core_fwd", "dsa_core_bwd_dq",
                    "dsa_core_bwd_dkv", "dsa_index_loss")
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 2 * 1024 ** 3
+
+
+def test_latent_attention_compiles_at_8192(chip):
+    """The joyai_llm_flash cell's attention layer whole at its geometry (one
+    row of 8192 tokens, 32 heads of 128 + 64 turned on one shared turned key
+    over values of 128, latents of 1536 and 512, beside a stream of 2048),
+    forward and backward: every scope of the layer, and under ``mla_core`` the
+    three causal flash kernels by their ``mla`` names at a head of a lane tile
+    and a half (K of a whole row is 12 MiB of VMEM in 256 lanes with V beside
+    it: ``_wide_head_params`` raises the limit); no [S, S] tensor in HBM."""
+    from bert_pytorch_tpu.config import JoyAIConfig
+    from bert_pytorch_tpu.models import joyai
+
+    layer = joyai.LatentAttention(JoyAIConfig(), jnp.bfloat16, "pallas")
+    params = jax.eval_shape(lambda: layer.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 512, 2048), jnp.bfloat16)))
+    sharding = SingleDeviceSharding(chip)
+    place = lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                              sharding=sharding)
+
+    def loss(variables, x):
+        out, _ = layer.apply(variables, x)
+        return jnp.sum(jnp.square(out.astype(jnp.float32)))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        jax.tree_util.tree_map(place, params),
+        place(jax.ShapeDtypeStruct((1, 8192, 2048), jnp.bfloat16))).compile()
+    names = set(re.findall(r'op_name="([^"]+)"', compiled.as_text()))
+    for scope in ("mla_q_proj", "mla_kv_proj", "attn_rope", "mla_core",
+                  "attn_out"):
+        assert any(f"/mla/{scope}/" in name for name in names), scope
+    _assert_kernel(compiled, "flash_mla_fwd", "flash_mla_bwd_dq",
+                   "flash_mla_bwd_dkv")
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 2 * 1024 ** 3
 

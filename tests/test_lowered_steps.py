@@ -49,6 +49,9 @@ PATHS = {
     "KeyeVL2": (('"dsa_select"', '"dsa_core_fwd"', '"dsa_core_bwd_dq"',
                  '"dsa_core_bwd_dkv"', '"dsa_index_loss"', "@tgmm"),
                 ('"flash_fwd"', "flash_gated")),
+    "joyai_llm_flash": (('"flash_mla_fwd"', '"flash_mla_bwd_dq"',
+                         '"flash_mla_bwd_dkv"', "@tgmm"),
+                        ('"flash_fwd"', '"rotary_turn"')),
     "kernel_bidirectional_dropout": (
         ("name=flash_fwd", "name=flash_bwd_dq", "name=flash_bwd_dkv",
          "prng_seed"), ("flash_window",)),
@@ -66,7 +69,8 @@ PATHS = {
 # family -> the tuple of pretrain that names its step's scopes
 SCOPES = {"nemotron_h": "CAUSAL_LM_SCOPES", "laguna": "LAGUNA_SCOPES",
           "phi4flash": "PHI_FLASH_SCOPES", "zaya": "ZAYA_SCOPES",
-          "qwen3_next": "QWEN3_NEXT_SCOPES", "KeyeVL2": "KEYE_SCOPES"}
+          "qwen3_next": "QWEN3_NEXT_SCOPES", "KeyeVL2": "KEYE_SCOPES",
+          "joyai_llm_flash": "JOYAI_SCOPES"}
 
 
 @pytest.fixture(scope="module")
@@ -136,6 +140,20 @@ def test_the_objectives_kernel_is_called_once_a_layer_and_micro_batch_trip(
     assert _kernel_call_sites(text, "dsa_select") == layers
     assert _kernel_call_sites(text, "dsa_core_fwd") == layers
     assert _kernel_call_sites(text, "dsa_core_bwd_dq") == layers
+
+
+def test_the_latent_cores_kernels_run_once_a_block_and_the_forward_twice(runs):
+    """``joyai_llm_flash.txt`` is two layers and the multi-token-prediction
+    module under ``--remat full``: three blocks of latent attention, each
+    holding the core's forward kernel in the forward pass and again in its
+    recompute (``remat='full'`` keeps no flash residual) and the two backward
+    kernels once."""
+    with open(os.path.join(runs[0], "joyai_llm_flash.txt")) as f:
+        text = f.read()
+    blocks = tool.SIZES["joyai_llm_flash"]["num_hidden_layers"] + 1
+    held = lambda kernel: text.count(f'kernel_name = "{kernel}"')
+    assert held("flash_mla_fwd") == 2 * blocks
+    assert held("flash_mla_bwd_dq") == held("flash_mla_bwd_dkv") == blocks
 
 
 def test_a_second_run_writes_the_same_bytes(runs):

@@ -23,7 +23,10 @@ element-wise pairs round it and the flash kernels at a head of 256),
 ``bert_phase1.txt`` (BERT at seq 128 with XLA attention, ``--remat dots``,
 LAMB, dropout drawn by ``rbg``: the phase-1 cells' path) and ``KeyeVL2.txt``
 (two layers of attention over the keys an indexer chooses, at the smallest
-shapes its kernels take: the choice's, the core's three and the objective's):
+shapes its kernels take: the choice's, the core's three and the objective's)
+and ``joyai_llm_flash.txt`` (a dense and an expert layer of latent attention
+and the multi-token-prediction module: the causal flash kernels at a head of
+128 + 64 against values of 128, the shared head run twice):
 ``make_train_step(...).trace(...).lower(lowering_platforms=("tpu",))`` as
 text, with the Mosaic payloads (the serialized kernels, which hold the
 checkout's path and line numbers) and the source locations cut out; and
@@ -99,6 +102,14 @@ SIZES = {
         moe_intermediate_size=128,
         sa_config=dict(indexer_num_heads=4, indexer_head_dim=64,
                        indexer_num_kv_heads=1, topk=128)),
+    # the published lane proportions of a head (128 + 64 turned against
+    # values of 128), one dense layer then one expert layer, and the module
+    "joyai_llm_flash": dict(
+        vocab_size=256, hidden_size=128, intermediate_size=256,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=4,
+        q_lora_rank=96, kv_lora_rank=64, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, n_routed_experts=4, ep_size=4,
+        ep_rank=1, num_experts_per_tok=3, moe_intermediate_size=128),
     "bert": dict(
         vocab_size=512, hidden_size=128, num_hidden_layers=2,
         num_attention_heads=2, intermediate_size=256,
@@ -218,6 +229,7 @@ def steps():
         ("qwen3_next", lambda: decoder_step("qwen3_next")),
         ("bert_phase1", lambda: bert_step(128, 20, "xla", lamb=True)),
         ("KeyeVL2", lambda: decoder_step("KeyeVL2")),
+        ("joyai_llm_flash", lambda: decoder_step("joyai_llm_flash")),
     )
 
 
