@@ -208,10 +208,11 @@ class ExpertLayer(nn.Module):
             return shared + routed.reshape(x.shape), counters
 
 
-def rematerialized(remat: str, block, without=()):
+def rematerialized(remat: str, block, without=(), keeping=()):
     """``block`` (a module class) under ``remat``'s policy less the names in
-    ``without`` (``ops/remat.py``); the class itself under 'none'."""
-    policy = remat_policy(remat, without)
+    ``without`` and, under 'full', with those in ``keeping``
+    (``ops/remat.py``); the class itself under 'none'."""
+    policy = remat_policy(remat, without, keeping)
     return block if policy is None else nn.remat(
         block, policy=policy, prevent_cse=True)
 
@@ -239,11 +240,21 @@ class CausalDecoder(nn.Module):
 
     def blocks(self, wrap) -> list:
         """The layers, in order; ``wrap`` rematerializes a block class, under
-        the policy less the names in its ``without`` (``ops/remat.py``)."""
+        the policy less the names in its ``without`` and with those in its
+        ``keeping`` (``ops/remat.py``)."""
         raise NotImplementedError
 
     def norm_epsilon(self) -> float:
         raise NotImplementedError
+
+    def kept_across_remat(self) -> dict:
+        """What the family asks ``remat='full'`` to keep beside
+        ``ops/remat.py KEPT_UNDER_FULL``, for the trainer's start-up line
+        (``ops/remat.py kept_residual_bytes``): ``keeping`` (the names its
+        ``blocks`` hand ``wrap``), ``regions`` (how many rematerialized
+        regions keep them) and the kept output's ``heads`` and ``head_dim``.
+        Empty for a family that asks for nothing, which is most."""
+        return {}
 
     def objective_terms(self) -> dict:
         """{counter: coefficient}: what the model returns beside its counters

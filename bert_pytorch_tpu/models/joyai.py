@@ -74,6 +74,7 @@ from bert_pytorch_tpu.ops import rope
 from bert_pytorch_tpu.ops.attention import (dot_product_attention,
                                             resolve_backend)
 from bert_pytorch_tpu.ops.pallas.attention import tiles_visited
+from bert_pytorch_tpu.ops.remat import FLASH_LSE, FLASH_OUT
 
 Dtype = Any
 MTP = "mtp"
@@ -212,6 +213,24 @@ class MTPModule(nn.Module):
             return norm(name="final_norm")(z), counters
 
 
+# What every rematerialized region of the family (a block, the module) keeps
+# under ``--remat full`` beside ops/remat.py ``KEPT_UNDER_FULL``: the flash
+# forward kernel's output and log-sum-exps, the residuals of its two backward
+# kernels, so that a region's recompute does not run the core a second time
+# only to hand them over. By what ONE chip holds at the published widths on a
+# micro-batch of one row of 8192 tokens: a region's two tensors are 32 x 8192
+# x 128 bfloat16 + 32 x 8192 float32 = 67,108,864 + 1,048,576 B = 68.2 MB,
+# 545.3 MB over the eight regions of the chip's share (seven layers and the
+# module; one micro-batch is live at a time), and with none kept 883-889 MB of
+# the chip's 16,909 MB are free. What the chip read with all eight keeping
+# (PERF.md 6, "PR 49"): ``memory_peak_bytes`` 16,024,163,840 for
+# 16,019,849,728, the step's temporaries 3,451,392 B more (the peak is not
+# where a region's residuals are live), no op of the compiler's own
+# rematerialization in the traced step, 32 forward calls of the core an
+# update for 64: so all regions keep, and there is no count to choose.
+KEPT_ACROSS_REMAT = (FLASH_OUT, FLASH_LSE)
+
+
 class JoyAIForCausalLM(CausalDecoder):
     config: JoyAIConfig
 
@@ -220,8 +239,9 @@ class JoyAIForCausalLM(CausalDecoder):
     def setup(self):
         super().setup()
         if self.config.num_nextn_predict_layers:
-            self.mtp = rematerialized(self.remat, MTPModule)(
-                self.config, self.dtype, self.attention_backend)
+            self.mtp = rematerialized(
+                self.remat, MTPModule, keeping=KEPT_ACROSS_REMAT)(
+                    self.config, self.dtype, self.attention_backend)
 
     def __call__(self, input_ids):
         if self.is_initializing() and self.prediction_streams():
@@ -230,13 +250,20 @@ class JoyAIForCausalLM(CausalDecoder):
 
     def blocks(self, wrap):
         cfg = self.config
-        block = wrap(JoyAIBlock)
+        block = wrap(JoyAIBlock, keeping=KEPT_ACROSS_REMAT)
         return [block(cfg, layer < cfg.first_k_dense_replace, self.dtype,
                       self.attention_backend)
                 for layer in range(cfg.num_hidden_layers)]
 
     def norm_epsilon(self):
         return self.config.rms_norm_eps
+
+    def kept_across_remat(self) -> dict:
+        cfg = self.config
+        return dict(
+            keeping=KEPT_ACROSS_REMAT,
+            regions=cfg.num_hidden_layers + cfg.num_nextn_predict_layers,
+            heads=cfg.num_attention_heads, head_dim=cfg.v_head_dim)
 
     def shared_inputs(self, seq):
         """The rotary tables of the turned part, made once a call."""

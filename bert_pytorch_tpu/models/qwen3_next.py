@@ -75,6 +75,7 @@ from bert_pytorch_tpu.models.decoder import (MOE_COUNTERS, CausalDecoder,
                                              normal)
 from bert_pytorch_tpu.ops import delta_rule, gdn_mix, rope
 from bert_pytorch_tpu.ops.attention import dot_product_attention
+from bert_pytorch_tpu.ops.remat import FLASH_LSE, FLASH_OUT
 
 Dtype = Any
 DELTA_COUNTERS = ("delta_chunks_run", "delta_kernel_chunks_run",
@@ -249,6 +250,21 @@ class Qwen3NextBlock(nn.Module):
         return x + out, {**counters, **chunks}
 
 
+# What every block keeps under ``--remat full`` beside ops/remat.py
+# ``KEPT_UNDER_FULL`` (the mechanism is ``remat_policy(keeping=)``; its first
+# user and the reasons are models/joyai.py's): the gated softmax attention's
+# flash output and log-sum-exps, so that its block's recompute does not run
+# ``flash_gated_fwd`` again; a delta-rule block carries neither name and
+# keeps what it kept. By what ONE chip holds at the published widths on a
+# micro-batch of two rows of 8192 tokens: 2 x 16 heads x 8192 x 256 bfloat16
+# + 2 x 16 x 8192 float32 = 134,217,728 + 1,048,576 B = 135.3 MB in the one
+# attention layer of the chip's period of four, where 0.98 GB are free. What
+# the chip read (PERF.md 6, "PR 49"): ``memory_peak_bytes`` 15,934,228,992
+# for 15,933,512,704, no op of the compiler's own rematerialization, 4
+# forward calls of the core an update for 8, tokens/s +2.5%.
+KEPT_ACROSS_REMAT = (FLASH_OUT, FLASH_LSE)
+
+
 class Qwen3NextForCausalLM(CausalDecoder):
     config: Qwen3NextConfig
 
@@ -256,12 +272,19 @@ class Qwen3NextForCausalLM(CausalDecoder):
     NORM = staticmethod(functools.partial(RMSNorm, offset=1))
 
     def blocks(self, wrap):
-        block = wrap(Qwen3NextBlock)
+        block = wrap(Qwen3NextBlock, keeping=KEPT_ACROSS_REMAT)
         return [block(self.config, layer, self.dtype, self.attention_backend)
                 for layer in range(self.config.num_hidden_layers)]
 
     def norm_epsilon(self):
         return self.config.rms_norm_eps
+
+    def kept_across_remat(self) -> dict:
+        cfg = self.config
+        return dict(
+            keeping=KEPT_ACROSS_REMAT,
+            regions=sum(kind != "linear_attention" for kind in cfg.layer_types),
+            heads=cfg.num_attention_heads, head_dim=cfg.head_dim)
 
     def shared_inputs(self, seq):
         """The rotary tables, made once a call and not in every attention

@@ -68,6 +68,7 @@ from bert_pytorch_tpu.models.decoder import (MOE_COUNTERS, CausalDecoder,
                                              normal)
 from bert_pytorch_tpu.ops import moe, rope, ssm
 from bert_pytorch_tpu.ops.attention import dot_product_attention
+from bert_pytorch_tpu.ops.remat import FLASH_LSE, FLASH_OUT
 
 Dtype = Any
 COUNTERS = MOE_COUNTERS + ("moe_skip_slots", "router_carried_layers")
@@ -278,6 +279,20 @@ class ZayaBlock(nn.Module):
                 counters)
 
 
+# What every block keeps under ``--remat full`` beside ops/remat.py
+# ``KEPT_UNDER_FULL`` (the mechanism is ``remat_policy(keeping=)``; its first
+# user and the reasons are models/joyai.py's): the causal core's output and
+# log-sum-exps, so that a block's recompute does not run ``flash_cca_fwd``
+# again. By what ONE chip holds at the published widths on a micro-batch of
+# two rows of 8192 tokens: 2 x 8 heads x 8192 x 128 bfloat16 + 2 x 8 x 8192
+# float32 = 33,554,432 + 524,288 B = 34.1 MB a layer, 170.4 MB over the five
+# layers of the chip's share, where 1.13 GB are free. What the chip read
+# (PERF.md 6, "PR 49"): ``memory_peak_bytes`` 15,780,656,128 for
+# 15,782,489,600, no op of the compiler's own rematerialization, 20 forward
+# calls of the core an update for 40, tokens/s +4.4%.
+KEPT_ACROSS_REMAT = (FLASH_OUT, FLASH_LSE)
+
+
 class ZayaForCausalLM(CausalDecoder):
     config: ZayaConfig
 
@@ -286,12 +301,17 @@ class ZayaForCausalLM(CausalDecoder):
     CARRIES = True
 
     def blocks(self, wrap):
-        block = wrap(ZayaBlock)
+        block = wrap(ZayaBlock, keeping=KEPT_ACROSS_REMAT)
         return [block(self.config, self.dtype, self.attention_backend)
                 for _ in range(self.config.num_hidden_layers)]
 
     def norm_epsilon(self):
         return self.config.rms_norm_eps
+
+    def kept_across_remat(self) -> dict:
+        cfg = self.config
+        return dict(keeping=KEPT_ACROSS_REMAT, regions=cfg.num_hidden_layers,
+                    heads=cfg.num_attention_heads, head_dim=cfg.head_dim)
 
     def shared_inputs(self, seq):
         """The rotary tables, made once a call and not in every layer of

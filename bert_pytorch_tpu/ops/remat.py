@@ -49,12 +49,20 @@ again. So the ops name them where they are made
     policy ``without`` the two names.
 
 ``remat='full'`` keeps these four names of the sparse attention's and nothing
-else: the user asked for least memory. The other names do nothing there, none
-does anything under ``remat='none'`` (no policy) or where no gradient is
-taken, and a name that no tensor of a program carries changes nothing in that
-program. ``remat_policy`` is the ONE place that builds the policy: the
-scanned encoder (models/bert.py), the decoders' layers (models/decoder.py)
-and the pipeline's stages (pretrain.py) all call it.
+else: the user asked for least memory. The other names do nothing there
+unless the caller asks for them by name (``keeping``): a decoder family
+whose chip has the room says that its blocks keep ``FLASH_OUT`` and
+``FLASH_LSE`` under 'full' too (``KEPT_ACROSS_REMAT`` in models/joyai.py,
+models/zaya.py and models/qwen3_next.py, each with its arithmetic), because
+the recompute of a block otherwise runs the flash forward kernel a second
+time only to hand the backward kernels those two; every other family says
+nothing and gets the four. No name does anything
+under ``remat='none'`` (no policy) or where no gradient is taken, and a name
+that no tensor of a program carries changes nothing in that program.
+``remat_policy`` is the ONE place that builds the policy: the scanned encoder
+(models/bert.py), the decoders' layers (models/decoder.py) and the
+pipeline's stages (pretrain.py) all call it, and ``kept_residual_bytes``
+asks it (``kept_names``) what a policy keeps.
 """
 
 from __future__ import annotations
@@ -72,40 +80,57 @@ DSA_CORE_LSE = "dsa_core_lse"
 KEPT_UNDER_FULL = (DSA_CHOICE, DSA_INDEX_GRADS, DSA_CORE_OUT, DSA_CORE_LSE)
 KEPT_NAMES = (KEEP_MASK, FLASH_OUT, FLASH_LSE) + KEPT_UNDER_FULL
 
-def remat_policy(remat: str, without: tuple = ()):
-    """The ``jax.checkpoint`` policy for a ``remat`` value; None for 'none'.
-    ``without``: names of ``KEPT_NAMES`` that this policy does not keep (a
-    model whose layers cannot all afford a name: models/keye_vl.py)."""
-    unknown = set(without) - set(KEPT_NAMES)
+
+def kept_names(remat: str, without: tuple = (), keeping: tuple = ()) -> tuple:
+    """The names of ``KEPT_NAMES`` that ``remat``'s policy keeps: all under
+    'dots', ``KEPT_UNDER_FULL`` and the caller's ``keeping`` under 'full',
+    none under 'none'; never one of ``without``."""
+    unknown = (set(without) | set(keeping)) - set(KEPT_NAMES)
     if unknown:
         raise ValueError(f"no kept name {sorted(unknown)}: {KEPT_NAMES}")
-    kept = lambda names: [name for name in names if name not in without]
+    if remat not in ("none", "dots", "full"):
+        raise ValueError(f"remat must be none|dots|full, got {remat!r}")
+    names = {"none": (), "dots": KEPT_NAMES,
+             "full": KEPT_UNDER_FULL + tuple(keeping)}[remat]
+    return tuple(name for name in KEPT_NAMES
+                 if name in names and name not in without)
+
+
+def remat_policy(remat: str, without: tuple = (), keeping: tuple = ()):
+    """The ``jax.checkpoint`` policy for a ``remat`` value; None for 'none'.
+    ``without``: names of ``KEPT_NAMES`` that this policy does not keep (a
+    model whose layers cannot all afford a name: models/keye_vl.py).
+    ``keeping``: names of ``KEPT_NAMES`` that this policy keeps under 'full'
+    beside ``KEPT_UNDER_FULL`` (a model whose chip has the room for them:
+    models/joyai.py); 'dots' keeps every name as it is."""
+    names = kept_names(remat, without, keeping)  # (refuses an unknown value)
     if remat == "none":
         return None
+    by_name = jax.checkpoint_policies.save_only_these_names(*names)
     if remat == "full":
-        return jax.checkpoint_policies.save_only_these_names(
-            *kept(KEPT_UNDER_FULL))
-    if remat == "dots":
-        return jax.checkpoint_policies.save_from_both_policies(
-            jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
-            jax.checkpoint_policies.save_only_these_names(*kept(KEPT_NAMES)))
-    raise ValueError(f"remat must be none|dots|full, got {remat!r}")
+        return by_name
+    return jax.checkpoint_policies.save_from_both_policies(
+        jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims, by_name)
 
 
 def kept_residual_bytes(remat: str, path: str, dropout: bool, batch: int,
-                        seq: int, heads: int, head_dim: int, dtype) -> dict:
-    """Bytes one layer keeps by name for one micro-batch of ``batch`` rows,
-    from shapes: {name: bytes}, empty where the policy keeps none. ``path``
-    is what attention runs (ops/attention.py ``resolve_backend``): the
-    'pallas' kernel, or the 'xla' path, which has a mask to keep only with
-    ``dropout`` on; the ring paths draw their own masks and name nothing."""
-    if remat != "dots":
-        return {}
+                        seq: int, heads: int, head_dim: int, dtype,
+                        keeping: tuple = ()) -> dict:
+    """Bytes one layer keeps of attention's names for one micro-batch of
+    ``batch`` rows, from shapes: {name: bytes}, empty where the policy
+    (``remat`` and the caller's ``keeping``, as ``remat_policy`` takes them)
+    keeps none of them. ``path`` is what attention runs (ops/attention.py
+    ``resolve_backend``): the 'pallas' kernel (``head_dim`` the VALUES'
+    width, which is the output's), or the 'xla' path, which has a mask to
+    keep only with ``dropout`` on; the ring paths draw their own masks and
+    name nothing."""
+    made = {}
     if path == "pallas":
-        return {
+        made = {
             FLASH_OUT: batch * heads * seq * head_dim * np.dtype(dtype).itemsize,
             FLASH_LSE: batch * heads * seq * 4,
         }
-    if path == "xla" and dropout:
-        return {KEEP_MASK: batch * heads * seq * seq}
-    return {}
+    elif path == "xla" and dropout:
+        made = {KEEP_MASK: batch * heads * seq * seq}
+    kept = kept_names(remat, keeping=keeping)
+    return {name: size for name, size in made.items() if name in kept}

@@ -445,22 +445,29 @@ def setup_training(args):
 
 
 def _kept_across_remat(model, config, micro_batch, seq) -> str:
-    """The start-up line that says what the layers keep across remat by
-    name (ops/remat.py) and what it costs, from shapes: per layer and
-    micro-batch on one data shard."""
-    dropout = config.attention_probs_dropout_prob > 0.0
+    """The start-up line that says what the rematerialized regions keep
+    across remat by name (ops/remat.py) and what it costs, from shapes: per
+    region and micro-batch on one data shard. The encoder's regions are its
+    layers, which keep attention's names under 'dots'; a decoder family says
+    what it asked 'full' for and in what shapes
+    (``CausalDecoder.kept_across_remat``)."""
+    asked = (model.kept_across_remat() if hasattr(model, "kept_across_remat")
+             else dict(keeping=(), regions=config.num_hidden_layers,
+                       heads=config.num_attention_heads,
+                       head_dim=config.head_dim))
+    dropout = getattr(config, "attention_probs_dropout_prob", 0.0) > 0.0
     path = resolve_backend(model.attention_backend, seq, dropout)
     kept = kept_residual_bytes(
         model.remat, path, dropout, batch=micro_batch, seq=seq,
-        heads=config.num_attention_heads, head_dim=config.head_dim,
-        dtype=model.dtype)
+        heads=asked["heads"], head_dim=asked["head_dim"], dtype=model.dtype,
+        keeping=asked["keeping"])
     line = f"remat {model.remat}, attention path {path} at seq {seq}: "
     if not kept:
         return line + "no named residual kept across remat"
-    total = sum(kept.values()) * config.num_hidden_layers
-    return line + "kept across remat per layer and micro-batch: " + ", ".join(
+    total = sum(kept.values()) * asked["regions"]
+    return line + "kept across remat per region and micro-batch: " + ", ".join(
         f"{name} {size} B" for name, size in kept.items()
-    ) + f" ({total / 1e9:.2f} GB over {config.num_hidden_layers} layers)"
+    ) + f" ({total / 1e9:.2f} GB over {asked['regions']} regions)"
 
 
 def prepare_model(args, mesh):
@@ -1097,7 +1104,7 @@ def main(args) -> dict:
                 if data_seq_len is None:
                     data_seq_len = int(batch["input_ids"].shape[-1])
                     placement["batch_devices"] = _devices_holding(batch)
-                    if not causal_lm:
+                    if not causal_lm or model.kept_across_remat():
                         logger.info(_kept_across_remat(
                             model, config, args.local_batch_size,
                             data_seq_len))

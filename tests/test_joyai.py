@@ -16,6 +16,7 @@ a latent norm left out or a target one place off (each moves the result by
 percents).
 """
 
+import functools
 import json
 
 import flax
@@ -134,6 +135,159 @@ def test_the_flash_kernels_at_a_head_of_192_over_values_of_128_match_the_xla_for
 
 
 # -- the attention layer ---------------------------------------------------------------
+
+# the smallest shapes the causal kernels take at the published lane
+# proportions: rows of 512, two heads of 128 + 64 over values of 128
+KERNEL_SIZES = dict(num_attention_heads=2, num_key_value_heads=2,
+                    qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128)
+# region -> (layers of the model, what the loss reads): a dense block alone
+# (``hidden_states`` never runs the module), the module alone (no layer ahead
+# of it, so the one core of the program is its block's)
+REGIONS = {"block": (1, "hidden_states"), "module": (0, "streams")}
+
+
+def _kernel_calls(jaxpr):
+    """The name of every ``pallas_call`` a jaxpr holds, the nested jaxprs'
+    too, in program order."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _kernel_calls(sub)
+    return found
+
+
+@functools.lru_cache(maxsize=None)
+def _region_grad(region, remat):
+    """(the gradient function of a loss through ONE rematerialized region of
+    the model as it builds them, its parameters): the kernels, interpreted."""
+    layers, method = REGIONS[region]
+    model = _model("pallas", remat, num_hidden_layers=layers,
+                   first_k_dense_replace=layers, **KERNEL_SIZES)
+    ids = jax.random.randint(keys(1, 11)[0], (1, 512), 0, TINY["vocab_size"])
+    params = flax.linen.unbox(model.init(keys(1, 12)[0], ids)["params"])
+
+    def loss(params):
+        out = model.apply({"params": params}, ids,
+                          method=getattr(model, method))
+        y = out[2][joyai.MTP] if region == "module" else out[0]
+        return jnp.sum(y * jnp.cos(y))
+    return jax.grad(loss), params
+
+
+@pytest.fixture(scope="module")
+def region_grads():
+    """(region, remat) -> the gradients, each program run once a module."""
+    made = {}
+
+    def of(region, remat):
+        if (region, remat) not in made:
+            grad, params = _region_grad(region, remat)
+            made[region, remat] = jax.jit(grad)(params)
+        return made[region, remat]
+    return of
+
+
+@pytest.mark.parametrize("remat", ["full", "dots", "none"])
+@pytest.mark.parametrize("region", sorted(REGIONS))
+def test_the_core_runs_once_a_region_under_a_gradient_whatever_the_remat(
+        region, remat, region_grads):
+    """A block and the module each keep the flash forward kernel's output and
+    log-sum-exps across remat by name (``joyai.KEPT_ACROSS_REMAT``), under
+    ``--remat full`` too: the gradient program through a rematerialized
+    region holds ONE ``flash_mla_fwd`` as it does without remat (two before:
+    the recompute ran the forward rule of the kernel's ``custom_vjp`` again),
+    the two backward kernels once each, and every gradient is
+    ``remat='none'``'s bit for bit: those of the three projections that make
+    q, k and v, which the core's gradients to q, k and v pass into, with the
+    rest (float32: on the chip a kept bfloat16 output beside recomputed q, k
+    and v moves them by rounding, PERF.md 6)."""
+    grad, params = _region_grad(region, remat)
+    made = _kernel_calls(jax.make_jaxpr(grad)(params).jaxpr)
+    assert sorted(made) == ["flash_mla_bwd_dkv", "flash_mla_bwd_dq",
+                            "flash_mla_fwd"], made
+    if remat == "none":
+        return
+    got, want = region_grads(region, remat), region_grads(region, "none")
+    block = want["mtp"]["block"] if region == "module" else want["layers_0"]
+    for name in ("q_a_proj", "q_b_proj", "kv_a_proj", "kv_b_proj"):
+        assert np.asarray(block["attention"][name]["kernel"]).any(), name
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_every_region_of_the_model_keeps_and_no_other_policy_does():
+    """Two layers and the module: three forward calls of the core in the
+    whole objective's gradient program under ``--remat full`` (six with the
+    plain policy, which is what ``rematerialized`` still builds for a family
+    that asks for nothing: ``flash_out`` and ``flash_lse`` are no names of
+    ``KEPT_UNDER_FULL``), each region's backward kernels straight after the
+    forward pass's own, none of them behind a second forward call."""
+    from bert_pytorch_tpu.models import decoder
+    from bert_pytorch_tpu.ops import remat
+
+    assert joyai.KEPT_ACROSS_REMAT == (remat.FLASH_OUT, remat.FLASH_LSE)
+    assert not set(joyai.KEPT_ACROSS_REMAT) & set(remat.KEPT_UNDER_FULL)
+    model = _model("pallas", "full", **KERNEL_SIZES)
+    ids = jnp.zeros((1, 512), jnp.int32)
+    params = flax.linen.unbox(jax.eval_shape(
+        model.init, keys(1)[0], ids)["params"])
+    objective = lambda p: _objective(model, ids)(p)[0]
+    made = _kernel_calls(jax.make_jaxpr(jax.grad(objective))(params).jaxpr)
+    backward = ["flash_mla_bwd_dq", "flash_mla_bwd_dkv"]
+    assert made == ["flash_mla_fwd"] * 3 + backward * 3, made
+
+    plain = decoder.rematerialized("full", joyai.JoyAIBlock)(
+        model.config, True, jnp.float32, "pallas")
+    x = jnp.zeros((1, 512, TINY["hidden_size"]), jnp.float32)
+    block_params = jax.eval_shape(plain.init, keys(1)[0], x)["params"]
+    through = lambda p: jnp.sum(plain.apply({"params": p}, x)[0])
+    made = _kernel_calls(jax.make_jaxpr(jax.grad(through))(
+        flax.linen.unbox(block_params)).jaxpr)
+    assert made == ["flash_mla_fwd"] * 2 + backward, made
+
+
+@pytest.mark.parametrize("config_file,micro_batch,asked,line", [
+    (CONFIG_FILE, 1, dict(regions=8, heads=32, head_dim=128),
+     "flash_out 67108864 B, flash_lse 1048576 B (0.55 GB over 8 regions)"),
+    ("benchmarks/configs/zaya1-8b.json", 2,
+     dict(regions=5, heads=8, head_dim=128),
+     "flash_out 33554432 B, flash_lse 524288 B (0.17 GB over 5 regions)"),
+    ("benchmarks/configs/qwen3-next-80b-a3b.json", 2,
+     dict(regions=1, heads=16, head_dim=256),
+     "flash_out 134217728 B, flash_lse 1048576 B (0.14 GB over 1 regions)"),
+], ids=["joyai", "zaya", "qwen3_next"])
+def test_the_start_up_line_says_what_the_family_keeps(
+        config_file, micro_batch, asked, line):
+    """``run_pretraining._kept_across_remat`` reads the family's own answer
+    (``kept_across_remat``: the names, the regions and the OUTPUT's shape:
+    joyai's values of 128 and not its keys of 192) through ``ops/remat.py
+    kept_residual_bytes``, at each cell's micro-batch of rows of 8192 (a
+    joyai region keeps 67,108,864 + 1,048,576 B, the issue's arithmetic); a
+    family that asks for nothing says so and gets no line."""
+    import run_pretraining
+    from bert_pytorch_tpu.models.laguna import LagunaForCausalLM
+    from bert_pytorch_tpu.ops import remat
+
+    config = load_model_config(config_file)
+    model = build_pretraining_model(config, jnp.bfloat16, remat="full",
+                                    attention_backend="pallas")
+    assert model.kept_across_remat() == dict(
+        asked, keeping=(remat.FLASH_OUT, remat.FLASH_LSE))
+    assert run_pretraining._kept_across_remat(
+        model, config, micro_batch, 8192) == (
+            "remat full, attention path pallas at seq 8192: kept across "
+            "remat per region and micro-batch: " + line)
+    for value, backend in (("none", "pallas"), ("full", "xla")):
+        other = build_pretraining_model(config, jnp.bfloat16, remat=value,
+                                        attention_backend=backend)
+        assert run_pretraining._kept_across_remat(
+            other, config, micro_batch, 8192).endswith(
+                "no named residual kept across remat")
+    assert LagunaForCausalLM(LagunaConfig()).kept_across_remat() == {}
+
 
 def test_latent_attention_matches_the_reference_with_every_cotangent():
     c, p = _seeded(4, loud=True)

@@ -142,18 +142,38 @@ def test_the_objectives_kernel_is_called_once_a_layer_and_micro_batch_trip(
     assert _kernel_call_sites(text, "dsa_core_bwd_dq") == layers
 
 
-def test_the_latent_cores_kernels_run_once_a_block_and_the_forward_twice(runs):
-    """``joyai_llm_flash.txt`` is two layers and the multi-token-prediction
-    module under ``--remat full``: three blocks of latent attention, each
-    holding the core's forward kernel in the forward pass and again in its
-    recompute (``remat='full'`` keeps no flash residual) and the two backward
-    kernels once."""
-    with open(os.path.join(runs[0], "joyai_llm_flash.txt")) as f:
-        text = f.read()
-    blocks = tool.SIZES["joyai_llm_flash"]["num_hidden_layers"] + 1
-    held = lambda kernel: text.count(f'kernel_name = "{kernel}"')
-    assert held("flash_mla_fwd") == 2 * blocks
-    assert held("flash_mla_bwd_dq") == held("flash_mla_bwd_dkv") == blocks
+@pytest.mark.parametrize("name,kernel,blocks", [
+    # every layer and the multi-token-prediction module
+    ("joyai_llm_flash", "flash_mla",
+     tool.SIZES["joyai_llm_flash"]["num_hidden_layers"] + 1),
+    ("zaya", "flash_cca", tool.SIZES["zaya"]["num_hidden_layers"]),
+    # one gated softmax attention in a period of four
+    ("qwen3_next", "flash_gated", 1),
+])
+def test_a_family_that_keeps_the_flash_residuals_runs_its_core_once(
+        runs, name, kernel, blocks):
+    """Under ``--remat full`` the families that ask for ``FLASH_OUT`` /
+    ``FLASH_LSE`` (``KEPT_ACROSS_REMAT`` in ``models/joyai.py``, ``zaya.py``,
+    ``qwen3_next.py``, through ``remat_policy(keeping=)``) hold the core's
+    forward kernel ONCE a block of attention (twice before PR 49, the second
+    in the block's recompute) and the two backward kernels once."""
+    assert _held(runs, name, kernel + "_fwd") == blocks
+    assert (_held(runs, name, kernel + "_bwd_dq")
+            == _held(runs, name, kernel + "_bwd_dkv") == blocks)
+
+
+@pytest.mark.parametrize("name", ["nemotron_h", "laguna"])
+def test_a_family_that_asks_for_nothing_still_runs_its_core_twice(runs, name):
+    """The hybrid's and laguna's cells stand at the chip's limit and ask
+    ``full`` for nothing: their causal forward runs in the forward pass and
+    again in each block's recompute, as before PR 49."""
+    assert (_held(runs, name, "flash_fwd")
+            == 2 * _held(runs, name, "flash_bwd_dq") > 0)
+
+
+def _held(runs, name, kernel):
+    with open(os.path.join(runs[0], name + ".txt")) as f:
+        return f.read().count(f'kernel_name = "{kernel}"')
 
 
 def test_a_second_run_writes_the_same_bytes(runs):

@@ -142,7 +142,8 @@ def test_the_kept_mask_is_one_byte_an_element():
 
 @pytest.mark.parametrize("path", sorted(PATHS))
 def test_full_still_keeps_nothing(path):
-    """Attention's three names add nothing to 'full' (its policy keeps four
+    """Attention's three names add nothing to 'full' where the caller asks
+    for none of them, as the encoder asks for none (its policy keeps four
     names, all the sparse attention's: asserted below, and no tensor of an
     encoder carries any of them): the costly op runs twice, and only the
     layer's inputs cross the scan."""
@@ -186,8 +187,27 @@ def test_one_function_builds_the_policy_for_both_sites():
         assert all(fewer(name_p, name=name)
                    for name in (remat.DSA_CHOICE, remat.DSA_INDEX_GRADS))
     assert remat.remat_policy("none", without=core) is None
+    # a family whose chip has the room asks 'full' for the flash kernel's two
+    # residuals by name, and gets them beside the four, and nothing else
+    flash = (remat.FLASH_OUT, remat.FLASH_LSE)
+    family = remat.remat_policy("full", keeping=flash)
+    assert all(family(name_p, name=name)
+               for name in flash + remat.KEPT_UNDER_FULL)
+    assert not family(name_p, name=remat.KEEP_MASK)
+    assert not family(name_p, name="some_other_name")
+    assert not family(jax.lax.dot_general_p) and not family(jax.lax.exp_p)
+    assert remat.kept_names("full", keeping=flash) == (
+        flash + remat.KEPT_UNDER_FULL)
+    assert remat.kept_names("full") == remat.KEPT_UNDER_FULL
+    assert remat.kept_names("dots", keeping=flash) == remat.KEPT_NAMES
+    assert remat.kept_names("none", keeping=flash) == ()
+    assert not any(remat.remat_policy("full", flash, flash)(name_p, name=name)
+                   for name in flash)  # ``without`` has the last word
+    assert remat.remat_policy("none", keeping=flash) is None
     with pytest.raises(ValueError, match="some_other_name"):
         remat.remat_policy("full", without=("some_other_name",))
+    with pytest.raises(ValueError, match="some_other_name"):
+        remat.remat_policy("full", keeping=("some_other_name",))
     with pytest.raises(ValueError, match="none|dots|full"):
         remat.remat_policy("some")
 
@@ -202,8 +222,35 @@ def test_one_function_builds_the_policy_for_both_sites():
 def test_kept_residual_bytes_from_shapes(path, batch, seq, want):
     shapes = dict(batch=batch, seq=seq, heads=16, head_dim=64,
                   dtype=jnp.bfloat16)
+    flash = (remat.FLASH_OUT, remat.FLASH_LSE)
     assert remat.kept_residual_bytes("dots", path, True, **shapes) == want
     assert remat.kept_residual_bytes("full", path, True, **shapes) == {}
     assert remat.kept_residual_bytes("none", path, True, **shapes) == {}
+    # a family that asks 'full' for the flash kernel's two gets those two
+    assert remat.kept_residual_bytes(
+        "full", path, True, keeping=flash, **shapes) == (
+            want if path == "pallas" else {})
+    assert remat.kept_residual_bytes(
+        "none", path, True, keeping=flash, **shapes) == {}
     if path == "xla":
         assert remat.kept_residual_bytes("dots", path, False, **shapes) == {}
+
+
+@pytest.mark.parametrize("remat_value,want", [
+    ("full", {remat.FLASH_OUT: 67_108_864, remat.FLASH_LSE: 1_048_576}),
+    ("dots", {remat.FLASH_OUT: 67_108_864, remat.FLASH_LSE: 1_048_576}),
+    ("none", {}),
+])
+def test_kept_residual_bytes_of_a_latent_block(remat_value, want):
+    """The joyai cell's block: one row of 8192, 32 heads over VALUES of 128
+    (the output's width, not the keys' 192) in bfloat16, the family's own
+    ``keeping``; without it 'full' keeps none."""
+    from bert_pytorch_tpu.models import joyai
+
+    shapes = dict(batch=1, seq=8192, heads=32, head_dim=128,
+                  dtype=jnp.bfloat16)
+    assert joyai.KEPT_ACROSS_REMAT == (remat.FLASH_OUT, remat.FLASH_LSE)
+    assert remat.kept_residual_bytes(
+        remat_value, "pallas", False, keeping=joyai.KEPT_ACROSS_REMAT,
+        **shapes) == want
+    assert remat.kept_residual_bytes("full", "pallas", False, **shapes) == {}
