@@ -17,6 +17,12 @@ Layout (mirrors SURVEY.md §2's component inventory):
   tools/      — offline pipeline: download / format / shard / vocab / encode
 """
 
+import time
+
+# When the program's first import began, on ``time.perf_counter_ns``: the
+# ``startup`` record's ``package_imported_s`` (telemetry/profiler.py).
+IMPORT_BEGAN_NS = time.perf_counter_ns()
+
 __version__ = "0.1.0"
 
 from bert_pytorch_tpu.config import BertConfig  # noqa: F401

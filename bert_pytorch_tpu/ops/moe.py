@@ -81,6 +81,7 @@ import jax
 import jax.numpy as jnp
 
 from bert_pytorch_tpu.ops.pallas.common import interpret_mode
+from bert_pytorch_tpu.utils import trace_parts
 
 GMM_TILE_ROWS = 512
 LANES = 128
@@ -172,10 +173,11 @@ def _tiled_forward(rows, weights, sizes, group_rows):
     from jax.experimental.pallas.ops.tpu.megablox.ops import backend as megablox
 
     k, n = weights.shape[1:]
-    out = megablox.gmm(
-        rows, weights, sizes, rows.dtype,
-        gmm_tiles(k, n, group_rows, rows.dtype.itemsize),
-        interpret=interpret_mode())
+    with trace_parts.kernel_build("gmm"):
+        out = megablox.gmm(
+            rows, weights, sizes, rows.dtype,
+            gmm_tiles(k, n, group_rows, rows.dtype.itemsize),
+            interpret=interpret_mode())
     return out, (rows, weights, sizes)
 
 
@@ -185,15 +187,17 @@ def _tiled_backward(group_rows, kept, d_out):
     rows, weights, sizes = kept
     (k, n), itemsize = weights.shape[1:], rows.dtype.itemsize
     # d_out [m, n] x weights[g]^T [n, k]: n is the contraction now
-    d_rows = megablox.gmm(
-        d_out, weights, sizes, rows.dtype,
-        gmm_tiles(n, k, group_rows, itemsize),
-        transpose_rhs=True, interpret=interpret_mode())
+    with trace_parts.kernel_build("gmm"):
+        d_rows = megablox.gmm(
+            d_out, weights, sizes, rows.dtype,
+            gmm_tiles(n, k, group_rows, itemsize),
+            transpose_rhs=True, interpret=interpret_mode())
     # (tgmm takes rows^T and turns it back itself: XLA drops the pair)
-    d_weights = megablox.tgmm(
-        rows.swapaxes(0, 1), d_out, sizes, weights.dtype,
-        gmm_tiles(k, n, group_rows, itemsize, weights_out=True),
-        num_actual_groups=weights.shape[0], interpret=interpret_mode())
+    with trace_parts.kernel_build("tgmm"):
+        d_weights = megablox.tgmm(
+            rows.swapaxes(0, 1), d_out, sizes, weights.dtype,
+            gmm_tiles(k, n, group_rows, itemsize, weights_out=True),
+            num_actual_groups=weights.shape[0], interpret=interpret_mode())
     return d_rows, d_weights, None
 
 

@@ -89,6 +89,7 @@ from jax.experimental.pallas import tpu as pltpu
 from bert_pytorch_tpu.ops.pallas import autotune
 from bert_pytorch_tpu.ops.pallas.common import interpret_mode, pick_block
 from bert_pytorch_tpu.ops.remat import FLASH_LSE, FLASH_OUT
+from bert_pytorch_tpu.utils import trace_parts
 
 _NEG_INF = -1e30
 
@@ -516,30 +517,32 @@ def _flash_forward(q3, k3, v3, bias3, seg3, seed, scale, rate, segmented,
     block_q, block_k = _pick_blocks(seq)
     g = _pick_bh_block(seq, bh)
     grid = (bh // g, seq // block_q)
-    out, lse = pl.pallas_call(
-        partial(_flash_fwd_kernel, block_k=block_k, scale=scale, rate=rate,
-                bh_block=g, **_static(segmented, causal, window)),
-        grid=grid,
-        in_specs=[
-            _seed_spec(),
-            pl.BlockSpec((g, block_q, depth), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((g, seq, depth), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((g, seq, depth_v), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((g, 1, seq), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((g, 1, seq), lambda b, i: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((g, block_q, depth_v), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((g, 1, block_q), lambda b, i: (b, 0, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, seq, depth_v), q3.dtype),
-            jax.ShapeDtypeStruct((bh, 1, seq), jnp.float32),
-        ],
-        name=_name("fwd", window, label),
-        interpret=interpret_mode(),
-        **_wide_head_params(g, seq, depth, depth_v, q3.dtype.itemsize),
-    )(seed, q3, k3, v3, bias3, seg3)
+    name = _name("fwd", window, label)
+    with trace_parts.kernel_build(name):
+        out, lse = pl.pallas_call(
+            partial(_flash_fwd_kernel, block_k=block_k, scale=scale, rate=rate,
+                    bh_block=g, **_static(segmented, causal, window)),
+            grid=grid,
+            in_specs=[
+                _seed_spec(),
+                pl.BlockSpec((g, block_q, depth), lambda b, i: (b, i, 0)),
+                pl.BlockSpec((g, seq, depth), lambda b, i: (b, 0, 0)),
+                pl.BlockSpec((g, seq, depth_v), lambda b, i: (b, 0, 0)),
+                pl.BlockSpec((g, 1, seq), lambda b, i: (b, 0, 0)),
+                pl.BlockSpec((g, 1, seq), lambda b, i: (b, 0, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((g, block_q, depth_v), lambda b, i: (b, i, 0)),
+                pl.BlockSpec((g, 1, block_q), lambda b, i: (b, 0, i)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((bh, seq, depth_v), q3.dtype),
+                jax.ShapeDtypeStruct((bh, 1, seq), jnp.float32),
+            ],
+            name=name,
+            interpret=interpret_mode(),
+            **_wide_head_params(g, seq, depth, depth_v, q3.dtype.itemsize),
+        )(seed, q3, k3, v3, bias3, seg3)
     return out, lse
 
 
@@ -575,58 +578,62 @@ def _flash_bwd(scale, rate, segmented, causal, window, label, residuals, g):
 
     gb = _pick_bh_block(seq, bh)
     wide = _wide_head_params(gb, seq, depth, depth_v, q3.dtype.itemsize)
-    dq = pl.pallas_call(
-        partial(_flash_dq_kernel, block_k=block_k, scale=scale, rate=rate,
-                bh_block=gb, **_static(segmented, causal, window)),
-        grid=(bh // gb, seq // block_q),
-        in_specs=[
-            _seed_spec(),
-            pl.BlockSpec((gb, block_q, depth), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((gb, seq, depth), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((gb, seq, depth_v), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((gb, 1, seq), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((gb, 1, seq), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((gb, 1, block_q), lambda b, i: (b, 0, i)),
-            pl.BlockSpec((gb, 1, block_q), lambda b, i: (b, 0, i)),
-            pl.BlockSpec((gb, block_q, depth_v), lambda b, i: (b, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((gb, block_q, depth), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, seq, depth), q3.dtype),
-        name=_name("bwd_dq", window, label),
-        interpret=interpret_mode(),
-        **wide,
-    )(seed, q3, k3, v3, bias3, seg3, lse, delta, g)
+    name = _name("bwd_dq", window, label)
+    with trace_parts.kernel_build(name):
+        dq = pl.pallas_call(
+            partial(_flash_dq_kernel, block_k=block_k, scale=scale, rate=rate,
+                    bh_block=gb, **_static(segmented, causal, window)),
+            grid=(bh // gb, seq // block_q),
+            in_specs=[
+                _seed_spec(),
+                pl.BlockSpec((gb, block_q, depth), lambda b, i: (b, i, 0)),
+                pl.BlockSpec((gb, seq, depth), lambda b, i: (b, 0, 0)),
+                pl.BlockSpec((gb, seq, depth_v), lambda b, i: (b, 0, 0)),
+                pl.BlockSpec((gb, 1, seq), lambda b, i: (b, 0, 0)),
+                pl.BlockSpec((gb, 1, seq), lambda b, i: (b, 0, 0)),
+                pl.BlockSpec((gb, 1, block_q), lambda b, i: (b, 0, i)),
+                pl.BlockSpec((gb, 1, block_q), lambda b, i: (b, 0, i)),
+                pl.BlockSpec((gb, block_q, depth_v), lambda b, i: (b, i, 0)),
+            ],
+            out_specs=pl.BlockSpec((gb, block_q, depth), lambda b, i: (b, i, 0)),
+            out_shape=jax.ShapeDtypeStruct((bh, seq, depth), q3.dtype),
+            name=name,
+            interpret=interpret_mode(),
+            **wide,
+        )(seed, q3, k3, v3, bias3, seg3, lse, delta, g)
 
-    dk, dv, dbias = pl.pallas_call(
-        partial(_flash_dkv_kernel, block_q=block_q, scale=scale, rate=rate,
-                bh_block=gb, **_static(segmented, causal, window)),
-        grid=(bh // gb, seq // block_k),
-        in_specs=[
-            _seed_spec(),
-            pl.BlockSpec((gb, seq, depth), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((gb, block_k, depth), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((gb, block_k, depth_v), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((gb, 1, block_k), lambda b, j: (b, 0, j)),
-            # seg needs the k tile AND every q block: full row, like lse.
-            pl.BlockSpec((gb, 1, seq), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((gb, 1, seq), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((gb, 1, seq), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((gb, seq, depth_v), lambda b, j: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((gb, block_k, depth), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((gb, block_k, depth_v), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((gb, 1, block_k), lambda b, j: (b, 0, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, seq, depth), k3.dtype),
-            jax.ShapeDtypeStruct((bh, seq, depth_v), v3.dtype),
-            jax.ShapeDtypeStruct((bh, 1, seq), jnp.float32),
-        ],
-        name=_name("bwd_dkv", window, label),
-        interpret=interpret_mode(),
-        **wide,
-    )(seed, q3, k3, v3, bias3, seg3, lse, delta, g)
+    name = _name("bwd_dkv", window, label)
+    with trace_parts.kernel_build(name):
+        dk, dv, dbias = pl.pallas_call(
+            partial(_flash_dkv_kernel, block_q=block_q, scale=scale, rate=rate,
+                    bh_block=gb, **_static(segmented, causal, window)),
+            grid=(bh // gb, seq // block_k),
+            in_specs=[
+                _seed_spec(),
+                pl.BlockSpec((gb, seq, depth), lambda b, j: (b, 0, 0)),
+                pl.BlockSpec((gb, block_k, depth), lambda b, j: (b, j, 0)),
+                pl.BlockSpec((gb, block_k, depth_v), lambda b, j: (b, j, 0)),
+                pl.BlockSpec((gb, 1, block_k), lambda b, j: (b, 0, j)),
+                # seg needs the k tile AND every q block: full row, like lse.
+                pl.BlockSpec((gb, 1, seq), lambda b, j: (b, 0, 0)),
+                pl.BlockSpec((gb, 1, seq), lambda b, j: (b, 0, 0)),
+                pl.BlockSpec((gb, 1, seq), lambda b, j: (b, 0, 0)),
+                pl.BlockSpec((gb, seq, depth_v), lambda b, j: (b, 0, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((gb, block_k, depth), lambda b, j: (b, j, 0)),
+                pl.BlockSpec((gb, block_k, depth_v), lambda b, j: (b, j, 0)),
+                pl.BlockSpec((gb, 1, block_k), lambda b, j: (b, 0, j)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((bh, seq, depth), k3.dtype),
+                jax.ShapeDtypeStruct((bh, seq, depth_v), v3.dtype),
+                jax.ShapeDtypeStruct((bh, 1, seq), jnp.float32),
+            ],
+            name=name,
+            interpret=interpret_mode(),
+            **wide,
+        )(seed, q3, k3, v3, bias3, seg3, lse, delta, g)
 
     dseed = np.zeros(seed.shape, dtype=jax.dtypes.float0)
     dseg = jnp.zeros_like(seg3)  # ids are data, not parameters
@@ -761,22 +768,23 @@ def flash_attention_infer(q, k, v, bias=None, sequence_ids=None,
     q3, k3, v3 = to3(q), to3(k), to3(v)
     bh = batch * heads
     block_q, block_k, g = _infer_geometry("infer", seq, bh, geometry)
-    out3 = pl.pallas_call(
-        partial(_infer_fwd_kernel, block_k=block_k, scale=scale,
-                bh_block=g, segmented=segmented),
-        grid=(bh // g, seq // block_q),
-        in_specs=[
-            pl.BlockSpec((g, block_q, depth), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((g, seq, depth), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((g, seq, depth), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((g, 1, seq), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((g, 1, seq), lambda b, i: (b, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((g, block_q, depth), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, seq, depth), q3.dtype),
-        name="flash_infer_fwd",
-        interpret=interpret_mode(),
-    )(q3, k3, v3, bias3, seg3)
+    with trace_parts.kernel_build("flash_infer_fwd"):
+        out3 = pl.pallas_call(
+            partial(_infer_fwd_kernel, block_k=block_k, scale=scale,
+                    bh_block=g, segmented=segmented),
+            grid=(bh // g, seq // block_q),
+            in_specs=[
+                pl.BlockSpec((g, block_q, depth), lambda b, i: (b, i, 0)),
+                pl.BlockSpec((g, seq, depth), lambda b, i: (b, 0, 0)),
+                pl.BlockSpec((g, seq, depth), lambda b, i: (b, 0, 0)),
+                pl.BlockSpec((g, 1, seq), lambda b, i: (b, 0, 0)),
+                pl.BlockSpec((g, 1, seq), lambda b, i: (b, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((g, block_q, depth), lambda b, i: (b, i, 0)),
+            out_shape=jax.ShapeDtypeStruct((bh, seq, depth), q3.dtype),
+            name="flash_infer_fwd",
+            interpret=interpret_mode(),
+        )(q3, k3, v3, bias3, seg3)
     return out3.reshape(batch, heads, seq, depth).transpose(0, 2, 1, 3)
 
 
@@ -865,24 +873,25 @@ def flash_attention_infer_int8(q, k, v, bias=None, sequence_ids=None,
     q8, q_scale = quant_ops.quantize_symmetric(q3, axes=(1, 2))
     k8, k_scale = quant_ops.quantize_symmetric(k3, axes=(1, 2))
     block_q, block_k, g = _infer_geometry("infer_int8", seq, bh, geometry)
-    out3 = pl.pallas_call(
-        partial(_infer_fwd_kernel_int8, block_k=block_k, scale=scale,
-                bh_block=g, segmented=segmented),
-        grid=(bh // g, seq // block_q),
-        in_specs=[
-            pl.BlockSpec((g, block_q, depth), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((g, seq, depth), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((g, seq, depth), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((g, 1, 1), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((g, 1, 1), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((g, 1, seq), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((g, 1, seq), lambda b, i: (b, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((g, block_q, depth), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, seq, depth), q3.dtype),
-        name="flash_infer_fwd_int8",
-        interpret=interpret_mode(),
-    )(q8, k8, v3, q_scale, k_scale, bias3, seg3)
+    with trace_parts.kernel_build("flash_infer_fwd_int8"):
+        out3 = pl.pallas_call(
+            partial(_infer_fwd_kernel_int8, block_k=block_k, scale=scale,
+                    bh_block=g, segmented=segmented),
+            grid=(bh // g, seq // block_q),
+            in_specs=[
+                pl.BlockSpec((g, block_q, depth), lambda b, i: (b, i, 0)),
+                pl.BlockSpec((g, seq, depth), lambda b, i: (b, 0, 0)),
+                pl.BlockSpec((g, seq, depth), lambda b, i: (b, 0, 0)),
+                pl.BlockSpec((g, 1, 1), lambda b, i: (b, 0, 0)),
+                pl.BlockSpec((g, 1, 1), lambda b, i: (b, 0, 0)),
+                pl.BlockSpec((g, 1, seq), lambda b, i: (b, 0, 0)),
+                pl.BlockSpec((g, 1, seq), lambda b, i: (b, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((g, block_q, depth), lambda b, i: (b, i, 0)),
+            out_shape=jax.ShapeDtypeStruct((bh, seq, depth), q3.dtype),
+            name="flash_infer_fwd_int8",
+            interpret=interpret_mode(),
+        )(q8, k8, v3, q_scale, k_scale, bias3, seg3)
     return out3.reshape(batch, heads, seq, depth).transpose(0, 2, 1, 3)
 
 

@@ -70,6 +70,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from bert_pytorch_tpu.ops.pallas import common
+from bert_pytorch_tpu.utils import trace_parts
 
 LANES = 128
 SUBLANES = 8
@@ -529,19 +530,20 @@ def _call(kernel, name, operands, results, args, key_heads, value_heads,
                    lambda i, h, c: (i, h, at(c), 0)),
     }
     spec = lambda kind: pl.BlockSpec(*kinds[kind][1:])
-    return pl.pallas_call(
-        partial(kernel, block=block, ratio=ratio),
-        grid=(batch, blocks, chunks),
-        in_specs=[spec(kind) for kind in operands],
-        out_specs=[spec(kind) for kind, _ in results],
-        out_shape=[jax.ShapeDtypeStruct(kinds[kind][0], dtype)
-                   for kind, dtype in results],
-        scratch_shapes=[pltpu.VMEM((held, LANES, LANES), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=VMEM_LIMIT_BYTES),
-        name=name, interpret=common.interpret_mode(),
-    )(*args)
+    with trace_parts.kernel_build(name):
+        return pl.pallas_call(
+            partial(kernel, block=block, ratio=ratio),
+            grid=(batch, blocks, chunks),
+            in_specs=[spec(kind) for kind in operands],
+            out_specs=[spec(kind) for kind, _ in results],
+            out_shape=[jax.ShapeDtypeStruct(kinds[kind][0], dtype)
+                       for kind, dtype in results],
+            scratch_shapes=[pltpu.VMEM((held, LANES, LANES), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=VMEM_LIMIT_BYTES),
+            name=name, interpret=common.interpret_mode(),
+        )(*args)
 
 
 _OPERANDS = ("key", "key", "value", "heads", "heads", "rows", "rows")

@@ -48,6 +48,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from bert_pytorch_tpu.ops.pallas import common
+from bert_pytorch_tpu.utils import trace_parts
 
 LANES = 128
 ROW_TILE = 16   # a bfloat16 sublane tile: the block the rows before come in
@@ -220,15 +221,16 @@ def gdn_mix_forward(q, k, v, taps_q, taps_k, taps_v, unit_scales, epsilon):
     kernel once.)"""
     arrays, taps = (q, k, v), (taps_q, taps_k, taps_v)
     grid, block, before, whole = _mix_specs(arrays, taps, backwards=False)
-    return pl.pallas_call(
-        partial(_mix_fwd_kernel, unit_scales=unit_scales, epsilon=epsilon),
-        grid=grid, in_specs=block + before + whole, out_specs=block,
-        out_shape=[jax.ShapeDtypeStruct(t.shape, t.dtype) for t in arrays],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
-            vmem_limit_bytes=VMEM_LIMIT_BYTES),
-        name="gdn_mix_fwd", interpret=common.interpret_mode(),
-    )(*arrays, *arrays, *taps)
+    with trace_parts.kernel_build("gdn_mix_fwd"):
+        return pl.pallas_call(
+            partial(_mix_fwd_kernel, unit_scales=unit_scales, epsilon=epsilon),
+            grid=grid, in_specs=block + before + whole, out_specs=block,
+            out_shape=[jax.ShapeDtypeStruct(t.shape, t.dtype) for t in arrays],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel"),
+                vmem_limit_bytes=VMEM_LIMIT_BYTES),
+            name="gdn_mix_fwd", interpret=common.interpret_mode(),
+        )(*arrays, *arrays, *taps)
 
 
 @partial(jax.jit, static_argnames=("unit_scales", "epsilon"))
@@ -239,20 +241,21 @@ def gdn_mix_backward(q, k, v, taps_q, taps_k, taps_v, dq, dk, dv,
     arrays, taps = (q, k, v), (taps_q, taps_k, taps_v)
     grid, block, before, whole = _mix_specs(arrays, taps, backwards=True)
     sums = [(w.shape[0], HALO, w.shape[1]) for w in taps]
-    *raw, dtaps_q, dtaps_k, dtaps_v = pl.pallas_call(
-        partial(_mix_bwd_kernel, unit_scales=unit_scales, epsilon=epsilon),
-        grid=grid, in_specs=block + before + whole + block,
-        out_specs=block + [pl.BlockSpec(shape, lambda b, i: (0, 0, 0))
-                           for shape in sums],
-        out_shape=[jax.ShapeDtypeStruct(t.shape, t.dtype) for t in arrays]
-        + [jax.ShapeDtypeStruct(shape, jnp.float32) for shape in sums],
-        scratch_shapes=[pltpu.VMEM((HALO, t.shape[2]), jnp.float32)
-                        for t in arrays],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=VMEM_LIMIT_BYTES),
-        name="gdn_mix_bwd", interpret=common.interpret_mode(),
-    )(*arrays, *arrays, *taps, dq, dk, dv)
+    with trace_parts.kernel_build("gdn_mix_bwd"):
+        *raw, dtaps_q, dtaps_k, dtaps_v = pl.pallas_call(
+            partial(_mix_bwd_kernel, unit_scales=unit_scales, epsilon=epsilon),
+            grid=grid, in_specs=block + before + whole + block,
+            out_specs=block + [pl.BlockSpec(shape, lambda b, i: (0, 0, 0))
+                               for shape in sums],
+            out_shape=[jax.ShapeDtypeStruct(t.shape, t.dtype) for t in arrays]
+            + [jax.ShapeDtypeStruct(shape, jnp.float32) for shape in sums],
+            scratch_shapes=[pltpu.VMEM((HALO, t.shape[2]), jnp.float32)
+                            for t in arrays],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+                vmem_limit_bytes=VMEM_LIMIT_BYTES),
+            name="gdn_mix_bwd", interpret=common.interpret_mode(),
+        )(*arrays, *arrays, *taps, dq, dk, dv)
     return (*raw, *(jnp.sum(d, axis=1) for d in (dtaps_q, dtaps_k, dtaps_v)))
 
 
@@ -304,19 +307,20 @@ def _norm_call(kernel, name, arrays, scale, epsilon, backward: bool):
     whole = pl.BlockSpec(scale.shape, lambda b, i: (0, 0))
     like_o = jax.ShapeDtypeStruct(arrays[0].shape, arrays[0].dtype)
     order = "arbitrary" if backward else "parallel"
-    return pl.pallas_call(
-        partial(kernel, epsilon=epsilon),
-        grid=(batch, seq // rows),
-        in_specs=[block, block, whole] + [block] * (len(arrays) - 2),
-        out_specs=[block, block, pl.BlockSpec(
-            (HALO, LANES), lambda b, i: (0, 0))] if backward else [block],
-        out_shape=[like_o, like_o, jax.ShapeDtypeStruct(
-            (HALO, LANES), jnp.float32)] if backward else [like_o],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=(order, order),
-            vmem_limit_bytes=VMEM_LIMIT_BYTES),
-        name=name, interpret=common.interpret_mode(),
-    )(arrays[0], arrays[1], scale, *arrays[2:])
+    with trace_parts.kernel_build(name):
+        return pl.pallas_call(
+            partial(kernel, epsilon=epsilon),
+            grid=(batch, seq // rows),
+            in_specs=[block, block, whole] + [block] * (len(arrays) - 2),
+            out_specs=[block, block, pl.BlockSpec(
+                (HALO, LANES), lambda b, i: (0, 0))] if backward else [block],
+            out_shape=[like_o, like_o, jax.ShapeDtypeStruct(
+                (HALO, LANES), jnp.float32)] if backward else [like_o],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=(order, order),
+                vmem_limit_bytes=VMEM_LIMIT_BYTES),
+            name=name, interpret=common.interpret_mode(),
+        )(arrays[0], arrays[1], scale, *arrays[2:])
 
 
 @partial(jax.jit, static_argnames=("epsilon",))

@@ -20,6 +20,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from bert_pytorch_tpu.ops.pallas.common import interpret_mode, pick_block
+from bert_pytorch_tpu.utils import trace_parts
 
 
 def _ln_fwd_kernel(x_ref, scale_ref, bias_ref, out_ref, mean_ref, rstd_ref, *, eps):
@@ -39,27 +40,28 @@ def _ln_forward(x2d, scale, bias, eps):
     rows, hidden = x2d.shape
     block_rows = pick_block(rows, (256, 128, 64, 32, 16, 8, 4, 2, 1))
     grid = (rows // block_rows,)
-    out, mean, rstd = pl.pallas_call(
-        partial(_ln_fwd_kernel, eps=eps),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, hidden), lambda i: (i, 0)),
-            pl.BlockSpec((hidden,), lambda i: (0,)),
-            pl.BlockSpec((hidden,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_rows, hidden), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((rows, hidden), x2d.dtype),
-            jax.ShapeDtypeStruct((rows, 1), jnp.float32),
-            jax.ShapeDtypeStruct((rows, 1), jnp.float32),
-        ],
-        name="layernorm_fwd",
-        interpret=interpret_mode(),
-    )(x2d, scale, bias)
+    with trace_parts.kernel_build("layernorm_fwd"):
+        out, mean, rstd = pl.pallas_call(
+            partial(_ln_fwd_kernel, eps=eps),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((block_rows, hidden), lambda i: (i, 0)),
+                pl.BlockSpec((hidden,), lambda i: (0,)),
+                pl.BlockSpec((hidden,), lambda i: (0,)),
+            ],
+            out_specs=[
+                pl.BlockSpec((block_rows, hidden), lambda i: (i, 0)),
+                pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
+                pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((rows, hidden), x2d.dtype),
+                jax.ShapeDtypeStruct((rows, 1), jnp.float32),
+                jax.ShapeDtypeStruct((rows, 1), jnp.float32),
+            ],
+            name="layernorm_fwd",
+            interpret=interpret_mode(),
+        )(x2d, scale, bias)
     return out, mean, rstd
 
 

@@ -44,6 +44,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from bert_pytorch_tpu.ops.pallas import common
+from bert_pytorch_tpu.utils import trace_parts
 
 LANES = 128
 ROW_TILE = 16  # a bfloat16 sublane tile
@@ -116,17 +117,18 @@ def rotary_turn(x, cos, sin, sign: int = 1):
     table = pl.BlockSpec((rows, cos.shape[-1]), lambda i: (i % blocks, 0))
     rows_shape, heads_shape = (batch * seq, width), (batch, heads, seq, head_dim)
     forward = sign > 0
-    out = pl.pallas_call(
-        partial(_turn_kernel, heads=heads, head_dim=head_dim, sign=sign),
-        grid=(batch * blocks,),
-        in_specs=[by_rows if forward else by_heads, table, table],
-        out_specs=by_heads if forward else by_rows,
-        out_shape=jax.ShapeDtypeStruct(
-            heads_shape if forward else rows_shape, x.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
-            vmem_limit_bytes=VMEM_LIMIT_BYTES),
-        interpret=common.interpret_mode(),
-        name="rotary_turn",
-    )(x.reshape(rows_shape) if forward else x.transpose(0, 2, 1, 3), cos, sin)
+    with trace_parts.kernel_build("rotary_turn"):
+        out = pl.pallas_call(
+            partial(_turn_kernel, heads=heads, head_dim=head_dim, sign=sign),
+            grid=(batch * blocks,),
+            in_specs=[by_rows if forward else by_heads, table, table],
+            out_specs=by_heads if forward else by_rows,
+            out_shape=jax.ShapeDtypeStruct(
+                heads_shape if forward else rows_shape, x.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",),
+                vmem_limit_bytes=VMEM_LIMIT_BYTES),
+            interpret=common.interpret_mode(),
+            name="rotary_turn",
+        )(x.reshape(rows_shape) if forward else x.transpose(0, 2, 1, 3), cos, sin)
     return out.transpose(0, 2, 1, 3) if forward else out.reshape(x.shape)

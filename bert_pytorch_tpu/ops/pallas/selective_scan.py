@@ -49,6 +49,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from bert_pytorch_tpu.ops.pallas.common import interpret_mode
+from bert_pytorch_tpu.utils import trace_parts
 
 LANES = 128
 ROWS = 8  # positions a trip of the time loop takes: one float32 sublane tile
@@ -209,20 +210,21 @@ def scan_forward(u, dt, b_wide, c_wide, a_t, chunk: int):
     states, block = a_t.shape[0], pick_block(channels)
     chunks, blocks = seq // chunk, channels // block
     row, wide, a_spec, piece = _specs(chunk, block, states, lambda c: c)
-    return pl.pallas_call(
-        partial(_fwd_kernel, chunk=chunk),
-        grid=(batch, chunks, blocks),
-        in_specs=[row, row, wide, wide, a_spec],
-        out_specs=[row, piece],
-        out_shape=[
-            jax.ShapeDtypeStruct((batch, seq, channels), jnp.float32),
-            jax.ShapeDtypeStruct((batch, chunks, states, channels),
-                                 jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((blocks, states, block), jnp.float32)],
-        compiler_params=_params(),
-        name="selective_scan_fwd",
-        interpret=interpret_mode(),
-    )(u, dt, b_wide, c_wide, a_t)
+    with trace_parts.kernel_build("selective_scan_fwd"):
+        return pl.pallas_call(
+            partial(_fwd_kernel, chunk=chunk),
+            grid=(batch, chunks, blocks),
+            in_specs=[row, row, wide, wide, a_spec],
+            out_specs=[row, piece],
+            out_shape=[
+                jax.ShapeDtypeStruct((batch, seq, channels), jnp.float32),
+                jax.ShapeDtypeStruct((batch, chunks, states, channels),
+                                     jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((blocks, states, block), jnp.float32)],
+            compiler_params=_params(),
+            name="selective_scan_fwd",
+            interpret=interpret_mode(),
+        )(u, dt, b_wide, c_wide, a_t)
 
 
 def scan_backward(u, dt, b_wide, c_wide, a_t, starts, dy, chunk: int):
@@ -234,21 +236,22 @@ def scan_backward(u, dt, b_wide, c_wide, a_t, starts, dy, chunk: int):
     chunks, blocks = seq // chunk, channels // block
     row, wide, a_spec, piece = _specs(
         chunk, block, states, lambda c: chunks - 1 - c)
-    return pl.pallas_call(
-        partial(_bwd_kernel, chunk=chunk),
-        grid=(batch, chunks, blocks),
-        in_specs=[row, row, wide, wide, a_spec, piece, row],
-        out_specs=[row, row, wide, wide, piece],
-        out_shape=[
-            jax.ShapeDtypeStruct((batch, seq, channels), jnp.float32),
-            jax.ShapeDtypeStruct((batch, seq, channels), jnp.float32),
-            jax.ShapeDtypeStruct((batch, seq, states, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((batch, seq, states, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((batch, chunks, states, channels),
-                                 jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((blocks, states, block), jnp.float32),
-                        pltpu.VMEM((chunk, states, block), jnp.float32)],
-        compiler_params=_params(),
-        name="selective_scan_bwd",
-        interpret=interpret_mode(),
-    )(u, dt, b_wide, c_wide, a_t, starts, dy)
+    with trace_parts.kernel_build("selective_scan_bwd"):
+        return pl.pallas_call(
+            partial(_bwd_kernel, chunk=chunk),
+            grid=(batch, chunks, blocks),
+            in_specs=[row, row, wide, wide, a_spec, piece, row],
+            out_specs=[row, row, wide, wide, piece],
+            out_shape=[
+                jax.ShapeDtypeStruct((batch, seq, channels), jnp.float32),
+                jax.ShapeDtypeStruct((batch, seq, channels), jnp.float32),
+                jax.ShapeDtypeStruct((batch, seq, states, LANES), jnp.float32),
+                jax.ShapeDtypeStruct((batch, seq, states, LANES), jnp.float32),
+                jax.ShapeDtypeStruct((batch, chunks, states, channels),
+                                     jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((blocks, states, block), jnp.float32),
+                            pltpu.VMEM((chunk, states, block), jnp.float32)],
+            compiler_params=_params(),
+            name="selective_scan_bwd",
+            interpret=interpret_mode(),
+        )(u, dt, b_wide, c_wide, a_t, starts, dy)

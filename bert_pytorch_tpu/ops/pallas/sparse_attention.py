@@ -65,6 +65,7 @@ from jax.experimental.pallas import tpu as pltpu
 from bert_pytorch_tpu.ops.pallas import common
 from bert_pytorch_tpu.ops.remat import (DSA_CORE_LSE, DSA_CORE_OUT,
                                         DSA_INDEX_GRADS)
+from bert_pytorch_tpu.utils import trace_parts
 
 WORD_LANES = 512          # keys a bit plane covers: the kernels' key tile
 WORD_BITS = 32
@@ -223,23 +224,24 @@ def select(qi, ki, w, topk: int):
     largest score, ties to the lower position."""
     batch, seq, heads, width = qi.shape
     rows = min(SELECT_ROWS, seq)
-    return pl.pallas_call(
-        partial(_select_kernel, topk=topk,
-                position_bits=max(1, (seq - 1).bit_length()),
-                scale=1.0 / math.sqrt(heads * width)),
-        grid=(batch, seq // rows),
-        in_specs=[
-            pl.BlockSpec((1, heads, rows, width), lambda b, i: (b, 0, i, 0)),
-            pl.BlockSpec((1, seq, width), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, heads, rows), lambda b, i: (b, 0, i)),
-        ],
-        out_specs=pl.BlockSpec((1, rows, WORD_LANES), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((batch, seq, WORD_LANES), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((seq // WORD_LANES, rows, WORD_LANES),
-                                   jnp.int32)],
-        compiler_params=_params("parallel", "parallel"),
-        name="dsa_select", interpret=common.interpret_mode(),
-    )(_heads_first(qi), ki, _heads_first(w))
+    with trace_parts.kernel_build("dsa_select"):
+        return pl.pallas_call(
+            partial(_select_kernel, topk=topk,
+                    position_bits=max(1, (seq - 1).bit_length()),
+                    scale=1.0 / math.sqrt(heads * width)),
+            grid=(batch, seq // rows),
+            in_specs=[
+                pl.BlockSpec((1, heads, rows, width), lambda b, i: (b, 0, i, 0)),
+                pl.BlockSpec((1, seq, width), lambda b, i: (b, 0, 0)),
+                pl.BlockSpec((1, heads, rows), lambda b, i: (b, 0, i)),
+            ],
+            out_specs=pl.BlockSpec((1, rows, WORD_LANES), lambda b, i: (b, i, 0)),
+            out_shape=jax.ShapeDtypeStruct((batch, seq, WORD_LANES), jnp.int32),
+            scratch_shapes=[pltpu.VMEM((seq // WORD_LANES, rows, WORD_LANES),
+                                       jnp.int32)],
+            compiler_params=_params("parallel", "parallel"),
+            name="dsa_select", interpret=common.interpret_mode(),
+        )(_heads_first(qi), ki, _heads_first(w))
 
 
 # -------------------------------------------------------------------- core
@@ -344,16 +346,17 @@ def _core_forward(q4, k3, v3, words, scale):
     kv = bkv // words.shape[0]
     rows = min(CORE_ROWS, seq)
     spec = _core_specs(kv, group, rows, seq, depth)
-    return pl.pallas_call(
-        partial(_core_fwd_kernel, scale=scale),
-        grid=(bkv, seq // rows),
-        in_specs=[spec["q"], spec["kv"], spec["kv"], spec["words"]],
-        out_specs=[spec["q"], spec["row"]],
-        out_shape=[jax.ShapeDtypeStruct(q4.shape, q4.dtype),
-                   jax.ShapeDtypeStruct((bkv, group, 1, seq), jnp.float32)],
-        compiler_params=_params("parallel", "parallel"),
-        name="dsa_core_fwd", interpret=common.interpret_mode(),
-    )(q4, k3, v3, words)
+    with trace_parts.kernel_build("dsa_core_fwd"):
+        return pl.pallas_call(
+            partial(_core_fwd_kernel, scale=scale),
+            grid=(bkv, seq // rows),
+            in_specs=[spec["q"], spec["kv"], spec["kv"], spec["words"]],
+            out_specs=[spec["q"], spec["row"]],
+            out_shape=[jax.ShapeDtypeStruct(q4.shape, q4.dtype),
+                       jax.ShapeDtypeStruct((bkv, group, 1, seq), jnp.float32)],
+            compiler_params=_params("parallel", "parallel"),
+            name="dsa_core_fwd", interpret=common.interpret_mode(),
+        )(q4, k3, v3, words)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(4,))
@@ -381,16 +384,17 @@ def _core_bwd(scale, residuals, cotangents):
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)[:, :, None, :]
     spec = _core_specs(kv, group, rows, seq, depth)
-    dq = pl.pallas_call(
-        partial(_core_dq_kernel, scale=scale),
-        grid=(bkv, seq // rows),
-        in_specs=[spec["q"], spec["kv"], spec["kv"], spec["words"],
-                  spec["row"], spec["row"], spec["q"]],
-        out_specs=spec["q"],
-        out_shape=jax.ShapeDtypeStruct(q4.shape, q4.dtype),
-        compiler_params=_params("parallel", "parallel"),
-        name="dsa_core_bwd_dq", interpret=common.interpret_mode(),
-    )(q4, k3, v3, words, lse, delta, do)
+    with trace_parts.kernel_build("dsa_core_bwd_dq"):
+        dq = pl.pallas_call(
+            partial(_core_dq_kernel, scale=scale),
+            grid=(bkv, seq // rows),
+            in_specs=[spec["q"], spec["kv"], spec["kv"], spec["words"],
+                      spec["row"], spec["row"], spec["q"]],
+            out_specs=spec["q"],
+            out_shape=jax.ShapeDtypeStruct(q4.shape, q4.dtype),
+            compiler_params=_params("parallel", "parallel"),
+            name="dsa_core_bwd_dq", interpret=common.interpret_mode(),
+        )(q4, k3, v3, words, lse, delta, do)
     # the query blocks above a key tile's diagonal are neither computed nor
     # fetched: their index is held at the first block the tile's keys see
     at = lambda j, i: jnp.maximum(i, (j * WORD_LANES) // rows)
@@ -399,21 +403,22 @@ def _core_bwd(scale, residuals, cotangents):
     tile_spec = pl.BlockSpec((1, WORD_LANES, depth), lambda b, j, i: (b, j, 0))
     row_spec = pl.BlockSpec((1, group, 1, rows),
                             lambda b, j, i: (b, 0, 0, at(j, i)))
-    dk, dv = pl.pallas_call(
-        partial(_core_dkv_kernel, scale=scale),
-        grid=(bkv, seq // WORD_LANES, seq // rows),
-        in_specs=[rows_spec, tile_spec, tile_spec,
-                  pl.BlockSpec((1, rows, WORD_LANES),
-                               lambda b, j, i: (b // kv, at(j, i), 0)),
-                  row_spec, row_spec, rows_spec],
-        out_specs=[tile_spec, tile_spec],
-        out_shape=[jax.ShapeDtypeStruct(k3.shape, k3.dtype),
-                   jax.ShapeDtypeStruct(v3.shape, v3.dtype)],
-        scratch_shapes=[pltpu.VMEM((WORD_LANES, depth), jnp.float32),
-                        pltpu.VMEM((WORD_LANES, depth), jnp.float32)],
-        compiler_params=_params("parallel", "parallel", "arbitrary"),
-        name="dsa_core_bwd_dkv", interpret=common.interpret_mode(),
-    )(q4, k3, v3, words, lse, delta, do)
+    with trace_parts.kernel_build("dsa_core_bwd_dkv"):
+        dk, dv = pl.pallas_call(
+            partial(_core_dkv_kernel, scale=scale),
+            grid=(bkv, seq // WORD_LANES, seq // rows),
+            in_specs=[rows_spec, tile_spec, tile_spec,
+                      pl.BlockSpec((1, rows, WORD_LANES),
+                                   lambda b, j, i: (b // kv, at(j, i), 0)),
+                      row_spec, row_spec, rows_spec],
+            out_specs=[tile_spec, tile_spec],
+            out_shape=[jax.ShapeDtypeStruct(k3.shape, k3.dtype),
+                       jax.ShapeDtypeStruct(v3.shape, v3.dtype)],
+            scratch_shapes=[pltpu.VMEM((WORD_LANES, depth), jnp.float32),
+                            pltpu.VMEM((WORD_LANES, depth), jnp.float32)],
+            compiler_params=_params("parallel", "parallel", "arbitrary"),
+            name="dsa_core_bwd_dkv", interpret=common.interpret_mode(),
+        )(q4, k3, v3, words, lse, delta, do)
     return dq, dk, dv, np.zeros(words.shape, dtype=jax.dtypes.float0)
 
 
@@ -526,23 +531,24 @@ def _index_loss_call(qi, ki, w, q, k, lse, words, with_grads: bool):
         out_specs += [by_rows(index_heads, width), whole, vectors(index_heads)]
         out_shape += [jax.ShapeDtypeStruct(t.shape, jnp.float32)
                       for t in (qi, ki, w)]
-    return pl.pallas_call(
-        partial(_index_loss_kernel,
-                index_scale=1.0 / math.sqrt(index_heads * width),
-                core_scale=1.0 / math.sqrt(depth)),
-        grid=(batch, seq // rows),
-        in_specs=[
-            by_rows(index_heads, width), whole, vectors(index_heads),
-            by_rows(heads, depth),
-            pl.BlockSpec((1, kv, seq, depth), lambda b, i: (b, 0, 0, 0)),
-            pl.BlockSpec((1, heads, 1, rows), lambda b, i: (b, 0, 0, i)),
-            pl.BlockSpec((1, rows, WORD_LANES), lambda b, i: (b, i, 0)),
-        ],
-        out_specs=out_specs, out_shape=out_shape,
-        # (every program of a row adds to the row's dki: one after the other)
-        compiler_params=_params("parallel", "arbitrary"),
-        name="dsa_index_loss", interpret=common.interpret_mode(),
-    )(qi, ki, w, q, k, lse, words)
+    with trace_parts.kernel_build("dsa_index_loss"):
+        return pl.pallas_call(
+            partial(_index_loss_kernel,
+                    index_scale=1.0 / math.sqrt(index_heads * width),
+                    core_scale=1.0 / math.sqrt(depth)),
+            grid=(batch, seq // rows),
+            in_specs=[
+                by_rows(index_heads, width), whole, vectors(index_heads),
+                by_rows(heads, depth),
+                pl.BlockSpec((1, kv, seq, depth), lambda b, i: (b, 0, 0, 0)),
+                pl.BlockSpec((1, heads, 1, rows), lambda b, i: (b, 0, 0, i)),
+                pl.BlockSpec((1, rows, WORD_LANES), lambda b, i: (b, i, 0)),
+            ],
+            out_specs=out_specs, out_shape=out_shape,
+            # (every program of a row adds to the row's dki: one after the other)
+            compiler_params=_params("parallel", "arbitrary"),
+            name="dsa_index_loss", interpret=common.interpret_mode(),
+        )(qi, ki, w, q, k, lse, words)
 
 
 @jax.custom_vjp

@@ -62,6 +62,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from bert_pytorch_tpu.ops.pallas import common
+from bert_pytorch_tpu.utils import trace_parts
 
 LANES = 128
 SUBLANES = 8
@@ -304,20 +305,21 @@ def _call(kernel, name, operands, results, args, heads, groups, chunk,
         "dd": ((batch, 1, width), (1, 1, width), lambda i, c: (i, 0, 0)),
     }
     spec = lambda kind: pl.BlockSpec(*kinds[kind][1:])
-    return pl.pallas_call(
-        partial(kernel, chunk=chunk, groups=groups, per=heads // groups,
-                hdim=width // heads, states=states),
-        grid=(batch, chunks),
-        in_specs=[spec(kind) for kind in operands],
-        out_specs=[spec(kind) for kind, _ in results],
-        out_shape=[jax.ShapeDtypeStruct(kinds[kind][0], dtype)
-                   for kind, dtype in results],
-        scratch_shapes=[pltpu.VMEM((states, width), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=VMEM_LIMIT_BYTES),
-        name=name, interpret=common.interpret_mode(),
-    )(*args)
+    with trace_parts.kernel_build(name):
+        return pl.pallas_call(
+            partial(kernel, chunk=chunk, groups=groups, per=heads // groups,
+                    hdim=width // heads, states=states),
+            grid=(batch, chunks),
+            in_specs=[spec(kind) for kind in operands],
+            out_specs=[spec(kind) for kind, _ in results],
+            out_shape=[jax.ShapeDtypeStruct(kinds[kind][0], dtype)
+                       for kind, dtype in results],
+            scratch_shapes=[pltpu.VMEM((states, width), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=VMEM_LIMIT_BYTES),
+            name=name, interpret=common.interpret_mode(),
+        )(*args)
 
 
 _OPERANDS = ("wide", "heads", "heads", "rows", "bc", "bc", "d")

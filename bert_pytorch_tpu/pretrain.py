@@ -35,6 +35,7 @@ from bert_pytorch_tpu.optim.transforms import (LossScaleState, OptState,
                                                opt_step_count)
 from bert_pytorch_tpu.parallel.mesh import AXIS_PIPE, AXIS_SEQ
 from bert_pytorch_tpu.parallel.sharding import params_shardings
+from bert_pytorch_tpu.utils import trace_parts
 
 # Every ``jax.named_scope`` the train steps write where Flax gives no module
 # name (here, models/losses.py, ops/attention.py, optim/transforms.py). They
@@ -151,7 +152,8 @@ def make_init_fn(model, tx, sample_inputs, shardings: TrainState):
 
     def init_fn(rng):
         init_rng, state_rng = jax.random.split(rng)
-        variables = nn.unbox(model.init(init_rng, *sample_inputs))
+        with trace_parts.modules():
+            variables = nn.unbox(model.init(init_rng, *sample_inputs))
         params = variables["params"]
         return TrainState(
             params=params, opt_state=tx.init(params), rng=state_rng
@@ -185,20 +187,21 @@ def _apply_pretraining_loss(model, variables, mb, rng, next_sentence,
     labels, masked_positions = _mlm_positions(
         mb["masked_lm_labels"], max_pred_per_seq
     )
-    out = model.apply(
-        variables,
-        mb["input_ids"],
-        mb["segment_ids"],
-        mb["input_mask"],
-        False,  # deterministic
-        masked_positions,
-        # Packed batches (data/packing.py) carry the extra arrays; absent
-        # keys select the unpacked model path unchanged.
-        mb.get("sequence_ids"),
-        mb.get("cls_positions"),
-        rngs={"dropout": rng},
-        **({"mutable": mutable} if mutable else {}),
-    )
+    with trace_parts.modules():  # the modules' Python, by class, at trace time
+        out = model.apply(
+            variables,
+            mb["input_ids"],
+            mb["segment_ids"],
+            mb["input_mask"],
+            False,  # deterministic
+            masked_positions,
+            # Packed batches (data/packing.py) carry the extra arrays; absent
+            # keys select the unpacked model path unchanged.
+            mb.get("sequence_ids"),
+            mb.get("cls_positions"),
+            rngs={"dropout": rng},
+            **({"mutable": mutable} if mutable else {}),
+        )
     (mlm_logits, nsp_logits), mutated = out if mutable else (out, None)
     loss = pretraining_loss(
         mlm_logits,
@@ -225,20 +228,20 @@ def _apply_causal_lm_loss(model, variables, mb):
     pieces, ragged = divmod(ids.shape[-1], LM_HEAD_PIECE)
     whole = bool(ragged) or pieces < 2
     streams = model.prediction_streams()
-    if not streams:
-        if whole:
-            logits, counters = model.apply(variables, ids)
-            loss, accuracy = next_token_loss(logits, ids)
-        else:  # long rows: the head and the loss in pieces of the sequence
-            hidden, counters = model.apply(variables, ids,
-                                           method="hidden_states")
-            loss, accuracy = chunked_next_token_loss(
-                hidden, model.head_kernel(variables["params"]), ids, pieces)
-    else:
-        hidden, counters, further = model.apply(variables, ids,
-                                                method="streams")
+    method = "streams" if streams else None if whole else "hidden_states"
+    with trace_parts.modules():  # the modules' Python, by class, at trace time
+        out = model.apply(variables, ids, method=method)
+    if streams:
+        hidden, counters, further = out
         loss, accuracy = _shared_head_loss(
             model, variables, hidden, ids, 1 if whole else pieces)
+    elif whole:
+        logits, counters = out
+        loss, accuracy = next_token_loss(logits, ids)
+    else:  # long rows: the head and the loss in pieces of the sequence
+        hidden, counters = out
+        loss, accuracy = chunked_next_token_loss(
+            hidden, model.head_kernel(variables["params"]), ids, pieces)
     for name, (shift, coefficient) in streams.items():
         term, right = _shared_head_loss(
             model, variables, further[name], ids, 1 if whole else pieces,
@@ -604,7 +607,7 @@ def make_train_step(
                        % kfac_inv_interval) == 0
             kfac_state = jax.lax.cond(
                 inv_due, kfac.inverse_factors, lambda s: s, kfac_state)
-        with jax.named_scope("optimizer"):
+        with jax.named_scope("optimizer"), trace_parts.optimizer():
             grads = jax.tree_util.tree_map(lambda g: g / accum_steps, grads)
 
             if kfac is not None:
@@ -875,10 +878,11 @@ def make_pp_train_step(
 
     def step_fn(state: TrainState, batch: dict, kfac_state=None):
         step_rng, new_rng = jax.random.split(state.rng)
-        (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            state.params, batch, step_rng
-        )
-        with jax.named_scope("optimizer"):
+        with trace_parts.modules():  # the stages' *.apply calls
+            (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+                state.params, batch, step_rng
+            )
+        with jax.named_scope("optimizer"), trace_parts.optimizer():
             if kfac is not None:
                 grads = kfac.precondition(
                     kfac_state, grads, schedule(opt_step_count(state.opt_state))

@@ -12,7 +12,11 @@ Emitted record (``kind="compile"``, schema.py)::
 
     {"kind": "compile", "fn": "train_step", "shapes_digest": "ab12…",
      "compile_s": 12.31, "trace_s": 0.21, "lower_s": 0.18,
-     "backend_compile_s": 11.90, "cache_load_s": 0.0, "cache": "miss"}
+     "backend_compile_s": 11.90, "cache_load_s": 0.0, "cache": "miss",
+     "trace_parts": {"modules": {"BertLayer": {"calls": 2, "self_s": 0.04}},
+                     "kernels": {"flash_fwd": {"builds": 2, "build_s": 0.05}},
+                     "optimizer_s": 0.03, "other_s": 0.09,
+                     "outside_trace_s": 0.0}}
 
 ``compile_s`` is host time round the wrapped call, whatever the call does
 (under a wrapper that blocks or copies inside it, that too). The other four
@@ -22,6 +26,27 @@ MLIR module) are the time covered by those events, an event inside another
 counted once; ``backend_compile_s`` is the time in JAX's compile-or-load
 call, which on a persistent-cache hit is mostly ``cache_load_s`` (the read
 and deserialization of the entry, fired on hits only).
+
+``trace_parts`` says what ``trace_s`` was spent on. While the wrapped call
+is on the stack the hooks of ``utils/trace_parts.py`` book host intervals
+into it where the work happens (pretrain.py round the model's ``apply`` and
+the optimizer's update, each ``pallas_call`` site of ops/pallas/ and the
+megablox calls of ops/moe.py): ``modules`` is the SELF time of the flax
+module methods by class (a method's duration less the intervals entered
+inside it; the ``KEPT_CLASSES`` largest, the rest summed under
+``OTHER_CLASSES``), ``kernels`` the kernel builds by the kernel's name (a
+build inside a module's method is taken out of that module's self time, so
+no interval is booked twice), ``optimizer_s`` the optimizer's update, and
+``other_s`` the remainder of ``trace_s``: JAX's own passes (linearize,
+transpose, remat's partial evaluation) and the step's Python outside model
+and optimizer. The four add up to ``trace_s``. JAX tells of a trace when it
+ends, so a hook cannot know whether it runs inside one: the outermost
+intervals are kept and cut against the call's trace spans when the record
+is made, and one that no span holds goes whole to ``outside_trace_s``
+instead. ``builds`` against the calls the device trace holds says which
+kernels share a trace: a site behind a jitted entry point is built once a
+distinct shape; any other at every call, in the primal body, in the forward
+rule and in the backward rule.
 
 ``cache`` is one of:
 
@@ -59,6 +84,7 @@ import time
 from typing import Callable, Optional
 
 from bert_pytorch_tpu.utils import compile_cache as compile_cache_util
+from bert_pytorch_tpu.utils import trace_parts as trace_parts_util
 
 _BACKEND_COMPILE_EVENTS = (
     "/jax/core/compile/backend_compile_duration",
@@ -75,8 +101,13 @@ _NESTING_EVENTS = {
 _CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+# trace_parts keeps the classes with the most self time, the rest as one row
+KEPT_CLASSES = 12
+OTHER_CLASSES = "(other classes)"
 
-_tls = threading.local()
+# The call on this thread's stack; the booking hooks of utils/trace_parts.py
+# read the same slot.
+_tls = trace_parts_util.tls
 
 
 def _current_call():
@@ -131,19 +162,69 @@ def _ensure_listeners() -> None:
         _installed = True
 
 
-def _new_call() -> dict:
+def _new_call(clock=None) -> dict:
+    # "book" is made by the first hook of utils/trace_parts.py that the call
+    # reaches, on "clock"
     return {"backend_compile_s": 0.0, "compiled": False, "cache_load_s": 0.0,
-            "cache_hits": 0, "cache_misses": 0, "trace": [], "lower": []}
+            "cache_hits": 0, "cache_misses": 0, "trace": [], "lower": [],
+            "clock": clock, "book": None}
 
 
 def _split(call: dict) -> dict:
     """The record's fields for where a call's compile time went."""
+    trace_s = round(sum((e - s for s, e in call["trace"]), 0.0), 4)
     return {
-        "trace_s": round(sum((e - s for s, e in call["trace"]), 0.0), 4),
+        "trace_s": trace_s,
         "lower_s": round(sum((e - s for s, e in call["lower"]), 0.0), 4),
         "backend_compile_s": round(call["backend_compile_s"], 4),
         "cache_load_s": round(call["cache_load_s"], 4),
+        "trace_parts": _trace_parts(call, trace_s),
     }
+
+
+def _trace_parts(call: dict, trace_s: float) -> dict:
+    """What ``trace_s`` was spent on, from the call's book (module
+    docstring): ``modules`` ({class: calls, self_s}), ``kernels`` ({name:
+    builds, build_s}), ``optimizer_s`` and, as the remainder, ``other_s``:
+    the four add up to ``trace_s``. An outermost booked interval that lies
+    outside every trace span is taken whole to ``outside_trace_s`` and to
+    none of the four."""
+    book = call["book"]
+    modules, kernels = {}, {}
+    optimizer_s = outside_s = 0.0
+    done = 0
+    for start, end, upto in (book.tops if book else ()):
+        middle = (start + end) / 2 + book.spans_ahead_s
+        traced = any(s <= middle <= e for s, e in call["trace"])
+        for kind, name, own_s in book.booked[done:upto]:
+            if not traced:
+                outside_s += own_s
+            elif kind == trace_parts_util.OPTIMIZER:
+                optimizer_s += own_s
+            else:
+                row = (modules if kind == trace_parts_util.MODULE
+                       else kernels).setdefault(name, [0, 0.0])
+                row[0] += 1
+                row[1] += own_s
+        done = upto
+    ranked = sorted(modules.items(), key=lambda kv: -kv[1][1])
+    rest = ranked[KEPT_CLASSES:]
+    if rest:
+        ranked = ranked[:KEPT_CLASSES] + [(OTHER_CLASSES, [
+            sum(r[0] for _, r in rest), sum(r[1] for _, r in rest)])]
+    parts = {
+        "modules": {name: {"calls": n, "self_s": round(s, 4)}
+                    for name, (n, s) in ranked},
+        "kernels": {name: {"builds": n, "build_s": round(s, 4)}
+                    for name, (n, s) in sorted(kernels.items())},
+        "optimizer_s": round(optimizer_s, 4),
+    }
+    parts["other_s"] = round(
+        trace_s - sum(m["self_s"] for m in parts["modules"].values())
+        - sum(k["build_s"] for k in parts["kernels"].values())
+        - parts["optimizer_s"], 4)
+    parts["outside_trace_s"] = round(outside_s, 4)
+    return parts
 
 
 def shapes_digest(tree) -> str:
@@ -219,7 +300,7 @@ class CompileMonitor:
 
         def wrapper(*args, **kwargs):
             prev = _current_call()
-            call = _new_call()
+            call = _new_call(self._clock)
             _tls.call = call
             t0 = self._clock()
             try:
@@ -285,7 +366,7 @@ class CompileMonitor:
         # The analysis lowers the function again and asks for its
         # executable (memory.py): what that costs, and where, is start-up
         # time like the first call's.
-        prev, call = _current_call(), _new_call()
+        prev, call = _current_call(), _new_call(self._clock)
         _tls.call = call
         t0 = self._clock()
         try:

@@ -15,7 +15,7 @@ Two halves, both feeding the JSONL stream:
 * :func:`analyze_executable` — static attribution for one jitted
   function: HLO ``cost_analysis`` (FLOPs, bytes accessed) and — when a
   compile is affordable — ``compiled.memory_analysis()``
-  (argument/output/temp/generated-code bytes). The CompileMonitor calls
+  (argument/output/temp bytes). The CompileMonitor calls
   it once per (fn, shapes-digest) and joins the result to the compile
   event's digest, so every compile in the stream carries its cost.
 
@@ -161,9 +161,7 @@ def analyze_executable(fn, args, kwargs, mode: str = "auto"):
             for name, key in (
                     ("argument_bytes", "argument_size_in_bytes"),
                     ("output_bytes", "output_size_in_bytes"),
-                    ("temp_bytes", "temp_size_in_bytes"),
-                    ("alias_bytes", "alias_size_in_bytes"),
-                    ("generated_code_bytes", "generated_code_size_in_bytes")):
+                    ("temp_bytes", "temp_size_in_bytes")):
                 value = getattr(mem, key, None)
                 if value is not None:
                     fields[name] = int(value)

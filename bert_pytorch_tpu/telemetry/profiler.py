@@ -53,7 +53,11 @@ from typing import Optional, Tuple
 
 import jax
 
-_IMPORTED_NS = time.perf_counter_ns()  # process_origin()'s fallback
+import bert_pytorch_tpu
+
+# When the package began to be imported: the record's ``package_imported_s``,
+# and process_origin()'s fallback.
+_IMPORTED_NS = bert_pytorch_tpu.IMPORT_BEGAN_NS
 
 # Every span the program writes, on the thread that writes it. The
 # ``train:*`` spans nest in the step's ``train`` annotation on the loop's
@@ -144,7 +148,7 @@ def process_origin() -> Tuple[int, str]:
     comes from). On Linux the kernel's own stamp (``/proc/self/stat`` field
     22, in clock ticks since boot: 10 ms steps) against ``CLOCK_BOOTTIME``
     now, so interpreter start and imports are inside; elsewhere the moment
-    this module was imported, which leaves out what came before it."""
+    the package began to be imported, which leaves out what came before."""
     global _origin
     if _origin is None:
         _origin = (_IMPORTED_NS, "package_import")
@@ -161,14 +165,28 @@ def process_origin() -> Tuple[int, str]:
     return _origin
 
 
+IMPORTS_NAMED = 20
+
+
+def imported_between(before: set, after: set) -> dict:
+    """The record's ``imported_in_first_call`` from two snapshots of
+    ``sys.modules``' keys: how many entries came between them, and the first
+    ``IMPORTS_NAMED`` new top-level package names."""
+    new = after - before
+    return {"modules": len(new),
+            "packages": sorted({name.partition(".")[0]
+                                for name in new})[:IMPORTS_NAMED]}
+
+
 def startup_record(store: StartupSpans, feed_start_s: float,
                    feed_end_s: float, call_end_s: float, sync_end_s: float,
-                   compiles: list) -> dict:
+                   compiles: list, imported: Optional[dict] = None) -> dict:
     """The one ``kind="startup"`` record of a run (schema.py; the sibling of
     serving's ``serve_cold_start``). The four ``*_s`` arguments are the
     step timer's ``perf_counter`` marks of the first update (its feed
     entered and left, its step call returned) and the end of the runner's
-    barrier on that update; ``compiles`` are the ``compile`` records so far.
+    barrier on that update; ``compiles`` are the ``compile`` records so far;
+    ``imported`` is :func:`imported_between` over the first step call.
     Every stamp in the record is in seconds since the process was created."""
     origin_ns, origin = process_origin()
 
@@ -189,6 +207,9 @@ def startup_record(store: StartupSpans, feed_start_s: float,
     pair = (time.perf_counter_ns(), time.time_ns())
     return {
         "kind": "startup", "tag": "telemetry", "origin": origin,
+        # main_entered_s in two: up to the program's first import
+        # (interpreter, the caller's own start), and from there
+        "package_imported_s": since(_IMPORTED_NS),
         "main_entered_s": entered,
         "phases": phases,
         "first_batch_wait_s": wait,
@@ -203,6 +224,9 @@ def startup_record(store: StartupSpans, feed_start_s: float,
         "compiles_cold": sum(1 for c in compiles
                              if c.get("cache") in ("miss", "uncached")),
         "compiles_warm": sum(1 for c in compiles if c.get("cache") == "hit"),
+        # what sys.modules gained inside the first step call: an import
+        # that runs there is inside first_call_s, and inside trace_s
+        "imported_in_first_call": imported or {"modules": 0, "packages": []},
         # perf_counter_ns and time_ns read together: a stamp s of this
         # record is unix time time_ns + (origin + s * 1e9 - perf_counter_ns),
         # the clock of a trace's events (docs/telemetry.md "Start-up")

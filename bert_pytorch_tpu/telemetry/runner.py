@@ -47,6 +47,7 @@ from __future__ import annotations
 import contextlib
 import itertools
 import math
+import sys
 import time
 from typing import Callable, Iterator, Optional
 
@@ -54,7 +55,8 @@ from bert_pytorch_tpu.telemetry.compile_events import CompileMonitor
 from bert_pytorch_tpu.telemetry.memory import MemorySampler
 from bert_pytorch_tpu.telemetry.model_stats import (DivergenceMonitor,
                                                     health_record)
-from bert_pytorch_tpu.telemetry.profiler import (ProfilerWindow, span,
+from bert_pytorch_tpu.telemetry.profiler import (ProfilerWindow,
+                                                 imported_between, span,
                                                  startup_record)
 from bert_pytorch_tpu.telemetry.sampler import CaptureController
 from bert_pytorch_tpu.telemetry.sentinels import (FailureSentinel, Heartbeat,
@@ -162,6 +164,11 @@ class TrainTelemetry:
         self._prefetcher = None
         self._last_sync_target = None
         self.last_step_synced = False
+        # sys.modules' keys as the first step call is entered, and what it
+        # gained by the call's end (the startup record's
+        # imported_in_first_call): two snapshots, once a run
+        self._modules_before = None
+        self._imported = None
 
     # -- wiring ---------------------------------------------------------
 
@@ -206,7 +213,7 @@ class TrainTelemetry:
         self.emit(startup_record(
             startup, *self.timer.marks(), self._clock(),
             [e for e in self.compile_monitor.events
-             if e.get("kind") == "compile"]))
+             if e.get("kind") == "compile"], self._imported))
 
     @contextlib.contextmanager
     def checkpoint_stall(self):
@@ -251,6 +258,8 @@ class TrainTelemetry:
                     # prefetcher; record how much of the wait was H2D
                     # staging (0.0 when the batch was already resident).
                     self.timer.note_h2d(self._prefetcher.pop_h2d_wait_s())
+                if step == first_step:
+                    self._modules_before = set(sys.modules)
                 yield item
             # Startup trace window's auto-stop, once its last step's
             # annotation has closed (so the trace holds that step whole).
@@ -258,6 +267,9 @@ class TrainTelemetry:
 
     def dispatch_done(self) -> None:
         self.timer.dispatch_end()
+        before, self._modules_before = self._modules_before, None
+        if before is not None:
+            self._imported = imported_between(before, set(sys.modules))
 
     def step_done(self, step: int, metrics: Optional[dict] = None,
                   sync_target=None,

@@ -342,6 +342,9 @@ def validate_record(rec) -> list:
                     _check_cold_start_fields(rec, errors)
                 if kind == "startup":
                     _check_startup_fields(rec, errors)
+                if kind in ("compile", "compile_cost") and \
+                        "trace_parts" in rec:
+                    _check_trace_parts(rec, errors)
                 if kind == "serve_trace":
                     _check_trace_fields(rec, errors)
                 if kind == "serve_phase":
@@ -537,9 +540,24 @@ def _check_startup_fields(rec, errors) -> None:
             and (p.get("parent") is None or isinstance(p["parent"], str))
             for p in phases):
         bad.append("phases")
+    imported = rec.get("imported_in_first_call")
+    if imported is not None and not (
+            isinstance(imported, dict)
+            and isinstance(imported.get("modules"), int)
+            and isinstance(imported.get("packages"), list)
+            and all(isinstance(p, str) for p in imported["packages"])
+            and 0 <= len(imported["packages"]) <= imported["modules"]):
+        errors.append("imported_in_first_call must hold modules (a count) "
+                      "and packages (names, no more of them than modules), "
+                      f"got {imported!r}")
     if bad:
         errors.append(f"startup fields malformed or negative: {bad}")
         return
+    began = rec.get("package_imported_s", 0.0)
+    if not (_is_number(began)
+            and 0 <= began <= rec["main_entered_s"] + _STARTUP_EPS_S):
+        errors.append("package_imported_s must lie between the process's "
+                      f"creation and main_entered_s, got {began!r}")
     _check_cold_start_fields(
         {"cold_start_s": rec["time_to_first_update_s"], **rec}, errors)
     lo, hi = rec["main_entered_s"], rec["time_to_first_update_s"]
@@ -566,6 +584,46 @@ def _check_startup_fields(rec, errors) -> None:
         errors.append(
             f"startup parts add up to {total:.6f} s, not to "
             f"time_to_first_update_s - main_entered_s = {hi - lo:.6f} s")
+
+
+# each of a few dozen parts is rounded to 1e-4 s before the sum is taken
+_TRACE_PARTS_EPS_S = 5e-3
+
+
+def _check_trace_parts(rec, errors) -> None:
+    """``trace_parts`` of a ``compile`` / ``compile_cost`` record
+    (telemetry/compile_events.py): counts are whole and positive, no part is
+    negative, and the modules' self seconds, the kernels' build seconds,
+    ``optimizer_s`` and ``other_s`` add up to the record's ``trace_s``
+    (``outside_trace_s`` is none of them)."""
+    parts = rec["trace_parts"]
+    tables = (("modules", "calls", "self_s"), ("kernels", "builds", "build_s"))
+    if not isinstance(parts, dict) or not all(
+            isinstance(parts.get(table), dict) and all(
+                isinstance(row, dict) and isinstance(row.get(count), int)
+                and row[count] > 0 and _is_number(row.get(seconds))
+                for row in parts[table].values())
+            for table, count, seconds in tables) or not all(
+            _is_number(parts.get(k)) for k in
+            ("optimizer_s", "other_s", "outside_trace_s")) or \
+            not _is_number(rec.get("trace_s")):
+        errors.append("trace_parts must hold modules {class: calls, self_s}, "
+                      "kernels {name: builds, build_s}, optimizer_s, other_s "
+                      "and outside_trace_s beside a numeric trace_s")
+        return
+    named = {f"{table}[{name!r}].{seconds}": row[seconds]
+             for table, _, seconds in tables
+             for name, row in parts[table].items()}
+    named.update({k: parts[k] for k in
+                  ("optimizer_s", "other_s", "outside_trace_s")})
+    negative = [k for k, v in named.items() if v < -_TRACE_PARTS_EPS_S]
+    if negative:
+        errors.append(f"trace_parts holds negative seconds: {negative}")
+    total = sum(named.values()) - parts["outside_trace_s"]
+    if abs(total - rec["trace_s"]) > _TRACE_PARTS_EPS_S:
+        errors.append(
+            f"trace_parts add up to {total:.4f} s, not to trace_s = "
+            f"{rec['trace_s']:.4f} s")
 
 
 def _check_trace_fields(rec, errors) -> None:

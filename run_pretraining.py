@@ -747,7 +747,13 @@ def main(args) -> dict:
             b_shardings = pretrain.batch_shardings(
                 mesh, batch_spec, seq_sharded=args.mesh_spec.seq > 1)
             init_fn = pretrain.make_init_fn(model, tx, sample, shardings)
-            state = init_fn(jax.random.PRNGKey(args.seed))
+            # The init program's own ``compile`` record (fn "init_state":
+            # traced, lowered, compiled or loaded, persisted or not), held
+            # until the telemetry that emits it exists.
+            init_compiles = []
+            state = telemetry.CompileMonitor(
+                emit=init_compiles.append).instrument(
+                    init_fn, "init_state")(jax.random.PRNGKey(args.seed))
 
             if checkpoint is not None:
                 with telemetry.span("startup:restore"):
@@ -923,6 +929,8 @@ def main(args) -> dict:
                 output_dir=args.output_dir,
                 process="pretrain")
             tele.attach_loader(loader)
+            for record in init_compiles:  # ahead of the step's own
+                tele.compile_monitor.note(record)
             train_step = tele.instrument(train_step, "train_step")
 
             eval_step = None
