@@ -22,13 +22,19 @@ this and the XLA form's [B, S, S] booleans.
   words are written once.
 * ``dsa_core_fwd``, ``dsa_core_bwd_dq``, ``dsa_core_bwd_dkv``
   (``masked_attention``): flash attention under the words. A program of the
-  forward and of dq holds a block of 256 query rows of ALL the query heads
+  forward and of dq holds a block of 512 query rows of ALL the query heads
   of one key-value head (they share K, V and the words), K and V whole in
-  VMEM, and walks the key tiles up to the diagonal; dk/dv walks the query
-  blocks from the diagonal down for one key tile of 512, summing over the
-  group's heads in float32 scratch. The dense tiles up to the diagonal are
-  run and masked: the model's work is the chosen pairs, a quarter of that at
-  16,384 (``benchmarks/trace/flops_keye.py``). The forward RULE names the
+  VMEM, and walks the key tiles up to the diagonal ONCE, the group's heads
+  inside the walk: a trip takes tile ``j``'s K and V and makes its mask from
+  the words once, and every head uses them, as dk/dv does. What a head
+  carries from tile to tile (the forward's running maximum and sum, a row's
+  number in all 128 lanes of a lane tile, and its accumulator; dq's sum) is
+  float32 VMEM scratch for the whole group, written out after the last
+  tile. dk/dv walks the query blocks from the diagonal down for one key tile
+  of 512, summing over the group's heads in float32 scratch. The dense tiles
+  up to the diagonal are run and masked: the model's work is the chosen
+  pairs, a quarter of that at 16,384
+  (``benchmarks/trace/flops_keye.py``). The forward RULE names the
   forward kernel's output and log-sum-exps, the residuals the two backward
   kernels read (``ops/remat.py DSA_CORE_OUT``, ``DSA_CORE_LSE``), so under a
   remat policy that keeps the names the recompute does not run the forward
@@ -70,7 +76,7 @@ from bert_pytorch_tpu.utils import trace_parts
 WORD_LANES = 512          # keys a bit plane covers: the kernels' key tile
 WORD_BITS = 32
 SELECT_ROWS = 128
-CORE_ROWS = 256
+CORE_ROWS = 512           # (at 256 the core's calls read 5% / 2% / 9% longer)
 LOSS_ROWS = 128
 NEG = -1e30
 VMEM_LIMIT_BYTES = 100 * 1024 * 1024
@@ -124,6 +130,11 @@ def _dot(a, b, contract):
 _NT = ((1,), (1,))   # a @ b.T
 _NN = ((1,), (0,))   # a @ b
 _TN = ((0,), (0,))   # a.T @ b
+
+
+def _across(t, width):
+    """[rows, 128] with a row's number in every lane -> [rows, width]."""
+    return t if width == t.shape[1] else jnp.tile(t, (1, width // t.shape[1]))
 
 
 def _index_tile(qi_ref, ki_tile, weights, scale):
@@ -246,56 +257,71 @@ def select(qi, ki, w, topk: int):
 
 # -------------------------------------------------------------------- core
 
-def _core_fwd_kernel(q_ref, k_ref, v_ref, words_ref, out_ref, lse_ref, *,
-                     scale):
+def _core_fwd_kernel(q_ref, k_ref, v_ref, words_ref, out_ref, lse_ref, m_scr,
+                     l_scr, acc_scr, *, scale):
     # q_ref, out_ref [1, G, rows, D]; k_ref, v_ref [1, S, D]; words_ref
-    # [1, rows, 512]; lse_ref [1, G, 1, rows]
+    # [1, rows, 512]; lse_ref [1, G, 1, rows]. Scratch, float32, for the whole
+    # group: the running maxima and sums m_scr, l_scr [G, rows, 128], a row's
+    # number in every lane of a lane tile (it meets the accumulator element
+    # for element and the scores by tiling, with no turn from a column), and
+    # the accumulators acc_scr [G, rows, D]. ONE walk of the key tiles up to
+    # the diagonal: a trip takes the tile's K, V and mask once, and every
+    # head of the group uses them.
     group, rows, depth = q_ref.shape[1:]
     seen = ((pl.program_id(1) + 1) * rows + WORD_LANES - 1) // WORD_LANES
-    words = words_ref[0]
-    for g in range(group):
-        q = q_ref[0, g]
+    m_scr[...] = jnp.full(m_scr.shape, NEG, jnp.float32)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
 
-        def body(j, carry):
-            m_prev, l_prev, acc = carry
-            k = k_ref[0, pl.ds(j * WORD_LANES, WORD_LANES), :]
-            v = v_ref[0, pl.ds(j * WORD_LANES, WORD_LANES), :]
-            s = jnp.where(_keep(words, j), _dot(q, k, _NT) * scale, NEG)
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+    def trip(j, carry):
+        k = k_ref[0, pl.ds(j * WORD_LANES, WORD_LANES), :]
+        v = v_ref[0, pl.ds(j * WORD_LANES, WORD_LANES), :]
+        keep = _keep(words_ref[0], j)
+        for g in range(group):
+            s = jnp.where(keep, _dot(q_ref[0, g], k, _NT) * scale, NEG)
+            m_prev = m_scr[g]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
             # (a row with no chosen key yet sums ones under m = NEG; the
-            # first chosen key's alpha = exp(NEG - m) = 0 wipes them)
+            # first chosen key's alpha = exp(NEG - m) = 0 wipes them, a head
+            # at a time: each head has its own m)
             alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new[:, None])
-            return (m_new, l_prev * alpha + jnp.sum(p, axis=-1),
-                    acc * alpha[:, None] + _dot(p.astype(v.dtype), v, _NN))
+            p = jnp.exp(s - _across(m_new, WORD_LANES))
+            m_scr[g] = m_new
+            l_scr[g] = l_scr[g] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_scr[g] = (acc_scr[g] * _across(alpha, depth)
+                          + _dot(p.astype(v.dtype), v, _NN))
+        return carry
 
-        m, l, acc = jax.lax.fori_loop(0, seen, body, (
-            jnp.full((rows,), NEG, jnp.float32),
-            jnp.zeros((rows,), jnp.float32),
-            jnp.zeros((rows, depth), jnp.float32)))
-        out_ref[0, g] = (acc / l[:, None]).astype(out_ref.dtype)
-        lse_ref[0, g, 0] = m + jnp.log(l)
+    jax.lax.fori_loop(0, seen, trip, 0)
+    for g in range(group):
+        l = l_scr[g]
+        out_ref[0, g] = (acc_scr[g] / _across(l, depth)).astype(out_ref.dtype)
+        lse_ref[0, g, 0] = (m_scr[g] + jnp.log(l))[:, 0]
 
 
 def _core_dq_kernel(q_ref, k_ref, v_ref, words_ref, lse_ref, delta_ref,
-                    do_ref, dq_ref, *, scale):
+                    do_ref, dq_ref, dq_scr, *, scale):
+    # the forward's operands and walk; do_ref, dq_ref [1, G, rows, D];
+    # lse_ref, delta_ref [1, G, 1, rows]; the group's dq in dq_scr
+    # [G, rows, D] float32
     group, rows, _ = q_ref.shape[1:]
     seen = ((pl.program_id(1) + 1) * rows + WORD_LANES - 1) // WORD_LANES
-    words = words_ref[0]
-    for g in range(group):
-        q, do = q_ref[0, g], do_ref[0, g]
-        lse, delta = lse_ref[0, g, 0][:, None], delta_ref[0, g, 0][:, None]
+    lse = [lse_ref[0, g, 0][:, None] for g in range(group)]
+    delta = [delta_ref[0, g, 0][:, None] for g in range(group)]
+    dq_scr[...] = jnp.zeros_like(dq_scr)
 
-        def body(j, dq):
-            k = k_ref[0, pl.ds(j * WORD_LANES, WORD_LANES), :]
-            v = v_ref[0, pl.ds(j * WORD_LANES, WORD_LANES), :]
-            s = jnp.where(_keep(words, j), _dot(q, k, _NT) * scale, NEG)
-            ds = jnp.exp(s - lse) * (_dot(do, v, _NT) - delta)
-            return dq + _dot(ds.astype(k.dtype), k, _NN)
+    def trip(j, carry):
+        k = k_ref[0, pl.ds(j * WORD_LANES, WORD_LANES), :]
+        v = v_ref[0, pl.ds(j * WORD_LANES, WORD_LANES), :]
+        keep = _keep(words_ref[0], j)
+        for g in range(group):
+            s = jnp.where(keep, _dot(q_ref[0, g], k, _NT) * scale, NEG)
+            ds = jnp.exp(s - lse[g]) * (_dot(do_ref[0, g], v, _NT) - delta[g])
+            dq_scr[g] += _dot(ds.astype(k.dtype), k, _NN)
+        return carry
 
-        dq = jax.lax.fori_loop(0, seen, body,
-                               jnp.zeros(q.shape, jnp.float32))
-        dq_ref[0, g] = (dq * scale).astype(dq_ref.dtype)
+    jax.lax.fori_loop(0, seen, trip, 0)
+    dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
 
 
 def _core_dkv_kernel(q_ref, k_ref, v_ref, words_ref, lse_ref, delta_ref,
@@ -354,6 +380,9 @@ def _core_forward(q4, k3, v3, words, scale):
             out_specs=[spec["q"], spec["row"]],
             out_shape=[jax.ShapeDtypeStruct(q4.shape, q4.dtype),
                        jax.ShapeDtypeStruct((bkv, group, 1, seq), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((group, rows, 128), jnp.float32),
+                            pltpu.VMEM((group, rows, 128), jnp.float32),
+                            pltpu.VMEM((group, rows, depth), jnp.float32)],
             compiler_params=_params("parallel", "parallel"),
             name="dsa_core_fwd", interpret=common.interpret_mode(),
         )(q4, k3, v3, words)
@@ -392,6 +421,7 @@ def _core_bwd(scale, residuals, cotangents):
                       spec["row"], spec["row"], spec["q"]],
             out_specs=spec["q"],
             out_shape=jax.ShapeDtypeStruct(q4.shape, q4.dtype),
+            scratch_shapes=[pltpu.VMEM((group, rows, depth), jnp.float32)],
             compiler_params=_params("parallel", "parallel"),
             name="dsa_core_bwd_dq", interpret=common.interpret_mode(),
         )(q4, k3, v3, words, lse, delta, do)

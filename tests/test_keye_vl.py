@@ -143,7 +143,8 @@ def test_ordered_keys_keep_the_order_of_floats_and_the_kth_is_exact():
         np.testing.assert_array_equal(np.asarray(got), want[:, k - 1])
 
 
-def _kernel_inputs(seq=1024, heads=4, kv=2, depth=128, index_heads=4, width=64):
+def _kernel_inputs(seq=1024, heads=4, kv=2, depth=128, index_heads=4,
+                   width=64):
     k = keys(6, 1)
     q = jax.random.normal(k[0], (1, seq, heads, depth))
     kk = jax.random.normal(k[1], (1, seq, kv, depth))
@@ -173,8 +174,38 @@ def test_the_select_kernel_chooses_the_xla_forms_set_bit_for_bit(kernel_case):
         300 * 301 // 2 + 724 * 300)
 
 
-def test_the_core_kernels_match_the_xla_form_forward_and_backward(kernel_case):
-    q, k, v, *_, mask, words = kernel_case
+# (heads, key-value heads, row length, head width, rows whose first key tile
+# is emptied): a group of 2, 4 and the published 8 heads a key-value head;
+# rows 600-899 with NO chosen key among keys 0-511, so their first trip sums
+# ones under m = NEG and the second must wipe them, each head by its own
+# maximum; a row of 1536, where a program's first query block sees one key
+# tile of 512 and its last sees three; and a head of two lane tiles, which
+# the forward's statistics (one lane tile wide) meet by tiling.
+CORE_CASES = {
+    "group2": (4, 2, 1024, 128, None),
+    "group4": (4, 1, 1024, 128, None),
+    "group8": (8, 1, 1024, 128, None),
+    "group8_first_tile_empty": (8, 1, 1024, 128, (600, 900)),
+    "group2_seq1536": (4, 2, 1536, 128, None),
+    "group2_depth256": (2, 1, 1024, 256, None),
+}
+
+
+@pytest.mark.parametrize("case", CORE_CASES)
+def test_the_core_kernels_match_the_xla_form_forward_and_backward(case):
+    heads, kv, seq, depth, emptied = CORE_CASES[case]
+    q, k, v, qi, ki, w = _kernel_inputs(seq=seq, heads=heads, kv=kv,
+                                        depth=depth)
+    mask = sparse.choose(qi, ki, w, 300)
+    if emptied:
+        first, last = emptied
+        at = jnp.arange(seq)
+        late = ((at >= first) & (at < last))[:, None]
+        # (each such row keeps its own key, so none is left without any)
+        mask = (mask & ~(late & (at < kernels.WORD_LANES)[None, :])
+                | (late & (at[:, None] == at[None, :])))
+        assert not np.asarray(mask[0, first:last, :kernels.WORD_LANES]).any()
+    words = kernels.pack(mask)
     weigh = lambda ctx: jnp.sum(ctx * jnp.cos(ctx))
 
     def xla(q, k, v):
