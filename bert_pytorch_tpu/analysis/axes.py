@@ -32,9 +32,11 @@ AXIS_FSDP = "fsdp"
 AXIS_PIPE = "pipe"
 AXIS_SEQ = "seq"
 AXIS_MODEL = "model"
+AXIS_EXPERT = "expert"
 
 MESH_AXES: Tuple[str, ...] = (
-    AXIS_DATA, AXIS_FSDP, AXIS_PIPE, AXIS_SEQ, AXIS_MODEL)
+    AXIS_DATA, AXIS_FSDP, AXIS_PIPE, AXIS_SEQ, AXIS_MODEL, AXIS_EXPERT)
+BATCH_AXES: Tuple[str, ...] = (AXIS_DATA, AXIS_FSDP, AXIS_EXPERT)
 
 # The constant spellings model/runner code must import instead of raw
 # literals (the SD603 contract). Name -> axis value, for messages and
@@ -45,6 +47,7 @@ AXIS_CONSTANTS: Dict[str, str] = {
     "AXIS_PIPE": AXIS_PIPE,
     "AXIS_SEQ": AXIS_SEQ,
     "AXIS_MODEL": AXIS_MODEL,
+    "AXIS_EXPERT": AXIS_EXPERT,
 }
 
 # -- logical-axis rules (mirror of mesh.py _BASE_RULES/_RULE_TEMPLATE/
@@ -55,12 +58,14 @@ AXIS_CONSTANTS: Dict[str, str] = {
 # the whole table.
 
 BASE_RULES: Tuple[Tuple[str, object], ...] = (
-    ("batch", (AXIS_DATA, AXIS_FSDP)),
+    ("batch", BATCH_AXES),
     ("seq_act", AXIS_SEQ),
     ("pos", None),
     ("types", None),
     ("classes", None),
     ("layers", None),
+    ("experts", None),
+    ("vocab_rows", None),
 )
 
 # Mirror of mesh.py _RULE_TEMPLATE: per param logical axis, the mesh axis
@@ -98,6 +103,8 @@ def derive_rules(active) -> Tuple[Tuple[str, object], ...]:
     rules = []
     if AXIS_PIPE in active:
         rules.append(("layers", AXIS_PIPE))
+    if AXIS_EXPERT in active:
+        rules += [("experts", AXIS_EXPERT), ("vocab_rows", AXIS_EXPERT)]
     for name, axis in RULE_TEMPLATE:
         rules.append((name, axis if axis is not None and axis in active
                       else None))
@@ -114,7 +121,7 @@ STRATEGY_RULES: Dict[str, Tuple[Tuple[str, object], ...]] = {
 # (fsdp × pipe × model, with/without seq): SD602 coverage runs over
 # these generated products too, so a logical name that resolves under
 # the legacy aliases but not under some composed mesh is still caught.
-_PRODUCT_AXES = (AXIS_FSDP, AXIS_PIPE, AXIS_SEQ, AXIS_MODEL)
+_PRODUCT_AXES = (AXIS_FSDP, AXIS_PIPE, AXIS_SEQ, AXIS_MODEL, AXIS_EXPERT)
 
 PRODUCT_RULES: Dict[str, Tuple[Tuple[str, object], ...]] = {}
 for _mask in range(1 << len(_PRODUCT_AXES)):

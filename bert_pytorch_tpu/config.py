@@ -908,11 +908,90 @@ class JoyAIConfig:
         return 16
 
 
+class MellumConfig(LagunaConfig):
+    """Configuration of the ``mellum`` family (JetBrains/Mellum2-12B-A2.5B):
+    :class:`LagunaConfig`'s decoder of sliding and full attention layers
+    before routed experts, less what that family adds: no gate on the
+    attention's output, one count of query heads for every layer, every MLP
+    sparse, no shared expert and no factor on the routed weights. Keys and
+    defaults are the published ``config.json``'s; the per-layer lists the
+    shared blocks read (``models/laguna.py``) are derived here.
+    ``intermediate_size`` is carried and used by no layer.
+
+    ``ep_size`` / ``ep_rank`` state a chip's share as the other expert
+    families do (``num_experts`` HELD of ``num_experts * ep_size``). Under a
+    mesh with an ``expert`` axis (``--mesh ep=4``) the share is the mesh's
+    instead and the file states the whole layer (``ep_size`` 1): the experts
+    and the vocabulary's rows are divided over the axis and the slots cross
+    it (``ops/moe.py exchanged_experts``)."""
+
+    model_type = "mellum"
+
+    def __init__(self, **values: Any):
+        defaults = dict(
+            vocab_size=98304, hidden_size=2304, intermediate_size=7168,
+            num_hidden_layers=28, num_attention_heads=32,
+            num_key_value_heads=4, head_dim=128, layer_types=None,
+            mlp_layer_types=None, sliding_window=1024, use_sliding_window=True,
+            max_window_layers=0, attention_bias=False, hidden_act="silu",
+            max_position_embeddings=131072,
+            rope_parameters={
+                "full_attention": {
+                    "rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+                    "original_max_position_embeddings": 8192, "beta_fast": 32,
+                    "beta_slow": 1, "attention_factor": 1.2772588722239782},
+                "sliding_attention": {
+                    "rope_type": "default", "rope_theta": 500000}},
+            num_experts=64, ep_size=1, ep_rank=0, num_experts_per_tok=8,
+            moe_intermediate_size=896, norm_topk_prob=True,
+            rms_norm_eps=1e-6, initializer_range=0.02,
+            tie_word_embeddings=False)
+        for key, value in {**defaults, **values}.items():
+            setattr(self, key, value)
+        layers = self.num_hidden_layers
+        if self.layer_types is None:
+            self.layer_types = [
+                "full_attention" if i % 4 == 3 else "sliding_attention"
+                for i in range(layers)]
+        if self.mlp_layer_types is None:
+            self.mlp_layer_types = ["sparse"] * layers
+        for name, allowed in (
+                ("layer_types", {"full_attention", "sliding_attention"}),
+                ("mlp_layer_types", {"sparse"})):
+            got = getattr(self, name)
+            if len(got) != layers or set(got) - allowed:
+                raise ValueError(
+                    f"{name} must be {layers} of {sorted(allowed)}: {got}")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError(
+                f"{self.num_attention_heads} query heads on "
+                f"{self.num_key_value_heads} key-value heads")
+        if not 0 <= self.ep_rank < self.ep_size:
+            raise ValueError(f"ep_rank {self.ep_rank} of ep_size {self.ep_size}")
+        if (self.attention_bias or self.tie_word_embeddings
+                or self.hidden_act != "silu" or not self.use_sliding_window):
+            raise ValueError(
+                "mellum is built with no attention bias, an untied head, "
+                "silu experts and its sliding layers windowed")
+        # what models/laguna.py's blocks read beside the published keys
+        self.num_attention_heads_per_layer = [self.num_attention_heads] * layers
+        self.shared_expert_intermediate_size = 0
+        self.moe_routed_scaling_factor = 1.0
+        self.tp_size, self.tp_rank = 1, 0
+
+    def to_dict(self) -> dict:
+        derived = ("num_attention_heads_per_layer", "tp_size", "tp_rank",
+                   "shared_expert_intermediate_size",
+                   "moe_routed_scaling_factor")
+        return {k: v for k, v in super().to_dict().items()
+                if k not in derived}
+
+
 MODEL_FAMILIES = {"bert": BertConfig, "nemotron_h": NemotronHConfig,
                   "laguna": LagunaConfig, "phi4flash": PhiFlashConfig,
                   "zaya": ZayaConfig, "qwen3_next": Qwen3NextConfig,
                   "KeyeVL2": KeyeVLConfig,
-                  "joyai_llm_flash": JoyAIConfig}
+                  "joyai_llm_flash": JoyAIConfig, "mellum": MellumConfig}
 
 
 def load_model_config(json_file: str):

@@ -52,6 +52,7 @@ from bert_pytorch_tpu.models.losses import (
 from bert_pytorch_tpu.models.joyai import JoyAIForCausalLM
 from bert_pytorch_tpu.models.keye_vl import KeyeVLForCausalLM
 from bert_pytorch_tpu.models.laguna import LagunaForCausalLM
+from bert_pytorch_tpu.models.mellum import MellumForCausalLM
 from bert_pytorch_tpu.models.nemotron_h import NemotronHForCausalLM
 from bert_pytorch_tpu.models.phi4flash import PhiFlashForCausalLM
 from bert_pytorch_tpu.models.qwen3_next import Qwen3NextForCausalLM
@@ -66,11 +67,13 @@ def build_pretraining_model(config, dtype, remat: str = "none",
     ``pretrain.make_train_step`` trains it on."""
     from bert_pytorch_tpu.config import (BertConfig, JoyAIConfig,
                                          KeyeVLConfig, LagunaConfig,
-                                         NemotronHConfig,
+                                         MellumConfig, NemotronHConfig,
                                          PhiFlashConfig, Qwen3NextConfig,
                                          ZayaConfig)
 
+    # (MellumConfig is a LagunaConfig: the narrower class first)
     for family, model in ((NemotronHConfig, NemotronHForCausalLM),
+                          (MellumConfig, MellumForCausalLM),
                           (LagunaConfig, LagunaForCausalLM),
                           (PhiFlashConfig, PhiFlashForCausalLM),
                           (ZayaConfig, ZayaForCausalLM),
@@ -88,6 +91,7 @@ __all__ = [
     "JoyAIForCausalLM",
     "KeyeVLForCausalLM",
     "LagunaForCausalLM",
+    "MellumForCausalLM",
     "NemotronHForCausalLM",
     "PhiFlashForCausalLM",
     "Qwen3NextForCausalLM",

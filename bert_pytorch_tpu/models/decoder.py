@@ -30,7 +30,7 @@ are called as before and lower as before.
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable
+from typing import Any, Callable, Optional
 
 import flax.linen as nn
 import jax
@@ -44,16 +44,24 @@ Dtype = Any
 
 MOE_COUNTERS = ("moe_local_slots", "moe_dropped_slots",
                 "moe_load_max_over_mean", "moe_pieces_run", "moe_tile_fill")
+# ... and what the exchange between chips adds (ops/moe.py exchanged_experts)
+EXCHANGE_COUNTERS = (
+    "moe_exchange_slots_out", "moe_exchange_slots_in",
+    "moe_exchange_bytes_out", "moe_chip_load_max_over_mean")
 
 
 def normal(std: float):
     return nn.initializers.normal(stddev=std)
 
 
-def dense(features: int, std: float, dtype, name):
+def dense(features: int, std: float, dtype, name, axes=None):
+    """The bias-free projection; ``axes`` names the kernel's two axes for the
+    mesh's rules (``parallel/mesh.py``), none by default."""
+    init = normal(std)
     return nn.Dense(features, use_bias=False, dtype=dtype,
-                    param_dtype=jnp.float32, kernel_init=normal(std),
-                    name=name)
+                    param_dtype=jnp.float32, name=name,
+                    kernel_init=nn.with_logical_partitioning(init, axes)
+                    if axes else init)
 
 
 class RMSNorm(nn.Module):
@@ -137,7 +145,16 @@ class ExpertLayer(nn.Module):
     its outputs included (no chip holds it: its slots add nothing).
     ``shared_gate`` (``qwen3_next``) multiplies the shared expert's output by
     ``sigmoid(x w_g)`` a token, ``w_g`` one vector of the stream's width.
-    Returns (output, the layer's ``moe_*`` counters)."""
+    With ``axis_names`` the stacked tensors carry the axis name ``experts`` on
+    their first axis, for the mesh's rules (``parallel/mesh.py``: a family
+    opts in, ``CausalDecoder.AXIS_NAMES``; ``model.init`` then returns them
+    boxed, ``nn.unbox``). Under ``expert_axis`` (a mesh axis the caller runs manually,
+    ``expert_shards`` wide: ``pretrain.make_train_step``) the layer holds
+    ``held / expert_shards`` experts of a WHOLE layer (``held`` =
+    ``router_experts``, no shared expert) and the slots cross the axis
+    (``ops/moe.py exchanged_experts``); ``first_expert`` is then the axis
+    index's and the field is not read. Returns (output, the layer's
+    ``moe_*`` counters)."""
     width: int
     shared_width: int
     held: int
@@ -155,10 +172,25 @@ class ExpertLayer(nn.Module):
     # (tests at a small size set a smaller rounding of the pieces)
     piece_multiple: int = moe.GMM_TILE_ROWS
     dtype: Dtype = jnp.float32
+    axis_names: bool = False
+    expert_axis: Optional[str] = None
+    expert_shards: int = 1
 
     @nn.compact
     def __call__(self, x, routing=None):
         hidden, fan = x.shape[-1], 2 if self.gated else 1
+        held = self.held // self.expert_shards
+        if self.expert_axis and (routing is not None or self.shared_width
+                                 or self.held != self.router_experts):
+            raise ValueError(
+                "under an expert axis the layer holds a whole layer's "
+                "experts, a router matrix of its own and no shared expert")
+
+        def stacked(std):
+            init = normal(std)
+            return nn.with_logical_partitioning(
+                init, ("experts", None, None)) if self.axis_names else init
+
         if routing is None:
             router_w = self.param("router_kernel", normal(self.std),
                                   (hidden, self.router_experts), jnp.float32)
@@ -170,10 +202,10 @@ class ExpertLayer(nn.Module):
                 correction = self.param(
                     "router_correction_bias", nn.initializers.zeros,
                     (self.router_experts,), jnp.float32)
-        w_up = self.param("experts_up", normal(self.std),
-                          (self.held, hidden, fan * self.width), jnp.float32)
-        w_down = self.param("experts_down", normal(self.out_std),
-                            (self.held, self.width, hidden), jnp.float32)
+        w_up = self.param("experts_up", stacked(self.std),
+                          (held, hidden, fan * self.width), jnp.float32)
+        w_down = self.param("experts_down", stacked(self.out_std),
+                            (held, self.width, hidden), jnp.float32)
         batch, seq = x.shape[:2]
         flat = x.reshape(batch * seq, hidden)
         with jax.named_scope("moe"):
@@ -183,10 +215,16 @@ class ExpertLayer(nn.Module):
             # for a caller that asks (``mutable=["intermediates"]``): which
             # experts each token chose; otherwise nothing is kept
             self.sow("intermediates", "chosen", chosen)
-            routed, counters = moe.held_experts(
-                flat, chosen, weights, w_up, w_down, self.first_expert,
-                self.router_experts, self.activation,
-                multiple=self.piece_multiple, gated=self.gated)
+            if self.expert_axis:
+                routed, counters = moe.exchanged_experts(
+                    flat, chosen, weights, w_up, w_down, self.router_experts,
+                    self.activation, self.expert_axis,
+                    multiple=self.piece_multiple, gated=self.gated)
+            else:
+                routed, counters = moe.held_experts(
+                    flat, chosen, weights, w_up, w_down, self.first_expert,
+                    self.router_experts, self.activation,
+                    multiple=self.piece_multiple, gated=self.gated)
             counters = {"moe_" + name: value
                         for name, value in counters.items()}
             if not self.shared_width:
@@ -225,11 +263,25 @@ class CausalDecoder(nn.Module):
     its layers share (``shared_inputs``). ``NORM`` is
     the final norm's class; under ``TIED_HEAD`` the output head is the
     embedding's transpose and the model holds no ``lm_head``. The model
-    returns ``(logits [B, S, V], counters)``."""
+    returns ``(logits [B, S, V], counters)``.
+
+    **Under an expert axis.** A family with ``AXIS_NAMES`` names its big
+    tensors' axes (embedding and head by ``vocab_rows``, the expert layers'
+    stacked tensors by ``experts``), and a mesh with an ``expert`` axis
+    divides them over it. ``pretrain.make_train_step`` then runs the model
+    inside a ``shard_map`` manual over that axis, built a second time with
+    ``expert_axis`` (the axis's name) and ``expert_shards`` (its width): every
+    chip sees its own rows of the batch and the SHARDS of those tensors
+    (``vocab_size / expert_shards`` rows of both tables); attention, norms and
+    routers are whole on every chip; the embedding's look-up, the expert
+    layers and the loss cross the axis (``embed``, ``ops/moe.py
+    exchanged_experts``, ``models/losses.py``)."""
     config: Any
     dtype: Dtype = jnp.float32
     remat: str = "none"
     attention_backend: str = "xla"
+    expert_axis: Optional[str] = None
+    expert_shards: int = 1
 
     # What pretrain.make_train_step trains these families on.
     objective = "causal_lm"
@@ -237,6 +289,7 @@ class CausalDecoder(nn.Module):
     NORM = RMSNorm
     TIED_HEAD = False
     CARRIES = False
+    AXIS_NAMES = False
 
     def blocks(self, wrap) -> list:
         """The layers, in order; ``wrap`` rematerializes a block class, under
@@ -288,14 +341,51 @@ class CausalDecoder(nn.Module):
 
     def setup(self):
         cfg = self.config
+        if self.expert_axis and not (self.AXIS_NAMES and not self.TIED_HEAD
+                                     and not self.prediction_streams()):
+            raise ValueError(
+                f"the {cfg.model_type} family does not run under an expert "
+                "axis (models/decoder.py AXIS_NAMES)")
+        rows = cfg.vocab_size // self.expert_shards
+        init = normal(cfg.initializer_range)
         self.embedding = self.param(
-            "embedding", normal(cfg.initializer_range),
-            (cfg.vocab_size, cfg.hidden_size), jnp.float32)
+            "embedding", nn.with_logical_partitioning(
+                init, ("vocab_rows", None)) if self.AXIS_NAMES else init,
+            (rows, cfg.hidden_size), jnp.float32)
         self.layers = self.blocks(functools.partial(rematerialized, self.remat))
         self.final_norm = self.NORM(self.norm_epsilon(), self.dtype)
         if not self.TIED_HEAD:
-            self.lm_head = dense(cfg.vocab_size, cfg.initializer_range,
-                                 self.dtype, None)
+            self.lm_head = dense(
+                rows, cfg.initializer_range, self.dtype, None,
+                (None, "vocab_rows") if self.AXIS_NAMES else None)
+
+    def on_expert_axis(self, axis: str, shards: int):
+        """This model as one chip of ``shards`` runs it inside a ``shard_map``
+        manual over the mesh axis ``axis``."""
+        if self.config.vocab_size % shards:
+            raise ValueError(f"{self.config.vocab_size} rows of the "
+                             f"vocabulary over {shards} chips")
+        return self.clone(expert_axis=axis, expert_shards=shards)
+
+    def embed(self, input_ids):
+        """[B, S] ids -> their rows of the embedding in the compute dtype.
+        Under an expert axis the table's rows lie on ``expert_shards`` chips:
+        the ids are gathered over the axis (they are small), each chip looks
+        up the rows it holds for every chip's tokens, and the sums go back to
+        the tokens' owners (one term of each sum is not zero)."""
+        if not self.expert_axis:
+            return jnp.take(self.embedding, input_ids, axis=0).astype(
+                self.dtype)
+        with jax.named_scope("embed_exchange"):
+            rows = self.embedding.shape[0]
+            ids = jax.lax.all_gather(input_ids, self.expert_axis)
+            local = ids - jax.lax.axis_index(self.expert_axis) * rows
+            here = (local >= 0) & (local < rows)
+            found = jnp.where(
+                here[..., None],
+                jnp.take(self.embedding, jnp.clip(local, 0, rows - 1), axis=0),
+                0).astype(self.dtype)
+            return jax.lax.psum_scatter(found, self.expert_axis)
 
     def head_kernel(self, params):
         """The output head's [H, V] matrix in a parameter tree of this
@@ -320,6 +410,11 @@ class CausalDecoder(nn.Module):
             if self.TIED_HEAD:
                 return jnp.matmul(
                     x, self.embedding.T.astype(self.dtype)), counters
+            if self.expert_axis:
+                raise ValueError(
+                    "under an expert axis no chip holds a row's logits: take "
+                    "hidden_states and the loss over the axis "
+                    "(models/losses.py chunked_next_token_loss)")
             return self.lm_head(x), counters
 
 
@@ -327,7 +422,7 @@ def _run_layers(model: CausalDecoder, input_ids, further: bool = False):
     """(the final norm's output, counters, {stream: hidden}) of ``model``
     bound to its parameters. A plain function, so that the two methods that
     call it write the same scopes as when each held this body."""
-    embedded = jnp.take(model.embedding, input_ids, axis=0).astype(model.dtype)
+    embedded = model.embed(input_ids)
     x = embedded
     seen = {name: [] for name in model.COUNTERS}
     carried = {}

@@ -38,7 +38,7 @@ expert layer's ``moe_*``.
 from __future__ import annotations
 
 import math
-from typing import Any
+from typing import Any, Optional
 
 import flax.linen as nn
 import jax
@@ -66,11 +66,13 @@ class GatedAttention(nn.Module):
     """Layer ``layer``'s attention: grouped-query, causal (within the window
     on a sliding layer), rotary on the first dimensions of each head (by the
     tables ``rotary`` the model made once for the layer's kind; a layer called
-    alone makes its own), a sigmoid gate per head on the output."""
+    alone makes its own), a sigmoid gate per head on the output (``gate``;
+    ``models/mellum.py`` builds the layer without one)."""
     config: LagunaConfig
     layer: int
     dtype: Dtype = jnp.float32
     attention_backend: str = "xla"
+    gate: bool = True
 
     @nn.compact
     def __call__(self, x, rotary=None):
@@ -93,10 +95,11 @@ class GatedAttention(nn.Module):
         ctx = dot_product_attention(
             q, k, v.reshape(batch, seq, kv, hd),
             backend=self.attention_backend, causal=True, window=window)
-        with jax.named_scope("attn_gate"):
-            gate = jax.nn.sigmoid(dense(heads, std, self.dtype, "g_proj")(
-                x).astype(jnp.float32))
-            ctx = (ctx * gate[..., None]).astype(self.dtype)
+        if self.gate:
+            with jax.named_scope("attn_gate"):
+                gate = jax.nn.sigmoid(dense(heads, std, self.dtype, "g_proj")(
+                    x).astype(jnp.float32))
+                ctx = (ctx * gate[..., None]).astype(self.dtype)
         with jax.named_scope("attn_out"):
             out = dense(cfg.hidden_size, _out_std(cfg), self.dtype, "o_proj")(
                 ctx.reshape(batch, seq, heads * hd))
@@ -107,9 +110,11 @@ class GatedAttention(nn.Module):
         return out, {name: jnp.asarray(tiles, jnp.float32)}
 
 
-def expert_layer(cfg: LagunaConfig, dtype, name=None) -> ExpertLayer:
+def expert_layer(cfg: LagunaConfig, dtype, name=None, **axis) -> ExpertLayer:
     """The family's expert layer: softmax scores, gated silu experts, the
-    share ``cfg`` states."""
+    share ``cfg`` states (``axis``: ``ExpertLayer``'s ``axis_names``,
+    ``expert_axis`` and ``expert_shards``, for a family that runs under an
+    expert axis)."""
     return ExpertLayer(
         width=cfg.moe_intermediate_size,
         shared_width=cfg.shared_expert_intermediate_size,
@@ -121,22 +126,29 @@ def expert_layer(cfg: LagunaConfig, dtype, name=None) -> ExpertLayer:
         gated=True,
         piece_multiple=getattr(cfg, "moe_piece_multiple",
                                ExpertLayer.piece_multiple),
-        dtype=dtype, name=name)
+        dtype=dtype, name=name, **axis)
 
 
 class LagunaBlock(nn.Module):
+    """One layer: attention, then the MLP its ``mlp_layer_types`` entry
+    names. ``gate`` and the three fields it hands on to ``expert_layer`` are
+    ``models/mellum.py``'s to set."""
     config: LagunaConfig
     layer: int
     dtype: Dtype = jnp.float32
     attention_backend: str = "xla"
+    gate: bool = True
+    axis_names: bool = False
+    expert_axis: Optional[str] = None
+    expert_shards: int = 1
 
     @nn.compact
     def __call__(self, x, tables):
         cfg = self.config
         h = RMSNorm(cfg.rms_norm_eps, self.dtype, name="attn_norm")(x)
         out, counters = GatedAttention(
-            cfg, self.layer, self.dtype, self.attention_backend, name="attn")(
-                h, tables[cfg.layer_types[self.layer]])
+            cfg, self.layer, self.dtype, self.attention_backend, self.gate,
+            name="attn")(h, tables[cfg.layer_types[self.layer]])
         x = x + out
         h = RMSNorm(cfg.rms_norm_eps, self.dtype, name="mlp_norm")(x)
         if cfg.mlp_layer_types[self.layer] == "dense":
@@ -144,7 +156,10 @@ class LagunaBlock(nn.Module):
                            cfg.initializer_range, _out_std(cfg),
                            self.dtype, name="mlp")(h)
         else:
-            out, routed = expert_layer(cfg, self.dtype, name="mlp")(h)
+            out, routed = expert_layer(
+                cfg, self.dtype, name="mlp", axis_names=self.axis_names,
+                expert_axis=self.expert_axis,
+                expert_shards=self.expert_shards)(h)
             counters = {**counters, **routed}
         return x + out, counters
 
@@ -163,10 +178,13 @@ class LagunaForCausalLM(CausalDecoder):
         return self.config.rms_norm_eps
 
     def shared_inputs(self, seq):
-        """The rotary tables, one (cos, sin) for each kind of layer, made
-        once a call and not in every layer of every pass."""
-        cfg = self.config
-        with jax.named_scope("attn_rope"):
-            return ({kind: rope.rotary_tables(
-                seq, *cfg.rope_of(cfg.layer_types.index(kind)))
-                for kind in dict.fromkeys(cfg.layer_types)},)
+        return (rotary_tables_by_kind(self.config, seq),)
+
+
+def rotary_tables_by_kind(cfg: LagunaConfig, seq: int) -> dict:
+    """The rotary tables, one (cos, sin) for each kind of layer, made once a
+    call and not in every layer of every pass."""
+    with jax.named_scope("attn_rope"):
+        return {kind: rope.rotary_tables(
+            seq, *cfg.rope_of(cfg.layer_types.index(kind)))
+            for kind in dict.fromkeys(cfg.layer_types)}

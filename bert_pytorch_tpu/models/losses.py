@@ -85,8 +85,43 @@ def next_token_loss(logits, input_ids, shift: int = 1, scope: str = "lm"):
         return loss, jnp.sum(right) / count
 
 
+def _piece_over_axis(h, lab, head_kernel, axis: str, scope: str):
+    """One piece's (cross entropy, arg-max) per position when the head's
+    COLUMNS lie on the chips of the mesh axis ``axis`` (inside a ``shard_map``
+    manual over it): ``h`` [B, s, H] and ``lab`` [B, s] are this chip's rows,
+    ``head_kernel`` [H, V / chips] its columns. The chips' rows of the piece
+    are gathered (``<scope>_head_gather``; bfloat16 hidden states, far fewer
+    bytes than the head's columns), every chip scores all of them against its
+    own columns, and the softmax's maximum, its sum and the target's logit
+    are reduced over the axis; the arg-max is the lowest id among the chips'
+    largest. Each chip keeps its own rows' numbers."""
+    me, cols = jax.lax.axis_index(axis), head_kernel.shape[1]
+    with jax.named_scope(scope + "_head_gather"):
+        h = jax.lax.all_gather(h, axis)                      # [chips, B, s, H]
+        labels = jax.lax.all_gather(lab, axis)
+    with jax.named_scope(scope + "_head"):
+        logits = jnp.matmul(h, head_kernel.astype(h.dtype))
+    with jax.named_scope(scope + "_loss"):
+        logits = logits.astype(jnp.float32)
+        top = jax.lax.stop_gradient(jnp.max(logits, axis=-1))
+        highest = jax.lax.pmax(top, axis)
+        total = jax.lax.psum(
+            jnp.sum(jnp.exp(logits - highest[..., None]), axis=-1), axis)
+        local = labels - me * cols
+        here = (local >= 0) & (local < cols)
+        target = jax.lax.psum(jnp.where(here, jnp.take_along_axis(
+            logits, jnp.clip(local, 0, cols - 1)[..., None], axis=-1)[..., 0],
+            0.0), axis)
+        per_pos = jnp.log(total) + highest - target
+        best = jax.lax.pmin(jnp.where(
+            top >= highest, jnp.argmax(logits, axis=-1) + me * cols,
+            jnp.iinfo(jnp.int32).max), axis)
+        mine = lambda t: jax.lax.dynamic_index_in_dim(t, me, 0, keepdims=False)
+        return mine(per_pos), mine(best)
+
+
 def chunked_next_token_loss(hidden, head_kernel, input_ids, chunks: int,
-                            shift: int = 1, scope: str = "lm"):
+                            shift: int = 1, scope: str = "lm", axis=None):
     """:func:`next_token_loss` of ``hidden @ head_kernel`` without ever
     holding every position's logits: the head and the cross entropy run over
     ``chunks`` equal pieces of the sequence, one after the other, each
@@ -94,7 +129,10 @@ def chunked_next_token_loss(hidden, head_kernel, input_ids, chunks: int,
     (at 8192 x 16384 in float32 the whole is 0.5 GB, several times over).
     Same loss, same accuracy, the head's forward once more in the backward
     pass. ``chunks`` must divide the sequence length. The head runs under the
-    scope ``<scope>_head`` and the loss under ``<scope>_loss``."""
+    scope ``<scope>_head`` and the loss under ``<scope>_loss``. ``axis``: the
+    head's columns lie on the chips of that mesh axis and ``head_kernel`` is
+    this chip's [H, V / chips] (:func:`_piece_over_axis`); the loss and the
+    accuracy returned are of this chip's rows."""
     batch, seq, width = hidden.shape
     labels = jnp.roll(input_ids, -shift, axis=-1)
     predicted = jnp.broadcast_to(jnp.arange(seq) < seq - shift, labels.shape)
@@ -104,6 +142,11 @@ def chunked_next_token_loss(hidden, head_kernel, input_ids, chunks: int,
     @jax.checkpoint
     def piece(carry, xs):
         h, lab, keep = xs
+        if axis:
+            per_pos, best = _piece_over_axis(h, lab, head_kernel, axis, scope)
+            with jax.named_scope(scope + "_loss"):
+                return (carry[0] + jnp.sum(jnp.where(keep, per_pos, 0.0)),
+                        carry[1] + jnp.sum((best == lab) & keep)), None
         with jax.named_scope(scope + "_head"):
             logits = jnp.matmul(h, head_kernel.astype(h.dtype))
         with jax.named_scope(scope + "_loss"):

@@ -3,9 +3,12 @@
 The router scores every token over ALL ``n_experts`` (the published width);
 this chip holds the experts ``[first, first + held)`` (``held`` is the
 leading axis of the expert weights) and adds only their terms to the result:
-what the absent experts would add lies on other chips (expert parallelism;
-the exchange between chips is not in this file, and on one chip the layer runs
-without it). Nothing stands in for the absent chips.
+what the absent experts would add lies on other chips (expert parallelism:
+``held_experts``, one chip's share, with no exchange). Nothing stands in for
+the absent chips. Where the chips are there (a mesh with an ``expert`` axis),
+``exchanged_experts`` computes the WHOLE layer: each chip routes its own
+tokens, the slots cross to the chips that hold their experts and the terms
+come back (the last section of this file).
 
 Three routing rules, all in float32, all through ``choose`` (``route`` is
 ``choose`` of ``x router_w``). ``sigmoid`` (DeepSeek-V3's, as ``nemotron_h``
@@ -70,7 +73,10 @@ three passes (PERF.md 5).
 
 Scopes (``pretrain.CAUSAL_LM_SCOPES``): ``moe_route``, ``moe_dispatch``,
 ``moe_experts``, ``moe_combine`` (a router of its own opens ``moe_route``
-itself: ``pretrain.ZAYA_SCOPES``).
+itself: ``pretrain.ZAYA_SCOPES``); the exchange adds ``moe_exchange_out``
+(counts, rows and, in the backward pass, the sums' cotangents on their way to
+the experts) and ``moe_exchange_back`` (what returns: terms, and the rows' and
+weights' cotangents): ``pretrain.MELLUM_SCOPES``.
 """
 
 from __future__ import annotations
@@ -247,6 +253,27 @@ def chunk_rows(tokens: int, top_k: int, n_experts: int, held: int,
     return min(rows, -(-most // multiple) * multiple)
 
 
+def _piece_terms(activation, gated: bool, group_rows: int, live, mine,
+                 rows_in, slot_w, w_up, w_down):
+    """A piece's gathered rows [rows, H], sorted by expert in groups of
+    ``mine`` [E] rows (``live`` [rows, 1]: which rows belong to a group), and
+    their slot weights [rows] -> its weighted outputs [rows, H] in float32."""
+    with jax.named_scope("moe_dispatch"):
+        rows_in = jnp.where(live, rows_in, 0)
+    with jax.named_scope("moe_experts"):
+        mid = jnp.where(
+            live, grouped_dot(rows_in, w_up, mine, group_rows), 0)
+        if gated:
+            gate, up = jnp.split(mid, 2, axis=-1)
+            mid = activation(gate) * up
+        else:
+            mid = activation(mid)
+        rows_out = grouped_dot(mid, w_down, mine, group_rows)
+    with jax.named_scope("moe_combine"):
+        return jnp.where(live, rows_out, 0).astype(
+            jnp.float32) * slot_w[:, None]
+
+
 def _held_sum(rows: int, top_k: int, activation, gated: bool,
               group_rows: int):
     """``total(x, slot_weights, w_up, w_down, order, sizes, ends, trips) ->
@@ -284,23 +311,7 @@ def _held_sum(rows: int, top_k: int, activation, gated: bool,
             slot_w = slot_weights[slots]
         return slots, token, live, mine, rows_in, slot_w
 
-    def experts(live, mine, rows_in, slot_w, w_up, w_down):
-        """A piece's gathered rows [rows, H] and slot weights [rows] -> its
-        weighted outputs [rows, H] in float32."""
-        with jax.named_scope("moe_dispatch"):
-            rows_in = jnp.where(live, rows_in, 0)
-        with jax.named_scope("moe_experts"):
-            mid = jnp.where(
-                live, grouped_dot(rows_in, w_up, mine, group_rows), 0)
-            if gated:
-                gate, up = jnp.split(mid, 2, axis=-1)
-                mid = activation(gate) * up
-            else:
-                mid = activation(mid)
-            rows_out = grouped_dot(mid, w_down, mine, group_rows)
-        with jax.named_scope("moe_combine"):
-            return jnp.where(live, rows_out, 0).astype(
-                jnp.float32) * slot_w[:, None]
+    experts = functools.partial(_piece_terms, activation, gated, group_rows)
 
     def forward(x, slot_weights, w_up, w_down, order, sizes, ends, trips):
         def add_piece(index, total):
@@ -356,15 +367,13 @@ def _held_sum(rows: int, top_k: int, activation, gated: bool,
     return total
 
 
-def _tile_fill(sizes, ends, rows: int, pieces: int, group_rows: int,
-               products, itemsize: int):
+def _tile_fill(start, stop, ends, group_rows: int, products, itemsize: int):
     """Live work over the work of the tiles visited, for the forward products
-    ``products`` ((k, n) each) over every piece of ``rows`` sorted slots: a
-    group visits, in each piece it reaches, every row tile it touches, and a
+    ``products`` ((k, n) each) over pieces of sorted slots in which group e
+    lies in rows ``start[p, e] .. stop[p, e]`` of piece p ([pieces, E];
+    ``ends``: the groups' running ends, its last the live slots): a group
+    visits, in each piece it reaches, every row tile it touches, and a
     visited tile is computed whole over k and n rounded up to their tiles."""
-    lo = jnp.arange(pieces)[:, None] * rows
-    start = jnp.clip(ends - sizes - lo, 0, rows)      # [pieces, E], in a piece
-    stop = jnp.clip(ends - lo, 0, rows)
     live = visited = jnp.zeros((), jnp.float32)
     for k, n in products:
         tm, tk, tn = gmm_tiles(k, n, group_rows, itemsize)
@@ -374,6 +383,15 @@ def _tile_fill(sizes, ends, rows: int, pieces: int, group_rows: int,
         visited += tiles.astype(jnp.float32) * float(
             tm * (-(-k // tk) * tk) * (-(-n // tn) * tn))
     return live / jnp.maximum(visited, 1.0)
+
+
+def _in_piece(ends, sizes, lo, rows: int):
+    """Of runs that end at ``ends`` and hold ``sizes``, where the part inside
+    the piece (or round) of ``rows`` places from ``lo`` on starts and stops,
+    counted from the piece's first place."""
+    start = jnp.clip(ends - sizes - lo, 0, rows)
+    stop = jnp.clip(ends - lo, 0, rows)
+    return start, stop
 
 
 def held_experts(x, chosen, weights, w_up, w_down, first: int,
@@ -420,7 +438,290 @@ def held_experts(x, chosen, weights, w_up, w_down, first: int,
             n_local - pieces * rows, 0).astype(jnp.float32),
         "pieces_run": pieces_run.astype(jnp.float32),
         "tile_fill": _tile_fill(
-            sizes, ends, rows, pieces, group_rows,
-            (w_up.shape[1:], w_down.shape[1:]), x.dtype.itemsize),
+            *_in_piece(ends, sizes, jnp.arange(pieces)[:, None] * rows, rows),
+            ends, group_rows, (w_up.shape[1:], w_down.shape[1:]),
+            x.dtype.itemsize),
     }
     return out.astype(x.dtype), counters
+
+
+# ------------------------------------------------- the exchange between chips
+
+def exchange_rows(tokens: int, top_k: int, chips: int,
+                  multiple: int = GMM_TILE_ROWS) -> int:
+    """Rows one chip sends one chip in one round of the exchange: half of
+    what a chip expects to send another (``tokens x top_k / chips``), rounded
+    up to ``multiple`` rows. An even routing takes two full rounds and a third
+    for what lies over the mean; an uneven one takes more rounds."""
+    return max(-(-tokens * top_k // (2 * chips * multiple)), 1) * multiple
+
+
+def _dealt(axis: str, chips: int, t):
+    """``t`` [T, ...] dealt round the ``chips`` of ``axis``: token i of every
+    chip goes to chip ``i mod chips``, and the tokens chip r sends lie where
+    they lay on r (its token ``i`` at ``i - i mod chips + r``). Dealt twice,
+    every token is home again: the deal is its own inverse, and its own
+    transpose. A row of the batch prefers some experts to others all along
+    (its tokens share what attention averaged over them), so the pair of the
+    chip that holds the row and the chip that holds those experts would carry
+    far more than a pair's share and every chip would wait for its rounds;
+    after the deal each chip holds every ``chips``-th token of every row, and
+    each pair carries the holder's load over ``chips``: the rounds follow the
+    fullest CHIP and not the fullest pair. ``t`` as it is where the tokens do
+    not divide by the chips."""
+    if t.shape[0] % chips:
+        return t
+    dealt = jax.lax.all_to_all(
+        t.reshape(t.shape[0] // chips, chips, *t.shape[1:]), axis, 1, 1)
+    return dealt.reshape(t.shape)
+
+
+def _in_round(ends, sizes, lo, rows: int):
+    """How much of each run lies in the round of ``rows`` places from ``lo``
+    on (:func:`_in_piece`)."""
+    start, stop = _in_piece(ends, sizes, lo, rows)
+    return stop - start
+
+
+def _exchanged_sum(axis: str, chips: int, rows: int, top_k: int, activation,
+                   gated: bool, group_rows: int):
+    """``total(x, slot_weights, w_up, w_down, order, starts, to_chip, arrived,
+    rounds) -> [T, H] float32``: every slot's term, computed by the chip that
+    holds its expert and summed into its token here, in ``rounds`` rounds of
+    ``rows`` slots a pair of chips. Inside a ``shard_map`` manual over
+    ``axis`` (``chips`` wide).
+
+    ``order`` [T k]: this chip's slots sorted by expert, so by the chip that
+    holds it; ``starts`` / ``to_chip`` [chips]: where each chip's run begins
+    in ``order`` and how many slots it holds; ``arrived`` [chips, E]: the
+    slots each chip sends this one, by local expert (the counts, exchanged
+    first); ``rounds``: the most rounds any pair needs, the same on every
+    chip (the collectives inside the loops need every chip in every trip).
+
+    A round: each chip gathers the next ``rows`` slots' rows for every chip
+    (``moe_dispatch``), the rows cross (``moe_exchange_out``), the arrivals
+    are sorted by local expert (each sender's run already is, and the counts
+    say where its groups end, so no expert id travels) and go through ONE
+    piece of :func:`_held_sum`'s kind (:func:`_piece_terms`: the grouped
+    products over the groups' true sizes, ``moe_experts``), the outputs
+    return to their places and cross back (``moe_exchange_back``), and each
+    is added to its token with the router's weight (``moe_combine``): the
+    weights stay with their tokens. Like :func:`_held_sum` the forward pass
+    keeps only its arguments and the backward pass makes each round again:
+    the rows cross once more and, beside them, the cotangents of the tokens'
+    sums and the weights (float32, a number a slot); the rows' cotangents and
+    the weights' come back: the same exchange turned round. No buffer grows
+    with the imbalance: a chip that draws more slots costs more rounds.
+    """
+    experts = functools.partial(_piece_terms, activation, gated, group_rows)
+    swap = lambda t: jax.lax.all_to_all(t, axis, 0, 0)
+
+    def outbound(index, order, starts, to_chip):
+        """Round ``index`` at the sender: the slot [chips, rows] each place of
+        the send buffer carries and which places carry one."""
+        place = index * rows + jnp.arange(rows)[None, :]
+        live = place < to_chip[:, None]
+        slots = order[jnp.clip(starts[:, None] + place, 0, order.shape[0] - 1)]
+        return slots, live
+
+    def inbound(index, arrived):
+        """Round ``index`` at the holder: the order that sorts the
+        [chips x rows] arrivals by local expert, the groups' sizes [E] and
+        which sorted rows belong to a group [chips x rows, 1]."""
+        ends = jnp.cumsum(arrived, axis=1)                     # [chips, E]
+        place = index * rows + jnp.arange(rows)                # [rows]
+        expert = jnp.sum(place[None, :, None] >= ends[:, None, :], axis=-1)
+        order = jnp.argsort(expert.reshape(-1), stable=True)   # absent: last
+        mine = jnp.sum(_in_round(ends, arrived, index * rows, rows), axis=0)
+        live = (jnp.arange(chips * rows) < jnp.sum(mine))[:, None]
+        return order, mine, live
+
+    def send_rows(x, slots, live):
+        with jax.named_scope("moe_dispatch"):
+            rows_out = jnp.where(live[..., None], x[slots // top_k], 0)
+        with jax.named_scope("moe_exchange_out"):
+            return swap(rows_out)
+
+    def forward(x, slot_weights, w_up, w_down, order, starts, to_chip,
+                arrived, rounds):
+        ones = jnp.ones((chips * rows,), jnp.float32)
+
+        def add_round(index, total):
+            slots, live_out = outbound(index, order, starts, to_chip)
+            got = send_rows(x, slots, live_out).reshape(chips * rows, -1)
+            by_expert, mine, live = inbound(index, arrived)
+            with jax.named_scope("moe_dispatch"):
+                rows_in = got[by_expert]
+            terms = experts(live, mine, rows_in, ones, w_up, w_down)
+            with jax.named_scope("moe_combine"):
+                back = jnp.zeros_like(got).at[by_expert].set(
+                    terms.astype(x.dtype)).reshape(chips, rows, -1)
+            with jax.named_scope("moe_exchange_back"):
+                back = swap(back)
+            with jax.named_scope("moe_combine"):
+                weight = jnp.where(live_out, slot_weights[slots], 0.0)
+                return total.at[(slots // top_k).reshape(-1)].add(
+                    (back.astype(jnp.float32) * weight[..., None]).reshape(
+                        chips * rows, -1))
+
+        total = jax.lax.fori_loop(0, rounds, add_round,
+                                  jnp.zeros(x.shape, jnp.float32))
+        return total, (x, slot_weights, w_up, w_down, order, starts, to_chip,
+                       arrived, rounds)
+
+    def backward(kept, d_total):
+        (x, slot_weights, w_up, w_down, order, starts, to_chip, arrived,
+         rounds) = kept
+
+        def cotangents(index):
+            slots, live_out = outbound(index, order, starts, to_chip)
+            token = slots // top_k
+            got = send_rows(x, slots, live_out).reshape(chips * rows, -1)
+            with jax.named_scope("moe_combine"):
+                weight = jnp.where(live_out, slot_weights[slots], 0.0)
+                # (the sums' cotangent is a cast of x's dtype: exact there)
+                d_sums = jnp.where(live_out[..., None], d_total[token], 0
+                                   ).astype(x.dtype)
+            with jax.named_scope("moe_exchange_out"):
+                d_got = swap(d_sums).reshape(chips * rows, -1)
+                weight_got = swap(weight).reshape(-1)
+            by_expert, mine, live = inbound(index, arrived)
+            with jax.named_scope("moe_dispatch"):
+                rows_in = got[by_expert]
+            _, pull = jax.vjp(functools.partial(experts, live, mine),
+                              rows_in, weight_got[by_expert], w_up, w_down)
+            with jax.named_scope("moe_combine"):
+                d_terms = d_got[by_expert].astype(jnp.float32)
+            d_rows, d_weight, d_up, d_down = pull(d_terms)
+            with jax.named_scope("moe_dispatch"):
+                d_rows = jnp.zeros_like(got).at[by_expert].set(
+                    d_rows).reshape(chips, rows, -1)
+                d_weight = jnp.zeros_like(weight_got).at[by_expert].set(
+                    d_weight).reshape(chips, rows)
+            with jax.named_scope("moe_exchange_back"):
+                d_rows, d_weight = swap(d_rows), swap(d_weight)
+            return (slots.reshape(-1), token.reshape(-1),
+                    d_rows.reshape(chips * rows, -1), d_weight.reshape(-1),
+                    d_up, d_down)
+
+        def add_round(index, sums):
+            d_x, d_slot_weights, d_up, d_down = sums
+            slots, token, d_rows, d_weight, d_up_round, d_down_round = (
+                cotangents(index))
+            with jax.named_scope("moe_dispatch"):
+                d_x = d_x.at[token].add(d_rows)
+            with jax.named_scope("moe_combine"):
+                d_slot_weights = d_slot_weights.at[slots].add(d_weight)
+            with jax.named_scope("moe_experts"):
+                return (d_x, d_slot_weights, d_up + d_up_round,
+                        d_down + d_down_round)
+
+        # Round 0 outside the loop, as _held_sum's piece 0 (the weights' sums
+        # start as its cotangents; a place that carries no slot is zero).
+        slots, token, d_rows, d_weight, d_up, d_down = cotangents(0)
+        with jax.named_scope("moe_dispatch"):
+            d_x = jnp.zeros_like(x).at[token].add(d_rows)
+        with jax.named_scope("moe_combine"):
+            d_slot_weights = jnp.zeros_like(slot_weights).at[slots].add(
+                d_weight)
+        sums = jax.lax.fori_loop(1, rounds, add_round,
+                                 (d_x, d_slot_weights, d_up, d_down))
+        return (*sums, None, None, None, None, None)
+
+    total = jax.custom_vjp(lambda *args: forward(*args)[0])
+    total.defvjp(forward, backward)
+    return total
+
+
+def exchanged_experts(x, chosen, weights, w_up, w_down, n_experts: int,
+                      activation, axis: str, multiple: int = GMM_TILE_ROWS,
+                      gated: bool = False):
+    """The WHOLE layer's routed output for this chip's tokens, with the
+    experts divided over the mesh axis ``axis``: called inside a
+    ``shard_map`` manual over it, every chip with its own tokens ``x`` [T, H],
+    their routing over all ``n_experts`` (``chosen`` / ``weights`` [T, k] from
+    :func:`route`) and the stacked weights of the experts it holds (chip r of
+    c: experts ``r E / c .. (r + 1) E / c - 1``; ``w_up`` [E / c, H, F or
+    2F], ``w_down`` [E / c, F, H]).
+
+    The order of operations: DEAL the routed tokens round the chips
+    (:func:`_dealt`: rows, choices and weights, so that no pair of chips
+    carries one row's preference); sort the slots by expert (so by chip);
+    exchange the COUNTS (one small all-to-all: every chip learns how many
+    slots each chip sends each of its experts, and with one maximum over the
+    axis how many rounds the fullest pair needs); then the rounds of
+    :func:`_exchanged_sum`; deal the sums home. No slot is dropped under any
+    routing and nothing is sized for the worst case or by a capacity factor.
+
+    Counters, this chip's (``models/decoder.py`` and
+    ``pretrain.make_train_step`` add them up over layers, micro-batches and
+    chips): ``local_slots`` (slots that arrived here, this chip's own among
+    them), ``exchange_slots_out`` / ``exchange_slots_in`` (slots sent to and
+    received from OTHER chips: over all chips the two sums are equal),
+    ``exchange_bytes_out`` (those slots' rows, one crossing: slots x H x the
+    item size), ``chip_load_max_over_mean`` (the fullest chip's arrivals
+    over the mean chip's) and ``load_max_over_mean``
+    (the fullest expert's over the mean expert's, of the whole layer),
+    ``dropped_slots`` (slots of this chip that no round carried: 0, since the
+    rounds cover the fullest pair), ``pieces_run`` (the rounds: every chip
+    runs one piece a round) and ``tile_fill`` (:func:`held_experts`'s, over
+    the rounds' groups).
+    """
+    tokens, top_k = chosen.shape
+    held, hidden = w_up.shape[0], x.shape[-1]
+    chips = n_experts // held
+    rows = exchange_rows(tokens, top_k, chips, multiple)
+    group_rows = max(chips * rows // held, 1)   # of one round's arrivals
+    me = jax.lax.axis_index(axis)
+    deal = functools.partial(_dealt, axis, chips)
+    with jax.named_scope("moe_exchange_out"):
+        x, chosen, weights = deal(x), deal(chosen), deal(weights)
+    with jax.named_scope("moe_dispatch"):
+        key = chosen.reshape(-1)
+        order = jnp.argsort(key, stable=True)
+        counts = jnp.sum(jax.nn.one_hot(key, n_experts, dtype=jnp.int32),
+                         axis=0).reshape(chips, held)
+        to_chip = jnp.sum(counts, axis=1)
+        starts = jnp.cumsum(to_chip) - to_chip
+    with jax.named_scope("moe_exchange_out"):
+        arrived = jax.lax.all_to_all(counts, axis, 0, 0)    # [sender, E/c]
+        rounds = jax.lax.pmax(jnp.max(-(-to_chip // rows)), axis)
+    w_up, w_down = w_up.astype(x.dtype), w_down.astype(x.dtype)
+    out = _exchanged_sum(axis, chips, rows, top_k, activation, gated,
+                         group_rows)(
+        x, weights.reshape(-1), w_up, w_down, order, starts, to_chip, arrived,
+        rounds)
+    with jax.named_scope("moe_dispatch"):
+        sizes = jnp.sum(arrived, axis=0)                     # by local expert
+        n_here = jnp.sum(sizes)
+        remote = jnp.arange(chips) != me
+        sent = jnp.sum(jnp.where(remote, to_chip, 0))
+        total = jax.lax.psum(n_here, axis)
+        as_float = lambda v: v.astype(jnp.float32)
+        mean = jnp.maximum(as_float(total), 1.0)
+        # the rounds' groups, laid end to end: what the kernels' tiles see
+        ends = jnp.cumsum(arrived, axis=1)
+        lo = jnp.arange(-(-tokens * top_k // rows))[:, None, None] * rows
+        by_round = jnp.sum(_in_round(ends, arrived, lo, rows), axis=1)
+        stop = jnp.cumsum(by_round, axis=1)   # a round's groups start anew
+        fill = _tile_fill(stop - by_round, stop, jnp.cumsum(sizes),
+                          group_rows, (w_up.shape[1:], w_down.shape[1:]),
+                          x.dtype.itemsize)
+    counters = {
+        "local_slots": as_float(n_here),
+        "exchange_slots_out": as_float(sent),
+        "exchange_slots_in": as_float(
+            jnp.sum(jnp.where(remote[:, None], arrived, 0))),
+        "exchange_bytes_out": as_float(sent) * float(
+            hidden * x.dtype.itemsize),
+        "chip_load_max_over_mean": as_float(
+            jax.lax.pmax(n_here, axis)) * chips / mean,
+        "load_max_over_mean": as_float(
+            jax.lax.pmax(jnp.max(sizes), axis)) * n_experts / mean,
+        "dropped_slots": as_float(
+            jnp.sum(jnp.maximum(to_chip - rounds * rows, 0))),
+        "pieces_run": as_float(rounds),
+        "tile_fill": fill,
+    }
+    with jax.named_scope("moe_exchange_back"):
+        return deal(out.astype(x.dtype)), counters

@@ -3,7 +3,8 @@
 The TPU-native replacement for the reference's NCCL/DDP/torchrun stack
 (SURVEY.md §2.2/§5.8): instead of wrapping the model in DDP and letting NCCL
 allreduce gradients (run_pretraining.py:185,270), we lay the pod out as a
-`jax.sharding.Mesh` with axes ``('data', 'fsdp', 'seq', 'model')``, annotate
+`jax.sharding.Mesh` with axes ``('data', 'fsdp', 'pipe', 'seq', 'model',
+'expert')``, annotate
 parameters/activations with logical axis names, and let XLA insert the
 collectives (psum / all-gather / reduce-scatter) over ICI.
 
@@ -21,6 +22,11 @@ Strategies (rule sets):
                 stacked layers shard into contiguous stage blocks and
                 microbatches rotate through them on a GPipe schedule
                 (parallel/pipeline.py).
+  - ``ep``    — expert parallelism over the expert axis (the decoder
+                families that name their axes: a layer's experts and the
+                vocabulary's rows divided, the slots exchanged between
+                chips, ops/moe.py; the causal_lm step runs this axis
+                manually, pretrain.make_train_step).
 These compose: a mesh may use several axes at once. The composition is
 first-class via ``MeshSpec`` (``--mesh dp=4,fsdp=2,pipe=2``, the one way
 the command line names a mesh): any axis product's rules derive from one

@@ -25,8 +25,15 @@ AXIS_FSDP = "fsdp"
 AXIS_PIPE = "pipe"
 AXIS_SEQ = "seq"
 AXIS_MODEL = "model"
+# Expert parallelism (ops/moe.py ``exchanged_experts``): a layer's experts
+# are divided over this axis, the rows of the batch too (as over ``data``),
+# and token-slots cross it to the chip that holds their expert.
+AXIS_EXPERT = "expert"
 
-MESH_AXES = (AXIS_DATA, AXIS_FSDP, AXIS_PIPE, AXIS_SEQ, AXIS_MODEL)
+MESH_AXES = (AXIS_DATA, AXIS_FSDP, AXIS_PIPE, AXIS_SEQ, AXIS_MODEL,
+             AXIS_EXPERT)
+# The axes the rows of a batch are divided over.
+BATCH_AXES = (AXIS_DATA, AXIS_FSDP, AXIS_EXPERT)
 
 
 @dataclasses.dataclass
@@ -53,36 +60,37 @@ class MeshConfig:
     pipe: int = 1
     seq: int = 1
     model: int = 1
+    expert: int = 1
     dcn_data: int = 1
     dcn_process_granule: bool = False
 
-    def resolve(self, n_devices: int) -> tuple[int, int, int, int, int]:
-        """Per-ICI-granule axis sizes (the full mesh's data axis is
-        ``resolve()[0] * dcn_data``)."""
-        fixed = self.fsdp * self.pipe * self.seq * self.model
+    def resolve(self, n_devices: int) -> tuple[int, ...]:
+        """Per-ICI-granule axis sizes, in ``MESH_AXES``' order (the full
+        mesh's data axis is ``resolve()[0] * dcn_data``)."""
+        fixed = self.fsdp * self.pipe * self.seq * self.model * self.expert
         denom = fixed * self.dcn_data
         data = self.data
         if data == -1:
             if n_devices % denom != 0:
                 raise ValueError(
                     f"{n_devices} devices not divisible by "
-                    f"fsdp*pipe*seq*model*dcn_data={denom}"
+                    f"fsdp*pipe*seq*model*expert*dcn_data={denom}"
                 )
             data = n_devices // denom
         if data * denom != n_devices:
             raise ValueError(
                 f"mesh {data}x{self.fsdp}x{self.pipe}x{self.seq}"
-                f"x{self.model} (x{self.dcn_data} dcn)"
+                f"x{self.model}x{self.expert} (x{self.dcn_data} dcn)"
                 f" != {n_devices} devices"
             )
-        return (data, self.fsdp, self.pipe, self.seq, self.model)
+        return (data, self.fsdp, self.pipe, self.seq, self.model, self.expert)
 
 
 def create_mesh(
     mesh_config: Optional[MeshConfig] = None,
     devices: Optional[Sequence[jax.Device]] = None,
 ) -> Mesh:
-    """Build the ('data', 'fsdp', 'pipe', 'seq', 'model') mesh.
+    """Build the ('data', 'fsdp', 'pipe', 'seq', 'model', 'expert') mesh.
 
     Device order comes from `jax.devices()`, which JAX already returns in
     ICI-topology order — nearest-neighbor axes (model/seq) get the fastest
@@ -100,7 +108,7 @@ def create_mesh(
 
         device_array = mesh_utils.create_hybrid_device_mesh(
             shape,
-            (mesh_config.dcn_data, 1, 1, 1, 1),
+            (mesh_config.dcn_data,) + (1,) * (len(MESH_AXES) - 1),
             devices,
             process_is_granule=mesh_config.dcn_process_granule,
         )
@@ -113,12 +121,18 @@ def create_mesh(
 # Model code only knows logical names (bert.py); changing strategy never
 # touches model code — this table is the entire parallelism configuration.
 _BASE_RULES = [
-    ("batch", ("data", "fsdp")),  # batch shards over data (and fsdp if used)
+    # batch shards over data (and fsdp, and the expert axis, if used)
+    ("batch", BATCH_AXES),
     ("seq_act", "seq"),  # activation sequence axis (context parallelism)
     ("pos", None),
     ("types", None),
     ("classes", None),
     ("layers", None),  # scan axis; an active 'pipe' axis overrides this
+    # the decoder families (models/decoder.py): a layer's stacked experts by
+    # expert, embedding and head by row of the vocabulary; an active 'expert'
+    # axis overrides both
+    ("experts", None),
+    ("vocab_rows", None),
 ]
 
 # The rule TEMPLATE: for each param logical axis, the mesh axis that
@@ -157,7 +171,10 @@ def derive_rules(active) -> list[tuple]:
 
     An active 'pipe' prepends ``('layers', 'pipe')`` — each pipeline stage
     holds L/P contiguous layers; the pipeline engine runs 'pipe' manually
-    (explicit ppermute) and leaves the other axes to the compiler. Every
+    (explicit ppermute) and leaves the other axes to the compiler. An active
+    'expert' prepends the decoder families' two rules (a layer's experts and
+    the vocabulary's rows over it; ``pretrain.make_train_step`` runs that
+    axis manually too). Every
     template rule then resolves to its controlling axis when active, else
     to None (replicated). Only param axes appear here; batch/seq_act
     sharding lives in ``_BASE_RULES`` (first-wins matching)."""
@@ -165,6 +182,8 @@ def derive_rules(active) -> list[tuple]:
     rules = []
     if AXIS_PIPE in active:
         rules.append(("layers", AXIS_PIPE))
+    if AXIS_EXPERT in active:
+        rules += [("experts", AXIS_EXPERT), ("vocab_rows", AXIS_EXPERT)]
     for name, axis in _RULE_TEMPLATE:
         rules.append((name, axis if axis is not None and axis in active
                       else None))
@@ -196,6 +215,8 @@ _SPEC_KEY_ALIASES = {
     "ring": "seq",
     "model": "model",
     "tp": "model",
+    "expert": "expert",
+    "ep": "expert",
     "dcn": "dcn_data",
     "dcn_data": "dcn_data",
 }
@@ -217,12 +238,14 @@ class MeshSpec:
     pipe: int = 1
     seq: int = 1
     model: int = 1
+    expert: int = 1
     dcn_data: int = 1
 
     @staticmethod
     def parse(text: str) -> "MeshSpec":
         """Parse ``"dp=4,fsdp=2,pipe=2,seq=1"`` (keys accept the
-        strategy-flavored aliases pp→pipe, sp/ring→seq, tp→model)."""
+        strategy-flavored aliases pp→pipe, sp/ring→seq, tp→model,
+        ep→expert)."""
         sizes = {}
         for item in str(text).split(","):
             item = item.strip()
@@ -255,7 +278,7 @@ class MeshSpec:
     def canonical(self) -> str:
         """Round-trippable spec string; inactive axes are elided."""
         parts = [f"dp={self.data}"]
-        for key in ("fsdp", "pipe", "seq", "model"):
+        for key in ("fsdp", "pipe", "seq", "model", "expert"):
             size = getattr(self, key)
             if size != 1:
                 parts.append(f"{key}={size}")
@@ -267,7 +290,7 @@ class MeshSpec:
         """Plain-int dict for the (stdlib-only) checkpoint manifest."""
         return {"data": self.data, "fsdp": self.fsdp, "pipe": self.pipe,
                 "seq": self.seq, "model": self.model,
-                "dcn_data": self.dcn_data}
+                "expert": self.expert, "dcn_data": self.dcn_data}
 
     @staticmethod
     def from_dict(d: dict) -> "MeshSpec":
@@ -281,7 +304,8 @@ class MeshSpec:
         if self.data != 1:
             active.add(AXIS_DATA)
         for axis, size in ((AXIS_FSDP, self.fsdp), (AXIS_PIPE, self.pipe),
-                           (AXIS_SEQ, self.seq), (AXIS_MODEL, self.model)):
+                           (AXIS_SEQ, self.seq), (AXIS_MODEL, self.model),
+                           (AXIS_EXPERT, self.expert)):
             if size > 1:
                 active.add(axis)
         return frozenset(active)
@@ -292,7 +316,7 @@ class MeshSpec:
 
         ``packed`` enables the sequence-packing compatibility check; pass
         ``n_devices`` to also enforce the axis-product divisibility."""
-        for key in ("fsdp", "pipe", "seq", "model", "dcn_data"):
+        for key in ("fsdp", "pipe", "seq", "model", "expert", "dcn_data"):
             size = getattr(self, key)
             if size < 1:
                 raise MeshSpecError(
@@ -323,7 +347,7 @@ class MeshSpec:
                     dcn_process_granule: bool = False) -> MeshConfig:
         return MeshConfig(data=self.data, fsdp=self.fsdp, pipe=self.pipe,
                           seq=self.seq, model=self.model,
-                          dcn_data=self.dcn_data,
+                          expert=self.expert, dcn_data=self.dcn_data,
                           dcn_process_granule=dcn_process_granule)
 
     def rules(self) -> list[tuple]:

@@ -14,17 +14,20 @@ import flax.linen as nn
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from bert_pytorch_tpu.parallel.mesh import AXIS_SEQ, BATCH_AXES
+
 
 def mesh_sharding(mesh: Mesh, *spec) -> NamedSharding:
     return NamedSharding(mesh, P(*spec))
 
 
 def batch_sharding(mesh: Mesh, seq_sharded: bool = False) -> NamedSharding:
-    """Sharding for [B, S] / [B] host batches: batch over data(+fsdp) axes,
-    sequence over seq axis when context parallelism is on."""
+    """Sharding for [B, S] / [B] host batches: batch over the data, fsdp and
+    expert axes (``BATCH_AXES``), sequence over seq axis when context
+    parallelism is on."""
     if seq_sharded:
-        return NamedSharding(mesh, P(("data", "fsdp"), "seq"))
-    return NamedSharding(mesh, P(("data", "fsdp")))
+        return NamedSharding(mesh, P(BATCH_AXES, AXIS_SEQ))
+    return NamedSharding(mesh, P(BATCH_AXES))
 
 
 def params_shardings(mesh: Mesh, abstract_variables: Any, rules) -> Any:

@@ -33,7 +33,8 @@ from bert_pytorch_tpu.ops.grad_utils import global_norm
 from bert_pytorch_tpu.ops.remat import remat_policy
 from bert_pytorch_tpu.optim.transforms import (LossScaleState, OptState,
                                                opt_step_count)
-from bert_pytorch_tpu.parallel.mesh import AXIS_PIPE, AXIS_SEQ
+from bert_pytorch_tpu.parallel.mesh import (AXIS_EXPERT, AXIS_PIPE, AXIS_SEQ,
+                                            BATCH_AXES)
 from bert_pytorch_tpu.parallel.sharding import params_shardings
 from bert_pytorch_tpu.utils import trace_parts
 
@@ -100,6 +101,15 @@ JOYAI_SCOPES = (
     "moe_combine", "moe_shared", "mtp", "mtp_merge", "mtp_head", "mtp_loss",
     "lm_head", "lm_loss")
 
+# ... and those of the mellum family's step (models/mellum.py: laguna's
+# blocks without gate, dense layer or shared expert; under an expert axis the
+# slots' exchange, the embedding's and the head's crossing, and the one sum of
+# the whole tensors' gradients an update).
+MELLUM_SCOPES = (
+    "attn_qkv", "attn_rope", "attn_out", "moe", "moe_route", "moe_dispatch",
+    "moe_exchange_out", "moe_experts", "moe_exchange_back", "moe_combine",
+    "embed_exchange", "lm_head_gather", "lm_head", "lm_loss", "grad_sync")
+
 # Rows longer than this many positions take the output head and its loss in
 # pieces of this length (models/losses.py chunked_next_token_loss).
 LM_HEAD_PIECE = 2048
@@ -135,14 +145,15 @@ def state_shardings(mesh: Mesh, model, rules, sample_inputs,
 
 def batch_shardings(mesh: Mesh, batch_spec: dict, seq_sharded: bool = False) -> dict:
     """Shardings for the [A, B, ...] stacked microbatch dict: accumulation
-    axis replicated (scanned), batch axis sharded over data(+fsdp), and —
+    axis replicated (scanned), batch axis sharded over data(+fsdp, +expert:
+    ``parallel/mesh.py BATCH_AXES``), and —
     under context parallelism (``seq_sharded``) — the sequence axis of
     [A, B, S] entries sharded over the mesh 'seq' axis."""
     out = {}
     for key, ndim in batch_spec.items():
-        spec = [None, ("data", "fsdp")] + [None] * (ndim - 2)
+        spec = [None, BATCH_AXES] + [None] * (ndim - 2)
         if seq_sharded and ndim == 3:
-            spec[2] = "seq"
+            spec[2] = AXIS_SEQ
         out[key] = NamedSharding(mesh, P(*spec))
     return out
 
@@ -227,6 +238,19 @@ def _apply_causal_lm_loss(model, variables, mb):
     ids = mb["input_ids"]
     pieces, ragged = divmod(ids.shape[-1], LM_HEAD_PIECE)
     whole = bool(ragged) or pieces < 2
+    if model.expert_axis:
+        # The head's columns lie on the axis's chips: always in pieces (one,
+        # for a short row), each as long as a chip's share of LM_HEAD_PIECE
+        # rows gathered over the axis.
+        with trace_parts.modules():
+            hidden, counters = model.apply(variables, ids,
+                                           method="hidden_states")
+        finer = pieces * model.expert_shards
+        loss, accuracy = chunked_next_token_loss(
+            hidden, model.head_kernel(variables["params"]), ids,
+            1 if whole else pieces if ids.shape[-1] % finer else finer,
+            axis=model.expert_axis)
+        return loss, {"token_accuracy": accuracy, **counters}
     streams = model.prediction_streams()
     method = "streams" if streams else None if whole else "hidden_states"
     with trace_parts.modules():  # the modules' Python, by class, at trace time
@@ -267,17 +291,107 @@ def _shared_head_loss(model, variables, hidden, ids, pieces: int,
     return next_token_loss(logits, ids, shift, scope)
 
 
+# How a counter's name ends says how it is reduced, over an update's
+# micro-batches and over the chips of an expert axis alike.
+_SUMMED = ("_slots", "_run", "_slots_out", "_slots_in", "_bytes_out")
+_WORST = ("_max_over_mean",)
+
+
 def _aux_metrics(aux) -> dict:
     """Step metrics from the micro-batches' stacked aux: the MLM objective's
     is its accuracy; the causal objective's is a dict whose counters add up
-    over the update (``*_slots``, ``*_run``) or take its worst
+    over the update (``_SUMMED``: ``*_slots``, ``*_run``, the exchange's
+    ``*_slots_out``, ``*_slots_in``, ``*_bytes_out``) or take its worst
     (``*_max_over_mean``)."""
     if not isinstance(aux, dict):
         return {"mlm_accuracy": jnp.mean(aux)}
-    reduce = lambda name: (jnp.sum if name.endswith(("_slots", "_run")) else
-                           jnp.max if name.endswith("_max_over_mean") else
+    reduce = lambda name: (jnp.sum if name.endswith(_SUMMED) else
+                           jnp.max if name.endswith(_WORST) else
                            jnp.mean)
     return {name: reduce(name)(value) for name, value in aux.items()}
+
+
+def expert_axis_shards(mesh) -> int:
+    """The width of ``mesh``'s expert axis (1: none, or no mesh)."""
+    return 1 if mesh is None else mesh.shape.get(AXIS_EXPERT, 1)
+
+
+def on_expert_axis(fn, mesh, in_specs, out_specs):
+    """``fn`` under a ``shard_map`` manual over every axis of ``mesh``, each
+    chip with its shards. The collectives inside are written by hand
+    (``ops/moe.py exchanged_experts``, ``models/losses.py``,
+    ``CausalDecoder.embed``), so nothing is checked or inserted for them
+    (``check_vma=False``: a sum over the axis transposes to a sum)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
+def _sums_over_chips(sums, param_specs, rows_over):
+    """The chips' gradient sums made the update's: a tensor every chip holds
+    whole gets the sum over every axis the rows are divided over; one that is
+    divided over the expert axis holds the other chips' terms already (they
+    crossed inside the step) and is summed over the other axes alone. Both
+    over the number of chips, since each chip's loss is its own rows' mean."""
+    def divided(spec) -> bool:
+        return any(AXIS_EXPERT in (part if isinstance(part, tuple)
+                                   else (part,)) for part in spec)
+
+    others = tuple(a for a in rows_over if a != AXIS_EXPERT)
+    chips = jax.lax.axis_size(rows_over)
+
+    def over_chips(g, spec):
+        axes = others if divided(spec) else rows_over
+        return (jax.lax.psum(g, axes) if axes else g) / chips
+
+    return jax.tree_util.tree_map(over_chips, sums, param_specs)
+
+
+def _expert_axis_micro_batches(model, mesh, param_specs):
+    """``(params, batch) -> (gradient sums, losses [A], aux {name: [A]})`` of
+    an update's micro-batches for a model whose experts and vocabulary are
+    divided over ``mesh``'s expert axis: the scan over the micro-batches of
+    :func:`make_train_step`, inside ONE ``shard_map``. Every chip runs the
+    model (``CausalDecoder.on_expert_axis``) on its own rows and takes the
+    gradient of its own rows' loss; what crosses the axis inside (the
+    embedding's look-up, the slots, the head) carries the other chips' terms
+    to the tensors that are divided, so their gradient sums are whole. The
+    tensors every chip holds whole (attention, norms, routers) get each
+    chip's rows' part, and those are summed over the axis ONCE an update,
+    after the last micro-batch (:func:`_sums_over_chips`). The objective is
+    the mean over every chip's rows: sums over the chips, over their number.
+    Losses and counters are reduced over the chips as ``_aux_metrics`` reduces
+    them over the update.
+    """
+    shards = mesh.shape[AXIS_EXPERT]
+    rows_over = tuple(a for a in BATCH_AXES if mesh.shape[a] > 1)
+    local = model.on_expert_axis(AXIS_EXPERT, shards)
+
+    def per_chip(params, batch):
+        def body(sums, mb):
+            (loss, aux), grads = jax.value_and_grad(
+                lambda p: _apply_causal_lm_loss(local, {"params": p}, mb),
+                has_aux=True)(params)
+            with jax.named_scope("grad_accumulate"):
+                sums = jax.tree_util.tree_map(
+                    lambda a, g: a + g.astype(a.dtype), sums, grads)
+            return sums, (loss, aux)
+
+        sums, (losses, aux) = _scan_micro_batches(
+            body, jax.tree_util.tree_map(
+                lambda p: jnp.zeros(p.shape, jnp.float32), params), batch)
+        with jax.named_scope("grad_sync"):
+            sums = _sums_over_chips(sums, param_specs, rows_over)
+        with jax.named_scope("step_metrics"):
+            over = lambda name: (
+                jax.lax.psum if name.endswith(_SUMMED) else
+                jax.lax.pmax if name.endswith(_WORST) else jax.lax.pmean)
+            aux = {name: over(name)(value, rows_over)
+                   for name, value in aux.items()}
+            return sums, jax.lax.pmean(losses, rows_over), aux
+
+    return on_expert_axis(
+        per_chip, mesh, (param_specs, {"input_ids": P(None, BATCH_AXES)}),
+        (param_specs, P(), P()))
 
 
 def _real_tokens(batch):
@@ -426,9 +540,12 @@ def make_train_step(
     aligning the due gate with the host's run-local sync cadence.
     TrainTelemetry.step_done pops and emits it.
 
-    ``mesh`` is accepted and not read: the step takes its layout from
-    ``shardings``. It stays only because ``benchmarks/rehearse/`` passes
-    it and a PR outside the benchmark may not edit those files.
+    ``mesh``: with an ``expert`` axis wider than 1 (``--mesh ep=4``) the
+    ``causal_lm`` step runs its micro-batches under a ``shard_map`` over the
+    mesh (:func:`_expert_axis_micro_batches`), each chip with its shards of
+    ``shardings``; the optimizer, the clipping and the norms stay outside it,
+    on the divided arrays, for the compiler. Any other mesh is not read: the
+    step takes its layout from ``shardings``.
     """
     # What the model is trained on: ``mlm`` (BERT: masked tokens and next
     # sentence) unless the model's family says otherwise
@@ -461,6 +578,16 @@ def make_train_step(
         raise ValueError(
             f"kfac_capture_microbatches must be first|all, got "
             f"{kfac_capture_microbatches!r}")
+    sharded_micro_batches = None
+    if expert_axis_shards(mesh) > 1:
+        if objective != "causal_lm" or loss_scale or shardings is None:
+            raise ValueError(
+                "an expert axis runs the causal_lm step, with shardings and "
+                "without a loss scale")
+        sharded_micro_batches = _expert_axis_micro_batches(
+            model, mesh, jax.tree_util.tree_map(
+                lambda s: s.spec, shardings.params))
+
     def loss_fn(params, mb, rng):
         if objective == "causal_lm":  # no dropout: rng unused
             return _apply_causal_lm_loss(model, {"params": params}, mb)
@@ -595,6 +722,8 @@ def make_train_step(
                 grads = grads0
                 losses = loss0[None]
                 accs = acc0[None]
+        elif sharded_micro_batches is not None:
+            grads, losses, accs = sharded_micro_batches(state.params, batch)
         else:
             (grads, _), (losses, accs) = _scan_micro_batches(
                 body, (zero_grads, step_rng), batch

@@ -176,6 +176,19 @@ def laguna_forward_flops_per_token(config, seq_len: int) -> dict:
     return dict(parts, head=2.0 * h * config.vocab_size)
 
 
+def mellum_forward_flops_per_token(config, seq_len: int) -> dict:
+    """:func:`laguna_forward_flops_per_token` of a ``mellum`` model, which
+    has no per-head gate (and, by its config's derived keys, no dense layer
+    and no shared expert). The experts held are the config's: the whole
+    layer's under an expert axis, where the count is of all the axis's chips
+    together."""
+    parts = laguna_forward_flops_per_token(config, seq_len)
+    parts["attention_proj"] -= sum(
+        2 * config.hidden_size * heads
+        for heads in config.num_attention_heads_per_layer)
+    return parts
+
+
 def phi_flash_forward_flops_per_token(config, seq_len: int) -> dict:
     """Forward matmul FLOPs per token of a ``phi4flash`` model on THIS chip
     (the layers, heads and vocabulary rows it holds), by part: ``mlp`` (two
@@ -354,6 +367,7 @@ def causal_lm_train_flops_per_seq(config, seq_len: int) -> float:
                  "qwen3_next": qwen3_next_forward_flops_per_token,
                  "KeyeVL2": keye_vl_forward_flops_per_token,
                  "joyai_llm_flash": joyai_forward_flops_per_token,
+                 "mellum": mellum_forward_flops_per_token,
                  }[config.model_type]
     return 3.0 * seq_len * sum(per_token(config, seq_len).values())
 

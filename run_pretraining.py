@@ -41,7 +41,8 @@ from bert_pytorch_tpu.ops.remat import kept_residual_bytes
 from bert_pytorch_tpu.parallel import (MeshSpec, MeshSpecError, create_mesh,
                                        logical_axis_rules)
 from bert_pytorch_tpu.parallel import launcher
-from bert_pytorch_tpu.parallel.mesh import AXIS_DATA, AXIS_FSDP, AXIS_PIPE
+from bert_pytorch_tpu.parallel.mesh import (AXIS_DATA, AXIS_EXPERT, AXIS_FSDP,
+                                            AXIS_PIPE)
 from bert_pytorch_tpu.testing import faults
 from bert_pytorch_tpu.utils import checkpoint as ckpt
 from bert_pytorch_tpu.utils import logging as logger
@@ -421,7 +422,8 @@ def setup_training(args):
     # Accumulation math (reference :213-228), in global terms: one optimizer
     # step consumes global_batch_size sequences as accumulation_steps
     # microbatches of local_batch_size per data shard.
-    n_data = mesh.shape[AXIS_DATA] * mesh.shape[AXIS_FSDP]
+    n_data = (mesh.shape[AXIS_DATA] * mesh.shape[AXIS_FSDP]
+              * mesh.shape[AXIS_EXPERT])
     global_microbatch = args.local_batch_size * n_data
     if args.global_batch_size % global_microbatch != 0:
         raise ValueError(
@@ -889,7 +891,7 @@ def main(args) -> dict:
                     stats_phase=stats_phase)
             else:
                 train_step = pretrain.make_train_step(
-                    model, tx, schedule=schedule,
+                    model, tx, schedule=schedule, mesh=mesh,
                     next_sentence=bool(getattr(config, "next_sentence", False)),
                     shardings=shardings, batch_shardings_=b_shardings,
                     max_pred_per_seq=eff_max_pred,

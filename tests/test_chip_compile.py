@@ -259,6 +259,9 @@ def test_ssd_scan_compiles_at_8192(chip):
     pytest.param(8192, 1024, 768, 2048, id="keye-down"),
     pytest.param(2048, 256, 2048, 1536, id="joyai-up"),  # a quarter of keye's
     pytest.param(2048, 256, 768, 2048, id="joyai-down"),  # fill, its shapes
+    # a round's arrivals from four chips, sixteen experts a chip
+    pytest.param(32768, 2048, 2304, 1792, id="mellum-up"),
+    pytest.param(32768, 2048, 896, 2304, id="mellum-down"),
 ])
 def test_grouped_products_compile_at_the_cells_shapes(chip, monkeypatch, rows,
                                                       group, k, n):
@@ -281,6 +284,100 @@ def test_grouped_products_compile_at_the_cells_shapes(chip, monkeypatch, rows,
         ((8,), jnp.int32), ((rows, n), jnp.bfloat16))
     _assert_kernel(compiled, "gmm", "tgmm")
     assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_the_exchange_compiles_over_four_chips_at_the_published_widths(
+        topo, monkeypatch):
+    """The mellum cell's expert layer whole under its expert axis, forward
+    and backward, for the described 2x2 host: a row of 8192 tokens a chip,
+    64 experts top-8 of 2304 x 896 divided sixteen a chip. The chip's own
+    compiler takes the all-to-alls inside the two loops whose trip counts are
+    traced (the rounds), the grouped kernels over a round's 32,768 arrivals
+    and the sorts; what crosses the axis is in the program (all-to-all of the
+    rows both ways) and nothing of a layer is gathered (no all-gather: a
+    chip's 16 experts stay where they are); the temporaries stay under 3 GB a
+    chip (two buffers of a round's rows, 151 MB each, and the piece's
+    activations: nothing sized for 65,536 slots to one chip)."""
+    import flax.linen as nn
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from bert_pytorch_tpu import pretrain
+    from bert_pytorch_tpu.config import MellumConfig
+    from bert_pytorch_tpu.models.laguna import expert_layer
+    from bert_pytorch_tpu.ops import moe
+    from bert_pytorch_tpu.parallel.mesh import AXIS_EXPERT
+
+    monkeypatch.setattr(moe, "interpret_mode", lambda: False)
+    cfg = MellumConfig(num_hidden_layers=4)
+    mesh = Mesh(np.asarray(topo.devices[:4]), (AXIS_EXPERT,))
+    whole = expert_layer(cfg, jnp.bfloat16, axis_names=True)
+    local = expert_layer(cfg, jnp.bfloat16, axis_names=True,
+                         expert_axis=AXIS_EXPERT, expert_shards=4)
+    boxed = jax.eval_shape(lambda: whole.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 16, 2304), jnp.bfloat16)))
+    specs = jax.tree_util.tree_map(
+        lambda names: P(*(AXIS_EXPERT if n == "experts" else None
+                          for n in names)), nn.get_partition_spec(boxed))
+    place = lambda leaf, spec: jax.ShapeDtypeStruct(
+        leaf.shape, leaf.dtype, sharding=NamedSharding(mesh, spec))
+    params = jax.tree_util.tree_map(place, nn.unbox(boxed), specs)
+    x = place(jax.ShapeDtypeStruct((4, 8192, 2304), jnp.bfloat16),
+              P(AXIS_EXPERT))
+
+    def per_chip(variables, x_):
+        def loss(v, h):
+            out, counters = local.apply(v, h)
+            return jnp.sum(jnp.square(out.astype(jnp.float32))), counters
+
+        (_, counters), grads = jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True)(variables, x_)
+        return grads, jax.lax.psum(counters["moe_dropped_slots"], AXIS_EXPERT)
+
+    fn = pretrain.on_expert_axis(per_chip, mesh, (specs, P(AXIS_EXPERT)),
+                                 ((specs, P(AXIS_EXPERT)), P()))
+    compiled = jax.jit(fn).lower(params, x).compile()
+    text = compiled.as_text()
+    assert text.count("all-to-all") >= 7  # counts, rows, terms; four back
+    assert "all-gather" not in text
+    names = set(re.findall(r'op_name="([^"]+)"', text))
+    for scope in ("moe_route", "moe_dispatch", "moe_exchange_out",
+                  "moe_experts", "moe_exchange_back", "moe_combine"):
+        assert any(f"/{scope}/" in name for name in names), scope
+    _assert_kernel(compiled, "gmm", "tgmm")
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 1024 ** 3
+
+
+def test_mellum_step_compiles_for_four_chips_at_the_published_widths(
+        topo, monkeypatch):
+    """The mellum cell's WHOLE train step at its real size (2124 M
+    parameters over four chips, 4 micro-batches of 1 row of 8192 tokens a
+    chip, ``--remat full``, AdamW) under its own mesh (``--mesh ep=4``)
+    through the rehearsal's own ``compile_step``: the TPU's compiler takes it
+    within a 16 GB chip (a chip's arguments are its 595.2 M parameters'
+    twelve bytes: a quarter of the experts and of both tables, the rest
+    whole) without rematerializing on its own account, with the windowed and
+    the causal flash kernels, the grouped products and the exchange's
+    all-to-alls in it. A compile that passes here is not a fit (PERF.md 4):
+    the chips' own compiler has the last word. (Here and not in
+    ``test_chip_compile_steps.py``, which holds two such compiles already.)"""
+    import benchmarks.run as bench_run
+    from benchmarks.rehearse.compile_real_mellum import WORKLOAD, compile_step
+    from bert_pytorch_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "interpret_mode", lambda: False)
+    step = compile_step(bench_run.context(bench_run.ROOT, WORKLOAD), topo)
+    assert step["parameters"] == 2_123_976_960
+    assert step["remat_fusions"] == 0
+    a_chip = (2_123_976_960 - 0.75 * (4 * 64 * 3 * 2304 * 896
+                                      + 2 * 98304 * 2304))
+    assert a_chip == pytest.approx(595.2e6, rel=1e-3)
+    assert step["argument_bytes"] == pytest.approx(12 * a_chip, rel=1e-3)
+    assert step["argument_bytes"] + step["temp_bytes"] < 17.2e9
+    assert step["window_kernels"] > 0 and step["tpu_custom_calls"] >= 40
+    assert step["collectives"]["all-to-all"] >= 4 * 7
+    # the tables' crossings: ids and hidden states gathered, never a table
+    assert 1 <= step["collectives"]["all-gather"] <= 12
 
 
 def test_sparse_attention_compiles_at_16384(chip):

@@ -52,6 +52,13 @@ PATHS = {
     "joyai_llm_flash": (('"flash_mla_fwd"', '"flash_mla_bwd_dq"',
                          '"flash_mla_bwd_dkv"', "@tgmm"),
                         ('"flash_fwd"', '"rotary_turn"')),
+    "mellum": (('"flash_fwd"', '"flash_window_fwd"', '"flash_window_bwd_dq"',
+                '"rotary_turn"', "@tgmm"),
+               ("flash_gated", "all_to_all", "sdy.manual_computation")),
+    "mellum_ep4": (('"flash_fwd"', '"flash_window_fwd"', '"rotary_turn"',
+                    "@tgmm", "sdy.manual_computation", "stablehlo.all_to_all",
+                    "stablehlo.all_gather", "stablehlo.reduce_scatter",
+                    '"expert"'), ("flash_gated",)),
     "kernel_bidirectional_dropout": (
         ("name=flash_fwd", "name=flash_bwd_dq", "name=flash_bwd_dkv",
          "prng_seed"), ("flash_window",)),
@@ -70,7 +77,7 @@ PATHS = {
 SCOPES = {"nemotron_h": "CAUSAL_LM_SCOPES", "laguna": "LAGUNA_SCOPES",
           "phi4flash": "PHI_FLASH_SCOPES", "zaya": "ZAYA_SCOPES",
           "qwen3_next": "QWEN3_NEXT_SCOPES", "KeyeVL2": "KEYE_SCOPES",
-          "joyai_llm_flash": "JOYAI_SCOPES"}
+          "joyai_llm_flash": "JOYAI_SCOPES", "mellum": "MELLUM_SCOPES"}
 
 
 @pytest.fixture(scope="module")
@@ -169,6 +176,25 @@ def test_a_family_that_asks_for_nothing_still_runs_its_core_twice(runs, name):
     again in each block's recompute, as before PR 49."""
     assert (_held(runs, name, "flash_fwd")
             == 2 * _held(runs, name, "flash_bwd_dq") > 0)
+
+
+def test_the_step_under_an_expert_axis_is_one_manual_region(runs):
+    """``mellum_ep4.txt``: the update's micro-batches are ONE ``shard_map``
+    over the mesh (the optimizer stays outside, on the divided arrays); the
+    kernels inside are the one-device step's, call for call; the exchange's
+    all-to-alls are there and the one-device step has none."""
+    read = lambda name: open(os.path.join(runs[0], name + ".txt")).read()
+    one, four = read("mellum"), read("mellum_ep4")
+    assert four.count("sdy.manual_computation(") == 1
+    for kernel in ("flash_fwd", "flash_window_fwd", "flash_window_bwd_dq",
+                   "flash_window_bwd_dkv", "rotary_turn"):
+        held = lambda text: text.count(f'kernel_name = "{kernel}"')
+        assert held(four) == held(one) > 0, kernel
+    assert "stablehlo.all_to_all" not in one
+    # the slots' rows and terms, the counts, the backward's cotangents and
+    # weights: more than one exchange a layer and pass
+    layers = tool.SIZES["mellum"]["num_hidden_layers"]
+    assert four.count("stablehlo.all_to_all") >= 6 * layers
 
 
 def _held(runs, name, kernel):

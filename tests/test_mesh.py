@@ -8,10 +8,10 @@ from bert_pytorch_tpu.parallel import MeshConfig, create_mesh
 
 def test_resolve_dcn_divides_data_axis():
     # 16 devices, dcn_data=2: the ICI granule holds 8-way data parallelism.
-    assert MeshConfig(dcn_data=2).resolve(16) == (8, 1, 1, 1, 1)
+    assert MeshConfig(dcn_data=2).resolve(16) == (8, 1, 1, 1, 1, 1)
     # explicit data size is the PER-GRANULE size
     assert MeshConfig(data=4, dcn_data=2, model=2).resolve(16) == \
-        (4, 1, 1, 1, 2)
+        (4, 1, 1, 1, 2, 1)
 
 
 def test_resolve_dcn_divisibility_errors():
@@ -33,7 +33,7 @@ def test_create_mesh_plain_shapes(devices):
     mesh = create_mesh(MeshConfig(data=2, seq=2, model=2),
                        devices=jax.devices()[:8])
     assert dict(zip(mesh.axis_names, mesh.devices.shape)) == {
-        "data": 2, "fsdp": 1, "pipe": 1, "seq": 2, "model": 2}
+        "data": 2, "fsdp": 1, "pipe": 1, "seq": 2, "model": 2, "expert": 1}
 
 
 def test_current_mesh_reads_the_with_block(devices):

@@ -26,7 +26,11 @@ LAMB, dropout drawn by ``rbg``: the phase-1 cells' path) and ``KeyeVL2.txt``
 shapes its kernels take: the choice's, the core's three and the objective's)
 and ``joyai_llm_flash.txt`` (a dense and an expert layer of latent attention
 and the multi-token-prediction module: the causal flash kernels at a head of
-128 + 64 against values of 128, the shared head run twice):
+128 + 64 against values of 128, the shared head run twice), ``mellum.txt``
+(a sliding and a full layer before routed experts, on one device) and
+``mellum_ep4.txt`` (the same step under ``--mesh ep=4`` over four virtual
+devices: the micro-batches inside a ``shard_map``, the slots' all-to-alls,
+the head's and the embedding's crossings, the one gradient sum):
 ``make_train_step(...).trace(...).lower(lowering_platforms=("tpu",))`` as
 text, with the Mosaic payloads (the serialized kernels, which hold the
 checkout's path and line numbers) and the source locations cut out; and
@@ -48,6 +52,10 @@ import re
 import sys
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
+    # (the step under an expert axis is lowered over four virtual devices)
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                               + " --xla_force_host_platform_device_count=4")
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
@@ -110,6 +118,14 @@ SIZES = {
         q_lora_rank=96, kv_lora_rank=64, qk_nope_head_dim=128,
         qk_rope_head_dim=64, v_head_dim=128, n_routed_experts=4, ep_size=4,
         ep_rank=1, num_experts_per_tok=3, moe_intermediate_size=128),
+    # a sliding and a full layer at a head of 128, every MLP routed, the
+    # whole layer's experts (the expert axis divides them, not the config)
+    "mellum": dict(
+        vocab_size=256, hidden_size=128, num_hidden_layers=2,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=128,
+        layer_types=["sliding_attention", "full_attention"],
+        mlp_layer_types=["sparse", "sparse"], sliding_window=128,
+        num_experts=8, num_experts_per_tok=3, moe_intermediate_size=128),
     "bert": dict(
         vocab_size=512, hidden_size=128, num_hidden_layers=2,
         num_attention_heads=2, intermediate_size=256,
@@ -121,19 +137,36 @@ def ids(*shape):
     return jax.ShapeDtypeStruct(shape, np.int32)
 
 
-def lowered_step(model, batch, max_pred=80, lamb=False):
-    """The step's text for the TPU, less the Mosaic payloads and locations."""
+def lowered_step(model, batch, max_pred=80, lamb=False, mesh_spec=None):
+    """The step's text for the TPU, less the Mosaic payloads and locations.
+    ``mesh_spec`` (``ep=4``): the step under that mesh over the first virtual
+    devices, its state divided by the model's axis names."""
+    import contextlib
+
     from bert_pytorch_tpu import optim, pretrain
 
     causal = getattr(model, "objective", "mlm") == "causal_lm"
     tx = (optim.lamb if lamb else optim.adamw)(
         1e-3, max_grad_norm=1.0, weight_decay_mask=optim.no_decay_mask)
     sample = tuple(jnp.zeros((1, 16), jnp.int32) for _ in range(1 if causal else 3))
-    with jax.default_prng_impl("rbg"):
+    mesh, placed = contextlib.nullcontext(), {}
+    if mesh_spec:
+        from bert_pytorch_tpu.parallel import (MeshSpec, create_mesh,
+                                               logical_axis_rules)
+
+        spec = MeshSpec.parse(mesh_spec)
+        mesh = create_mesh(spec.mesh_config(), devices=jax.devices()[
+            :max(spec.data, 1) * spec.expert])
+        placed = dict(
+            mesh=mesh, shardings=pretrain.state_shardings(
+                mesh, model, logical_axis_rules(spec), sample),
+            batch_shardings_=pretrain.batch_shardings(mesh, {"input_ids": 3}))
+    with mesh, jax.default_prng_impl("rbg"):
         state = jax.eval_shape(
-            pretrain.make_init_fn(model, tx, sample, None), jax.random.PRNGKey(0))
+            pretrain.make_init_fn(model, tx, sample, placed.get("shardings")),
+            jax.random.PRNGKey(0))
         step = pretrain.make_train_step(
-            model, tx, next_sentence=not causal,
+            model, tx, next_sentence=not causal, **placed,
             **({} if causal else {"max_pred_per_seq": max_pred}))
         text = step.trace(state, batch).lower(
             lowering_platforms=("tpu",)).as_text()
@@ -155,10 +188,10 @@ def family_model(family, remat, backend):
         attention_backend=backend)
 
 
-def decoder_step(family, seq=SEQ, backend="pallas"):
+def decoder_step(family, seq=SEQ, backend="pallas", mesh_spec=None, rows=1):
     """A decoder family's step: ``--remat full``, AdamW, rows of token ids."""
     return lowered_step(family_model(family, "full", backend),
-                        {"input_ids": ids(2, 1, seq)})
+                        {"input_ids": ids(2, rows, seq)}, mesh_spec=mesh_spec)
 
 
 def bert_step(seq, max_pred, backend, lamb=False):
@@ -230,6 +263,9 @@ def steps():
         ("bert_phase1", lambda: bert_step(128, 20, "xla", lamb=True)),
         ("KeyeVL2", lambda: decoder_step("KeyeVL2")),
         ("joyai_llm_flash", lambda: decoder_step("joyai_llm_flash")),
+        ("mellum", lambda: decoder_step("mellum")),
+        ("mellum_ep4", lambda: decoder_step("mellum", mesh_spec="ep=4",
+                                            rows=4)),
     )
 
 
